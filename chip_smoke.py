@@ -1,0 +1,651 @@
+#!/usr/bin/env python3
+"""Does the system still start on the chip? Run it from the checkout's root:
+
+    python3 chip_smoke.py             # one chip: the server path, end to end
+    python3 chip_smoke.py --chips 4   # four chips: tensor parallelism only
+
+One chip (what the driver runs): builds a Qwen3-8B-shaped model with random
+Q40 weights from `--seed` (launch.py's `qwen3_8b_q40`: 36 layers, dim 4096,
+ffn 12288, 32Q/8KV, head_dim 128, vocab 151936; no width is cut), then
+
+1. numbers, on a depth-cut copy at the same widths: teacher-forced logits of
+   the bf16 kernel path against the same engine's float32 XLA path; the int8
+   page-table kernel against the gather+dequant formulation on a random
+   pool; a short paged `--kv-dtype int8` generation against the bf16-paged
+   one, token for token;
+2. the server, at full depth: `server.api.serve(server.api.parse_args(...))`
+   with the entry points' defaults (paged KV, prefix cache, n-gram
+   speculation, grammar arena, warm-up on, sanitizers armed), batch 2,
+   answering over HTTP on localhost one non-streamed and one streamed chat
+   completion, two concurrent ones, the same greedy request twice (the
+   second a prefix hit with the same text) and one `response_format`
+   request. It fails on any response that is not 200, is empty or names no
+   finish reason; on any recovery, supervisor rebuild or post-warm-up
+   recompile in `/stats`; and when the compiled decode, batch-decode,
+   prefill or verify program holds fewer Mosaic kernels than the path has
+   kernelled matmuls (a weight that fell off a kernel takes the XLA
+   dequantize-then-dot path without a word).
+
+Four chips (`--chips 4`, run by the builder): only `--tp 4` over
+`jax.devices()` — the shard_map pipeline engine at the same widths, paged
+KV, batch 2 — and what it is compared with, the one-device engine in the
+same process on the same prompts.
+
+Every phase prints one JSON line. The LAST line of standard output is
+`{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}` and
+the exit code 0 only if JAX found the TPU and no phase failed; anything else
+ends in `"ok": false` with the reasons and a non-zero exit code — on a
+machine without the chip the device check comes first and nothing is built.
+
+`--rehearse` is the CPU rehearsal the tests run: tiny widths, interpret-mode
+kernels, the same control flow in every phase; it must end in `"ok": false`
+for the device check alone.
+"""
+
+import argparse
+import json
+import os
+import sys
+import threading
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(HERE, ".chip_smoke")  # git-ignored; models are built here
+DEADLINE_S = 1150  # the chip tool's limit is 1200 s: end with a reason first
+
+# launch.py `qwen3_8b_q40` (Qwen/Qwen3-8B config.json)
+QWEN3_8B = dict(
+    dim=4096, hidden_dim=12288, n_layers=36, n_heads=32, n_kv_heads=8,
+    head_dim=128, vocab_size=151936, seq_len=40960, rope_theta=1000000.0,
+)
+# --rehearse: the smallest shape every Q40 kernel's alignment rule accepts
+TINY = dict(
+    dim=256, hidden_dim=512, n_layers=2, n_heads=8, n_kv_heads=4,
+    head_dim=32, vocab_size=512, seq_len=256, rope_theta=10000.0,
+)
+CHATML = (
+    "{% for m in messages %}<|im_start|>{{ m['role'] }}\n{{ m['content'] }}"
+    "<|im_end|>\n{% endfor %}{% if add_generation_prompt %}"
+    "<|im_start|>assistant\n{% endif %}"
+)
+# kernelled matmuls of a dense step: wqkv, wo, w13, w2, wcls
+N_MATMUL_KERNELS = 5
+
+# bf16 kernels vs float32 XLA, teacher-forced (phase "numbers"). The logits
+# of a random model are ~N(0, s), near-tied at the top: bounds are in units
+# of their std s. Measured on the v5e over 4 layers (PR 21): top-1
+# agreement 0.91-0.97 and max |diff| 0.09 s on 64 prefill positions
+# (bf16-dequant + flash kernels), 0.94 and 0.16 s on 32 decode positions
+# (int8-MXU kernels, whose activations are quantized to int8 as well).
+MIN_TOP1_AGREEMENT = 0.75
+MAX_LOGIT_DIFF_STD = 0.3
+# int8 page-table kernel vs gather+dequant on one random pool (bf16 output;
+# measured 0.0055)
+MAX_PAGED_KERNEL_DIFF = 0.03
+# int8 KV vs bf16 KV, greedy: leading tokens that must agree. int8 rounding
+# parts two near-tied random-weight logits sooner or later (measured: after
+# 8 and 14 of 32 tokens, on two weight draws) — the count is printed
+MIN_INT8_LEADING_MATCH = 4
+# tp=4 vs one device: prefill logits, in units of their std (measured
+# 0.076), and greedy tokens: the psum's bf16 reduction order parts two
+# near-tied random-weight logits sooner or later (measured: one row after 4
+# of 16 tokens, the other not at all) — where is printed
+MAX_TP_LOGIT_DIFF_STD = 0.25
+MIN_TP_LEADING_MATCH = 2
+
+FAILURES: list = []
+T0 = time.time()
+
+
+def host_gib() -> dict:
+    """This process's resident memory and what the machine has left (the
+    chip machine ends a command that reaches its host-memory limit)."""
+    try:
+        with open("/proc/self/statm") as f:
+            rss = int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+        with open("/proc/meminfo") as f:
+            info = dict(line.split(":") for line in f)
+        kib = lambda key: int(info[key].split()[0]) / 2**20
+        return {"rss": round(rss / 2**30, 1), "avail": round(kib("MemAvailable"), 1),
+                "shmem": round(kib("Shmem"), 1)}
+    except (OSError, KeyError, ValueError):
+        return {}
+
+
+def say(phase: str, **fields) -> None:
+    line = {"phase": phase, "t": round(time.time() - T0, 1), "host_gib": host_gib(), **fields}
+    print(json.dumps(line), flush=True)
+
+
+def fail(reason: str) -> None:
+    FAILURES.append(reason)
+    say("FAIL", reason=reason)
+
+
+def finish(device: dict) -> "NoReturn":
+    ok = not FAILURES
+    result = {"ok": ok, "device": device}
+    if not ok:
+        result["reasons"] = FAILURES
+    print(json.dumps(result), flush=True)
+    # no process was started; daemon threads (HTTP, Batcher) die with us
+    os._exit(0 if ok else 1)
+
+
+# -- models -------------------------------------------------------------------
+
+
+def build_model(shape: dict, n_layers: int, seed: int) -> str:
+    from distributed_llama_tpu.formats.mfile import ArchType, RopeType, tensor_walk
+    from distributed_llama_tpu.testing import tiny_header, write_tiny_model
+
+    h = tiny_header(
+        arch=ArchType.QWEN3, rope_type=RopeType.FALCON,
+        **{**shape, "n_layers": n_layers},
+    )
+    path = os.path.join(WORK, f"qwen3_d{h.dim}_L{n_layers}_seed{seed}.m")
+    t0 = time.time()
+    specs = tensor_walk(h)  # header_bytes is set by the writer; payload only
+    want = sum(s.n_bytes for s in specs)
+    have = os.path.getsize(path) if os.path.exists(path) else -1
+    reused = have > want and have - want < 4096
+    if not reused:
+        tmp = f"{path}.{os.getpid()}.tmp"  # two smokes may build side by side
+        write_tiny_model(tmp, h, seed=seed, scale=0.02, bulk=True)
+        os.replace(tmp, path)
+    say(
+        "build", path=os.path.relpath(path, HERE), layers=n_layers,
+        gbytes=round(os.path.getsize(path) / 1e9, 2),
+        seconds=round(time.time() - t0, 1), reused=reused,
+    )
+    return path
+
+
+def build_tokenizer(vocab_size: int) -> str:
+    from distributed_llama_tpu.testing import write_tiny_tokenizer
+
+    os.makedirs(WORK, exist_ok=True)
+    path = os.path.join(WORK, f"byte_vocab_{vocab_size}.t")
+    write_tiny_tokenizer(path, pad_to=vocab_size, chat_template=CHATML)
+    return path
+
+
+# -- phase: numbers -----------------------------------------------------------
+
+
+def free(engine) -> None:
+    import gc
+
+    engine.close()
+    engine.params = engine.cache = None
+    gc.collect()
+
+
+def phase_numbers(cut_model: str, tokenizer: str, rehearse: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llama_tpu.ops.attention import gqa_attention
+    from distributed_llama_tpu.ops.kv_quant import dequantize_kv
+    from distributed_llama_tpu.ops.pallas_attention import paged_flash_attention
+    from distributed_llama_tpu.runtime.engine import InferenceEngine
+    from distributed_llama_tpu.runtime.profiling import build_cost_table
+
+    rng = np.random.default_rng(11)
+    seq = 256
+    n_pre, n_dec = (16, 4) if rehearse else (64, 32)
+
+    def engine(**kw):
+        return InferenceEngine(cut_model, max_seq_len=seq, kv_layout="paged", **kw)
+
+    # (a) bf16 kernels vs float32 XLA, the same tokens through both
+    vocab = None
+    runs = {}
+    for dtype in ("bfloat16", "float32"):
+        eng = engine(compute_dtype=dtype)
+        vocab = eng.cfg.vocab_size
+        toks = [int(t) for t in np.random.default_rng(5).integers(1, vocab, n_pre + n_dec)]
+        pre = eng.forward_tokens(toks[:n_pre], 0, logits_mode="all")[0]
+        dec = np.stack(
+            [eng.decode_one(toks[n_pre + i], n_pre + i)[0] for i in range(n_dec)]
+        )
+        runs[dtype] = (pre, dec)
+        depth = eng.cfg.n_layers
+        free(eng)
+    for name, a, b in (
+        ("prefill", runs["bfloat16"][0], runs["float32"][0]),
+        ("decode", runs["bfloat16"][1], runs["float32"][1]),
+    ):
+        std = float(b.std())
+        top1 = float((a.argmax(-1) == b.argmax(-1)).mean())
+        diff = float(np.abs(a - b).max()) / std
+        say(
+            "numbers", check=f"bf16-kernels vs float32-xla, {name}",
+            depth_cut_layers=depth, positions=int(a.shape[0]),
+            finite=bool(np.isfinite(a).all()), top1_agreement=round(top1, 3),
+            max_abs_diff_over_std=round(diff, 4), logit_std=round(std, 3),
+            bounds=[MIN_TOP1_AGREEMENT, MAX_LOGIT_DIFF_STD],
+        )
+        if not (np.isfinite(a).all() and a.shape == b.shape):
+            fail(f"numbers/{name}: logits not finite or wrong shape {a.shape}")
+        elif top1 < MIN_TOP1_AGREEMENT or diff > MAX_LOGIT_DIFF_STD:
+            fail(f"numbers/{name}: top1 {top1:.3f}, max diff {diff:.3f} std")
+
+    # (b) the int8 page-table kernel alone, at the pool's real trailing shape
+    interp = bool(os.environ.get("DLT_PALLAS_INTERPRET"))
+    n_kv, hd, heads = (4, 32, 8) if rehearse else (8, 128, 32)
+    L, P, ps, b, t, n_read = 2, 64, 16, 2, 5, 8
+    kp, vp = (jnp.asarray(rng.integers(-127, 128, (L, P, ps, n_kv, hd), dtype=np.int8)) for _ in "kv")
+    ks, vs = (jnp.asarray(rng.uniform(1e-3, 2e-2, (L, P, ps, n_kv)).astype(np.float32)) for _ in "kv")
+    q = jnp.asarray(rng.standard_normal((b, t, heads, hd), dtype=np.float32)).astype(jnp.bfloat16)
+    table_ = jnp.asarray(rng.permutation(P)[: b * n_read].reshape(b, n_read).astype(np.int32))
+    pos = jnp.asarray([n_read * ps - t - 3, 17], jnp.int32)
+    got = paged_flash_attention(
+        q, kp, vp, ks, vs, jnp.int32(1), pos, table_, n_read=n_read,
+        page_size=ps, interpret=interp,
+    )
+    k_ref = dequantize_kv(kp[1, table_], ks[1, table_], jnp.float32).reshape(b, n_read * ps, n_kv, hd)
+    v_ref = dequantize_kv(vp[1, table_], vs[1, table_], jnp.float32).reshape(b, n_read * ps, n_kv, hd)
+    want = gqa_attention(q.astype(jnp.float32), k_ref, v_ref, pos[:, None] + jnp.arange(t)[None, :])
+    kdiff = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+    say(
+        "numbers", check="int8 page-table kernel vs gather+dequant",
+        pool_tail=[ps, n_kv, hd], max_abs_diff=round(kdiff, 5),
+        bound=MAX_PAGED_KERNEL_DIFF,
+    )
+    if not kdiff <= MAX_PAGED_KERNEL_DIFF:
+        fail(f"numbers/paged kernel: max diff {kdiff}")
+
+    # (c) a short paged int8 generation vs the bf16-paged one (host loop:
+    # greedy argmax of the t=1 forward, the program the kernel serves)
+    prompt = [int(x) for x in np.random.default_rng(6).integers(1, vocab, 20)]
+    n_new = 8 if rehearse else 32
+    gens, kernels = {}, {}
+    for kv in ("bfloat16", "int8"):
+        eng = engine(cache_dtype=kv, device_decode=False)
+        res = eng.generate(prompt, len(prompt) + n_new - 1, sampler=None)
+        gens[kv] = res.tokens[len(prompt):]
+        entry = build_cost_table(
+            eng, [("prefill", 1, eng._kv_bucket(len(prompt) + 1))]
+        ).entries
+        kernels[kv] = next(iter(entry.values())) if entry else None
+        free(eng)
+    lead = 0
+    while lead < n_new and gens["int8"][lead] == gens["bfloat16"][lead]:
+        lead += 1
+    count = lambda e: (e.pallas_calls, e.tpu_custom_calls) if e else None
+    fused = bool(
+        kernels["int8"] and kernels["bfloat16"]
+        and kernels["int8"].pallas_calls == kernels["bfloat16"].pallas_calls + 1
+    )
+    say(
+        "numbers", check="paged int8 generation vs bf16-paged",
+        new_tokens=n_new, leading_tokens_equal=lead,
+        int8_decode_arm="fused page-table kernel" if fused else "gather+dequant",
+        decode_kernels_traced_compiled={k: count(v) for k, v in kernels.items()},
+        bound=MIN_INT8_LEADING_MATCH,
+    )
+    if len(gens["int8"]) != n_new or lead < min(MIN_INT8_LEADING_MATCH, n_new):
+        fail(f"numbers/int8 generation: {lead} leading tokens equal of {n_new}")
+    if not fused:
+        fail("numbers/int8 decode did not take the fused page-table kernel")
+
+
+# -- phase: server ------------------------------------------------------------
+
+
+def http(port: int, path: str, body: dict | None = None, timeout: float = 600.0):
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        if body is None:
+            conn.request("GET", path)
+        else:
+            conn.request(
+                "POST", path, json.dumps(body).encode(),
+                {"Content-Type": "application/json"},
+            )
+        r = conn.getresponse()
+        return r.status, r.read()
+    finally:
+        conn.close()
+
+
+def chat(port: int, name: str, content: str, max_tokens: int, **extra) -> dict:
+    """One /v1/chat/completions round trip, held to the smoke's contract."""
+    body = {
+        "messages": [{"role": "user", "content": content}],
+        "max_tokens": max_tokens, "temperature": 0.0, **extra,
+    }
+    t0 = time.time()
+    try:
+        status, raw = http(port, "/v1/chat/completions", body)
+    except Exception as e:
+        fail(f"request {name}: {type(e).__name__}: {e}")
+        return {}
+    out = {"request": name, "status": status, "seconds": round(time.time() - t0, 2)}
+    text, reason = "", ""
+    if status == 200 and extra.get("stream"):
+        events = [
+            json.loads(line[len("data: "):])
+            for line in raw.decode("utf-8", "replace").split("\r\n\r\n")
+            if line.startswith("data: {")
+        ]
+        text = "".join(e["choices"][0].get("delta", {}).get("content", "") for e in events)
+        reason = events[-1]["choices"][0]["finish_reason"] if events else ""
+        out["chunks"] = len(events)
+    elif status == 200:
+        reply = json.loads(raw)
+        text = reply["choices"][0]["message"]["content"]
+        reason = reply["choices"][0]["finish_reason"]
+        usage = reply["usage"]
+        out.update(
+            prompt_tokens=usage["prompt_tokens"],
+            completion_tokens=usage["completion_tokens"],
+            prefix_hit_tokens=(usage.get("goodput") or {}).get("prefix_hit_tokens"),
+        )
+    else:
+        out["body"] = raw[:300].decode("utf-8", "replace")
+    out.update(text_chars=len(text), finish_reason=reason)
+    say("request", **out)
+    if status != 200 or not text or reason not in ("length", "stop"):
+        fail(f"request {name}: status {status}, {len(text)} chars, finish {reason!r}")
+    out["text"] = text
+    return out
+
+
+def phase_server(model: str, tokenizer: str, rehearse: bool) -> None:
+    import socket
+
+    import jax
+
+    from distributed_llama_tpu.formats import native
+    from distributed_llama_tpu.server import api
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    argv = [
+        "--model", model, "--tokenizer", tokenizer, "--port", str(port),
+        "--batch", "2", "--temperature", "0.0",
+        "--max-seq-len", "256" if rehearse else "4096",
+    ]
+    if rehearse:
+        argv += ["--max-batch-size", "8"]  # prefill chunk: a smaller ladder
+    say("serve", argv=[a for a in argv if a not in (model, tokenizer)])
+    t0 = time.time()
+    httpd = api.serve(api.parse_args(argv))
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    engine = httpd.api_state.engine
+    cfg = engine.cfg
+    plan = engine.warm_plan()
+    st = engine.stats.snapshot()
+    say(
+        "serve", start_seconds=round(time.time() - t0, 1),
+        model=dict(
+            arch="qwen3", layers=cfg.n_layers, dim=cfg.dim, ffn=cfg.hidden_dim,
+            heads=[cfg.n_heads, cfg.n_kv_heads], head_dim=cfg.head_dim,
+            vocab=cfg.vocab_size, seq_len=cfg.seq_len, weights="q40",
+        ),
+        kv=dict(layout=engine.kv_layout, dtype=cfg.cache_dtype, page=engine.page_size),
+        native_bpe_loaded=native.bpe_available(),
+        warm_plan_programs=len(plan),
+        **{
+            k: st["gauges"].get(k)
+            for k in ("startup_cost_table_s", "startup_warmup_s", "sanitizer_warm_compiles")
+        },
+        device_gib={
+            k: round(v / 2**30, 2)
+            for k, v in (jax.devices()[0].memory_stats() or {}).items()
+            if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+        },
+    )
+
+    # kernels in the compiled programs (the cost table serve() built)
+    table = engine.cost_table(build=False)
+    kvb = max(k for _, _, k in plan)
+    on_tpu = jax.devices()[0].platform == "tpu"
+    for kind, size, need in (
+        ("decode", 1, N_MATMUL_KERNELS),
+        ("batch_decode", 1, N_MATMUL_KERNELS),
+        ("prefill", max(s for k, s, _ in plan if k == "prefill"), N_MATMUL_KERNELS + 1),
+        ("verify", min(s for k, s, _ in plan if k == "verify"), N_MATMUL_KERNELS),
+    ):
+        e = table.entries.get((kind, size, kvb)) if table else None
+        n = (e.tpu_custom_calls if on_tpu else e.pallas_calls) if e else -1
+        say(
+            "kernels", program=f"{kind}[{size}|kv{kvb}]", need=need,
+            pallas_calls_traced=e and e.pallas_calls,
+            tpu_custom_calls_compiled=e and e.tpu_custom_calls,
+        )
+        if n < need:
+            fail(
+                f"kernels: {kind}[{size}] holds {n} kernels, the path has "
+                f"{need} (a weight fell off its kernel, or flash is missing)"
+            )
+    if table is None or table.failures:
+        fail(f"cost table: {table and dict(list(table.failures.items())[:3])}")
+
+    chat(port, "plain", "Say something about rivers.", 24)
+    chat(port, "streamed", "Say something about hills.", 24, stream=True)
+    both = [
+        threading.Thread(target=chat, args=(port, f"concurrent-{i}", text, 16))
+        for i, text in enumerate(("One two three four.", "A b c d e f g h i j k."))
+    ]
+    for th in both:
+        th.start()
+    for th in both:
+        th.join(timeout=900)
+    story = "In the valley " + "the river ran past the old mill and " * 3 + "then?"
+    first = chat(port, "repeat-1", story, 16)
+    second = chat(port, "repeat-2", story, 16)
+    if first.get("text") != second.get("text"):
+        fail("repeated greedy request returned a different text")
+    if not second.get("prefix_hit_tokens"):
+        fail("repeated request was not a prefix-cache hit")
+    schema = {
+        "type": "object", "properties": {"ok": {"type": "boolean"}}, "required": ["ok"],
+    }
+    shaped = chat(
+        port, "response_format", "Is water wet? Answer in JSON.", 32,
+        response_format={"type": "json_schema", "json_schema": {"schema": schema}},
+    )
+    try:
+        if not isinstance(json.loads(shaped.get("text", ""))["ok"], bool):
+            raise ValueError("ok is not a boolean")
+    except (ValueError, KeyError, TypeError) as e:
+        # a length stop mid-object is legal; a finished one must parse
+        if shaped.get("finish_reason") == "stop":
+            fail(f"response_format text does not satisfy its schema: {e}")
+
+    status, raw = http(port, "/stats")
+    stats = json.loads(raw) if status == 200 else {}
+    counters = stats.get("steps", {}).get("counters", {})
+    sup = stats.get("supervisor", {})
+    watched = {
+        k: counters.get(k, 0)
+        for k in (
+            "sanitizer_recompiles", "supervisor_rebuilds", "stall_resets",
+            "recover_reset_failed", "sanitizer_d2h_violations",
+        )
+    }
+    say(
+        "stats", status=status, watched=watched,
+        supervisor={k: sup.get(k) for k in ("state", "rebuilds_total", "resets_total")},
+        requests_completed=counters.get("requests_completed"),
+        prefix_hits=counters.get("prefix_hits"),
+        prefix_hit_tokens=counters.get("prefix_hit_tokens"),
+        spec_rounds=counters.get("spec_rounds"),
+        spec_accepted_tokens=counters.get("spec_accepted_tokens"),
+        kv_pool=stats.get("kv_pool"), notices=stats.get("notices"),
+        batcher=stats.get("batcher"),
+    )
+    if (
+        status != 200 or any(watched.values()) or sup.get("state") != "serving"
+        or sup.get("rebuilds_total") or sup.get("resets_total")
+    ):
+        fail(f"/stats: {watched}, supervisor {sup.get('state')}/{sup.get('rebuilds_total')}/{sup.get('resets_total')}")
+    if counters.get("requests_completed", 0) < 7:
+        fail(f"/stats counts {counters.get('requests_completed')} completed requests of 7")
+    httpd.shutdown()
+    httpd.server_close()
+
+
+# -- phase: four chips ----------------------------------------------------------
+
+
+def phase_tp4(model: str, tokenizer: str, rehearse: bool) -> None:
+    import jax
+    import numpy as np
+
+    from distributed_llama_tpu.cli import make_engine
+    from distributed_llama_tpu.runtime.profiling import count_tpu_kernels, lower_entry
+    from distributed_llama_tpu.server.api import parse_args
+
+    seq = "256" if rehearse else "512"
+    base = ["--model", model, "--tokenizer", tokenizer, "--batch", "2", "--max-seq-len", seq]
+    rng = np.random.default_rng(3)
+    vocab = (TINY if rehearse else QWEN3_8B)["vocab_size"]
+    prompts = [[int(x) for x in rng.integers(1, vocab, n)] for n in (24, 17)]
+    n_new = 6 if rehearse else 16
+    out = {}
+    for name, argv in (("tp4", base + ["--tp", "4"]), ("one", base)):
+        t0 = time.time()
+        eng = make_engine(parse_args(argv))
+        load_s = time.time() - t0
+        logits = eng.forward_tokens(prompts[0], 0)[0]
+        eng.reset()
+        tokens = eng.generate_batch(prompts, n_new)
+        info = dict(engine=name, load_seconds=round(load_s, 1), notices=eng.notices)
+        if name == "tp4":
+            mesh = dict(eng.mesh.shape)
+            w, pool = eng.params.layers.wqkv.q, eng.cache.k
+            share = lambda a: sorted(
+                round(s.data.nbytes / a.nbytes, 3) for s in a.addressable_shards
+            )
+            per_dev = [
+                round((d.memory_stats() or {}).get("bytes_in_use", 0) / 2**30, 2)
+                for d in jax.devices()
+            ]
+            # a decode program generate_batch just ran (largest power of
+            # two within the budget, shallowest kv bucket)
+            key = min(
+                (k for k in eng.warm_plan() if k[0] == "decode" and k[1] <= n_new),
+                key=lambda k: (-k[1], k[2]),
+            )
+            compiled = lower_entry(eng, key).compile()
+            kernels = count_tpu_kernels(compiled)
+            text = compiled.as_text()
+            reduces = text.count(" all-reduce(") + text.count(" all-reduce-start(")
+            info.update(
+                mesh=mesh, execution="pipeline" if eng.use_pipeline else "gspmd",
+                kv_layout=eng.kv_layout, wqkv_shard_shares=share(w),
+                kv_pool_shard_shares=share(pool), gib_in_use_per_device=per_dev,
+                step_program=f"{key[0]}[{key[1]}|kv{key[2]}]",
+                tpu_custom_calls=kernels, all_reduces=reduces,
+            )
+            if share(w) != [0.25] * 4 or share(pool) != [0.25] * 4:
+                fail(f"tp4: shards are not quarters: {share(w)} / {share(pool)}")
+            if len({s.device for s in w.addressable_shards}) != 4:
+                fail("tp4: the weight's shards do not sit on four devices")
+            if reduces < 2:
+                fail(f"tp4: {reduces} all-reduces in the compiled step")
+            if jax.devices()[0].platform == "tpu" and kernels < N_MATMUL_KERNELS - 1:
+                fail(f"tp4: {kernels} Mosaic kernels in the compiled step")
+        say("tp4", **info)
+        out[name] = (logits, tokens)
+        free(eng)
+    (la, ta), (lb, tb) = out["tp4"], out["one"]
+    std = float(lb.std())
+    diff = float(np.abs(la - lb).max()) / std
+    leads = []
+    for a, b in zip(ta, tb):
+        n = 0
+        while n < min(len(a), len(b)) and a[n] == b[n]:
+            n += 1
+        leads.append(n)
+    say(
+        "tp4", check="tp=4 vs one device", prefill_logits_max_diff_over_std=round(diff, 4),
+        top1_equal=bool(la.argmax() == lb.argmax()), new_tokens=n_new,
+        leading_tokens_equal=leads, bounds=[MAX_TP_LOGIT_DIFF_STD, MIN_TP_LEADING_MATCH],
+    )
+    if not np.isfinite(la).all() or diff > MAX_TP_LOGIT_DIFF_STD:
+        fail(f"tp4: prefill logits differ by {diff:.3f} std")
+    if min(leads) < min(MIN_TP_LEADING_MATCH, n_new):
+        fail(f"tp4: greedy tokens part after {leads} tokens")
+
+
+# -- main ---------------------------------------------------------------------
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+
+    os.environ["DLT_SANITIZERS"] = "1"  # recompile sentinel + host-sync guard
+    if args.rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["DLT_PALLAS_INTERPRET"] = "1"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.chips}"
+        )
+
+    def out_of_time():
+        time.sleep(DEADLINE_S)
+        FAILURES.append(f"not finished after {DEADLINE_S} s")
+        finish({"platform": None, "kind": None, "count": 0})
+
+    threading.Thread(target=out_of_time, daemon=True).start()
+
+    try:
+        import jax
+
+        d = jax.devices()[0]
+        device = {"platform": d.platform, "kind": d.device_kind, "count": len(jax.devices())}
+    except Exception as e:  # no backend at all: still end with a reason
+        FAILURES.append(f"jax found no device: {type(e).__name__}: {e}")
+        finish({"platform": None, "kind": None, "count": 0})
+    say("device", **device, jax=jax.__version__)
+    if device["platform"] != "tpu" or device["count"] != args.chips:
+        fail(f"need {args.chips} tpu device(s), jax found {device['count']} x {device['platform']}")
+        if not args.rehearse:
+            finish(device)
+
+    try:
+        sys.path.insert(0, HERE)
+        from distributed_llama_tpu.runtime.engine import enable_compilation_cache
+    except ImportError as e:
+        fail(f"the program is not here: {e}")
+        finish(device)
+    say("compile_cache", dir=enable_compilation_cache())
+    if args.rehearse:
+        # on the chip every program takes seconds to compile and is cached;
+        # here none would reach the cache's one-second floor
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+    shape = TINY if args.rehearse else QWEN3_8B
+    phases = (
+        [("tp4", phase_tp4, shape["n_layers"])] if args.chips == 4
+        else [("numbers", phase_numbers, 1 if args.rehearse else 4),
+              ("server", phase_server, shape["n_layers"])]
+    )
+    for name, phase, depth in phases:
+        try:
+            tokenizer = build_tokenizer(shape["vocab_size"])
+            phase(build_model(shape, depth, args.seed), tokenizer, args.rehearse)
+        except Exception as e:  # a failed phase is a result, not a crash
+            import traceback
+
+            traceback.print_exc()
+            fail(f"{name}: {type(e).__name__}: {str(e)[:500]}")
+    finish(device)
+
+
+if __name__ == "__main__":
+    main()

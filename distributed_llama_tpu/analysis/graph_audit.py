@@ -41,10 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.4.x keeps these importable from jax.core (newer: jax.extend)
-    from jax.extend.core import ClosedJaxpr, Jaxpr  # type: ignore
-except ImportError:
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 from .jaxpr_tools import (  # noqa: F401  (re-exported: the walking layer

@@ -36,10 +36,7 @@ from collections import Counter
 
 import numpy as np
 
-try:  # jax >= 0.4.x keeps these importable from jax.core (newer: jax.extend)
-    from jax.extend.core import ClosedJaxpr, Jaxpr  # type: ignore
-except ImportError:
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 #: primitive names that are explicit cross-device collectives

@@ -158,7 +158,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
 
 
 def make_engine(args) -> InferenceEngine:
+    from .runtime.engine import enable_compilation_cache
     from .runtime.prefix_cache import resolve_budget_mb
+
+    enable_compilation_cache()
     from .runtime.speculative import ModelDraft, resolve_draft_k, resolve_spec_mode
 
     max_chunk = args.prefill_chunk_size if args.prefill_chunk_size > 0 else args.max_chunk

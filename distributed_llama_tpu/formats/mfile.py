@@ -324,6 +324,23 @@ def _norm_epsilon(v: int) -> float:
     raise ValueError(f"unsupported norm epsilon code {v}")
 
 
+def encode_tensor(x: np.ndarray, float_type: int, scratch: np.ndarray | None = None) -> bytes:
+    """Serialize a tensor (any shape, row-major) to its .m payload bytes
+    (`scratch`: see quants.quantize_q40)."""
+    from .quants import quantize_q40, quantize_q80
+
+    flat = np.ascontiguousarray(x, dtype=np.float32).reshape(-1)
+    if float_type == FloatType.F32:
+        return flat.tobytes()
+    if float_type == FloatType.F16:
+        return flat.astype(np.float16).tobytes()
+    if float_type == FloatType.Q40:
+        return quantize_q40(flat, scratch)
+    if float_type == FloatType.Q80:
+        return quantize_q80(flat)
+    raise ValueError(f"unsupported float type {float_type}")
+
+
 class MFileWriter:
     """Writes .m files in the reference layout; used by the converter and by
     the synthetic-model generator in tests."""
@@ -335,19 +352,12 @@ class MFileWriter:
         self._f.write(data)
 
     def write_tensor(self, x: np.ndarray, float_type: int):
-        from .quants import quantize_q40, quantize_q80
+        self._f.write(encode_tensor(x, float_type))
 
-        flat = np.ascontiguousarray(x, dtype=np.float32).reshape(-1)
-        if float_type == FloatType.F32:
-            self._f.write(flat.tobytes())
-        elif float_type == FloatType.F16:
-            self._f.write(flat.astype(np.float16).tobytes())
-        elif float_type == FloatType.Q40:
-            self._f.write(quantize_q40(flat))
-        elif float_type == FloatType.Q80:
-            self._f.write(quantize_q80(flat))
-        else:
-            raise ValueError(f"unsupported float type {float_type}")
+    def write_encoded(self, data: bytes):
+        """Append payload bytes `encode_tensor` produced (a whole tensor or a
+        row range of one — the walk order is the caller's to keep)."""
+        self._f.write(data)
 
     def close(self):
         self._f.close()

@@ -1,15 +1,18 @@
-"""ctypes loader for the native Q40 codec (native/q40_codec.cpp).
+"""ctypes loader for the native BPE merge engine (native/bpe_encoder.cpp).
 
-Builds the shared library on first use with g++ (cached next to the source;
-rebuilt when the source is newer) and exposes `q40_unpack_t_native`. All
-callers must tolerate `available() == False` (no compiler, sandboxed fs) and
-fall back to the numpy codec in formats/quants.py — the native path is a
-load-time accelerator, not a correctness dependency.
+Builds the shared library on first use with g++ and caches it next to the
+source under a name that carries a hash of the source and the build flags,
+so a library built from other source — a stale one, or one that arrived with
+a copied tree whose mtimes say nothing — is never loaded in its place. Every
+caller must tolerate unavailability (no compiler, read-only fs) and fall back
+to the Python merge loop in tokenizer.py, the semantic reference.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,26 +20,25 @@ import threading
 import numpy as np
 
 _lock = threading.Lock()
-_lib = None
-_tried = False
 
-_SRC = os.path.join(
+_NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native",
-    "q40_codec.cpp",
 )
-_SO = os.path.join(os.path.dirname(_SRC), "libq40codec.so")
 
 
-def _build_and_load(src: str, so: str, extra_flags: tuple = ()):
-    """Compile `src` to `so` if stale and dlopen it; None on any failure.
-    Shared by every native library in this package — the build/caching and
-    concurrency subtleties live in exactly one place."""
+def _build_and_load(src: str, stem: str, extra_flags: tuple = ()):
+    """dlopen the library built from exactly `src` with exactly these flags,
+    compiling it first if it is not there; None on any failure."""
     if os.environ.get("DLT_NO_NATIVE"):
         return None
-    if not os.path.exists(src):
+    try:
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read() + repr(extra_flags).encode()).hexdigest()[:16]
+    except OSError:
         return None
-    if not (os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src)):
+    so = f"{stem}.{digest}.so"
+    if not os.path.exists(so):
         # pid-suffixed temp: concurrent builders (server + CLI, pytest-xdist)
         # must not interleave writes into one temp and install a corrupt .so
         tmp = f"{so}.{os.getpid()}.tmp"
@@ -50,76 +52,20 @@ def _build_and_load(src: str, so: str, extra_flags: tuple = ()):
             except OSError:
                 pass
             return None
+        for old in glob.glob(f"{stem}.*.so") + glob.glob(f"{stem}.so"):
+            if old != so:  # builds of other source: never loaded again
+                try:
+                    os.unlink(old)
+                except OSError:
+                    pass
     try:
         return ctypes.CDLL(so)
     except OSError:
         return None
 
 
-def _load():
-    global _lib, _tried
-    with _lock:
-        if _tried:
-            return _lib
-        _tried = True
-        lib = _build_and_load(_SRC, _SO, extra_flags=("-pthread",))
-        if lib is None:
-            return None
-        lib.q40_unpack_t.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int32,
-        ]
-        lib.q40_unpack_t.restype = None
-        lib.q40_dequant.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
-        lib.q40_dequant.restype = None
-        _lib = lib
-        return _lib
-
-
-def available() -> bool:
-    return _load() is not None
-
-
-def q40_unpack_t_native(
-    raw, out_f: int, in_f: int, n_threads: int = 0
-) -> tuple[np.ndarray, np.ndarray] | None:
-    """Q40 file bytes -> (qt [in_f//32, 32, out_f] int8, dt [in_f//32, out_f]
-    f16) — the device T layout, in one pass. The scale plane carries the
-    file's f16 bits verbatim (bit-exact, half the f32 plane's traffic). None
-    if the codec is missing."""
-    lib = _load()
-    if lib is None:
-        return None
-    bpr = in_f // 32
-    buf = np.frombuffer(raw, dtype=np.uint8, count=out_f * bpr * 18)
-    qt = np.empty((bpr, 32, out_f), dtype=np.int8)
-    dt = np.empty((bpr, out_f), dtype=np.float16)
-    lib.q40_unpack_t(
-        buf.ctypes.data, out_f, bpr,
-        qt.ctypes.data, dt.ctypes.data, n_threads,
-    )
-    return qt, dt
-
-
-def q40_dequant_native(raw, n_elements: int) -> np.ndarray | None:
-    lib = _load()
-    if lib is None:
-        return None
-    n_blocks = n_elements // 32
-    buf = np.frombuffer(raw, dtype=np.uint8, count=n_blocks * 18)
-    out = np.empty(n_elements, dtype=np.float32)
-    lib.q40_dequant(buf.ctypes.data, n_blocks, out.ctypes.data)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Native BPE merge engine (native/bpe_encoder.cpp) — same loader contract:
-# build-on-first-use, every caller tolerates unavailability and falls back to
-# the Python merge loop in tokenizer.py (the semantic reference).
-# ---------------------------------------------------------------------------
-
-_BPE_SRC = os.path.join(os.path.dirname(_SRC), "bpe_encoder.cpp")
-_BPE_SO = os.path.join(os.path.dirname(_SRC), "libbpeencoder.so")
+_BPE_SRC = os.path.join(_NATIVE_DIR, "bpe_encoder.cpp")
+_BPE_STEM = os.path.join(_NATIVE_DIR, "libbpeencoder")
 _bpe_lib = None
 _bpe_tried = False
 
@@ -130,7 +76,7 @@ def _load_bpe():
         if _bpe_tried:
             return _bpe_lib
         _bpe_tried = True
-        lib = _build_and_load(_BPE_SRC, _BPE_SO)
+        lib = _build_and_load(_BPE_SRC, _BPE_STEM)
         if lib is None:
             return None
         lib.bpe_create.argtypes = [
@@ -144,6 +90,11 @@ def _load_bpe():
         lib.bpe_merge.restype = ctypes.c_int64
         _bpe_lib = lib
         return _bpe_lib
+
+
+def bpe_available() -> bool:
+    """Did the native merge engine build and load in this process?"""
+    return _load_bpe() is not None
 
 
 class NativeBpe:

@@ -67,12 +67,14 @@ def tensor_bytes(float_type: int, n_elements: int) -> int:
 # Q40
 # ---------------------------------------------------------------------------
 
-def quantize_q40(x: np.ndarray) -> bytes:
+def quantize_q40(x: np.ndarray, scratch: np.ndarray | None = None) -> bytes:
     """Quantize a flat f32 array to Q40 bytes.
 
     Mirrors the converter's algorithm (reference: converter/writer.py:29-53):
     scale = extreme/-8 (the signed extreme, so the value furthest from zero maps
-    to nibble 0 or 15), q = clip(x/d + 8.5, 0, 15) truncated.
+    to nibble 0 or 15), q = clip(x/d + 8.5, 0, 15) truncated. `scratch`: an f32
+    array of at least x.size elements to compute in, for a caller that
+    quantizes piece after piece (testing.write_tiny_model's bulk writer).
     """
     x = np.ascontiguousarray(x, dtype=np.float32).reshape(-1)
     assert x.size % Q_BLOCK == 0, f"size {x.size} not a multiple of {Q_BLOCK}"
@@ -82,14 +84,18 @@ def quantize_q40(x: np.ndarray) -> bytes:
     deltas = np.where(-gmin > gmax, gmin, gmax) / -8.0
     deltas16 = deltas.astype(np.float16)
     inv = np.where(deltas != 0, np.divide(1.0, deltas, where=deltas != 0), 0.0)
-    q = np.clip(groups * inv[:, None] + 8.5, 0, 15).astype(np.int64)
-    lo = q[:, : Q_BLOCK // 2] & 0xF
-    hi = (q[:, Q_BLOCK // 2 :] & 0xF) << 4
-    packed = (lo | hi).astype(np.uint8)
+    # one f32 scratch, updated in place (fresh 4-byte-per-weight temporaries
+    # cost more than the arithmetic); values clipped to [0, 15] truncate
+    # exactly into uint8
+    y = None if scratch is None else scratch[: x.size].reshape(groups.shape)
+    y = np.multiply(groups, inv[:, None], out=y)
+    y += 8.5
+    np.clip(y, 0, 15, out=y)
+    q = y.astype(np.uint8)
 
     out = np.empty((groups.shape[0], Q40_BLOCK_BYTES), dtype=np.uint8)
     out[:, :2] = deltas16.view(np.uint8).reshape(-1, 2)
-    out[:, 2:] = packed
+    out[:, 2:] = q[:, : Q_BLOCK // 2] | (q[:, Q_BLOCK // 2 :] << 4)
     return out.tobytes()
 
 

@@ -24,7 +24,7 @@ import numpy as np
 
 from ..formats.mfile import MFileReader, TensorSpec
 from ..formats.quants import FloatType
-from ..ops.quant import QuantTensor
+from ..ops.quant import QuantTensor, q40_raw_to_t_layout
 from .config import ModelConfig
 
 # A weight is either a dense jnp array [out, in] or a QuantTensor.
@@ -153,23 +153,11 @@ def _load_one(reader: MFileReader, spec: TensorSpec, dense_dtype) -> Any:
     """Host-side load of a single tensor: QuantTensor parts (in the device T
     layout, ops/quant.py) or a dense ndarray."""
     if spec.float_type == FloatType.Q40 and len(spec.shape) == 2:
-        out_f, in_f = spec.shape
-        # fast path: the native codec unpacks + transposes in one
-        # multithreaded C++ pass (native/q40_codec.cpp)
-        from ..formats.native import q40_unpack_t_native
-
-        nat = q40_unpack_t_native(reader.raw(spec), out_f, in_f)
-        if nat is not None:
-            from ..ops.quant import pack_q
-
-            qt, dt = nat
-            return pack_q(qt), dt
-        from ..ops.quant import q40_to_t_layout
-
-        q, d = reader.tensor_q40(spec)  # [out, in//32, 32], [out, in//32]
-        return q40_to_t_layout(q, d)
+        return q40_raw_to_t_layout(reader.raw(spec), *spec.shape)
     x = reader.tensor_f32(spec)
-    return x.astype(dense_dtype) if len(spec.shape) == 2 else x
+    # copy=False: the f32 embedding (GBs at real vocabularies) is already a
+    # private f32 copy — a second one is seconds of page faults
+    return x.astype(dense_dtype, copy=False) if len(spec.shape) == 2 else x
 
 
 def _stack(parts: list) -> Any:

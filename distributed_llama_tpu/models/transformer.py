@@ -166,13 +166,22 @@ def _fused_paged_eligible(cfg, q, t: int, ps: int) -> bool:
     enabled, decode-sized q blocks (one page of queries at most — solo
     decode t=1, batch decode t=1, speculative verify t=k+1 all qualify;
     prefill chunks take the gather+dequant view, which stays
-    flash-eligible), and uniform lane-aligned head grouping."""
+    flash-eligible), uniform head grouping, and — where the kernel is
+    compiled, not interpreted — a pool whose trailing (n_kv, head_dim) axes
+    fill whole int8 (8, 128) tiles. The TPU's compiler stores only such a
+    pool in the row-major order the kernel's page blocks need; for any other
+    shape it copies the WHOLE pool at every call (seen compiling hd 64 and
+    n_kv 2/4 for v5e), which the gather arm never does."""
     n_heads, head_dim = q.shape[2], q.shape[3]
     return (
         _pallas_enabled(cfg)
         and t <= ps
         and n_heads % cfg.n_kv_heads == 0
         and head_dim % 8 == 0
+        and (
+            cfg.pallas_interpret
+            or (cfg.n_kv_heads % 8 == 0 and head_dim % 128 == 0)
+        )
     )
 
 

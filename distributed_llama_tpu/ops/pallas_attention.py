@@ -231,38 +231,45 @@ def flash_attention(
 
 # -- fused page-table-aware int8 decode attention ---------------------------
 #
-# The paged decode arm used to materialize an HLO gather of the row's
-# kv_len/ps pages into a [b, n_read*ps, h, d] bf16 view every step — the
-# single biggest HBM stream on the decode hot path. This kernel reads the
-# pool DIRECTLY: the row's int32 page table rides the scalar-prefetch
-# operand, the KV block index map resolves (row, kv-step) -> physical page
-# on the scalar core, and the int8 payload dequantizes against its f32
-# per-(token, head) scale in VMEM (the same place pallas_q40.py unpacks
-# weight nibbles) — so HBM sees int8 + scale bytes only, and the jaxpr
-# carries NO page-view gather (profiling.assert_gather_free pins this).
+# The paged decode arm's HLO formulation materializes a gather of the row's
+# kv_len/ps pages into a [b, n_read*ps, h, d] bf16 view every step. This
+# kernel reads the pool DIRECTLY: the row's int32 page table rides the
+# scalar-prefetch operand, the KV block index map resolves (row, kv-step) ->
+# physical page on the scalar core, and the int8 payload meets its f32
+# per-(token, head) scales in VMEM — so HBM sees int8 payload bytes only, and
+# the jaxpr carries NO gather of the pool (tests/test_kv_quant.py pins this).
 #
-# Hardware note: one KV block is one page — (ps, hd) int8 tiles with
-# ps=16 under-fill the int8 sublane tile (32); fine in interpret mode
-# (CI) and correct on hardware, with a packing follow-up recorded in
-# PERF.md before hardware rounds chase peak.
+# What the TPU's compiler allows shapes all of it (tests/test_tpu_compile.py
+# holds the kernel to that compiler at the real pool shape):
+# * a block's last two dims must be (8, 128)-divisible or equal the array's,
+#   so one KV block is one WHOLE page, (ps, n_kv, hd) — a block of one kv head
+#   of eight is refused — and the kernel loops the heads, reading head h's
+#   (ps, hd) slab as a strided ref load;
+# * the pool is handed over as it is stored: the compiler turns a reshape of
+#   its trailing axes into a copy of the whole pool on every call;
+# * the scale sidecars [L, P, ps, n_kv] f32 are stored by the compiler with
+#   the page axis minor-most, so a kernel operand would also be a whole-array
+#   copy per call. Their pages are gathered in HLO instead (1/32 of the
+#   payload's bytes), head-major, and multiply the score and probability
+#   COLUMNS — cheaper than scaling K and V elementwise.
+# One page per grid step under-fills the int8 (32, 128) tile at ps=16; reading
+# two pages per block is the follow-up ROADMAP S2 records.
 
 
 def _paged_kernel(
     m_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_sref, l_sref, acc_ref,
-    *, scale, g, ps, n_read, n_kv,
+    *, scale, g, t, ps, n_read, n_kv,
 ):
-    """One page's online-softmax update. m_ref (scalar prefetch) carries
-    [layer, pos_base[b], page_table[b*n_read]]; pos_base is each row's
-    FIRST query position (per-row — batch decode's unequal rows share the
-    program). Clamped-page garbage is causally masked for live rows and
-    discarded host-side for parked rows, the XLA paged arm's semantics."""
-    si = pl.program_id(2)
-    ti = pl.program_id(1)
-    bk = pl.program_id(0)
-
-    _, bt, _, hd = q_ref.shape
-    rows = bt * g
-    pos_base = m_ref[1 + bk // n_kv]
+    """One page's online-softmax update for every kv head of one batch row.
+    m_ref (scalar prefetch) carries [layer, pos_base[b], page_table[b*n_read]];
+    pos_base is each row's FIRST query position (per-row — batch decode's
+    unequal rows share the program). Clamped-page garbage is causally masked
+    for live rows and discarded host-side for parked rows, the XLA paged
+    arm's semantics."""
+    bi = pl.program_id(0)
+    si = pl.program_id(1)
+    rows = t * g
+    pos_base = m_ref[1 + bi]
 
     @pl.when(si == 0)
     def _():
@@ -272,41 +279,44 @@ def _paged_kernel(
 
     # page si holds positions [si*ps, (si+1)*ps): visible iff its first
     # position is <= the row's last query position
-    last_pos = pos_base + ti * bt + (bt - 1)
+    last_pos = pos_base + (t - 1)
 
     @pl.when(si * ps <= last_pos)
     def _():
-        q = q_ref[0].reshape(rows, hd).astype(jnp.float32)
-        # in-VMEM dequant: int8 payload x f32 per-(token, head) scale
-        k = k_ref[0, 0, :, 0, :].astype(jnp.float32) * ks_ref[0, 0, :, 0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [rows, ps]
-
-        row_pos = pos_base + ti * bt + jax.lax.broadcasted_iota(
+        # query row r is token r // g of the block (q is [n_kv, t*g, hd])
+        row_pos = pos_base + jax.lax.broadcasted_iota(
             jnp.int32, (rows, ps), 0
         ) // g
         col_pos = si * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-        s = jnp.where(col_pos <= row_pos, s, NEG_INF)
+        visible = col_pos <= row_pos
+        ks = ks_ref[0, 0]  # [n_kv, ps] f32
+        vs = vs_ref[0, 0]
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)  # [rows, hd]
+            k = k_ref[0, 0, :, h, :].astype(jnp.float32)  # [ps, hd]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * (ks[h : h + 1, :] * scale)  # [rows, ps]
+            s = jnp.where(visible, s, NEG_INF)
 
-        m_prev = m_sref[...][:, :1]
-        m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
-        m_safe = jnp.maximum(m_cur, NEG_INF / 2)
-        corr = jnp.exp(m_prev - m_safe)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(col_pos <= row_pos, p, 0.0)
-        l_sref[...] = l_sref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0, :, 0, :].astype(jnp.float32) * vs_ref[0, 0, :, 0][:, None]
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_sref[...] = jnp.broadcast_to(m_safe, m_sref.shape)
+            m_prev = m_sref[h][:, :1]
+            m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
+            m_safe = jnp.maximum(m_cur, NEG_INF / 2)
+            corr = jnp.exp(m_prev - m_safe)
+            p = jnp.where(visible, jnp.exp(s - m_safe), 0.0)
+            l_sref[h] = l_sref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, 0, :, h, :].astype(jnp.float32)
+            pv = jax.lax.dot_general(
+                p * vs[h : h + 1, :], v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [rows, hd]
+            acc_ref[h] = acc_ref[h] * corr + pv
+            m_sref[h] = jnp.broadcast_to(m_safe, m_sref.shape[1:])
 
     @pl.when(si == n_read - 1)
     def _():
-        l = jnp.maximum(l_sref[...][:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).reshape(bt, g, hd).astype(o_ref.dtype)
+        l = jnp.maximum(l_sref[...][:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @partial(jax.jit, static_argnames=("n_read", "page_size", "scale", "interpret"))
@@ -335,58 +345,60 @@ def paged_flash_attention(
     n_kv = k_pool.shape[3]
     ps = page_size
     g = n_heads // n_kv
+    rows = t * g  # decode-sized q: the whole block is one grid row's queries
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
-    bt = t  # decode-sized q blocks: one t block per grid row
 
+    # [b, t, kv, g, hd] -> [b, kv, t*g, hd]
     q4 = (
         q.reshape(b, t, n_kv, g, hd)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(b * n_kv, t, g, hd)
+        .reshape(b, n_kv, rows, hd)
     )
+    li = jnp.asarray(layer_idx, jnp.int32)
+    pages = jnp.maximum(
+        jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0
+    ).astype(jnp.int32)  # [b, n_read]
+    ks = jnp.swapaxes(k_scale[li, pages], 2, 3)  # [b, n_read, n_kv, ps]
+    vs = jnp.swapaxes(v_scale[li, pages], 2, 3)
     meta = jnp.concatenate(
         [
-            jnp.asarray(layer_idx, jnp.int32).reshape(1),
+            li.reshape(1),
             jnp.asarray(pos_base, jnp.int32).reshape(b),
-            jnp.maximum(
-                jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0
-            ).astype(jnp.int32).reshape(b * n_read),
+            pages.reshape(b * n_read),
         ]
     )
 
-    def kv_map(bk, ti, si, m):
-        return (m[0], m[1 + b + (bk // n_kv) * n_read + si], 0, bk % n_kv, 0)
+    def page_map(bi, si, m):
+        return (m[0], m[1 + b + bi * n_read + si], 0, 0, 0)
 
-    def scale_map(bk, ti, si, m):
-        return (m[0], m[1 + b + (bk // n_kv) * n_read + si], 0, bk % n_kv)
-
+    q_spec = pl.BlockSpec((1, n_kv, rows, hd), lambda bi, si, m: (bi, 0, 0, 0))
+    scale_spec = pl.BlockSpec((1, 1, n_kv, ps), lambda bi, si, m: (bi, si, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b * n_kv, t // bt, n_read),
+        grid=(b, n_read),
         in_specs=[
-            pl.BlockSpec((1, bt, g, hd), lambda bk, ti, si, m: (bk, ti, 0, 0)),
-            pl.BlockSpec((1, 1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, 1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, 1, ps, 1), scale_map),
-            pl.BlockSpec((1, 1, ps, 1), scale_map),
+            q_spec,
+            pl.BlockSpec((1, 1, ps, n_kv, hd), page_map),
+            pl.BlockSpec((1, 1, ps, n_kv, hd), page_map),
+            scale_spec,
+            scale_spec,
         ],
-        out_specs=pl.BlockSpec(
-            (1, bt, g, hd), lambda bk, ti, si, m: (bk, ti, 0, 0)
-        ),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((bt * g, 128), jnp.float32),  # running row max
-            pltpu.VMEM((bt * g, 128), jnp.float32),  # running exp-sum
-            pltpu.VMEM((bt * g, hd), jnp.float32),  # weighted-V accumulator
+            pltpu.VMEM((n_kv, rows, 128), jnp.float32),  # running row max
+            pltpu.VMEM((n_kv, rows, 128), jnp.float32),  # running exp-sum
+            pltpu.VMEM((n_kv, rows, hd), jnp.float32),  # weighted-V accumulator
         ],
     )
     out = pl.pallas_call(
         partial(
-            _paged_kernel, scale=scale, g=g, ps=ps, n_read=n_read, n_kv=n_kv
+            _paged_kernel, scale=scale, g=g, t=t, ps=ps, n_read=n_read, n_kv=n_kv
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * n_kv, t, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, hd), q.dtype),
         interpret=interpret,
-    )(meta, q4, k_pool, v_pool, k_scale, v_scale)
+    )(meta, q4, k_pool, v_pool, ks, vs)
     return (
         out.reshape(b, n_kv, t, g, hd)
         .transpose(0, 2, 1, 3, 4)

@@ -144,6 +144,23 @@ def q40_to_t_layout(q: np.ndarray, d: np.ndarray) -> tuple[np.ndarray, np.ndarra
     return pack_q(qt), dt
 
 
+def q40_raw_to_t_layout(raw, out_f: int, in_f: int) -> tuple[np.ndarray, np.ndarray]:
+    """File bytes of one [out_f, in_f] Q40 tensor -> the packed device T
+    layout (qp [in_f//8, out_f] int32, dt [in_f//32, out_f] f16), without
+    unpacking a nibble. The file's byte j of a block already holds
+    ``(elem j + 8) | (elem j+16 + 8) << 4`` — exactly the codec's
+    ``byte[b, s, o]`` — so ``word[b, g, o]`` is the little-endian u32 at
+    bytes 4g..4g+3 of block (o, b) and the whole repack is one transpose of
+    4-byte words. Equal to ``q40_to_t_layout(*unpack_q40(raw))`` (tested);
+    this is the weight loader's path."""
+    bpr = in_f // Q_BLOCK
+    buf = np.frombuffer(raw, dtype=np.uint8, count=out_f * bpr * 18).reshape(out_f, bpr, 18)
+    words = np.ascontiguousarray(buf[:, :, 2:]).view(np.uint32)  # [out, bpr, 4]
+    qp = np.ascontiguousarray(words.transpose(1, 2, 0)).reshape(bpr * 4, out_f)
+    scales = np.ascontiguousarray(buf[:, :, :2]).view(np.float16).reshape(out_f, bpr)
+    return qp.view(np.int32), np.ascontiguousarray(scales.T)
+
+
 def quant_tensor_from_q40(q: np.ndarray, d: np.ndarray) -> QuantTensor:
     """From host-side `unpack_q40` output reshaped to [out, in//32, 32] /
     [out, in//32] (the file layout): transpose into the device T layout."""

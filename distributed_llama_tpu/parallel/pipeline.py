@@ -30,26 +30,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map as _shard_map
-    if not callable(_shard_map):  # some versions expose a module by that name
-        raise ImportError
-except ImportError:  # older jax keeps it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-if "check_vma" in _inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    def shard_map(f=None, **kw):
-        """Older-jax adapter: the replication-check kwarg was renamed
-        check_rep -> check_vma when shard_map left experimental."""
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map(f, **kw) if f is not None else _shard_map(**kw)
 
 from ..models.config import ModelConfig
 from ..models.params import KVCache, ModelParams

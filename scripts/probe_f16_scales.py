@@ -1,8 +1,8 @@
 """Probe: how to get a 2-byte EXACT f16 scale plane through Mosaic.
 
-Result of probe A (kept for the record): jnp.float16 arrays fail to compile
-in Pallas on this platform (remote_compile HTTP 500) at every tile shape;
-bfloat16 compiles everywhere -- but bf16 cannot represent the .m file's f16
+Result of probe A (kept for the record): jnp.float16 arrays failed to compile
+in Pallas at every tile shape on the compiler of that time (not tried again
+on the installed one); bfloat16 compiles everywhere -- but bf16 cannot represent the .m file's f16
 scales exactly, which would break the reference parity gate.
 
 Probe B (this file's main act): store the scale plane as the raw f16 BITS in

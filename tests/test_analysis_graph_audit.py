@@ -122,7 +122,7 @@ def test_float64_program_is_flagged(model_path):
     eng = _engine(model_path)
     try:
         entry = ga.warm_key_ladder(eng)[0]
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(
                 lambda x: jnp.asarray(x, jnp.float64) * 2.0
             )(jax.ShapeDtypeStruct((4,), jnp.float32))

@@ -1,0 +1,146 @@
+"""`chip_smoke.py --rehearse`: the chip check's control flow, run on the CPU
+at tiny widths with interpret-mode kernels. Every phase must work and the run
+must still end in `"ok": false` — for the device check alone: the script's
+refusal to pass without the chip is itself under test. The two rehearsals
+(one chip, `--chips 4` on four virtual devices) run side by side, once."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+SMOKE = ROOT / "chip_smoke.py"
+
+
+def _env(**extra):
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS")}
+    env.update(JAX_PLATFORMS="cpu", **extra)
+    return env
+
+
+def _run(argv, cwd=ROOT, script=SMOKE, **env):
+    return subprocess.Popen(
+        [sys.executable, str(script), *argv], cwd=cwd, env=_env(**env),
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+    )
+
+
+def _lines(stdout):
+    out = []
+    for line in stdout.splitlines():
+        if line.startswith("{"):
+            out.append(json.loads(line))
+    return out
+
+
+@pytest.fixture(scope="module")
+def rehearsals(tmp_path_factory):
+    cache = tmp_path_factory.mktemp("placed_cache")
+    procs = {
+        # the cache directory placed from outside / left to the program
+        "one": _run(["--rehearse"], JAX_COMPILATION_CACHE_DIR=str(cache)),
+        "four": _run(["--rehearse", "--chips", "4"]),
+    }
+    runs = {}
+    for name, p in procs.items():
+        stdout, _ = p.communicate(timeout=600)
+        runs[name] = (p.returncode, _lines(stdout), stdout.rstrip().splitlines()[-1])
+    runs["cache"] = cache
+    return runs
+
+
+def _by_phase(lines, phase):
+    return [l for l in lines if l.get("phase") == phase]
+
+
+def test_one_chip_rehearsal_fails_for_the_device_check_alone(rehearsals):
+    code, lines, last = rehearsals["one"]
+    result = json.loads(last)
+    assert code != 0 and result["ok"] is False
+    assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert result["reasons"] == ["need 1 tpu device(s), jax found 1 x cpu"], result
+    assert not any(l.get("ok") for l in lines)  # never "ok": true off the chip
+
+
+def test_one_chip_rehearsal_runs_every_phase(rehearsals):
+    _, lines, _ = rehearsals["one"]
+    checks = [l["check"] for l in _by_phase(lines, "numbers")]
+    assert len(checks) == 4 and any("int8 page-table kernel" in c for c in checks)
+    gen = next(l for l in _by_phase(lines, "numbers") if "generation" in l["check"])
+    assert gen["int8_decode_arm"] == "fused page-table kernel"
+    assert gen["leading_tokens_equal"] >= gen["bound"]
+    kernels = _by_phase(lines, "kernels")
+    assert [k["program"].split("[")[0] for k in kernels] == [
+        "decode", "batch_decode", "prefill", "verify",
+    ]
+    assert all(k["pallas_calls_traced"] >= k["need"] for k in kernels)
+    requests = {l["request"]: l for l in _by_phase(lines, "request")}
+    assert set(requests) == {
+        "plain", "streamed", "concurrent-0", "concurrent-1", "repeat-1",
+        "repeat-2", "response_format",
+    }
+    for r in requests.values():
+        assert r["status"] == 200 and r["text_chars"] > 0
+        assert r["finish_reason"] in ("length", "stop")
+    assert requests["repeat-2"]["prefix_hit_tokens"] > 0
+    (stats,) = _by_phase(lines, "stats")
+    assert not any(stats["watched"].values())
+    assert stats["supervisor"] == {"state": "serving", "rebuilds_total": 0, "resets_total": 0}
+
+
+def test_compile_cache_is_placed_from_outside_and_shared_with_warm_up(rehearsals):
+    _, lines, _ = rehearsals["one"]
+    (placed,) = _by_phase(lines, "compile_cache")
+    assert placed["dir"] == str(rehearsals["cache"])
+    assert any(rehearsals["cache"].iterdir())  # entries appear under it
+    serve = _by_phase(lines, "serve")[-1]
+    # warm-up loads what the cost table compiled: one compile per program
+    # and a few eager ops, not the ladder twice
+    assert serve["sanitizer_warm_compiles"] < 1.5 * serve["warm_plan_programs"]
+
+
+def test_four_chip_rehearsal_runs_tensor_parallelism_only(rehearsals):
+    code, lines, last = rehearsals["four"]
+    result = json.loads(last)
+    assert code != 0 and result["ok"] is False and result["device"]["count"] == 4
+    assert result["reasons"] == ["need 4 tpu device(s), jax found 4 x cpu"], result
+    assert not {"numbers", "serve", "request", "stats"} & {l.get("phase") for l in lines}
+    tp4 = next(l for l in _by_phase(lines, "tp4") if l.get("engine") == "tp4")
+    assert tp4["mesh"]["tp"] == 4 and tp4["execution"] == "pipeline"
+    assert tp4["kv_layout"] == "paged"
+    assert tp4["wqkv_shard_shares"] == [0.25] * 4
+    assert tp4["kv_pool_shard_shares"] == [0.25] * 4
+    assert tp4["all_reduces"] >= 2
+    check = next(l for l in _by_phase(lines, "tp4") if "check" in l)
+    assert min(check["leading_tokens_equal"]) >= min(check["bounds"][1], check["new_tokens"])
+
+
+def test_compile_cache_defaults_to_the_checkout(rehearsals):
+    _, lines, _ = rehearsals["four"]
+    (placed,) = _by_phase(lines, "compile_cache")
+    assert placed["dir"] == str(ROOT / ".jax_cache")
+    assert any((ROOT / ".jax_cache").iterdir())
+
+
+def test_without_the_chip_the_device_check_comes_first():
+    p = _run([])
+    stdout, _ = p.communicate(timeout=120)
+    lines = _lines(stdout)
+    assert p.returncode != 0 and lines[-1]["ok"] is False
+    assert [l["phase"] for l in lines[:-1]] == ["device", "FAIL"]  # nothing built
+    assert "tpu" in lines[-1]["reasons"][0]
+
+
+def test_alone_in_a_directory_it_fails(tmp_path):
+    shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    p = _run(["--rehearse"], cwd=tmp_path, script=tmp_path / "chip_smoke.py")
+    stdout, _ = p.communicate(timeout=120)
+    result = _lines(stdout)[-1]
+    assert p.returncode != 0 and result["ok"] is False
+    assert any("the program is not here" in r for r in result["reasons"])
+    assert os.listdir(tmp_path) == ["chip_smoke.py"]

@@ -168,3 +168,33 @@ def test_tfile_round_trip(tmp_path):
     assert t2.eos_token_ids == t.eos_token_ids
     assert t2.add_bos == t.add_bos
     assert t2.chat_template == "{{bos}}{% x %}"
+
+
+def test_bulk_writer_is_deterministic_and_loads(tmp_path, monkeypatch):
+    """`write_tiny_model(bulk=True)`: the bytes depend on (header, seed,
+    scale) alone — pieces carry their own child seeds, so neither the thread
+    count nor the order threads finish in shows — and the file reads back
+    like any other: norms near 1, weights at the asked scale."""
+    from distributed_llama_tpu import testing
+
+    monkeypatch.setattr(testing, "_BULK_PIECE", 1 << 12)  # several pieces per tensor
+    h = lambda: tiny_header(dim=64, hidden_dim=128, n_layers=2, vocab_size=288)
+    a, b, c = (str(tmp_path / n) for n in ("a.m", "b.m", "c.m"))
+    write_tiny_model(a, h(), seed=3, scale=0.02, bulk=True)
+    monkeypatch.setattr("os.cpu_count", lambda: 1)
+    write_tiny_model(b, h(), seed=3, scale=0.02, bulk=True)
+    write_tiny_model(c, h(), seed=4, scale=0.02, bulk=True)
+    assert open(a, "rb").read() == open(b, "rb").read()
+    assert open(a, "rb").read() != open(c, "rb").read()
+    with MFileReader(a) as r:
+        norm = r.tensor_f32(r.by_name["norm0.l1"])
+        w = r.tensor_f32(r.by_name["w1.l0"])
+    assert abs(float(norm.mean()) - 1.0) < 0.01 and float(norm.std()) < 0.03
+    assert 0.015 < float(w.std()) < 0.025
+
+
+def test_quantize_q40_scratch_is_bit_identical():
+    rng = np.random.default_rng(9)
+    x = (rng.standard_normal(32 * 50) * 0.1).astype(np.float32)
+    scratch = np.empty(32 * 64, np.float32)
+    assert quantize_q40(x, scratch) == quantize_q40(x)

@@ -438,8 +438,9 @@ def test_int8_decode_is_gather_free_and_census_prices_it(model_path,
 @pytest.mark.analysis
 def test_dot_census_sees_inside_fused_kernel():
     """graph_audit's dot census descends into pallas_call: the fused kernel
-    contributes exactly its qk^T and pV dots, and a planted extra dot next
-    to it is visible (the f32_dot_budget regression class)."""
+    contributes exactly its qk^T and pV dots — one pair per kv head, which
+    the kernel loops — and a planted extra dot next to it is visible (the
+    f32_dot_budget regression class)."""
     from distributed_llama_tpu.analysis import graph_audit as ga
 
     rng = np.random.default_rng(4)
@@ -455,7 +456,7 @@ def test_dot_census_sees_inside_fused_kernel():
             tab, n_read=n_read, page_size=ps, interpret=True)
 
     dots = ga.dot_input_census(jax.make_jaxpr(run)(q))
-    assert sum(dots.values()) == 2, dots
+    assert sum(dots.values()) == 2 * n_kv, dots
 
     def planted(q):
         o = run(q)
@@ -463,7 +464,7 @@ def test_dot_census_sees_inside_fused_kernel():
         return o + jnp.sum(extra) * 0
 
     dots = ga.dot_input_census(jax.make_jaxpr(planted)(q))
-    assert sum(dots.values()) == 3, dots
+    assert sum(dots.values()) == 2 * n_kv + 1, dots
 
 
 # -- analysis integration: audit, costs, sanitizer ----------------------------

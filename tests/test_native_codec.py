@@ -1,65 +1,50 @@
-"""Native C++ Q40 codec vs the numpy codec (bit-exact)."""
+"""The weight loader's Q40 repack vs the unpack-then-pack reference (bit-exact),
+and the native BPE merge engine vs the Python merge loop."""
 
+import os
+import shutil
 import time
 
 import numpy as np
 import pytest
 
 from distributed_llama_tpu.formats import native
-from distributed_llama_tpu.formats.quants import dequantize_q40, quantize_q40, unpack_q40
-from distributed_llama_tpu.ops.quant import q40_to_t_layout
+from distributed_llama_tpu.formats.quants import quantize_q40, unpack_q40
+from distributed_llama_tpu.ops.quant import q40_raw_to_t_layout, q40_to_t_layout
 
 
-@pytest.fixture(scope="module")
-def codec_available():
-    if not native.available():
-        pytest.skip("native codec unavailable (no g++?)")
+def _reference_layout(raw, out_f, in_f):
+    q, d = unpack_q40(raw, out_f * in_f)
+    return q40_to_t_layout(q.reshape(out_f, in_f // 32, 32), d.reshape(out_f, in_f // 32))
 
 
-def test_unpack_t_matches_numpy(codec_available):
-    rng = np.random.default_rng(0)
-    out_f, in_f = 96, 128
-    w = rng.standard_normal((out_f, in_f)).astype(np.float32)
-    raw = quantize_q40(w.reshape(-1))
-
-    q, d = unpack_q40(raw, w.size)
-    want_qt, want_dt = q40_to_t_layout(q.reshape(out_f, in_f // 32, 32), d.reshape(out_f, in_f // 32))
-
-    got = native.q40_unpack_t_native(raw, out_f, in_f)
-    assert got is not None
-    qt, dt = got
-    # the codec emits the UNPACKED T layout; the loader nibble-packs it
-    # (models/params.py _load_one), so compare packed-vs-packed
-    from distributed_llama_tpu.ops.quant import pack_q
-
-    np.testing.assert_array_equal(pack_q(qt), want_qt)
-    np.testing.assert_array_equal(dt, want_dt)
+@pytest.mark.parametrize("out_f,in_f", [(96, 128), (7, 32), (256, 4096)])
+def test_raw_repack_matches_unpacked_layout(out_f, in_f):
+    rng = np.random.default_rng(out_f)
+    raw = quantize_q40(rng.standard_normal(out_f * in_f).astype(np.float32))
+    want_q, want_d = _reference_layout(raw, out_f, in_f)
+    got_q, got_d = q40_raw_to_t_layout(raw, out_f, in_f)
+    assert got_q.dtype == np.int32 and got_d.dtype == np.float16
+    assert got_q.flags.c_contiguous and got_d.flags.c_contiguous
+    np.testing.assert_array_equal(got_q, want_q)
+    np.testing.assert_array_equal(got_d.view(np.uint16), want_d.view(np.uint16))
 
 
-def test_dequant_matches_numpy(codec_available):
-    rng = np.random.default_rng(1)
-    x = rng.standard_normal(32 * 17).astype(np.float32)
-    raw = quantize_q40(x)
-    want = dequantize_q40(raw, x.size)
-    got = native.q40_dequant_native(raw, x.size)
-    np.testing.assert_array_equal(got, want)
-
-
-def test_f16_subnormal_scales(codec_available):
-    """Tiny per-block scales hit the f16 subnormal decode path."""
-    x = np.full(32, 1e-7, dtype=np.float32)
+def test_raw_repack_keeps_subnormal_scale_bits():
+    """Tiny per-block scales are f16 subnormals: the plane carries the file's
+    bits verbatim."""
+    x = np.full(64, 1e-7, dtype=np.float32)
     x[0] = -8e-7  # extreme -> scale 1e-7 (subnormal in f16)
     raw = quantize_q40(x)
-    want = dequantize_q40(raw, 32)
-    got = native.q40_dequant_native(raw, 32)
-    np.testing.assert_array_equal(got, want)
+    _, d = unpack_q40(raw, 64)
+    _, got_d = q40_raw_to_t_layout(raw, 2, 32)
+    assert 0 < abs(float(got_d[0, 0])) < 6.2e-5  # below the smallest normal
+    np.testing.assert_array_equal(got_d.view(np.uint16).reshape(-1), d.view(np.uint16))
 
 
-def test_load_path_uses_native(tmp_path, codec_available):
-    """End-to-end: params loaded through the native codec equal the numpy
-    path (guarded by env toggle)."""
-    import os
-
+def test_load_path_equals_reference_layout(tmp_path):
+    """End-to-end: a loaded weight equals the reference layout of its file
+    tensor."""
     from distributed_llama_tpu.formats.mfile import MFileReader
     from distributed_llama_tpu.models import config_from_header, load_params
     from distributed_llama_tpu.testing import tiny_header, write_tiny_model
@@ -68,38 +53,48 @@ def test_load_path_uses_native(tmp_path, codec_available):
     path = str(tmp_path / "m.m")
     write_tiny_model(path, h)
     reader = MFileReader(path)
-    cfg = config_from_header(reader.header, compute_dtype="float32")
-    a = load_params(reader, cfg)
-
-    os.environ["DLT_NO_NATIVE"] = "1"
-    # reset the loader's cache so the toggle takes effect
-    native._tried, native._lib = False, None
-    try:
-        b = load_params(MFileReader(path), cfg)
-    finally:
-        del os.environ["DLT_NO_NATIVE"]
-        native._tried, native._lib = False, None
-
-    np.testing.assert_array_equal(np.asarray(a.layers.wqkv.q), np.asarray(b.layers.wqkv.q))
-    np.testing.assert_array_equal(np.asarray(a.layers.wqkv.d), np.asarray(b.layers.wqkv.d))
+    params = load_params(reader, config_from_header(reader.header, compute_dtype="float32"))
+    want_q, want_d = q40_to_t_layout(*reader.tensor_q40(reader.by_name["wo.l0"]))
+    np.testing.assert_array_equal(np.asarray(params.layers.wo.q)[0], want_q)
+    np.testing.assert_array_equal(np.asarray(params.layers.wo.d)[0], want_d)
 
 
-def test_native_codec_speedup_large(codec_available):
-    """The point of the native codec: beat numpy on a big tensor."""
+def test_raw_repack_beats_unpack_then_pack():
+    """The reason the loader repacks words directly: the nibble round trip
+    costs an order of magnitude more on a big tensor."""
     rng = np.random.default_rng(2)
     out_f, in_f = 2048, 2048
     raw = quantize_q40(rng.standard_normal(out_f * in_f).astype(np.float32))
-
     t0 = time.perf_counter()
-    q, d = unpack_q40(raw, out_f * in_f)
-    q40_to_t_layout(q.reshape(out_f, in_f // 32, 32), d.reshape(out_f, in_f // 32))
-    t_np = time.perf_counter() - t0
-
+    _reference_layout(raw, out_f, in_f)
+    t_ref = time.perf_counter() - t0
     t0 = time.perf_counter()
-    native.q40_unpack_t_native(raw, out_f, in_f)
-    t_nat = time.perf_counter() - t0
+    q40_raw_to_t_layout(raw, out_f, in_f)
+    t_raw = time.perf_counter() - t0
     # don't flake on loaded machines; just require it's not slower
-    assert t_nat < t_np * 1.5, (t_nat, t_np)
+    assert t_raw < t_ref * 1.5, (t_raw, t_ref)
+
+
+def test_native_library_is_keyed_by_its_source(tmp_path):
+    """A library on disk is loaded only if it was built from the source that
+    is there now: a copied tree's foreign `.so` (whatever its mtime) is never
+    trusted, and an edit to the source builds anew."""
+    if shutil.which("g++") is None:
+        pytest.skip("no g++")
+    src = str(tmp_path / "bpe_encoder.cpp")
+    shutil.copy(native._BPE_SRC, src)
+    stem = str(tmp_path / "libbpeencoder")
+    with open(stem + ".so", "wb") as f:  # arrived with the copy, newer than src
+        f.write(b"not a library")
+    os.utime(src, (0, 0))
+    assert native._build_and_load(src, stem) is not None
+    first = [n for n in os.listdir(tmp_path) if n.endswith(".so")]
+    assert len(first) == 1 and first[0] != "libbpeencoder.so"
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    assert native._build_and_load(src, stem) is not None
+    second = [n for n in os.listdir(tmp_path) if n.endswith(".so")]
+    assert len(second) == 1 and second != first
 
 
 # ---------------------------------------------------------------------------

@@ -49,8 +49,8 @@ def test_async_prefill_bit_identical_to_sync_path(model_path):
 
 
 def test_prefill_pipeline_env_knob(model_path, monkeypatch):
-    """DLT_PREFILL_PIPELINE=0 forces the serial path engine-wide (the
-    tunnel-triage knob); default is pipelined."""
+    """DLT_PREFILL_PIPELINE=0 forces the serial path engine-wide; default is
+    pipelined."""
     monkeypatch.setenv("DLT_PREFILL_PIPELINE", "0")
     eng = InferenceEngine(model_path, compute_dtype="float32", max_chunk=16)
     assert eng.prefill_pipelined is False

@@ -202,8 +202,7 @@ def test_roofline_mfu_units(monkeypatch):
     p50 wall against a 1 TFLOP/s / 1000 GB/s peak gives MFU 0.5 and
     bandwidth utilization 0.1; the per-program series carry GB/s and
     TFLOP/s at the same walls."""
-    monkeypatch.setenv("DLT_PEAK_TFLOPS", "1")
-    monkeypatch.setenv("DLT_PEAK_HBM_GBS", "1000")
+    monkeypatch.setattr(profiling, "device_peaks", lambda: (1e12, 1000e9))
     stats = StepStats()
     for _ in range(8):
         stats.record("decode[4]", 2000.0)  # 2 ms walls
@@ -226,6 +225,32 @@ def test_roofline_mfu_units(monkeypatch):
     assert gbs == pytest.approx(100.0, rel=0.01)  # 2e8 B / 2 ms
     (_, tflops), = series["program_tflop_s"]
     assert tflops == pytest.approx(0.5, rel=0.01)
+
+
+def test_device_peaks_by_kind():
+    """One table keyed by device_kind: the v5e's published peaks, nothing
+    on a CPU (a host run has no device utilization — the gauges are absent,
+    not filled with a chip's numbers), and an error for an accelerator the
+    table does not know."""
+    dev = lambda platform, kind: SimpleNamespace(platform=platform, device_kind=kind)
+    assert profiling.device_peaks(dev("tpu", "TPU v5 lite")) == (197.0e12, 819.0e9)
+    assert profiling.device_peaks(dev("cpu", "cpu")) is None
+    assert profiling.device_peaks() is None  # the test backend is the CPU
+    with pytest.raises(LookupError, match="TPU v9"):
+        profiling.device_peaks(dev("tpu", "TPU v9"))
+    stats = StepStats()
+    stats.record("decode[4]", 2000.0)
+    eng = SimpleNamespace(stats=stats, _t_start=time.perf_counter() - 1.0)
+    entry = CostEntry(
+        "decode", 4, 64, flops=1e9, bytes_accessed=2e8, xla_body_flops=0,
+        xla_body_bytes=0, arg_bytes=0, out_bytes=0, temp_bytes=0,
+        alias_bytes=0, tokens=4,
+    )
+    table = CostTable({("decode", 4, 64): entry}, {})
+    gauges, series = profiling.roofline_view(eng, table)
+    assert "mfu" not in gauges and "bw_utilization" not in gauges
+    assert series["program_gb_s"]  # achieved rates need no peak
+    assert "peak_tflops" not in table.snapshot()
 
 
 def test_roofline_skips_unjoinable_series(monkeypatch):

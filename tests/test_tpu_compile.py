@@ -1,0 +1,130 @@
+"""The main path's kernels, compiled by the TPU's own compiler for a v5e that
+is described, not attached (the on-chip-measurement guide, section 2): what
+interpret mode on a CPU cannot refuse — a block the lowering rejects, more
+VMEM than a kernel may use — fails here, at Qwen3-8B widths, without a chip.
+
+A compile that passes is a compile, never a run. Each case asserts a Mosaic
+kernel (`tpu_custom_call`) in the compiled HLO; skipped where the topology
+cannot be described (no libtpu)."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_llama_tpu.ops import pallas_attention as pa
+from distributed_llama_tpu.ops import pallas_q40 as pq
+from distributed_llama_tpu.runtime.profiling import count_tpu_kernels
+
+# Qwen3-8B (launch.py qwen3_8b_q40): in -> out of each kernelled matmul
+DIM, FFN, VOCAB, LAYERS = 4096, 12288, 151936, 36
+MATMULS = {
+    "wqkv": (DIM, 6144), "wo": (DIM, DIM), "w13": (DIM, 2 * FFN),
+    "w2": (FFN, DIM), "wcls": (DIM, VOCAB),
+}
+HEADS, KV_HEADS, HEAD_DIM, PAGE = 32, 8, 128, 16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device; the persistent cache is off around these
+    compiles (an entry written for a described chip cannot be read back
+    without one — the next compile would warn and compile again)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _matmul(kernel, rows, name, stacked=False, **kw):
+    in_f, out_f = MATMULS[name]
+    lead = (LAYERS,) if stacked else ()
+
+    def build(S):
+        args = [
+            S((rows, in_f), jnp.bfloat16),
+            S((*lead, in_f // 8, out_f), jnp.int32),
+            S((*lead, in_f // 32, out_f), jnp.float16),
+        ]
+        if stacked:
+            args.append(S((), jnp.int32))
+        return (lambda *a: kernel(*a, **kw)), args
+
+    return build
+
+
+def _flash(t, cache_len):
+    def build(S):
+        kv = S((1, cache_len, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+        return pa.flash_attention, [
+            S((1, t, HEADS, HEAD_DIM), jnp.bfloat16), kv, kv, S((), jnp.int32),
+        ]
+
+    return build
+
+
+def _paged(b, t, n_read):
+    def build(S):
+        pool = S((LAYERS, 2048, PAGE, KV_HEADS, HEAD_DIM), jnp.int8)
+        scale = S((LAYERS, 2048, PAGE, KV_HEADS), jnp.float32)
+        fn = lambda *a: pa.paged_flash_attention(*a, n_read=n_read, page_size=PAGE)
+        return fn, [
+            S((b, t, HEADS, HEAD_DIM), jnp.bfloat16), pool, pool, scale, scale,
+            S((), jnp.int32), S((b,), jnp.int32), S((b, 256), jnp.int32),
+        ]
+
+    return build
+
+
+CASES = {
+    # decode rows (<= 8): the int8-MXU kernel, every weight of the step
+    **{f"i8-1row-{n}": _matmul(pq.q40_matmul_pallas_i8, 1, n) for n in MATMULS},
+    **{f"i8-8rows-{n}": _matmul(pq.q40_matmul_pallas_i8, 8, n) for n in MATMULS},
+    # prefill rows: the bf16-dequant kernel
+    **{
+        f"bf16-64rows-{n}": _matmul(pq.q40_matmul_pallas, 64, n, dtype=jnp.bfloat16)
+        for n in MATMULS
+    },
+    # the layer-stacked variants the scan dispatches (layer by scalar prefetch)
+    "stacked-i8-1row-w13": _matmul(pq.q40_matmul_pallas_stacked_i8, 1, "w13", stacked=True),
+    "stacked-i8-8rows-w2": _matmul(pq.q40_matmul_pallas_stacked_i8, 8, "w2", stacked=True),
+    "stacked-bf16-64rows-w13": _matmul(
+        pq.q40_matmul_pallas_stacked, 64, "w13", stacked=True, dtype=jnp.bfloat16
+    ),
+    "stacked-bf16-64rows-w2": _matmul(
+        pq.q40_matmul_pallas_stacked, 64, "w2", stacked=True, dtype=jnp.bfloat16
+    ),
+    "flash-t512-S4096": _flash(512, 4096),
+    "flash-t64-S4096": _flash(64, 4096),
+    # the int8 page-table kernel: solo / batch decode and verify blocks
+    **{
+        f"paged-b{b}-t{t}-read{n}": _paged(b, t, n)
+        for b, t, n in ((1, 1, 8), (4, 1, 64), (8, 1, 256), (1, 5, 64), (8, 9, 64))
+    },
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    fn, args = CASES[case](S)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert count_tpu_kernels(compiled) >= 1
+    if case.startswith("paged"):
+        # the pool is read where it lies: a reshaped or re-laid-out operand
+        # shows up as a copy of the whole pool (GBs) in the program's temps
+        pool_bytes = LAYERS * 2048 * PAGE * KV_HEADS * HEAD_DIM
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 64
