@@ -1006,7 +1006,7 @@ def leg_fleet_overhead():
     + profiling gauges + goodput) AND parsing it back through the
     federation parser, i.e. both halves of the scrape — and (b) a
     pre-bound batch_step timeline event lands per chunk
-    (the DLT_BATCH_TIMELINE=1 serving configuration); vs both off. Every
+    (what every serving Batcher does); vs both off. Every
     emission/scrape is host-side, so the acceptance bar is the same <=2%
     decode-throughput delta the tracing/profiling legs hold."""
     import threading

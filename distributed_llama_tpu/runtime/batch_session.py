@@ -183,6 +183,14 @@ class BatchSession:
         # rows mid-admission: prompt + prefill progress, armed on completion
         # (begin_admit / prefill_pending — the Batcher's interleaved path)
         self._pending: dict[int, dict] = {}
+        # prompt tokens whose prefill this session has dispatched (spliced
+        # prefix-cache tokens are not among them): the Batcher reads the
+        # difference around a prefill_pending call into its prefill span
+        self.prefilled_tokens = 0
+        # the owning thread's phase clock (runtime/phases.py PhaseClock; the
+        # Batcher sets it) — step()/spec_step() enter step.dispatch and
+        # step.fetch on it; None = nobody partitions this thread's time
+        self.phases = None
         engine.reset()
 
     def free_rows(self) -> list[int]:
@@ -399,6 +407,7 @@ class BatchSession:
                         int((time.perf_counter() - t_chunk) * 1e6), n_real, row,
                     )
                 st["done"] = done + n_real
+                self.prefilled_tokens += n_real
                 budget -= n_real
 
         remaining = len(pre) - st["done"]
@@ -499,7 +508,7 @@ class BatchSession:
             )
         out = verify_row_round(
             eng, drafts, self.token, self.pos, self.seq_len,
-            grammars=self.grammars,
+            grammars=self.grammars, phases=self.phases,
         )
         for r, emitted in out.items():
             self.pos[r] += len(emitted)
@@ -522,6 +531,9 @@ class BatchSession:
             )
         kv_len = eng._kv_bucket(min(max(ends, default=1), self.seq_len))
         t_chunk = time.perf_counter()
+        phases = self.phases
+        if phases is not None:
+            phases.enter("step.dispatch", n_steps, kv_len)
         if eng.paged:
             # paged layout: every live row needs private pages over its
             # chunk span BEFORE the dispatch (PagePoolExhausted surfaces
@@ -581,6 +593,8 @@ class BatchSession:
             # watchdog it like the solo decode path, so a wedged device
             # raises StallError into the Batcher loop (reset + bounded
             # client retry) instead of hanging every co-batched request
+            if phases is not None:
+                phases.enter("step.fetch", n_steps)
             with eng._guard(
                 f"batch_decode[{n_steps}]", ("batch_decode", n_steps, kv_len)
             ):

@@ -195,7 +195,7 @@ def choose_bucket(buckets, dmax: int) -> int:
 
 
 def verify_row_round(
-    engine, drafts: dict, token, pos, seq_len: int, grammars=None
+    engine, drafts: dict, token, pos, seq_len: int, grammars=None, phases=None
 ) -> dict:
     """ONE per-row verify round — the shared core of
     `BatchSession.spec_step` and `InferenceEngine._decode_batch_speculative`
@@ -217,7 +217,13 @@ def verify_row_round(
     greedy ids, and returns {row: emitted tokens} after per-row
     longest-prefix acceptance (telemetry recorded here: note_round +
     the spec_verify[K] latency series). Callers advance their own
-    position/token state from the returned rows."""
+    position/token state from the returned rows.
+
+    `phases` is the calling thread's phase clock (runtime/phases.py; the
+    Batcher's, through `BatchSession.spec_step`): the round enters the same
+    `step.dispatch` / `step.fetch` phases as a plain decode chunk."""
+    if phases is not None:
+        phases.enter("step.dispatch", 0, 0)
     rows = sorted(drafts)
 
     def _sess(r):
@@ -251,9 +257,13 @@ def verify_row_round(
                 gr_states[r, : len(vs)] = vs
     kvb = engine._kv_bucket(min(int(max(pv[r] for r in rows)) + size, seq_len))
     t0 = time.perf_counter()
+    if phases is not None:
+        phases.set(size, kvb)
     with engine._sanitizer_scope():
         with engine._guard(f"verify_row[{K}]", ("verify_row", size, kvb)):
             ids_dev, _ = engine._dispatch_verify(toks, pv, kvb, gr_states=gr_states)
+            if phases is not None:
+                phases.enter("step.fetch", size)
             ids = engine._host_fetch(ids_dev)
     engine.stats.record(f"spec_verify[{K}]", (time.perf_counter() - t0) * 1e6)
     # one engine-level event per verify round (per-row acceptance spans are

@@ -302,12 +302,44 @@ def trace_payload(trace_id: str, events: list) -> dict:
 
 # -- batch-composition timeline ----------------------------------------------
 
+#: the phases that partition one turn of the Batcher's loop (server/api.py
+#: `Batcher._loop`, runtime/batch_session.py `BatchSession.step`), in the
+#: order a turn passes through them, with each span's argument keys. They
+#: do not overlap and leave nothing out: a phase ends where the next one
+#: starts (runtime/phases.py `PhaseClock`), so over any window the spans
+#: add up to the Batcher thread's wall. `turn` is the loop iteration's
+#: ordinal: the phases of one turn join on it without interval arithmetic.
+#: `batch_step` (the decode chunk's wall) contains `batcher.draft`,
+#: `step.dispatch` and `step.fetch` of its turn.
+BATCHER_PHASES = {
+    # blocked on the request queue with every row free and nothing waiting
+    "batcher.idle": ("turn",),
+    # queue drain, admission sweep, deadline sweep, preemption check
+    "batcher.admit": ("turn", "admitted", "queue_depth"),
+    # session.prefill_pending: the dispatch of one staged prompt's chunks
+    "batcher.prefill": ("turn", "row", "tokens", "remaining"),
+    # the speculative drafting loop (absent with --speculative off)
+    "batcher.draft": ("turn", "drafted"),
+    # page allocation, operand uploads, the program call
+    "step.dispatch": ("turn", "n_steps", "kv_len"),
+    # the blocking fetches: the thread waits for the device
+    "step.fetch": ("turn", "n_steps"),
+    # the per-row loop after the fetch: puts to writers, retirements
+    "batcher.deliver": ("turn", "tokens", "overrun", "finished"),
+}
+
 #: the event families the Batcher's timeline emits (server/api.py): one
-#: sampled ``batch_step`` snapshot per step (slot composition + pool
-#: occupancy) plus always-landed ``batch_park``/``batch_shed`` marks at the
+#: ``batch_step`` snapshot per step (slot composition + pool occupancy),
+#: the phase spans above, one ``req_first_tokens`` per request at its first
+#: delivery (the server's share of its time to first token, in three
+#: parts), plus always-landed ``batch_park``/``batch_shed`` marks at the
 #: pool-pressure decisions — the post-hoc view of batching pathologies
-#: (admission stalls, park livelocks, pool thrash).
-BATCH_TIMELINE_NAMES = ("batch_step", "batch_park", "batch_shed")
+#: (admission stalls, park livelocks, pool thrash) and what the benchmark's
+#: per-layer readers read (perfbench/phases.py).
+BATCH_TIMELINE_NAMES = (
+    "batch_step", "batch_park", "batch_shed", "req_first_tokens",
+    *BATCHER_PHASES,
+)
 
 
 def batch_timeline_chrome(events: list) -> list:
@@ -315,13 +347,23 @@ def batch_timeline_chrome(events: list) -> list:
     becomes an ``X`` slice (the chunk wall) PLUS counter (``C``) samples —
     ``batch_slots`` stacks decoding/prefilling/free rows, ``kv_pool`` plots
     pages used — so chrome://tracing / Perfetto render slot composition and
-    pool pressure as stacked area charts over time; park/shed marks land as
-    global instant events."""
+    pool pressure as stacked area charts over time; the turn's phases are
+    ``X`` slices on the same track (the chunk holds its draft, dispatch and
+    fetch); park/shed/first-token marks land as global instant events."""
     out: list = []
     pid = os.getpid()
     for ev in events:
         _, name, t_us, dur_us, keys, vals = ev
         args = dict(zip(keys, vals))
+        if name in BATCHER_PHASES:
+            out.append(
+                {
+                    "name": name, "cat": "dlt_batch", "ph": "X",
+                    "ts": int(t_us), "dur": max(int(dur_us), 1),
+                    "pid": pid, "tid": 0, "args": args,
+                }
+            )
+            continue
         if name == "batch_step":
             out.append(
                 {
@@ -357,7 +399,7 @@ def batch_timeline_chrome(events: list) -> list:
                         "args": {"queue_depth": args["queue_depth"]},
                     }
                 )
-        else:  # batch_park / batch_shed: instant marks, global scope
+        else:  # batch_park / batch_shed / req_first_tokens: instant marks
             out.append(
                 {
                     "name": name, "cat": "dlt_batch", "ph": "i", "s": "g",

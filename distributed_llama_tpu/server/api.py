@@ -41,6 +41,7 @@ from ..runtime.telemetry import (
     GoodputLedger,
     LEDGER_TRACE_KEYS,
 )
+from ..runtime.phases import PhaseClock
 from ..runtime.tracing import (
     BATCH_TIMELINE_NAMES,
     PROM_CONTENT_TYPE,
@@ -236,6 +237,8 @@ class _BatchReq:
         # or unsampled, and every emission site guards on it)
         self.trace = trace
         self.t_enqueue_us = 0  # set by submit(); queue_wait span base
+        self.t_slot_us = 0  # slot taken (admission); staged_wait span base
+        self.t_armed_us = 0  # prompt prefilled, row decoding; first_chunk base
         self._em_decode = trace.bind("decode_chunk", ("n",)) if trace else None
         self._em_spec = (
             trace.bind("spec_round", ("drafted", "accepted")) if trace else None
@@ -341,32 +344,21 @@ class Batcher:
         self._pending_by_class = {c: 0 for c in _classes}
         self._pending_lock = threading.Lock()
         self.q: "queue.Queue[_BatchReq]" = queue.Queue()
-        # batch-composition timeline (runtime/tracing.py): one sampled
-        # snapshot of slot state per step into the bounded TraceRing —
+        # batch-composition timeline (runtime/tracing.py): one snapshot of
+        # slot state per step into the bounded TraceRing —
         # decoding/prefilling/free rows, spec round flag, KV-pool pages,
-        # backlog depth — served post-hoc at /debug/batch_timeline.
-        # DLT_BATCH_TIMELINE=0 disables; DLT_BATCH_TIMELINE_SAMPLE=N keeps
-        # one step in N (default 1 = all; the ring bounds memory either
-        # way). Emission is a pre-bound tuple append: zero device work.
-        import os
-
-        try:
-            sample = int(os.environ.get("DLT_BATCH_TIMELINE_SAMPLE", "1"))
-        except ValueError:
-            sample = 1
-        if os.environ.get("DLT_BATCH_TIMELINE", "1") in ("0", ""):
-            sample = 0
-        self.timeline_sample = max(sample, 0)
-        self._em_timeline = (
-            TRACER.bind_global(
-                "batch_step",
-                ("decoding", "prefilling", "free", "spec",
-                 "pool_pages_used", "queue_depth"),
-            )
-            if self.timeline_sample > 0
-            else None
+        # backlog depth, the turn's ordinal — served post-hoc at
+        # /debug/batch_timeline. Emission is a pre-bound tuple append: zero
+        # device work, and no switch: the benchmark's per-layer readers
+        # read every step.
+        self._em_timeline = TRACER.bind_global(
+            "batch_step",
+            ("decoding", "prefilling", "free", "spec",
+             "pool_pages_used", "queue_depth", "turn"),
         )
-        self._timeline_n = 0
+        # the phases that partition a turn of the loop, in the trace ring
+        # and on the profiler's host plane (runtime/phases.py)
+        self.phases = PhaseClock()
         # observable serving state (/stats): the loop owns the mutations,
         # readers take racy-but-consistent-enough snapshots
         self.slots: list[_BatchReq | None] = [None] * engine.batch
@@ -563,25 +555,47 @@ class Batcher:
         self, engine, slots, n_decoding: int, t_us: int, dur_us: int,
         spec: bool,
     ):
-        """One sampled batch-composition snapshot: slot roles + pool/backlog
-        occupancy at this step boundary. A pre-bound tuple append when it
-        fires; a counter bump and a modulo when sampled out."""
-        em = self._em_timeline
-        if em is None:
-            return
-        self._timeline_n += 1
-        if self._timeline_n % self.timeline_sample != 0:
-            return
+        """One batch-composition snapshot: slot roles + pool/backlog
+        occupancy at this step boundary. A pre-bound tuple append."""
         n_prefilling = sum(
             1 for s in slots if s is not None and s.prefilling
         )
         n_free = sum(1 for s in slots if s is None)
-        em(
+        self._em_timeline(
             t_us, dur_us, n_decoding, n_prefilling, n_free,
             1 if spec else 0,
             engine.page_pool.used_pages if engine.paged else 0,
             self.queue_depth(),
+            self.phases.turn,
         )
+
+    def _first_tokens(self, req: _BatchReq, row: int):
+        """Once per request, where the loop hands it its first tokens: the
+        server's share of its time to first token in three parts that add
+        up — in the queue (enqueue to slot taken), staged (slot taken to
+        armed: its prompt's turns to prefill), first chunk (armed to these
+        tokens' put). One global event for the timeline's readers, and the
+        two new parts as spans on the request's own trace beside
+        `queue_wait`, so /debug/trace?id= shows why ONE first token was
+        late. What the client sees beyond their sum is HTTP, tokenizer,
+        writer thread and socket."""
+        nowu = now_us()
+        staged_us = max(req.t_armed_us - req.t_slot_us, 0)
+        first_chunk_us = max(nowu - req.t_armed_us, 0)
+        TRACER.event(
+            "req_first_tokens", nowu, 0,
+            ("row", "queue_us", "staged_us", "first_chunk_us",
+             "prompt_tokens", "prefix_hit_tokens"),
+            (row, req.ledger.queue_us, staged_us, first_chunk_us,
+             len(req.ids), req.ledger.prefix_hit_tokens),
+        )
+        if req.trace is not None:
+            req.trace.event(
+                "staged_wait", req.t_slot_us, staged_us, ("row",), (row,)
+            )
+            req.trace.event(
+                "first_chunk", req.t_armed_us, first_chunk_us, ("row",), (row,)
+            )
 
     def _shed_expired(self, session, slots):
         """Per-chunk-boundary deadline sweep: a row whose end-to-end
@@ -627,6 +641,9 @@ class Batcher:
 
         engine = self.state.engine
         session = BatchSession(engine)
+        # the session's step enters step.dispatch / step.fetch on the
+        # loop's own clock (runtime/phases.py): one partition of the thread
+        phases = session.phases = self.phases
         slots = self.slots
         # class-priority backlog (server/scheduler.py): interactive drains
         # before standard drains before batch; within a class, FIFO — the
@@ -660,16 +677,24 @@ class Batcher:
                         continue
                     req.error = Overloaded(retry_after_s=2)
                     req.done.set()
+                phases.close()
                 return
             # drain the queue into the class backlog; block only when fully
-            # idle (no active slots and nothing waiting)
+            # idle (no active slots and nothing waiting). A turn's phases
+            # start here: whatever the last turn left open (it may have
+            # left through any `continue` below) ends at this instant.
             idle = all(s is None for s in slots)
             if idle and not backlog:
+                phases.begin_turn("batcher.idle")
                 req = self.q.get()
+                phases.enter("batcher.admit", 0, 0)
                 if req is _BATCHER_STOP:
                     continue
                 self._drained(req)
                 backlog.append(req, req.slo_class)
+            else:
+                phases.begin_turn("batcher.admit", 0, 0)
+            n_admitted = 0
             while True:
                 try:
                     req = self.q.get_nowait()
@@ -709,7 +734,7 @@ class Batcher:
                     req.done.set()
                     continue
                 try:
-                    nowu = now_us()
+                    nowu = req.t_slot_us = now_us()
                     t0 = req.t_enqueue_us or nowu
                     req.ledger.queue_us = max(nowu - t0, 0)
                     if req.trace is not None:
@@ -744,6 +769,7 @@ class Batcher:
                     req.ledger.prefix_hit_tokens = session.pending_resume(row)
                     req.prefilling = True
                     slots[row] = req
+                    n_admitted += 1
                     self.scheduler.record(req.slo_class, "admit")
                 except Exception as e:
                     if req.grammar_session is not None:
@@ -751,6 +777,7 @@ class Batcher:
                         req.grammar_session = None
                     req.error = e
                     req.done.set()
+            phases.set(n_admitted, self.queue_depth())
 
             # per-boundary deadline sweep over ALL active rows —
             # PREFILLING included: a request whose deadline passed must
@@ -827,9 +854,14 @@ class Batcher:
                     continue
                 try:
                     budget = self.prefill_budget if decode_rows else None
+                    phases.enter("batcher.prefill", row, 0, -1)
+                    n_before = session.prefilled_tokens
                     t_pf = time.perf_counter()
                     remaining = session.prefill_pending(row, budget)
                     prefill_wall_us = int((time.perf_counter() - t_pf) * 1e6)
+                    phases.set(
+                        row, session.prefilled_tokens - n_before, remaining
+                    )
                     req.ledger.prefill_us += prefill_wall_us
                     if decode_rows:
                         engine.stats.incr("interleaved_prefill_chunks")
@@ -883,6 +915,7 @@ class Batcher:
                     continue
                 if remaining == 0:
                     req.prefilling = False
+                    req.t_armed_us = now_us()
                     decode_rows.append(row)
                     armed = True
             if not decode_rows:
@@ -944,6 +977,7 @@ class Batcher:
                 # as a main-engine failure — not kill the batcher thread
                 spec_drafts = None
                 if engine.spec_mode is not None and engine.device_decode:
+                    phases.enter("batcher.draft", 0)
                     K = engine.spec_buckets[-1]
                     if all(
                         slots[r].temperature == 0.0
@@ -962,6 +996,7 @@ class Batcher:
                                     if cap > 0
                                     else []
                                 )
+                            phases.set(sum(len(d) for d in drafts.values()))
                             if any(drafts.values()):
                                 spec_drafts = drafts
                         except PagePoolExhausted:
@@ -975,6 +1010,7 @@ class Batcher:
                             spec_drafts = None
                 if spec_drafts is not None:
                     per_row = session.spec_step(spec_drafts)
+                    phases.enter("batcher.deliver", 0, 0, 0)
                 else:
                     n = min(8, self.chunk) if armed and not ramped_last else self.chunk
                     ramped_last = armed and not ramped_last
@@ -982,6 +1018,7 @@ class Batcher:
                         n //= 2
                     n = max(n, 1)
                     toks = session.step(n)
+                    phases.enter("batcher.deliver", 0, 0, 0)
                     per_row = {
                         r: [int(t) for t in toks[r]]
                         for r, s in enumerate(slots)
@@ -995,6 +1032,7 @@ class Batcher:
                 # reduces to the old least-progress pick) — its pages free
                 # immediately, everyone else keeps decoding. The shed
                 # client gets the standard 503 + Retry-After.
+                phases.enter("batcher.deliver", 0, 0, 1)
                 victim = self.scheduler.shed_victim(
                     [(r, slots[r].slo_class, slots[r].n) for r in decode_rows]
                 )
@@ -1026,6 +1064,10 @@ class Batcher:
                 # say `recovering` — a client that polls (or instantly
                 # retries) after its 500 must never read a stale `serving`
                 # and then get shed by the rebuild it didn't know about
+                phases.enter(
+                    "batcher.deliver", 0, 0,
+                    sum(1 for s in slots if s is not None),
+                )
                 entered = self.state.recover_enter(e)
                 for row, req in enumerate(slots):
                     if req is not None:
@@ -1034,6 +1076,7 @@ class Batcher:
                 self.state.recover(exc=e, entered=entered)
                 engine = self.state.engine  # a rebuild swaps the object
                 session = BatchSession(engine)
+                session.phases = phases
                 continue
             chunk_dur_us = int((time.perf_counter() - t_chunk) * 1e6)
             preempted_last = False  # a decode chunk ran: the next boundary
@@ -1043,6 +1086,7 @@ class Batcher:
                 engine, slots, len(decode_rows), t_chunk_us, chunk_dur_us,
                 spec=spec_drafts is not None,
             )
+            n_put = n_over = n_finished = 0  # batcher.deliver's arguments
             for row, req in enumerate(slots):
                 if req is None or req.prefilling or row not in per_row:
                     continue
@@ -1064,6 +1108,9 @@ class Batcher:
                         req._em_decode(t_chunk_us, chunk_dur_us, len(per_row[row]))
                 row_toks = per_row[row]
                 gr = req.grammar_session
+                if req.n == 0 and row_toks:
+                    self._first_tokens(req, row)
+                n_put += len(row_toks)
                 for i, t in enumerate(row_toks):
                     req.n += 1
                     req.out_ids.append(t)
@@ -1105,9 +1152,14 @@ class Batcher:
                         # chunk tail past the stop WAS decoded by the
                         # engine — without this count it would appear in
                         # neither generated nor discarded tokens
-                        req.n_overrun += len(row_toks) - i - 1
+                        tail = len(row_toks) - i - 1
+                        req.n_overrun += tail
+                        n_put -= tail
+                        n_over += tail
+                        n_finished += 1
                         self._finish(req, session, slots, row)
                         break
+            phases.set(n_put, n_over, n_finished)
 
 
 class ApiState:
@@ -2029,8 +2081,6 @@ class ApiState:
 #: proves the list complete — an os.environ/getenv read of a DLT_* name
 #: missing here (or from the docs) fails lint. Keep alphabetized.
 DLT_ENV_SURFACE = (
-    "DLT_BATCH_TIMELINE",
-    "DLT_BATCH_TIMELINE_SAMPLE",
     "DLT_COMPILE_LOG_MS",
     "DLT_COST_TABLE",
     "DLT_DISAGG_PEER_BACKOFF_S",
@@ -2132,7 +2182,6 @@ def resolved_config(state: "ApiState") -> dict:
             "chunk_size": batcher.chunk,
             "prefill_budget": batcher.prefill_budget,
             "max_backlog": batcher.max_backlog,
-            "timeline_sample": batcher.timeline_sample,
             "scheduler": batcher.scheduler.config.snapshot(),
         },
         "role": state.role,

@@ -801,22 +801,13 @@ def test_stall_produces_flight_record_with_request_spans(
     from distributed_llama_tpu.runtime.telemetry import watchdog
 
     httpd, port = batched_server
-    # warm the server's program ladder FIRST (one untimed request): the
-    # stall envs below apply process-wide, so a cold first-shape compile
-    # on the shared server would trip the 60 ms hard timeout for real and
-    # make this test order-dependent on whoever compiled those shapes
-    warm = urllib.request.Request(
-        f"http://127.0.0.1:{port}/v1/chat/completions",
-        data=json.dumps(PAYLOAD).encode(),
-        headers={"Content-Type": "application/json"},
-    )
-    with urllib.request.urlopen(warm, timeout=120) as r:
-        r.read()
     # a real watchdog timeout: the guarded "device call" sleeps past the
     # hard deadline, so the genuine StallError path runs — the watchdog
-    # event, the flight-record snapshot, then the raise into the Batcher
-    monkeypatch.setenv("DLT_STALL_LOG_MS", "20")
-    monkeypatch.setenv("DLT_STALL_TIMEOUT_MS", "60")
+    # event, the flight-record snapshot, then the raise into the Batcher.
+    # The short deadline belongs to the planted stall ALONE: set through
+    # DLT_STALL_TIMEOUT_MS it held for every guard of the process, and with
+    # the suite's other workers busy a real prefill on the CPU overran
+    # 60 ms before the planted stall did.
     monkeypatch.setenv("DLT_FLIGHTREC_DIR", "")  # memory-only for the test
     boom = {"armed": True}
     orig_step = BatchSession.step
@@ -825,7 +816,9 @@ def test_stall_produces_flight_record_with_request_spans(
     def stalling_step(self, n):
         if boom["armed"]:
             boom["armed"] = False
-            with watchdog("decode chunk (chaos)", log_fn=logs.append):
+            planted = watchdog("decode chunk (chaos)", log_fn=logs.append)
+            planted.log_ms, planted.timeout_ms = 20.0, 60.0
+            with planted:
                 time.sleep(0.2)
         return orig_step(self, n)
 

@@ -301,7 +301,10 @@ def test_debug_config_resolved_snapshot(goodput_server):
     assert cfg["prefix_cache"]["budget_bytes"] > 0
     assert cfg["speculative"]["mode"] in (None, "ngram", "model")
     assert cfg["batcher"]["max_backlog"] == state.batcher.max_backlog
-    assert "timeline_sample" in cfg["batcher"]
+    # the batch timeline has no switch and no sampling (the benchmark's
+    # per-layer readers read every step): neither knob is on the surface
+    assert "timeline_sample" not in cfg["batcher"]
+    assert not [k for k in cfg["env_surface"] if "TIMELINE" in k]
     assert cfg["tracing"]["ring_capacity"] > 0
     assert isinstance(cfg["env"], dict)
     # the declared env-knob surface (the env-surface lint rule's registry):
@@ -426,7 +429,6 @@ def test_emission_paths_clean_under_fatal_sanitizers(tmp_path, monkeypatch):
 
     monkeypatch.setenv("DLT_SANITIZERS", "1")
     monkeypatch.setenv("DLT_SANITIZERS_FATAL", "1")
-    monkeypatch.setenv("DLT_BATCH_TIMELINE", "1")
     monkeypatch.setenv("DLT_COST_TABLE", "0")
     h = tiny_header(
         arch=ArchType.LLAMA, dim=64, hidden_dim=128, n_layers=2, seq_len=128,

@@ -502,6 +502,9 @@ class TierStack:
         for httpd in (self.a, self.b):
             httpd.shutdown()
             httpd.server_close()
+        # the switch was for these two servers alone: left set, the next
+        # file on this worker (chip_smoke's rehearsal) serves without a table
+        os.environ.pop("DLT_COST_TABLE", None)
 
 
 @pytest.fixture(scope="module")
