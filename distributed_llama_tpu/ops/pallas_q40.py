@@ -6,7 +6,7 @@ of SIMD nibble tricks over CPU cache lines, the weight streams from HBM
 NIBBLE-PACKED (0.5 bytes/weight — the packed T layout, ops/quant.py; the
 reference's own 4.5 bits/weight Q40 trait, nn-quants.hpp:64-72) and unpacks
 in VMEM with two i32 mask ops + a pltpu.bitcast to int8 (~0.4 VPU
-ops/weight). HBM traffic is half the round-4 int8 layout's and 4-8x less
+ops/weight). HBM traffic is half an unpacked int8 layout's and 4-8x less
 than the dequant-materialize XLA fallback pays.
 
 The unpack (the FEATURE-SPLIT codec, ops/quant.py docstring): a packed
@@ -14,18 +14,23 @@ block arrives as [TILE_KNB*4, TILE_N] int32; `w & 0x0F0F0F0F` yields the
 bytes of features 0..15 of each 32-block (+8, unsigned), `(w >> 4) & ...`
 features 16..31, and pltpu.bitcast reinterprets each masked word as 4 int8
 sublanes (probed natural little-endian order) — no per-element VPU work.
-  * decode (row counts <= 8): the int8 results feed the MXU directly via
-    two block-diagonal dots (one per nibble plane); the +8 offset folds
-    into a per-block correction 8*sum(x8_block) computed in the prologue.
-    Bit-exact vs the reference's Q80xQ40 integer dot.
+  * decode (row counts <= 8): the int8 results feed the MXU directly. A k
+    step's tile is walked 8 blocks at a time (`_fs_sub`): per sub-block and
+    nibble plane one dot of the activations, masked onto the block diagonal
+    [rows*8, 8*16], against that sub-block's [8*16, TILE_N] slice of the
+    plane gives every block's integer partial; the +8 offset folds into a
+    per-block correction 8*sum(x8_block) computed in the prologue. Bit-exact
+    vs the reference's Q80xQ40 integer dot. The MXU executes rows*8
+    multiply-adds a weight, the rest of each on the diagonal's zeros.
   * prefill (large row counts): the planes concat to [TILE_KNB, 32, TILE_N]
     and dequantize to bf16 ((u - 8) * scale) — the per-element convert
     amortizes over the activation rows, MXU work dominates.
-Probes and tile sweeps: scripts/probe_int4*.py (also documents the dead
-ends found then: s4 arrays as jit operands, int8 bitwise ops and
-bitwidth-changing jax.lax.bitcasts in Mosaic, and VPU-bound
-plane-extraction unpacks). None of them has been tried again on the
-installed compiler — PERF.md, "Device rules carried over".
+The kernel alone on the chip, every shape of the benchmark's two models at 1
+to 8 rows: scripts/probe_i8_sub.py (its table: PERF.md, PR 26). Dead ends of
+earlier rounds, none tried again on the installed compiler: s4 arrays as jit
+operands, int8 bitwise ops and bitwidth-changing jax.lax.bitcasts in Mosaic,
+VPU-bound plane-extraction unpacks, an unpacked int8 weight layout (twice
+the HBM bytes).
 
 Tiling:
   grid = (out/TILE_N, nb/TILE_KNB), k innermost (output tile revisited,
@@ -38,13 +43,12 @@ Tiling:
 Scale plane: the .m file's per-block scales are f16; the T layout carries
 them verbatim (2 bytes/block — half the round-2 f32 plane's HBM traffic and
 footprint, and bit-exact). The kernels do not load float16: an earlier
-compiler refused an f16 block at every tile shape
-(scripts/probe_f16_scales.py; not tried again on the installed one), so
-the wrappers bitcast the plane to int16 and the kernels convert bits -> f32
-on the VPU (`_scale_f32`): shifts + masks + one bitcast, subnormal-aware,
-measured exact. Scales are 1/32nd of the elements, so the conversion cost is
-noise next to the dequant work it replaces. f32 planes (hand-built test
-tensors) still work everywhere.
+compiler refused an f16 block at every tile shape (not tried again on the
+installed one), so the wrappers bitcast the plane to int16 and the kernels
+convert bits -> f32 on the VPU (`_scale_f32`): shifts + masks + one bitcast,
+subnormal-aware, measured exact. Scales are 1/32nd of the elements, so the
+conversion cost is noise next to the dequant work it replaces. f32 planes
+(hand-built test tensors) still work everywhere.
 """
 
 from __future__ import annotations
@@ -319,186 +323,13 @@ def q40_matmul_pallas_stacked(
     return out2.reshape(*lead, out)
 
 
-def _kernel_i8(x8_ref, xs_ref, mask_ref, qt_ref, dt_ref, out_ref):
-    """int8xint8 MXU path (decode-sized activation rows): the weight's int8
-    values hit the MXU directly — no per-element VPU dequant, the structural
-    bottleneck of the bf16 kernel at square shapes (measured 17x there).
-
-    Per-block partial dots come from ONE 2D int8 matmul: the lhs stacks, for
-    every activation row r, the block-diagonal expansion of that row (lhs
-    row r*knb + b = row r masked to block b's 32 columns), so product row
-    r*knb + b is exactly x8[r]_block_b . q_block_b. The per-block scales
-    (activation q80 scale x weight Q40 scale) then combine on the VPU at
-    O(R*knb*tn) — 1/32nd of the dequant's element count. Activation
-    numerics are the reference's default `--buffer-float-type q80`
-    (src/llm.cpp:221-255). R is small (<= 8, gated in quant_matmul) — the
-    lhs expansion is R*knb rows; larger batches amortize dequant over rows
-    and use the bf16 kernel instead.
-    """
-    k = pl.program_id(1)
-    knb, tn = dt_ref.shape
-    R = x8_ref.shape[0]
-    x8 = x8_ref[...]  # [R, knb*32] int8
-    # select, not multiply: muli on i8 vectors doesn't legalize in Mosaic.
-    # Multi-row stays strictly 2D: per-row broadcast-select then concat on
-    # the sublane axis — 3D int8 broadcasts/reshapes ([R,1,knb*32] etc.)
-    # failed Mosaic's shape-cast lowering (found by compiling for the chip;
-    # interpret mode accepted them — tests/test_tpu_compile.py is that
-    # check now).
-    mask = mask_ref[...]  # [knb, knb*32]
-    if R == 1:
-        blockdiag = jnp.where(
-            mask != 0, jnp.broadcast_to(x8, mask.shape), jnp.int8(0)
-        )  # [knb, knb*32]
-    else:
-        blockdiag = jnp.concatenate(
-            [
-                jnp.where(
-                    mask != 0,
-                    jnp.broadcast_to(x8[r : r + 1], mask.shape),
-                    jnp.int8(0),
-                )
-                for r in range(R)
-            ],
-            axis=0,
-        )  # [R*knb, knb*32]
-    qt2 = qt_ref[...].reshape(knb * Q_BLOCK, tn)
-    partials = jax.lax.dot_general(
-        blockdiag, qt2, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # [R*knb, tn]; row r*knb+b = row r's block-b integer dot
-    dtf = _scale_f32(dt_ref[...])  # [knb, tn]
-    # per-row scale combine, unrolled over the (small, static) R; row r's
-    # activation scales sit at xs column r*128 (see _quantize_rows_q80)
-    rows = []
-    for r in range(R):
-        pr = partials[r * knb : (r + 1) * knb]  # [knb, tn]
-        scale = xs_ref[...][:, r * 128 : r * 128 + 1] * dtf  # [knb, tn]
-        rows.append(jnp.sum(pr.astype(jnp.float32) * scale, axis=0)[None, :])
-    acc = rows[0] if R == 1 else jnp.concatenate(rows, axis=0)  # [R, tn]
-
-    @pl.when(k == 0)
-    def _():
-        out_ref[...] = acc
-
-    @pl.when(k != 0)
-    def _():
-        out_ref[...] += acc
-
-
-def _kernel_stacked_i8(l_ref, x8_ref, xs_ref, mask_ref, qt_ref, dt_ref, out_ref):
-    # identical math to _kernel_i8; the layer offset was folded into the
-    # weight block index by the scalar-prefetch index_map
-    _kernel_i8(x8_ref, xs_ref, mask_ref, qt_ref, dt_ref, out_ref)
-
-
-def _quantize_rows_q80(x2: jnp.ndarray, nb: int):
-    """[R, in] f32-able rows -> (x8 [R, in] int8, xs [nb, R*128] f32).
-    Per-32-block symmetric int8 with the Q80 codec's numerics (same contract
-    as ops/quant.py quantize_q80_activations and the reference's
-    quantizeF32toQ80): int8 values are computed against the unrounded f32
-    scale, dequantization uses the f16-ROUNDED scale stored in the block.
-    Row r's per-block scales live at xs columns [r*128, (r+1)*128) — a
-    lane-aligned layout the kernel slices per row."""
-    R = x2.shape[0]
-    xb = x2.reshape(R, nb, Q_BLOCK).astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-    scale = amax / 127.0
-    inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
-    x8 = jnp.clip(jnp.round(xb * inv), -127, 127).astype(jnp.int8)
-    scale16 = scale.astype(jnp.float16).astype(jnp.float32)  # [R, nb, 1]
-    if R == 1:
-        # hot decode path: a [nb, 1] -> [nb, 128] broadcast. The general
-        # formulation below goes through a 3D transpose that XLA lowers to a
-        # relayout copy costing ~16 us PER MATMUL CALL on v5e — 3x the whole
-        # kernel at the square decode shapes (caught by a 410 -> 177 tok/s
-        # regression in the round-3 bench; scripts/kernel_lab.py reproduces)
-        xs = jnp.broadcast_to(scale16.reshape(nb, 1), (nb, 128))
-    else:
-        xs = jnp.broadcast_to(
-            jnp.transpose(scale16, (1, 0, 2)), (nb, R, 128)
-        ).reshape(nb, R * 128)
-    return x8.reshape(R, nb * Q_BLOCK), xs
-
-
-# backwards-compatible single-row name (scripts/sweeps import it)
-def _quantize_row_q80(x2: jnp.ndarray, nb: int):
-    return _quantize_rows_q80(x2, nb)
-
-
-def _blockdiag_mask(tile_knb: int) -> jnp.ndarray:
-    """[tile_knb, tile_knb*32] int8: row b is 1 on block b's columns."""
+def _halfmask(sub: int) -> jnp.ndarray:
+    """[sub, sub*16] int8: row b is 1 on block b's 16 columns — the
+    block-diagonal mask of one sub-block of one nibble plane's features."""
     import numpy as np
 
-    m = np.zeros((tile_knb, tile_knb * Q_BLOCK), np.int8)
-    for b in range(tile_knb):
-        m[b, b * Q_BLOCK : (b + 1) * Q_BLOCK] = 1
-    return jnp.asarray(m)
-
-
-def _i8_tiles(nb: int, out: int, rows: int = 1) -> tuple[int, int]:
-    """Tile shapes for the int8 kernel, from the round-3 measured sweeps on
-    v5e with the f16 scale plane at both the 1B and 8B model shapes
-    (scripts/sweep_i8_tiles.py; µs per decode matmul, best of the grid):
-      qkvo-like  (out<4096, nb<256):  tn=512  knb=64  (2048->2048:  7.3 µs)
-      deep-k w2  (nb>=256, out<4096): tn=2048 knb=16  (8192->2048: 24.8 µs,
-                 719 GB/s — wide lanes beat deep k-tiles for w2 shapes)
-      ffn-wide   (4096<=out<16384):   nb>=128: tn=2048 knb=16
-                 (4096->14336: 82 µs, 14336->4096: 86 µs); smaller
-                 contractions: tn=512 knb=32 (2048->8192: 25.6 µs)
-      vocab-wide (out>=16384): nb>=128: tn=2048 knb=128 (4096->128256:
-                 799 µs, 698 GB/s); nb<128: tn=1024 knb=64 — the round-4
-                 fused-shape sweep found deeper k-tiles best for SMALL
-                 contractions at huge out (w13-fused 2048->16384:
-                 57 -> 50 µs; 1B wcls 2048->32768: 98 µs, tied-best)
-    """
-    if out >= 16384:
-        tile_n = 2048 if nb >= 128 else 1024
-        tile_knb = 128 if nb >= 128 else 64
-    elif out >= 4096:
-        tile_n = 2048 if nb >= 128 else 512
-        tile_knb = 16 if nb >= 128 else 32
-    elif nb >= 256:
-        tile_n = 2048
-        tile_knb = 16
-    else:
-        # qkvo-class small shapes: the round-3 healthy-window re-sweep found
-        # wide lanes + shallower k decisively better with the i16 scale
-        # plane (2048->3072: 10.6 -> 7.6 us; 2048->2048: 10.1 -> 5.2 us)
-        tile_n = 1024
-        tile_knb = 32
-    tile_n = min(tile_n, out)
-    while out % tile_n:
-        tile_n //= 2
-    tile_knb = min(tile_knb, nb)
-    while nb % tile_knb:
-        tile_knb //= 2
-    # VMEM cap: the int8 weight block (tile_knb*32*tile_n bytes) is
-    # double-buffered; keep it at 4 MB or under.
-    # Multi-row calls also materialize the [rows*knb, knb*32] block-diagonal
-    # lhs in VMEM — cap it too.
-    while tile_n * tile_knb * Q_BLOCK > 4 * 1024 * 1024 and tile_knb > 8:
-        tile_knb //= 2
-    while rows * tile_knb * tile_knb * Q_BLOCK > 4 * 1024 * 1024 and tile_knb > 8:
-        tile_knb //= 2
-    # Mosaic's sublane rule for the multi-k-step case: a [tile_knb, tile_n]
-    # scale block must have tile_knb % 8 == 0 UNLESS it spans the whole
-    # leading dim. The divisor chain can land below 8 for ragged nb (e.g.
-    # nb=68 -> 4); fall back to one whole-dim k step — always legal, and
-    # ragged-nb weights are small enough for a single block. Interpret mode
-    # doesn't enforce this; only this guard protects real TPUs.
-    if tile_knb != nb and tile_knb % 8:
-        tile_knb = nb
-    return tile_n, tile_knb
-
-
-def _halfmask(tile_knb: int) -> jnp.ndarray:
-    """[tile_knb, tile_knb*16] int8: row b is 1 on block b's 16 columns —
-    the blockdiag mask for one nibble plane's feature group."""
-    import numpy as np
-
-    m = np.zeros((tile_knb, tile_knb * HGRP), np.int8)
-    for b in range(tile_knb):
+    m = np.zeros((sub, sub * HGRP), np.int8)
+    for b in range(sub):
         m[b, b * HGRP : (b + 1) * HGRP] = 1
     return jnp.asarray(m)
 
@@ -506,10 +337,13 @@ def _halfmask(tile_knb: int) -> jnp.ndarray:
 def _quantize_rows_q80_split(x2: jnp.ndarray, nb: int):
     """[R, in] rows -> (x8a, x8b [R, nb*16] int8, xs, bs [nb, R*128] f32).
 
-    Same Q80 numerics as `_quantize_rows_q80`; additionally splits each
-    32-block's int8 values into the two nibble-plane feature groups the
-    packed kernels dot separately (a/b = features 0..15 / 16..31), and
-    computes the per-block sums `bs` that fold the codec's +8 offset out of
+    Per-32-block symmetric int8 with the Q80 codec's numerics (same contract
+    as ops/quant.py quantize_q80_activations and the reference's
+    quantizeF32toQ80): int8 values are computed against the unrounded f32
+    scale, dequantization uses the f16-ROUNDED scale stored in the block.
+    Each block's int8 values are split into the two nibble-plane feature
+    groups the packed kernels dot separately (a/b = features 0..15 /
+    16..31), and the per-block sums `bs` fold the codec's +8 offset out of
     the integer partials (partial - 8*bs == the exact signed dot). Layouts
     mirror xs (row r's scalars at columns [r*128, (r+1)*128))."""
     R = x2.shape[0]
@@ -551,20 +385,16 @@ def _lane_tile(out: int, target: int) -> int:
     return out
 
 
-def _fs_tiles(nb: int, out: int, rows: int = 1) -> tuple[int, int]:
-    """Tile shapes for the packed (feature-split) int8 decode kernels, from
-    the round-5 on-chip sweeps (scripts/probe_int4c.py at 1B shapes plus an
-    8B-shape sweep; us per decode matmul, 2D [nb*4, out] storage):
-      big-out   (out >= 4096):  tn=2048; knb=64 at nb>=128 (8B wqkv 19.5 us
-                725 GB/s, w13 76.8 us 860 GB/s), knb=32 at smaller
-                contractions (1B w13 28.1 us 672 GB/s; wcls 51.9 us 728)
-      deep-k    (nb >= 256, out < 4096): tn=1024 knb=64 (8B w2 47.5 us
-                695 GB/s; the r5.0 (2048, 8) choice measured ~14.6 us at
-                the 1B w2 shape but loses at 8B scale)
-      square    (else):                  tn=1024 knb=32 (wqkv 1.27x)
-    Lane tiles come from `_lane_tile` so ragged outs (128256 vocab) keep
-    wide tiles.
-    """
+def _fs_tiles(nb: int, out: int) -> tuple[int, int]:
+    """DMA tile (lanes, blocks per k step) of the packed int8 decode kernels.
+    The table dates from sweeps at Llama 1B/8B shapes on an earlier software
+    stack and was not swept again when the dot was cut into sub-blocks
+    (PERF.md, PR 26): big outs take 2048 lanes, and 64 blocks a step where
+    the contraction has them; a contraction that is no multiple of 64 blocks
+    (Qwen3-14B: nb = 160 and 544) halves down to 32. Lane tiles come from
+    `_lane_tile`, so a ragged out keeps its widest divisor; a prime one
+    (Qwen3's vocabulary, 1187 x 128) is left with 128 lanes and a grid step
+    per 128 outputs, which is what bounds the head (ROADMAP S3)."""
     if out >= 4096:
         tile_n, tile_knb = 2048, (64 if nb >= 128 else 32)
     elif nb >= 256:
@@ -576,10 +406,9 @@ def _fs_tiles(nb: int, out: int, rows: int = 1) -> tuple[int, int]:
     while nb % tile_knb:
         tile_knb //= 2
     # VMEM: packed i32 block (dbl-buffered, 16*knb*tn bytes) + lo/hi int8
-    # temps + the per-row blockdiag expansions [rows*knb, knb*16] x2
+    # temps. The block-diagonal operand needs no cap of its own: it is one
+    # sub-block's, [rows*8, 128] int8 a plane (`_fs_sub`)
     while 4 * tile_knb * 16 * tile_n > 8 * 1024 * 1024 and tile_knb > 8:
-        tile_knb //= 2
-    while 2 * rows * tile_knb * tile_knb * HGRP > 4 * 1024 * 1024 and tile_knb > 8:
         tile_knb //= 2
     # Mosaic sublane rule for the [tile_knb, tile_n] scale block (multi-k
     # grids need tile_knb % 8 unless the block spans the whole leading dim)
@@ -588,52 +417,78 @@ def _fs_tiles(nb: int, out: int, rows: int = 1) -> tuple[int, int]:
     return tile_n, tile_knb
 
 
+def _fs_sub(tile_knb: int) -> int:
+    """Blocks per block-diagonal dot. A dot over `sub` blocks feeds the MXU
+    `rows * sub` left-operand rows, so it executes `rows * sub` multiply-adds
+    for every weight, one of them work and the rest zeros: the narrower the
+    better, at every row count (PERF.md, PR 26: 8 beat 16, 32 and the whole
+    tile at 8 and 4 rows and tied at 1 and 2). 8 is the narrowest that keeps
+    whole tiles: a row's partials are [8, tn], one 8-sublane tile of int32
+    and f32, and the dot contracts 8 * 16 = 128 lanes. A tile that 8 does
+    not divide (a ragged whole-dim k step) stays one dot."""
+    return 8 if tile_knb % 8 == 0 else tile_knb
+
+
+def _blockdiag_partials(x8_planes, w_planes, mask) -> jnp.ndarray:
+    """Every block's integer dot of one sub-block, [R*sub, tn] int32 (row
+    r*sub + b = activation row r against block b), from one int8 matmul a
+    nibble plane: the left operand stacks, for every activation row, that
+    row's [sub*16] values masked onto the block diagonal [sub, sub*16]. The
+    planes' partials add (disjoint halves of each block's features)."""
+    partials = None
+    for x8, w in zip(x8_planes, w_planes):
+        # strictly 2D per-row broadcast-select + sublane concat (3D int8
+        # broadcasts fail Mosaic's shape-cast lowering; select, not multiply:
+        # muli on i8 vectors does not legalize)
+        bd = jnp.concatenate(
+            [
+                jnp.where(mask, jnp.broadcast_to(x8[r : r + 1], mask.shape), jnp.int8(0))
+                for r in range(x8.shape[0])
+            ],
+            axis=0,
+        )  # [R*sub, sub*16]
+        p = jax.lax.dot_general(
+            bd, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        )
+        partials = p if partials is None else partials + p
+    return partials
+
+
 def _kernel_fs_i8(
     x8a_ref, x8b_ref, xs_ref, bs_ref, mask_ref, qp_ref, dt_ref, out_ref
 ):
     """Packed-weight int8-MXU decode kernel: two i32 mask ops + pltpu.bitcast
-    unpack the nibble planes straight into int8 MXU operands (module
-    docstring). Per plane, the blockdiag trick gives every block's partial
-    dot in ONE 2D int8 matmul; the two planes' partials add (they are
-    disjoint halves of each block's features), the +8 offset leaves via the
-    prologue-computed per-block sums, and per-block scales combine on the
-    VPU at 1/32nd the element count. Bit-exact vs the reference's Q80xQ40
-    integer dot (all-integer until the final f32 scale combine)."""
+    unpack the k step's nibble planes straight into int8 MXU operands (module
+    docstring). The tile is walked in sub-blocks of `sub` blocks (the mask's
+    rows; `_fs_sub`): `_blockdiag_partials` gives a sub-block's integer dots,
+    the +8 offset leaves via the prologue-computed per-block sums, and the
+    per-block scales combine on the VPU at 1/32nd the element count: each
+    row keeps [sub, tn] f32 sums over the sub-blocks, in ascending order,
+    and reduces them over the sublanes once a k step. Bit-exact vs the
+    reference's Q80xQ40 integer dot (all-integer until the f32 combine)."""
     k = pl.program_id(1)
     knb, tn = dt_ref.shape
     R = x8a_ref.shape[0]
-    mask = mask_ref[...]  # [knb, knb*16]
+    sub = mask_ref.shape[0]
+    mask = mask_ref[...] != 0  # [sub, sub*16]
     lo, hi = _fs_lo_hi(qp_ref[...])  # int8 [knb*16, tn] each
-    partials = None
-    for x_ref, w in ((x8a_ref, lo), (x8b_ref, hi)):
-        x8 = x_ref[...]  # [R, knb*16] int8
-        if R == 1:
-            bd = jnp.where(mask != 0, jnp.broadcast_to(x8, mask.shape), jnp.int8(0))
-        else:
-            # strictly 2D per-row broadcast-select + sublane concat (3D int8
-            # broadcasts fail Mosaic's shape-cast lowering on this platform)
-            bd = jnp.concatenate(
-                [
-                    jnp.where(
-                        mask != 0,
-                        jnp.broadcast_to(x8[r : r + 1], mask.shape),
-                        jnp.int8(0),
-                    )
-                    for r in range(R)
-                ],
-                axis=0,
-            )  # [R*knb, knb*16]
-        p = jax.lax.dot_general(
-            bd, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-        )  # [R*knb, tn]
-        partials = p if partials is None else partials + p
     dtf = _scale_f32(dt_ref[...])  # [knb, tn]
-    rows = []
-    for r in range(R):
-        pr = partials[r * knb : (r + 1) * knb].astype(jnp.float32)
-        pr = pr - 8.0 * bs_ref[...][:, r * 128 : r * 128 + 1]
-        scale = xs_ref[...][:, r * 128 : r * 128 + 1] * dtf
-        rows.append(jnp.sum(pr * scale, axis=0)[None, :])
+    terms = [None] * R  # row r: [sub, tn] f32, summed over the sub-blocks
+    for s in range(knb // sub):
+        cols = slice(s * sub * HGRP, (s + 1) * sub * HGRP)
+        blocks = slice(s * sub, (s + 1) * sub)
+        partials = _blockdiag_partials(
+            (x8a_ref[:, cols], x8b_ref[:, cols]), (lo[cols], hi[cols]), mask
+        )  # [R*sub, tn]
+        for r in range(R):
+            lane = slice(r * 128, r * 128 + 1)
+            pr = partials[r * sub : (r + 1) * sub].astype(jnp.float32)
+            pr = pr - 8.0 * bs_ref[blocks, lane]
+            term = pr * (xs_ref[blocks, lane] * dtf[blocks])
+            terms[r] = term if s == 0 else terms[r] + term
+    # one sublane reduction a row and k step, not one a sub-block: at 8
+    # rows the reductions were two fifths of the row-dependent time (PERF.md)
+    rows = [jnp.sum(t, axis=0)[None, :] for t in terms]
     acc = rows[0] if R == 1 else jnp.concatenate(rows, axis=0)  # [R, tn]
 
     @pl.when(k == 0)
@@ -654,35 +509,6 @@ def _kernel_fs_stacked_i8(
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def _i8_call(x8, xs, qt, dt, interpret: bool = False) -> jnp.ndarray:
-    """LEGACY (probe support): the round-4 unpacked-int8 MXU pallas_call on
-    pre-quantized activations — the A/B baseline the packed kernels are
-    measured against (scripts/probe_int4*.py). x8 [R, in] int8, xs
-    [nb, R*128] scales, dt already `_dt_operand`-shaped; qt UNPACKED
-    [nb, 32, out] int8. Returns [R, out] f32."""
-    nb, _, out = qt.shape
-    R = x8.shape[0]
-    tile_n, tile_knb = _i8_tiles(nb, out, rows=R)
-    mask = _blockdiag_mask(tile_knb)
-    grid = (out // tile_n, nb // tile_knb)
-    return pl.pallas_call(
-        _kernel_i8,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((R, tile_knb * Q_BLOCK), lambda j, k: (0, k)),
-            pl.BlockSpec((tile_knb, R * 128), lambda j, k: (k, 0)),
-            pl.BlockSpec((tile_knb, tile_knb * Q_BLOCK), lambda j, k: (0, 0)),
-            pl.BlockSpec((tile_knb, Q_BLOCK, tile_n), lambda j, k: (k, 0, j)),
-            pl.BlockSpec((tile_knb, tile_n), lambda j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((R, tile_n), lambda j, k: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((R, out), jnp.float32),
-        interpret=interpret,
-        **_i8_compiler_params(),
-    )(x8, xs, mask, qt, dt)
-
-
-@partial(jax.jit, static_argnames=("interpret",))
 def q40_matmul_pallas_i8(x, qt, dt, interpret: bool = False) -> jnp.ndarray:
     """x @ w via the packed int8-MXU kernel for decode-sized batches. x:
     [..., in] with a small row count (quant_matmul gates rows <= 8); qt the
@@ -698,8 +524,9 @@ def q40_matmul_pallas_i8(x, qt, dt, interpret: bool = False) -> jnp.ndarray:
         R *= s
     x8a, x8b, xs, bs = _quantize_rows_q80_split(x.reshape(R, in_features), nb)
     dt = _dt_operand(dt)
-    tile_n, tile_knb = _fs_tiles(nb, out, rows=R)
-    mask = _halfmask(tile_knb)
+    tile_n, tile_knb = _fs_tiles(nb, out)
+    sub = _fs_sub(tile_knb)
+    mask = _halfmask(sub)
     grid = (out // tile_n, nb // tile_knb)
     out2 = pl.pallas_call(
         _kernel_fs_i8,
@@ -709,7 +536,7 @@ def q40_matmul_pallas_i8(x, qt, dt, interpret: bool = False) -> jnp.ndarray:
             pl.BlockSpec((R, tile_knb * HGRP), lambda j, k: (0, k)),
             pl.BlockSpec((tile_knb, R * 128), lambda j, k: (k, 0)),
             pl.BlockSpec((tile_knb, R * 128), lambda j, k: (k, 0)),
-            pl.BlockSpec((tile_knb, tile_knb * HGRP), lambda j, k: (0, 0)),
+            pl.BlockSpec((sub, sub * HGRP), lambda j, k: (0, 0)),
             pl.BlockSpec((tile_knb * 4, tile_n), lambda j, k: (k, j)),
             pl.BlockSpec((tile_knb, tile_n), lambda j, k: (k, j)),
         ],
@@ -737,8 +564,9 @@ def q40_matmul_pallas_stacked_i8(
         R *= s
     x8a, x8b, xs, bs = _quantize_rows_q80_split(x.reshape(R, in_features), nb)
     dt = _dt_operand(dt)
-    tile_n, tile_knb = _fs_tiles(nb, out, rows=R)
-    mask = _halfmask(tile_knb)
+    tile_n, tile_knb = _fs_tiles(nb, out)
+    sub = _fs_sub(tile_knb)
+    mask = _halfmask(sub)
     k_steps = nb // tile_knb
     qt2 = qt.reshape(L * rows4, out)
     dt3 = dt.reshape(L * nb, out)
@@ -751,7 +579,7 @@ def q40_matmul_pallas_stacked_i8(
             pl.BlockSpec((R, tile_knb * HGRP), lambda j, k, l: (0, k)),
             pl.BlockSpec((tile_knb, R * 128), lambda j, k, l: (k, 0)),
             pl.BlockSpec((tile_knb, R * 128), lambda j, k, l: (k, 0)),
-            pl.BlockSpec((tile_knb, tile_knb * HGRP), lambda j, k, l: (0, 0)),
+            pl.BlockSpec((sub, sub * HGRP), lambda j, k, l: (0, 0)),
             pl.BlockSpec(
                 (tile_knb * 4, tile_n), lambda j, k, l: (l[0] * k_steps + k, j)
             ),

@@ -27,9 +27,8 @@ int8 layout's HBM traffic and footprint.
 The packing is the FEATURE-SPLIT codec the Pallas kernels unpack with two
 i32 mask ops + a pltpu.bitcast (~0.4 VPU ops/weight — probed as the only
 formulation that stays DMA-bound; plane-extraction unpacks are VPU-bound
-and s4 arrays can't cross jit boundaries on this platform, see
-scripts/probe_int4*.py): within block b, feature s in [0,16) shares a byte
-with feature s+16 —
+and s4 arrays can't cross jit boundaries on this platform): within block
+b, feature s in [0,16) shares a byte with feature s+16 —
 
     byte[b, s, o]  = (v[b, s, o] + 8) | ((v[b, s + 16, o] + 8) << 4)
     word[b, g, o]  = bytes 4g..4g+3 little-endian, rows flattened to
@@ -277,13 +276,18 @@ def quant_matmul(
         # exactly, so CPU kernel tests keep their f32 references).
         pallas = _use_pallas() and dtype != jnp.float32
     # decode-sized batches on the approximate bf16 path: the int8-MXU
-    # kernel — weights hit the MXU as int8 with per-block scale combine,
-    # removing the per-element VPU dequant (measured 17x on square shapes).
-    # The kernel's block-diagonal lhs stacks rows on the sublane axis, so
-    # any rows <= 8 qualify (beyond that, the bf16-dequant kernel's
-    # per-element dequant amortizes over rows and wins). Activation
-    # numerics = the reference's default `--buffer-float-type q80`; the
-    # f32 parity paths never take this branch.
+    # kernel — weights hit the MXU as int8 and the per-block scales combine
+    # after the dot, so no weight is dequantized on the VPU. What it pays
+    # instead grows with the rows: the block-diagonal left operand makes
+    # the MXU execute rows * 8 multiply-adds a weight (pallas_q40._fs_sub)
+    # and the VPU combine rows / 32 partials a weight. On the v5e one row
+    # streams weights at the HBM rate and 8 rows at 1.1-1.5x that time
+    # (PERF.md, PR 26: the table of scripts/probe_i8_sub.py). The kernel
+    # stacks rows on the sublane axis and is built and measured for up to 8;
+    # above 8 the bf16-dequant kernel takes over, and the benchmark's
+    # configurations state bf16 activations there. Activation numerics =
+    # the reference's default `--buffer-float-type q80`; the f32 parity
+    # paths never take this branch.
     rows = 1
     for s in x.shape[:-1]:
         rows *= s

@@ -274,3 +274,118 @@ def test_i8_kernel_ragged_vocab_out():
     want = _q80_reference(x, wt)
     got = np.asarray(q40_matmul_pallas_i8(x, wt.q, wt.d, interpret=True))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# ---- the block-diagonal dot cut into sub-blocks (PR 26) ----
+
+# Qwen3-14B's contractions are no multiple of 64 blocks: `_fs_tiles` halves
+# to 32, so dim 5120 is 5 k steps and ffn 17408 is 17 (outs scaled down)
+RAGGED_CONTRACTIONS = {160: 5, 544: 17}
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["plain", "stacked"])
+@pytest.mark.parametrize("rows", [1, 2, 4, 8])
+@pytest.mark.parametrize("nb", sorted(RAGGED_CONTRACTIONS))
+def test_i8_sub_blocked_kernel_at_ragged_contractions(nb, rows, stacked):
+    from distributed_llama_tpu.ops.pallas_q40 import (
+        _fs_sub,
+        _fs_tiles,
+        q40_matmul_pallas_i8,
+        q40_matmul_pallas_stacked_i8,
+    )
+
+    out_f, in_f = 256, nb * 32
+    tn, knb = _fs_tiles(nb, out_f)
+    assert (knb, nb // knb) == (32, RAGGED_CONTRACTIONS[nb])
+    assert _fs_sub(knb) == 8  # four sub-blocks a k step
+    rng = np.random.default_rng(nb + rows)
+    layers = [make_weight(rng, out_f, in_f) for _ in range(2 if stacked else 1)]
+    x = jnp.asarray(rng.standard_normal((rows, in_f)), jnp.float32)
+    want = np.concatenate([_q80_reference(x[r : r + 1], layers[-1]) for r in range(rows)])
+    if stacked:
+        qs = jnp.stack([w.q for w in layers])
+        ds = jnp.stack([w.d for w in layers])
+        got = q40_matmul_pallas_stacked_i8(x, qs, ds, jnp.int32(1), interpret=True)
+    else:
+        got = q40_matmul_pallas_i8(x, layers[0].q, layers[0].d, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+def test_sub_block_partials_equal_the_single_dot_exactly():
+    """Every block's integer partial is the same integer whether the k
+    step's tile is one block-diagonal dot or walked 8 blocks at a time, and
+    both are the exact dot of the Q80 codes with the (+8) Q40 codes."""
+    from distributed_llama_tpu.ops.pallas_q40 import (
+        HGRP,
+        _blockdiag_partials,
+        _halfmask,
+        _quantize_rows_q80_split,
+    )
+    from distributed_llama_tpu.ops.quant import unpack_q
+
+    R, knb, sub, tn = 8, 32, 8, 128
+    rng = np.random.default_rng(26)
+    wt = make_weight(rng, tn, knb * 32)
+    u = np.asarray(unpack_q(wt.q), np.int32) + 8  # [knb, 32, tn] as the kernel unpacks
+    lo = jnp.asarray(u[:, :HGRP].reshape(knb * HGRP, tn), jnp.int8)
+    hi = jnp.asarray(u[:, HGRP:].reshape(knb * HGRP, tn), jnp.int8)
+    x = jnp.asarray(rng.standard_normal((R, knb * 32)), jnp.float32)
+    x8a, x8b, _, _ = _quantize_rows_q80_split(x, knb)
+
+    whole = np.asarray(
+        _blockdiag_partials((x8a, x8b), (lo, hi), _halfmask(knb) != 0)
+    ).reshape(R, knb, tn)
+    mask = _halfmask(sub) != 0
+    walked = np.concatenate(
+        [
+            np.asarray(
+                _blockdiag_partials(
+                    (x8a[:, c], x8b[:, c]), (lo[c], hi[c]), mask
+                )
+            ).reshape(R, sub, tn)
+            for c in (slice(s * sub * HGRP, (s + 1) * sub * HGRP) for s in range(knb // sub))
+        ],
+        axis=1,
+    )
+    assert walked.dtype == np.int32
+    np.testing.assert_array_equal(walked, whole)
+    x8 = np.concatenate(
+        [np.asarray(x8a).reshape(R, knb, HGRP), np.asarray(x8b).reshape(R, knb, HGRP)], axis=2
+    ).astype(np.int32)
+    np.testing.assert_array_equal(whole, np.einsum("rbk,bko->rbo", x8, u))
+
+
+# in -> out of the matmuls the benchmark's two models send to the kernel
+MODEL_MATMULS = {
+    "14b.wqkv": (5120, 7168), "14b.wo": (5120, 5120), "14b.w13": (5120, 34816),
+    "14b.w2": (17408, 5120), "14b.wcls": (5120, 151936),
+    "8b.wqkv": (4096, 6144), "8b.wo": (4096, 4096), "8b.w13": (4096, 24576),
+    "8b.w2": (12288, 4096), "8b.wcls": (4096, 151936),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MODEL_MATMULS))
+def test_executed_multiply_adds_per_weight(name):
+    """The MXU executes rows * sub multiply-adds for every weight (one is
+    work, the rest multiply the block diagonal's zeros): at most the MXU's
+    128 rows at any row count the gate admits, and never more than the
+    single dot's rows * knb."""
+    from distributed_llama_tpu.ops.pallas_q40 import _fs_sub, _fs_tiles
+
+    in_f, out_f = MODEL_MATMULS[name]
+    nb = in_f // 32
+    tn, knb = _fs_tiles(nb, out_f)
+    assert nb % knb == 0 and out_f % tn == 0
+    sub = _fs_sub(knb)
+    assert knb % sub == 0
+    for rows in range(1, 9):
+        assert rows * sub <= 128, (rows, sub)
+        assert rows * sub <= rows * knb
+
+
+def test_sub_blocks_of_a_ragged_whole_dim_tile_stay_one_dot():
+    from distributed_llama_tpu.ops.pallas_q40 import _fs_sub, _fs_tiles
+
+    tn, knb = _fs_tiles(68, 256)  # 68 = 4 * 17: no divisor that is a multiple of 8
+    assert knb == 68 and _fs_sub(knb) == 68
+    assert _fs_sub(8) == 8 and _fs_sub(136) == 8  # a tp=4 shard of ffn 17408
