@@ -14,6 +14,7 @@ block arrives as [TILE_KNB*4, TILE_N] int32; `w & 0x0F0F0F0F` yields the
 bytes of features 0..15 of each 32-block (+8, unsigned), `(w >> 4) & ...`
 features 16..31, and pltpu.bitcast reinterprets each masked word as 4 int8
 sublanes (probed natural little-endian order) — no per-element VPU work.
+Two kernel families share it, split by row count (ops/quant.py quant_matmul):
   * decode (row counts <= 8): the int8 results feed the MXU directly. A k
     step's tile is walked 8 blocks at a time (`_fs_sub`): per sub-block and
     nibble plane one dot of the activations, masked onto the block diagonal
@@ -22,11 +23,19 @@ sublanes (probed natural little-endian order) — no per-element VPU work.
     per-block correction 8*sum(x8_block) computed in the prologue. Bit-exact
     vs the reference's Q80xQ40 integer dot. The MXU executes rows*8
     multiply-adds a weight, the rest of each on the diagonal's zeros.
-  * prefill (large row counts): the planes concat to [TILE_KNB, 32, TILE_N]
-    and dequantize to bf16 ((u - 8) * scale) — the per-element convert
-    amortizes over the activation rows, MXU work dominates.
-The kernel alone on the chip, every shape of the benchmark's two models at 1
-to 8 rows: scripts/probe_i8_sub.py (its table: PERF.md, PR 26). Dead ends of
+  * above 8 rows (16 decoding rows, a prompt's 64-256): the planes
+    dequantize to a bf16 [TILE_KNB*32, TILE_N] tile, bf16(code) *
+    bf16(scale) rounded once, and one bf16 dot takes it (`_dequant_tile`).
+    The v5e has no bf16 vector unit, so the dequant is written for the f32
+    one: the +8 leaves on the packed words (`_fs_planes_x16`), the int8
+    planes convert to f32 before any reshape, one f32 multiply, one
+    rounding: 7.0 vector operations for every 1024 weights (13.3 before
+    PR 30, the same bits). At 16 rows the kernel is still bound by those
+    operations, at about half the HBM rate; the convert amortizes over the
+    rows, and from 128 rows on the MXU's work dominates.
+The kernels alone on the chip, every shape of the benchmark's two models:
+scripts/probe_i8_sub.py at 1 to 8 rows (its table: PERF.md, PR 26),
+scripts/probe_bf16_dequant.py at 9 to 256 (PERF.md, PR 30). Dead ends of
 earlier rounds, none tried again on the installed compiler: s4 arrays as jit
 operands, int8 bitwise ops and bitwidth-changing jax.lax.bitcasts in Mosaic,
 VPU-bound plane-extraction unpacks, an unpacked int8 weight layout (twice
@@ -84,7 +93,6 @@ def _i8_compiler_params():
 
 
 DEFAULT_TILE_N = 256
-DEFAULT_TILE_KNB = 64  # 64 blocks = 2048 input features per k step
 
 
 def q40_matmul_aligned(x, w) -> bool:
@@ -160,29 +168,67 @@ def _fs_lo_hi(w32: jnp.ndarray):
     return lo, hi
 
 
+PLANE_MASK = 0xF0F0F0F0 - (1 << 32)  # each byte's high nibble, as an int32
+PLANE_FLIP = 0x80808080 - (1 << 32)  # each byte's top bit
+
+
+def _fs_planes_x16(w32: jnp.ndarray):
+    """Packed block [knb*4, tn] int32 -> (lo, hi) int8 [knb*16, tn] holding
+    16 * (code - 8), the SIGNED weight code times 16, of features 0..15 /
+    16..31 of each 32-block: a nibble u moved to its byte's high half with
+    its top bit flipped, `(u ^ 8) << 4`, reads as int8 exactly 16 * (u - 8).
+    A mask and an xor a plane (and the low plane's shift) on the packed
+    words, then the same pltpu.bitcast as `_fs_lo_hi`: the codec's +8 offset
+    leaves without a subtraction on the unpacked elements."""
+    m, flip = jnp.int32(PLANE_MASK), jnp.int32(PLANE_FLIP)
+    hi = pltpu.bitcast(jnp.bitwise_xor(jnp.bitwise_and(w32, m), flip), jnp.int8)
+    lo = pltpu.bitcast(
+        jnp.bitwise_xor(jnp.bitwise_and(jnp.left_shift(w32, jnp.int32(4)), m), flip),
+        jnp.int8,
+    )
+    return lo, hi
+
+
+def _dequant_tile(w32: jnp.ndarray, dt: jnp.ndarray, dtype) -> jnp.ndarray:
+    """A k step's packed tile [knb*4, tn] and its scales [knb, tn] -> the
+    dequantized weights [knb*32, tn] in `dtype`, natural feature order.
+    Single owner of the dequant rounding choice.
+
+    bf16 (the served path): the weight is bf16(code) * bf16(scale) rounded to
+    bf16, what `(bf16(u) - 8) * bf16(scale)` gave bit for bit, but computed
+    where the chip has a vector unit (it has none for bf16: every bf16
+    operation widens, operates and rounds again). The scale plane (1/32nd of
+    the elements) rounds to bf16, widens and takes the 1/16 that
+    `_fs_planes_x16` owes (a power of two: exact); each int8 plane converts
+    to f32 BEFORE it is reshaped (an f32 [knb*16, tn] -> [knb, 16, tn] is
+    whole registers; the int8 reshape costs selects and rotates), multiplies
+    in f32 (a 4-bit code times an 8-bit mantissa: exact) and rounds to bf16
+    once. A bf16 [knb, 16, tn] plane is whole registers too, so putting the
+    two planes back in natural order moves none of them: 7.0 vector
+    operations for every 1024 weights where the old body spent 13.3 (the
+    compiler's own count: scripts/probe_bf16_dequant.py --compile-only).
+    f32 (the parity tests): the same product with the f16 scale unrounded,
+    `f32(16 (u - 8)) * (scale / 16)`, then one cast."""
+    knb, tn = dt.shape
+    dtf = _scale_f32(dt)
+    if dtype == jnp.bfloat16:
+        dtf = dtf.astype(jnp.bfloat16).astype(jnp.float32)
+    s16 = (dtf * jnp.float32(1 / 16))[:, None, :]
+    planes = [
+        (p8.astype(jnp.float32).reshape(knb, HGRP, tn) * s16).astype(dtype)
+        for p8 in _fs_planes_x16(w32)
+    ]
+    return jnp.concatenate(planes, axis=1).reshape(knb * Q_BLOCK, tn)
+
+
 def _dequant_dot_accum(k, x_ref, qp_ref, dt_ref, out_ref):
     """Shared body of the bf16-dequant (prefill / multi-row) kernels:
-    unpack + dequantize this k-step's packed weight tile, matmul against the
-    x tile, accumulate into out over the k grid axis. Single owner of the
-    dequant rounding choice — the unstacked, stacked, and grouped kernels
-    differ only in how their BlockSpec index_maps pick the tile (plain /
-    scalar-prefetched layer / per-row-block expert), never in the math."""
-    knb, tn = dt_ref.shape
-    lo, hi = _fs_lo_hi(qp_ref[...])
-    u = jnp.concatenate(
-        [lo.reshape(knb, HGRP, tn), hi.reshape(knb, HGRP, tn)], axis=1
-    )  # [knb, 32, tn] unsigned (+8) values, natural feature order
-    dtf = _scale_f32(dt_ref[...])
-    if x_ref.dtype == jnp.bfloat16:
-        # dequant in bf16: (u - 8) is exact in bf16 (small integers); the
-        # scale multiply rounds once, same class as the pre-pack kernels
-        w = (u.astype(jnp.bfloat16) - jnp.bfloat16(8)) * dtf[:, None, :].astype(
-            jnp.bfloat16
-        )
-    else:
-        # f32 multiply keeps full f16-scale precision, then cast once
-        w = ((u.astype(jnp.float32) - 8.0) * dtf[:, None, :]).astype(x_ref.dtype)
-    w = w.reshape(knb * Q_BLOCK, tn)
+    dequantize this k-step's packed weight tile (`_dequant_tile`), matmul
+    against the x tile, accumulate into out over the k grid axis. The
+    unstacked, stacked, and grouped kernels differ only in how their
+    BlockSpec index_maps pick the tile (plain / scalar-prefetched layer /
+    per-row-block expert), never in the math."""
+    w = _dequant_tile(qp_ref[...], dt_ref[...], x_ref.dtype)
     acc = jnp.dot(x_ref[...], w, preferred_element_type=jnp.float32)
 
     @pl.when(k == 0)
@@ -194,50 +240,77 @@ def _dequant_dot_accum(k, x_ref, qp_ref, dt_ref, out_ref):
         out_ref[...] += acc
 
 
+BF16_TILE_N = 512  # lanes of a bf16-dequant tile where the budget lets it
+BF16_VMEM_CAP = 12 * 1024 * 1024
+
+
+def _bf16_vmem_need(b: int, tile_n: int, tile_knb: int) -> int:
+    """Scoped VMEM of one bf16-dequant grid step, bytes: the model that
+    `_bf16_tile_cap` holds under BF16_VMEM_CAP. Fitted to what the v5e's
+    compiler accepts and refuses of `_dequant_dot_accum` (PR 30: 117 tiles at
+    16 to 2048 rows compiled for a described v5e; every refusal models above
+    15.0 MB and 86 of the 92 it accepts below; the chip refused and accepted
+    the same tiles, scripts/probe_bf16_dequant.py): the activations' block
+    three times (two DMA buffers and the dot's own copy), the packed block
+    and the result block twice, the scales' block twice, and half of the
+    dequantized bf16 tile (the compiler feeds the MXU as it dequantizes and
+    never holds the whole tile). The cap leaves 3 MB under the lowest
+    refusal for what other shapes bring."""
+    weights = tile_knb * Q_BLOCK * tile_n
+    return (
+        3 * b * tile_knb * Q_BLOCK * 2
+        + 2 * (weights // 2 + tile_knb * tile_n * 2)
+        + weights
+        + 2 * b * tile_n * 4
+    )
+
+
 def _bf16_tile_cap(b: int, tile_n: int, tile_knb: int, nb: int):
-    """Shrink the bf16-dequant kernels' tiles so scoped VMEM stays under the
-    ~16 MB stack limit at large row counts (batched prefill pushes
-    b = batch x chunk rows; a real 4x256-row run OOMed at the w2 shape).
-    Budget model: x block (double-buffered bf16) + dequant temp + int8
-    weight block (double-buffered) + out/acc f32. The budget model
-    under-counts Mosaic's internal temporaries by ~4 MB (a 1024-row w2
-    config modeling 12 MB measured 16.24 MB scoped), so the cap is 10 MB.
-    k-depth shrinks first (less valuable than lane width)."""
+    """Shrink a bf16-dequant tile until `_bf16_vmem_need` is under
+    BF16_VMEM_CAP (the chip's scoped-VMEM limit is 16 MB; batched prefill
+    pushes b = batch x chunk rows, and a real 4x256-row run OOMed at the w2
+    shape). Depth shrinks first, over the LEGAL depths only: divisors of nb
+    (a non-divisor would DROP k blocks from the grid — silently wrong
+    results, not a perf choice) that are multiples of 8 or nb itself (the
+    Mosaic sublane rule for a multi-k-step scale block; only a whole-dim
+    block is exempt). A ragged nb with no such divisor under `tile_knb`
+    takes one whole-dim k step. Then lanes narrow, down to 128."""
 
-    def need(tn, knb):
-        # x (bf16, dbl-buffered) + dequant w (2B) + unpack temps (lo/hi/cat
-        # int8 ~ 2x the unpacked bytes) + packed i32 block (dbl-buffered,
-        # 0.5B/weight) + out/acc f32
-        return (
-            2 * b * knb * Q_BLOCK * 2
-            + knb * Q_BLOCK * tn * 2
-            + 2 * knb * Q_BLOCK * tn
-            + 2 * knb * HGRP * tn
-            + 2 * b * tn * 4
-        )
+    def over(tn, knb):
+        return _bf16_vmem_need(b, tn, knb) > BF16_VMEM_CAP
 
-    cap = 10 * 1024 * 1024
-    while need(tile_n, tile_knb) > cap and tile_knb >= 16:
-        nxt = tile_knb // 2
-        if nb % nxt:
-            break  # a non-divisor would DROP k blocks from the grid —
-            # silently wrong results, not a perf choice; shrink lanes instead
-        tile_knb = nxt
-    while need(tile_n, tile_knb) > cap and tile_n > 128:
-        tile_n //= 2
-    # Mosaic sublane rule: a multi-k-step scale block needs tile_knb % 8 == 0
-    # (only whole-dim blocks are exempt). Do NOT reset to nb here — that
-    # would discard the cap just computed (e.g. nb=24 halves to 12, then a
-    # reset back to 24 re-OOMs). 12 -> 8 SHRINKS the footprint (budget still
-    # holds); ragged nb falls back to one whole-dim k step with tile_n
-    # shrunk to fit.
-    if tile_knb != nb and tile_knb % 8:
-        if nb % 8 == 0:
-            tile_knb = 8
-        else:
-            tile_knb = nb  # ragged nb: whole-dim k step is always legal
-            while need(tile_n, tile_knb) > cap and tile_n > 128:
-                tile_n //= 2
+    legal = [
+        d
+        for d in range(min(tile_knb, nb), 0, -1)
+        if nb % d == 0 and (d == nb or d % 8 == 0)
+    ] or [nb]
+    tile_knb = next((d for d in legal if not over(tile_n, d)), legal[-1])
+    while over(tile_n, tile_knb) and tile_n > LANE:
+        # the next narrower whole-lane divisor (of a divisor of out: still one)
+        tile_n = next(t for t in range(tile_n - LANE, 0, -LANE) if tile_n % t == 0)
+    return tile_n, tile_knb
+
+
+def _bf16_tiles(b: int, nb: int, out: int) -> tuple[int, int]:
+    """(lanes, blocks a k step) of the plain and stacked bf16-dequant
+    kernels, from the row count and the shape alone. A grid step costs about
+    0.3 us whatever it holds, every k step after a tile's first reads and
+    rewrites the [b, lanes] result, and the activations are fetched again for
+    every tile of lanes unless the contraction is one k step; so (PERF.md,
+    PR 30, the table of tiles at 16 to 256 rows):
+      1. depth first: the whole contraction in one k step where the budget
+         lets it at DEFAULT_TILE_N lanes, else its deepest legal divisor
+         (`_bf16_tile_cap`);
+      2. then BF16_TILE_N lanes where the budget still holds, else
+         DEFAULT_TILE_N (a prime-ish out keeps its widest divisor:
+         `_lane_tile`; Qwen3's vocabulary is left with 128).
+    Few rows leave the budget to the weights, so 16 rows take 512 lanes of
+    the whole contraction at the benchmark's widths; a prompt's 256 rows keep
+    the depth and give the lanes back."""
+    tile_n, tile_knb = _bf16_tile_cap(b, _lane_tile(out, DEFAULT_TILE_N), nb, nb)
+    wide = _lane_tile(out, BF16_TILE_N)
+    if wide > tile_n and _bf16_vmem_need(b, wide, tile_knb) <= BF16_VMEM_CAP:
+        tile_n = wide
     return tile_n, tile_knb
 
 
@@ -280,19 +353,12 @@ def q40_matmul_pallas_stacked(
     b = 1
     for s in lead:
         b *= s
-    x2 = x.reshape(b, in_features).astype(dtype)
     dt = _dt_operand(dt)
-
-    tile_n = min(DEFAULT_TILE_N, out)
-    while out % tile_n:
-        tile_n //= 2
-    tile_knb = min(DEFAULT_TILE_KNB, nb)
-    while nb % tile_knb:
-        tile_knb //= 2
-    tile_n, tile_knb = _bf16_tile_cap(b, tile_n, tile_knb, nb)
     # callers gate on q40_stacked_aligned (nb % 8 == 0), which guarantees the
-    # chain above never lands below 8 — the sublane rule Mosaic enforces on
+    # tile never lands below 8 blocks — the sublane rule Mosaic enforces on
     # real TPUs for blocks that don't span the whole (flattened) leading dim
+    tile_n, tile_knb = _bf16_tiles(b, nb, out)
+    x2 = x.reshape(b, in_features).astype(dtype)
 
     # flatten the layer axis into the block-row axis (a free bitcast — the
     # memory is contiguous) so the kernel sees the same 2D blocks as the
@@ -634,7 +700,6 @@ def q40_matmul_pallas_grouped(
         E *= s
     in_features = nb * Q_BLOCK
     R_pad = xp.shape[0]
-    xp = xp.astype(dtype)
     dt = _dt_operand(dt)
 
     # Tiles start at the WHOLE expert and shrink only under VMEM pressure:
@@ -643,8 +708,9 @@ def q40_matmul_pallas_grouped(
     # steps per role per layer ran the kernel at ~70 GB/s effective (round-5
     # profile). Whole-expert tiles make one step per row block.
     def vmem_need(tn, knb):
-        # packed block (dbl-buffered) + dequant bf16 w + cat int8 temp +
-        # x block (dbl) + out block (dbl)
+        # packed block (dbl-buffered) + dequant bf16 w + an int8 temp the
+        # body no longer makes (PR 30; the margin stays until an expert
+        # shape is compiled for the chip) + x block (dbl) + out block (dbl)
         return (
             2 * knb * HGRP * tn
             + knb * Q_BLOCK * tn * 2
@@ -670,6 +736,7 @@ def q40_matmul_pallas_grouped(
     if tile_knb != nb and tile_knb % 8:
         tile_knb = nb
     k_steps = nb // tile_knb
+    xp = xp.astype(dtype)
 
     qt2 = qt.reshape(E * rows4, out)
     dt3 = dt.reshape(E * nb, out)
@@ -720,18 +787,9 @@ def q40_matmul_pallas(
     b = 1
     for s in lead:
         b *= s
-    x2 = x.reshape(b, in_features).astype(dtype)
     dt = _dt_operand(dt)
-
-    tile_n = min(DEFAULT_TILE_N, out)
-    while out % tile_n:
-        tile_n //= 2
-    tile_knb = min(DEFAULT_TILE_KNB, nb)
-    while nb % tile_knb:
-        tile_knb //= 2
-    # _bf16_tile_cap owns BOTH the VMEM cap and the Mosaic sublane rule
-    # (ragged nb falls back to one whole-dim k step inside it)
-    tile_n, tile_knb = _bf16_tile_cap(b, tile_n, tile_knb, nb)
+    tile_n, tile_knb = _bf16_tiles(b, nb, out)
+    x2 = x.reshape(b, in_features).astype(dtype)
 
     grid = (out // tile_n, nb // tile_knb)
     out2 = pl.pallas_call(

@@ -285,7 +285,12 @@ def quant_matmul(
     # (PERF.md, PR 26: the table of scripts/probe_i8_sub.py). The kernel
     # stacks rows on the sublane axis and is built and measured for up to 8;
     # above 8 the bf16-dequant kernel takes over, and the benchmark's
-    # configurations state bf16 activations there. Activation numerics =
+    # configurations state bf16 activations there. That kernel dequantizes
+    # every weight on the VPU, 7 vector operations for every 1024 weights
+    # (pallas_q40._dequant_tile), and at 9 to 32 rows those, not HBM, bound
+    # it: about twice the HBM floor's time at the benchmark's shapes
+    # (PERF.md, PR 30: the table of scripts/probe_bf16_dequant.py), so the
+    # step from 8 rows to 9 costs more than a row. Activation numerics =
     # the reference's default `--buffer-float-type q80`; the f32 parity
     # paths never take this branch.
     rows = 1

@@ -1,6 +1,7 @@
 """Fused Q40 matmul Pallas kernel vs the XLA dequant path (interpret mode on
 the CPU test mesh; the same kernel compiles natively on TPU)."""
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -239,20 +240,38 @@ def test_large_row_vmem_cap_keeps_results_exact():
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
-def test_vmem_cap_divisor_safety_sweep():
+@pytest.mark.parametrize("start", ["halved-from-64", "whole-contraction"])
+def test_vmem_cap_divisor_safety_sweep(start):
     """The cap must never return a tile_knb that fails to divide nb (a
     non-divisor grid DROPS k blocks -> wrong activations) and never violate
-    the Mosaic sublane rule (knb % 8 != 0 only for whole-dim steps)."""
-    from distributed_llama_tpu.ops.pallas_q40 import _bf16_tile_cap
+    the Mosaic sublane rule (knb % 8 != 0 only for whole-dim steps), from
+    either tile a caller can start from: the 64 blocks halved to a divisor
+    that the wrappers gave before PR 30, and the whole contraction that
+    `_bf16_tiles` gives now, at 128 to 512 lanes."""
+    from distributed_llama_tpu.ops.pallas_q40 import (
+        BF16_VMEM_CAP,
+        _bf16_tile_cap,
+        _bf16_vmem_need,
+    )
 
-    for nb in (8, 16, 17, 24, 33, 34, 64, 96, 256, 448):
-        for b in (1, 64, 512, 1024, 4096):
-            start_knb = min(64, nb)
-            while nb % start_knb:
-                start_knb //= 2
-            tn, knb = _bf16_tile_cap(b, 256, start_knb, nb)
-            assert nb % knb == 0, (nb, b, knb)
-            assert knb == nb or knb % 8 == 0, (nb, b, knb)
+    for nb in (8, 16, 17, 24, 33, 34, 64, 68, 96, 128, 136, 160, 256, 384, 448, 544):
+        for b in (1, 9, 16, 64, 256, 512, 1024, 4096):
+            start_knb = nb
+            if start == "halved-from-64":
+                start_knb = min(64, nb)
+                while nb % start_knb:
+                    start_knb //= 2
+            for start_n in (128, 256, 384, 512):
+                tn, knb = _bf16_tile_cap(b, start_n, start_knb, nb)
+                assert nb % knb == 0, (nb, b, knb)
+                assert knb == nb or knb % 8 == 0, (nb, b, knb)
+                assert knb <= max(start_knb, 8) or knb == nb, (nb, b, knb)
+                assert tn % 128 == 0 and start_n % tn == 0, (start_n, tn)
+                if _bf16_vmem_need(b, tn, knb) > BF16_VMEM_CAP:
+                    # nothing legal fits: the shallowest legal depth at 128 lanes
+                    assert tn == 128 and all(
+                        d > knb for d in range(8, nb, 8) if nb % d == 0
+                    ), (nb, b, tn, knb)
 
 
 def test_i8_kernel_ragged_vocab_out():
@@ -389,3 +408,162 @@ def test_sub_blocks_of_a_ragged_whole_dim_tile_stay_one_dot():
     tn, knb = _fs_tiles(68, 256)  # 68 = 4 * 17: no divisor that is a multiple of 8
     assert knb == 68 and _fs_sub(knb) == 68
     assert _fs_sub(8) == 8 and _fs_sub(136) == 8  # a tp=4 shard of ffn 17408
+
+
+# ---- the bf16-dequant body rebuilt (PR 30) ----
+
+
+def _every_code_and_scale():
+    """Packed weights and f16 scales in which every one of the 16 codes
+    meets every finite f16 scale (63,488: zeros, subnormals, the smallest
+    and largest normals, both signs) in both nibble planes: [8 blocks, 7936
+    lanes] of scales; a block's features 0..15 hold the codes -8..7 and its
+    features 16..31 hold them backwards."""
+    from distributed_llama_tpu.ops.quant import pack_q
+
+    bits = np.concatenate([np.arange(0x7C00), 0x8000 + np.arange(0x7C00)]).astype(np.uint16)
+    scales = bits.view(np.float16).reshape(8, -1)
+    assert np.isfinite(scales).all() and scales.size == 63488
+    assert {np.float16(6e-8), np.float16(-6e-8), np.float16(6.104e-5), np.float16(65504),
+            np.float16(-65504)} <= set(scales.ravel().tolist())
+    codes = np.concatenate([np.arange(-8, 8), np.arange(7, -9, -1)]).astype(np.int8)
+    qt = np.broadcast_to(codes[None, :, None], (8, 32, scales.shape[1]))
+    return qt, scales, jnp.asarray(pack_q(np.ascontiguousarray(qt)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_dequant_tile_equals_the_parent_formula_bit_for_bit(dtype):
+    """The tile the MXU receives is the one the body gave before PR 30:
+    `(bf16(u) - 8) * bf16(scale)` in bf16, `(f32(u) - 8) * f32(scale)` in
+    f32, u the unsigned (+8) code. Every code against every finite f16
+    scale, compared as bits (a -0 is not a +0)."""
+    from jax.experimental import pallas as pl
+    from distributed_llama_tpu.ops.pallas_q40 import _dequant_tile, _dt_operand
+
+    qt, scales, qp = _every_code_and_scale()
+    knb, tn = scales.shape
+
+    def kernel(qp_ref, dt_ref, out_ref):
+        out_ref[...] = _dequant_tile(qp_ref[...], dt_ref[...], dtype)
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((knb * 32, tn), dtype), interpret=True
+    )(qp, _dt_operand(jnp.asarray(scales)))
+    u = jnp.asarray(qt.astype(np.int8) + 8)  # [knb, 32, tn] as the old body unpacked it
+    if dtype == jnp.bfloat16:
+        want = (u.astype(jnp.bfloat16) - jnp.bfloat16(8)) * jnp.asarray(scales)[
+            :, None, :
+        ].astype(jnp.bfloat16)
+    else:
+        want = (u.astype(jnp.float32) - 8.0) * jnp.asarray(scales)[:, None, :].astype(jnp.float32)
+    as_bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(
+        np.asarray(got).view(as_bits), np.asarray(want.reshape(knb * 32, tn)).view(as_bits)
+    )
+
+
+def _parent_weight(wt, dtype):
+    """[in, out] f32: the weights as the bf16-dequant body hands them to the
+    MXU (bf16: code and scale rounded to bf16, their product rounded once)."""
+    from distributed_llama_tpu.ops.quant import unpack_q
+
+    qv = np.asarray(unpack_q(wt.q), np.float32)  # [nb, 32, out]
+    d = jnp.asarray(wt.d)[:, None, :]
+    if dtype == jnp.bfloat16:
+        w = jnp.asarray(qv, jnp.bfloat16) * d.astype(jnp.bfloat16)
+    else:
+        w = jnp.asarray(qv) * d.astype(jnp.float32)
+    return np.asarray(w.astype(jnp.float32)).reshape(-1, qv.shape[-1])
+
+
+# contractions of the bf16-dequant kernels' tests: Qwen3-14B's two ragged ones
+# (160 = 5 x 32 blocks, 544 = 17 x 32) and a power of two
+BF16_KERNEL_NB = (128, 160, 544)
+# an f32 sum of `in` products in another order: 1e-5 of the result's largest
+# magnitude (the products themselves are exact in f32, on both sides)
+REASSOCIATION_RTOL = 1e-5
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("kernel", ["plain", "stacked", "grouped"])
+@pytest.mark.parametrize("rows", [9, 16, 32, 256])
+@pytest.mark.parametrize("nb", BF16_KERNEL_NB)
+def test_bf16_dequant_kernels_match_the_xla_path(nb, rows, kernel, dtype):
+    """The three kernels that share `_dequant_dot_accum`, at the rows above
+    the int8 arm's 8 and at a prompt's 256. f32: against `_quant_matmul_xla`
+    (the same f32 products). bf16: against the matmul of the same bf16
+    operands in f32 (`_quant_matmul_xla` rounds `code * f16 scale` once; the
+    kernel rounds the scale to bf16 first, as it always did). Both within
+    REASSOCIATION_RTOL: only the order of the f32 sums differs."""
+    from distributed_llama_tpu.ops.pallas_q40 import (
+        q40_matmul_pallas_grouped,
+        q40_matmul_pallas_stacked,
+    )
+    from distributed_llama_tpu.ops.quant import _quant_matmul_xla
+
+    out_f, in_f = 256, nb * 32
+    rng = np.random.default_rng(nb * 1000 + rows)
+    layers = [make_weight(rng, out_f, in_f) for _ in range(1 if kernel == "plain" else 2)]
+    x = jnp.asarray(rng.standard_normal((rows, in_f)), dtype)
+
+    def reference(x_rows, wt):
+        if dtype == jnp.float32:
+            return np.asarray(_quant_matmul_xla(x_rows, wt.q, wt.d, dtype))
+        return np.asarray(x_rows, np.float32) @ _parent_weight(wt, dtype)
+
+    if kernel == "plain":
+        got = q40_matmul_pallas(x, layers[0].q, layers[0].d, dtype=dtype, interpret=True)
+        want = reference(x, layers[0])
+    else:
+        qs = jnp.stack([w.q for w in layers])
+        ds = jnp.stack([w.d for w in layers])
+        if kernel == "stacked":
+            got = q40_matmul_pallas_stacked(
+                x, qs, ds, jnp.int32(1), dtype=dtype, interpret=True
+            )
+            want = reference(x, layers[1])
+        else:  # row blocks of 8 (the last padded), experts alternating
+            block_r = 8
+            pad = -rows % block_r
+            xp = jnp.concatenate([x, jnp.zeros((pad, in_f), dtype)])
+            experts = np.arange(xp.shape[0] // block_r) % 2
+            got = q40_matmul_pallas_grouped(
+                xp, qs, ds, jnp.asarray(experts, jnp.int32), block_r=block_r,
+                dtype=dtype, interpret=True,
+            )[:rows]
+            want = np.concatenate(
+                [
+                    reference(xp[i * block_r : (i + 1) * block_r], layers[e])
+                    for i, e in enumerate(experts)
+                ]
+            )[:rows]
+    got = np.asarray(got)
+    assert got.dtype == np.float32 and got.shape == (rows, out_f)
+    np.testing.assert_allclose(got, want, rtol=0, atol=REASSOCIATION_RTOL * np.abs(want).max())
+
+
+@pytest.mark.parametrize("name", sorted(MODEL_MATMULS))
+def test_bf16_tiles_from_rows_and_shape(name):
+    """`_bf16_tiles` at the benchmark's matmuls: a grid that covers the
+    weight exactly, under the VMEM budget at every served row count, the
+    whole contraction in one k step wherever a prompt's 256 rows let it
+    (every matmul but w2) and 512 lanes of it at 16 rows."""
+    from distributed_llama_tpu.ops.pallas_q40 import (
+        BF16_VMEM_CAP,
+        _bf16_tiles,
+        _bf16_vmem_need,
+    )
+
+    in_f, out_f = MODEL_MATMULS[name]
+    nb = in_f // 32
+    for b in (9, 16, 32, 64, 128, 256, 512, 1024):
+        tn, knb = _bf16_tiles(b, nb, out_f)
+        assert out_f % tn == 0 and tn % 128 == 0 and nb % knb == 0 and knb % 8 == 0
+        assert _bf16_vmem_need(b, tn, knb) <= BF16_VMEM_CAP, (b, tn, knb)
+        if not name.endswith("w2") and b <= 256:
+            assert knb == nb, (b, tn, knb)
+    lanes = 128 if name.endswith("wcls") else 512  # 151936 = 1187 x 128
+    assert _bf16_tiles(16, nb, out_f)[0] == (256 if name.endswith("w2") else lanes)
+    # fewer rows never take a smaller tile
+    sizes = [tn * knb for tn, knb in (_bf16_tiles(b, nb, out_f) for b in (16, 64, 256, 1024))]
+    assert sizes == sorted(sizes, reverse=True), sizes

@@ -26,6 +26,11 @@ MATMULS = {
     "wqkv": (DIM, 6144), "wo": (DIM, DIM), "w13": (DIM, 2 * FFN),
     "w2": (FFN, DIM), "wcls": (DIM, VOCAB),
 }
+# Qwen3-14B (perfbench/configs/qwen3-14b.json): ragged contractions, 40 layers
+MATMULS_14B = {
+    "wqkv": (5120, 7168), "wo": (5120, 5120), "w13": (5120, 2 * 17408),
+    "w2": (17408, 5120), "wcls": (5120, VOCAB),
+}
 HEADS, KV_HEADS, HEAD_DIM, PAGE = 32, 8, 128, 16
 
 
@@ -49,8 +54,8 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _matmul(kernel, rows, name, stacked=False, **kw):
-    in_f, out_f = MATMULS[name]
+def _matmul(kernel, rows, name, stacked=False, matmuls=MATMULS, **kw):
+    in_f, out_f = matmuls[name]
     lead = (LAYERS,) if stacked else ()
 
     def build(S):
@@ -107,6 +112,20 @@ CASES = {
     "stacked-bf16-64rows-w2": _matmul(
         pq.q40_matmul_pallas_stacked, 64, "w2", stacked=True, dtype=jnp.bfloat16
     ),
+    # the bf16-dequant kernels as the benchmark's cells serve them (PR 30: the
+    # tile deepens with few rows, `_bf16_tiles`): 16 decoding rows at Qwen3-8B,
+    # a prompt's 256 rows at both models; the layers' matmuls stacked, the head
+    # plain. A tile that overruns VMEM fails here and not on the chip
+    **{
+        f"{model}-bf16-{rows}rows-{n}": _matmul(
+            pq.q40_matmul_pallas if n == "wcls" else pq.q40_matmul_pallas_stacked,
+            rows, n, stacked=n != "wcls", matmuls=shapes, dtype=jnp.bfloat16,
+        )
+        for model, shapes, rows in (
+            ("8b", MATMULS, 16), ("8b", MATMULS, 256), ("14b", MATMULS_14B, 256)
+        )
+        for n in shapes
+    },
     "flash-t512-S4096": _flash(512, 4096),
     "flash-t64-S4096": _flash(64, 4096),
     # the int8 page-table kernel: solo / batch decode and verify blocks
