@@ -560,10 +560,10 @@ def contract_for(engine, entry: LadderEntry) -> ProgramContract:
 
 def _fused_kernel_active(engine) -> bool:
     """True when the int8 paged decode programs trace the fused
-    page-table-aware Pallas kernel (models/transformer.py
+    page-table-aware Pallas kernel (models/kv_arms.py
     _fused_paged_eligible at decode's t=1): pallas enabled for this config
     and uniform lane-aligned head grouping."""
-    from ..models.transformer import _pallas_enabled
+    from ..models.kv_arms import _pallas_enabled
 
     cfg = engine.cfg
     return (

@@ -651,7 +651,6 @@ def main(argv=None) -> int:
     paths = [Path(p) for p in args.paths] or [
         root / "distributed_llama_tpu",
         root / "scripts",
-        root / "bench.py",
         root / "launch.py",
     ]
     violations = lint_paths([p for p in paths if p.exists()], root=root)

@@ -1,0 +1,372 @@
+"""The KV-cache arms of the layer: write this step's k, v, then attend.
+
+`models/transformer._layer` projects q, k, v and hands them here. An arm
+owns one cache layout — where a row lands, which rows attention reads back —
+and nothing else of the layer. All arms share one signature,
+
+    arm(cfg, cache, addr, q, k, v, positions, pos_start) -> (a, cache)
+
+with `cache` the whole `KVCache` value (an int8 cache carries its scale
+sidecars inside it; a float cache's `None` scales flatten away) and `addr`
+saying how this call addresses it. `select_arm` is the only place that
+lists the arms: a new layout is one function here and one line there.
+
+An int8 cache differs from a float one only in `_stored` (quantize, and
+return the scales) and `_put` (the scales go to the same indices as their
+payloads); the index arithmetic of an arm is written once.
+"""
+
+from __future__ import annotations
+
+from typing import Any, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import gqa_attention
+from ..ops.attention import flash_attention_sp, gqa_attention_sp, scatter_cache_update_sp
+from ..ops.kv_quant import dequantize_kv, quantize_kv
+from ..ops.pallas_attention import (
+    flash_attention,
+    flash_attention_aligned,
+    paged_flash_attention,
+)
+from ..ops.quant import _use_pallas
+from .params import KVCache
+
+
+class CacheAddr(NamedTuple):
+    """How one layer call addresses the cache it is handed."""
+
+    layer: Any = None  # scalar int32: this layer's index along the cache's
+    # leading axis — the FULL [L, b, S, h, d] stack (or [L, P, ps, h, d] page
+    # pool) rides a scan's CARRY and the layer's rows update in place (XLA
+    # keeps loop-carried buffers in place under a dynamic-update). None: the
+    # cache is this layer's own [b, S, h, d] slice, arriving via a scan's xs
+    # and leaving via its stacked ys — which REWRITES the whole allocation
+    # every call (measured: ~0.64 ms/token on a 134 MB cache).
+    kv_len: int | None = None  # static: attention reads only the first
+    # kv_len positions (a slice that fuses into the attention ops). The
+    # engine picks the power-of-two bucket covering pos_start + t, so decode
+    # reads scale with the position, not the allocated cache. None = all.
+    page_table: Any = None  # [b, max_slots] int32 traced array (paged
+    # layout, runtime/paged_kv.py): writes scatter through the table and
+    # reads gather the first kv_len/page_size pages per row. -1 entries are
+    # unmapped: their writes DROP, their reads clamp to page 0 and are
+    # causally masked. None = contiguous layout.
+    page_size: int | None = None  # static page length in tokens (paged only)
+    sp_ctx: Any = None  # (axis_name, shard_offset) when the cache's seq
+    # axis is sharded under shard_map (long-context sequence parallelism)
+
+
+def select_arm(addr: CacheAddr):
+    """The arm for what `addr` carries — the one list of cache layouts."""
+    if addr.page_table is not None:
+        return paged_arm
+    if addr.sp_ctx is not None:
+        return sp_arm
+    return stacked_arm if addr.layer is not None else unstacked_arm
+
+
+def _pallas_enabled(cfg) -> bool:
+    """Single owner of the pallas-enable resolution for trace-time path
+    choices: cfg.use_pallas, auto-resolved by backend when None, with
+    interpret mode forcing on (it exists to exercise the kernel paths)."""
+    if cfg.pallas_interpret:
+        return True
+    return cfg.use_pallas if cfg.use_pallas is not None else _use_pallas()
+
+
+def _attention_auto(cfg, q, k_view, v_view, positions, pos_start):
+    """Pick the attention implementation for this (static) shape:
+
+    * prefill-sized q on a bf16 cache with the Pallas path enabled -> blocked
+      flash kernel (ops/pallas_attention.py) — no O(t*S) score tensor;
+    * otherwise (decode t=1, f32 parity path, unaligned shapes) -> the XLA
+      whole-cache einsum (ops/attention.py), whose reads the engine already
+      bounds with the kv_len position bucket.
+    """
+    t = q.shape[1]
+    # interpret mode rides in the (static, hashable) config, so the jit
+    # cache can never replay a program traced in the other mode. Per-row
+    # pos_start (vector) only occurs at decode t=1, which takes the einsum
+    # path anyway — the flash kernel's causal math assumes one scalar chunk
+    # start, so it is gated to scalar pos_start.
+    if (
+        _pallas_enabled(cfg)
+        and jnp.ndim(pos_start) == 0
+        and k_view.dtype == jnp.bfloat16
+        and flash_attention_aligned(q, k_view, t)
+    ):
+        return flash_attention(
+            q, k_view, v_view, pos_start, interpret=cfg.pallas_interpret
+        )
+    return gqa_attention(q, k_view, v_view, positions)
+
+
+def _fused_paged_eligible(cfg, q, t: int, ps: int) -> bool:
+    """Gate for the fused page-table-aware int8 decode kernel: Pallas
+    enabled, decode-sized q blocks (one page of queries at most — solo
+    decode t=1, batch decode t=1, speculative verify t=k+1 all qualify;
+    prefill chunks take the gather+dequant view, which stays
+    flash-eligible), uniform head grouping, and — where the kernel is
+    compiled, not interpreted — a pool whose trailing (n_kv, head_dim) axes
+    fill whole int8 (8, 128) tiles. The TPU's compiler stores only such a
+    pool in the row-major order the kernel's page blocks need; for any other
+    shape it copies the WHOLE pool at every call (seen compiling hd 64 and
+    n_kv 2/4 for v5e), which the gather arm never does."""
+    n_heads, head_dim = q.shape[2], q.shape[3]
+    return (
+        _pallas_enabled(cfg)
+        and t <= ps
+        and n_heads % cfg.n_kv_heads == 0
+        and head_dim % 8 == 0
+        and (
+            cfg.pallas_interpret
+            or (cfg.n_kv_heads % 8 == 0 and head_dim % 128 == 0)
+        )
+    )
+
+
+# -- writes -----------------------------------------------------------------
+# `put(buffer, rows)` is an arm's one statement of where rows land; these
+# apply it to every buffer of the cache. The programs are pinned equation for
+# equation (analysis/golden/), so each helper keeps the order its callers
+# were compiled in: `_stored` + `_put` prepare both payloads and then write
+# k, v, k_scale, v_scale; `_write` casts each float payload as it is put.
+
+
+def _stored(cache: KVCache, k, v):
+    """This step's k, v as `cache` stores them, with their scales:
+    (k, v, k_scale, v_scale). int8: QUANTIZE-ON-WRITE (ops/kv_quant.py);
+    float: a cast, and no scales."""
+    if cache.quantized:
+        kq, ks = quantize_kv(k)
+        vq, vs = quantize_kv(v)
+        return kq, vq, ks, vs
+    return k.astype(cache.k.dtype), v.astype(cache.v.dtype), None, None
+
+
+def _put(cache: KVCache, stored, put) -> KVCache:
+    """`put` the stored rows into k and v and — int8 — their scales into the
+    sidecars, at the identical indices (they drop with their payloads)."""
+    kw, vw, ks, vs = stored
+    new_k, new_v = put(cache.k, kw), put(cache.v, vw)
+    if ks is None:
+        return KVCache(k=new_k, v=new_v)
+    return KVCache(
+        k=new_k, v=new_v, k_scale=put(cache.k_scale, ks), v_scale=put(cache.v_scale, vs)
+    )
+
+
+def _write(cache: KVCache, k, v, put) -> KVCache:
+    """`_put(cache, _stored(cache, k, v), put)`, but a float cache's k is
+    cast and put before v is cast."""
+    if cache.quantized:
+        return _put(cache, _stored(cache, k, v), put)
+    return KVCache(
+        k=put(cache.k, k.astype(cache.k.dtype)),
+        v=put(cache.v, v.astype(cache.v.dtype)),
+    )
+
+
+def _float_only(cache: KVCache, arm: str) -> None:
+    if cache.quantized:
+        raise NotImplementedError(
+            "int8 KV is supported on the stacked-contiguous and paged arms "
+            f"only, not the {arm} arm (the engine forces a float cache on "
+            "sp/pipeline meshes)"
+        )
+
+
+def _layer_view(buf, layer, b: int, n: int):
+    """[b, n, ...]: the first n positions of `layer`'s rows in a stacked
+    buffer — a bucketed dynamic-slice, the only cache traffic of a stacked
+    arm besides the row write."""
+    return jax.lax.dynamic_slice(
+        buf, (layer,) + (0,) * (buf.ndim - 1), (1, b, n) + buf.shape[3:]
+    )[0]
+
+
+# -- the arms ---------------------------------------------------------------
+
+
+def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
+    """Paged layout (runtime/paged_kv.py): the cache stacks are page POOLS
+    [L, P, ps, h, d]; logical positions map through the per-row page table.
+    Same write-before-read/causal-mask invariants as contiguous — outputs
+    are token-identical by construction."""
+    li, ps, page_table = addr.layer, addr.page_size, addr.page_table
+    b, t = q.shape[:2]
+    n_pool = cache.k.shape[1]
+    max_slots = page_table.shape[1]
+    # write: scatter each new row to (table[pos // ps], pos % ps).
+    # Invalid writes — parked rows at/past seq_len, or an unmapped
+    # (-1) table entry — remap to pairwise-distinct page indices past
+    # the pool and DROP (colliding dropped indices would be undefined
+    # scatter behavior, the same discipline as scatter_cache_update_sp)
+    slot = positions // ps
+    offset = positions % ps
+    safe_slot = jnp.clip(slot, 0, max_slots - 1)
+    phys = jnp.take_along_axis(page_table, safe_slot, axis=1)  # [b, t]
+    invalid = (positions >= cfg.seq_len) | (slot >= max_slots) | (phys < 0)
+    b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+    col = jnp.arange(t, dtype=jnp.int32)[None, :]
+    phys = jnp.where(invalid, n_pool + b_idx * t + col, phys)
+    cache = _write(
+        cache, k, v,
+        lambda buf, rows: buf.at[li, phys, offset].set(
+            rows, mode="drop", unique_indices=True
+        ),
+    )
+    # read: the first kv_len/ps page entries per row
+    n_read = max_slots if addr.kv_len is None else min(-(-addr.kv_len // ps), max_slots)
+    if cache.quantized and _fused_paged_eligible(cfg, q, t, ps):
+        # int8 decode: the FUSED kernel reads the pool through the page
+        # table (scalar-prefetch operand) and dequantizes in VMEM — no
+        # materialized page gather, no dequantized KV view in HBM
+        # (ops/pallas_attention.paged_flash_attention)
+        a = paged_flash_attention(
+            q, cache.k, cache.v, cache.k_scale, cache.v_scale,
+            jnp.asarray(li, jnp.int32), positions[:, 0], page_table,
+            n_read=n_read, page_size=ps,
+            interpret=cfg.pallas_interpret,
+        )
+        return a, cache
+    # gather them into the contiguous [b, n*ps, h, d] view the attention
+    # math consumes — this gather is the layout's whole read cost (the cost
+    # model counts it; analysis/profiling.py). Unmapped entries clamp to
+    # page 0: garbage, causally masked like any junk past a row's pos.
+    pages = jnp.maximum(
+        jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0
+    )  # [b, n_read]
+    k_view = cache.k[li, pages]
+    v_view = cache.v[li, pages]
+    if cache.quantized:
+        # int8 prefill / no-Pallas fallback: dequantize the gathered
+        # view to the compute dtype (prefill stays flash-eligible)
+        k_view = dequantize_kv(k_view, cache.k_scale[li, pages], cfg.dtype)
+        v_view = dequantize_kv(v_view, cache.v_scale[li, pages], cfg.dtype)
+    k_view = k_view.reshape(b, n_read * ps, -1, cfg.head_dim)
+    v_view = v_view.reshape(b, n_read * ps, -1, cfg.head_dim)
+    return _attention_auto(cfg, q, k_view, v_view, positions, pos_start), cache
+
+
+def stacked_arm(cfg, cache, addr, q, k, v, positions, pos_start):
+    """Contiguous [L, b, S, h, d] stack riding the carry: in-place update of
+    this layer's rows, then attention over a bucketed dynamic-slice view."""
+    li = addr.layer
+    b = q.shape[0]
+    S = cache.k.shape[2]
+    stored = _stored(cache, k, v)
+    if jnp.ndim(pos_start) == 0:
+
+        def put(buf, rows):
+            start = (li, 0, pos_start) + (0,) * (rows.ndim - 2)
+            return jax.lax.dynamic_update_slice(buf, rows[None], start)
+
+    else:
+        # per-row positions: OOB-DROP scatter (see unstacked_arm for why
+        # drop is load-bearing)
+        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+
+        def put(buf, rows):
+            return buf.at[li, b_idx, positions].set(
+                rows, mode="drop", unique_indices=True
+            )
+
+    cache = _put(cache, stored, put)
+    view_len = min(addr.kv_len, S) if addr.kv_len is not None else S
+    k_view = _layer_view(cache.k, li, b, view_len)
+    v_view = _layer_view(cache.v, li, b, view_len)
+    if cache.quantized:
+        # dequantize the bucketed read view to the compute dtype
+        # (flash stays eligible on the bf16 path)
+        ks_view = _layer_view(cache.k_scale, li, b, view_len)
+        vs_view = _layer_view(cache.v_scale, li, b, view_len)
+        k_view = dequantize_kv(k_view, ks_view, cfg.dtype)
+        v_view = dequantize_kv(v_view, vs_view, cfg.dtype)
+    return _attention_auto(cfg, q, k_view, v_view, positions, pos_start), cache
+
+
+def unstacked_arm(cfg, cache, addr, q, k, v, positions, pos_start):
+    """This layer's own [b, S, h, d] slice (a contiguous mesh prefill's scan
+    xs/ys, parallel/pipeline.py): float caches only."""
+    _float_only(cache, "per-layer contiguous")
+    if jnp.ndim(pos_start) == 0:
+
+        def put(buf, rows):
+            return jax.lax.dynamic_update_slice_in_dim(buf, rows, pos_start, axis=1)
+
+    else:
+        # per-row sequences (independent prompts per batch row):
+        # each row writes at its own positions — a scatter with
+        # OOB-DROP semantics, not a clamping dynamic_update_slice.
+        # The drop is load-bearing: a row whose positions reach
+        # seq_len writes NOTHING, so finished rows can keep riding
+        # decode chunks (generate_batch) and rolling admission can
+        # "park" a row at pos_start = seq_len, both without
+        # disturbing the row's live cache tail. Indices are
+        # pos_start + arange per row — strictly increasing, hence
+        # unique; all are >= 0 so none wrap before the drop applies.
+        b_idx = jnp.arange(q.shape[0], dtype=jnp.int32)[:, None]
+
+        def put(buf, rows):
+            return buf.at[b_idx, positions].set(rows, mode="drop", unique_indices=True)
+
+    cache = _write(cache, k, v, put)
+    k_view, v_view = cache.k, cache.v
+    if addr.kv_len is not None and addr.kv_len < cache.k.shape[1]:
+        k_view = jax.lax.slice_in_dim(k_view, 0, addr.kv_len, axis=1)
+        v_view = jax.lax.slice_in_dim(v_view, 0, addr.kv_len, axis=1)
+    return _attention_auto(cfg, q, k_view, v_view, positions, pos_start), cache
+
+
+def sp_arm(cfg, cache, addr, q, k, v, positions, pos_start):
+    """Sequence-parallel: the cache's seq axis is sharded under shard_map, so
+    writes are boundary-safe scatters and attention combines partial
+    online-softmax stats across the axis (ops/attention.py). Stacked or
+    per-layer (`addr.layer` None); float caches only."""
+    _float_only(cache, "sequence-parallel")
+    axis_name, shard_offset = addr.sp_ctx
+    li = addr.layer
+    b, t = q.shape[:2]
+    cache = KVCache(
+        k=scatter_cache_update_sp(cache.k, k, positions, shard_offset, layer=li),
+        v=scatter_cache_update_sp(cache.v, v, positions, shard_offset, layer=li),
+    )
+    # per-shard KV read bound: kv_len is the GLOBAL position bucket; a
+    # static local bound of min(kv_len, local_seq) is EXACT for every
+    # shard — rows past it are either beyond the bucket (shard 0) or at
+    # global positions >= kv_len (later shards), i.e. future and fully
+    # masked either way. SPMD forbids per-shard static shapes, so this
+    # uniform bound is the tightest static slice available; it caps the
+    # worst case at sp * min(kv_len, local_seq) reads instead of the
+    # full allocation every token (the round-2 behavior).
+    local_seq = cache.k.shape[1 if li is None else 2]
+    local_kv = min(addr.kv_len, local_seq) if addr.kv_len is not None else local_seq
+    k_view, v_view = cache.k, cache.v
+    if li is not None:
+        k_view = _layer_view(k_view, li, b, local_kv)
+        v_view = _layer_view(v_view, li, b, local_kv)
+    elif local_kv < local_seq:
+        k_view = jax.lax.slice_in_dim(k_view, 0, local_kv, axis=1)
+        v_view = jax.lax.slice_in_dim(v_view, 0, local_kv, axis=1)
+    if (
+        _pallas_enabled(cfg)
+        and jnp.ndim(pos_start) == 0  # flash's causal math assumes one
+        # scalar chunk start (same gate as _attention_auto); per-row
+        # prefill chunks take the masked einsum below
+        and k_view.dtype == jnp.bfloat16
+        and flash_attention_aligned(q, k_view, t)
+    ):
+        # prefill-sized chunks: blocked flash over the local shard with
+        # cross-shard online-softmax combine — the long-context sp path
+        # runs the same kernel as the single-chip path
+        a = flash_attention_sp(
+            q, k_view, v_view, pos_start, shard_offset, axis_name,
+            interpret=cfg.pallas_interpret,
+        )
+    else:
+        a = gqa_attention_sp(q, k_view, v_view, positions, shard_offset, axis_name)
+    return a, cache

@@ -28,11 +28,12 @@ import jax
 import jax.numpy as jnp
 
 from ..formats.mfile import HiddenAct
-from ..ops import gqa_attention, moe_router, rms_norm
+from ..ops import moe_router, rms_norm
 from ..ops.activations import gelu, silu
 from ..ops.quant import QuantTensor, dequantize_t, quant_matmul, quantize_q80_activations
 from ..ops.rope import RopeTables, apply_rope
 from .config import ModelConfig
+from .kv_arms import CacheAddr, _pallas_enabled, select_arm
 from .params import KVCache, LayerParams, ModelParams
 
 
@@ -119,70 +120,6 @@ def _expert_matmul(x: jnp.ndarray, w: Any, dtype, q80: bool = False) -> jnp.ndar
         eq, x.astype(dtype), wd, preferred_element_type=jnp.float32, precision=precision
     )
     return y.astype(x.dtype)
-
-
-def _pallas_enabled(cfg) -> bool:
-    """Single owner of the pallas-enable resolution for trace-time path
-    choices: cfg.use_pallas, auto-resolved by backend when None, with
-    interpret mode forcing on (it exists to exercise the kernel paths)."""
-    from ..ops.quant import _use_pallas
-
-    if cfg.pallas_interpret:
-        return True
-    return cfg.use_pallas if cfg.use_pallas is not None else _use_pallas()
-
-
-def _attention_auto(cfg, q, k_view, v_view, positions, pos_start):
-    """Pick the attention implementation for this (static) shape:
-
-    * prefill-sized q on a bf16 cache with the Pallas path enabled -> blocked
-      flash kernel (ops/pallas_attention.py) — no O(t*S) score tensor;
-    * otherwise (decode t=1, f32 parity path, unaligned shapes) -> the XLA
-      whole-cache einsum (ops/attention.py), whose reads the engine already
-      bounds with the kv_len position bucket.
-    """
-    from ..ops.pallas_attention import flash_attention, flash_attention_aligned
-
-    t = q.shape[1]
-    # interpret mode rides in the (static, hashable) config, so the jit
-    # cache can never replay a program traced in the other mode. Per-row
-    # pos_start (vector) only occurs at decode t=1, which takes the einsum
-    # path anyway — the flash kernel's causal math assumes one scalar chunk
-    # start, so it is gated to scalar pos_start.
-    if (
-        _pallas_enabled(cfg)
-        and jnp.ndim(pos_start) == 0
-        and k_view.dtype == jnp.bfloat16
-        and flash_attention_aligned(q, k_view, t)
-    ):
-        return flash_attention(
-            q, k_view, v_view, pos_start, interpret=cfg.pallas_interpret
-        )
-    return gqa_attention(q, k_view, v_view, positions)
-
-
-def _fused_paged_eligible(cfg, q, t: int, ps: int) -> bool:
-    """Gate for the fused page-table-aware int8 decode kernel: Pallas
-    enabled, decode-sized q blocks (one page of queries at most — solo
-    decode t=1, batch decode t=1, speculative verify t=k+1 all qualify;
-    prefill chunks take the gather+dequant view, which stays
-    flash-eligible), uniform head grouping, and — where the kernel is
-    compiled, not interpreted — a pool whose trailing (n_kv, head_dim) axes
-    fill whole int8 (8, 128) tiles. The TPU's compiler stores only such a
-    pool in the row-major order the kernel's page blocks need; for any other
-    shape it copies the WHOLE pool at every call (seen compiling hd 64 and
-    n_kv 2/4 for v5e), which the gather arm never does."""
-    n_heads, head_dim = q.shape[2], q.shape[3]
-    return (
-        _pallas_enabled(cfg)
-        and t <= ps
-        and n_heads % cfg.n_kv_heads == 0
-        and head_dim % 8 == 0
-        and (
-            cfg.pallas_interpret
-            or (cfg.n_kv_heads % 8 == 0 and head_dim % 128 == 0)
-        )
-    )
 
 
 def _n_local_experts(w: Any, stacked: bool = False) -> int:
@@ -316,75 +253,11 @@ def _moe_decode_i8(cfg, y, lp, layer, idx, wts):
     return out.reshape(*y.shape[:2], cfg.dim)
 
 
-def _layer(
-    cfg: ModelConfig,
-    rope: RopeTables,
-    x: jnp.ndarray,  # [b, t, dim] residual stream (f32)
-    positions: jnp.ndarray,  # [b, t] int32
-    pos_start: jnp.ndarray,  # scalar int32 — cache write offset
-    lp: LayerParams,
-    k_cache: jnp.ndarray,  # [b, seq, n_kv, head_dim]
-    v_cache: jnp.ndarray,
-    reduce_fn=None,  # TP partial-sum reduction (shard_map path): applied to
-    # the attention and ffn output projections. None under GSPMD — XLA
-    # inserts the psum itself from the shardings (the reference's explicit
-    # SYNC_NODE_SLICES after att/ff, src/llm.cpp:418,569).
-    sp_ctx=None,  # (axis_name, shard_offset) when the cache's seq axis is
-    # sharded under shard_map (long-context sequence parallelism): cache
-    # writes become boundary-safe scatters and attention combines partial
-    # online-softmax stats across the axis (ops/attention.py gqa_attention_sp)
-    ep_axis=None,  # mesh axis name when the MoE expert stacks are sharded
-    # under shard_map (expert parallelism — see _moe_ffn); attention weights
-    # are replicated over this axis and the MoE output psums over it
-    layer_idx=None,  # scalar int32 when `lp` holds ALL layers stacked: the
-    # big matmuls select the layer inside the Pallas kernel (no weight-slice
-    # copy — see quant_matmul) and the small per-layer tensors are sliced
-    # here. None = `lp` is already a single layer's weights.
-    kv_len=None,  # static int: attention reads only cache[:, :kv_len] (a
-    # static slice that fuses into the attention ops). The engine picks the
-    # power-of-two bucket covering pos_start + t, so decode reads scale with
-    # the position, not the allocated cache (full-cache reads made 32k-seq
-    # decode pay for the whole cache every token). None = full cache.
-    stacked_cache=False,  # True: k_cache/v_cache are the FULL [L, b, S, h,
-    # d] stacks riding the layer scan's CARRY, and this layer's rows are
-    # updated in place at index `cache_layer` (XLA keeps loop-carried
-    # buffers in place under a dynamic-update). False (the legacy
-    # threading): the per-layer slices arrive via the scan's xs and leave
-    # via its stacked ys — which REWRITES the whole allocation every call
-    # (measured: the scan ys stacking cost ~0.64 ms/token on a 134 MB
-    # cache, the round-3 small-model/32k per-token floor).
-    cache_layer=None,  # stacked_cache index; defaults to layer_idx (the
-    # pipeline path passes per-layer weight slices — layer_idx None — but
-    # still carries a stacked LOCAL cache, so the two indices differ there)
-    page_table=None,  # [b, max_slots] int32 traced array (paged KV layout,
-    # runtime/paged_kv.py): k_cache/v_cache are then the [L, n_pages,
-    # page_size, h, d] page POOLS, writes scatter through the table and
-    # attention reads gather the first kv_len/page_size pages per row. -1
-    # entries are unmapped: their writes DROP, their reads clamp to page 0
-    # and are causally masked. None = contiguous layout (unchanged).
-    page_size=None,  # static page length in tokens (paged layout only)
-    k_scale=None,  # int8 KV arm (cfg.kv_quantized): the f32 per-(token,
-    # head) scale sidecars riding the scan carry next to k_cache/v_cache
-    # ([L, P, ps, h] paged / [L, b, S, h] contiguous). None on float caches
-    # — every branch below is then BYTE-IDENTICAL to the pre-quantization
-    # graph (the bf16 A/B bit-identity contract). When present, writes
-    # quantize (ops/kv_quant.py) and the return grows to a 5-tuple.
-    v_scale=None,
-):
-    if reduce_fn is None:
-        reduce_fn = lambda z: z
-    if cache_layer is None:
-        cache_layer = layer_idx
-    if k_scale is not None and (sp_ctx is not None or not (stacked_cache or page_table is not None)):
-        raise NotImplementedError(
-            "int8 KV is supported on the stacked-contiguous and paged arms "
-            "only (the engine forces a float cache on sp/pipeline meshes)"
-        )
-    b, t, _ = x.shape
+def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp: LayerParams, positions, layer_idx):
+    """q, k, v [b, t, heads, head_dim] of the normed activation y: the fused
+    or the three projections, the Qwen3 head norm, RoPE."""
+    b, t, _ = y.shape
     q80 = cfg.q80_activations
-
-    # --- attention block ---
-    y = rms_norm(x, _sel_layer(lp.norm0, layer_idx), cfg.norm_epsilon)
     # head counts come from the weight shapes, not cfg: under shard_map the
     # local shard holds n_heads/tp heads (the reference's sliceMultiHeadAtt,
     # src/nn/nn-core.cpp:280-287)
@@ -415,255 +288,53 @@ def _layer(
 
     q = apply_rope(q, rope, positions, cfg.rope_type)
     k = apply_rope(k, rope, positions, cfg.rope_type)
+    return q, k, v
 
-    if page_table is not None:
-        # -- paged KV layout (runtime/paged_kv.py): the cache stacks are
-        # page POOLS [L, P, ps, h, d]; logical positions map through the
-        # per-row page table. Same write-before-read/causal-mask invariants
-        # as contiguous — outputs are token-identical by construction.
-        li = cache_layer
-        ps = page_size
-        n_pool = k_cache.shape[1]
-        max_slots = page_table.shape[1]
-        # write: scatter each new row to (table[pos // ps], pos % ps).
-        # Invalid writes — parked rows at/past seq_len, or an unmapped
-        # (-1) table entry — remap to pairwise-distinct page indices past
-        # the pool and DROP (colliding dropped indices would be undefined
-        # scatter behavior, the same discipline as scatter_cache_update_sp)
-        slot = positions // ps
-        offset = positions % ps
-        safe_slot = jnp.clip(slot, 0, max_slots - 1)
-        phys = jnp.take_along_axis(page_table, safe_slot, axis=1)  # [b, t]
-        invalid = (positions >= cfg.seq_len) | (slot >= max_slots) | (phys < 0)
-        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        col = jnp.arange(t, dtype=jnp.int32)[None, :]
-        phys = jnp.where(invalid, n_pool + b_idx * t + col, phys)
-        if k_scale is not None:
-            # int8 pool: QUANTIZE-ON-WRITE, fused into the same scatter —
-            # the scale sidecars take the identical (phys, offset) indices
-            # and drop with their payloads
-            from ..ops.kv_quant import quantize_kv
 
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            k_cache = k_cache.at[li, phys, offset].set(
-                kq, mode="drop", unique_indices=True
-            )
-            v_cache = v_cache.at[li, phys, offset].set(
-                vq, mode="drop", unique_indices=True
-            )
-            k_scale = k_scale.at[li, phys, offset].set(
-                ks, mode="drop", unique_indices=True
-            )
-            v_scale = v_scale.at[li, phys, offset].set(
-                vs, mode="drop", unique_indices=True
-            )
-        else:
-            k_cache = k_cache.at[li, phys, offset].set(
-                k.astype(k_cache.dtype), mode="drop", unique_indices=True
-            )
-            v_cache = v_cache.at[li, phys, offset].set(
-                v.astype(v_cache.dtype), mode="drop", unique_indices=True
-            )
-        # read: gather the first kv_len/ps page entries per row into the
-        # contiguous [b, n*ps, h, d] view the attention math consumes —
-        # this gather is the layout's whole read cost (the cost model
-        # counts it; analysis/profiling.py). Unmapped entries clamp to
-        # page 0: garbage, causally masked like any junk past a row's pos.
-        n_read = max_slots if kv_len is None else min(-(-kv_len // ps), max_slots)
-        if k_scale is not None and _fused_paged_eligible(cfg, q, t, ps):
-            # int8 decode: the FUSED kernel reads the pool through the page
-            # table (scalar-prefetch operand) and dequantizes in VMEM — no
-            # materialized page gather, no dequantized KV view in HBM
-            # (ops/pallas_attention.paged_flash_attention)
-            from ..ops.pallas_attention import paged_flash_attention
+def _ffn(cfg: ModelConfig, y, lp: LayerParams, layer_idx, ep_axis):
+    """The feed-forward of the normed activation y: experts or dense."""
+    if cfg.is_moe:
+        return _moe_ffn(cfg, y, lp, layer_idx, ep_axis=ep_axis)
+    return _dense_ffn(cfg, y, lp, layer_idx)
 
-            a = paged_flash_attention(
-                q, k_cache, v_cache, k_scale, v_scale,
-                jnp.asarray(li, jnp.int32), positions[:, 0], page_table,
-                n_read=n_read, page_size=ps,
-                interpret=cfg.pallas_interpret,
-            )
-        else:
-            pages = jnp.maximum(
-                jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0
-            )  # [b, n_read]
-            k_view = k_cache[li, pages]
-            v_view = v_cache[li, pages]
-            if k_scale is not None:
-                # int8 prefill / no-Pallas fallback: dequantize the gathered
-                # view to the compute dtype (prefill stays flash-eligible)
-                from ..ops.kv_quant import dequantize_kv
 
-                k_view = dequantize_kv(k_view, k_scale[li, pages], cfg.dtype)
-                v_view = dequantize_kv(v_view, v_scale[li, pages], cfg.dtype)
-            k_view = k_view.reshape(b, n_read * ps, -1, cfg.head_dim)
-            v_view = v_view.reshape(b, n_read * ps, -1, cfg.head_dim)
-            a = _attention_auto(cfg, q, k_view, v_view, positions, pos_start)
-    elif sp_ctx is None:
-        if stacked_cache:
-            # in-place update of this layer's rows inside the full carried
-            # stack; attention then reads a bucketed dynamic-slice view. The
-            # slice is the only cache traffic besides the row write — the
-            # legacy xs/ys threading instead re-stacked the WHOLE allocation
-            # per call.
-            li = cache_layer
-            S = k_cache.shape[2]
-            nh, hd = k_cache.shape[3], k_cache.shape[4]
-            if k_scale is not None:
-                # int8 contiguous arm: quantize-on-write into the stacked
-                # slab, scale sidecars at the same (layer, row, pos) indices
-                from ..ops.kv_quant import quantize_kv
+def _layer(
+    cfg: ModelConfig,
+    rope: RopeTables,
+    x: jnp.ndarray,  # [b, t, dim] residual stream (f32)
+    positions: jnp.ndarray,  # [b, t] int32
+    pos_start: jnp.ndarray,  # int32 cache write offset: scalar, or [b] per row
+    lp: LayerParams,
+    cache: KVCache,  # the layout `addr` describes, float or int8
+    addr: CacheAddr,
+    layer_idx=None,  # scalar int32 when `lp` holds ALL layers stacked: the
+    # big matmuls select the layer inside the Pallas kernel (no weight-slice
+    # copy — see quant_matmul) and the small per-layer tensors are sliced
+    # here. None = `lp` is already a single layer's weights.
+    reduce_fn=None,  # TP partial-sum reduction (shard_map path): applied to
+    # the attention and ffn output projections. None under GSPMD — XLA
+    # inserts the psum itself from the shardings (the reference's explicit
+    # SYNC_NODE_SLICES after att/ff, src/llm.cpp:418,569).
+    ep_axis=None,  # mesh axis name when the MoE expert stacks are sharded
+    # under shard_map (expert parallelism — see _moe_ffn); attention weights
+    # are replicated over this axis and the MoE output psums over it
+) -> tuple[jnp.ndarray, KVCache]:
+    if reduce_fn is None:
+        reduce_fn = lambda z: z
+    b, t, _ = x.shape
 
-                kw, ks = quantize_kv(k)
-                vw, vs = quantize_kv(v)
-            else:
-                kw, vw = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
-                ks = vs = None
-            if jnp.ndim(pos_start) == 0:
-                start = (li, 0, pos_start, 0, 0)
-                k_cache = jax.lax.dynamic_update_slice(k_cache, kw[None], start)
-                v_cache = jax.lax.dynamic_update_slice(v_cache, vw[None], start)
-                if k_scale is not None:
-                    sstart = (li, 0, pos_start, 0)
-                    k_scale = jax.lax.dynamic_update_slice(k_scale, ks[None], sstart)
-                    v_scale = jax.lax.dynamic_update_slice(v_scale, vs[None], sstart)
-            else:
-                # per-row positions: OOB-DROP scatter (see the unstacked
-                # branch below for why drop is load-bearing)
-                b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-                k_cache = k_cache.at[li, b_idx, positions].set(
-                    kw, mode="drop", unique_indices=True
-                )
-                v_cache = v_cache.at[li, b_idx, positions].set(
-                    vw, mode="drop", unique_indices=True
-                )
-                if k_scale is not None:
-                    k_scale = k_scale.at[li, b_idx, positions].set(
-                        ks, mode="drop", unique_indices=True
-                    )
-                    v_scale = v_scale.at[li, b_idx, positions].set(
-                        vs, mode="drop", unique_indices=True
-                    )
-            view_len = min(kv_len, S) if kv_len is not None else S
-            k_view = jax.lax.dynamic_slice(
-                k_cache, (li, 0, 0, 0, 0), (1, b, view_len, nh, hd)
-            )[0]
-            v_view = jax.lax.dynamic_slice(
-                v_cache, (li, 0, 0, 0, 0), (1, b, view_len, nh, hd)
-            )[0]
-            if k_scale is not None:
-                # dequantize the bucketed read view to the compute dtype
-                # (flash stays eligible on the bf16 path)
-                from ..ops.kv_quant import dequantize_kv
-
-                ks_view = jax.lax.dynamic_slice(
-                    k_scale, (li, 0, 0, 0), (1, b, view_len, nh)
-                )[0]
-                vs_view = jax.lax.dynamic_slice(
-                    v_scale, (li, 0, 0, 0), (1, b, view_len, nh)
-                )[0]
-                k_view = dequantize_kv(k_view, ks_view, cfg.dtype)
-                v_view = dequantize_kv(v_view, vs_view, cfg.dtype)
-        else:
-            if jnp.ndim(pos_start) == 0:
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    k_cache, k.astype(k_cache.dtype), pos_start, axis=1
-                )
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    v_cache, v.astype(v_cache.dtype), pos_start, axis=1
-                )
-            else:
-                # per-row sequences (independent prompts per batch row):
-                # each row writes at its own positions — a scatter with
-                # OOB-DROP semantics, not a clamping dynamic_update_slice.
-                # The drop is load-bearing: a row whose positions reach
-                # seq_len writes NOTHING, so finished rows can keep riding
-                # decode chunks (generate_batch) and rolling admission can
-                # "park" a row at pos_start = seq_len, both without
-                # disturbing the row's live cache tail. Indices are
-                # pos_start + arange per row — strictly increasing, hence
-                # unique; all are >= 0 so none wrap before the drop applies.
-                b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-                k_cache = k_cache.at[b_idx, positions].set(
-                    k.astype(k_cache.dtype), mode="drop", unique_indices=True
-                )
-                v_cache = v_cache.at[b_idx, positions].set(
-                    v.astype(v_cache.dtype), mode="drop", unique_indices=True
-                )
-            if kv_len is not None and kv_len < k_cache.shape[1]:
-                k_view = jax.lax.slice_in_dim(k_cache, 0, kv_len, axis=1)
-                v_view = jax.lax.slice_in_dim(v_cache, 0, kv_len, axis=1)
-            else:
-                k_view, v_view = k_cache, v_cache
-        a = _attention_auto(cfg, q, k_view, v_view, positions, pos_start)
-    else:
-        from ..ops.attention import (
-            flash_attention_sp,
-            gqa_attention_sp,
-            scatter_cache_update_sp,
-        )
-        from ..ops.pallas_attention import flash_attention_aligned
-
-        axis_name, shard_offset = sp_ctx
-        li = cache_layer if stacked_cache else None
-        k_cache = scatter_cache_update_sp(k_cache, k, positions, shard_offset, layer=li)
-        v_cache = scatter_cache_update_sp(v_cache, v, positions, shard_offset, layer=li)
-        # per-shard KV read bound: kv_len is the GLOBAL position bucket; a
-        # static local bound of min(kv_len, local_seq) is EXACT for every
-        # shard — rows past it are either beyond the bucket (shard 0) or at
-        # global positions >= kv_len (later shards), i.e. future and fully
-        # masked either way. SPMD forbids per-shard static shapes, so this
-        # uniform bound is the tightest static slice available; it caps the
-        # worst case at sp * min(kv_len, local_seq) reads instead of the
-        # full allocation every token (the round-2 behavior).
-        local_seq = k_cache.shape[2] if stacked_cache else k_cache.shape[1]
-        local_kv = min(kv_len, local_seq) if kv_len is not None else local_seq
-        if stacked_cache:
-            nh, hd = k_cache.shape[3], k_cache.shape[4]
-            k_view = jax.lax.dynamic_slice(
-                k_cache, (li, 0, 0, 0, 0), (1, b, local_kv, nh, hd)
-            )[0]
-            v_view = jax.lax.dynamic_slice(
-                v_cache, (li, 0, 0, 0, 0), (1, b, local_kv, nh, hd)
-            )[0]
-        elif local_kv < local_seq:
-            k_view = jax.lax.slice_in_dim(k_cache, 0, local_kv, axis=1)
-            v_view = jax.lax.slice_in_dim(v_cache, 0, local_kv, axis=1)
-        else:
-            k_view, v_view = k_cache, v_cache
-        if (
-            _pallas_enabled(cfg)
-            and jnp.ndim(pos_start) == 0  # flash's causal math assumes one
-            # scalar chunk start (same gate as _attention_auto); per-row
-            # prefill chunks take the masked einsum below
-            and k_view.dtype == jnp.bfloat16
-            and flash_attention_aligned(q, k_view, t)
-        ):
-            # prefill-sized chunks: blocked flash over the local shard with
-            # cross-shard online-softmax combine — the long-context sp path
-            # finally runs the same kernel as the single-chip path
-            a = flash_attention_sp(
-                q, k_view, v_view, pos_start, shard_offset, axis_name,
-                interpret=cfg.pallas_interpret,
-            )
-        else:
-            a = gqa_attention_sp(q, k_view, v_view, positions, shard_offset, axis_name)
+    # --- attention block ---
+    y = rms_norm(x, _sel_layer(lp.norm0, layer_idx), cfg.norm_epsilon)
+    q, k, v = _qkv(cfg, rope, y, lp, positions, layer_idx)
+    a, cache = select_arm(addr)(cfg, cache, addr, q, k, v, positions, pos_start)
     n_local_heads = q.shape[2]  # == cfg.n_heads unless sharded under shard_map
-    att_out = linear(a.reshape(b, t, n_local_heads * cfg.head_dim), lp.wo, cfg.dtype, cfg.pallas_arg, q80, layer_idx)
+    att_out = linear(a.reshape(b, t, n_local_heads * cfg.head_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, layer_idx)
     x = x + reduce_fn(att_out).astype(x.dtype)
 
     # --- ffn block ---
     y = rms_norm(x, _sel_layer(lp.norm1, layer_idx), cfg.norm_epsilon)
-    ff = (
-        _moe_ffn(cfg, y, lp, layer_idx, ep_axis=ep_axis)
-        if cfg.is_moe
-        else _dense_ffn(cfg, y, lp, layer_idx)
-    )
-    x = x + reduce_fn(ff).astype(x.dtype)
-    if k_scale is not None:
-        return x, k_cache, v_cache, k_scale, v_scale
-    return x, k_cache, v_cache
+    x = x + reduce_fn(_ffn(cfg, y, lp, layer_idx, ep_axis)).astype(x.dtype)
+    return x, cache
 
 
 def forward_uncompiled(
@@ -676,9 +347,9 @@ def forward_uncompiled(
     # scalar (all rows aligned) or [b] (independent per-row sequences;
     # batch decode / DP serving)
     logits_mode: str = "last",  # "last" | "all"
-    kv_len: int | None = None,  # static KV read bound (see _layer)
+    kv_len: int | None = None,  # static KV read bound (kv_arms.CacheAddr)
     page_table: jnp.ndarray | None = None,  # [b, max_slots] int32 — paged
-    # KV layout (cache = page pools; see _layer's paged branch)
+    # KV layout (cache = page pools; see kv_arms.paged_arm)
     page_size: int | None = None,  # static page length (paged layout only)
 ) -> tuple[jnp.ndarray, KVCache]:
     """One forward step (prefill chunk or decode token).
@@ -698,42 +369,21 @@ def forward_uncompiled(
     # via closure and each matmul selects its layer inside the kernel
     # (scanning over sliced weights instead would copy every layer's weights
     # out of the stack on every step — a dynamic-slice cannot fuse into a
-    # pallas_call). The FULL cache stack rides the CARRY and each layer
-    # updates its rows in place (stacked_cache): threading per-layer slices
-    # through xs/ys instead re-stacked the whole allocation every call —
-    # measured at ~0.64 ms/token on a 134 MB cache, the dominant term of the
-    # round-3 small-model and 32k-context decode floors.
-    quantized = cache.k_scale is not None
+    # pallas_call). The FULL cache rides the CARRY as one value (an int8
+    # cache's scale sidecars are leaves of it) and each layer updates its
+    # rows in place (CacheAddr.layer).
+    addr = CacheAddr(kv_len=kv_len, page_table=page_table, page_size=page_size)
 
     def body(carry, li):
-        if quantized:
-            # int8 arm: the f32 scale sidecars ride the carry beside their
-            # pools and update in place exactly like them
-            x, k_c, v_c, ks_c, vs_c = carry
-            x, k_c, v_c, ks_c, vs_c = _layer(
-                cfg, rope, x, positions, pos_start, params.layers, k_c, v_c,
-                layer_idx=li, kv_len=kv_len, stacked_cache=True,
-                page_table=page_table, page_size=page_size,
-                k_scale=ks_c, v_scale=vs_c,
-            )
-            return (x, k_c, v_c, ks_c, vs_c), None
-        x, k_c, v_c = carry
-        x, k_c, v_c = _layer(
-            cfg, rope, x, positions, pos_start, params.layers, k_c, v_c,
-            layer_idx=li, kv_len=kv_len, stacked_cache=True,
-            page_table=page_table, page_size=page_size,
+        x, cache = carry
+        x, cache = _layer(
+            cfg, rope, x, positions, pos_start, params.layers, cache,
+            addr._replace(layer=li), layer_idx=li,
         )
-        return (x, k_c, v_c), None
+        return (x, cache), None
 
     layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    if quantized:
-        (x, new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
-            body, (x, cache.k, cache.v, cache.k_scale, cache.v_scale), layer_ids
-        )
-        new_cache = KVCache(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
-    else:
-        (x, new_k, new_v), _ = jax.lax.scan(body, (x, cache.k, cache.v), layer_ids)
-        new_cache = KVCache(k=new_k, v=new_v)
+    (x, new_cache), _ = jax.lax.scan(body, (x, cache), layer_ids)
 
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     if logits_mode == "last":

@@ -99,7 +99,7 @@ def gqa_attention_sp(
 def scatter_cache_update_sp(
     cache: jnp.ndarray,  # [b, local_seq, n_kv, head_dim] — this shard's
     # slice; with `layer` given, the full [L, b, local_seq, n_kv, head_dim]
-    # stack (the in-place carried-cache threading, models/transformer.py)
+    # stack (the in-place carried-cache threading, models/kv_arms.py)
     new: jnp.ndarray,  # [b, t, n_kv, head_dim]
     positions: jnp.ndarray,  # [b, t] GLOBAL positions of the new rows
     shard_offset: jnp.ndarray,

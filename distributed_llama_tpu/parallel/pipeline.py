@@ -34,6 +34,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
+from ..models.kv_arms import CacheAddr
 from ..models.params import KVCache, ModelParams
 from ..models.transformer import _layer, linear, rms_norm
 from ..ops.rope import RopeTables
@@ -113,54 +114,47 @@ def pp_prefix_sharding(mesh: Mesh) -> NamedSharding:
 
 
 def _local_stage(
-    cfg, rope, x, positions, pos_start, layers, k_cache, v_cache, sp_ctx,
-    ep_axis=None, kv_len=None, stacked_cache=False, page_table=None,
-    page_size=None,
+    cfg, rope, x, positions, pos_start, layers, cache, addr, ep_axis=None,
+    stacked_cache=False,
 ):
     """Run this device's resident layers over x (a scan, like the global
-    forward but over the local slice).
+    forward but over the local slice). Returns (x, cache).
 
     `stacked_cache`: the local [L_local, b, S, ...] cache rides the scan's
-    CARRY with in-place per-layer updates (models/transformer.py) instead of
-    being re-stacked through xs/ys — the decode path, where the re-stack was
-    the per-token floor. Weights still arrive as per-layer xs slices.
+    CARRY with in-place per-layer updates (models/kv_arms.py stacked_arm)
+    instead of being re-stacked through xs/ys — the decode path, where the
+    re-stack was the per-token floor. Weights still arrive as per-layer xs
+    slices.
 
-    `page_table` (mesh-paged, runtime/paged_kv.py): k/v are then the LOCAL
-    shard of the page pool ([L/pp, n_pages, ps, h/tp, d]) riding the carry;
-    the replicated table steers writes/reads exactly like the single-chip
-    paged path — always stacked (the pool has no per-layer xs form)."""
-    reduce_fn = lambda z: jax.lax.psum(z, "tp")
+    `addr.page_table` (mesh-paged, runtime/paged_kv.py): the cache is then
+    the LOCAL shard of the page pool ([L/pp, n_pages, ps, h/tp, d]) riding
+    the carry; the replicated table steers writes/reads exactly like the
+    single-chip paged path — always stacked (the pool has no per-layer xs
+    form)."""
+    layer = partial(
+        _layer, cfg, rope, reduce_fn=lambda z: jax.lax.psum(z, "tp"), ep_axis=ep_axis
+    )
 
-    if stacked_cache or page_table is not None:
+    if stacked_cache or addr.page_table is not None:
 
         def body(carry, per_layer):
-            x, k_c, v_c = carry
+            x, cache = carry
             lp, li = per_layer
-            x, k_c, v_c = _layer(
-                cfg, rope, x, positions, pos_start, lp, k_c, v_c,
-                reduce_fn=reduce_fn, sp_ctx=sp_ctx, ep_axis=ep_axis,
-                kv_len=kv_len, stacked_cache=True, cache_layer=li,
-                page_table=page_table, page_size=page_size,
+            x, cache = layer(
+                x, positions, pos_start, lp, cache, addr._replace(layer=li)
             )
-            return (x, k_c, v_c), None
+            return (x, cache), None
 
-        lids = jnp.arange(k_cache.shape[0], dtype=jnp.int32)
-        (x, new_k, new_v), _ = jax.lax.scan(
-            body, (x, k_cache, v_cache), (layers, lids)
-        )
-        return x, new_k, new_v
+        lids = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+        (x, cache), _ = jax.lax.scan(body, (x, cache), (layers, lids))
+        return x, cache
 
-    def body(carry, per_layer):
-        x = carry
-        lp, k_c, v_c = per_layer
-        x, k_c, v_c = _layer(
-            cfg, rope, x, positions, pos_start, lp, k_c, v_c,
-            reduce_fn=reduce_fn, sp_ctx=sp_ctx, ep_axis=ep_axis, kv_len=kv_len,
-        )
-        return x, (k_c, v_c)
-
-    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, k_cache, v_cache))
-    return x, new_k, new_v
+    # per-layer cache slices in through xs, out through the stacked ys:
+    # _layer's (x, cache) is the scan body's (carry, y)
+    return jax.lax.scan(
+        lambda x, per_layer: layer(x, positions, pos_start, *per_layer, addr),
+        x, (layers, cache),
+    )
 
 
 _COMPILED: dict = {}
@@ -177,8 +171,8 @@ def pipeline_forward(
     logits_mode: str = "last",
     microbatches: int = 1,
     kv_len: int | None = None,  # static GLOBAL KV read bound
-    # (models.transformer._layer); under sp each shard clamps it to its
-    # local slice — min(kv_len, local_seq) — which is exact (see _layer)
+    # (models.kv_arms.CacheAddr); under sp each shard clamps it to its
+    # local slice — min(kv_len, local_seq) — which is exact (see sp_arm)
     page_table=None,  # mesh-paged KV (runtime/paged_kv.py): [b, slots]
     # int32, REPLICATED over the mesh (page ids are global); cache is then
     # the pp/tp-sharded page pool (pp_paged_pool_sharding)
@@ -244,22 +238,26 @@ def _cached_pipeline_fn(cfg, mesh, params, cache, extra_key, builder):
     return fn
 
 
-def _mesh_ctx(mesh, k_cache):
-    """(sp_ctx, ep_axis) for a shard_map body over this mesh."""
+def _mesh_ctx(mesh, cache, kv_len, page_table, page_size):
+    """(addr, ep_axis) for a shard_map body over this mesh: how its layers
+    address the local cache shard (the layer index is filled in per layer),
+    and the expert-parallel axis."""
     sp_ctx = None
     if mesh.shape["sp"] > 1:
-        local_seq = k_cache.shape[2]
+        local_seq = cache.k.shape[2]
         sp_ctx = ("sp", jax.lax.axis_index("sp") * local_seq)
     ep_axis = "ep" if mesh.shape.get("ep", 1) > 1 else None
-    return sp_ctx, ep_axis
+    addr = CacheAddr(
+        kv_len=kv_len, page_table=page_table, page_size=page_size, sp_ctx=sp_ctx
+    )
+    return addr, ep_axis
 
 
 def _stage_rounds(
-    cfg, pp, params, rope_t, x_all, k_cache, v_cache, pos_start, n_micro,
-    sp_ctx, ep_axis, kv_len=None, page_table=None, page_size=None,
+    cfg, pp, params, rope_t, x_all, cache, pos_start, n_micro, addr, ep_axis
 ):
     """Push x_all [b, t, dim] through the GPipe schedule; returns
-    (x_out [b, t, dim] — valid on every stage, k_cache, v_cache).
+    (x_out [b, t, dim] — valid on every stage, cache).
 
     Microbatch m enters stage 0 in round m; stage s processes it in round
     m+s; total rounds = n_micro + pp - 1. Each device carries one in-flight
@@ -267,8 +265,9 @@ def _stage_rounds(
 
     `pos_start` may be a scalar (all rows aligned — the single-sequence
     path) or a [b] vector (independent per-row sequences — batched serving
-    on meshes). The vector path routes the cache writes through `_layer`'s
-    OOB-drop scatter, so a row parked at pos seq_len writes nothing.
+    on meshes). The vector path routes the cache writes through the arms'
+    OOB-drop scatters (models/kv_arms.py), so a row parked at pos seq_len
+    writes nothing.
     """
     pp_rank = jax.lax.axis_index("pp")
     b, t, _ = x_all.shape
@@ -286,7 +285,7 @@ def _stage_rounds(
         pos0 = pos_start + jnp.maximum(mb_idx, 0) * mt
         active = jnp.logical_and(mb_idx >= 0, mb_idx < n_micro)
         off = jnp.arange(mt, dtype=jnp.int32)
-        if page_table is not None:
+        if addr.page_table is not None:
             # mesh-paged rounds (runtime/paged_kv.py): the local pool shard
             # updates IN PLACE inside the layer scan for ANY microbatch
             # size — an inactive stage parks at seq_len and its writes DROP
@@ -297,10 +296,9 @@ def _stage_rounds(
             pos_eff = jnp.where(active, pos0, jnp.int32(cfg.seq_len))
             positions = pos_eff[..., None] + off[None, :]
             positions = jnp.broadcast_to(positions, (b, mt))
-            y, k_cache, v_cache = _local_stage(
-                cfg, rope_t, x, positions, pos_eff, params.layers, k_cache,
-                v_cache, sp_ctx, ep_axis=ep_axis, kv_len=kv_len,
-                page_table=page_table, page_size=page_size,
+            y, cache = _local_stage(
+                cfg, rope_t, x, positions, pos_eff, params.layers, cache,
+                addr, ep_axis,
             )
         elif mt == 1:
             # decode rounds: the local cache stack updates IN PLACE inside
@@ -313,29 +311,28 @@ def _stage_rounds(
                 jnp.where(active, pos0, jnp.int32(cfg.seq_len)), (b,)
             )
             positions = pos_eff[:, None] + off[None, :]
-            y, k_cache, v_cache = _local_stage(
-                cfg, rope_t, x, positions, pos_eff, params.layers, k_cache,
-                v_cache, sp_ctx, ep_axis=ep_axis, kv_len=kv_len,
-                stacked_cache=True,
+            y, cache = _local_stage(
+                cfg, rope_t, x, positions, pos_eff, params.layers, cache,
+                addr, ep_axis, stacked_cache=True,
             )
         else:
             positions = (pos0[:, None] + off[None, :]) if per_row else (pos0 + off[None, :])
             positions = jnp.broadcast_to(positions, (b, mt))
 
-            y, k_upd, v_upd = _local_stage(
-                cfg, rope_t, x, positions, pos0, params.layers, k_cache, v_cache,
-                sp_ctx, ep_axis=ep_axis, kv_len=kv_len,
+            y, upd = _local_stage(
+                cfg, rope_t, x, positions, pos0, params.layers, cache, addr,
+                ep_axis,
             )
             # commit cache only when this stage held a real microbatch.
             # Without sp, only rows [pos0, pos0+mt) can differ — select just
             # that window (a full-cache jnp.where would read+write the whole
             # allocation per round)
-            if sp_ctx is None:
+            if addr.sp_ctx is None:
                 if per_row:
                     # per-row windows: each row's [pos0_r, pos0_r+mt) slice
                     # may start anywhere, so vmap the window select over the
                     # batch axis (cache axis 1). A parked row's pos0 clamps
-                    # into the tail here, but _layer's drop-scatter left
+                    # into the tail here, but the arm's drop-scatter left
                     # upd == full for it, so the re-write is an identity.
                     def commit(full, upd):
                         def row(fr, ur, p):  # [L, S, h, d]
@@ -354,12 +351,12 @@ def _stage_rounds(
                         win = jnp.where(active, new_win, old_win)
                         return jax.lax.dynamic_update_slice_in_dim(full, win, pos0, axis=2)
 
-                k_cache = commit(k_cache, k_upd)
-                v_cache = commit(v_cache, v_upd)
+                cache = jax.tree.map(commit, cache, upd)
             else:
                 # sp scatters rows anywhere in the local shard — no window bound
-                k_cache = jnp.where(active, k_upd, k_cache)
-                v_cache = jnp.where(active, v_upd, v_cache)
+                cache = jax.tree.map(
+                    lambda full, u: jnp.where(active, u, full), cache, upd
+                )
         # last stage's output for microbatch (r - pp + 1) is final
         if r >= pp - 1:
             done.append(jnp.where(pp_rank == pp - 1, y, 0.0))
@@ -371,7 +368,7 @@ def _stage_rounds(
     # every device computes logits identically
     x_out = jnp.concatenate(done, axis=1)
     x_out = jax.lax.psum(x_out, "pp")
-    return x_out, k_cache, v_cache
+    return x_out, cache
 
 
 def _logits_of(cfg, params, x_out):
@@ -407,17 +404,16 @@ def _build_pipeline_fn(
         check_vma=False,
     )
     def run(params, rope_t, cache, tokens, pos_start, page_table=None):
-        k_cache, v_cache = cache.k, cache.v  # [L_local, b_local, local_seq, kvh_local, hd]
-        sp_ctx, ep_axis = _mesh_ctx(mesh, k_cache)
+        # cache.k/.v: [L_local, b_local, local_seq, kvh_local, hd]
+        addr, ep_axis = _mesh_ctx(mesh, cache, kv_len, page_table, page_size)
         x_all = params.embedding[tokens].astype(jnp.float32)  # [b_local, t, dim]
-        x_out, k_cache, v_cache = _stage_rounds(
-            cfg, pp, params, rope_t, x_all, k_cache, v_cache, pos_start,
-            max(microbatches, 1), sp_ctx, ep_axis, kv_len=kv_len,
-            page_table=page_table, page_size=page_size,
+        x_out, cache = _stage_rounds(
+            cfg, pp, params, rope_t, x_all, cache, pos_start,
+            max(microbatches, 1), addr, ep_axis,
         )
         if logits_mode == "last":
             x_out = x_out[:, -1, :]
-        return _logits_of(cfg, params, x_out), KVCache(k=k_cache, v=v_cache)
+        return _logits_of(cfg, params, x_out), cache
 
     return jax.jit(run, donate_argnums=(2,))
 
@@ -492,7 +488,7 @@ def _build_pipeline_decode_fn(
         check_vma=False,
     )
     def run(params, rope_t, cache, token, pos_start, key, page_table=None):
-        sp_ctx, ep_axis = _mesh_ctx(mesh, cache.k)
+        addr, ep_axis = _mesh_ctx(mesh, cache, kv_len, page_table, page_size)
         # independent sampling randomness per dp shard (the key arrives
         # replicated; without the fold every shard would draw the same coins
         # for its local batch rows)
@@ -500,25 +496,23 @@ def _build_pipeline_decode_fn(
             key = jax.random.fold_in(key, jax.lax.axis_index("dp"))
 
         def step(carry, _):
-            token, pos, k_cache, v_cache, key = carry
+            token, pos, cache, key = carry
             x = params.embedding[token[:, None]].astype(jnp.float32)
-            x_out, k_cache, v_cache = _stage_rounds(
-                cfg, pp, params, rope_t, x, k_cache, v_cache, pos, 1, sp_ctx,
-                ep_axis, kv_len=kv_len, page_table=page_table,
-                page_size=page_size,
+            x_out, cache = _stage_rounds(
+                cfg, pp, params, rope_t, x, cache, pos, 1, addr, ep_axis
             )
             logits = _logits_of(cfg, params, x_out[:, -1, :])
             key, sub = jax.random.split(key)
             nxt = sample_logits(logits, sub, temperature, topp)
-            return (nxt, pos + 1, k_cache, v_cache, key), nxt
+            return (nxt, pos + 1, cache, key), nxt
 
-        (last, _, k_cache, v_cache, _), toks = jax.lax.scan(
+        (last, _, cache, _), toks = jax.lax.scan(
             step,
-            (token, jnp.asarray(pos_start, jnp.int32), cache.k, cache.v, key),
+            (token, jnp.asarray(pos_start, jnp.int32), cache, key),
             None,
             length=n_steps,
         )
-        return jnp.transpose(toks, (1, 0)), last, KVCache(k=k_cache, v=v_cache)
+        return jnp.transpose(toks, (1, 0)), last, cache
 
     return jax.jit(run, donate_argnums=(2,))
 
@@ -584,25 +578,23 @@ def _build_pipeline_batch_decode_fn(
     )
     def run(params, rope_t, cache, token, pos0, keys, temperature, topp,
             page_table=None):
-        sp_ctx, ep_axis = _mesh_ctx(mesh, cache.k)
+        addr, ep_axis = _mesh_ctx(mesh, cache, kv_len, page_table, page_size)
 
         def step(carry, _):
-            token, pos, k_cache, v_cache, keys = carry
+            token, pos, cache, keys = carry
             x = params.embedding[token[:, None]].astype(jnp.float32)
-            x_out, k_cache, v_cache = _stage_rounds(
-                cfg, pp, params, rope_t, x, k_cache, v_cache, pos, 1, sp_ctx,
-                ep_axis, kv_len=kv_len, page_table=page_table,
-                page_size=page_size,
+            x_out, cache = _stage_rounds(
+                cfg, pp, params, rope_t, x, cache, pos, 1, addr, ep_axis
             )
             logits = _logits_of(cfg, params, x_out[:, -1, :])
             keys, subs = split_row_keys(keys)
             nxt = sample_logits_per_row(logits, subs, temperature, topp)
-            return (nxt, pos + 1, k_cache, v_cache, keys), nxt
+            return (nxt, pos + 1, cache, keys), nxt
 
-        (_, _, k_cache, v_cache, keys), toks = jax.lax.scan(
-            step, (token, pos0, cache.k, cache.v, keys), None, length=n_steps
+        (_, _, cache, keys), toks = jax.lax.scan(
+            step, (token, pos0, cache, keys), None, length=n_steps
         )
-        return jnp.transpose(toks, (1, 0)), KVCache(k=k_cache, v=v_cache), keys
+        return jnp.transpose(toks, (1, 0)), cache, keys
 
     return jax.jit(run, donate_argnums=(2,))
 

@@ -17,7 +17,7 @@ decision instead:
   single-sequence forward (full speed: flash attention, scalar positions; the
   other rows' cache is untouched), on mesh paths via the per-row-position
   pipeline forward with every other row parked at pos seq_len (their cache
-  writes are dropped by the OOB scatter, models/transformer.py);
+  writes are dropped by the OOB scatter, models/kv_arms.py);
 * admission can be INTERLEAVED: `begin_admit` stages the prompt and
   `prefill_pending(row, budget)` advances it a bounded number of tokens at a
   time, so a long prompt's prefill slots between decode chunks instead of

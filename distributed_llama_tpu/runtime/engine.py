@@ -92,8 +92,8 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__fi
 def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
     THE one place a cache directory is chosen, and every entry point goes
-    through it (cli.make_engine — so the CLI and the server — chip_smoke.py,
-    bench.py); library engines built directly keep JAX's defaults.
+    through it (cli.make_engine — so the CLI and the server — and
+    chip_smoke.py); library engines built directly keep JAX's defaults.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already has the
     directory and none is set here: whoever runs the program places the
@@ -394,7 +394,7 @@ class InferenceEngine:
             prefill_pipelined = os.environ.get("DLT_PREFILL_PIPELINE", "1") != "0"
         self.prefill_pipelined = prefill_pipelined
         # dispatch-vs-compute overlap summary of the most recent prefill
-        # (bench.py reads it; /stats exports the gauge twin)
+        # (/stats exports the gauge twin)
         self.last_prefill_timing: dict | None = None
         # per-request tracing context (runtime/tracing.py Trace), set by the
         # serving layer around a request (the serialized API path; the
@@ -405,7 +405,7 @@ class InferenceEngine:
         # shape keys this engine has executed at least once: a first-shape
         # call legitimately blocks on XLA compilation, so its watchdog runs
         # with the (much wider) compile threshold and a "compile" label
-        # instead of crying EXEC_STALL (the BENCH_r04 false alarm)
+        # instead of crying EXEC_STALL
         self._warm: set = set()
         # radix prefix cache: cross-request KV reuse over shared prompt
         # prefixes (None = disabled). Warmup suppresses it (_in_warmup) so
@@ -431,7 +431,7 @@ class InferenceEngine:
         self.spec_buckets = spec_buckets(self.draft_k) if self.spec_mode else ()
         self.draft_source = build_draft_source(self.spec_mode, draft_source)
         # draft/verify/acceptance summary of the most recent speculative
-        # generate (bench.py reads it; mirrors last_prefill_timing)
+        # generate (mirrors last_prefill_timing)
         self.last_spec_timing: dict | None = None
         # grammar-constrained decoding (runtime/grammar.py): ONE device
         # mask-table arena serves every live grammar as a traced

@@ -12,7 +12,7 @@ key/value tensors — plus a host-managed **page table** per batch row
 positions ``[s*page_size, (s+1)*page_size)``).
 
 Device side, the forward pass changes in exactly two places
-(models/transformer.py ``_layer``):
+(models/kv_arms.py ``paged_arm``):
 
 * **write**: new KV rows scatter to ``(page_table[row, pos // ps],
   pos % ps)`` — out-of-range positions (parked rows) remap to page indices

@@ -28,7 +28,7 @@ Correctness invariants (the reasons this is bit-identical to a cold run):
   belong to a diverged sibling request, but the resumed prefill (and then
   decode) rewrites every position >= B before any query at position >= B
   reads it — the same write-before-read invariant padded prefill tails and
-  parked rows already rely on (models/transformer.py OOB-scatter notes);
+  parked rows already rely on (models/kv_arms.py OOB-scatter notes);
 * the copy/extract programs are plain jitted slice/update programs on the
   engine's warm-key ladder: one `(bucket, bucket)` entry per prefix bucket,
   warmed by `InferenceEngine.warmup()`, ZERO collectives (the graph

@@ -1128,66 +1128,6 @@ def bench_profile(engine, final_pos: int | None = None) -> dict:
     return out
 
 
-# -- prefill overlap probe (scripts/profile_prefill.py rides this) -----------
-
-
-def prefill_overlap_probe(
-    model_path: str,
-    prompt_tokens: int,
-    reps: int = 3,
-    max_chunk: int = 512,
-    compute_dtype: str = "bfloat16",
-) -> list:
-    """Dispatch-vs-compute overlap of the pipelined prefill, pipelined vs
-    the forced-serial arm — the ONE timing pathway: every number comes from
-    ``engine.last_prefill_timing`` and the ``prefill_dispatch[size]``
-    StepStats series, the same sources `/stats` and `/metrics` export, so
-    the probe script can never drift from serving telemetry."""
-    from .engine import InferenceEngine
-
-    arms = []
-    for pipelined in (True, False):
-        eng = InferenceEngine(
-            model_path, compute_dtype=compute_dtype, max_chunk=max_chunk,
-            prefill_pipelined=pipelined,
-            prefix_cache_mb=0,  # repeated-prompt probe: a splice would
-            # replace the prefill being measured
-        )
-        try:
-            prompt = [(i % 1000) + 1 for i in range(prompt_tokens)]
-            eng.prefill(prompt)  # compile the ladder
-            walls = []
-            for _ in range(reps):
-                eng.reset()
-                t0 = time.perf_counter()
-                eng.prefill(prompt)
-                walls.append((time.perf_counter() - t0) * 1e3)
-            t = dict(eng.last_prefill_timing or {})
-            arms.append(
-                {
-                    "pipelined": pipelined,
-                    "n_tokens": prompt_tokens,
-                    "n_chunks": t.get("n_chunks", 0),
-                    "best_wall_ms": round(min(walls), 1),
-                    "tok_s": round(prompt_tokens / min(walls) * 1e3, 1),
-                    "dispatch_ms": round(t.get("dispatch_us", 0) / 1e3, 1),
-                    "sync_ms": round(t.get("sync_us", 0) / 1e3, 1),
-                    "overlap_pct": t.get("overlap_pct"),
-                    "dispatch_series": {
-                        k: {
-                            "count": s.count,
-                            "avg_ms": round(s.total_us / s.count / 1e3, 2),
-                        }
-                        for k, s in sorted(eng.stats.series.items())
-                        if k.startswith("prefill_dispatch") and s.count
-                    },
-                }
-            )
-        finally:
-            eng.close()
-    return arms
-
-
 # -- on-demand profiler capture ----------------------------------------------
 
 

@@ -36,7 +36,7 @@ Correctness (why greedy outputs are bit-identical to plain decode):
   rollback: positions past the accepted boundary are rewritten by a later
   round's feed before any query reads them — the same write-before-read
   invariant padded prefill tails and parked batch rows already rely on
-  (models/transformer.py OOB-scatter notes);
+  (models/kv_arms.py OOB-scatter notes);
 * speculation applies to GREEDY requests only (temperature 0). Sampled
   rows keep the plain chunked path — accepting drafts under a sampler
   would change the RNG stream, and the per-row threefry chains' stream

@@ -73,7 +73,7 @@ class watchdog:
             import sys
 
             # diagnostics go to STDERR: tools that contract to emit one
-            # machine-readable stdout line (bench.py) must not get a stall
+            # machine-readable stdout line (chip_smoke.py) must not get a stall
             # notice spliced into their output
             log_fn = functools.partial(print, file=sys.stderr)
         self.log_fn = log_fn
@@ -81,9 +81,8 @@ class watchdog:
         # call legitimately spends 20-40s in XLA compilation. `compiling`
         # marks a first-shape call (the engine tracks which shapes it has
         # run): the log threshold widens so an expected cold compile is not
-        # reported as a stall (BENCH_r04 tripped EXEC_STALL on the 8B
-        # prefill's first compile — a false alarm that cost the round's
-        # measurement discipline a hole), and the label says what it is
+        # reported as a stall (an 8B prefill's first compile once tripped
+        # EXEC_STALL — a false alarm), and the label says what it is
         self.log_ms = _env_ms(
             "DLT_COMPILE_LOG_MS" if compiling else "DLT_STALL_LOG_MS",
             300000 if compiling else 60000,

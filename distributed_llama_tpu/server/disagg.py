@@ -25,8 +25,7 @@ subsystem instead of excluding them:
   arrays with zero host serialization (:class:`DeviceKvTransport`); the
   PR 10 length-prefixed binary codec stays as the portable HTTP fallback.
   Per-path walls and bytes land in ``kv_transfer_us[{path}]`` /
-  ``kv_transfer_bytes_{path}`` — the ≥3x device-vs-http cut is the bench
-  bar (bench.py leg_kv_movement);
+  ``kv_transfer_bytes_{path}``;
 * the decode worker inserts the shipped slice into its radix prefix cache
   (:meth:`~..runtime.prefix_cache.PrefixCache.insert_external` — paged
   engines scatter into freshly allocated pool pages and retain the held

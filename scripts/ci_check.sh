@@ -85,13 +85,6 @@
 #                            fuzz, corruption chaos trio + device corrupt
 #                            modes degrading token-identical, corrupt-
 #                            peer quarantine, wire-version skip-peer)
-#  12. scoreboard guard     (scripts/bench_compare.py: newest BENCH round
-#                            vs predecessor, tolerance-banded — STRICT in
-#                            this preflight since r08 (direction bands
-#                            held three rounds); the in-CI ci.yml stage
-#                            stays warn-only so bench noise cannot block
-#                            a PR, while local preflight catches real
-#                            regressions before push)
 #
 # Pass --full to also run the tier-1 fast subset (-m 'not slow').
 set -euo pipefail
@@ -199,9 +192,6 @@ echo "== cross-suite sentinel-lifecycle pair (single process, slow-marked) =="
 # (the PR 13 combined-slow-run pollution class; see ApiState.close)
 python -m pytest tests/test_supervisor.py tests/test_speculative.py \
   -q -m slow -p no:cacheprovider
-
-echo "== scoreboard guard (STRICT preflight; ci.yml stays warn-only) =="
-python scripts/bench_compare.py --strict
 
 if [[ "${1:-}" == "--full" ]]; then
   echo "== tier-1 fast subset =="

@@ -71,6 +71,25 @@ def _softmax(x):
     return e / e.sum()
 
 
+def attend(q, k_rows, v_rows):
+    """One token's grouped-query attention: q [n_heads, head_dim] over the
+    cached rows k_rows / v_rows [n_pos, n_kv_heads, head_dim], every one of
+    them visible (the caller passes positions 0..pos)."""
+    n_heads, head_dim = q.shape
+    n_pos, n_kv_heads = k_rows.shape[:2]
+    kv_mul = n_heads // n_kv_heads
+    att_out = np.zeros((n_heads, head_dim), np.float32)
+    for hh in range(n_heads):
+        kh = hh // kv_mul
+        scores = np.array(
+            [q[hh] @ k_rows[t, kh] / np.sqrt(head_dim) for t in range(n_pos)]
+        )
+        a = _softmax(scores)
+        for t in range(n_pos):
+            att_out[hh] += a[t] * v_rows[t, kh]
+    return att_out
+
+
 class NumpyModel:
     """f32 forward, one token at a time, full KV cache in numpy."""
 
@@ -104,16 +123,7 @@ class NumpyModel:
             kc[l, pos] = k
             vc[l, pos] = v
 
-            kv_mul = h.n_heads // h.n_kv_heads
-            att_out = np.zeros((h.n_heads, h.head_dim), np.float32)
-            for hh in range(h.n_heads):
-                kh = hh // kv_mul
-                scores = np.array(
-                    [q[hh] @ kc[l, t, kh] / np.sqrt(h.head_dim) for t in range(pos + 1)]
-                )
-                a = _softmax(scores)
-                for t in range(pos + 1):
-                    att_out[hh] += a[t] * vc[l, t, kh]
+            att_out = attend(q, kc[l, : pos + 1], vc[l, : pos + 1])
             x = x + self.w[f"wo.l{l}"] @ att_out.reshape(-1)
 
             y = _rms_norm(x, w("norm1"), h.norm_epsilon)

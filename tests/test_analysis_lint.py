@@ -162,7 +162,6 @@ def test_repo_tree_is_clean():
     paths = [
         ROOT / "distributed_llama_tpu",
         ROOT / "scripts",
-        ROOT / "bench.py",
         ROOT / "launch.py",
     ]
     violations = lint.lint_paths([p for p in paths if p.exists()], root=ROOT)

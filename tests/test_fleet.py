@@ -1,6 +1,6 @@
 """Fleet signal plane tests: the gateway's per-replica scraper driven
 end-to-end under the PR 1 chaos harness (server/chaos.py), plus the
-Prometheus federation format and the bench_compare scoreboard guard.
+Prometheus federation format.
 
 The replica backends are STUBS serving canned /metrics + /stats +
 /debug/config bodies — the subject under test is the TRANSPORT and the
@@ -341,54 +341,3 @@ def test_fleet_disabled_endpoint_degrades(fleet_stack):
         assert "dlt_fleet_replica_stale" not in body
     finally:
         stop.set()
-
-
-# ---- bench_compare scoreboard guard ----------------------------------------
-
-
-def _write_round(tmp_path, n, configs):
-    (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-        json.dumps({"n": n, "parsed": {"configs": configs}})
-    )
-
-
-def test_bench_compare_flags_regressions_only_beyond_band(tmp_path, capsys):
-    import scripts.bench_compare as bc
-
-    _write_round(
-        tmp_path, 1,
-        [
-            {"config": "legA", "decode_tok_s": 100.0, "ttft_ms": 100.0},
-            {"config": "gone", "decode_tok_s": 5.0},
-        ],
-    )
-    _write_round(
-        tmp_path, 2,
-        [
-            # decode within band (-5%), ttft regressed (+50%)
-            {"config": "legA", "decode_tok_s": 95.0, "ttft_ms": 150.0},
-            {"config": "brand_new", "decode_tok_s": 7.0},
-        ],
-    )
-    rc = bc.main(["--dir", str(tmp_path), "--tol", "10"])
-    out = capsys.readouterr().out
-    assert rc == 0  # warn-only by default
-    assert "REGRESSED" in out and "ttft_ms" in out
-    assert "decode_tok_s" not in [
-        line.split()[1] for line in out.splitlines()
-        if "REGRESSED" in line
-    ]
-    assert "brand_new" in out and "gone" in out
-    # --strict flips regressions to a failing exit code
-    assert bc.main(["--dir", str(tmp_path), "--tol", "10", "--strict"]) == 1
-    # throughput regression beyond band is caught too
-    _write_round(tmp_path, 3, [{"config": "legA", "decode_tok_s": 50.0,
-                                "ttft_ms": 150.0}])
-    assert bc.main(["--dir", str(tmp_path), "--tol", "10", "--strict"]) == 1
-
-
-def test_bench_compare_handles_missing_rounds(tmp_path, capsys):
-    import scripts.bench_compare as bc
-
-    assert bc.main(["--dir", str(tmp_path)]) == 0
-    assert "nothing to diff" in capsys.readouterr().out
