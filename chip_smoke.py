@@ -79,8 +79,8 @@ N_MATMUL_KERNELS = 5
 # (int8-MXU kernels, whose activations are quantized to int8 as well).
 MIN_TOP1_AGREEMENT = 0.75
 MAX_LOGIT_DIFF_STD = 0.3
-# int8 page-table kernel vs gather+dequant on one random pool (bf16 output;
-# measured 0.0055)
+# page-table kernel vs gather(+dequant) on one random pool, int8 and bf16
+# (bf16 output; the int8 per-page kernel measured 0.0055, PR 21)
 MAX_PAGED_KERNEL_DIFF = 0.03
 # int8 KV vs bf16 KV, greedy: leading tokens that must agree. int8 rounding
 # parts two near-tied random-weight logits sooner or later (measured: after
@@ -188,7 +188,7 @@ def phase_numbers(cut_model: str, tokenizer: str, rehearse: bool) -> None:
 
     from distributed_llama_tpu.ops.attention import gqa_attention
     from distributed_llama_tpu.ops.kv_quant import dequantize_kv
-    from distributed_llama_tpu.ops.pallas_attention import paged_flash_attention
+    from distributed_llama_tpu.ops.pallas_attention import paged_decode_attention
     from distributed_llama_tpu.runtime.engine import InferenceEngine
     from distributed_llama_tpu.runtime.profiling import build_cost_table
 
@@ -232,30 +232,43 @@ def phase_numbers(cut_model: str, tokenizer: str, rehearse: bool) -> None:
         elif top1 < MIN_TOP1_AGREEMENT or diff > MAX_LOGIT_DIFF_STD:
             fail(f"numbers/{name}: top1 {top1:.3f}, max diff {diff:.3f} std")
 
-    # (b) the int8 page-table kernel alone, at the pool's real trailing shape
+    # (b) the page-table kernel alone, at the pool's real trailing shape, over
+    # an int8 pool and a bf16 one: rows that end in different blocks
     interp = bool(os.environ.get("DLT_PALLAS_INTERPRET"))
     n_kv, hd, heads = (4, 32, 8) if rehearse else (8, 128, 32)
-    L, P, ps, b, t, n_read = 2, 64, 16, 2, 5, 8
-    kp, vp = (jnp.asarray(rng.integers(-127, 128, (L, P, ps, n_kv, hd), dtype=np.int8)) for _ in "kv")
-    ks, vs = (jnp.asarray(rng.uniform(1e-3, 2e-2, (L, P, ps, n_kv)).astype(np.float32)) for _ in "kv")
+    L, P, ps, b, t, n_read = 2, 64, 16, 2, 5, 24
     q = jnp.asarray(rng.standard_normal((b, t, heads, hd), dtype=np.float32)).astype(jnp.bfloat16)
     table_ = jnp.asarray(rng.permutation(P)[: b * n_read].reshape(b, n_read).astype(np.int32))
     pos = jnp.asarray([n_read * ps - t - 3, 17], jnp.int32)
-    got = paged_flash_attention(
-        q, kp, vp, ks, vs, jnp.int32(1), pos, table_, n_read=n_read,
-        page_size=ps, interpret=interp,
-    )
-    k_ref = dequantize_kv(kp[1, table_], ks[1, table_], jnp.float32).reshape(b, n_read * ps, n_kv, hd)
-    v_ref = dequantize_kv(vp[1, table_], vs[1, table_], jnp.float32).reshape(b, n_read * ps, n_kv, hd)
-    want = gqa_attention(q.astype(jnp.float32), k_ref, v_ref, pos[:, None] + jnp.arange(t)[None, :])
-    kdiff = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
-    say(
-        "numbers", check="int8 page-table kernel vs gather+dequant",
-        pool_tail=[ps, n_kv, hd], max_abs_diff=round(kdiff, 5),
-        bound=MAX_PAGED_KERNEL_DIFF,
-    )
-    if not kdiff <= MAX_PAGED_KERNEL_DIFF:
-        fail(f"numbers/paged kernel: max diff {kdiff}")
+    for store in ("int8", "bfloat16"):
+        if store == "int8":
+            kp, vp = (jnp.asarray(rng.integers(-127, 128, (L, P, ps, n_kv, hd), dtype=np.int8)) for _ in "kv")
+            ks, vs = (jnp.asarray(rng.uniform(1e-3, 2e-2, (L, P, ps, n_kv)).astype(np.float32)) for _ in "kv")
+            k_ref = dequantize_kv(kp[1, table_], ks[1, table_], jnp.float32)
+            v_ref = dequantize_kv(vp[1, table_], vs[1, table_], jnp.float32)
+        else:
+            kp, vp = (
+                jnp.asarray(rng.standard_normal((L, P, ps, n_kv, hd), dtype=np.float32)).astype(jnp.bfloat16)
+                for _ in "kv"
+            )
+            ks = vs = None
+            k_ref, v_ref = (x[1, table_].astype(jnp.float32) for x in (kp, vp))
+        got = paged_decode_attention(
+            q, kp, vp, ks, vs, jnp.int32(1), pos, table_, n_read=n_read,
+            page_size=ps, interpret=interp,
+        )
+        want = gqa_attention(
+            q.astype(jnp.float32), k_ref.reshape(b, n_read * ps, n_kv, hd),
+            v_ref.reshape(b, n_read * ps, n_kv, hd), pos[:, None] + jnp.arange(t)[None, :],
+        )
+        kdiff = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+        say(
+            "numbers", check=f"{store} page-table kernel vs gather",
+            pool_tail=[ps, n_kv, hd], max_abs_diff=round(kdiff, 5),
+            bound=MAX_PAGED_KERNEL_DIFF,
+        )
+        if not kdiff <= MAX_PAGED_KERNEL_DIFF:
+            fail(f"numbers/paged kernel ({store}): max diff {kdiff}")
 
     # (c) a short paged int8 generation vs the bf16-paged one (host loop:
     # greedy argmax of the t=1 forward, the program the kernel serves)
@@ -275,21 +288,22 @@ def phase_numbers(cut_model: str, tokenizer: str, rehearse: bool) -> None:
     while lead < n_new and gens["int8"][lead] == gens["bfloat16"][lead]:
         lead += 1
     count = lambda e: (e.pallas_calls, e.tpu_custom_calls) if e else None
-    fused = bool(
-        kernels["int8"] and kernels["bfloat16"]
-        and kernels["int8"].pallas_calls == kernels["bfloat16"].pallas_calls + 1
+    # both decode programs: the matmuls' kernels and the page-table kernel
+    fused = all(
+        kernels[kv] and kernels[kv].pallas_calls == N_MATMUL_KERNELS + 1
+        for kv in ("int8", "bfloat16")
     )
     say(
         "numbers", check="paged int8 generation vs bf16-paged",
         new_tokens=n_new, leading_tokens_equal=lead,
-        int8_decode_arm="fused page-table kernel" if fused else "gather+dequant",
+        decode_arm="page-table kernel" if fused else "gather",
         decode_kernels_traced_compiled={k: count(v) for k, v in kernels.items()},
         bound=MIN_INT8_LEADING_MATCH,
     )
     if len(gens["int8"]) != n_new or lead < min(MIN_INT8_LEADING_MATCH, n_new):
         fail(f"numbers/int8 generation: {lead} leading tokens equal of {n_new}")
     if not fused:
-        fail("numbers/int8 decode did not take the fused page-table kernel")
+        fail("numbers/paged decode did not take the page-table kernel")
 
 
 # -- phase: server ------------------------------------------------------------
@@ -408,10 +422,10 @@ def phase_server(model: str, tokenizer: str, rehearse: bool) -> None:
     kvb = max(k for _, _, k in plan)
     on_tpu = jax.devices()[0].platform == "tpu"
     for kind, size, need in (
-        ("decode", 1, N_MATMUL_KERNELS),
-        ("batch_decode", 1, N_MATMUL_KERNELS),
+        ("decode", 1, N_MATMUL_KERNELS + 1),  # + the page-table kernel
+        ("batch_decode", 1, N_MATMUL_KERNELS + 1),
         ("prefill", max(s for k, s, _ in plan if k == "prefill"), N_MATMUL_KERNELS + 1),
-        ("verify", min(s for k, s, _ in plan if k == "verify"), N_MATMUL_KERNELS),
+        ("verify", min(s for k, s, _ in plan if k == "verify"), N_MATMUL_KERNELS + 1),
     ):
         e = table.entries.get((kind, size, kvb)) if table else None
         n = (e.tpu_custom_calls if on_tpu else e.pallas_calls) if e else -1
@@ -423,7 +437,7 @@ def phase_server(model: str, tokenizer: str, rehearse: bool) -> None:
         if n < need:
             fail(
                 f"kernels: {kind}[{size}] holds {n} kernels, the path has "
-                f"{need} (a weight fell off its kernel, or flash is missing)"
+                f"{need} (a weight fell off its kernel, or attention's is missing)"
             )
     if table is None or table.failures:
         fail(f"cost table: {table and dict(list(table.failures.items())[:3])}")

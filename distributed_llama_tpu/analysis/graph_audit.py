@@ -486,8 +486,8 @@ def f32_dot_budget(engine, entry: LadderEntry) -> int:
 
 #: kind -> registry row. `copy_program`: a pure slice/gather/scatter
 #: KV-movement program (zero explicit collectives on EVERY topology);
-#: `fused_decode`: eligible for the fused page-table-aware int8 decode
-#: kernel, whose contract pins pool gathers to zero (PR 17).
+#: `fused_decode`: eligible for the page-table decode kernel (float or
+#: int8 pool), whose contract pins pool gathers to zero (PRs 17, 32).
 KIND_REGISTRY = {
     "prefill": dict(copy_program=False, fused_decode=False),
     "decode": dict(copy_program=False, fused_decode=True),
@@ -515,8 +515,8 @@ class ProgramContract:
     * `collectives` — the EXACT expected collective multiset for this
       topology, or None when the topology has no manifest (MoE/sp/ep);
     * `forbid_pool_gather` — the KV pool's shape when this program must
-      not materialize pool gathers (the fused int8 paged decode pin);
-      None = unpinned.
+      not materialize pool gathers (the page-table decode kernel's pin,
+      float and int8 pools alike); None = unpinned.
     """
 
     entry: LadderEntry
@@ -546,7 +546,6 @@ def contract_for(engine, entry: LadderEntry) -> ProgramContract:
     if (
         row["fused_decode"]
         and getattr(engine, "paged", False)
-        and engine.cfg.kv_quantized
         and _fused_kernel_active(engine)
     ):
         pool = tuple(engine.cache.k.shape)
@@ -559,17 +558,16 @@ def contract_for(engine, entry: LadderEntry) -> ProgramContract:
 
 
 def _fused_kernel_active(engine) -> bool:
-    """True when the int8 paged decode programs trace the fused
-    page-table-aware Pallas kernel (models/kv_arms.py
-    _fused_paged_eligible at decode's t=1): pallas enabled for this config
-    and uniform lane-aligned head grouping."""
-    from ..models.kv_arms import _pallas_enabled
+    """True when the paged decode programs trace the page-table Pallas
+    kernel (models/kv_arms.py _fused_paged_eligible at decode's t=1), read
+    off what the gate reads: the config and the pool's own shape."""
+    from ..models.kv_arms import _fused_paged_eligible
 
     cfg = engine.cfg
-    return (
-        _pallas_enabled(cfg)
-        and cfg.n_heads % cfg.n_kv_heads == 0
-        and cfg.head_dim % 8 == 0
+    tp = engine.mesh.shape["tp"] if engine.mesh is not None else 1
+    return _fused_paged_eligible(  # a tp shard's own head counts
+        cfg, (cfg.n_heads // tp, cfg.head_dim), cfg.n_kv_heads // tp, 1,
+        engine.cache.k.shape[2],
     )
 
 
@@ -612,8 +610,8 @@ def contract_problems(engine, contract: ProgramContract, jaxpr) -> list:
         n = pool_gather_count(jaxpr, contract.forbid_pool_gather)
         if n:
             problems.append(
-                f"gather x{n} materializes the int8 KV pool in "
-                f"{entry.kind} — the fused page-table-aware decode kernel "
+                f"gather x{n} materializes the KV pool in "
+                f"{entry.kind} — the page-table decode kernel's "
                 "contract requires ZERO pool gathers (page tables ride "
                 "the kernel's scalar prefetch; ops/pallas_attention.py)"
             )
@@ -1028,7 +1026,7 @@ def add_engine_args(p) -> None:
         "--kv-dtype", choices=["bfloat16", "float32", "int8"], default=None,
         help="audit the quantized-KV program ladder (int8 payload + f32 "
         "scale sidecars, ops/kv_quant.py): the paged arm must lower the "
-        "fused page-table-aware decode kernel and the collective budgets "
+        "page-table decode kernel and the collective budgets "
         "must match the float twin's (default: the compute-dtype default)",
     )
     p.add_argument(

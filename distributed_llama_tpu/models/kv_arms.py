@@ -29,7 +29,7 @@ from ..ops.kv_quant import dequantize_kv, quantize_kv
 from ..ops.pallas_attention import (
     flash_attention,
     flash_attention_aligned,
-    paged_flash_attention,
+    paged_decode_attention,
 )
 from ..ops.quant import _use_pallas
 from .params import KVCache
@@ -104,27 +104,26 @@ def _attention_auto(cfg, q, k_view, v_view, positions, pos_start):
     return gqa_attention(q, k_view, v_view, positions)
 
 
-def _fused_paged_eligible(cfg, q, t: int, ps: int) -> bool:
-    """Gate for the fused page-table-aware int8 decode kernel: Pallas
-    enabled, decode-sized q blocks (one page of queries at most — solo
+def _fused_paged_eligible(cfg, heads_dim, n_kv: int, t: int, ps: int) -> bool:
+    """Gate for the page-table decode kernel, float or int8 cache alike:
+    Pallas enabled, decode-sized q blocks (one page of queries at most — solo
     decode t=1, batch decode t=1, speculative verify t=k+1 all qualify;
-    prefill chunks take the gather+dequant view, which stays
-    flash-eligible), uniform head grouping, and — where the kernel is
-    compiled, not interpreted — a pool whose trailing (n_kv, head_dim) axes
-    fill whole int8 (8, 128) tiles. The TPU's compiler stores only such a
-    pool in the row-major order the kernel's page blocks need; for any other
-    shape it copies the WHOLE pool at every call (seen compiling hd 64 and
-    n_kv 2/4 for v5e), which the gather arm never does."""
-    n_heads, head_dim = q.shape[2], q.shape[3]
+    prefill chunks take the gathered view, which stays flash-eligible),
+    uniform head grouping, and — where the kernel is compiled, not
+    interpreted — a pool whose trailing (n_kv, head_dim) axes fill whole
+    (8, 128) tiles. The TPU's compiler stores only such a pool in the
+    row-major order the kernel's page copies need; for any other shape it
+    copies the WHOLE pool at every call (seen compiling hd 64 and n_kv 2/4
+    for v5e), which the gather arm never does. `heads_dim` is q's
+    (n_heads, head_dim) and `n_kv` the POOL's kv heads: a tp shard's own
+    counts, not the config's."""
+    n_heads, head_dim = heads_dim
     return (
         _pallas_enabled(cfg)
         and t <= ps
-        and n_heads % cfg.n_kv_heads == 0
+        and n_heads % n_kv == 0
         and head_dim % 8 == 0
-        and (
-            cfg.pallas_interpret
-            or (cfg.n_kv_heads % 8 == 0 and head_dim % 128 == 0)
-        )
+        and (cfg.pallas_interpret or (n_kv % 8 == 0 and head_dim % 128 == 0))
     )
 
 
@@ -221,21 +220,23 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     )
     # read: the first kv_len/ps page entries per row
     n_read = max_slots if addr.kv_len is None else min(-(-addr.kv_len // ps), max_slots)
-    if cache.quantized and _fused_paged_eligible(cfg, q, t, ps):
-        # int8 decode: the FUSED kernel reads the pool through the page
-        # table (scalar-prefetch operand) and dequantizes in VMEM — no
-        # materialized page gather, no dequantized KV view in HBM
-        # (ops/pallas_attention.paged_flash_attention)
-        a = paged_flash_attention(
+    if _fused_paged_eligible(cfg, q.shape[2:], cache.k.shape[3], t, ps):
+        # decode-sized: the page-table KERNEL reads the row's live pages of
+        # the pool where they lie (scalar-prefetched table, one copy a page,
+        # many pages a grid step) — no materialized page gather, no KV view
+        # in HBM, and bytes that follow the position, not the bucket
+        # (ops/pallas_attention.paged_decode_attention)
+        a = paged_decode_attention(
             q, cache.k, cache.v, cache.k_scale, cache.v_scale,
             jnp.asarray(li, jnp.int32), positions[:, 0], page_table,
             n_read=n_read, page_size=ps,
             interpret=cfg.pallas_interpret,
         )
         return a, cache
-    # gather them into the contiguous [b, n*ps, h, d] view the attention
-    # math consumes — this gather is the layout's whole read cost (the cost
-    # model counts it; analysis/profiling.py). Unmapped entries clamp to
+    # prefill chunks, tp shards with few local kv heads, no Pallas: gather
+    # them into the contiguous [b, n*ps, h, d] view the attention math
+    # consumes — this gather is the arm's whole read cost (the cost model
+    # counts it; runtime/profiling.py). Unmapped entries clamp to
     # page 0: garbage, causally masked like any junk past a row's pos.
     pages = jnp.maximum(
         jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0

@@ -229,178 +229,322 @@ def flash_attention(
     )
 
 
-# -- fused page-table-aware int8 decode attention ---------------------------
+# -- page-table decode attention over the pool ------------------------------
 #
-# The paged decode arm's HLO formulation materializes a gather of the row's
-# kv_len/ps pages into a [b, n_read*ps, h, d] bf16 view every step. This
-# kernel reads the pool DIRECTLY: the row's int32 page table rides the
-# scalar-prefetch operand, the KV block index map resolves (row, kv-step) ->
-# physical page on the scalar core, and the int8 payload meets its f32
-# per-(token, head) scales in VMEM — so HBM sees int8 payload bytes only, and
-# the jaxpr carries NO gather of the pool (tests/test_kv_quant.py pins this).
+# The paged arm's HLO formulation gathers the row's kv_len/ps pages into a
+# [b, n_read*ps, h, d] view every step and attends over the view: the whole
+# KV BUCKET crosses HBM two and a half times whatever the rows' positions
+# are. This kernel reads the pool where it lies, and only its live pages:
 #
-# What the TPU's compiler allows shapes all of it (tests/test_tpu_compile.py
-# holds the kernel to that compiler at the real pool shape):
-# * a block's last two dims must be (8, 128)-divisible or equal the array's,
-#   so one KV block is one WHOLE page, (ps, n_kv, hd) — a block of one kv head
-#   of eight is refused — and the kernel loops the heads, reading head h's
-#   (ps, hd) slab as a strided ref load;
+# * the pool stays in HBM (`pl.ANY`); a grid step is one batch row, and a
+#   loop inside it walks the row's live BLOCKS of `block` token positions
+#   (8-32 pages), each page one `make_async_copy` through the page ids in the
+#   scalar-prefetched table into a VMEM buffer [block, n_kv, hd]. Two buffers:
+#   the next block's copies (the next live ROW's first block after a row's
+#   last) fly under this block's arithmetic;
+# * bytes follow the position, not the bucket: a row copies the pages up to
+#   its last query position and no other; a parked row (position at or past
+#   the bucket's end) copies nothing. `n_read` only bounds the table;
+# * all kv heads at once: the buffer is read as [block*n_kv, hd] — rows are
+#   (token, kv head) pairs, a reshape that moves no data — against every
+#   query row of the batch row, and a column whose kv head is not the query
+#   row's is masked like a column past its position. Eight times the
+#   multiply-adds of a per-head product, on an MXU whose time is the loading
+#   of K and V either way, and no strided read of one head's slab;
+# * the same arithmetic as `gqa_attention` over the gathered view: products
+#   of the stored values, scores, softmax and the weighted sum in float32.
+#   bf16 x bf16 products are exact in the MXU's f32 accumulator; the f32
+#   probabilities meet bf16 V as three bf16 terms (8 + 8 + 8 mantissa bits,
+#   stacked as rows of one dot). An int8 pool differs in the payload's cast
+#   and in its scales, which multiply the score and probability COLUMNS.
+#
+# What the TPU's compiler allows shapes the rest (tests/test_tpu_compile.py
+# holds the kernel to that compiler at the cells' pool shapes):
 # * the pool is handed over as it is stored: the compiler turns a reshape of
-#   its trailing axes into a copy of the whole pool on every call;
-# * the scale sidecars [L, P, ps, n_kv] f32 are stored by the compiler with
-#   the page axis minor-most, so a kernel operand would also be a whole-array
-#   copy per call. Their pages are gathered in HLO instead (1/32 of the
-#   payload's bytes), head-major, and multiply the score and probability
-#   COLUMNS — cheaper than scaling K and V elementwise.
-# One page per grid step under-fills the int8 (32, 128) tile at ps=16; reading
-# two pages per block is the follow-up ROADMAP S2 records.
+#   its trailing axes into a copy of the whole pool on every call, and stores
+#   only a pool whose trailing (n_kv, hd) axes fill whole (8, 128) tiles in
+#   the row-major order a page copy needs (`kv_arms._fused_paged_eligible`);
+# * the scale sidecars [L, P, ps, n_kv] f32 are stored with the page axis
+#   minor-most, so a kernel operand would also be a whole-array copy per
+#   call. Their pages are gathered in HLO instead (1/32 of the payload).
+
+PAGED_BLOCK_TOKENS = 256  # positions a block; probe_paged_attention.py's sweep
+PAGED_VMEM_BUDGET = 10 * 2**20  # of the 16 MiB a kernel may scope on a v5e
 
 
-def _paged_kernel(
-    m_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_sref, l_sref, acc_ref,
-    *, scale, g, t, ps, n_read, n_kv,
+def _paged_block_pages(
+    block_tokens: int, n_read: int, ps: int, n_kv: int, hd: int, rows: int, itemsize: int
+) -> int:
+    """Pages a block: `block_tokens` positions, halved while the two K and
+    two V buffers and the [rows, block*n_kv] f32 score-sized values (six live
+    at once: scores, probabilities and their three bf16 terms) overrun the
+    budget — a verify block's rows are t times a decode step's."""
+    ppb = max(1, min(block_tokens // ps, n_read))
+
+    def need(ppb):
+        block = ppb * ps
+        return 4 * block * n_kv * hd * itemsize + 6 * rows * block * n_kv * 4
+
+    while ppb > 1 and need(ppb) > PAGED_VMEM_BUDGET:
+        ppb //= 2
+    return ppb
+
+
+def _paged_decode_kernel(
+    m_ref, q_ref, k_hbm, v_hbm, *rest,
+    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt,
 ):
-    """One page's online-softmax update for every kv head of one batch row.
-    m_ref (scalar prefetch) carries [layer, pos_base[b], page_table[b*n_read]];
-    pos_base is each row's FIRST query position (per-row — batch decode's
-    unequal rows share the program). Clamped-page garbage is causally masked
-    for live rows and discarded host-side for parked rows, the XLA paged
-    arm's semantics."""
+    """One batch row's attention over its live pages (see the notes above).
+    m_ref (scalar prefetch) carries [layer, first live row, pos_base[b],
+    live pages[b], next live row[b], page_table[b*n_read]]; pos_base is each
+    row's FIRST query position (batch decode's unequal rows share the
+    program). Stale buffer tails and clamped-page garbage are masked for
+    live rows; a row with no live page writes zeros (discarded host-side)."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, cnt_ref = rest
+    else:
+        o_ref, kbuf, vbuf, sem, cnt_ref = rest
     bi = pl.program_id(0)
-    si = pl.program_id(1)
-    rows = t * g
-    pos_base = m_ref[1 + bi]
+    layer = m_ref[0]
+    block = ppb * ps
+    cols = block * n_kv
+    rows_p, hd = q_ref.shape[1], q_ref.shape[2]
+    POS, LIVE, NEXT, TABLE = 2, 2 + b, 2 + 2 * b, 2 + 3 * b
 
-    @pl.when(si == 0)
+    def copies(row, i, slot, do):
+        """`do` each page copy of block i of `row` into buffer `slot`."""
+        first = i * ppb
+        n = jnp.minimum(ppb, m_ref[LIVE + row] - first)
+
+        def one(p, _):
+            page = m_ref[TABLE + row * n_read + first + p]
+            dst = pl.ds(p * ps, ps)
+            do(pltpu.make_async_copy(
+                k_hbm.at[layer, page], kbuf.at[slot, dst], sem.at[0, slot]))
+            do(pltpu.make_async_copy(
+                v_hbm.at[layer, page], vbuf.at[slot, dst], sem.at[1, slot]))
+            return 0
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    start = lambda row, i, slot: copies(row, i, slot, lambda c: c.start())
+    wait = lambda row, i, slot: copies(row, i, slot, lambda c: c.wait())
+
+    @pl.when(bi == 0)
     def _():
-        m_sref[...] = jnp.full_like(m_sref, NEG_INF)
-        l_sref[...] = jnp.zeros_like(l_sref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # a masked column's probability is 0, and 0 x a NaN left in VMEM is
+        # not: V's buffers start finite (a stale tail then holds pool values)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        cnt_ref[0] = 0
 
-    # page si holds positions [si*ps, (si+1)*ps): visible iff its first
-    # position is <= the row's last query position
-    last_pos = pos_base + (t - 1)
+        @pl.when(m_ref[1] < b)
+        def _():
+            start(m_ref[1], 0, 0)
 
-    @pl.when(si * ps <= last_pos)
-    def _():
-        # query row r is token r // g of the block (q is [n_kv, t*g, hd])
-        row_pos = pos_base + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, ps), 0
-        ) // g
-        col_pos = si * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-        visible = col_pos <= row_pos
-        ks = ks_ref[0, 0]  # [n_kv, ps] f32
-        vs = vs_ref[0, 0]
-        for h in range(n_kv):
-            q = q_ref[0, h].astype(jnp.float32)  # [rows, hd]
-            k = k_ref[0, 0, :, h, :].astype(jnp.float32)  # [ps, hd]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * (ks[h : h + 1, :] * scale)  # [rows, ps]
-            s = jnp.where(visible, s, NEG_INF)
+    # (index arithmetic in lax primitives: jnp's `//`, `%` and `where` are
+    # jitted helpers, each a nested lowering of every decode program's set-up)
+    i32 = jnp.int32
+    n_pages = m_ref[LIVE + bi]
+    n_blk = jax.lax.div(n_pages + (ppb - 1), i32(ppb))
+    base = cnt_ref[0]  # blocks walked before this row: the buffers alternate
+    cnt_ref[0] = base + n_blk
+    nxt = m_ref[NEXT + bi]
+    pos_base = m_ref[POS + bi]
 
-            m_prev = m_sref[h][:, :1]
-            m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
-            m_safe = jnp.maximum(m_cur, NEG_INF / 2)
-            corr = jnp.exp(m_prev - m_safe)
-            p = jnp.where(visible, jnp.exp(s - m_safe), 0.0)
-            l_sref[h] = l_sref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            v = v_ref[0, 0, :, h, :].astype(jnp.float32)
+    q = q_ref[0].astype(cdt)  # [rows_p, hd], rows ordered (kv head, token, g)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_p, 1), 0)
+    row_pos = pos_base + jax.lax.div(jax.lax.rem(r_iota, i32(t * g)), i32(g))  # [rows_p, 1]
+    c_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_p, cols), 1)
+    r_head = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows_p, cols), 0), i32(t * g))
+    # columns are (token, kv head): the other heads' columns are masked for good
+    masked = jnp.full((rows_p, cols), NEG_INF, jnp.float32)
+    head_bias = jax.lax.select(
+        jax.lax.rem(c_iota, i32(n_kv)) == r_head, jnp.zeros_like(masked), masked
+    )
+    col_tok = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), i32(n_kv))
+
+    def body(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(base + i, i32(2))
+
+        # what flies under this block: the row's next, or after its last the
+        # next live row's first
+        more = i + 1 < n_blk
+        ahead = jax.lax.select(more, bi, nxt)
+
+        @pl.when(ahead < b)
+        def _():
+            start(ahead, jax.lax.select(more, i + 1, i32(0)), 1 - slot)
+
+        wait(bi, i, slot)
+        k = kbuf[slot].reshape(cols, hd).astype(cdt)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=None if cdt == jnp.bfloat16 else jax.lax.Precision.HIGHEST,
+        )  # [rows_p, cols]
+        if quantized:
+            s = s * (ks_ref[0, pl.ds(i, 1), :] * scale)
+        else:
+            s = s * scale
+        visible = col_tok + i * block <= row_pos
+        s = jax.lax.select(visible, s + head_bias, masked)
+
+        m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
+        # clamp so a fully-masked ROW (padding, a dead tail) stays finite;
+        # a masked column's exp is then exactly 0
+        m_safe = jnp.maximum(m_cur, NEG_INF / 2)
+        corr = jnp.exp(m_prev - m_safe)
+        p = jnp.exp(s - m_safe)
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, pl.ds(i, 1), :]
+        v = vbuf[slot].reshape(cols, hd)
+        if cdt == jnp.bfloat16:
+            # f32 p x bf16 v, exactly: p = hi + mid + lo in bf16, one dot
+            hi = p.astype(jnp.bfloat16)
+            r1 = p - hi.astype(jnp.float32)
+            mid = r1.astype(jnp.bfloat16)
+            lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+            pv3 = jax.lax.dot_general(
+                jnp.concatenate([hi, mid, lo], axis=0), v.astype(jnp.bfloat16),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )  # [3*rows_p, hd]
+            pv = pv3[:rows_p] + pv3[rows_p : 2 * rows_p] + pv3[2 * rows_p :]
+        else:
             pv = jax.lax.dot_general(
-                p * vs[h : h + 1, :], v, (((1,), (0,)), ((), ())),
+                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [rows, hd]
-            acc_ref[h] = acc_ref[h] * corr + pv
-            m_sref[h] = jnp.broadcast_to(m_safe, m_sref.shape[1:])
+                precision=jax.lax.Precision.HIGHEST,
+            )
+        return m_safe, l_new, acc * corr + pv
 
-    @pl.when(si == n_read - 1)
-    def _():
-        l = jnp.maximum(l_sref[...][:, :, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    m0 = jnp.full((rows_p, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((rows_p, 1), jnp.float32)
+    acc0 = jnp.zeros((rows_p, hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("n_read", "page_size", "scale", "interpret"))
-def paged_flash_attention(
+@partial(
+    jax.jit,
+    static_argnames=("n_read", "page_size", "scale", "block_tokens", "interpret"),
+)
+def paged_decode_attention(
     q: jnp.ndarray,  # [b, t, n_heads, head_dim]
-    k_pool: jnp.ndarray,  # [L, n_pages, ps, n_kv, head_dim] int8
+    k_pool: jnp.ndarray,  # [L, n_pages, ps, n_kv, head_dim] float or int8
     v_pool: jnp.ndarray,
-    k_scale: jnp.ndarray,  # [L, n_pages, ps, n_kv] f32
-    v_scale: jnp.ndarray,
+    k_scale: jnp.ndarray | None,  # [L, n_pages, ps, n_kv] f32 (int8 pools)
+    v_scale: jnp.ndarray | None,
     layer_idx: jnp.ndarray,  # traced scalar int32 — one program for all layers
     pos_base: jnp.ndarray,  # [b] int32: each row's first query position
     page_table: jnp.ndarray,  # [b, >=n_read] int32 (-1 = unmapped)
     n_read: int,  # static page count per row (kv_len / page_size bucket)
     page_size: int,
     scale: float | None = None,
+    block_tokens: int = PAGED_BLOCK_TOKENS,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused page-table-aware int8 GQA decode attention over the pool.
+    """Page-table GQA decode attention over the pool, float or int8.
 
-    Reads the first `n_read` table entries per row THROUGH the scalar
-    prefetch operand — no materialized page gather, no dequantized KV view;
-    per-row positions make solo decode, batch decode, and the speculative
-    verify block all one kernel shape family. Returns [b, t, h, hd] in
-    q.dtype."""
+    Reads each row's live pages — those up to its last query position, of
+    the first `n_read` table entries — THROUGH the scalar-prefetch operand:
+    no materialized page gather, no KV view in HBM; per-row positions make
+    solo decode, batch decode and the speculative verify block one kernel
+    shape family. A row at or past position n_read*ps (parked) reads nothing.
+    Returns [b, t, h, hd] in q.dtype."""
     b, t, n_heads, hd = q.shape
     n_kv = k_pool.shape[3]
     ps = page_size
     g = n_heads // n_kv
-    rows = t * g  # decode-sized q: the whole block is one grid row's queries
+    rows = n_heads * t  # decode-sized q: every query row of a batch row at once
+    rows_p = -(-rows // 16) * 16
+    quantized = k_scale is not None
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
+    # products in bf16 where both sides are stored so (int8 codes are exact in
+    # bf16) — the MXU's accumulator keeps them exactly; else float32
+    cdt = (
+        jnp.bfloat16
+        if q.dtype == jnp.bfloat16 and k_pool.dtype != jnp.float32
+        else jnp.float32
+    )
+    ppb = _paged_block_pages(
+        block_tokens, n_read, ps, n_kv, hd, rows_p, k_pool.dtype.itemsize
+    )
+    n_blocks = -(-n_read // ppb)
+    cols = ppb * ps * n_kv
 
-    # [b, t, kv, g, hd] -> [b, kv, t*g, hd]
-    q4 = (
+    # [b, t, kv, g, hd] -> [b, kv*t*g, hd], padded to whole bf16 tiles of rows
+    q3 = (
         q.reshape(b, t, n_kv, g, hd)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(b, n_kv, rows, hd)
+        .reshape(b, rows, hd)
     )
+    if rows_p != rows:
+        q3 = jax.lax.pad(
+            q3, jnp.zeros((), q3.dtype), ((0, 0, 0), (0, rows_p - rows, 0), (0, 0, 0))
+        )
     li = jnp.asarray(layer_idx, jnp.int32)
+    pos_base = jnp.asarray(pos_base, jnp.int32).reshape(b)
     pages = jnp.maximum(
         jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0
     ).astype(jnp.int32)  # [b, n_read]
-    ks = jnp.swapaxes(k_scale[li, pages], 2, 3)  # [b, n_read, n_kv, ps]
-    vs = jnp.swapaxes(v_scale[li, pages], 2, 3)
+    # a row's live pages: those holding a position <= its last query's
+    live = jax.lax.select(
+        pos_base < n_read * ps,
+        jnp.minimum(jax.lax.div(pos_base + (t - 1), jnp.int32(ps)) + 1, n_read),
+        jnp.zeros_like(pos_base),
+    )
+    idx = jax.lax.select(
+        live > 0, jnp.arange(b, dtype=jnp.int32), jnp.full((b,), b, jnp.int32)
+    )
+    # next live row after each row (b: none), and the first of all
+    nxt = jax.lax.cummin(jnp.concatenate([idx[1:], jnp.full((1,), b, jnp.int32)]), reverse=True)
     meta = jnp.concatenate(
-        [
-            li.reshape(1),
-            jnp.asarray(pos_base, jnp.int32).reshape(b),
-            pages.reshape(b * n_read),
-        ]
+        [li.reshape(1), jnp.min(idx).reshape(1), pos_base, live, nxt,
+         pages.reshape(b * n_read)]
     )
 
-    def page_map(bi, si, m):
-        return (m[0], m[1 + b + bi * n_read + si], 0, 0, 0)
+    q_spec = pl.BlockSpec((1, rows_p, hd), lambda bi, m: (bi, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, pool_spec, pool_spec]
+    operands = [meta, q3, k_pool, v_pool]
+    if quantized:
+        # head-minor like the buffer's rows: [b, block, (token, kv head)]
+        def cols_of(sc):
+            sc = sc[li, pages]  # [b, n_read, ps, n_kv]
+            if n_read % ppb:  # whole blocks (the tail's columns are masked)
+                sc = jnp.pad(sc, ((0, 0), (0, n_blocks * ppb - n_read), (0, 0), (0, 0)))
+            return sc.reshape(b, n_blocks, cols)
 
-    q_spec = pl.BlockSpec((1, n_kv, rows, hd), lambda bi, si, m: (bi, 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, n_kv, ps), lambda bi, si, m: (bi, si, 0, 0))
+        scale_spec = pl.BlockSpec((1, n_blocks, cols), lambda bi, m: (bi, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        operands += [cols_of(k_scale), cols_of(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, n_read),
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, ps, n_kv, hd), page_map),
-            pl.BlockSpec((1, 1, ps, n_kv, hd), page_map),
-            scale_spec,
-            scale_spec,
-        ],
+        grid=(b,),
+        in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((n_kv, rows, 128), jnp.float32),  # running row max
-            pltpu.VMEM((n_kv, rows, 128), jnp.float32),  # running exp-sum
-            pltpu.VMEM((n_kv, rows, hd), jnp.float32),  # weighted-V accumulator
+            pltpu.VMEM((2, ppb * ps, n_kv, hd), k_pool.dtype),
+            pltpu.VMEM((2, ppb * ps, n_kv, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),  # blocks walked so far
         ],
     )
     out = pl.pallas_call(
         partial(
-            _paged_kernel, scale=scale, g=g, t=t, ps=ps, n_read=n_read, n_kv=n_kv
+            _paged_decode_kernel, scale=scale, g=g, t=t, ps=ps, ppb=ppb,
+            n_read=n_read, n_kv=n_kv, b=b, quantized=quantized, cdt=cdt,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows_p, hd), q.dtype),
         interpret=interpret,
-    )(meta, q4, k_pool, v_pool, ks, vs)
+        name="paged_decode_attention",
+    )(*operands)
     return (
-        out.reshape(b, n_kv, t, g, hd)
+        out[:, :rows]
+        .reshape(b, n_kv, t, g, hd)
         .transpose(0, 2, 1, 3, 4)
         .reshape(b, t, n_heads, hd)
     )

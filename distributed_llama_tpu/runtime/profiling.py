@@ -547,50 +547,33 @@ def _dot_flops(eqn, mult: float) -> float:
 
 
 def _paged_kernel_census(eqn, in_hbm):
-    """Recognize the fused page-table-aware decode kernel
-    (ops/pallas_attention.paged_flash_attention) by operand signature — the
-    ONE pallas_call whose HBM reads happen *inside* the kernel (the HLO page
-    gather the fusion removed) — and price them at STORED width: per grid
-    cell one whole page of int8 payload, for K and V (the f32 scale pages
-    are gathered in HLO beside the call and priced there like any gather).
-    Returns ``(bytes, body_grid_mult)`` or None (any other pallas_call keeps
-    the generic sub-jaxpr handling). Without this the fused program's KV
-    reads would census as ZERO bytes — the quantized roofline would flatter
-    itself by exactly the traffic it claims to save."""
-    import numpy as np
-
-    pools = [
-        v
-        for v, res in zip(eqn.invars, in_hbm)
-        if res
-        and getattr(v.aval, "ndim", 0) == 5
-        and v.aval.dtype == np.int8
-    ]
-    if len(pools) != 2:
+    """Recognize the page-table decode kernel
+    (ops/pallas_attention.paged_decode_attention) by its name — the ONE
+    pallas_call whose HBM reads happen *inside* the kernel (the HLO page
+    gather it removed) — and price them at STORED width, float or int8. The
+    kernel copies a row's live pages and no other, so the census prices what
+    it BOUNDS: every block of every row whole, K and V (an int8 pool's f32
+    scale pages are gathered in HLO beside the call and priced there like
+    any gather). Returns ``(bytes, blocks)`` — the blocks a call may walk,
+    each running the body's dots once — or None (any other pallas_call keeps
+    the generic sub-jaxpr handling). Without this the program's KV reads
+    would census as ZERO bytes — the roofline would flatter itself by
+    exactly the traffic the kernel moves."""
+    # operands: meta, q [b, rows, hd], K pool, V pool[, scales]; the kernel's
+    # K buffer [2, block, n_kv, hd] is its first scratch operand
+    if eqn.params.get("name") != "paged_decode_attention" or not all(in_hbm[2:4]):
         return None
-    meta = next(
-        (
-            v
-            for v in eqn.invars
-            if getattr(v.aval, "ndim", 0) == 1 and v.aval.dtype == np.int32
-        ),
-        None,
+    meta, q, pool = (v.aval for v in eqn.invars[:3])
+    b, (_, _, ps, n_kv, hd) = q.shape[0], pool.shape
+    block = next(
+        v.aval.shape[1]
+        for v in eqn.params["jaxpr"].invars
+        if tuple(v.aval.shape[2:]) == (n_kv, hd) and v.aval.shape[0] == 2
     )
-    # q and both gathered scale operands are 4-D floats that lead with b
-    batched = next(
-        (
-            v
-            for v in eqn.invars
-            if getattr(v.aval, "ndim", 0) == 4 and v.aval.dtype.kind == "f"
-        ),
-        None,
-    )
-    if meta is None or batched is None:
-        return None
-    _, _, ps, n_kv, hd = pools[0].aval.shape
-    # grid = (b, n_read); meta = [layer, pos_base[b], page_table[b*n_read]]
-    grid = int(meta.aval.size) - 1 - batched.aval.shape[0]
-    return 2 * grid * ps * n_kv * hd, grid
+    # meta = [layer, first live row, pos_base[b], live[b], next[b], table[b*n_read]]
+    n_read = (int(meta.size) - 2 - 3 * b) // b
+    blocks = b * -(-n_read * ps // block)
+    return 2 * blocks * block * n_kv * hd * pool.dtype.itemsize, blocks
 
 
 def _census_walk(jaxpr, mult: float, hbm: dict, acc: dict) -> None:
@@ -615,12 +598,12 @@ def _census_walk(jaxpr, mult: float, hbm: dict, acc: dict) -> None:
             in_hbm = [hbm.get(id(v), False) for v in eqn.invars]
             pk = _paged_kernel_census(eqn, in_hbm)
             if pk is not None:
-                pool_bytes, grid = pk
+                pool_bytes, blocks = pk
                 acc["bytes"] += pool_bytes * mult
-                # kernel body flops run once per grid cell (refs carry no
-                # residency — bytes are fully owned by the pricing above)
+                # the body's dots run once a block (refs carry no residency
+                # — bytes are fully owned by the pricing above)
                 for sub in _sub_jaxprs(eqn):
-                    _census_walk(sub, mult * grid, {}, acc)
+                    _census_walk(sub, mult * blocks, {}, acc)
                 continue
         subs = list(_sub_jaxprs(eqn))
         if subs:

@@ -70,9 +70,11 @@ def test_one_chip_rehearsal_fails_for_the_device_check_alone(rehearsals):
 def test_one_chip_rehearsal_runs_every_phase(rehearsals):
     _, lines, _ = rehearsals["one"]
     checks = [l["check"] for l in _by_phase(lines, "numbers")]
-    assert len(checks) == 4 and any("int8 page-table kernel" in c for c in checks)
+    assert len(checks) == 5
+    for store in ("int8", "bfloat16"):  # the page-table kernel alone, both pools
+        assert f"{store} page-table kernel vs gather" in checks
     gen = next(l for l in _by_phase(lines, "numbers") if "generation" in l["check"])
-    assert gen["int8_decode_arm"] == "fused page-table kernel"
+    assert gen["decode_arm"] == "page-table kernel"
     assert gen["leading_tokens_equal"] >= gen["bound"]
     kernels = _by_phase(lines, "kernels")
     assert [k["program"].split("[")[0] for k in kernels] == [

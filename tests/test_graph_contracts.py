@@ -151,6 +151,17 @@ def test_repo_goldens_cover_the_default_config():
     assert gd.main(["--check", "--coverage"]) == 0
 
 
+def test_repo_golden_pins_the_interpret_mode_kernels(monkeypatch):
+    """The one golden traced WITH Pallas (interpret mode, int8 paged) holds
+    the kernels' bodies — the page-table decode kernel's among them — so a
+    kernel edit that forgets to re-bless it fails here, not only in
+    scripts/ci_check.sh (it went stale unseen at PR 30)."""
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    assert gd.main(
+        ["--check", "--coverage", "--kv-layout", "paged", "--kv-dtype", "int8"]
+    ) == 0
+
+
 # -- the differential equivalence prover -------------------------------------
 
 

@@ -189,6 +189,53 @@ def test_arm_writes_its_rows_and_attends(arm, quantized, per_row):
             np.testing.assert_array_equal(rows_got[2], rows_before[2])
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+def test_paged_arm_takes_the_kernel_and_agrees_with_the_gather_arm(quantized):
+    """Under `pallas_interpret` a decode-sized call of the paged arm — float
+    cache or int8 — dispatches the page-table kernel (no gather of the pool
+    in its program) and gives the gather arm's attention and cache."""
+    import jax
+
+    from distributed_llama_tpu.analysis.graph_audit import pool_gather_count
+
+    rng = np.random.default_rng(32)
+    cfg = _cfg(quantized)
+    table = _page_table()
+    pos = np.array([5, 0, S], np.int32)  # row 2 parked: it reads nothing
+    positions = jnp.asarray(pos[:, None])
+    q, k, v = (
+        jnp.asarray(rng.standard_normal((B, 1, n, HD), dtype=np.float32))
+        for n in (N_HEADS, N_KV, N_KV)
+    )
+    bufs = {}
+    for name in ("k", "v"):
+        payload, scale, _ = _stored(
+            rng.standard_normal((L, N_PAGES, PS, N_KV, HD), dtype=np.float32), quantized
+        )
+        bufs[name] = payload
+        if quantized:
+            bufs[name + "_scale"] = scale
+    cache = KVCache(**{n: jnp.asarray(a) for n, a in bufs.items()})
+    addr = CacheAddr(layer=LAYER, kv_len=KV_LEN, page_table=jnp.asarray(table), page_size=PS)
+
+    def run(cfg):
+        return paged_arm(cfg, cache, addr, q, k, v, positions, jnp.asarray(pos))
+
+    kernel_cfg = cfg.with_(pallas_interpret=True)
+    kernel_program = jax.make_jaxpr(lambda: run(kernel_cfg))()
+    gather_program = jax.make_jaxpr(lambda: run(cfg))()
+    assert "pallas_call" in str(kernel_program)
+    assert "pallas_call" not in str(gather_program)
+    assert pool_gather_count(kernel_program, cache.k.shape) == 0
+    assert pool_gather_count(gather_program, cache.k.shape) == 2
+    (a_k, cache_k), (a_g, cache_g) = run(kernel_cfg), run(cfg)
+    np.testing.assert_allclose(np.asarray(a_k)[:2], np.asarray(a_g)[:2], rtol=1e-5, atol=1e-5)
+    for name in bufs:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(cache_k, name)), np.asarray(getattr(cache_g, name))
+        )
+
+
 @pytest.mark.parametrize(
     "addr,arm",
     [

@@ -24,7 +24,7 @@ from distributed_llama_tpu.ops.kv_quant import (
     dequantize_kv,
     quantize_kv,
 )
-from distributed_llama_tpu.ops.pallas_attention import paged_flash_attention
+from distributed_llama_tpu.ops.pallas_attention import paged_decode_attention
 from distributed_llama_tpu.runtime.batch_session import BatchSession
 from distributed_llama_tpu.runtime.engine import InferenceEngine
 from distributed_llama_tpu.runtime.paged_kv import (
@@ -129,17 +129,31 @@ def test_pool_wire_roundtrip_f32():
 # -- fused kernel numerics ----------------------------------------------------
 
 
-def _build_pool(rng, k_lin, v_lin, tables, L, n_pages, ps, layer):
-    """Quantize linear [b, S, h, d] KV and place it page by page at the
-    physical slots `tables` names (the pool rows OTHER layers/pages hold
-    garbage, which the layer index / causal mask must ignore)."""
+def _build_pool(rng, k_lin, v_lin, tables, L, n_pages, ps, layer, dtype=jnp.int8):
+    """Store linear [b, S, h, d] KV as `dtype` stores it (int8: quantized,
+    with scale sidecars; float: a cast, scales None) and place it page by
+    page at the physical slots `tables` names (the pool rows OTHER
+    layers/pages hold garbage, which the layer index / causal mask must
+    ignore). Returns the pools and the values a reader sees."""
     b, S, n_kv, hd = k_lin.shape
-    kq, ks = quantize_kv(jnp.asarray(k_lin))
-    vq, vs = quantize_kv(jnp.asarray(v_lin))
-    kp = rng.integers(-127, 127, (L, n_pages, ps, n_kv, hd)).astype(np.int8)
-    vp = rng.integers(-127, 127, (L, n_pages, ps, n_kv, hd)).astype(np.int8)
-    ksp = rng.random((L, n_pages, ps, n_kv), np.float32)
-    vsp = rng.random((L, n_pages, ps, n_kv), np.float32)
+    shape = (L, n_pages, ps, n_kv, hd)
+    if dtype == jnp.int8:
+        kq, ks = quantize_kv(jnp.asarray(k_lin))
+        vq, vs = quantize_kv(jnp.asarray(v_lin))
+        ref_k = np.asarray(dequantize_kv(kq, ks))
+        ref_v = np.asarray(dequantize_kv(vq, vs))
+        kp = rng.integers(-127, 127, shape).astype(np.int8)
+        vp = rng.integers(-127, 127, shape).astype(np.int8)
+        ksp = rng.random(shape[:-1], np.float32)
+        vsp = rng.random(shape[:-1], np.float32)
+    else:
+        kq, vq = (np.asarray(jnp.asarray(x).astype(dtype)) for x in (k_lin, v_lin))
+        ks = vs = ksp = vsp = None
+        ref_k, ref_v = kq.astype(np.float32), vq.astype(np.float32)
+        kp, vp = (
+            np.array(jnp.asarray(rng.standard_normal(shape, np.float32) * 8).astype(dtype))
+            for _ in "kv"
+        )
     for row in range(b):
         for si in range(S // ps):
             pg = tables[row, si]
@@ -148,18 +162,21 @@ def _build_pool(rng, k_lin, v_lin, tables, L, n_pages, ps, layer):
             sl = slice(si * ps, (si + 1) * ps)
             kp[layer, pg] = np.asarray(kq)[row, sl]
             vp[layer, pg] = np.asarray(vq)[row, sl]
-            ksp[layer, pg] = np.asarray(ks)[row, sl]
-            vsp[layer, pg] = np.asarray(vs)[row, sl]
-    ref_k = np.asarray(dequantize_kv(kq, ks))
-    ref_v = np.asarray(dequantize_kv(vq, vs))
+            if ks is not None:
+                ksp[layer, pg] = np.asarray(ks)[row, sl]
+                vsp[layer, pg] = np.asarray(vs)[row, sl]
     return kp, vp, ksp, vsp, ref_k, ref_v
+
+
+def _as_jnp(*arrays):
+    return [None if a is None else jnp.asarray(a) for a in arrays]
 
 
 @pytest.mark.parametrize("t,pos0", [(1, (37, 50)), (4, (16, 33))],
                          ids=["decode_t1", "verify_t4"])
 def test_paged_flash_attention_matches_reference(t, pos0):
-    """The fused kernel over a shuffled page table + garbage-filled pool
-    equals gqa_attention over the dequantized contiguous view, for solo
+    """The page-table kernel over a shuffled page table + garbage-filled int8
+    pool equals gqa_attention over the dequantized contiguous view, for solo
     decode (t=1, unequal row positions) and the verify block shape."""
     rng = np.random.default_rng(2)
     L, n_pages, ps, n_kv, hd, heads, b, n_read = 2, 8, 16, 2, 32, 4, 2, 4
@@ -170,7 +187,7 @@ def test_paged_flash_attention_matches_reference(t, pos0):
     tables = np.array([[3, 0, 5, 2], [1, 6, 4, 7]], np.int32)
     kp, vp, ksp, vsp, ref_k, ref_v = _build_pool(
         rng, k_lin, v_lin, tables, L, n_pages, ps, layer=1)
-    out = paged_flash_attention(
+    out = paged_decode_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(ksp),
         jnp.asarray(vsp), jnp.int32(1), jnp.asarray(pos0, jnp.int32),
         jnp.asarray(tables), n_read=n_read, page_size=ps, interpret=True,
@@ -182,6 +199,57 @@ def test_paged_flash_attention_matches_reference(t, pos0):
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
+
+
+#: stored dtype -> tolerance against gqa_attention over the SAME stored
+#: values: float32 arithmetic either way, so f32 and int8 storage hold the
+#: int8 test's 1e-4; a bf16 pool is met by bf16 queries and the result is
+#: rounded to bf16 on both sides (half a bf16 step of an O(1) output: 4e-3)
+PAGED_TOL = {"float32": 1e-4, "bfloat16": 4e-3, "int8": 1e-4}
+
+
+@pytest.mark.parametrize("g", [4, 5])
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("store", sorted(PAGED_TOL))
+def test_paged_decode_attention_matches_reference(store, t, g):
+    """One body for every stored dtype: rows at unequal positions that end in
+    different blocks (two pages a block here), one row parked at seq_len,
+    one with unmapped (-1) table entries past its position, a shuffled table
+    over a garbage-filled pool — against gqa_attention over the contiguous
+    view of what the pool stores."""
+    rng = np.random.default_rng(32)
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "int8": jnp.int8}[store]
+    L, n_pages, ps, n_kv, hd, b, n_read = 2, 40, 8, 2, 32, 4, 8
+    heads, S = n_kv * g, n_read * ps  # the bucket: 64 positions, 4 blocks
+    k_lin = rng.standard_normal((b, S, n_kv, hd)).astype(np.float32)
+    v_lin = rng.standard_normal((b, S, n_kv, hd)).astype(np.float32)
+    qdt = jnp.bfloat16 if store == "bfloat16" else jnp.float32
+    q = jnp.asarray(rng.standard_normal((b, t, heads, hd)).astype(np.float32)).astype(qdt)
+    tables = rng.permutation(n_pages)[: b * n_read].reshape(b, n_read).astype(np.int32)
+    # row 0 ends in the last block, row 1 in the first, row 2 is parked at
+    # seq_len (>= the bucket: it reads nothing), row 3 ends in the second
+    # block and has no page mapped past it
+    pos0 = np.array([S - t - 1, 3, S, 2 * ps + 5], np.int32)
+    tables[3, 4:] = -1
+    kp, vp, ksp, vsp, ref_k, ref_v = _build_pool(
+        rng, k_lin, v_lin, tables, L, n_pages, ps, layer=1, dtype=dtype)
+    out = paged_decode_attention(
+        q, *_as_jnp(kp, vp, ksp, vsp), jnp.int32(1), jnp.asarray(pos0),
+        jnp.asarray(tables), n_read=n_read, page_size=ps, block_tokens=2 * ps,
+        interpret=True,
+    )
+    positions = pos0[:, None] + np.arange(t)[None, :]
+    ref = gqa_attention(
+        q, jnp.asarray(ref_k).astype(qdt), jnp.asarray(ref_v).astype(qdt),
+        jnp.asarray(positions, jnp.int32),
+    )
+    live = [0, 1, 3]
+    assert out.dtype == q.dtype and np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32)[live], np.asarray(ref, np.float32)[live],
+        rtol=PAGED_TOL[store], atol=PAGED_TOL[store])
+    # the parked row copied nothing and attended to nothing
+    assert not np.asarray(out, np.float32)[2].any()
 
 
 def test_paged_flash_attention_masks_unmapped_pages():
@@ -199,7 +267,7 @@ def test_paged_flash_attention_masks_unmapped_pages():
     tables = np.array([[2, 5, -1, -1]], np.int32)
     kp, vp, ksp, vsp, ref_k, ref_v = _build_pool(
         rng, k_lin, v_lin, tables[:, :2], L, n_pages, ps, layer=0)
-    out = paged_flash_attention(
+    out = paged_decode_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(ksp),
         jnp.asarray(vsp), jnp.int32(0), jnp.asarray(pos0, jnp.int32),
         jnp.asarray(tables), n_read=n_read, page_size=ps, interpret=True,
@@ -398,48 +466,50 @@ def _count_pool_ops(jaxpr, pool_shape, acc):
 @pytest.mark.analysis
 def test_int8_decode_is_gather_free_and_census_prices_it(model_path,
                                                           monkeypatch):
-    """THE roofline pin: the int8 paged decode program carries ZERO
-    materialized pool gathers (the page table rides the kernel's scalar
-    prefetch) while the float twin gathers its page view; the census prices
-    the fused kernel's pool reads at STORED width (int8+scale < float), and
-    a planted removal of the census special case is caught — the kernel's
-    bytes would silently drop out of the roofline."""
+    """THE roofline pin: the paged decode program that takes the page-table
+    kernel — int8 pool or float — carries ZERO materialized pool gathers
+    (the page table rides the kernel's scalar prefetch) while the no-Pallas
+    float twin gathers its page view; the census prices the kernel's pool
+    reads at STORED width (int8+scale < float), and a planted removal of
+    the census special case is caught — the kernel's bytes would silently
+    drop out of the roofline."""
     from distributed_llama_tpu.analysis import graph_audit as ga
     from distributed_llama_tpu.runtime import profiling
 
+    eg = _engine(model_path, "paged")  # no Pallas on the CPU: the gather arm
     monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
     e8 = _engine(model_path, "paged", cache_dtype="int8")
     ef = _engine(model_path, "paged")
     try:
-        ent8 = [e for e in ga.warm_key_ladder(e8) if e.kind == "decode"][0]
-        entf = [e for e in ga.warm_key_ladder(ef) if e.kind == "decode"][0]
-        j8 = ga.trace_entry(e8, ent8)
-        jf = ga.trace_entry(ef, entf)
-        acc8 = {"pallas": 0, "pool_gather": 0}
-        accf = {"pallas": 0, "pool_gather": 0}
-        _count_pool_ops(j8.jaxpr, tuple(e8.cache.k.shape), acc8)
-        _count_pool_ops(jf.jaxpr, tuple(ef.cache.k.shape), accf)
-        assert acc8["pallas"] >= 1 and acc8["pool_gather"] == 0, acc8
-        assert accf["pool_gather"] >= 1, accf
+        jaxprs, accs = {}, {}
+        for name, eng in (("int8", e8), ("float", ef), ("gather", eg)):
+            ent = [e for e in ga.warm_key_ladder(eng) if e.kind == "decode"][0]
+            jaxprs[name] = ga.trace_entry(eng, ent)
+            accs[name] = {"pallas": 0, "pool_gather": 0}
+            _count_pool_ops(jaxprs[name].jaxpr, tuple(eng.cache.k.shape), accs[name])
+        for name in ("int8", "float"):
+            assert accs[name]["pallas"] >= 1 and accs[name]["pool_gather"] == 0, accs
+        assert accs["gather"]["pool_gather"] >= 1, accs
         # census honesty: stored width makes the int8 decode strictly
         # cheaper in modeled bytes than the float twin of the same shape
-        b8 = profiling.jaxpr_census(j8)["bytes"]
-        bf = profiling.jaxpr_census(jf)["bytes"]
+        b8 = profiling.jaxpr_census(jaxprs["int8"])["bytes"]
+        bf = profiling.jaxpr_census(jaxprs["float"])["bytes"]
         assert b8 < bf
-        # planted failure: without the fused-kernel census case the pool
-        # reads vanish from the model entirely
+        # planted failure: without the kernel's census case the pool reads
+        # vanish from the model entirely, whatever the pool stores
         monkeypatch.setattr(profiling, "_paged_kernel_census",
                             lambda eqn, in_hbm: None)
-        assert profiling.jaxpr_census(j8)["bytes"] < b8
+        assert profiling.jaxpr_census(jaxprs["int8"])["bytes"] < b8
+        assert profiling.jaxpr_census(jaxprs["float"])["bytes"] < bf
     finally:
-        e8.close(), ef.close()
+        e8.close(), ef.close(), eg.close()
 
 
 @pytest.mark.analysis
 def test_dot_census_sees_inside_fused_kernel():
-    """graph_audit's dot census descends into pallas_call: the fused kernel
-    contributes exactly its qk^T and pV dots — one pair per kv head, which
-    the kernel loops — and a planted extra dot next to it is visible (the
+    """graph_audit's dot census descends into pallas_call: the page-table
+    kernel contributes exactly its qk^T and pV dots — one pair, every kv
+    head at once — and a planted extra dot next to it is visible (the
     f32_dot_budget regression class)."""
     from distributed_llama_tpu.analysis import graph_audit as ga
 
@@ -451,12 +521,12 @@ def test_dot_census_sees_inside_fused_kernel():
     tab = jnp.asarray([[0, 1]], jnp.int32)
 
     def run(q):
-        return paged_flash_attention(
+        return paged_decode_attention(
             q, kp, kp, sc, sc, jnp.int32(0), jnp.asarray([0], jnp.int32),
             tab, n_read=n_read, page_size=ps, interpret=True)
 
     dots = ga.dot_input_census(jax.make_jaxpr(run)(q))
-    assert sum(dots.values()) == 2 * n_kv, dots
+    assert sum(dots.values()) == 2, dots
 
     def planted(q):
         o = run(q)
@@ -464,7 +534,7 @@ def test_dot_census_sees_inside_fused_kernel():
         return o + jnp.sum(extra) * 0
 
     dots = ga.dot_input_census(jax.make_jaxpr(planted)(q))
-    assert sum(dots.values()) == 2 * n_kv + 1, dots
+    assert sum(dots.values()) == 3, dots
 
 
 # -- analysis integration: audit, costs, sanitizer ----------------------------

@@ -81,13 +81,23 @@ def _flash(t, cache_len):
     return build
 
 
-def _paged(b, t, n_read):
+def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS):
+    """The page-table decode kernel over a pool of the served shape: int8
+    with its f32 scale sidecars, or bf16 (the cells' cache)."""
+
     def build(S):
-        pool = S((LAYERS, 2048, PAGE, KV_HEADS, HEAD_DIM), jnp.int8)
-        scale = S((LAYERS, 2048, PAGE, KV_HEADS), jnp.float32)
-        fn = lambda *a: pa.paged_flash_attention(*a, n_read=n_read, page_size=PAGE)
+        pool = S((layers, 2048, PAGE, KV_HEADS, HEAD_DIM), dtype)
+        scale = S((layers, 2048, PAGE, KV_HEADS), jnp.float32)
+        scales = [scale, scale] if dtype == jnp.int8 else []
+
+        def fn(q, k, v, *rest):
+            ks, vs = rest[:2] if scales else (None, None)
+            return pa.paged_decode_attention(
+                q, k, v, ks, vs, *rest[len(scales):], n_read=n_read, page_size=PAGE
+            )
+
         return fn, [
-            S((b, t, HEADS, HEAD_DIM), jnp.bfloat16), pool, pool, scale, scale,
+            S((b, t, heads, HEAD_DIM), jnp.bfloat16), pool, pool, *scales,
             S((), jnp.int32), S((b,), jnp.int32), S((b, 256), jnp.int32),
         ]
 
@@ -128,10 +138,23 @@ CASES = {
     },
     "flash-t512-S4096": _flash(512, 4096),
     "flash-t64-S4096": _flash(64, 4096),
-    # the int8 page-table kernel: solo / batch decode and verify blocks
+    # the page-table kernel over an int8 pool: solo / batch decode and verify blocks
     **{
         f"paged-b{b}-t{t}-read{n}": _paged(b, t, n)
         for b, t, n in ((1, 1, 8), (4, 1, 64), (8, 1, 256), (1, 5, 64), (8, 9, 64))
+    },
+    # and over the cells' own bf16 pools (PR 32): 16 rows of 4 query heads a
+    # kv head in the KV buckets 1024 and 2048 (Qwen3-8B), 8 rows of 5
+    # (Qwen3-14B: 40 heads, 40 layers), and a verify block of 9
+    **{
+        f"paged-bf16-{model}-b{b}-t{t}-read{n}": _paged(
+            b, t, n, dtype=jnp.bfloat16, heads=heads, layers=layers
+        )
+        for model, b, t, n, heads, layers in (
+            ("8b", 16, 1, 64, HEADS, LAYERS), ("8b", 16, 1, 128, HEADS, LAYERS),
+            ("14b", 8, 1, 64, 40, 40), ("14b", 8, 1, 128, 40, 40),
+            ("8b", 16, 9, 64, HEADS, LAYERS),
+        )
     },
 }
 
@@ -145,5 +168,5 @@ def test_kernel_compiles_for_v5e(v5e, case):
     if case.startswith("paged"):
         # the pool is read where it lies: a reshaped or re-laid-out operand
         # shows up as a copy of the whole pool (GBs) in the program's temps
-        pool_bytes = LAYERS * 2048 * PAGE * KV_HEADS * HEAD_DIM
+        pool_bytes = args[1].size * args[1].dtype.itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 64
