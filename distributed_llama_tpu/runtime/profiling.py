@@ -683,10 +683,10 @@ def build_cost_table(engine, plan=None) -> CostTable:
     """Lower + compile every program in `plan` (default: the engine's full
     ``warm_plan()``) and collect XLA's cost/memory analyses. Compilation is
     AOT — nothing executes, no device arrays move — but it IS compile work,
-    done several programs at a time: the TPU compiler spends ~25 s on ONE
-    thread for every program that holds the sampler's vocabulary-wide sort
-    (each decode program does), so a serial pass over a 4k-context ladder
-    is half an hour and a pass on every core a few minutes. The programs
+    done several programs at a time: the TPU compiler works on ONE thread
+    a program (seconds for a step program of a deep model), so a serial
+    pass over a 4k-context ladder is many minutes and a pass on every core
+    a few. The programs
     land in the persistent compilation cache
     (engine.enable_compilation_cache), which is why `serve()` builds the
     table BEFORE warm-up: warm-up's dispatches then load what was compiled

@@ -170,3 +170,18 @@ def test_kernel_compiles_for_v5e(v5e, case):
         # shows up as a copy of the whole pool (GBs) in the program's temps
         pool_bytes = args[1].size * args[1].dtype.itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 64
+
+
+@pytest.mark.parametrize("rows", (8, 16))
+def test_sampler_compiles_for_v5e_without_a_sort(v5e, rows):
+    """The cells' sampler at the real vocabulary (PR 35): the TPU compiler
+    took ~27 s on one thread for a program holding the two vocabulary-wide
+    sorts, in each of a ladder's decode programs; the threshold search
+    leaves none."""
+    from distributed_llama_tpu.ops.sampling import sample_logits_per_row
+
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    args = [S((rows, VOCAB), jnp.float32), S((rows, 2), jnp.uint32),
+            S((rows,), jnp.float32), S((rows,), jnp.float32)]
+    text = jax.jit(sample_logits_per_row).lower(*args).compile().as_text()
+    assert " sort(" not in text
