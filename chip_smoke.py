@@ -81,6 +81,7 @@ MIN_TOP1_AGREEMENT = 0.75
 MAX_LOGIT_DIFF_STD = 0.3
 # page-table kernel vs gather(+dequant) on one random pool, int8 and bf16
 # (bf16 output; the int8 per-page kernel measured 0.0055, PR 21)
+MAX_GDN_DIFF = 2e-4  # float32 arithmetic on both sides; sound runs read ~1e-5
 MAX_PAGED_KERNEL_DIFF = 0.03
 # int8 KV vs bf16 KV, greedy: leading tokens that must agree. int8 rounding
 # parts two near-tied random-weight logits sooner or later (measured: after
@@ -269,6 +270,42 @@ def phase_numbers(cut_model: str, tokenizer: str, rehearse: bool) -> None:
         )
         if not kdiff <= MAX_PAGED_KERNEL_DIFF:
             fail(f"numbers/paged kernel ({store}): max diff {kdiff}")
+
+    # (b') the gated-delta decode kernel (ops/pallas_gdn.py) against the
+    # recurrence it implements, at Olmo-Hybrid-7B's heads (30 x 96 x 192; 4
+    # rows, layer 1 of 2), and the chunked form a prompt's chunk takes
+    # against the same recurrence over 128 positions: float32 throughout, so
+    # the bound is float32's rounding over a state of norm ~10
+    from distributed_llama_tpu.ops import gated_delta as gdn
+    from distributed_llama_tpu.ops.pallas_gdn import gdn_decode_step
+    from distributed_llama_tpu.testing import gdn_recurrence
+
+    gh, gk, gv = (6, 32, 64) if rehearse else (30, 96, 192)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s, dtype=np.float32))  # noqa: E731
+    gq = gdn.l2_normalize(f32(4, 128, gh, gk)) * gk**-0.5
+    gkk, gvv = gdn.l2_normalize(f32(4, 128, gh, gk)), f32(4, 128, gh, gv)
+    gla, gbeta = gdn.gdn_gates(f32(4, 128, gh) * 3, f32(4, 128, gh) * 3,
+                               jnp.zeros(gh), jnp.zeros(gh), True)
+    s0 = f32(4, gk, gh * gv)
+    o_ref, s_ref = gdn_recurrence(s0, gq, gkk, gvv, gla, gbeta)
+    o_chk, s_chk = jax.jit(gdn.gdn_chunked)(s0, gq, gkk, gvv, gla, gbeta)
+    o_k, rec = gdn_decode_step(
+        jnp.stack([s0 * 2.0, s0]), 1, gq[:, 0], gkk[:, 0], gvv[:, 0],
+        jnp.exp(gla[:, 0]), gbeta[:, 0], jnp.ones((4,), bool), interpret=interp,
+    )
+    o_1, s_1 = gdn_recurrence(s0, gq[:, :1], gkk[:, :1], gvv[:, :1], gla[:, :1], gbeta[:, :1])
+    diffs = {
+        "kernel_o": float(jnp.max(jnp.abs(o_k - o_1[:, 0]))),
+        "kernel_state": float(jnp.max(jnp.abs(rec[1] - s_1))),
+        "kernel_other_layer": float(jnp.max(jnp.abs(rec[0] - s0 * 2.0))),
+        "chunked_o": float(jnp.max(jnp.abs(o_chk - o_ref))),
+        "chunked_state": float(jnp.max(jnp.abs(s_chk - s_ref))),
+    }
+    say("numbers", check="gated-delta kernel and chunked form vs the recurrence",
+        heads=[gh, gk, gv], **{k: float(f"{v:.3g}") for k, v in diffs.items()},
+        bound=MAX_GDN_DIFF)
+    if not max(diffs.values()) <= MAX_GDN_DIFF:
+        fail(f"numbers/gated delta: {diffs}")
 
     # (c) a short paged int8 generation vs the bf16-paged one (host loop:
     # greedy argmax of the t=1 forward, the program the kernel serves)

@@ -225,14 +225,17 @@ def trace_entry(engine, entry: LadderEntry):
             # row's one-row page-table slice (engine._dispatch_prefill_row)
             from ..models.transformer import forward
 
-            fn = lambda toks, pos, pt: forward(
+            # (a hybrid model's is also told whose recurrent-state slot the
+            # row is: one more scalar operand)
+            fn = lambda toks, pos, pt, *row: forward(
                 cfg, engine.params, engine.rope, engine.cache, toks, pos,
                 logits_mode="last", kv_len=entry.kv_len, page_table=pt,
-                page_size=ps,
+                page_size=ps, rec_row=row[0] if row else None,
             )
             return jax.make_jaxpr(fn)(
                 _sds((1, entry.size), jnp.int32), _sds((), jnp.int32),
                 _sds((1, engine.page_pool.max_slots), jnp.int32),
+                *([_sds((), jnp.int32)] if cfg.is_hybrid else []),
             )
         from ..runtime.batch_session import prefill_row
 
@@ -472,7 +475,28 @@ def f32_dot_budget(engine, entry: LadderEntry) -> int:
     path + probs·V). Everything else — the quantized Q40/int8 projections,
     logits — must keep bf16 inputs, so any EXTRA f32-touching dot is an
     accidental upcast of a quantized matmul path."""
-    return 2 * attention_sites(engine, entry)
+    return 2 * attention_sites(engine, entry) + recurrence_f32_dots(engine, entry)
+
+
+def recurrence_f32_dots(engine, entry: LadderEntry) -> int:
+    """The float32 dots a hybrid model's linear layers need, counted in one
+    period's scan body (0 for every other model). Each of a period's linear
+    layers projects its two gates in float32 (1 dot); its recurrence is then
+    either the Pallas decode step, whose body's two dots take bfloat16 (0;
+    traced only where Pallas is on), or the chunked form: K K^T and Q K^T
+    (2), the forward substitution's row update (1) and the sub-chunk scan's
+    four products (4)."""
+    cfg = engine.cfg
+    if not cfg.is_hybrid:
+        return 0
+    from ..models.kv_arms import _rec_kernel_eligible
+
+    one_position = entry.kind in ("decode", "batch_decode") or entry.size == 1
+    # a paged admission prefill is one row against the whole batch's slots
+    # (CacheAddr.rec_row), which the kernel does not take
+    row = 0 if entry.kind == "prefill_row" and engine.paged else None
+    kernel = one_position and _rec_kernel_eligible(cfg, 1, row)
+    return (1 if kernel else 1 + 7) * (cfg.full_attn_interval - 1)
 
 
 # -- the declarative contract registry --------------------------------------
@@ -565,8 +589,11 @@ def _fused_kernel_active(engine) -> bool:
 
     cfg = engine.cfg
     tp = engine.mesh.shape["tp"] if engine.mesh is not None else 1
-    return _fused_paged_eligible(  # a tp shard's own head counts
-        cfg, (cfg.n_heads // tp, cfg.head_dim), cfg.n_kv_heads // tp, 1,
+    # the pool's own kv heads (it may store more than the model has:
+    # paged_kv.pool_kv_heads), a tp shard's share of them
+    n_kv = engine.cache.k.shape[3] // tp
+    return _fused_paged_eligible(
+        cfg, (cfg.n_heads // cfg.n_kv_heads * n_kv, cfg.head_dim), n_kv, 1,
         engine.cache.k.shape[2],
     )
 
@@ -661,6 +688,20 @@ def donation_check(name: str, lowered) -> list:
     return []
 
 
+def donated_leaf_check(name: str, lowered, n_leaves: int) -> list:
+    """Every leaf of the cache is donated, not just one: a hybrid model's
+    cache holds the recurrent state and the conv tail beside k and v, and a
+    program that passed them through undonated would copy 1.7 GB a step."""
+    txt = lowered if isinstance(lowered, str) else lowered.as_text()
+    n = sum(txt.count(m) for m in DONATION_MARKERS)
+    if n < n_leaves:
+        return [
+            f"{name}: {n} donated buffers in the lowered program, the cache "
+            f"has {n_leaves} leaves (k, v, the recurrent state, the conv tail)"
+        ]
+    return []
+
+
 def donation_problems(engine) -> list:
     """Lower each decode/prefill jit entry point this engine uses and
     assert the KV cache donation survived into the MLIR (buffer-alias
@@ -676,8 +717,12 @@ def donation_problems(engine) -> list:
     pos = jnp.int32(0)
     problems = []
 
+    n_leaves = len(jax.tree_util.tree_leaves(engine.cache))
+
     def check(name, lowered):
         problems.extend(donation_check(name, lowered))
+        if engine.cfg.is_hybrid:
+            problems.extend(donated_leaf_check(name, lowered, n_leaves))
 
     if engine.use_pipeline:
         from ..parallel import pipeline as pl
@@ -997,6 +1042,12 @@ def add_engine_args(p) -> None:
     analysis/graph_diff.py): ONE flag surface so a blessed golden config
     and the audited config can never drift apart syntactically."""
     p.add_argument("--model", default=None, help=".m file (default: tiny synthetic)")
+    p.add_argument(
+        "--arch", choices=["llama", "olmo_hybrid"], default="llama",
+        help="the tiny synthetic model's architecture (ignored with --model): "
+        "olmo_hybrid = two periods of three gated-delta layers and a full one "
+        "(pass --speculative off --prefix-cache-mb 0: refused for it)",
+    )
     p.add_argument("--compute-dtype", default="float32")
     p.add_argument("--batch", type=int, default=2)
     p.add_argument("--max-chunk", type=int, default=16)
@@ -1050,6 +1101,22 @@ def add_engine_args(p) -> None:
     )
 
 
+def tiny_hybrid_header():
+    """The tiny Olmo-Hybrid the hybrid golden and its contracts are traced
+    on: two periods, 12 kv heads (a paged pool stores 16), 6 linear heads of
+    32 x 64 (whole lanes: the Pallas step traces where Pallas is on), a
+    linear output projection of 384 inputs (padded to 512 on the device)."""
+    from ..formats.mfile import ArchType
+    from ..testing import tiny_header
+
+    return tiny_header(
+        arch=ArchType.OLMO_HYBRID, dim=256, hidden_dim=512, n_layers=8,
+        n_heads=12, n_kv_heads=12, head_dim=32, vocab_size=256, seq_len=128,
+        full_attn_interval=4, lin_heads=6, lin_key_head_dim=32,
+        lin_value_head_dim=64,
+    )
+
+
 def engine_from_args(args, workdir: str):
     """Build the engine the parsed `add_engine_args` flags describe
     (writing a tiny synthetic model into `workdir` when no --model)."""
@@ -1065,7 +1132,9 @@ def engine_from_args(args, workdir: str):
         from ..testing import tiny_header, write_tiny_model
 
         model = workdir + "/tiny.m"
-        if mesh is not None:
+        if getattr(args, "arch", "llama") == "olmo_hybrid":
+            hdr = tiny_hybrid_header()
+        elif mesh is not None:
             # layer/head counts must divide over the mesh axes
             hdr = tiny_header(
                 seq_len=128, dim=128, hidden_dim=128, n_layers=4,

@@ -108,10 +108,17 @@ def config_key(engine) -> str:
     gr = ""
     if getattr(engine, "grammar", None) is not None:
         gr = f"_gr{engine.grammar.n_states}"
+    # a layer pattern changes every program's body (a period of layers, a
+    # second kind of cache): its own family, keyed by the architecture
+    arch = ""
+    if cfg.is_hybrid:
+        from ..formats.mfile import ArchType
+
+        arch = "_" + ArchType.name(cfg.arch_type)
     return (
         f"{layout}_{kv}_{compute}_b{engine.batch}"
         f"_c{engine.max_chunk}_d{engine.decode_chunk_size}"
-        f"_{spec}_{pfx}_{mesh}{pi}{gr}"
+        f"_{spec}_{pfx}_{mesh}{pi}{gr}{arch}"
     )
 
 

@@ -8,6 +8,10 @@ Binary-compatible with the reference engine's model format:
   loader agree on (reference: src/llm.cpp:658-713) —
   ``embedding; per layer: q,k,v,wo, [moe_gate, experts x (w1,w2,w3) | w1,w2,w3],
   [qwen3: q_norm,k_norm], norm0, norm1; final_norm; wcls``.
+  An ``olmo_hybrid`` file (this project's own extension: the reference has no
+  such architecture) walks each layer by its KIND: a linear-attention layer
+  holds the gated-delta mixer's tensors where a full-attention layer holds
+  q,k,v,wo (see `tensor_walk`).
 
 Float header values are stored as int32s and cast on read (so e.g. a rope
 theta of 500000 is the int 500000); norm epsilon is encoded as the exponent
@@ -50,14 +54,27 @@ K_ROPE_TYPE = 18
 K_HEAD_DIM = 19
 K_NORM_EPSILON = 20
 K_MOE_HIDDEN_DIM = 21
+# olmo_hybrid (keys past the reference's): every `full_attn_interval`-th layer
+# is full attention, the others gated-delta linear attention
+K_FULL_ATTN_INTERVAL = 22
+K_LIN_KEY_HEADS = 23
+K_LIN_VALUE_HEADS = 24
+K_LIN_KEY_HEAD_DIM = 25
+K_LIN_VALUE_HEAD_DIM = 26
+K_LIN_CONV_KERNEL = 27
+K_LIN_NEG_EIGVAL = 28
 
 
 class ArchType:
     LLAMA = 0xABCD00
     QWEN3 = 0xABCD01
     QWEN3_MOE = 0xABCD02
+    OLMO_HYBRID = 0xABCD03
 
-    _NAMES = {LLAMA: "llama", QWEN3: "qwen3", QWEN3_MOE: "qwen3_moe"}
+    _NAMES = {
+        LLAMA: "llama", QWEN3: "qwen3", QWEN3_MOE: "qwen3_moe",
+        OLMO_HYBRID: "olmo_hybrid",
+    }
 
     @classmethod
     def name(cls, t: int) -> str:
@@ -102,6 +119,15 @@ class ModelHeader:
     norm_epsilon: float = 1e-5
     weight_type: int = FloatType.UNK
     head_dim: int = 0
+    # olmo_hybrid: layer l is full attention where (l + 1) % interval == 0
+    # and gated-delta linear attention otherwise; 1 = every layer is full
+    full_attn_interval: int = 1
+    lin_key_heads: int = 0
+    lin_value_heads: int = 0
+    lin_key_head_dim: int = 0
+    lin_value_head_dim: int = 0
+    lin_conv_kernel: int = 0
+    lin_neg_eigval: int = 0
     header_bytes: int = 0  # magic + size field + kv pairs
     file_bytes: int = 0
 
@@ -118,6 +144,13 @@ class ModelHeader:
         """Per-expert FFN width for MoE, dense FFN width otherwise."""
         return self.moe_hidden_dim if self.arch_type == ArchType.QWEN3_MOE else self.hidden_dim
 
+    @property
+    def is_hybrid(self) -> bool:
+        return self.arch_type == ArchType.OLMO_HYBRID
+
+    def layer_is_linear(self, layer: int) -> bool:
+        return self.is_hybrid and (layer + 1) % self.full_attn_interval != 0
+
     def finalize(self, max_seq_len: int = 0) -> "ModelHeader":
         """Apply derived-field defaults (reference: src/llm.cpp:105-117)."""
         self.orig_seq_len = self.seq_len
@@ -125,8 +158,22 @@ class ModelHeader:
             self.seq_len = max_seq_len
         if self.head_dim == 0:
             self.head_dim = self.dim // self.n_heads
-        if self.arch_type in (ArchType.QWEN3, ArchType.QWEN3_MOE):
+        if self.arch_type in (ArchType.QWEN3, ArchType.QWEN3_MOE, ArchType.OLMO_HYBRID):
             self.rope_type = RopeType.FALCON
+        if self.is_hybrid:
+            if self.full_attn_interval < 2 or self.n_layers % self.full_attn_interval:
+                raise ValueError(
+                    f"olmo_hybrid: {self.n_layers} layers are not whole periods of "
+                    f"{self.full_attn_interval}"
+                )
+            if self.lin_key_heads != self.lin_value_heads:
+                raise ValueError(
+                    "olmo_hybrid: linear layers with more value heads than key "
+                    f"heads are not supported ({self.lin_key_heads} key, "
+                    f"{self.lin_value_heads} value)"
+                )
+            if self.lin_conv_kernel < 2 or not self.lin_key_head_dim or not self.lin_value_head_dim:
+                raise ValueError("olmo_hybrid: the header lacks the linear layers' sizes")
         return self
 
 
@@ -135,6 +182,8 @@ class TensorSpec:
     """One entry of the fixed tensor walk."""
 
     role: str  # embedding|q|k|v|wo|moe_gate|w1|w2|w3|q_norm|k_norm|norm0|norm1|final_norm|wcls
+    # olmo_hybrid linear layers: lin_q|lin_k|lin_v|lin_g|lin_a|lin_b|lin_conv|
+    # lin_a_log|lin_dt_bias|lin_o_norm|lin_wo
     layer: int  # -1 for global tensors
     expert: int  # -1 for non-expert tensors
     shape: tuple  # logical (out_features, in_features) or (n,) — torch row-major
@@ -183,10 +232,30 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
 
     add("embedding", -1, -1, (h.vocab_size, h.dim), FloatType.F32)
     for l in range(h.n_layers):
-        add("q", l, -1, (h.q_dim, h.dim), wt)
-        add("k", l, -1, (h.kv_dim, h.dim), wt)
-        add("v", l, -1, (h.kv_dim, h.dim), wt)
-        add("wo", l, -1, (h.dim, h.q_dim), wt)
+        if h.layer_is_linear(l):
+            # the gated-delta mixer (ops/gated_delta.py): four projections
+            # of the residual stream, the two gates' float projections, the
+            # depthwise causal conv's taps (tap-major: row i multiplies the
+            # input 3 - i positions back, over q|k|v channels), the decay's
+            # two per-head vectors, the output norm and the output projection
+            hk = h.lin_key_heads * h.lin_key_head_dim
+            hv = h.lin_value_heads * h.lin_value_head_dim
+            add("lin_q", l, -1, (hk, h.dim), wt)
+            add("lin_k", l, -1, (hk, h.dim), wt)
+            add("lin_v", l, -1, (hv, h.dim), wt)
+            add("lin_g", l, -1, (hv, h.dim), wt)
+            add("lin_a", l, -1, (h.lin_value_heads, h.dim), FloatType.F32)
+            add("lin_b", l, -1, (h.lin_value_heads, h.dim), FloatType.F32)
+            add("lin_conv", l, -1, (h.lin_conv_kernel, 2 * hk + hv), FloatType.F32)
+            add("lin_a_log", l, -1, (h.lin_value_heads,), FloatType.F32)
+            add("lin_dt_bias", l, -1, (h.lin_value_heads,), FloatType.F32)
+            add("lin_o_norm", l, -1, (h.lin_value_head_dim,), FloatType.F32)
+            add("lin_wo", l, -1, (h.dim, hv), wt)
+        else:
+            add("q", l, -1, (h.q_dim, h.dim), wt)
+            add("k", l, -1, (h.kv_dim, h.dim), wt)
+            add("v", l, -1, (h.kv_dim, h.dim), wt)
+            add("wo", l, -1, (h.dim, h.q_dim), wt)
         if h.n_experts > 0:
             add("moe_gate", l, -1, (h.n_experts, h.dim), FloatType.F32)
             for e in range(h.n_experts):
@@ -200,6 +269,10 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
         if is_qwen:
             add("q_norm", l, -1, (h.head_dim,), FloatType.F32)
             add("k_norm", l, -1, (h.head_dim,), FloatType.F32)
+        elif h.is_hybrid and not h.layer_is_linear(l):
+            # Olmo's q/k norm spans the whole projection, not a head
+            add("q_norm", l, -1, (h.q_dim,), FloatType.F32)
+            add("k_norm", l, -1, (h.kv_dim,), FloatType.F32)
         add("norm0", l, -1, (h.dim,), FloatType.F32)
         add("norm1", l, -1, (h.dim,), FloatType.F32)
     add("final_norm", -1, -1, (h.dim,), FloatType.F32)
@@ -302,6 +375,13 @@ def _parse_header(buf, file_size: int) -> ModelHeader:
         K_HEAD_DIM: lambda v: setattr(h, "head_dim", v),
         K_NORM_EPSILON: lambda v: setattr(h, "norm_epsilon", _norm_epsilon(v)),
         K_MOE_HIDDEN_DIM: lambda v: setattr(h, "moe_hidden_dim", v),
+        K_FULL_ATTN_INTERVAL: lambda v: setattr(h, "full_attn_interval", v),
+        K_LIN_KEY_HEADS: lambda v: setattr(h, "lin_key_heads", v),
+        K_LIN_VALUE_HEADS: lambda v: setattr(h, "lin_value_heads", v),
+        K_LIN_KEY_HEAD_DIM: lambda v: setattr(h, "lin_key_head_dim", v),
+        K_LIN_VALUE_HEAD_DIM: lambda v: setattr(h, "lin_value_head_dim", v),
+        K_LIN_CONV_KERNEL: lambda v: setattr(h, "lin_conv_kernel", v),
+        K_LIN_NEG_EIGVAL: lambda v: setattr(h, "lin_neg_eigval", v),
     }
     for i in range(0, n_kv, 2):
         key, value = vals[i], vals[i + 1]

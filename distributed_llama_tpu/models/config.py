@@ -52,6 +52,51 @@ class ModelConfig:
     # argument — at construction (from DLT_PALLAS_INTERPRET) so a program
     # traced in one mode can never be replayed in the other.
     pallas_interpret: bool = False
+    # layer pattern (olmo_hybrid): layer l is full attention where
+    # (l + 1) % full_attn_interval == 0 and gated-delta linear attention
+    # (ops/gated_delta.py) otherwise. 1 = every layer is full attention, and
+    # every program of such a model is what it was before the pattern existed
+    full_attn_interval: int = 1
+    lin_heads: int = 0
+    lin_key_dim: int = 0  # per head
+    lin_value_dim: int = 0  # per head
+    lin_conv_kernel: int = 0
+    lin_neg_eigval: bool = False
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """("linear" | "full") per layer."""
+        p = self.full_attn_interval
+        return tuple(
+            "full" if p == 1 or (l + 1) % p == 0 else "linear"
+            for l in range(self.n_layers)
+        )
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep a KV cache (the pool's leading axis)."""
+        return self.n_layers // self.full_attn_interval
+
+    @property
+    def n_rec_layers(self) -> int:
+        """Layers that keep a recurrent state a row instead."""
+        return self.n_layers - self.n_kv_layers
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.n_rec_layers > 0
+
+    @property
+    def lin_kdim(self) -> int:
+        return self.lin_heads * self.lin_key_dim
+
+    @property
+    def lin_vdim(self) -> int:
+        return self.lin_heads * self.lin_value_dim
+
+    @property
+    def lin_conv_channels(self) -> int:
+        return 2 * self.lin_kdim + self.lin_vdim
 
     @property
     def q_dim(self) -> int:
@@ -122,4 +167,10 @@ def config_from_header(
         norm_epsilon=h.norm_epsilon,
         compute_dtype=compute_dtype,
         cache_dtype=cache_dtype,
+        full_attn_interval=h.full_attn_interval if h.is_hybrid else 1,
+        lin_heads=h.lin_value_heads,
+        lin_key_dim=h.lin_key_head_dim,
+        lin_value_dim=h.lin_value_head_dim,
+        lin_conv_kernel=h.lin_conv_kernel,
+        lin_neg_eigval=bool(h.lin_neg_eigval),
     )

@@ -18,12 +18,13 @@ payloads); the index arithmetic of an arm is written once.
 
 from __future__ import annotations
 
+from dataclasses import replace
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops import gqa_attention
+from ..ops import gated_delta, gqa_attention
 from ..ops.attention import flash_attention_sp, gqa_attention_sp, scatter_cache_update_sp
 from ..ops.kv_quant import dequantize_kv, quantize_kv
 from ..ops.pallas_attention import (
@@ -31,6 +32,7 @@ from ..ops.pallas_attention import (
     flash_attention_aligned,
     paged_decode_attention,
 )
+from ..ops.pallas_gdn import gdn_decode_step, gdn_head_chunk
 from ..ops.quant import _use_pallas
 from .params import KVCache
 
@@ -57,6 +59,9 @@ class CacheAddr(NamedTuple):
     page_size: int | None = None  # static page length in tokens (paged only)
     sp_ctx: Any = None  # (axis_name, shard_offset) when the cache's seq
     # axis is sharded under shard_map (long-context sequence parallelism)
+    rec_row: Any = None  # scalar int32: the call's ONE batch row is slot
+    # `rec_row` of the recurrent leaves (a paged admission prefill: b = 1
+    # against the whole batch's state). None: batch row r is slot r.
 
 
 def select_arm(addr: CacheAddr):
@@ -152,9 +157,10 @@ def _put(cache: KVCache, stored, put) -> KVCache:
     kw, vw, ks, vs = stored
     new_k, new_v = put(cache.k, kw), put(cache.v, vw)
     if ks is None:
-        return KVCache(k=new_k, v=new_v)
-    return KVCache(
-        k=new_k, v=new_v, k_scale=put(cache.k_scale, ks), v_scale=put(cache.v_scale, vs)
+        return replace(cache, k=new_k, v=new_v)
+    return replace(
+        cache, k=new_k, v=new_v,
+        k_scale=put(cache.k_scale, ks), v_scale=put(cache.v_scale, vs),
     )
 
 
@@ -163,7 +169,8 @@ def _write(cache: KVCache, k, v, put) -> KVCache:
     cast and put before v is cast."""
     if cache.quantized:
         return _put(cache, _stored(cache, k, v), put)
-    return KVCache(
+    return replace(
+        cache,
         k=put(cache.k, k.astype(cache.k.dtype)),
         v=put(cache.v, v.astype(cache.v.dtype)),
     )
@@ -176,6 +183,11 @@ def _float_only(cache: KVCache, arm: str) -> None:
             f"only, not the {arm} arm (the engine forces a float cache on "
             "sp/pipeline meshes)"
         )
+
+
+def _pad_heads(x, n: int):
+    """[b, t, heads, d] with zero heads appended up to `n`."""
+    return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
 
 
 def _layer_view(buf, layer, b: int, n: int):
@@ -198,6 +210,16 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     li, ps, page_table = addr.layer, addr.page_size, addr.page_table
     b, t = q.shape[:2]
     n_pool = cache.k.shape[1]
+    # a pool whose head axis is wider than the model's kv heads
+    # (paged_kv.pool_kv_heads: 30 heads are stored as 32, whole tiles of 8,
+    # so that the page-table kernel keeps the pool's rows where they lie):
+    # the extra heads are written as zeros, asked about by zero queries, and
+    # their outputs cut off again
+    n_kv, pool_kv = k.shape[2], cache.k.shape[3]
+    n_q, q_pool = q.shape[2], q
+    if pool_kv > n_kv:
+        k, v = _pad_heads(k, pool_kv), _pad_heads(v, pool_kv)
+        q_pool = _pad_heads(q, n_q // n_kv * pool_kv)
     max_slots = page_table.shape[1]
     # write: scatter each new row to (table[pos // ps], pos % ps).
     # Invalid writes — parked rows at/past seq_len, or an unmapped
@@ -220,19 +242,19 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     )
     # read: the first kv_len/ps page entries per row
     n_read = max_slots if addr.kv_len is None else min(-(-addr.kv_len // ps), max_slots)
-    if _fused_paged_eligible(cfg, q.shape[2:], cache.k.shape[3], t, ps):
+    if _fused_paged_eligible(cfg, q_pool.shape[2:], pool_kv, t, ps):
         # decode-sized: the page-table KERNEL reads the row's live pages of
         # the pool where they lie (scalar-prefetched table, one copy a page,
         # many pages a grid step) — no materialized page gather, no KV view
         # in HBM, and bytes that follow the position, not the bucket
         # (ops/pallas_attention.paged_decode_attention)
         a = paged_decode_attention(
-            q, cache.k, cache.v, cache.k_scale, cache.v_scale,
+            q_pool, cache.k, cache.v, cache.k_scale, cache.v_scale,
             jnp.asarray(li, jnp.int32), positions[:, 0], page_table,
             n_read=n_read, page_size=ps,
             interpret=cfg.pallas_interpret,
         )
-        return a, cache
+        return (a[:, :, :n_q] if pool_kv > n_kv else a), cache
     # prefill chunks, tp shards with few local kv heads, no Pallas: gather
     # them into the contiguous [b, n*ps, h, d] view the attention math
     # consumes — this gather is the arm's whole read cost (the cost model
@@ -250,6 +272,8 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
         v_view = dequantize_kv(v_view, cache.v_scale[li, pages], cfg.dtype)
     k_view = k_view.reshape(b, n_read * ps, -1, cfg.head_dim)
     v_view = v_view.reshape(b, n_read * ps, -1, cfg.head_dim)
+    if pool_kv > n_kv:
+        k_view, v_view = k_view[:, :, :n_kv], v_view[:, :, :n_kv]
     return _attention_auto(cfg, q, k_view, v_view, positions, pos_start), cache
 
 
@@ -371,3 +395,69 @@ def sp_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     else:
         a = gqa_attention_sp(q, k_view, v_view, positions, shard_offset, axis_name)
     return a, cache
+
+
+# -- the recurrent arm ------------------------------------------------------
+
+
+def _rec_kernel_eligible(cfg, t: int, rec_row) -> bool:
+    """Gate for the Pallas decode step (ops/pallas_gdn.py): Pallas enabled,
+    one position a row, batch rows that are the state's slots, and heads that
+    fill whole lanes in some chunk."""
+    return (
+        _pallas_enabled(cfg)
+        and t == 1
+        and rec_row is None
+        and gdn_head_chunk(cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim) is not None
+    )
+
+
+def recurrent_arm(cfg, cache, addr, rec_layer, z, a, b, gp, positions, valid):
+    """A linear-attention layer's "cache": no page list, a slot a row. Not
+    one of `select_arm`'s — the layer's KIND picks it, not the address.
+
+    z [b, t, 2*hk + hv] f32, the q | k | v projections before the conv; a, b
+    [b, t, H] f32, the gates' projections; gp (conv taps [K, C], a_log [H],
+    dt_bias [H]) this layer's; positions [b, t]; valid [b, t] bool: false on
+    a chunk's padding and on parked rows (positions at seq_len), whose state
+    and conv tail stay what they were. A row at position 0 starts from a zero
+    state and a zero tail, whatever its slot held: a slot needs no clearing
+    when a request takes it. Returns (o [b, t, H, dv] f32, cache)."""
+    taps, a_log, dt_bias = gp
+    bsz, t = positions.shape
+    H, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    hk = H * dk
+    fresh = positions[:, 0] == 0  # [b]
+    row = addr.rec_row
+
+    def slot(buf):  # this layer's [b, ...] slots
+        if row is None:
+            return jax.lax.dynamic_index_in_dim(buf, rec_layer, 0, keepdims=False)
+        start = (rec_layer, row) + (0,) * (buf.ndim - 2)
+        return jax.lax.dynamic_slice(buf, start, (1, 1) + buf.shape[2:])[0]
+
+    def put(buf, rows):
+        start = (rec_layer, 0 if row is None else row) + (0,) * (buf.ndim - 2)
+        return jax.lax.dynamic_update_slice(buf, rows[None].astype(buf.dtype), start)
+
+    tail = jnp.where(fresh[:, None, None], 0.0, slot(cache.conv).astype(jnp.float32))
+    y, new_tail = gated_delta.causal_conv(z, tail, taps, valid)
+    y = jax.nn.silu(y)
+    q = gated_delta.l2_normalize(y[..., :hk].reshape(bsz, t, H, dk)) * dk**-0.5
+    k = gated_delta.l2_normalize(y[..., hk : 2 * hk].reshape(bsz, t, H, dk))
+    v = y[..., 2 * hk :].reshape(bsz, t, H, dv)
+    log_alpha, beta = gated_delta.gdn_gates(a, b, a_log, dt_bias, cfg.lin_neg_eigval)
+    log_alpha = jnp.where(valid[..., None], log_alpha, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    conv = put(cache.conv, new_tail)
+
+    if _rec_kernel_eligible(cfg, t, row):
+        o, rec = gdn_decode_step(
+            cache.rec, jnp.asarray(rec_layer, jnp.int32), q[:, 0], k[:, 0], v[:, 0],
+            jnp.exp(log_alpha[:, 0]), beta[:, 0], ~fresh,
+            interpret=cfg.pallas_interpret,
+        )
+        return o[:, None], replace(cache, rec=rec, conv=conv)
+    S = jnp.where(fresh[:, None, None], 0.0, slot(cache.rec).astype(jnp.float32))
+    o, S = gated_delta.gdn_chunked(S, q, k, v, log_alpha, beta)
+    return o, replace(cache, rec=put(cache.rec, S), conv=conv)

@@ -43,8 +43,31 @@ def _register(cls, fields):
 
 
 @dataclass
+class GdnParams:
+    """The gated-delta linear-attention layers' mixer weights (olmo_hybrid;
+    ops/gated_delta.py), stacked over those layers alone: index `r` of a
+    period `p`'s j-th linear layer is `p * (interval - 1) + j`."""
+
+    wqkvg: Weight  # [Lr, 2*hk + 2*hv, dim]: q | k | v | output gate, fused
+    wab: jnp.ndarray  # [Lr, 2*H, dim] f32: the decay's and the step's projections
+    conv: jnp.ndarray  # [Lr, K, 2*hk + hv] f32 depthwise taps over q | k | v
+    a_log: jnp.ndarray  # [Lr, H] f32
+    dt_bias: jnp.ndarray  # [Lr, H] f32
+    o_norm: jnp.ndarray  # [Lr, dv]
+    wo: Weight  # [Lr, dim, hv_pad]: `in` padded with zero blocks to whole
+    # 256s, the stacked Q40 kernels' rule (ops/pallas_q40.q40_stacked_aligned)
+
+
+_register(GdnParams, ["wqkvg", "wab", "conv", "a_log", "dt_bias", "o_norm", "wo"])
+
+
+@dataclass
 class LayerParams:
     """Per-layer weights, each stacked with a leading [n_layers] axis.
+
+    A hybrid model (cfg.is_hybrid) stacks by KIND: the attention fields hold
+    the full-attention layers alone ([n_kv_layers, ...]), `gdn` the linear
+    ones, and the feed-forward fields and both norms all n_layers.
 
     Decode makes one kernel dispatch per matmul, so the loader FUSES the
     row-split projections that share an input: q/k/v -> `wqkv` (always) and
@@ -70,12 +93,13 @@ class LayerParams:
     moe_gate: Optional[jnp.ndarray] = None  # [L, E, dim] f32 (moe)
     wqkv: Optional[Weight] = None  # [L, q_dim+2*kv_dim, dim] fused projection
     w13: Optional[Weight] = None  # [L, 2*ff, dim] fused dense ffn in-proj
+    gdn: Optional[GdnParams] = None  # the linear-attention layers' mixers
 
 
 _register(
     LayerParams,
     ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1", "q_norm", "k_norm",
-     "moe_gate", "wqkv", "w13"],
+     "moe_gate", "wqkv", "w13", "gdn"],
 )
 
 
@@ -108,6 +132,13 @@ class KVCache:
     # every donation/sharding contract over it) is unchanged.
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    # a hybrid model's linear-attention layers keep no KV: a fixed state a
+    # row instead (ops/gated_delta.py), slots by batch row whatever layout
+    # k/v have. `rec` [n_rec_layers, rows, dk, H*dv] f32; `conv`
+    # [n_rec_layers, rows, K-1, channels], the conv's last pre-activation
+    # inputs in the compute dtype. None on every other model (flattens away).
+    rec: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
 
     @property
     def batch(self) -> int:
@@ -122,12 +153,35 @@ class KVCache:
         return self.k_scale is not None
 
 
-_register(KVCache, ["k", "v", "k_scale", "v_scale"])
+_register(KVCache, ["k", "v", "k_scale", "v_scale", "rec", "conv"])
+
+
+def init_rec_state(cfg: ModelConfig, rows: int) -> dict:
+    """The recurrent leaves of a hybrid model's cache, zeroed ({} otherwise)."""
+    if not cfg.is_hybrid:
+        return {}
+    return dict(
+        rec=jnp.zeros((cfg.n_rec_layers, rows, cfg.lin_key_dim, cfg.lin_vdim), jnp.float32),
+        conv=jnp.zeros(
+            (cfg.n_rec_layers, rows, cfg.lin_conv_kernel - 1, cfg.lin_conv_channels),
+            cfg.dtype,
+        ),
+    )
+
+
+def rec_state_bytes(cfg: ModelConfig, rows: int) -> int:
+    """Device bytes of `init_rec_state`'s leaves."""
+    if not cfg.is_hybrid:
+        return 0
+    per_row = cfg.lin_key_dim * cfg.lin_vdim * 4 + (
+        (cfg.lin_conv_kernel - 1) * cfg.lin_conv_channels * cfg.dtype.itemsize
+    )
+    return cfg.n_rec_layers * rows * per_row
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None) -> KVCache:
     shape = (
-        cfg.n_layers,
+        cfg.n_kv_layers,
         batch,
         seq_len if seq_len is not None else cfg.seq_len,
         cfg.n_kv_heads,
@@ -141,7 +195,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None) -> K
             k_scale=jnp.zeros(shape[:-1], jnp.float32),
             v_scale=jnp.zeros(shape[:-1], jnp.float32),
         )
-    return KVCache(k=k, v=v)
+    return KVCache(k=k, v=v, **init_rec_state(cfg, batch))
 
 
 # ---------------------------------------------------------------------------
@@ -235,6 +289,9 @@ def load_params(
     def put(role: str, x):
         return _put(x, sh.get(role))
 
+    if cfg.is_hybrid:
+        return _load_hybrid(reader, cfg, dense)
+
     roles = ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1"]
     if cfg.is_qwen3:
         roles += ["q_norm", "k_norm"]
@@ -283,3 +340,68 @@ def load_params(
     final_norm = put("final_norm", _load_one(reader, reader.by_name["final_norm"], dense))
     wcls = put("wcls", _load_one(reader, reader.by_name["wcls"], dense))
     return ModelParams(embedding=embedding, layers=layers, final_norm=final_norm, wcls=wcls)
+
+
+def _pad_in_blocks(part: tuple, multiple: int = 8) -> tuple:
+    """A T-layout Q40 pair (qp [nb*4, out], dt [nb, out]) with `in` padded to
+    whole `multiple`s of blocks: zero scales under the code for 0, so the
+    padded inputs add nothing whatever they hold."""
+    qp, dt = part
+    nb = dt.shape[0]
+    pad = -nb % multiple
+    if not pad:
+        return part
+    zero_words = np.full((pad * 4, qp.shape[1]), 0x88888888, np.uint32).view(np.int32)
+    return (
+        np.concatenate([qp, zero_words], axis=0),
+        np.concatenate([dt, np.zeros((pad, dt.shape[1]), dt.dtype)], axis=0),
+    )
+
+
+def _load_hybrid(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
+    """olmo_hybrid: the attention stack over the full layers, the gated-delta
+    stack over the linear ones, the feed-forward and both norms over all.
+    Single chip only (the engine refuses a mesh for this architecture)."""
+
+    def one(role, l, dtype=dense):
+        return _load_one(reader, reader.by_name[f"{role}.l{l}"], dtype)
+
+    kinds = cfg.layer_kinds
+    full = [l for l, kind in enumerate(kinds) if kind == "full"]
+    lin = [l for l, kind in enumerate(kinds) if kind == "linear"]
+    every = range(cfg.n_layers)
+    put = lambda parts: _put(_stack(parts))  # noqa: E731
+    f32 = np.float32
+    gdn = GdnParams(
+        wqkvg=put([
+            _fuse_rows([one(r, l) for r in ("lin_q", "lin_k", "lin_v", "lin_g")], 1)
+            for l in lin
+        ]),
+        wab=put([np.concatenate([one("lin_a", l, f32), one("lin_b", l, f32)]) for l in lin]),
+        conv=put([one("lin_conv", l, f32) for l in lin]),
+        a_log=put([one("lin_a_log", l) for l in lin]),
+        dt_bias=put([one("lin_dt_bias", l) for l in lin]),
+        o_norm=put([one("lin_o_norm", l) for l in lin]),
+        wo=put([
+            _pad_in_blocks(w) if isinstance(w, tuple) else w
+            for w in (one("lin_wo", l) for l in lin)
+        ]),
+    )
+    layers = LayerParams(
+        q=None, k=None, v=None, w1=None, w3=None,
+        wqkv=put([_fuse_rows([one(r, l) for r in ("q", "k", "v")], 1) for l in full]),
+        wo=put([one("wo", l) for l in full]),
+        q_norm=put([one("q_norm", l) for l in full]),
+        k_norm=put([one("k_norm", l) for l in full]),
+        w13=put([_fuse_rows([one("w1", l), one("w3", l)], 1) for l in every]),
+        w2=put([one("w2", l) for l in every]),
+        norm0=put([one("norm0", l) for l in every]),
+        norm1=put([one("norm1", l) for l in every]),
+        gdn=gdn,
+    )
+    return ModelParams(
+        embedding=_put(_load_one(reader, reader.by_name["embedding"], np.float32)),
+        layers=layers,
+        final_norm=_put(_load_one(reader, reader.by_name["final_norm"], dense)),
+        wcls=_put(_load_one(reader, reader.by_name["wcls"], dense)),
+    )

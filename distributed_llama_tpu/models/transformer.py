@@ -1,4 +1,4 @@
-"""The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE).
+"""The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE / Olmo-Hybrid).
 
 Functional re-design of the reference's per-node op graph (reference:
 buildLlmNet, src/llm.cpp:152-649). One layer body is `lax.scan`ned over
@@ -17,6 +17,16 @@ src/llm.cpp:421-569):
     moe:   route -> top-k experts' swiglu, weighted sum (src/llm.cpp:440-514)
 
 Final: rms_norm(x, final_norm) @ Wcls -> logits   (src/llm.cpp:593-636)
+
+Olmo-Hybrid (no reference analogue; `_hybrid_layers`): the layers come in
+periods of `full_attn_interval`, all but the last of a period gated-delta
+linear attention (ops/gated_delta.py) and the last full attention; the norm
+sits on each sub-layer's OUTPUT and there is none before it:
+
+    x += rms_norm(mixer(x), norm0);  x += rms_norm(ffn(x), norm1)
+
+with mixer = attention over a q/k normed across the whole projection, or the
+gated delta rule (`_gdn_mixer`).
 """
 
 from __future__ import annotations
@@ -33,7 +43,7 @@ from ..ops.activations import gelu, silu
 from ..ops.quant import QuantTensor, dequantize_t, quant_matmul, quantize_q80_activations
 from ..ops.rope import RopeTables, apply_rope
 from .config import ModelConfig
-from .kv_arms import CacheAddr, _pallas_enabled, select_arm
+from .kv_arms import CacheAddr, _pallas_enabled, recurrent_arm, select_arm
 from .params import KVCache, LayerParams, ModelParams
 
 
@@ -278,6 +288,11 @@ def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp: LayerParams, positions, laye
         q = linear(y, lp.q, cfg.dtype, cfg.pallas_arg, q80, layer_idx)
         k = linear(y, lp.k, cfg.dtype, cfg.pallas_arg, q80, layer_idx)
         v = linear(y, lp.v, cfg.dtype, cfg.pallas_arg, q80, layer_idx)
+    if lp.q_norm is not None and lp.q_norm.shape[-1] != cfg.head_dim:
+        # a q/k norm as wide as the projection spans all of it, before the
+        # heads split (Olmo 2 / 3); one a head wide is Qwen3's, below
+        q = rms_norm(q, _sel_layer(lp.q_norm, layer_idx), cfg.norm_epsilon)
+        k = rms_norm(k, _sel_layer(lp.k_norm, layer_idx), cfg.norm_epsilon)
     q = q.reshape(b, t, q.shape[-1] // cfg.head_dim, cfg.head_dim)
     k = k.reshape(b, t, k.shape[-1] // cfg.head_dim, cfg.head_dim)
     v = v.reshape(b, t, v.shape[-1] // cfg.head_dim, cfg.head_dim)
@@ -337,6 +352,75 @@ def _layer(
     return x, cache
 
 
+def _gdn_mixer(cfg, x, gp, cache, addr, ri, positions, valid):
+    """The gated-delta mixer of the residual stream x [b, t, dim] for linear
+    layer `ri` of the `gp` stack: projections, the recurrent arm (conv, gates,
+    the delta rule over this layer's state slots), the gated output norm and
+    the output projection. Returns (y [b, t, dim], cache)."""
+    b, t, _ = x.shape
+    q80 = cfg.q80_activations
+    zg = linear(x, gp.wqkvg, cfg.dtype, cfg.pallas_arg, q80, ri)
+    n_conv = cfg.lin_conv_channels
+    # the gates decide what the state keeps for the rest of the sequence:
+    # their two small projections stay float32 at full precision
+    ab = jnp.einsum(
+        "btd,hd->bth", x.astype(jnp.float32), _sel_layer(gp.wab, ri),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    o, cache = recurrent_arm(
+        cfg, cache, addr, ri, zg[..., :n_conv].astype(jnp.float32),
+        ab[..., : cfg.lin_heads], ab[..., cfg.lin_heads :],
+        (_sel_layer(gp.conv, ri), _sel_layer(gp.a_log, ri), _sel_layer(gp.dt_bias, ri)),
+        positions, valid,
+    )
+    gate = zg[..., n_conv:].astype(jnp.float32).reshape(b, t, cfg.lin_heads, cfg.lin_value_dim)
+    o = rms_norm(o, _sel_layer(gp.o_norm, ri), cfg.norm_epsilon) * silu(gate)
+    o = o.reshape(b, t, cfg.lin_vdim)
+    wo_in = gp.wo.in_features if isinstance(gp.wo, QuantTensor) else gp.wo.shape[-1]
+    if wo_in > cfg.lin_vdim:  # the device layout's zero blocks (params._pad_in_blocks)
+        o = jnp.pad(o, ((0, 0), (0, 0), (0, wo_in - cfg.lin_vdim)))
+    return linear(o, gp.wo, cfg.dtype, cfg.pallas_arg, q80, ri), cache
+
+
+def _hybrid_layers(cfg, params, rope, x, cache, positions, pos_start, valid, addr):
+    """Olmo-Hybrid's layer stack: one scan over the PERIODS, its body the
+    period's linear layers and then its full layer. Weights stay stacked by
+    kind and are selected inside the kernels: linear stack index
+    `p (interval-1) + j`, full stack and KV index `p`, feed-forward and norm
+    index `p interval + j`. The KV cache, the recurrent state and the conv
+    tail ride the carry as one `KVCache` value. `valid` [b, t]: false where a
+    position must not advance a recurrent state. Returns (x, cache)."""
+    b, t, _ = x.shape
+    lp = params.layers
+    period = cfg.full_attn_interval
+    eps = cfg.norm_epsilon
+
+    def ffn_block(x, fi):
+        h = _dense_ffn(cfg, x, lp, fi)
+        return x + rms_norm(h, _sel_layer(lp.norm1, fi), eps).astype(x.dtype)
+
+    def body(carry, p):
+        x, cache = carry
+        for j in range(period - 1):
+            fi = p * period + j
+            y, cache = _gdn_mixer(
+                cfg, x, lp.gdn, cache, addr, p * (period - 1) + j, positions, valid
+            )
+            x = x + rms_norm(y, _sel_layer(lp.norm0, fi), eps).astype(x.dtype)
+            x = ffn_block(x, fi)
+        fi = p * period + period - 1
+        q, k, v = _qkv(cfg, rope, x, lp, positions, p)
+        a_addr = addr._replace(layer=p)
+        a, cache = select_arm(a_addr)(cfg, cache, a_addr, q, k, v, positions, pos_start)
+        y = linear(a.reshape(b, t, cfg.q_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, p)
+        x = x + rms_norm(y, _sel_layer(lp.norm0, fi), eps).astype(x.dtype)
+        return (ffn_block(x, fi), cache), None
+
+    periods = jnp.arange(cfg.n_layers // period, dtype=jnp.int32)
+    (x, cache), _ = jax.lax.scan(body, (x, cache), periods)
+    return x, cache
+
+
 def forward_uncompiled(
     cfg: ModelConfig,
     params: ModelParams,
@@ -351,6 +435,8 @@ def forward_uncompiled(
     page_table: jnp.ndarray | None = None,  # [b, max_slots] int32 — paged
     # KV layout (cache = page pools; see kv_arms.paged_arm)
     page_size: int | None = None,  # static page length (paged layout only)
+    rec_row: jnp.ndarray | None = None,  # hybrid models: the one batch row
+    # of this call is slot `rec_row` of the recurrent state (kv_arms.CacheAddr)
 ) -> tuple[jnp.ndarray, KVCache]:
     """One forward step (prefill chunk or decode token).
 
@@ -363,6 +449,14 @@ def forward_uncompiled(
     positions = ps[..., None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     positions = jnp.broadcast_to(positions, (b, t))
 
+    if cfg.is_hybrid:
+        # a token id below 0 is a chunk's PADDING (the engine pads this
+        # architecture's prompt chunks with -1): embedded as token 0, and its
+        # KV is overwritten before it is read like any padding's, but it
+        # must not advance a recurrent state, and that mask has to come from
+        # somewhere; so must no parked row (position at seq_len)
+        valid = (tokens >= 0) & (positions < cfg.seq_len)
+        tokens = jnp.maximum(tokens, 0)
     x = params.embedding[tokens].astype(jnp.float32)
 
     # the scan's xs carry only the layer index; the stacked weights ride in
@@ -372,7 +466,9 @@ def forward_uncompiled(
     # pallas_call). The FULL cache rides the CARRY as one value (an int8
     # cache's scale sidecars are leaves of it) and each layer updates its
     # rows in place (CacheAddr.layer).
-    addr = CacheAddr(kv_len=kv_len, page_table=page_table, page_size=page_size)
+    addr = CacheAddr(
+        kv_len=kv_len, page_table=page_table, page_size=page_size, rec_row=rec_row
+    )
 
     def body(carry, li):
         x, cache = carry
@@ -382,8 +478,13 @@ def forward_uncompiled(
         )
         return (x, cache), None
 
-    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    (x, new_cache), _ = jax.lax.scan(body, (x, cache), layer_ids)
+    if cfg.is_hybrid:
+        x, new_cache = _hybrid_layers(
+            cfg, params, rope, x, cache, positions, pos_start, valid, addr
+        )
+    else:
+        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, new_cache), _ = jax.lax.scan(body, (x, cache), layer_ids)
 
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     if logits_mode == "last":

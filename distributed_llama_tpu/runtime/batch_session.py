@@ -128,32 +128,18 @@ def prefill_row(
     slice+unslice moves one cache row (~tens of MB), negligible next to the
     prefill itself; the alternative — pushing the whole batch through with
     b-1 parked rows — multiplies the prefill matmul FLOPs by the batch."""
-    k_row = jax.lax.dynamic_slice_in_dim(cache.k, row, 1, axis=1)
-    v_row = jax.lax.dynamic_slice_in_dim(cache.v, row, 1, axis=1)
-    row_cache = KVCache(k=k_row, v=v_row)
-    if cache.k_scale is not None:
-        # int8 arm: the row's scale sidecars slice/unslice with the payload
-        row_cache = KVCache(
-            k=k_row, v=v_row,
-            k_scale=jax.lax.dynamic_slice_in_dim(cache.k_scale, row, 1, axis=1),
-            v_scale=jax.lax.dynamic_slice_in_dim(cache.v_scale, row, 1, axis=1),
-        )
+    # every leaf of the cache has the batch rows on axis 1: k and v, an int8
+    # cache's scale sidecars, a hybrid model's state slots and conv tails
+    row_cache = jax.tree.map(
+        lambda buf: jax.lax.dynamic_slice_in_dim(buf, row, 1, axis=1), cache
+    )
     _, rc = forward_uncompiled(
         cfg, params, rope, row_cache, tokens, pos_start,
         logits_mode="last", kv_len=kv_len,
     )
-    k = jax.lax.dynamic_update_slice_in_dim(cache.k, rc.k, row, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache.v, rc.v, row, axis=1)
-    if cache.k_scale is None:
-        return KVCache(k=k, v=v)
-    return KVCache(
-        k=k, v=v,
-        k_scale=jax.lax.dynamic_update_slice_in_dim(
-            cache.k_scale, rc.k_scale, row, axis=1
-        ),
-        v_scale=jax.lax.dynamic_update_slice_in_dim(
-            cache.v_scale, rc.v_scale, row, axis=1
-        ),
+    return jax.tree.map(
+        lambda buf, part: jax.lax.dynamic_update_slice_in_dim(buf, part, row, axis=1),
+        cache, rc,
     )
 
 
@@ -297,6 +283,9 @@ class BatchSession:
                 )
         if grammar is not None and self.engine.grammar is None:
             raise ValueError("this engine was built without a grammar arena")
+        # a hybrid model's row takes its recurrent-state slot as it is: the
+        # first forward, at position 0, starts it from zero in-graph
+        # (kv_arms.recurrent_arm)
         self._pending[row] = {
             "tokens": list(prompt_tokens),
             "done": 0,  # prefilled prefix length within tokens[:-1]
@@ -381,7 +370,7 @@ class BatchSession:
                         )
                     )
                 )
-                chunk = pre[done : done + n_real] + [0] * (size - n_real)
+                chunk = pre[done : done + n_real] + [eng.pad_token] * (size - n_real)
                 kv_len = eng._kv_bucket(done + size)
                 # dispatch through the ONE owner of the admission-prefill
                 # chunk program (engine._dispatch_prefill_row: pipeline /

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..formats.mfile import MFileReader
+from ..formats.mfile import ArchType, MFileReader
 from ..models import KVCache, config_from_header, forward, init_kv_cache, load_params
 from ..ops import build_rope_tables
 from ..tokenizer import Sampler
@@ -266,6 +266,27 @@ class InferenceEngine:
         )
         if q80_activations:
             self.cfg = self.cfg.with_(q80_activations=True)
+        if self.cfg.is_hybrid:
+            # a recurrent state cannot be cut at a token position: what
+            # assumes KV can (below, and the prefix cache further down) is
+            # refused for this architecture, not served without it
+            from .speculative import resolve_spec_mode as _spec
+
+            refused = [
+                what for what, asked in (
+                    ("a tp/pp/sp/ep/dp mesh", mesh is not None),
+                    ("int8 KV (--kv-dtype int8)", cache_dtype == "int8"),
+                    ("speculative decoding (--speculative other than off)",
+                     _spec(speculative, default="off") is not None),
+                ) if asked
+            ]
+            if refused:
+                raise ValueError(
+                    f"{ArchType.name(self.header.arch_type)}: linear-attention layers "
+                    "keep a recurrent state a row, which has no snapshots or "
+                    "rollback yet (ROADMAP R7): " + "; ".join(refused) + " "
+                    + ("is" if len(refused) == 1 else "are") + " not supported"
+                )
         self.mesh = mesh
         shardings = None
         self._cache_sharding = None
@@ -324,6 +345,15 @@ class InferenceEngine:
         )
         self.rope = build_rope_tables(self.header)
         self.batch = batch
+        # what a prompt chunk's tail past its real tokens is filled with. A
+        # hybrid model's padding is -1: its forward reads "token below 0" as
+        # "do not advance the recurrent state" (transformer.forward_uncompiled)
+        self.pad_token = -1 if self.cfg.is_hybrid else 0
+        # bytes of one batch row's recurrent state over all linear layers
+        # (0: the model keeps none)
+        from ..models.params import rec_state_bytes
+
+        self.rec_slot_bytes = rec_state_bytes(self.cfg, 1)
         self.max_chunk = max(1, min(max_chunk, self.cfg.seq_len))
         # device_decode: run the decode loop on device in chunks (fast path);
         # False = per-token host loop with the reference's exact RNG stream.
@@ -344,6 +374,8 @@ class InferenceEngine:
 
         self.kv_layout = resolve_kv_layout(kv_layout)
         self.paged = self.kv_layout == "paged"
+        # shards of a pool's head axis (paged_kv.pool_kv_heads pads a shard's)
+        self.kv_tp = mesh.shape["tp"] if mesh is not None else 1
         self.page_size = resolve_page_size(kv_page_size) if self.paged else None
         self.page_pool = None
         self._pt_cache = None  # (pool.version, device tables) — the cached
@@ -372,12 +404,12 @@ class InferenceEngine:
             max_slots = -(-self.cfg.seq_len // ps)
             parity = self.batch * max_slots
             n_pages = resolve_pool_pages(
-                kv_pool_mb, page_pool_bytes(self.cfg, 1, ps), parity
+                kv_pool_mb, page_pool_bytes(self.cfg, 1, ps, self.kv_tp), parity
             )
             self.page_pool = PagePool(
                 n_pages, ps, self.batch, self.cfg.seq_len, stats=self.stats,
                 reclaim=self._reclaim_pages,
-                page_bytes=page_pool_bytes(self.cfg, 1, ps),
+                page_bytes=page_pool_bytes(self.cfg, 1, ps, self.kv_tp),
                 kv_dtype=self.cfg.cache_dtype,
             )
         self.cache = self._new_cache()
@@ -413,6 +445,16 @@ class InferenceEngine:
         # nor match each other.
         from .prefix_cache import PrefixCache
 
+        if self.cfg.is_hybrid:
+            from .prefix_cache import resolve_budget_mb
+
+            if resolve_budget_mb(prefix_cache_mb, default_mb=0) > 0:
+                self._notice(
+                    "prefix cache off: a linear-attention layer's recurrent "
+                    "state has no snapshots at page boundaries yet (ROADMAP "
+                    "R7), so a cached prefix cannot be resumed"
+                )
+            prefix_cache_mb = 0
         self.prefix_cache = PrefixCache.build(self, prefix_cache_mb)
         self.last_prefix_hit_tokens = 0  # tokens the most recent prefill
         # skipped via a prefix-cache splice (0 = cold; /stats gauge twin)
@@ -480,6 +522,36 @@ class InferenceEngine:
             from ..analysis.recompile_sentinel import RecompileSentinel
 
             self.sentinel = RecompileSentinel(stats=self.stats).start()
+
+    @property
+    def warms_solo_programs(self) -> bool:
+        """Whether the warm plan and the warm-up hold the solo `prefill` /
+        `decode` programs (`generate`, `generate_batch`) beside the batched
+        ones. A server's Batcher dispatches `prefill_row`, `batch_decode` and
+        the page programs only, whatever the architecture, but the engine
+        does not know who drives it, and the plans of the architectures that
+        were here first are pinned as they are (the goldens, the warm-plan
+        tests, `setup_s` of two benchmark cells): dropping the solo half for
+        every batched server is a change of its own, measured in those cells
+        (ROADMAP S10, D12). A hybrid model's plan is new, so it starts without:
+        its period of four layers is one scan body and every program costs
+        more to compile (65 programs against 177 at the cell's arguments);
+        `generate` on such an engine compiles what it uses when it uses it."""
+        batched = self.batch > 1 and self.device_decode
+        return not (batched and self.cfg.is_hybrid)
+
+    def rec_state_snapshot(self):
+        """The recurrent-state cache as /stats reports it beside `kv_pool`:
+        one slot a batch row, allocated once. None for a model that keeps no
+        such state."""
+        if not self.rec_slot_bytes:
+            return None
+        return {
+            "slots": self.batch,
+            "bytes": self.rec_slot_bytes * self.batch,
+            "slot_bytes": self.rec_slot_bytes,
+            "layers": self.cfg.n_rec_layers,
+        }
 
     def _notice(self, msg: str) -> None:
         import warnings
@@ -583,14 +655,15 @@ class InferenceEngine:
                 + self._halving_sizes(min(8, self.decode_chunk_size))
             )
         )
-        for kvb in kvbs:
+        batched = self.batch > 1 and self.device_decode
+        for kvb in kvbs if self.warms_solo_programs else []:
             for s in prefill_sizes:
                 if s <= kvb:
                     plan.append(("prefill", s, kvb))
             for n in decode_sizes:
                 if n <= kvb:
                     plan.append(("decode", n, kvb))
-        if self.batch > 1 and self.device_decode:
+        if batched:
             for kvb in kvbs:
                 for s in prefill_sizes:
                     if s <= kvb:
@@ -689,7 +762,10 @@ class InferenceEngine:
         if self.paged:
             from .paged_kv import init_kv_pool
 
-            pool = init_kv_pool(self.cfg, self.page_pool.n_pages, self.page_size)
+            pool = init_kv_pool(
+                self.cfg, self.page_pool.n_pages, self.page_size, rows=self.batch,
+                tp=self.kv_tp,
+            )
             if self._cache_sharding is not None:
                 # int8 is single-chip (ctor gate), so mesh pools never carry
                 # scale sidecars — sharding only the payload is exhaustive
@@ -837,7 +913,8 @@ class InferenceEngine:
             n = max(1, min(self.max_chunk, self.cfg.seq_len - self.decode_chunk_size - 2))
             prompt = [1] * n
             steps = min(n + self.decode_chunk_size + 8, self.cfg.seq_len)
-            self.generate(prompt, steps, sampler=None, on_token=lambda t: None)
+            if self.warms_solo_programs:
+                self.generate(prompt, steps, sampler=None, on_token=lambda t: None)
             self.reset()
             # sampled-request RNG plumbing: a seeded/sampled request derives
             # its device PRNG key through EAGER ops (wrap_key_data, the
@@ -1096,6 +1173,9 @@ class InferenceEngine:
                 self.cfg, self.params, self.rope, self.cache, toks_dev,
                 pos_dev, logits_mode="last", kv_len=kv_len,
                 page_table=pt_row, page_size=self.page_size,
+                # a hybrid model's state slots are by batch row: this b=1
+                # call is told whose it advances (None = no operand at all)
+                rec_row=jnp.int32(row) if self.cfg.is_hybrid else None,
             )
         else:
             from .batch_session import prefill_row
@@ -1299,7 +1379,7 @@ class InferenceEngine:
             host->device transfer of its operands. Runs on the worker thread
             so it overlaps the previous chunk's dispatch."""
             i, size, n_real = plan[idx]
-            chunk = rem[i : i + n_real] + [0] * (size - n_real)
+            chunk = rem[i : i + n_real] + [self.pad_token] * (size - n_real)
             arr = np.asarray([chunk] * self.batch, dtype=np.int32)  # dlt: allow(host-sync) — host token list -> device operand prep
             return jax.device_put((arr, np.int32(base + i)))
 
@@ -1732,7 +1812,8 @@ class InferenceEngine:
         # chunk pipeline (worker-thread prep overlapping dispatch; honors
         # prefill_pipelined like `prefill`)
         if pre_t > resume:
-            padded = [list(p[:-1]) + [0] * (pre_t - (len(p) - 1)) for p in prompts]
+            pad = self.pad_token
+            padded = [list(p[:-1]) + [pad] * (pre_t - (len(p) - 1)) for p in prompts]
             plan = list(
                 chunk_plan(pre_t - resume, resume, self.max_chunk, self.cfg.seq_len)
             )
@@ -1743,7 +1824,7 @@ class InferenceEngine:
             def prep(idx):
                 i, size, _ = plan[idx]
                 rows = [row[resume + i : resume + i + size] for row in padded]
-                rows = [r + [0] * (size - len(r)) for r in rows]
+                rows = [r + [pad] * (size - len(r)) for r in rows]
                 return jax.device_put(
                     (np.asarray(rows, dtype=np.int32), np.int32(resume + i))  # dlt: allow(host-sync) — host token rows -> device operand prep
                 )

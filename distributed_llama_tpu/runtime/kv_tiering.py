@@ -109,6 +109,16 @@ def _env_float(name: str, default: float) -> float:
         return default
 
 
+def tiers_configured() -> bool:
+    """Whether the environment asks for any tier below HBM: a host or disk
+    budget, or fleet-cache peers."""
+    return bool(
+        _env_int("DLT_KV_HOST_TIER_MB", 0) > 0
+        or _env_int("DLT_KV_DISK_TIER_MB", 0) > 0
+        or resolve_tier_peers()
+    )
+
+
 def resolve_tier_peers(explicit=None) -> list:
     """``DLT_KV_TIER_PEERS``: comma-separated host:port fleet-cache peers."""
     raw = list(explicit) if explicit else [
@@ -335,12 +345,7 @@ class TieredKvStore:
         """None unless some tier is configured (host or disk budget > 0,
         or fleet-cache peers named) AND the engine runs a prefix cache —
         without tier 0 there is nothing to demote from or promote into."""
-        if engine.prefix_cache is None:
-            return None
-        host_mb = _env_int("DLT_KV_HOST_TIER_MB", 0)
-        disk_mb = _env_int("DLT_KV_DISK_TIER_MB", 0)
-        peers = resolve_tier_peers()
-        if host_mb <= 0 and disk_mb <= 0 and not peers:
+        if engine.prefix_cache is None or not tiers_configured():
             return None
         return cls(engine, goodput=goodput)
 

@@ -58,13 +58,14 @@ from __future__ import annotations
 
 import os
 import threading
+from dataclasses import replace
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.params import KVCache
+from ..models.params import KVCache, init_rec_state
 
 #: default page size in token positions. 16 == prefix_cache.PREFIX_MIN_TOKENS:
 #: every accepted prefix-cache resume boundary (a multiple of max_chunk, or a
@@ -149,23 +150,46 @@ def resolve_pool_pages(
     return parity_pages
 
 
-def page_pool_bytes(cfg, n_pages: int, page_size: int) -> int:
+def pool_kv_heads(n_kv_heads: int, tp: int = 1) -> int:
+    """Heads a pool stores for a model's `n_kv_heads`, over `tp` shards of the
+    head axis: a shard's heads, from 8 up, in whole tiles of 8 (30 -> 32; 24
+    over tp 2 -> 2 x 16). The page-table decode kernel copies a page where
+    it lies only if the pool's trailing (heads, head_dim) axes, a shard's,
+    fill whole (8, 128) tiles (models/kv_arms._fused_paged_eligible); the
+    extra heads hold zeros and cost their share of the pool (6.7% at 30, a
+    third at 12). Fewer than 8 heads a shard (12 over tp 3, a test model) are
+    stored as they are: padding them would cost more than it saves, and
+    those pools take the gather arm."""
+    local = n_kv_heads // tp
+    return n_kv_heads if local < 8 else -(-local // 8) * 8 * tp
+
+
+def page_pool_bytes(cfg, n_pages: int, page_size: int, tp: int = 1) -> int:
     """Device bytes of a pool's k+v tensors (+ the f32 scale sidecars on the
     int8 arm — capacity math, /stats, and the cost model must all price the
     STORED width, including the 4 scale bytes per head_dim payload bytes)."""
     per_vector = cfg.head_dim * jnp.dtype(cfg.kv_dtype).itemsize
     if cfg.kv_quantized:
         per_vector += 4  # one f32 scale per (token, kv-head) vector
-    return 2 * cfg.n_layers * n_pages * page_size * cfg.n_kv_heads * per_vector
+    return (
+        2 * cfg.n_kv_layers * n_pages * page_size
+        * pool_kv_heads(cfg.n_kv_heads, tp) * per_vector
+    )
 
 
-def init_kv_pool(cfg, n_pages: int, page_size: int) -> KVCache:
+def init_kv_pool(cfg, n_pages: int, page_size: int, rows: int = 0, tp: int = 1) -> KVCache:
     """The device page pool, riding the existing :class:`KVCache` pytree so
     every jit entry point's ``donate_argnames=("cache",)`` keeps working:
     ``k``/``v`` are ``[L, n_pages, page_size, n_kv, head_dim]``; the int8
     arm adds ``[L, n_pages, page_size, n_kv]`` f32 scale sidecars that page
     ops move with the SAME page indices as their payloads."""
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    # the leading axis is the layers that KEEP KV (a hybrid model's full-
+    # attention layers); its linear layers' state slots, `rows` of them,
+    # ride the same value (models/params.init_rec_state)
+    shape = (
+        cfg.n_kv_layers, n_pages, page_size, pool_kv_heads(cfg.n_kv_heads, tp),
+        cfg.head_dim,
+    )
     k = jnp.zeros(shape, dtype=cfg.kv_dtype)
     v = jnp.zeros(shape, dtype=cfg.kv_dtype)
     if cfg.kv_quantized:
@@ -174,7 +198,7 @@ def init_kv_pool(cfg, n_pages: int, page_size: int) -> KVCache:
             k_scale=jnp.zeros(shape[:-1], jnp.float32),
             v_scale=jnp.zeros(shape[:-1], jnp.float32),
         )
-    return KVCache(k=k, v=v)
+    return KVCache(k=k, v=v, **init_rec_state(cfg, rows))
 
 
 # -- the jitted copy-on-write program ----------------------------------------
@@ -198,7 +222,7 @@ def copy_page(cache: KVCache, src, dst, out_sharding=None) -> KVCache:
         k = jax.lax.with_sharding_constraint(k, out_sharding)
         v = jax.lax.with_sharding_constraint(v, out_sharding)
     if cache.k_scale is None:
-        return KVCache(k=k, v=v)
+        return replace(cache, k=k, v=v)
     # int8 arm: the scale sidecars move with the SAME page indices — a COW
     # copy that left scales behind would dequantize the moved payload with
     # the destination page's stale scales (int8 is single-chip, no sharding)

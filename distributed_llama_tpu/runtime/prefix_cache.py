@@ -768,7 +768,7 @@ class PrefixCache:
             from .paged_kv import page_pool_bytes
 
             ps = self.page_pool.page_size
-            return page_pool_bytes(engine.cfg, P // ps, ps)
+            return page_pool_bytes(engine.cfg, P // ps, ps, engine.kv_tp)
         L, _, _, h, d = engine.cache.k.shape
         return 2 * L * P * h * d * engine.cache.k.dtype.itemsize
 

@@ -1162,6 +1162,31 @@ class Batcher:
             phases.set(n_put, n_over, n_finished)
 
 
+def refuse_state_handoff(engine, args) -> None:
+    """A hybrid model's linear-attention layers keep a recurrent state a row
+    that has no snapshot, hand-off or demotion yet (ROADMAP R7): a replica
+    asked to ship or tier KV for such a model is refused at start-up (the
+    engine itself refuses meshes, int8 KV and speculation, and turns the
+    prefix cache off with a notice)."""
+    if not engine.cfg.is_hybrid:
+        return
+    from ..runtime.kv_tiering import tiers_configured
+    from .disagg import resolve_peers, resolve_role
+
+    asked = []
+    if resolve_role(getattr(args, "role", None)) != "unified" or resolve_peers(
+        getattr(args, "prefill_peer", None)
+    ):
+        asked.append("disaggregated serving (--role / --prefill-peer) ships KV pages")
+    if tiers_configured():
+        asked.append("KV tiering (DLT_KV_*_TIER_*) demotes and promotes prefix-cache pages")
+    if asked:
+        raise ValueError(
+            "; ".join(asked) + ": this architecture's recurrent state has no "
+            "snapshots or hand-off yet (ROADMAP R7): not supported"
+        )
+
+
 class ApiState:
     """Engine + tokenizer + cache shared by all requests (serialized)."""
 
@@ -1262,6 +1287,7 @@ class ApiState:
 
         self.role = resolve_role(getattr(args, "role", None))
         peers = resolve_peers(getattr(args, "prefill_peer", None))
+        refuse_state_handoff(engine, args)
         self.disagg = None
         if self.role == "decode" and peers and engine.prefix_cache is not None:
             self.disagg = DisaggClient(self, peers)
@@ -2498,6 +2524,11 @@ class Handler(BaseHTTPRequestHandler):
                     if st.engine.paged
                     else None
                 ),
+                # the second kind of cache, a hybrid model's alone: a fixed
+                # recurrent state a batch row (None on every other model);
+                # a slot is live while a request holds its row: `batcher`'s
+                # `slots_active`
+                "rec_state": st.engine.rec_state_snapshot(),
                 # per-request goodput rollup: outcomes, delivered vs wasted
                 # tokens (by reason), recent-window delivered-token rate —
                 # incl. the by_class breakdown (server/scheduler.py)
@@ -2988,6 +3019,7 @@ def serve(args) -> HTTPServer:
     # warmed page_extract/page_insert programs, so the old roles-force-
     # contiguous override is gone and the paged default applies everywhere
     engine = make_engine(args)
+    refuse_state_handoff(engine, args)  # before the warm-up is paid for
     tokenizer = Tokenizer(args.tokenizer)
     import os as _os
 

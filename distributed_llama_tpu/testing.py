@@ -39,6 +39,14 @@ def tiny_header(
     rope_scaling_low_freq_factor: float = 1.0,
     rope_scaling_high_freq_factor: float = 4.0,
     rope_scaling_orig_max_seq_len: int = 8192,
+    # olmo_hybrid: every `full_attn_interval`-th layer is full attention, the
+    # others gated-delta linear attention of `lin_heads` heads
+    full_attn_interval: int = 1,
+    lin_heads: int = 0,
+    lin_key_head_dim: int = 0,
+    lin_value_head_dim: int = 0,
+    lin_conv_kernel: int = 4,
+    lin_neg_eigval: bool = True,
 ) -> ModelHeader:
     h = ModelHeader(
         version=1,
@@ -64,6 +72,13 @@ def tiny_header(
         weight_type=weight_type,
         head_dim=head_dim,
     )
+    if arch == ArchType.OLMO_HYBRID:
+        h.full_attn_interval = full_attn_interval
+        h.lin_key_heads = h.lin_value_heads = lin_heads
+        h.lin_key_head_dim = lin_key_head_dim
+        h.lin_value_head_dim = lin_value_head_dim
+        h.lin_conv_kernel = lin_conv_kernel
+        h.lin_neg_eigval = int(lin_neg_eigval)
     return h.finalize()
 
 
@@ -95,10 +110,61 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
         kv[mfile.K_ROPE_SCALING_ORIG_MAX_SEQ_LEN] = h.rope_scaling_orig_max_seq_len
     if h.moe_hidden_dim:
         kv[mfile.K_MOE_HIDDEN_DIM] = h.moe_hidden_dim
+    if h.is_hybrid:
+        kv[mfile.K_FULL_ATTN_INTERVAL] = h.full_attn_interval
+        kv[mfile.K_LIN_KEY_HEADS] = h.lin_key_heads
+        kv[mfile.K_LIN_VALUE_HEADS] = h.lin_value_heads
+        kv[mfile.K_LIN_KEY_HEAD_DIM] = h.lin_key_head_dim
+        kv[mfile.K_LIN_VALUE_HEAD_DIM] = h.lin_value_head_dim
+        kv[mfile.K_LIN_CONV_KERNEL] = h.lin_conv_kernel
+        kv[mfile.K_LIN_NEG_EIGVAL] = h.lin_neg_eigval
     return kv
 
 
-_NORM_ROLES = ("norm0", "norm1", "final_norm", "q_norm", "k_norm")
+_NORM_ROLES = ("norm0", "norm1", "final_norm", "q_norm", "k_norm", "lin_o_norm")
+
+
+def _gdn_init(role: str, shape: tuple, rng) -> np.ndarray | None:
+    """The gated-delta layer's published initialisation for the three
+    tensors a plain normal draw would make degenerate (Yang et al., as the
+    flash-linear-attention `GatedDeltaNet` draws them): `exp(a_log)` uniform
+    in [1, 16]; `dt_bias` the inverse softplus of a step log-uniform in
+    [0.001, 0.1]; conv taps std 0.02 around a last tap of 1. None for every
+    other role."""
+    if role == "lin_a_log":
+        return np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
+    if role == "lin_dt_bias":
+        dt = np.exp(rng.uniform(np.log(0.001), np.log(0.1), shape))
+        return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+    if role == "lin_conv":
+        x = rng.standard_normal(shape).astype(np.float32) * 0.02
+        x[-1] += 1.0
+        return x
+    return None
+
+def gdn_recurrence(S, q, k, v, log_alpha, beta):
+    """The gated delta rule written out position by position: the definition
+    that `ops/gated_delta.gdn_chunked` and the Pallas step
+    `ops/pallas_gdn.gdn_decode_step` are held to (tests, `chip_smoke.py`).
+    S [b, dk, H*dv]; q, k [b, t, H, dk]; v [b, t, H, dv]; log_alpha, beta
+    [b, t, H]; float32, products at `highest`. Returns (o [b, t, H, dv], S)."""
+    import jax
+    import jax.numpy as jnp
+
+    from .ops.gated_delta import HP, _heads, _lanes
+
+    def step(S4, xs):
+        q_t, k_t, v_t, la_t, beta_t = xs
+        S4 = S4 * jnp.exp(la_t)[:, :, None, None]
+        r = jnp.einsum("bhkv,bhk->bhv", S4, k_t, precision=HP)
+        u = beta_t[..., None] * (v_t - r)
+        S4 = S4 + k_t[..., :, None] * u[..., None, :]
+        return S4, jnp.einsum("bhkv,bhk->bhv", S4, q_t, precision=HP)
+
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, log_alpha, beta))
+    S4, o = jax.lax.scan(step, _heads(S, q.shape[2]), xs)
+    return jnp.moveaxis(o, 0, 1), _lanes(S4)
+
 
 # bulk writer piece size: 4M elements. Each worker thread draws and quantizes
 # its pieces in two f32 buffers it keeps (16 MB each), and what it still
@@ -130,10 +196,10 @@ def write_tiny_model(
             return h
         rng = np.random.default_rng(seed)
         for spec in tensor_walk(h):
-            if spec.role in _NORM_ROLES:
-                x = 1.0 + rng.standard_normal(spec.shape).astype(np.float32) * 0.01
-            else:
-                x = rng.standard_normal(spec.shape).astype(np.float32) * scale
+            x = _gdn_init(spec.role, spec.shape, rng)
+            if x is None:
+                x = rng.standard_normal(spec.shape).astype(np.float32)
+                x = 1.0 + x * 0.01 if spec.role in _NORM_ROLES else x * scale
             w.write_tensor(x, spec.float_type)
     return h
 
@@ -153,6 +219,9 @@ def _write_bulk(w: MFileWriter, h: ModelHeader, seed: int, scale: float) -> None
             local.x = np.empty(max(n, _BULK_PIECE), np.float32)
             local.scratch = np.empty_like(local.x)
         rng = np.random.default_rng([seed, ti, pi])
+        special = _gdn_init(spec.role, spec.shape, rng) if n == spec.n_elements else None
+        if special is not None:  # a small tensor, whole in its one piece
+            return encode_tensor(special, spec.float_type)
         x = rng.standard_normal(n, dtype=np.float32, out=local.x[:n])
         if spec.role in _NORM_ROLES:
             x *= np.float32(0.01)

@@ -127,6 +127,11 @@ DLT_PALLAS_INTERPRET=1 \
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python scripts/dlt_graph_diff.py --check --coverage \
   --kv-layout paged --pp 2 --tp 2 --speculative off
+# the tiny Olmo-Hybrid's ladder (a period of layers, the recurrent arm, the
+# gated-delta decode kernel's body under interpret mode)
+DLT_PALLAS_INTERPRET=1 \
+  python scripts/dlt_graph_diff.py --check --coverage --arch olmo_hybrid \
+  --kv-layout paged --speculative off --prefix-cache-mb 0
 
 echo "== graph contracts (MASKED ladder goldens, grammar arena) =="
 # the grammar-capable engine's decode/verify programs carry the mask-table

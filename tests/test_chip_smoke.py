@@ -70,7 +70,10 @@ def test_one_chip_rehearsal_fails_for_the_device_check_alone(rehearsals):
 def test_one_chip_rehearsal_runs_every_phase(rehearsals):
     _, lines, _ = rehearsals["one"]
     checks = [l["check"] for l in _by_phase(lines, "numbers")]
-    assert len(checks) == 5
+    assert len(checks) == 6
+    gated = next(l for l in _by_phase(lines, "numbers") if "gated-delta" in l["check"])
+    assert max(gated[k] for k in ("kernel_o", "kernel_state", "chunked_o", "chunked_state")) <= gated["bound"]
+    assert gated["kernel_other_layer"] == 0.0  # the step writes its own layer's state alone
     for store in ("int8", "bfloat16"):  # the page-table kernel alone, both pools
         assert f"{store} page-table kernel vs gather" in checks
     gen = next(l for l in _by_phase(lines, "numbers") if "generation" in l["check"])

@@ -376,3 +376,89 @@ def test_planted_dot_breaks_the_masked_proof(contig_engine, model_path):
         )
     finally:
         var.close()
+
+
+# -- the hybrid model's programs (a period of layers, a second kind of cache) --
+
+HYBRID_ARGS = ["--arch", "olmo_hybrid", "--kv-layout", "paged", "--speculative", "off",
+               "--prefix-cache-mb", "0"]
+
+
+def test_repo_golden_covers_the_tiny_hybrid(monkeypatch):
+    """One golden for the tiny Olmo-Hybrid's warm plan (prefill_row,
+    batch_decode, page_copy), traced with Pallas interpreted so that it holds
+    the gated-delta decode kernel's body beside the Q40 and page-table
+    kernels'."""
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    assert gd.main(["--check", "--coverage", *HYBRID_ARGS]) == 0
+
+
+@pytest.fixture(scope="module", params=["xla", "interpret"])
+def hybrid_engine(request, tmp_path_factory):
+    import argparse
+
+    mp = pytest.MonkeyPatch()
+    if request.param == "interpret":
+        mp.setenv("DLT_PALLAS_INTERPRET", "1")
+    else:
+        mp.delenv("DLT_PALLAS_INTERPRET", raising=False)
+    p = argparse.ArgumentParser()
+    ga.add_engine_args(p)
+    args = p.parse_args([*HYBRID_ARGS, "--compute-dtype", "bfloat16"])
+    eng = ga.engine_from_args(args, str(tmp_path_factory.mktemp("hybrid")))
+    yield eng
+    eng.close()
+    mp.undo()
+
+
+def test_hybrid_programs_meet_their_contracts(hybrid_engine):
+    """No float64, the float32 dots within what the recurrence needs, no
+    collective, and every leaf of the cache donated on every jit entry."""
+    eng = hybrid_engine
+    ga.assert_clean(ga.audit_engine(eng))
+    assert ga.donation_problems(eng) == []
+    assert len(jax.tree_util.tree_leaves(eng.cache)) == 4  # k, v, rec, conv
+
+
+def test_hybrid_f32_dot_budget_counts_the_recurrence(hybrid_engine):
+    """Per period: attention's 2, and for each of the 3 linear layers the
+    gates' projection (1) and the recurrence: the Pallas step's dots are
+    bfloat16 (0), the chunked form has 7."""
+    eng = hybrid_engine
+    kernel = eng.cfg.pallas_interpret
+    want = {
+        ("batch_decode", 8): 2 + 3 * (1 if kernel else 8),
+        ("prefill_row", 1): 2 + 3 * 8,  # one row against the batch's slots: never the kernel
+        ("prefill_row", 16): 2 + 3 * 8,
+    }
+    for (kind, size), budget in want.items():
+        entry = ga.LadderEntry(kind, size, 128)
+        assert ga.f32_dot_budget(eng, entry) == budget
+        dots = jt.dot_input_census(ga.trace_entry(eng, entry))
+        got = sum(n for (l, r), n in dots.items() if "float32" in (l, r))
+        assert got == budget, (kind, size, dots)
+
+
+def test_hybrid_batch_decode_gathers_no_pool(hybrid_engine):
+    """12 kv heads stored as 16: with Pallas on, the full-attention layer of
+    the batch-decode program takes the page-table kernel through the padded
+    pool, and the contract pins the pool's gathers to zero."""
+    eng = hybrid_engine
+    entry = ga.LadderEntry("batch_decode", 8, 128)
+    contract = ga.contract_for(eng, entry)
+    assert eng.cache.k.shape[3] == 16
+    if not eng.cfg.pallas_interpret:
+        assert contract.forbid_pool_gather is None  # the gather arm, off the TPU
+        return
+    assert contract.forbid_pool_gather == tuple(eng.cache.k.shape)
+    jaxpr = ga.trace_entry(eng, entry)
+    assert ga.contract_problems(eng, contract, jaxpr) == []
+    text = str(jaxpr)
+    assert "gdn_decode_step" in text and "paged_decode_attention" in text
+
+
+def test_a_lost_state_donation_is_reported():
+    txt = 'func @main(%a {tf.aliasing_output = 0 : i32}, %b {tf.aliasing_output = 1 : i32})'
+    assert ga.donated_leaf_check("x", txt, 2) == []
+    problems = ga.donated_leaf_check("x", txt, 4)
+    assert problems and "4 leaves" in problems[0]

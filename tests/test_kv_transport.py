@@ -184,6 +184,36 @@ def test_mesh_paged_identity_tp2_and_pp2tp2(tmp_path):
         assert got == want, shape
 
 
+@pytest.mark.parametrize("n_kv, tp, stored", [
+    (30, 1, 32), (12, 1, 16), (8, 1, 8), (4, 1, 4),  # one chip: from 8 heads up, whole tiles
+    (12, 3, 12), (8, 2, 8),  # fewer than 8 a shard: stored as they are, and 12 % 3 still divides
+    (24, 2, 32), (20, 2, 32),  # 12 and 10 a shard -> 16 a shard
+])
+def test_a_pool_pads_each_tp_shards_heads_to_whole_tiles(n_kv, tp, stored):
+    from distributed_llama_tpu.runtime.paged_kv import pool_kv_heads
+
+    assert pool_kv_heads(n_kv, tp) == stored and stored % tp == 0
+
+
+def test_mesh_paged_identity_with_padded_heads_a_shard(tmp_path):
+    """24 kv heads over tp=2: each shard's 12 are stored as 16 (a pool of
+    32), the arm pads its local heads inside the shard_map, and paged still
+    serves what contiguous serves."""
+    from distributed_llama_tpu.testing import tiny_header, write_tiny_model
+
+    mp = str(tmp_path / "heads24.m")
+    write_tiny_model(mp, tiny_header(seq_len=128, dim=192, hidden_dim=128, n_layers=2,
+                                     n_heads=24, n_kv_heads=24), seed=0)
+    ec = _mesh_engine(mp, "contiguous", tp=2)
+    want = _greedy(ec, steps=24)
+    ec.close()
+    ep = _mesh_engine(mp, "paged", tp=2)
+    assert ep.cache.k.shape[3] == 32
+    got = _greedy(ep, steps=24)
+    ep.close()
+    assert got == want
+
+
 def test_mesh_paged_rejects_unsupported_topologies(tmp_path):
     from distributed_llama_tpu.parallel import make_mesh
     from distributed_llama_tpu.runtime.engine import InferenceEngine

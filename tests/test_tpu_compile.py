@@ -32,6 +32,14 @@ MATMULS_14B = {
     "w2": (17408, 5120), "wcls": (5120, VOCAB),
 }
 HEADS, KV_HEADS, HEAD_DIM, PAGE = 32, 8, 128, 16
+# Olmo-Hybrid-7B (perfbench/configs/olmo-hybrid-7b.json): the linear layers'
+# fused q|k|v|gate projection and their output projection as the device holds
+# it (`in` 5760 padded to 6144), 24 of 32 layers; 8 full layers of 30 heads
+OLMOH_LIN, OLMOH_FULL = 24, 8
+MATMULS_OLMOH = {
+    "lin_wqkvg": (3840, 17280), "lin_wo": (6144, 3840), "w13": (3840, 2 * 11008),
+    "w2": (11008, 3840), "wcls": (3840, 100352),
+}
 
 
 @pytest.fixture(scope="module")
@@ -104,7 +112,72 @@ def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS):
     return build
 
 
+def _gdn(rows):
+    """The gated-delta decode step over every linear layer's state
+    (ops/pallas_gdn.py): 2.2 MB a row a layer, updated in place."""
+    from distributed_llama_tpu.ops.pallas_gdn import gdn_decode_step
+
+    def build(S):
+        H, dk, dv = 30, 96, 192
+        f32 = jnp.float32
+        return gdn_decode_step, [
+            S((OLMOH_LIN, rows, dk, H * dv), f32), S((), jnp.int32),
+            S((rows, H, dk), f32), S((rows, H, dk), f32), S((rows, H, dv), f32),
+            S((rows, H), f32), S((rows, H), f32), S((rows,), jnp.bool_),
+        ]
+
+    return build
+
+
+def _paged_arm_30_heads(rows, n_read):
+    """A full-attention layer's cache arm as the hybrid's batch-decode program
+    runs it (models/kv_arms.paged_arm): 30 kv heads written into, and read
+    from, a pool that stores 32."""
+    from distributed_llama_tpu.models import kv_arms
+    from distributed_llama_tpu.models.config import ModelConfig
+    from distributed_llama_tpu.models.params import KVCache
+    from distributed_llama_tpu.runtime.paged_kv import pool_kv_heads
+
+    cfg = ModelConfig(
+        arch_type=0xABCD03, dim=3840, hidden_dim=11008, n_layers=32, n_heads=30,
+        n_kv_heads=30, head_dim=128, vocab_size=100352, seq_len=2048, n_experts=0,
+        n_active_experts=0, hidden_act=1, rope_type=1, norm_epsilon=1e-6,
+        use_pallas=True, full_attn_interval=4,
+    )
+    assert pool_kv_heads(30) == 32
+
+    def build(S):
+        def fn(q, k, v, pool_k, pool_v, pos, table):
+            addr = kv_arms.CacheAddr(
+                layer=jnp.int32(3), kv_len=n_read * PAGE, page_table=table, page_size=PAGE
+            )
+            a, cache = kv_arms.paged_arm(
+                cfg, KVCache(k=pool_k, v=pool_v), addr, q, k, v, pos[:, None], pos
+            )
+            return a, cache.k, cache.v
+
+        head = S((rows, 1, 30, HEAD_DIM), jnp.bfloat16)
+        pool = S((OLMOH_FULL, 2560, PAGE, 32, HEAD_DIM), jnp.bfloat16)
+        return fn, [head, head, head, pool, pool, S((rows,), jnp.int32),
+                    S((rows, 128), jnp.int32)], (3, 4)
+
+    return build
+
+
 CASES = {
+    # Olmo-Hybrid-7B: the decode step at the issue's 32 rows, the cell's 24
+    # and the next fallback, 16
+    "gdn-decode-32rows": _gdn(32),
+    "gdn-decode-24rows": _gdn(24),
+    "gdn-decode-16rows": _gdn(16),
+    "paged-arm-30heads-b24-read128": _paged_arm_30_heads(24, 128),
+    **{
+        f"olmoh-bf16-{rows}rows-{n}": _matmul(
+            pq.q40_matmul_pallas if n == "wcls" else pq.q40_matmul_pallas_stacked,
+            rows, n, stacked=n != "wcls", matmuls=MATMULS_OLMOH, dtype=jnp.bfloat16,
+        )
+        for rows in (24, 256) for n in MATMULS_OLMOH
+    },
     # decode rows (<= 8): the int8-MXU kernel, every weight of the step
     **{f"i8-1row-{n}": _matmul(pq.q40_matmul_pallas_i8, 1, n) for n in MATMULS},
     **{f"i8-8rows-{n}": _matmul(pq.q40_matmul_pallas_i8, 8, n) for n in MATMULS},
@@ -162,14 +235,17 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(v5e, case):
     S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-    fn, args = CASES[case](S)
-    compiled = jax.jit(fn).lower(*args).compile()
+    fn, args, *donate = CASES[case](S)
+    if case.startswith("gdn"):
+        donate = [(0,)]  # the state, updated in place as the step programs donate it
+    compiled = jax.jit(fn, donate_argnums=donate[0] if donate else ()).lower(*args).compile()
     assert count_tpu_kernels(compiled) >= 1
-    if case.startswith("paged"):
-        # the pool is read where it lies: a reshaped or re-laid-out operand
-        # shows up as a copy of the whole pool (GBs) in the program's temps
-        pool_bytes = args[1].size * args[1].dtype.itemsize
-        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 64
+    if case.startswith("paged") or case.startswith("gdn"):
+        # the pool (the state) is read where it lies: a reshaped or
+        # re-laid-out operand shows up as a copy of the whole of it (GBs) in
+        # the program's temps
+        big = max(args, key=lambda a: a.size * a.dtype.itemsize)
+        assert compiled.memory_analysis().temp_size_in_bytes < big.size * big.dtype.itemsize // 64
 
 
 @pytest.mark.parametrize("rows", (8, 16))
