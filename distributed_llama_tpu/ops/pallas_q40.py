@@ -42,8 +42,13 @@ VPU-bound plane-extraction unpacks, an unpacked int8 weight layout (twice
 the HBM bytes).
 
 Tiling:
-  grid = (out/TILE_N, nb/TILE_KNB), k innermost (output tile revisited,
-  f32 accumulation in place);
+  grid = (cdiv(out, TILE_N), nb/TILE_KNB), k innermost (output tile
+  revisited, f32 accumulation in place). TILE_KNB divides nb always; TILE_N
+  divides out unless out's widest divisor is under half the width asked for
+  (`_lane_tile`): then the last tile of lanes is ragged, which the bodies
+  need not know (no column reads another's weights). So Qwen3's output
+  head, 151936 = 1187 x 128 columns, runs 75 tiles of 2048 lanes where the
+  divisor rule gave it 1187 of 128 (PERF.md, PR 37);
   packed block [TILE_KNB*4, TILE_N] int32 — full 8-sublane i32 vregs (a
   3D [TILE_KNB, 4, TILE_N] block leaves half of every vreg empty and
   measures ~2x slower);
@@ -286,7 +291,8 @@ def _bf16_tile_cap(b: int, tile_n: int, tile_knb: int, nb: int):
     ] or [nb]
     tile_knb = next((d for d in legal if not over(tile_n, d)), legal[-1])
     while over(tile_n, tile_knb) and tile_n > LANE:
-        # the next narrower whole-lane divisor (of a divisor of out: still one)
+        # the next narrower whole-lane divisor of the tile: of a tile that
+        # divides out still one, and a ragged tile needs none
         tile_n = next(t for t in range(tile_n - LANE, 0, -LANE) if tile_n % t == 0)
     return tile_n, tile_knb
 
@@ -302,8 +308,10 @@ def _bf16_tiles(b: int, nb: int, out: int) -> tuple[int, int]:
          lets it at DEFAULT_TILE_N lanes, else its deepest legal divisor
          (`_bf16_tile_cap`);
       2. then BF16_TILE_N lanes where the budget still holds, else
-         DEFAULT_TILE_N (a prime-ish out keeps its widest divisor:
-         `_lane_tile`; Qwen3's vocabulary is left with 128).
+         DEFAULT_TILE_N. Either width is `_lane_tile`'s: the widest divisor
+         of out under it, or the width itself with a ragged last tile where
+         no divisor reaches half of it (Qwen3's vocabulary, 1187 x 128:
+         BF16_TILE_N lanes and 297 tiles, where it had 128 and 1187).
     Few rows leave the budget to the weights, so 16 rows take 512 lanes of
     the whole contraction at the benchmark's widths; a prompt's 256 rows keep
     the depth and give the lanes back."""
@@ -367,7 +375,7 @@ def q40_matmul_pallas_stacked(
     qt2 = qt.reshape(L * rows4, out)
     dt3 = dt.reshape(L * nb, out)
 
-    grid = (out // tile_n, k_steps)
+    grid = (pl.cdiv(out, tile_n), k_steps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -438,16 +446,24 @@ def _quantize_rows_q80_split(x2: jnp.ndarray, nb: int):
 
 
 def _lane_tile(out: int, target: int) -> int:
-    """Largest multiple-of-128 divisor of `out` that is <= target. The old
-    halving chain collapsed non-power-of-two outs to tiny tiles (the 8B's
-    128256 vocab fell from a 2048 target to 256 lanes; 128256 = 167 * 768,
-    so 768 is the honest answer)."""
+    """Lanes of a tile of `out` columns: the widest multiple of 128 under
+    `target` that divides `out`, unless that is under half of what was asked
+    for; then the asked width itself, and the grid's last tile is ragged
+    (the wrappers take `pl.cdiv(out, tile)` tiles). The old halving chain
+    collapsed non-power-of-two outs to tiny tiles; the divisor search keeps
+    1792 of 2048 for Qwen3-14B's wqkv (7168 = 4 x 1792). A prime-ish out has
+    no divisor worth keeping: Qwen3's vocabulary, 151936 = 1187 x 128, was
+    left with 128 lanes and a grid step per 128 outputs (PR 37), Llama-3's
+    128256 = 167 x 768 with 768 of 2048. A ragged tile costs nothing in the
+    body: Pallas hands the last grid step a whole block whose columns past
+    `out` hold unspecified values and are dropped on the way back, and no
+    column of these kernels reads another's weights or scales. Only lanes
+    may be ragged: padding on the contraction axis would enter every sum."""
     tn = min(target, out)
     tn -= tn % LANE
-    while tn >= LANE:
-        if out % tn == 0:
-            return tn
-        tn -= LANE
+    for t in range(tn, 0, -LANE):
+        if out % t == 0:
+            return t if 2 * t >= tn else tn
     return out
 
 
@@ -458,9 +474,11 @@ def _fs_tiles(nb: int, out: int) -> tuple[int, int]:
     (PERF.md, PR 26): big outs take 2048 lanes, and 64 blocks a step where
     the contraction has them; a contraction that is no multiple of 64 blocks
     (Qwen3-14B: nb = 160 and 544) halves down to 32. Lane tiles come from
-    `_lane_tile`, so a ragged out keeps its widest divisor; a prime one
-    (Qwen3's vocabulary, 1187 x 128) is left with 128 lanes and a grid step
-    per 128 outputs, which is what bounds the head (ROADMAP S3)."""
+    `_lane_tile`: a ragged out keeps its widest divisor (Qwen3-14B's wqkv
+    1792, its wo and w2 1280), and one with no divisor of 1024 lanes or more
+    takes the 2048 with a ragged last tile: Qwen3's vocabulary, 1187 x 128,
+    runs 75 tiles x 5 k steps at 14B where 128 lanes made 5,935 grid steps
+    of 0.4 us each, which bounded the head (PERF.md, PR 37)."""
     if out >= 4096:
         tile_n, tile_knb = 2048, (64 if nb >= 128 else 32)
     elif nb >= 256:
@@ -593,7 +611,7 @@ def q40_matmul_pallas_i8(x, qt, dt, interpret: bool = False) -> jnp.ndarray:
     tile_n, tile_knb = _fs_tiles(nb, out)
     sub = _fs_sub(tile_knb)
     mask = _halfmask(sub)
-    grid = (out // tile_n, nb // tile_knb)
+    grid = (pl.cdiv(out, tile_n), nb // tile_knb)
     out2 = pl.pallas_call(
         _kernel_fs_i8,
         grid=grid,
@@ -636,7 +654,7 @@ def q40_matmul_pallas_stacked_i8(
     k_steps = nb // tile_knb
     qt2 = qt.reshape(L * rows4, out)
     dt3 = dt.reshape(L * nb, out)
-    grid = (out // tile_n, k_steps)
+    grid = (pl.cdiv(out, tile_n), k_steps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -791,7 +809,7 @@ def q40_matmul_pallas(
     tile_n, tile_knb = _bf16_tiles(b, nb, out)
     x2 = x.reshape(b, in_features).astype(dtype)
 
-    grid = (out // tile_n, nb // tile_knb)
+    grid = (pl.cdiv(out, tile_n), nb // tile_knb)
     out2 = pl.pallas_call(
         _kernel,
         grid=grid,

@@ -23,7 +23,10 @@ give the same bf16 weight tile, bit for bit (tests/test_pallas_q40.py):
   twodots the same stacked planes, one dot a plane
 Table `tiles`, the served body at every tile of TILE_N x TILE_KNB that divides
 the shape and fits the chip's VMEM (the compiler refuses the rest), `served`
-marking the one `pallas_q40._bf16_tiles` gives.
+marking the one `pallas_q40._bf16_tiles` gives. An out that not even 256 lanes
+divide (the heads: 151936 = 1187 x 128) takes every lane width, the last tile
+ragged (PR 37; 128 lanes are what it had before): `--only wcls --rows 16,24`
+is the head's row, us a call beside its floor, tile and grid steps.
 `--compile-only` compiles every variant of both tables for a described v5e
 (what the compiler refuses costs no chip time) and counts, in Mosaic's
 `post-finalize-llo` dump of the body, the vector-ALU operations for every
@@ -61,7 +64,7 @@ from jax.experimental import pallas as pl
 
 from distributed_llama_tpu.formats.quants import Q_BLOCK
 from distributed_llama_tpu.ops import pallas_q40 as pq
-from probe_i8_sub import chained, weights  # the loop of n dependent calls; Q40 weights
+from probe_i8_sub import chained, grid_steps, weights  # the loop of n dependent calls; Q40 weights
 
 HBM_BYTES_PER_S, BF16_FLOPS_PER_S = 819e9, 197e12  # perfbench/peaks.json
 SHAPES = [
@@ -163,7 +166,7 @@ def kernel_call(body, x, qp, dt, tn, knb):
     nb, out, b = qp.shape[0] // 4, qp.shape[1], x.shape[0]
     return pl.pallas_call(
         BODIES[body],
-        grid=(out // tn, nb // knb),
+        grid=(pl.cdiv(out, tn), nb // knb),
         in_specs=[
             pl.BlockSpec((b, knb * Q_BLOCK), lambda j, k: (0, k)),
             pl.BlockSpec((knb * 4, tn), lambda j, k: (k, j)),
@@ -186,15 +189,16 @@ def parent_tile(b, nb, out):
 
 
 def candidate_tiles(b, nb, out):
-    """Every (lanes, blocks) of TILE_N x TILE_KNB that divides the shape,
-    keeps the scale block's sublane rule and whose double-buffered blocks
+    """Every (lanes, blocks) of TILE_N x TILE_KNB that divides the shape
+    (in lanes: or leaves a ragged last tile, where 256 lanes do not divide it
+    either), keeps the scale block's sublane rule and whose double-buffered blocks
     (activations, packed weights, scales, result) stay under VMEM_BLOCKS;
     the parent's and the served tile among them in any case."""
     tiles = []
     for tn in TILE_N:
         for knb in TILE_KNB:
             knb = min(knb, nb)
-            if out % tn or nb % knb or (knb != nb and knb % 8):
+            if (out % tn and out % 256 == 0) or nb % knb or (knb != nb and knb % 8):
                 continue
             if (tn == 128 and out % 256 == 0) or (knb == 32 and nb % 64 == 0):
                 continue  # narrower or shallower than the parent's: not a candidate
@@ -326,7 +330,10 @@ def main():
                 x = jnp.asarray(rng.standard_normal((b, in_f)), jnp.bfloat16)
                 run = chained(lambda c, q, d: pq.q40_matmul_pallas(c, q, d, dtype=jnp.bfloat16))
                 us = call_us(run, (x, qp, dt), 3 * max(hbm, mxu))
-                lines.append({"shape": label, "rows": b, "us": round(us, 2),
+                tn, knb = pq._bf16_tiles(b, in_f // Q_BLOCK, out_f)
+                lines.append({"shape": label, "tile_n": tn, "knb": knb,
+                              "grid_steps": grid_steps(in_f // Q_BLOCK, out_f, tn, knb),
+                              "rows": b, "us": round(us, 2),
                               "hbm_floor_us": round(hbm, 2), "mxu_floor_us": round(mxu, 2)})
                 print(json.dumps(lines[-1]), flush=True)
     else:
@@ -343,7 +350,7 @@ def main():
             )
             xk = plane_major(x, knb) if body in PLANE_MAJOR else x
             line = {"table": table, "shape": label, "rows": b, "body": body,
-                    "tile_n": tn, "knb": knb, "grid_steps": (out_f // tn) * (in_f // Q_BLOCK // knb),
+                    "tile_n": tn, "knb": knb, "grid_steps": grid_steps(in_f // Q_BLOCK, out_f, tn, knb),
                     "served": body == "served" and (tn, knb) == pq._bf16_tiles(b, in_f // Q_BLOCK, out_f)}
             try:
                 got = np.asarray(jax.jit(lambda *t: kernel_call(body, *t, tn, knb))(xk, qp, dt16))

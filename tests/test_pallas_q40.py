@@ -300,12 +300,22 @@ def test_i8_kernel_ragged_vocab_out():
 # Qwen3-14B's contractions are no multiple of 64 blocks: `_fs_tiles` halves
 # to 32, so dim 5120 is 5 k steps and ffn 17408 is 17 (outs scaled down)
 RAGGED_CONTRACTIONS = {160: 5, 544: 17}
+# outs whose widest dividing tile is under half of what is asked for (19 and
+# 1187 are prime; 128 x 1187 is Qwen3's vocabulary): the last tile of lanes is
+# ragged (PR 37). Over a small contraction: the lanes are what is tested
+RAGGED_LANES = (128 * 19, 128 * 1187)
+I8_RAGGED_CASES = [(nb, 256, rows) for nb in sorted(RAGGED_CONTRACTIONS) for rows in (1, 2, 4, 8)] + [
+    (8, out_f, rows) for out_f in RAGGED_LANES for rows in (1, 8)
+]
 
 
 @pytest.mark.parametrize("stacked", [False, True], ids=["plain", "stacked"])
-@pytest.mark.parametrize("rows", [1, 2, 4, 8])
-@pytest.mark.parametrize("nb", sorted(RAGGED_CONTRACTIONS))
-def test_i8_sub_blocked_kernel_at_ragged_contractions(nb, rows, stacked):
+@pytest.mark.parametrize("nb,out_f,rows", I8_RAGGED_CASES)
+def test_i8_sub_blocked_kernel_at_ragged_contractions(nb, out_f, rows, stacked):
+    """Ragged on either axis. A contraction that 64 blocks do not divide
+    takes an exact divisor; an out that no wide tile divides takes the wide
+    tile and a ragged last one, whose padding (NaN in interpret mode)
+    reaches no stored column. Both as exact against the Q80 reference."""
     from distributed_llama_tpu.ops.pallas_q40 import (
         _fs_sub,
         _fs_tiles,
@@ -313,10 +323,13 @@ def test_i8_sub_blocked_kernel_at_ragged_contractions(nb, rows, stacked):
         q40_matmul_pallas_stacked_i8,
     )
 
-    out_f, in_f = 256, nb * 32
+    in_f = nb * 32
     tn, knb = _fs_tiles(nb, out_f)
-    assert (knb, nb // knb) == (32, RAGGED_CONTRACTIONS[nb])
-    assert _fs_sub(knb) == 8  # four sub-blocks a k step
+    if nb in RAGGED_CONTRACTIONS:
+        assert (knb, nb // knb) == (32, RAGGED_CONTRACTIONS[nb])
+    else:
+        assert tn == (2048 if out_f >= 4096 else 1024) and out_f % tn
+    assert nb % knb == 0 and _fs_sub(knb) == 8  # whole sub-blocks a k step
     rng = np.random.default_rng(nb + rows)
     layers = [make_weight(rng, out_f, in_f) for _ in range(2 if stacked else 1)]
     x = jnp.asarray(rng.standard_normal((rows, in_f)), jnp.float32)
@@ -327,7 +340,9 @@ def test_i8_sub_blocked_kernel_at_ragged_contractions(nb, rows, stacked):
         got = q40_matmul_pallas_stacked_i8(x, qs, ds, jnp.int32(1), interpret=True)
     else:
         got = q40_matmul_pallas_i8(x, layers[0].q, layers[0].d, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    got = np.asarray(got)
+    assert got.shape == (rows, out_f) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 def test_sub_block_partials_equal_the_single_dot_exactly():
@@ -394,7 +409,7 @@ def test_executed_multiply_adds_per_weight(name):
     in_f, out_f = MODEL_MATMULS[name]
     nb = in_f // 32
     tn, knb = _fs_tiles(nb, out_f)
-    assert nb % knb == 0 and out_f % tn == 0
+    assert nb % knb == 0 and (out_f % tn == 0) != name.endswith("wcls")
     sub = _fs_sub(knb)
     assert knb % sub == 0
     for rows in range(1, 9):
@@ -484,24 +499,41 @@ BF16_KERNEL_NB = (128, 160, 544)
 REASSOCIATION_RTOL = 1e-5
 
 
+# (nb, out, rows, kernel): every contraction at a dividing out, and the ragged
+# outs (RAGGED_LANES; the grouped kernel has a tile rule of its own) at the
+# two decoding row counts the benchmark serves on this arm
+BF16_KERNEL_CASES = [
+    (nb, 256, rows, kernel)
+    for nb in BF16_KERNEL_NB
+    for rows in (9, 16, 32, 256)
+    for kernel in ("plain", "stacked", "grouped")
+] + [
+    (8, out_f, rows, kernel)
+    for out_f in RAGGED_LANES
+    for rows in (16, 24)
+    for kernel in ("plain", "stacked")
+]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("kernel", ["plain", "stacked", "grouped"])
-@pytest.mark.parametrize("rows", [9, 16, 32, 256])
-@pytest.mark.parametrize("nb", BF16_KERNEL_NB)
-def test_bf16_dequant_kernels_match_the_xla_path(nb, rows, kernel, dtype):
+@pytest.mark.parametrize("nb,out_f,rows,kernel", BF16_KERNEL_CASES)
+def test_bf16_dequant_kernels_match_the_xla_path(nb, out_f, rows, kernel, dtype):
     """The three kernels that share `_dequant_dot_accum`, at the rows above
     the int8 arm's 8 and at a prompt's 256. f32: against `_quant_matmul_xla`
     (the same f32 products). bf16: against the matmul of the same bf16
     operands in f32 (`_quant_matmul_xla` rounds `code * f16 scale` once; the
     kernel rounds the scale to bf16 first, as it always did). Both within
-    REASSOCIATION_RTOL: only the order of the f32 sums differs."""
+    REASSOCIATION_RTOL: only the order of the f32 sums differs. At a ragged
+    out the last tile's padding (NaN in interpret mode) is stored nowhere."""
     from distributed_llama_tpu.ops.pallas_q40 import (
         q40_matmul_pallas_grouped,
         q40_matmul_pallas_stacked,
     )
+    from distributed_llama_tpu.ops.pallas_q40 import _bf16_tiles
     from distributed_llama_tpu.ops.quant import _quant_matmul_xla
 
-    out_f, in_f = 256, nb * 32
+    in_f = nb * 32
+    assert bool(out_f % _bf16_tiles(rows, nb, out_f)[0]) == (out_f in RAGGED_LANES)
     rng = np.random.default_rng(nb * 1000 + rows)
     layers = [make_weight(rng, out_f, in_f) for _ in range(1 if kernel == "plain" else 2)]
     x = jnp.asarray(rng.standard_normal((rows, in_f)), dtype)
@@ -539,15 +571,18 @@ def test_bf16_dequant_kernels_match_the_xla_path(nb, rows, kernel, dtype):
             )[:rows]
     got = np.asarray(got)
     assert got.dtype == np.float32 and got.shape == (rows, out_f)
+    assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=0, atol=REASSOCIATION_RTOL * np.abs(want).max())
 
 
 @pytest.mark.parametrize("name", sorted(MODEL_MATMULS))
 def test_bf16_tiles_from_rows_and_shape(name):
     """`_bf16_tiles` at the benchmark's matmuls: a grid that covers the
-    weight exactly, under the VMEM budget at every served row count, the
-    whole contraction in one k step wherever a prompt's 256 rows let it
-    (every matmul but w2) and 512 lanes of it at 16 rows."""
+    weight, exactly on the contraction and on every out but the head's
+    (151936 = 1187 x 128: ragged last tile), under the VMEM budget at every
+    served row count, the whole contraction in one k step wherever a
+    prompt's 256 rows let it (every matmul but w2) and 512 lanes of it at
+    16 rows, the head's too."""
     from distributed_llama_tpu.ops.pallas_q40 import (
         BF16_VMEM_CAP,
         _bf16_tiles,
@@ -558,12 +593,93 @@ def test_bf16_tiles_from_rows_and_shape(name):
     nb = in_f // 32
     for b in (9, 16, 32, 64, 128, 256, 512, 1024):
         tn, knb = _bf16_tiles(b, nb, out_f)
-        assert out_f % tn == 0 and tn % 128 == 0 and nb % knb == 0 and knb % 8 == 0
+        assert tn % 128 == 0 and nb % knb == 0 and knb % 8 == 0
+        assert out_f % tn == 0 or (name.endswith("wcls") and tn == 512)
         assert _bf16_vmem_need(b, tn, knb) <= BF16_VMEM_CAP, (b, tn, knb)
         if not name.endswith("w2") and b <= 256:
             assert knb == nb, (b, tn, knb)
-    lanes = 128 if name.endswith("wcls") else 512  # 151936 = 1187 x 128
-    assert _bf16_tiles(16, nb, out_f)[0] == (256 if name.endswith("w2") else lanes)
+    assert _bf16_tiles(16, nb, out_f)[0] == (256 if name.endswith("w2") else 512)
+    if name.endswith("wcls"):  # 297 ragged tiles of the whole contraction, not 1187
+        assert _bf16_tiles(16, nb, out_f) == (512, nb) and -(-out_f // 512) == 297
     # fewer rows never take a smaller tile
     sizes = [tn * knb for tn, knb in (_bf16_tiles(b, nb, out_f) for b in (16, 64, 256, 1024))]
     assert sizes == sorted(sizes, reverse=True), sizes
+
+
+# ---- a lane tile need not divide `out` (PR 37) ----
+
+# in -> out of the hybrid's matmuls (lin_wo's contraction as the device layout
+# pads it: 180 blocks to 184)
+HYBRID_MATMULS = {
+    "olmoh.wqkv": (3840, 11520), "olmoh.wo": (3840, 3840), "olmoh.w13": (3840, 22016),
+    "olmoh.w2": (11008, 3840), "olmoh.wcls": (3840, 100352),
+    "olmoh.lin_wqkvg": (3840, 17280), "olmoh.lin_wo": (5888, 3840),
+}
+BF16_TABLE_ROWS = (16, 24, 64, 256)
+# (lanes, blocks a k step) at the parent of PR 37, where a lane tile had to
+# divide `out`: the int8 arm's (1 to 8 rows: `_fs_tiles` reads no row count),
+# then the bf16-dequant arm's at BF16_TABLE_ROWS
+PARENT_TILES = {
+    "14b.wqkv": ((1792, 32), (512, 160), (512, 160), (512, 160), (256, 160)),
+    "14b.wo": ((1280, 32), (512, 160), (512, 160), (512, 160), (256, 160)),
+    "14b.w13": ((2048, 32), (512, 160), (512, 160), (512, 160), (256, 160)),
+    "14b.w2": ((1280, 32), (256, 544), (256, 544), (256, 272), (512, 136)),
+    "14b.wcls": ((128, 32), (128, 160), (128, 160), (128, 160), (128, 160)),
+    "8b.wqkv": ((2048, 64), (512, 128), (512, 128), (512, 128), (512, 128)),
+    "8b.wo": ((2048, 64), (512, 128), (512, 128), (512, 128), (512, 128)),
+    "8b.w13": ((2048, 64), (512, 128), (512, 128), (512, 128), (512, 128)),
+    "8b.w2": ((2048, 64), (256, 384), (256, 384), (256, 384), (512, 128)),
+    "8b.wcls": ((128, 64), (128, 128), (128, 128), (128, 128), (128, 128)),
+    "olmoh.wqkv": ((1920, 8), (384, 120), (384, 120), (384, 120), (384, 120)),
+    "olmoh.wo": ((768, 8), (384, 120), (384, 120), (384, 120), (384, 120)),
+    "olmoh.w13": ((512, 8), (512, 120), (512, 120), (512, 120), (512, 120)),
+    "olmoh.w2": ((768, 8), (384, 344), (384, 344), (256, 344), (384, 8)),
+    "olmoh.wcls": ((2048, 8), (512, 120), (512, 120), (512, 120), (512, 120)),
+    "olmoh.lin_wqkvg": ((1920, 8), (384, 120), (384, 120), (384, 120), (384, 120)),
+    "olmoh.lin_wo": ((768, 8), (384, 184), (384, 184), (384, 184), (384, 8)),
+}
+# what a ragged last tile changes of that table. The heads' 256-row entry at
+# 14B stays (512 lanes of 256 rows are over the VMEM budget). The hybrid's
+# w13 (22016 = 43 x 512) changes below 9 rows only, which its cell warms
+# (`prefill_row` of 1 to 8 tokens) and never serves: 24 decoding rows, prompts
+# of 64 and more
+RAGGED_TILES = {
+    "14b.wcls": ((2048, 32), (512, 160), (512, 160), (512, 160), (128, 160)),
+    "8b.wcls": ((2048, 64), (512, 128), (512, 128), (512, 128), (512, 128)),
+    "olmoh.w13": ((2048, 8),) + PARENT_TILES["olmoh.w13"][1:],
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TILES))
+def test_only_an_out_without_a_wide_divisor_changes_tile(name):
+    """Every matmul of the three benchmark configurations, both arms: the
+    tile is the parent's wherever `out` has a dividing tile of half the
+    asked width or more, and the asked width with a ragged last tile
+    elsewhere; the contraction divides exactly everywhere; and Qwen3's head
+    runs 400 grid steps a call or fewer at the rows its cells serve."""
+    from distributed_llama_tpu.ops.pallas_q40 import _bf16_tiles, _fs_tiles
+
+    in_f, out_f = {**MODEL_MATMULS, **HYBRID_MATMULS}[name]
+    nb = in_f // 32
+    tiles = (_fs_tiles(nb, out_f),) + tuple(_bf16_tiles(b, nb, out_f) for b in BF16_TABLE_ROWS)
+    assert tiles == RAGGED_TILES.get(name, PARENT_TILES[name])
+    for (tn, knb), (ptn, _) in zip(tiles, PARENT_TILES[name]):
+        assert nb % knb == 0 and tn % 128 == 0
+        assert (out_f % tn == 0) == (tn == ptn)  # ragged exactly where it changed
+        assert tn == ptn or 2 * ptn < tn
+    if name in ("14b.wcls", "8b.wcls"):
+        steps = [-(-out_f // tn) * (nb // knb) for tn, knb in tiles[:3]]  # 1-8, 16, 24 rows
+        assert steps == [75 * (nb // tiles[0][1]), 297, 297] and max(steps) <= 400
+        assert -(-out_f // 128) * (nb // tiles[0][1]) == (5935 if name == "14b.wcls" else 2374)
+
+
+@pytest.mark.parametrize("out_f,target,want", [
+    (151936, 2048, 2048), (151936, 512, 512), (151936, 256, 128),  # 128 is half of 256: kept
+    (128256, 2048, 2048), (128256, 512, 384), (7168, 2048, 1792), (5120, 2048, 1280),
+    (22016, 2048, 2048), (22016, 512, 512), (100352, 2048, 2048), (3840, 512, 384),
+    (384, 2048, 384), (128, 256, 128), (96, 256, 96), (200, 256, 200),
+])
+def test_lane_tile_is_the_widest_divisor_from_half_the_target_up(out_f, target, want):
+    from distributed_llama_tpu.ops.pallas_q40 import _lane_tile
+
+    assert _lane_tile(out_f, target) == want

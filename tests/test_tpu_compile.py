@@ -34,10 +34,10 @@ MATMULS_14B = {
 HEADS, KV_HEADS, HEAD_DIM, PAGE = 32, 8, 128, 16
 # Olmo-Hybrid-7B (perfbench/configs/olmo-hybrid-7b.json): the linear layers'
 # fused q|k|v|gate projection and their output projection as the device holds
-# it (`in` 5760 padded to 6144), 24 of 32 layers; 8 full layers of 30 heads
+# it (`in` 5760 padded to 5888: 180 blocks to whole 8s), 24 of 32 layers; 8 full layers of 30 heads
 OLMOH_LIN, OLMOH_FULL = 24, 8
 MATMULS_OLMOH = {
-    "lin_wqkvg": (3840, 17280), "lin_wo": (6144, 3840), "w13": (3840, 2 * 11008),
+    "lin_wqkvg": (3840, 17280), "lin_wo": (5888, 3840), "w13": (3840, 2 * 11008),
     "w2": (11008, 3840), "wcls": (3840, 100352),
 }
 
@@ -209,6 +209,20 @@ CASES = {
         )
         for n in shapes
     },
+    # a ragged last tile of lanes (PR 37), where `out` has no divisor of half
+    # the asked width: Qwen3's head on the int8 arm at 14B (2048 lanes, 75
+    # tiles x 5 k steps; the 8B's cases are above, on both arms), and the
+    # hybrid's w13 below 9 rows (22016 = 10.75 x 2048). The lowering takes the
+    # partial block or refuses it here
+    **{
+        f"14b-i8-{rows}rows-wcls": _matmul(
+            pq.q40_matmul_pallas_i8, rows, "wcls", matmuls=MATMULS_14B
+        )
+        for rows in (1, 8)
+    },
+    "olmoh-i8-8rows-w13": _matmul(
+        pq.q40_matmul_pallas_stacked_i8, 8, "w13", stacked=True, matmuls=MATMULS_OLMOH
+    ),
     "flash-t512-S4096": _flash(512, 4096),
     "flash-t64-S4096": _flash(64, 4096),
     # the page-table kernel over an int8 pool: solo / batch decode and verify blocks
