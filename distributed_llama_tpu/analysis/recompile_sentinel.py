@@ -9,15 +9,42 @@ traced argument — silently re-introduces multi-second compiles inside user
 requests, and the only production symptom is a p99 cliff.
 
 The sentinel makes the contract observable: it subscribes to JAX's
-monitoring events (``/jax/core/compile/backend_compile_duration`` fires once
-per actual backend compile; cache hits are silent), counts compiles during
-the warmup window, and after ``seal()`` turns every further compile into
+monitoring events, counts compiles during the warmup window, and after
+``seal()`` turns every further compile into
 
 * a ``sanitizer_recompiles`` counter bump in the engine's `StepStats`
-  (surfaces in ``/stats`` and ``/health``), and
+  (surfaces in ``/stats`` and ``/health``),
+* an always-landed ``sanitizer.recompile`` trace event that names the program
+  by the span open on the compiling thread (`runtime/tracing.py`
+  `ProgramSpan`, set by `InferenceEngine._guard`: kind, size, kv_len, label;
+  ``unknown`` where the thread has none) and JAX's own ``fun_name``, kept
+  among the last 8 of the engine's start-up record (``/stats`` ``startup``
+  ``recompiled``), and
 * optionally a raised :class:`RecompileError` (``DLT_SANITIZERS_FATAL=1``
   or ``fatal=True``) — the exception propagates out of the jit call that
   triggered the compile, so tests and canaries fail at the exact site.
+
+**What a "compile" is here** (checked in jax 0.9.0, the version this repo
+runs): ``/jax/core/compile/backend_compile_duration`` fires once for every
+compile REQUEST that jit's in-memory caches could not answer, whether the
+backend then compiled or the persistent cache handed the executable back:
+`pxla._cached_compilation` wraps `compiler.compile_or_get_cached` whole, and
+on a persistent-cache hit ``/jax/compilation_cache/cache_retrieval_time_sec``
+fires inside it. Persistent-cache hits are therefore NOT silent (they were in
+the JAX this module was written against): ``sanitizer_warm_compiles`` counts
+the warm window's compile requests, hits and misses alike, and a sealed
+server that loads a program from the persistent cache has breached the
+contract just as one that compiles it (the request still pays trace, lowering
+and retrieval). What the count does not see is a dispatch that reuses an
+executable the process already holds: warm-up after the cost table's build
+of the same program. Which share of the requests the cache answered is the
+start-up record's ``cache_hits`` / ``cache_misses``, not this count.
+
+This module's `_dispatch` is the process's ONE `jax.monitoring` duration
+listener. Before it filters for compiles it hands every event to
+`tracing.program_compile_event`, which credits the four compile-stage events
+(trace, lowering, backend compile, cache retrieval) to the program span open
+on the calling thread: the start-up record's ``startup.warm`` stages.
 
 Scope: compile events are PROCESS-wide (JAX has no per-function hook).
 While any subscribed sentinel is still in its warm window, compiles are
@@ -35,6 +62,7 @@ from __future__ import annotations
 import contextlib
 import threading
 
+from ..runtime.tracing import program_compile_event
 from . import sanitizers_fatal
 
 #: substrings identifying a compile event across jax versions
@@ -51,9 +79,14 @@ class RecompileError(RuntimeError):
 
 
 def _dispatch(event: str, *args, **kwargs):
+    if args:
+        # the start-up record's stages: the span open on THIS thread
+        program_compile_event(event, args[0])
     if not any(m in event for m in _COMPILE_EVENT_MARKERS):
         return
-    # JAX's compile events carry no function identity, so attribution is a
+    fun = str(kwargs.get("fun_name", ""))
+    # the event names the jitted function (`fun_name`), not the engine it
+    # was compiled for, so attribution to a SENTINEL stays a
     # heuristic: while ANY subscriber is still in its warm window, compiles
     # belong to the warming engine(s) — a sealed co-resident engine must
     # neither count them nor (fatal mode) abort another engine's warmup.
@@ -72,17 +105,18 @@ def _dispatch(event: str, *args, **kwargs):
     err = None
     for s in (claimants if claimants else subs):
         try:
-            s._on_compile(event)
+            s._on_compile(event, fun)
         except RecompileError as e:
             err = err if err is not None else e
     if err is not None:
         raise err
 
 
-def _install_once():
+def install_listener():
     """Register the ONE process-wide monitoring listener (jax.monitoring has
     no unregister, so sentinels subscribe/unsubscribe against our own
-    dispatcher instead of the jax registry)."""
+    dispatcher instead of the jax registry). An engine installs it whether
+    or not it runs a sentinel: its start-up record takes the stages from it."""
     global _installed
     with _install_lock:
         if _installed:
@@ -108,8 +142,12 @@ class RecompileSentinel:
     can arrive from any thread that triggers a jit compile.
     """
 
-    def __init__(self, stats=None, fatal: bool | None = None, name: str = "engine"):
+    def __init__(
+        self, stats=None, fatal: bool | None = None, name: str = "engine",
+        record=None,
+    ):
         self.stats = stats  # StepStats: violations become counters
+        self.record = record  # tracing.StartupRecord: names what recompiled
         self.fatal = sanitizers_fatal() if fatal is None else fatal
         self.name = name
         self.sealed = False
@@ -122,7 +160,7 @@ class RecompileSentinel:
     # -- lifecycle ----------------------------------------------------------
 
     def start(self) -> "RecompileSentinel":
-        _install_once()
+        install_listener()
         _subscribers.add(self)
         self._active = True
         return self
@@ -175,7 +213,7 @@ class RecompileSentinel:
 
     # -- event sink ---------------------------------------------------------
 
-    def _on_compile(self, event: str):
+    def _on_compile(self, event: str, fun: str = ""):
         with self._lock:
             if (
                 not self.sealed
@@ -186,6 +224,9 @@ class RecompileSentinel:
             self.post_seal_compiles += 1
         if self.stats is not None:
             self.stats.incr("sanitizer_recompiles")
+        if self.record is not None:
+            # before the flight record is taken: it then holds the name
+            self.record.recompile(fun)
         if self.fatal:
             # post-mortem BEFORE the raise: the trace ring holds the spans
             # of whatever request dispatched the mis-bucketed shape

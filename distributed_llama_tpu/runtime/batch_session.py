@@ -535,7 +535,13 @@ class BatchSession:
         # the sanitizer scope covers the Batcher's production decode path
         # exactly like the solo loops: the ONLY device->host syncs allowed
         # in here are the two _host_fetch calls below (DLT_SANITIZERS=1)
-        with eng._sanitizer_scope():
+        # the guard holds the program call as well as the fetch: a first
+        # dispatch blocks on XLA's compile there (the compile threshold, and
+        # the start-up record's `startup.warm` span with its compile stages),
+        # and a compile after the seal is named by the slot the guard sets
+        with eng._sanitizer_scope(), eng._guard(
+            f"batch_decode[{n_steps}]", ("batch_decode", n_steps, kv_len)
+        ):
             token = jnp.asarray(self.token)
             pos = jnp.asarray(self.pos)
             keys = jnp.asarray(self.keys)
@@ -579,15 +585,12 @@ class BatchSession:
                     page_size=eng.page_size,
                 )
             # the fetch is the batch path's one blocking device call —
-            # watchdog it like the solo decode path, so a wedged device
+            # watchdogged like the solo decode path, so a wedged device
             # raises StallError into the Batcher loop (reset + bounded
             # client retry) instead of hanging every co-batched request
             if phases is not None:
                 phases.enter("step.fetch", n_steps)
-            with eng._guard(
-                f"batch_decode[{n_steps}]", ("batch_decode", n_steps, kv_len)
-            ):
-                host = eng._host_fetch(toks)
+            host = eng._host_fetch(toks)
             # .copy(): the fetched view of a device array is READ-ONLY, and
             # admit writes rows into these between chunks
             self.keys = eng._host_fetch(keys).copy()

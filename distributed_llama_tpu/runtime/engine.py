@@ -39,8 +39,8 @@ from ..formats.mfile import ArchType, MFileReader
 from ..models import KVCache, config_from_header, forward, init_kv_cache, load_params
 from ..ops import build_rope_tables
 from ..tokenizer import Sampler
-from .telemetry import StepStats, memory_report, watchdog
-from .tracing import to_us
+from .telemetry import StepStats, _tree_bytes, memory_report, watchdog
+from .tracing import ProgramSpan, StartupRecord, to_us
 
 
 @dataclass
@@ -179,6 +179,27 @@ def chunk_plan(n_tokens: int, pos_start: int, max_chunk: int, seq_len: int):
         i += n_real
 
 
+class _ProgramGuard(watchdog):
+    """`_guard`'s watchdog, which also holds the thread's program slot
+    (tracing.ProgramSpan) for as long as the guarded call runs."""
+
+    def __init__(self, label, key, first, stats, record):
+        super().__init__(label, compiling=first, stats=stats)
+        self._span = ProgramSpan(label, key)
+        self._record = record  # set on a key's first dispatch while warming
+
+    def __enter__(self):
+        super().__enter__()
+        self._span.open()
+        return self
+
+    def __exit__(self, exc_type, *exc):
+        self._span.close()
+        if self._record is not None and exc_type is None:
+            self._record.program("startup.warm", self._span)
+        return super().__exit__(exc_type, *exc)
+
+
 class InferenceEngine:
     """Owns params + cache + compiled steps for one model."""
 
@@ -239,6 +260,11 @@ class InferenceEngine:
         # DLT_KV_POOL_MB env; 0/unset = contiguous parity (batch x seq_len
         # worth of pages), so default paged never fits fewer tokens
     ):
+        # the start-up record (runtime/tracing.py `STARTUP_SPANS`): this
+        # engine's phases and one span a program built or first dispatched
+        # while warming, a dispatch count a program, what recompiled
+        self.startup = StartupRecord()
+        t_load = time.perf_counter()
         self.reader = MFileReader(model_path, max_seq_len=max_seq_len)
         self.header = self.reader.header
         # capabilities this engine was asked for and serves without (each
@@ -413,6 +439,11 @@ class InferenceEngine:
                 kv_dtype=self.cfg.cache_dtype,
             )
         self.cache = self._new_cache()
+        self.startup.span(
+            "startup.load", t_load, time.perf_counter(),
+            os.path.getsize(model_path),
+            _tree_bytes(self.params) + _tree_bytes(self.cache),
+        )
         if verbose:
             print(memory_report(self.params, self.cache))
         self._argmax_step = jax.jit(
@@ -516,12 +547,18 @@ class InferenceEngine:
         # outside the sanctioned _fetch_pool/_host_fetch sites raise.
         from ..analysis import sanitizers_enabled
 
+        from ..analysis.recompile_sentinel import RecompileSentinel, install_listener
+
         self._sanitize = sanitizers_enabled()
         self.sentinel = None
         if self._sanitize:
-            from ..analysis.recompile_sentinel import RecompileSentinel
-
-            self.sentinel = RecompileSentinel(stats=self.stats).start()
+            self.sentinel = RecompileSentinel(
+                stats=self.stats, record=self.startup
+            ).start()
+        # the process's one listener of JAX's compile events, sentinel or
+        # not: the record's `startup.warm` spans take their stages from it
+        install_listener()
+        self.startup.plan_len(len(self.warm_plan()))
 
     @property
     def warms_solo_programs(self) -> bool:
@@ -908,7 +945,14 @@ class InferenceEngine:
         once, up front, instead of inside the first user's request). The
         prefix cache is suppressed for the duration and cleared at the end:
         warmup's synthetic prompts must not publish junk entries."""
+        rec = self.startup
+        n_plan = len(self.warm_plan())
+        rec.plan_len(n_plan)
         self._in_warmup = True
+        phase = contextlib.ExitStack()
+        phase.enter_context(
+            rec.phase("startup.warmup", lambda: (n_plan, len(rec.first_in_warmup)))
+        )
         try:
             n = max(1, min(self.max_chunk, self.cfg.seq_len - self.decode_chunk_size - 2))
             prompt = [1] * n
@@ -965,6 +1009,9 @@ class InferenceEngine:
                 self.cost_table()
         finally:
             self._in_warmup = False
+            phase.close()
+        # the seal's moment: "warmed" and "dispatched since" part here
+        rec.seal(self.warm_plan())
         if self.sentinel is not None:
             # the ladder is compiled: from here on, any XLA compile is a
             # ladder hole — counted (sanitizer_recompiles) and optionally
@@ -1247,10 +1294,21 @@ class InferenceEngine:
 
     def _guard(self, label: str, key) -> watchdog:
         """Watchdog for a blocking device call; `key` identifies the
-        compiled shape so first-time calls get the compile threshold."""
+        compiled shape so first-time calls get the compile threshold.
+
+        Every program dispatch, warm or served, passes here, so this is also
+        where the start-up record counts a program's dispatches and where the
+        thread's program slot is set: JAX's compile events inside the guard
+        are credited to this program, and a compile after the seal is named
+        by it. The first dispatch of a key while warming closes a
+        `startup.warm` span."""
         first = key not in self._warm
         self._warm.add(key)
-        return watchdog(label, compiling=first, stats=self.stats)
+        warming = first and self._in_warmup
+        self.startup.count(key, warming)
+        return _ProgramGuard(
+            label, key, first, self.stats, self.startup if warming else None
+        )
 
     def _pipelined_chunks(self, n_chunks: int, prep, dispatch):
         """The ONE owner of the double-buffered prep/dispatch loop shared by
@@ -1303,7 +1361,7 @@ class InferenceEngine:
         StepStats
         (`prefill_dispatch[size]`), the sync wait in `prefill_sync`, and
         `last_prefill_timing` carries the dispatch-vs-compute overlap summary
-        the bench and `/stats` export. `DLT_PREFILL_PIPELINE=0` (or
+        whose gauges `/stats` exports. `DLT_PREFILL_PIPELINE=0` (or
         engine `prefill_pipelined=False`) forces the strict serial
         dispatch->block->dispatch path — the bit-parity reference for the
         overlap smoke test.

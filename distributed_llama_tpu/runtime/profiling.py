@@ -29,8 +29,8 @@ Prometheus); this module makes the *device* observable. Four pieces:
   ``batch_decode[n]``, ``spec_verify[k]``) yields achieved GB/s and
   FLOP/s per program and the aggregate ``dlt_mfu`` /
   ``dlt_bw_utilization`` / ``dlt_device_duty_cycle`` gauges on
-  ``/metrics`` — the bench's roofline arithmetic as a first-class live
-  metric. SLO attainment (``dlt_slo_ttft_attainment`` /
+  ``/metrics`` — roofline arithmetic as a first-class live metric. SLO
+  attainment (``dlt_slo_ttft_attainment`` /
   ``dlt_slo_tpot_attainment``) is derived from the PR 6 cumulative
   TTFT/TPOT histograms against ``DLT_SLO_TTFT_MS`` / ``DLT_SLO_TPOT_MS``.
 * **On-demand capture** — ``GET /debug/profile?ms=...`` wraps
@@ -40,11 +40,10 @@ Prometheus); this module makes the *device* observable. Four pieces:
 
 Measurement honesty notes:
 
-* The joined walls are HOST chunk-boundary walls — the same numbers the
-  bench's roofline headline uses. In steady state a decode chunk's wall is
+* The joined walls are HOST chunk-boundary walls. In steady state a decode chunk's wall is
   its device compute (the lookahead hides dispatch/fetch); when dispatch
   and fetch dominate (tiny models), achieved GB/s is honestly *lower*
-  than the kernel rate, exactly as the bench reports it. Prefill
+  than the kernel rate. Prefill
   *dispatch* walls are asynchronous (the device runs behind them) and are
   deliberately NOT joined.
 * Per-series joins use the **p50 of the recent window**, so warmup's
@@ -680,6 +679,11 @@ def jaxpr_census(closed_jaxpr) -> dict:
     return acc
 
 
+def build_threads() -> int:
+    """Worker threads of a cost table's build."""
+    return min(16, os.cpu_count() or 1)
+
+
 def build_cost_table(engine, plan=None) -> CostTable:
     """Lower + compile every program in `plan` (default: the engine's full
     ``warm_plan()``) and collect XLA's cost/memory analyses. Compilation is
@@ -701,19 +705,42 @@ def build_cost_table(engine, plan=None) -> CostTable:
     from concurrent.futures import ThreadPoolExecutor
 
     from ..analysis.graph_audit import LadderEntry, trace_entry
+    from .tracing import ProgramSpan
 
     partial = plan is not None
     plan = engine.warm_plan() if plan is None else list(plan)
     keys = list(dict.fromkeys(tuple(k) for k in plan))
     sentinel = getattr(engine, "sentinel", None)
+    # the start-up record takes a `startup.build` span a program, while
+    # `serve()` holds the `startup.cost_table` phase open and at no other
+    # time (a lazy build on a sealed server is not start-up)
+    record = getattr(engine, "startup", None)
+    if record is not None and record.open_phase != "startup.cost_table":
+        record = None
 
     def build(key):
         kind, size, kvb = key
-        with sentinel.exempt() if sentinel is not None else contextlib.nullcontext():
-            census = jaxpr_census(
-                trace_entry(engine, LadderEntry(kind, size, kvb))
+        # this worker's program slot: a persistent-cache hit shows as JAX's
+        # retrieval event on this thread. The three stages are timed here, at
+        # the calls themselves (the start-up record's `startup.build`)
+        span = ProgramSpan(f"build {kind}[{size}|kv{kvb}]", key).open()
+        try:
+            with sentinel.exempt() if sentinel is not None else contextlib.nullcontext():
+                census = jaxpr_census(
+                    trace_entry(engine, LadderEntry(kind, size, kvb))
+                )
+                t_census = time.perf_counter()
+                lowered = lower_entry(engine, key)
+                t_lower = time.perf_counter()
+                compiled = lowered.compile()
+        finally:
+            span.close()
+        if record is not None:
+            record.program(
+                "startup.build", span,
+                int((t_census - span.t0) * 1e6), int((t_lower - t_census) * 1e6),
+                int((span.t1 - t_lower) * 1e6),
             )
-            compiled = lower_entry(engine, key).compile()
         xla_flops, xla_bytes, mem = _cost_from_compiled(compiled)
         return CostEntry(
             kind=kind, size=size, kv_len=kvb,
@@ -728,7 +755,7 @@ def build_cost_table(engine, plan=None) -> CostTable:
 
     entries: dict = {}
     failures: dict = {}
-    with ThreadPoolExecutor(min(16, os.cpu_count() or 1)) as pool:
+    with ThreadPoolExecutor(build_threads()) as pool:
         futures = {key: pool.submit(build, key) for key in keys}
         for key, fut in futures.items():
             try:
@@ -1066,50 +1093,6 @@ def metrics_view(engine):
             series[gauge] = rows
     gauges.update(slo_flat)
     return gauges, series
-
-
-# -- bench integration -------------------------------------------------------
-
-
-def bench_profile(engine, final_pos: int | None = None) -> dict:
-    """The bench's per-leg device profile: build a PARTIAL cost table over
-    exactly the decode/verify programs the leg's series recorded (a handful
-    of compiles, not the whole ladder — the full table is a serving-time
-    concern) and return the ledger + roofline numbers for the BENCH json."""
-    kvb = engine._kv_bucket(
-        final_pos if final_pos is not None else engine.cfg.seq_len
-    )
-    plan = []
-    for name in list(engine.stats.series):
-        m = _SERIES_RE.match(name)
-        if not m or m.group(1) not in _SERIES_KINDS:
-            continue
-        for kind, off in _SERIES_KINDS[m.group(1)]:
-            size = int(m.group(2)) + off
-            if kind in ("verify", "verify_row") and (
-                engine.spec_mode is None or engine.batch <= 1
-                and kind == "verify_row"
-            ):
-                continue
-            plan.append((kind, size, max(kvb, size)))
-    table = build_cost_table(engine, plan=plan)
-    if engine._cost_table is None:
-        engine._cost_table = table
-    gauges, _ = roofline_view(engine, table)
-    ledger = hbm_ledger(engine)
-    out = {
-        "dlt_mfu": gauges.get("mfu"),
-        "dlt_bw_utilization": gauges.get("bw_utilization"),
-        "hbm_modeled_gb": round(ledger["modeled_bytes"] / 1e9, 3),
-        "hbm_components_gb": {
-            k: round(v / 1e9, 3) for k, v in ledger["components"].items()
-        },
-    }
-    dchunk = table.lookup("decode", engine.decode_chunk_size)
-    if dchunk is not None:
-        out["decode_bytes_per_token_modeled"] = round(dchunk.bytes_per_token, 1)
-        out["decode_flops_per_token_modeled"] = round(dchunk.flops_per_token, 1)
-    return out
 
 
 # -- on-demand profiler capture ----------------------------------------------

@@ -173,8 +173,7 @@ def accept_greedy(drafts, greedy_ids) -> int:
 def note_round(stats, n_drafted: int, n_accepted: int) -> None:
     """Record one verify round's acceptance telemetry: the four spec_*
     counters plus the cumulative ``spec_acceptance_rate`` gauge (accepted /
-    drafted over the engine's lifetime — the number the bench and `/stats`
-    report)."""
+    drafted over the engine's lifetime — the number `/stats` reports)."""
     stats.incr("spec_rounds")
     stats.incr("spec_draft_tokens", n_drafted)
     stats.incr("spec_accepted_tokens", n_accepted)

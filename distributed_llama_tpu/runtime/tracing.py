@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
 import json
 import os
 import re
@@ -419,6 +420,445 @@ def batch_timeline_payload(events: list) -> dict:
         "sheds": sum(1 for e in events if e[1] == "batch_shed"),
         "events": [render_event(e) for e in events],
         "chrome_trace": batch_timeline_chrome(events),
+    }
+
+
+# -- start-up record ---------------------------------------------------------
+
+#: the spans that cut a server's start-up, from the entry of `serve()` to its
+#: return, with each span's argument keys (after `parent`, which every one
+#: carries first). The three phases lie inside `startup.serve` one after the
+#: other; `startup.build` spans run on the cost table's worker threads, so
+#: their sum is thread-seconds, not wall; `startup.warm` spans run one at a
+#: time on the thread that warms. Emitted into the ring like the Batcher's
+#: phases AND kept by the engine (`StartupRecord`): the ring forgets the
+#: start-up under traffic, an operator asks hours later.
+STARTUP_SPANS = {
+    # server/api.py `serve`: entry to return
+    "startup.serve": (),
+    # InferenceEngine.__init__: the model file opened, the weights on the
+    # device, pool / recurrent state allocated
+    "startup.load": ("file_bytes", "device_bytes"),
+    # the cost table's build in `serve` (profiling.build_cost_table)
+    "startup.cost_table": ("programs", "failures", "threads"),
+    # one program of the table, on its worker's thread: the census's trace,
+    # the lowering and `.compile()`, each by the host's clock
+    "startup.build": (
+        "kind", "size", "kv_len", "census_us", "lower_us", "compile_us",
+        "cache_hit",
+    ),
+    # InferenceEngine.warmup: the canonical pass and the ladder's fill
+    "startup.warmup": ("programs", "first_dispatches"),
+    # the FIRST dispatch of a key while warming, the host's wall inside
+    # `_guard`; the three stages are JAX's own compile events on that thread
+    # (de-nested), and what the wall holds beyond them is dispatch and
+    # whatever the call blocks on. A solo prefill guards a whole chunk
+    # ladder: `size` / `kv_len` are its last pair, `chunks` the pairs
+    "startup.warm": (
+        "kind", "size", "kv_len", "chunks", "trace_us", "lower_us",
+        "compile_us", "cache_hit",
+    ),
+}
+STARTUP_PARENTS = {
+    "startup.serve": "",
+    "startup.load": "startup.serve",
+    "startup.cost_table": "startup.serve",
+    "startup.build": "startup.cost_table",
+    "startup.warmup": "startup.serve",
+    "startup.warm": "startup.warmup",
+}
+_PROGRAM_SPANS = ("startup.build", "startup.warm")
+
+#: JAX's duration events that a compile fires on the thread that compiles
+#: (jax 0.9.0: dispatch.py, compiler.py), by the stage each one is. The
+#: retrieval event fires INSIDE the backend-compile one on a persistent-cache
+#: hit: `compile_or_get_cached` is wrapped whole.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": 0,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": 1,
+    "/jax/core/compile/backend_compile_duration": 2,
+    "/jax/compilation_cache/cache_retrieval_time_sec": 3,
+}
+
+_program_slot = threading.local()
+
+
+class ProgramSpan:
+    """The program (or start-up phase) a thread is compiling or dispatching
+    right now: what JAX's compile events on that thread are credited to, and
+    what a compile after the seal is named by. `open()` puts it in the
+    thread's slot and remembers what was there; `close()` puts that back, so
+    a guard inside a guard takes the events while it is open and the outer
+    one keeps the rest."""
+
+    __slots__ = (
+        "label", "key", "t0", "t1", "stage_s", "hits", "compiles", "_outer",
+        "_traces",
+    )
+
+    def __init__(self, label: str, key=None):
+        self.label = label
+        self.key = key
+        self.t0 = self.t1 = 0.0
+        self.stage_s = [0.0, 0.0, 0.0]  # trace, lowering, backend compile
+        self.hits = 0  # persistent-cache retrievals
+        self.compiles = 0  # backend-compile events (a retrieval is inside one)
+        self._outer = None
+        self._traces = None  # open trace intervals, to de-nest them
+
+    def open(self) -> "ProgramSpan":
+        self._outer = getattr(_program_slot, "span", None)
+        _program_slot.span = self
+        self.t0 = time.perf_counter()
+        return self
+
+    def close(self) -> None:
+        self.t1 = time.perf_counter()
+        _program_slot.span = self._outer
+
+    def triple(self) -> tuple:
+        """(kind, size, kv_len, chunks) as `warm_plan()` keys its programs.
+        A solo prefill's key holds its whole chunk ladder: the last pair.
+        A phase's slot has no key: its label stands for the kind."""
+        if not self.key:
+            return self.label, 0, 0, 0
+        triples = key_triples(self.key)
+        return (*triples[-1], len(triples))
+
+    def add(self, stage: int, secs: float) -> None:
+        if stage == 3:
+            self.hits += 1
+            return
+        if stage == 2:
+            self.compiles += 1
+        elif stage == 0:
+            # a jitted helper traced inside a program fires its own event
+            # before the program's, which holds it: keep the outermost. An
+            # event ends now, so it started `secs` ago; whatever started
+            # after that (100 us of slack: the listener's own delay) is inside
+            start = time.perf_counter() - secs
+            open_ = self._traces
+            if open_ is None:
+                open_ = self._traces = []
+            while open_ and open_[-1][0] >= start - 1e-4:
+                self.stage_s[0] -= open_.pop()[1]
+            open_.append((start, secs))
+        self.stage_s[stage] += secs
+
+
+def current_program() -> ProgramSpan | None:
+    """The span open on the calling thread, or None."""
+    return getattr(_program_slot, "span", None)
+
+
+def program_compile_event(event: str, secs: float) -> None:
+    """One of JAX's duration events, from the process's one listener
+    (analysis/recompile_sentinel.py): credited to the span open on the
+    thread that fired it, if one is and the event is a compile stage."""
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    span = getattr(_program_slot, "span", None)
+    if span is not None:
+        span.add(stage, secs)
+
+
+def key_triples(key) -> list:
+    """The `warm_plan()` triples a guarded key stands for: itself, or one a
+    pair for a solo prefill's ladder."""
+    if len(key) == 2 and isinstance(key[1], tuple):
+        return [(key[0], size, kvb) for size, kvb in key[1]]
+    return [key]
+
+
+class StartupRecord:
+    """One engine's start-up: the spans of `STARTUP_SPANS` as `(name, t_us,
+    dur_us, parent, vals)`, a dispatch count a program, the programs that
+    compiled after the seal. Every span also lands in the trace ring. The
+    list is bounded (`plan_len`: twice the plan and the phases), the counts
+    by the programs an engine can dispatch. Written by the thread that
+    starts the engine and the cost table's workers (appends: atomic under
+    the GIL); after the seal the Batcher's thread alone writes `dispatches`."""
+
+    def __init__(self, tracer=TRACER):
+        self.spans: list = []
+        self.limit = len(STARTUP_SPANS)
+        self.dropped = 0  # spans past the bound (none expected)
+        self.record_us = 0.0  # what the record's own bookkeeping took
+        self.dispatches: dict = {}  # plan triple -> guarded calls
+        self.first_in_warmup: set = set()  # triples first dispatched warming
+        self.outside = {}  # phase -> its own ProgramSpan (compiles outside a program span)
+        self.open_phase: str | None = None  # the innermost phase open now
+        self.sealed_at: dict | None = None  # `dispatches` at the seal
+        self.summary: dict | None = None  # `/stats` `startup`, built at the seal
+        self.recompiled: collections.deque = collections.deque(maxlen=8)
+        self._em = {
+            name: tracer.bind_global(name, ("parent",) + keys)
+            for name, keys in STARTUP_SPANS.items()
+        }
+
+    def plan_len(self, n_programs: int) -> None:
+        self.limit = 2 * n_programs + len(STARTUP_SPANS)
+
+    def span(self, name: str, t0: float, t1: float, *vals) -> None:
+        """Close a span that ran from `t0` to `t1` (`perf_counter`)."""
+        t_us, dur_us = to_us(t0), int((t1 - t0) * 1e6)
+        parent = STARTUP_PARENTS[name]
+        if len(self.spans) < self.limit:
+            self.spans.append((name, t_us, dur_us, parent, vals))
+        else:
+            self.dropped += 1
+        self._em[name](t_us, dur_us, parent, *vals)
+        if self.summary is not None and name not in _PROGRAM_SPANS:
+            # a phase that closes after the seal (`startup.serve` does)
+            self.summary["phases"] = _phase_rows(self.spans)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, vals=None, since: float | None = None):
+        """Around one phase: its span, and a program slot of its own on this
+        thread, so that what compiles inside the phase and outside every
+        program span is still counted (`outside`). `vals` is called at the
+        end for the span's arguments; `since` is an earlier start
+        (`perf_counter`) than the entry."""
+        slot = self.outside[name] = ProgramSpan(name).open()
+        if since is not None:
+            slot.t0 = since
+        outer, self.open_phase = self.open_phase, name
+        try:
+            yield slot
+        finally:
+            slot.close()
+            self.open_phase = outer
+            self.span(name, slot.t0, slot.t1, *(vals() if vals is not None else ()))
+
+    def phase_seconds(self, name: str) -> float:
+        """The wall of the phase's spans so far."""
+        return sum(s[2] for s in self.spans if s[0] == name) / 1e6
+
+    def program(self, name: str, span: ProgramSpan, *stage_us) -> None:
+        """Close a program span (`startup.build`, `startup.warm`). A build
+        gives its three stages by its own clock; a warm span takes JAX's."""
+        t = time.perf_counter()
+        kind, size, kv_len, chunks = span.triple()
+        hit = 1 if span.hits else 0
+        if name == "startup.warm":
+            stages = tuple(int(max(s, 0.0) * 1e6) for s in span.stage_s)
+            vals = (kind, size, kv_len, chunks, *stages, hit)
+        else:
+            vals = (kind, size, kv_len, *stage_us, hit)
+        self.span(name, span.t0, span.t1, *vals)
+        self.record_us += (time.perf_counter() - t) * 1e6
+
+    # -- counts ---------------------------------------------------------------
+
+    def count(self, key, first_warming: bool) -> None:
+        """One guarded call of `key` (the hot path: a dict increment)."""
+        d = self.dispatches
+        for triple in key_triples(key):
+            d[triple] = d.get(triple, 0) + 1
+            if first_warming:
+                self.first_in_warmup.add(triple)
+
+    def recompile(self, fun: str = "") -> dict:
+        """A compile after the seal: name it by the calling thread's open
+        span, keep it among the last 8 and land it in the ring."""
+        span = current_program()
+        if span is None:
+            row = {"kind": "unknown", "size": 0, "kv_len": 0, "label": "unknown"}
+        else:
+            kind, size, kv_len, _ = span.triple()
+            row = {"kind": kind, "size": size, "kv_len": kv_len, "label": span.label}
+        row["fun"] = fun
+        row["t_us"] = now_us()
+        self.recompiled.append(row)
+        keys = ("kind", "size", "kv_len", "label", "fun")
+        global_event(
+            "sanitizer.recompile", t_us=row["t_us"], keys=keys,
+            vals=tuple(row[k] for k in keys),
+        )
+        return row
+
+    # -- the seal's summary ---------------------------------------------------
+
+    def seal(self, plan: list) -> None:
+        """The moment warm-up ends: snapshot the counts and build `/stats`
+        `startup` once (it is polled inside a benchmark's window)."""
+        self.sealed_at = dict(self.dispatches)
+        self.summary = startup_summary(self, plan)
+
+    def stats(self) -> dict | None:
+        """`/stats` `startup`: the seal's summary with the since-seal counts
+        filled in. None before the first seal."""
+        if self.summary is None:
+            return None
+        out = dict(self.summary)
+        sealed = self.sealed_at
+        by_kind = {k: dict(v) for k, v in out["by_kind"].items()}
+        for key, n in list(self.dispatches.items()):
+            since = n - sealed.get(key, 0)
+            if since > 0:
+                row = _kind_row(by_kind, key[0])
+                row["dispatched"] += 1
+                row["dispatches"] += since
+        out["by_kind"] = by_kind
+        out["recompiled"] = list(self.recompiled)
+        return out
+
+
+def _kind_row(by_kind: dict, kind: str) -> dict:
+    return by_kind.setdefault(
+        kind, {"planned": 0, "warmed": 0, "dispatched": 0, "dispatches": 0}
+    )
+
+
+def _span_args(span: tuple) -> dict:
+    name, _t, _d, _parent, vals = span
+    return dict(zip(STARTUP_SPANS[name], vals))
+
+
+def _phase_rows(spans: list) -> dict:
+    """Each phase's seconds and self seconds: its span less its children;
+    the cost table's as wall against its children's thread-seconds."""
+    wall, child = {}, {}
+    args = {}
+    for span in spans:
+        name, _t, dur_us, parent, _vals = span
+        if name in _PROGRAM_SPANS:
+            child[parent] = child.get(parent, 0) + dur_us
+        else:
+            wall[name] = wall.get(name, 0) + dur_us
+            args[name] = _span_args(span)
+            if parent:
+                child[parent] = child.get(parent, 0) + dur_us
+    out = {}
+    for name, us in wall.items():
+        row = {"s": round(us / 1e6, 3)}
+        if name == "startup.cost_table":
+            row["thread_s"] = round(child.get(name, 0) / 1e6, 3)
+        else:
+            row["self_s"] = round((us - child.get(name, 0)) / 1e6, 3)
+        row.update(args[name])
+        out[name[len("startup."):]] = row
+    return out
+
+
+def _stage_table(spans: list, name: str, stages: tuple) -> dict:
+    rows = [(s[2], _span_args(s)) for s in spans if s[0] == name]
+    out = {"spans": len(rows), "wall_s": round(sum(d for d, _ in rows) / 1e6, 3)}
+    for key in stages:
+        out[key[:-3] + "_s"] = round(sum(a[key] for _, a in rows) / 1e6, 3)
+    out["cache_hits"] = sum(a["cache_hit"] for _, a in rows)
+    out["cache_misses"] = sum(
+        1 for _, a in rows if a["compile_us"] > 0 and not a["cache_hit"]
+    )
+    return out
+
+
+def startup_summary(record: StartupRecord, plan: list) -> dict:
+    """`/stats` `startup`: aggregates only. The phases, the stage sums of
+    `startup.build` and `startup.warm` (a warm span's `rest_s` is its wall
+    less JAX's three stages: dispatch, and what the call waited for of the
+    device), what compiled inside a phase and outside every program span,
+    cache hits and misses by program span, the five longest program spans,
+    and by `kind` the programs planned, first dispatched in warm-up,
+    dispatched since the seal, and those dispatches."""
+    spans = list(record.spans)
+    build = _stage_table(spans, "startup.build", ("census_us", "lower_us", "compile_us"))
+    warm = _stage_table(spans, "startup.warm", ("trace_us", "lower_us", "compile_us"))
+    warm["rest_s"] = round(
+        warm["wall_s"] - warm["trace_s"] - warm["lower_s"] - warm["compile_s"], 3
+    )
+    outside = {}
+    for name, slot in record.outside.items():
+        if slot.compiles or any(slot.stage_s):
+            outside[name[len("startup."):]] = {
+                "trace_s": round(slot.stage_s[0], 3),
+                "lower_s": round(slot.stage_s[1], 3),
+                "compile_s": round(slot.stage_s[2], 3),
+                "compiles": slot.compiles, "cache_hits": slot.hits,
+            }
+    planned = list(dict.fromkeys(tuple(k) for k in plan))
+    by_kind: dict = {}
+    for kind, _size, _kvb in planned:
+        _kind_row(by_kind, kind)["planned"] += 1
+    for key in record.first_in_warmup:
+        _kind_row(by_kind, key[0])["warmed"] += 1
+    never = [list(k) for k in planned if k not in record.first_in_warmup]
+    longest = sorted(
+        (s for s in spans if s[0] in _PROGRAM_SPANS), key=lambda s: -s[2]
+    )[:5]
+    return {
+        "phases": _phase_rows(spans),
+        "build": build,
+        "warm": warm,
+        "outside": outside,
+        "by_kind": by_kind,
+        "programs_planned": len(planned),
+        "programs_warmed": len(record.first_in_warmup),
+        "never_warmed": never[:8],
+        "never_warmed_n": len(never),
+        "longest": [
+            dict(_span_args(s), name=s[0], s=round(s[2] / 1e6, 3)) for s in longest
+        ],
+        "recompiled": [],
+        "record_us": round(record.record_us, 1),
+        "dropped": record.dropped,
+    }
+
+
+def startup_events(spans: list) -> list:
+    """The record's spans as ring events, for `render_event` and the other
+    helpers the batch timeline uses."""
+    return [
+        ("", name, t_us, dur_us, ("parent",) + STARTUP_SPANS[name], (parent,) + tuple(vals))
+        for name, t_us, dur_us, parent, vals in spans
+    ]
+
+
+def startup_chrome(events: list) -> list:
+    """Chrome ``trace_event`` view of a start-up: ``X`` slices, the phases
+    and the warm spans on track 0 (they nest by containment), the cost
+    table's builds on tracks of their own, one a worker: a build takes the
+    lowest track that is free when it starts."""
+    out: list = []
+    pid = os.getpid()
+    lanes: list = []  # end of the last build on each worker track
+    for ev in sorted(events, key=lambda e: (e[2], -e[3])):
+        _, name, t_us, dur_us, keys, vals = ev
+        tid = 0
+        if name == "startup.build":
+            lane = next((i for i, end in enumerate(lanes) if end <= t_us), len(lanes))
+            if lane == len(lanes):
+                lanes.append(0)
+            lanes[lane] = t_us + dur_us
+            tid = lane + 1
+        out.append(
+            {
+                "name": name, "cat": "dlt_startup", "ph": "X",
+                "ts": int(t_us), "dur": max(int(dur_us), 1),
+                "pid": pid, "tid": tid, "args": dict(zip(keys, vals)),
+            }
+        )
+    return out
+
+
+def startup_payload(record: StartupRecord) -> dict:
+    """The ``/debug/startup`` response body: the record's rows, `/stats`
+    `startup`, the counts a program, and the chrome://tracing export."""
+    events = startup_events(list(record.spans))
+    sealed = record.sealed_at or {}
+    return {
+        "n_events": len(events),
+        "events": [render_event(e) for e in events],
+        "summary": record.stats(),
+        "programs": [
+            {
+                "kind": key[0], "size": key[1], "kv_len": key[2],
+                "warmed": sealed.get(key, 0), "since_seal": n - sealed.get(key, 0),
+            }
+            for key, n in sorted(record.dispatches.items(), key=repr)
+        ],
+        "chrome_trace": startup_chrome(events),
     }
 
 

@@ -54,6 +54,7 @@ from ..runtime.tracing import (
     now_us,
     parse_sampled,
     render_step_stats,
+    startup_payload,
     to_us,
     trace_payload,
 )
@@ -2414,6 +2415,15 @@ class Handler(BaseHTTPRequestHandler):
             events = TRACER.for_names(BATCH_TIMELINE_NAMES)
             self._json(200, json.dumps(batch_timeline_payload(events)).encode())
             return
+        if route == "/debug/startup":
+            # the engine's start-up record (runtime/tracing.py
+            # `STARTUP_SPANS`): every phase, one span a program built and one
+            # a program first dispatched while warming, the counts a program,
+            # and a chrome://tracing export. Kept by the engine: the ring
+            # forgets the start-up under traffic
+            body = startup_payload(self.state.engine.startup)
+            self._json(200, json.dumps(body).encode())
+            return
         if route == "/debug/hot_prefixes":
             # warm drain handoff (server/scheduler.py HotPrefixTracker +
             # server/autoscaler.py): this replica's hottest router chain
@@ -2529,6 +2539,10 @@ class Handler(BaseHTTPRequestHandler):
                 # a slot is live while a request holds its row: `batcher`'s
                 # `slots_active`
                 "rec_state": st.engine.rec_state_snapshot(),
+                # where start-up went (runtime/tracing.py `StartupRecord`):
+                # aggregates built once at the seal, the since-seal dispatch
+                # counts by kind filled in here; the rows: /debug/startup
+                "startup": st.engine.startup.stats(),
                 # per-request goodput rollup: outcomes, delivered vs wasted
                 # tokens (by reason), recent-window delivered-token rate —
                 # incl. the by_class breakdown (server/scheduler.py)
@@ -3010,21 +3024,31 @@ def serve(args) -> HTTPServer:
     batch == 1: single-threaded server, serialized requests + prefix cache
     (the reference's model). batch > 1: threaded server so concurrent
     handlers can reach the Batcher together."""
-    from http.server import ThreadingHTTPServer
-
     from ..cli import make_engine
 
     # since the KV movement layer (runtime/kv_transport.py), BOTH serving
     # roles speak both KV layouts: paged workers extract/insert through the
     # warmed page_extract/page_insert programs, so the old roles-force-
     # contiguous override is gone and the paged default applies everywhere
+    t_serve = time.perf_counter()
     engine = make_engine(args)
+    # the start-up record (runtime/tracing.py `STARTUP_SPANS`) is the
+    # engine's; `startup.serve` began before there was one
+    with engine.startup.phase("startup.serve", since=t_serve):
+        return _serve_engine(engine, args)
+
+
+def _serve_engine(engine, args) -> HTTPServer:
+    """`serve` past the engine's construction: cost table, warm-up, state
+    and the unstarted server, inside the `startup.serve` span."""
+    from http.server import ThreadingHTTPServer
+
     refuse_state_handoff(engine, args)  # before the warm-up is paid for
     tokenizer = Tokenizer(args.tokenizer)
     import os as _os
 
     if not _os.environ.get("DLT_NO_WARMUP"):
-        t0 = time.perf_counter()
+        record = engine.startup
         if _os.environ.get("DLT_COST_TABLE") != "0":
             # serving processes carry the warm-ladder cost table from the
             # start (/debug/costs, /metrics roofline gauges). It is built
@@ -3033,14 +3057,26 @@ def serve(args) -> HTTPServer:
             # programs the warm-up below dispatches, which would otherwise
             # compile one at a time. DLT_COST_TABLE=0 opts out; the table
             # then builds lazily on the first /debug/costs hit.
-            engine.cost_table()
-        t1 = time.perf_counter()
+            from ..runtime.profiling import build_threads
+
+            def table_vals():
+                table = engine.cost_table(build=False)
+                failures = len(table.failures) if table is not None else 0
+                entries = len(table.entries) if table is not None else 0
+                return entries + failures, failures, build_threads()
+
+            with record.phase("startup.cost_table", table_vals):
+                engine.cost_table()
         # run the chunk ladder before accepting connections so the first
         # request pays serving latency, not XLA compile (cold-TTFT)
         engine.warmup()
-        # cold start, as set-up metrics (/stats gauges)
-        engine.stats.gauge("startup_cost_table_s", round(t1 - t0, 1))
-        engine.stats.gauge("startup_warmup_s", round(time.perf_counter() - t1, 1))
+        # cold start, as set-up metrics (/stats gauges): the two phases'
+        # spans, one measurement
+        for gauge, name in (
+            ("startup_cost_table_s", "startup.cost_table"),
+            ("startup_warmup_s", "startup.warmup"),
+        ):
+            engine.stats.gauge(gauge, round(record.phase_seconds(name), 1))
     state = ApiState(engine, tokenizer, args)
     # same-process device-path registry (runtime/kv_transport.py): a decode
     # worker whose --prefill-peer names this port reaches the prefill

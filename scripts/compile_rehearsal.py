@@ -14,8 +14,9 @@ the TPU's compiler raises here what it would raise on the chip. The engine
 asks `jax.default_backend()` whether to use its kernels and sees the CPU, so
 the script turns them on itself (`use_pallas=True`); nothing else is steered.
 
-What it prints per program: compile seconds, Mosaic kernels
-(`tpu_custom_call`), collectives by name, and `memory_analysis()` bytes on one
+What it prints per program: the lowering's and the compile's microseconds
+apart (`lower_us`, `compile_us`: the names of the start-up record's
+`startup.build` span), Mosaic kernels (`tpu_custom_call`), collectives by name, and `memory_analysis()` bytes on one
 device. A compile that passes is a compile, never a run: nothing executes.
 A depth-cut model file is enough — the layer scan compiles one layer body.
 """
@@ -104,13 +105,19 @@ def main() -> int:
     )
     failed = 0
     for key in chosen:
+        # the two stages apart, under the names the start-up record's
+        # `startup.build` span gives them: what a kernel's body adds to a
+        # program's LOWERING shows here, before any chip time is spent
         t0 = time.time()
         try:
-            compiled = profiling.lower_entry(engine, key).compile()
+            lowered = profiling.lower_entry(engine, key)
+            t1 = time.time()
+            compiled = lowered.compile()
         except Exception as e:  # the finding this script exists to make
             failed += 1
             print(f"FAIL {key}: {type(e).__name__}: {str(e)[:1500]}")
             continue
+        t_done = time.time()
         text = compiled.as_text()
         coll = {
             name: len(re.findall(rf"= \S+ {name}(?:-start)?\(", text))
@@ -118,7 +125,8 @@ def main() -> int:
         }
         ma = compiled.memory_analysis()
         print(
-            f"ok   {key}: {time.time() - t0:.1f}s "
+            f"ok   {key}: lower_us={int((t1 - t0) * 1e6)} "
+            f"compile_us={int((t_done - t1) * 1e6)} "
             f"tpu_custom_call={profiling.count_tpu_kernels(compiled)} "
             f"collectives={ {k: v for k, v in coll.items() if v} } "
             f"args={ma.argument_size_in_bytes / 2**20:.0f}MiB "
