@@ -21,8 +21,9 @@ ffn 12288, 32Q/8KV, head_dim 128, vocab 151936; no width is cut), then
    second a prefix hit with the same text) and one `response_format`
    request. It fails on any response that is not 200, is empty or names no
    finish reason; on any recovery, supervisor rebuild or post-warm-up
-   recompile in `/stats`; and when the compiled decode, batch-decode,
-   prefill or verify program holds fewer Mosaic kernels than the path has
+   recompile in `/stats`; and when the compiled batch-decode, per-row
+   prefill or per-row verify program (the Batcher's: a batched server plans
+   no solo `prefill` / `decode`) holds fewer Mosaic kernels than the path has
    kernelled matmuls (a weight that fell off a kernel takes the XLA
    dequantize-then-dot path without a word).
 
@@ -458,11 +459,17 @@ def phase_server(model: str, tokenizer: str, rehearse: bool) -> None:
     table = engine.cost_table(build=False)
     kvb = max(k for _, _, k in plan)
     on_tpu = jax.devices()[0].platform == "tpu"
+    # a batched server's plan holds its Batcher's programs and not the solo
+    # `prefill` / `decode` (`InferenceEngine.warms_solo_programs`)
+    sizes: dict = {}
+    for kind, size, _ in plan:
+        sizes.setdefault(kind, []).append(size)
+    if "prefill" in sizes or "decode" in sizes:
+        fail(f"a batched server planned solo programs: {sorted(sizes)}")
     for kind, size, need in (
-        ("decode", 1, N_MATMUL_KERNELS + 1),  # + the page-table kernel
-        ("batch_decode", 1, N_MATMUL_KERNELS + 1),
-        ("prefill", max(s for k, s, _ in plan if k == "prefill"), N_MATMUL_KERNELS + 1),
-        ("verify", min(s for k, s, _ in plan if k == "verify"), N_MATMUL_KERNELS + 1),
+        ("batch_decode", 1, N_MATMUL_KERNELS + 1),  # + the page-table kernel
+        ("prefill_row", max(sizes["prefill_row"]), N_MATMUL_KERNELS + 1),
+        ("verify_row", min(sizes["verify_row"]), N_MATMUL_KERNELS + 1),
     ):
         e = table.entries.get((kind, size, kvb)) if table else None
         n = (e.tpu_custom_calls if on_tpu else e.pallas_calls) if e else -1

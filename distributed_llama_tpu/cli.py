@@ -157,7 +157,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
     return p
 
 
-def make_engine(args) -> InferenceEngine:
+def make_engine(args, server_role: str | None = None) -> InferenceEngine:
+    """The engine the arguments describe. `server_role` is server/api.py's
+    alone: the role of the server process that will drive the engine
+    (`InferenceEngine.warms_solo_programs`)."""
     from .runtime.engine import enable_compilation_cache
     from .runtime.prefix_cache import resolve_budget_mb
 
@@ -280,6 +283,7 @@ def make_engine(args) -> InferenceEngine:
             kv_page_size=getattr(args, "kv_page_size", 0) or None,
             kv_pool_mb=getattr(args, "kv_pool_mb", 0) or None,
             grammar=grammar,
+            server_role=server_role,
         )
     except BaseException:
         # the main engine failed to build: release the draft engine's

@@ -259,6 +259,12 @@ class InferenceEngine:
         kv_pool_mb: int | None = None,  # paged-pool HBM budget. None =
         # DLT_KV_POOL_MB env; 0/unset = contiguous parity (batch x seq_len
         # worth of pages), so default paged never fits fewer tokens
+        server_role: str | None = None,  # set by server/api.py alone (`serve`
+        # and the supervisor's rebuild): the --role of the server process
+        # that drives this engine, so the warm plan holds that driver's
+        # programs and no other (`warms_solo_programs`). None = nobody said
+        # (the library, the CLI's inference / chat modes, a draft engine):
+        # the plan is what it always was
     ):
         # the start-up record (runtime/tracing.py `STARTUP_SPANS`): this
         # engine's phases and one span a program built or first dispatched
@@ -384,6 +390,7 @@ class InferenceEngine:
         # device_decode: run the decode loop on device in chunks (fast path);
         # False = per-token host loop with the reference's exact RNG stream.
         self.device_decode = device_decode
+        self.server_role = server_role
         self.decode_chunk_size = decode_chunk_size
         self.stats = StepStats()
         # KV layout (runtime/paged_kv.py): paged replaces the per-row
@@ -528,6 +535,7 @@ class InferenceEngine:
             else:
                 self.grammar = GrammarArena(self.cfg.vocab_size)
         self._in_warmup = False
+        self._solo_noticed = False  # `_solo_entry` speaks once
         # engine lifetime anchor: the device-duty-cycle gauge (profiling
         # .roofline_view) reports busy-time as a fraction of this span
         self._t_start = time.perf_counter()
@@ -563,19 +571,45 @@ class InferenceEngine:
     @property
     def warms_solo_programs(self) -> bool:
         """Whether the warm plan and the warm-up hold the solo `prefill` /
-        `decode` programs (`generate`, `generate_batch`) beside the batched
-        ones. A server's Batcher dispatches `prefill_row`, `batch_decode` and
-        the page programs only, whatever the architecture, but the engine
-        does not know who drives it, and the plans of the architectures that
-        were here first are pinned as they are (the goldens, the warm-plan
-        tests, `setup_s` of two benchmark cells): dropping the solo half for
-        every batched server is a change of its own, measured in those cells
-        (ROADMAP S10, D12). A hybrid model's plan is new, so it starts without:
-        its period of four layers is one scan body and every program costs
-        more to compile (65 programs against 177 at the cell's arguments);
-        `generate` on such an engine compiles what it uses when it uses it."""
+        `decode` programs (what `generate` and `prefill` dispatch) beside the
+        batched ones: THE predicate `warm_plan` and `warmup` both ask.
+
+        A server says who drives the engine (`server_role`, from `serve()` and
+        the supervisor's rebuild). It gives an engine with batch > 1 and
+        device decode a Batcher, every chat request then goes to the Batcher,
+        and a Batcher dispatches `prefill_row`, `batch_decode` and the page
+        programs only: the solo half was 80 of 177 programs at Qwen3-8B,
+        batch 16, 45% of the cost table's thread-seconds and half of
+        warm-up, and nothing dispatched it (PERF.md section 6, PR 39). A
+        replica at --role prefill keeps the solo half: /v1/prefill calls
+        `engine.prefill`. So does a server without a Batcher (--batch 1,
+        --host-decode: `ApiState.complete` calls `generate`).
+
+        Where nobody said (the library, the CLI, tests) the architecture's
+        default stands: the plans that were here first are pinned as they are
+        (the goldens, the warm-plan tests), and a hybrid model's batched plan,
+        which is newer, starts without the solo half. Either way `generate`
+        and `prefill` still run on an engine whose plan leaves them out: they
+        compile what they use when they use it, and say so once
+        (`_solo_entry`). `verify` and the contiguous layout's `prefix_extract`
+        / `prefix_copy` are solo programs too and stay in every plan (ROADMAP
+        D12)."""
         batched = self.batch > 1 and self.device_decode
-        return not (batched and self.cfg.is_hybrid)
+        if self.server_role is None:
+            return not (batched and self.cfg.is_hybrid)
+        return not batched or self.server_role == "prefill"
+
+    def _solo_entry(self, what: str) -> None:
+        """`generate` / `prefill` were called: where the plan leaves their
+        programs out, say once that they compile now."""
+        if self.warms_solo_programs or self._in_warmup or self._solo_noticed:
+            return
+        self._solo_noticed = True
+        self._notice(
+            f"{what}: this engine's warm plan holds its Batcher's programs only "
+            "(prefill_row, batch_decode, the page programs), so the solo "
+            "prefill / decode programs compile on first use"
+        )
 
     def rec_state_snapshot(self):
         """The recurrent-state cache as /stats reports it beside `kv_pool`:
@@ -929,9 +963,11 @@ class InferenceEngine:
         request (cold-TTFT, VERDICT r4 #6), in two passes:
 
         1. the CANONICAL flow — a streaming generate (prefill ladder + TTFT
-           ramp + full decode chunks) and, batch > 1, one BatchSession
-           admit/step cycle — exercising the real driver paths end to end
-           (argmax step, per-row key chains, the admission prefill ladder);
+           ramp + full decode chunks; left out where the plan holds no solo
+           programs, `warms_solo_programs`) and, batch > 1, one
+           BatchSession admit/step cycle — exercising the real driver paths
+           end to end (argmax step, per-row key chains, the admission
+           prefill ladder);
         2. the LADDER FILL (`warm_plan`) — every remaining (kind, size,
            kv-bucket) cross-product program the canonical request's shapes
            do not reach: prefill tail buckets below max_chunk, deep-kv-
@@ -1371,6 +1407,7 @@ class InferenceEngine:
         n = len(tokens)
         if n == 0:
             return
+        self._solo_entry("prefill")
         t0 = time.perf_counter()
         # prefix-cache splice: longest-prefix-match the radix trie, round
         # the match DOWN to a chunk-bucket boundary, copy the cached KV into
@@ -1705,6 +1742,7 @@ class InferenceEngine:
             )
         if pos_start + len(prompt_tokens) > self.cfg.seq_len:
             raise ValueError("prompt is longer than the sequence length")
+        self._solo_entry("generate")
         res = GenerationResult(tokens=list(prompt_tokens), n_prompt_tokens=len(prompt_tokens))
         wall0 = time.perf_counter()
 

@@ -691,11 +691,16 @@ def build_cost_table(engine, plan=None) -> CostTable:
     done several programs at a time: the TPU compiler works on ONE thread
     a program (seconds for a step program of a deep model), so a serial
     pass over a 4k-context ladder is many minutes and a pass on every core
-    a few. The programs
-    land in the persistent compilation cache
-    (engine.enable_compilation_cache), which is why `serve()` builds the
-    table BEFORE warm-up: warm-up's dispatches then load what was compiled
-    here instead of compiling the ladder again, one program at a time.
+    a few. `serve()` builds the table BEFORE warm-up, over the same
+    `warm_plan()`: warm-up's dispatches then find the executables this
+    build's `.compile()` left IN THE PROCESS (the AOT path and the jit call
+    share JAX's lowering cache), so warm-up compiles nothing and asks the
+    persistent cache for nothing: its seconds are each program's one
+    execution (the start-up record, PR 38). The persistent cache
+    (engine.enable_compilation_cache) is what the NEXT process's build
+    retrieves from. A program that is not in the plan costs neither: a
+    batched server's plan leaves the solo half out
+    (`InferenceEngine.warms_solo_programs`).
 
     Each worker compiles inside the sentinel's thread-scoped `exempt()`
     window — a lazy build on a sealed server (`/debug/costs`) is sanctioned

@@ -2069,8 +2069,6 @@ class ApiState:
         (the fleet scraper re-baselines backward counters anyway)."""
         import os
 
-        from ..cli import make_engine
-
         old = self.engine
         # build-then-swap: the NEW engine comes up fully (weights, warm
         # ladder, sealed sentinel) before the old one is released — a
@@ -2080,7 +2078,7 @@ class ApiState:
         # Sentinel attribution is safe in the overlap: the new engine's
         # UNSEALED sentinel claims the build's compiles, so the old sealed
         # one neither counts nor (fatal) aborts them.
-        engine = make_engine(self.args)
+        engine = make_served_engine(self.args)
         for k, v in old.stats.counters_snapshot().items():
             engine.stats.incr(k, v)
         if not os.environ.get("DLT_NO_WARMUP"):
@@ -3018,20 +3016,29 @@ class Handler(BaseHTTPRequestHandler):
         self._respond(code, body, headers=headers)
 
 
+def make_served_engine(args):
+    """The engine of a server process: `serve()`'s first build and the
+    supervisor's rebuild alike. The server says which role it serves, so the
+    engine's warm plan holds the programs of what will drive it and no other
+    (`InferenceEngine.warms_solo_programs` is the rule, and its one copy)."""
+    from ..cli import make_engine
+    from .disagg import resolve_role
+
+    return make_engine(args, server_role=resolve_role(getattr(args, "role", None)))
+
+
 def serve(args) -> HTTPServer:
     """Build state and return a configured (unstarted) HTTPServer.
 
     batch == 1: single-threaded server, serialized requests + prefix cache
     (the reference's model). batch > 1: threaded server so concurrent
     handlers can reach the Batcher together."""
-    from ..cli import make_engine
-
     # since the KV movement layer (runtime/kv_transport.py), BOTH serving
     # roles speak both KV layouts: paged workers extract/insert through the
     # warmed page_extract/page_insert programs, so the old roles-force-
     # contiguous override is gone and the paged default applies everywhere
     t_serve = time.perf_counter()
-    engine = make_engine(args)
+    engine = make_served_engine(args)
     # the start-up record (runtime/tracing.py `STARTUP_SPANS`) is the
     # engine's; `startup.serve` began before there was one
     with engine.startup.phase("startup.serve", since=t_serve):
@@ -3052,11 +3059,11 @@ def _serve_engine(engine, args) -> HTTPServer:
         if _os.environ.get("DLT_COST_TABLE") != "0":
             # serving processes carry the warm-ladder cost table from the
             # start (/debug/costs, /metrics roofline gauges). It is built
-            # FIRST: its AOT compiles run on every core and fill the
-            # persistent cache (make_engine turned it on) with the very
-            # programs the warm-up below dispatches, which would otherwise
-            # compile one at a time. DLT_COST_TABLE=0 opts out; the table
-            # then builds lazily on the first /debug/costs hit.
+            # FIRST: its AOT compiles run on every core, and the warm-up
+            # below finds their executables in the process, where it would
+            # otherwise compile the plan one program at a time.
+            # DLT_COST_TABLE=0 opts out; the table then builds lazily on
+            # the first /debug/costs hit.
             from ..runtime.profiling import build_threads
 
             def table_vals():

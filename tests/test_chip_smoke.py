@@ -81,7 +81,7 @@ def test_one_chip_rehearsal_runs_every_phase(rehearsals):
     assert gen["leading_tokens_equal"] >= gen["bound"]
     kernels = _by_phase(lines, "kernels")
     assert [k["program"].split("[")[0] for k in kernels] == [
-        "decode", "batch_decode", "prefill", "verify",
+        "batch_decode", "prefill_row", "verify_row",  # the Batcher's: no solo half
     ]
     assert all(k["pallas_calls_traced"] >= k["need"] for k in kernels)
     requests = {l["request"]: l for l in _by_phase(lines, "request")}

@@ -1,0 +1,186 @@
+"""The server says who drives the engine, and the warm plan holds that
+driver's programs and no other (`InferenceEngine.warms_solo_programs`,
+`server.api.make_served_engine`): a Batcher dispatches `prefill_row`,
+`batch_decode` and the page programs, never the solo `prefill` / `decode`.
+An engine that nobody told plans what it always planned. Tiny models, no
+warm-up: the plan is a function of the constructor's arguments."""
+
+import collections
+import warnings
+
+import pytest
+
+from distributed_llama_tpu.formats.mfile import ArchType
+from distributed_llama_tpu.runtime.engine import InferenceEngine
+from distributed_llama_tpu.runtime.tracing import STARTUP_SPANS
+from distributed_llama_tpu.server import api
+from distributed_llama_tpu.testing import tiny_header, write_tiny_model, write_tiny_tokenizer
+
+from test_goodput import CHATML
+
+SOLO = ("prefill", "decode")
+
+
+def _headers():
+    from distributed_llama_tpu.analysis.graph_audit import tiny_hybrid_header
+
+    small = dict(dim=64, hidden_dim=128, n_layers=1, seq_len=64, vocab_size=288)
+    return {
+        "dense": tiny_header(arch=ArchType.LLAMA, **small),
+        "moe": tiny_header(
+            arch=ArchType.QWEN3_MOE, n_experts=4, n_active_experts=2, moe_hidden_dim=64, **small
+        ),
+        "hybrid": tiny_hybrid_header(),
+    }
+
+
+@pytest.fixture(scope="module")
+def files(tmp_path_factory):
+    d = tmp_path_factory.mktemp("served_plan")
+    paths = {}
+    for name, h in _headers().items():
+        paths[name] = str(d / f"{name}.m")
+        write_tiny_model(paths[name], h, seed=3)
+    paths["tokenizer"] = str(d / "t.t")
+    write_tiny_tokenizer(paths["tokenizer"], pad_to=288, chat_template=CHATML)
+    return paths
+
+
+def _args(files, *extra, model="dense"):
+    return api.parse_args([
+        "--model", files[model], "--tokenizer", files["tokenizer"],
+        "--compute-dtype", "float32", "--max-batch-size", "4", "--port", "0", *extra,
+    ])
+
+
+def _plans(args):
+    """(the plan of the engine as a server builds it, the plan of the same
+    arguments' engine that nobody told)."""
+    from distributed_llama_tpu.cli import make_engine
+
+    served, bare = api.make_served_engine(args), make_engine(args)
+    try:
+        return served.warm_plan(), bare.warm_plan(), served
+    finally:
+        served.close()
+        bare.close()
+
+
+@pytest.mark.parametrize("extra", [
+    (), ("--kv-layout", "contiguous"), ("--speculative", "off", "--prefix-cache-mb", "0"),
+    ("--role", "decode"),
+], ids=["default", "contiguous", "no-spec-no-prefix", "role-decode"])
+def test_a_batched_server_plans_its_batchers_programs_in_the_bare_plans_order(files, extra):
+    served, bare, eng = _plans(_args(files, "--batch", "4", *extra))
+    assert eng.server_role in ("unified", "decode") and not eng.warms_solo_programs
+    assert {k[0] for k in bare} >= set(SOLO)
+    assert served == [k for k in bare if k[0] not in SOLO]
+    assert {"prefill_row", "batch_decode"} <= {k[0] for k in served}
+    # the record's bound on its spans follows the narrower plan
+    assert eng.startup.limit == 2 * len(served) + len(STARTUP_SPANS)
+
+
+@pytest.mark.parametrize("extra", [
+    ("--batch", "4", "--role", "prefill"), ("--batch", "1"), ("--batch", "4", "--host-decode"),
+], ids=["role-prefill", "batch-1", "host-decode"])
+def test_a_server_without_a_batcher_or_at_role_prefill_plans_the_bare_plan(files, extra):
+    served, bare, eng = _plans(_args(files, *extra))
+    assert eng.server_role is not None and eng.warms_solo_programs
+    assert served == bare and set(SOLO) <= {k[0] for k in served}
+
+
+def test_the_servers_decision_and_the_engines_are_one(files):
+    """`ApiState` gives a Batcher to exactly the engines whose plan leaves
+    the solo half out (role prefill aside, which has both)."""
+    tok = api.Tokenizer(files["tokenizer"])
+    for extra, batcher, solo in (
+        (("--batch", "4"), True, False), (("--batch", "1"), False, True),
+        (("--batch", "4", "--host-decode"), False, True),
+        (("--batch", "4", "--role", "prefill"), True, True),
+    ):
+        args = _args(files, *extra)
+        eng = api.make_served_engine(args)
+        state = api.ApiState(eng, tok, args)
+        try:
+            assert (state.batcher is not None) == batcher, extra
+            assert eng.warms_solo_programs == solo, extra
+            assert state.role == eng.server_role
+        finally:
+            state.close()
+
+
+def test_the_supervisors_rebuild_plans_what_the_first_build_planned(files, monkeypatch):
+    monkeypatch.setenv("DLT_NO_WARMUP", "1")
+    args = _args(files, "--batch", "4")
+    first = api.make_served_engine(args)
+    plan = first.warm_plan()
+    state = api.ApiState(first, api.Tokenizer(files["tokenizer"]), args)
+    try:
+        state._rebuild_engine()
+        rebuilt = state.engine
+        assert rebuilt is not first and rebuilt.server_role == first.server_role == "unified"
+        assert rebuilt.warm_plan() == plan and not any(k[0] in SOLO for k in plan)
+    finally:
+        state.close()
+
+
+# what each architecture's bare engine planned on the parent commit (paged,
+# chunks to 4, no prefix cache, no speculation): kind -> programs
+_BATCHED = {"prefill_row": 3, "batch_decode": 7}
+_SOLO = {"prefill": 3, "decode": 7}
+_PAGE = {"page_copy": 1}
+
+
+@pytest.mark.parametrize("arch,batch,expected", [
+    ("dense", 1, {**_SOLO, **_PAGE}), ("dense", 2, {**_SOLO, **_BATCHED, **_PAGE}),
+    ("moe", 1, {**_SOLO, **_PAGE}), ("moe", 2, {**_SOLO, **_BATCHED, **_PAGE}),
+    ("hybrid", 1, {**_SOLO, **_PAGE}), ("hybrid", 2, {**_BATCHED, **_PAGE}),
+])
+def test_an_engine_nobody_told_plans_what_it_planned(files, arch, batch, expected):
+    eng = InferenceEngine(
+        files[arch], compute_dtype="float32", batch=batch, kv_layout="paged", max_chunk=4,
+        prefix_cache_mb=0, speculative="off",
+    )
+    try:
+        plan = eng.warm_plan()
+        assert eng.server_role is None
+        assert dict(collections.Counter(k[0] for k in plan)) == expected
+        assert list(expected) == list(dict.fromkeys(k[0] for k in plan))  # and in this order
+        assert eng.warms_solo_programs == ("prefill" in expected)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("entry", ["generate", "prefill"])
+def test_a_solo_entry_point_on_a_batchers_engine_says_once_that_it_compiles(files, entry):
+    args = _args(files, "--batch", "2", "--speculative", "off", "--prefix-cache-mb", "0")
+    eng = api.make_served_engine(args)
+    try:
+        assert eng.notices == []
+        with pytest.warns(UserWarning, match="compile on first use"):
+            if entry == "generate":
+                eng.generate([1, 2, 3], 6, sampler=None)
+            else:
+                eng.prefill([1, 2, 3])
+        assert len(eng.notices) == 1 and eng.notices[0].startswith(entry + ":")
+        eng.reset()
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")  # the second call says nothing
+            eng.generate([1, 2], 4, sampler=None)
+        assert len(eng.notices) == 1
+    finally:
+        eng.close()
+
+
+def test_the_engines_own_solo_callers_stay_silent(files):
+    """A bare engine and a --batch 1 server dispatch the solo programs they
+    planned: no notice."""
+    from distributed_llama_tpu.cli import make_engine
+
+    for build in (make_engine, api.make_served_engine):
+        eng = build(_args(files, "--batch", "1", "--speculative", "off"))
+        try:
+            eng.generate([1, 2, 3], 6, sampler=None)
+            assert eng.notices == []
+        finally:
+            eng.close()

@@ -29,12 +29,15 @@ from test_goodput import CHATML, _get_json, _post, free_port
 
 SLACK_US = 2  # start and duration are truncated to whole microseconds apart
 PHASES = ("startup.load", "startup.cost_table", "startup.warmup")
+STOP_SEEDS = (0, 2, 4, 28)  # sampled requests of the fixture's model that meet a stop string
 
 
 @pytest.fixture(scope="module")
 def startup_server(tmp_path_factory):
-    """batch 2, paged, no speculation, warm-up and cost table as `serve()`
-    runs them by default; 21 programs."""
+    """Qwen3-shaped, batch 2, paged, no speculation, warm-up and cost table as `serve()`
+    runs them by default. The server says a Batcher drives the engine, so
+    the plan leaves the solo `prefill` / `decode` out: 11 programs of the
+    bare engine's 21."""
     from distributed_llama_tpu.formats.mfile import ArchType
     from distributed_llama_tpu.server import api as api_mod
     from distributed_llama_tpu.testing import (
@@ -47,7 +50,7 @@ def startup_server(tmp_path_factory):
     os.environ.pop("DLT_NO_WARMUP", None)
     d = tmp_path_factory.mktemp("startup_srv")
     h = tiny_header(
-        arch=ArchType.LLAMA, dim=64, hidden_dim=128, n_layers=1, seq_len=64,
+        arch=ArchType.QWEN3, dim=64, hidden_dim=128, n_layers=1, seq_len=128,
         vocab_size=288,
     )
     mp, tp = str(d / "m.m"), str(d / "t.t")
@@ -149,6 +152,114 @@ def test_one_build_and_at_most_one_warm_a_program(startup_server):
     assert len(eng.startup.spans) <= eng.startup.limit == 2 * len(plan) + len(STARTUP_SPANS)
 
 
+def _chat(port, **payload):
+    """One chat request; (status, text, finish_reason)."""
+    payload.setdefault("messages", [{"role": "user", "content": "hi there"}])
+    with _post(port, payload) as r:
+        raw = r.read().decode()
+        if not payload.get("stream"):
+            choice = json.loads(raw)["choices"][0]
+            return r.status, choice["message"]["content"], choice["finish_reason"]
+    events = [json.loads(e[len("data: "):]) for e in raw.split("\r\n\r\n")
+              if e.strip() and e.strip() != "data: [DONE]"]
+    text = "".join(e["choices"][0].get("delta", {}).get("content") or "" for e in events)
+    return r.status, text, events[-1]["choices"][0]["finish_reason"]
+
+
+def test_the_batchers_plan_alone_seals_a_server_that_serves_chat(startup_server, monkeypatch):
+    """No solo `prefill` / `decode` was planned or warmed, and no canonical
+    solo pass ran; what chat traffic needs was compiled all the same: with
+    the sanitizers fatal, no request of any shape compiles after the seal."""
+    _, port, state = startup_server
+    eng = state.engine
+    assert state.batcher is not None and eng.server_role == "unified"
+    assert not eng.warms_solo_programs
+    plan = eng.warm_plan()
+    startup = _get_json(port, "/stats")["startup"]
+    assert set(startup["by_kind"]) == {"prefill_row", "batch_decode", "page_copy"}
+    assert startup["programs_warmed"] == startup["programs_planned"] == len(plan) == 11
+    assert startup["never_warmed"] == [] and startup["never_warmed_n"] == 0
+    # the solo programs' stats series were never opened: nothing ran them
+    series = _get_json(port, "/stats")["steps"]
+    assert not [k for k in series if k.startswith(("prefill[", "decode["))], series.keys()
+    monkeypatch.setenv("DLT_SANITIZERS_FATAL", "1")
+    monkeypatch.setattr(eng.sentinel, "fatal", True)
+    before = eng.stats.counters_snapshot()
+    assert before.get("sanitizer_recompiles", 0) == 0
+
+    greedy = _chat(port, max_tokens=12, temperature=0.0)
+    assert greedy[0] == 200 and greedy == _chat(port, max_tokens=12, temperature=0.0, stream=True)
+    sampled = _chat(port, max_tokens=12, temperature=0.9, top_p=0.8, seed=7)
+    assert sampled == _chat(port, max_tokens=12, temperature=0.9, top_p=0.8, seed=7, stream=True)
+    assert _chat(port, max_tokens=12, temperature=1.0, seed=11)[0] == 200
+    # a request that ends at a stop string (the tokenizer's own: the server
+    # takes none from the request) frees its row before its budget
+    stopped = [_chat(port, max_tokens=40, temperature=1.0, seed=seed) for seed in STOP_SEEDS]
+    assert all(r[0] == 200 for r in stopped) and "stop" in {r[2] for r in stopped}, stopped
+    # two requests at once
+    both = [None, None]
+
+    def one(i):
+        both[i] = _chat(port, max_tokens=16, temperature=0.0 if i else 0.7, seed=3,
+                        stream=bool(i))
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert all(r is not None and r[0] == 200 for r in both)
+    # one admitted while the other streams: its prompt's chunks run between
+    # the first one's decode chunks
+    for attempt in range(8):
+        first_chunk = threading.Event()
+        done = []
+
+        def streamer():
+            # odd seeds run to their budget on this model (STOP_SEEDS do not)
+            with _post(port, {"messages": [{"role": "user", "content": "hi there"}],
+                              "max_tokens": 60, "temperature": 1.0, "seed": 1 + 2 * attempt,
+                              "stream": True}) as r:
+                r.read(16)
+                first_chunk.set()
+                done.append((r.status, r.read()))
+
+        t = threading.Thread(target=streamer)
+        t.start()
+        assert first_chunk.wait(timeout=120)
+        late = _chat(port, max_tokens=8, temperature=0.0,
+                     messages=[{"role": "user", "content": "one more question here"}])
+        t.join(timeout=120)
+        assert late[0] == 200 and done and done[0][0] == 200
+        if eng.stats.counters_snapshot().get("interleaved_prefill_chunks", 0) > before.get(
+                "interleaved_prefill_chunks", 0):
+            break
+    else:
+        pytest.fail("no admission ran while another row decoded, in 8 tries")
+    after = _get_json(port, "/stats")
+    assert after["steps"]["counters"].get("sanitizer_recompiles", 0) == 0
+    assert after["startup"]["recompiled"] == [] and after["notices"] == []
+    assert set(after["startup"]["by_kind"]) == {"prefill_row", "batch_decode", "page_copy"}
+    assert after["startup"]["by_kind"]["prefill_row"]["dispatched"] > 0
+
+
+def test_graph_audit_and_cost_coverage_are_clean_on_the_served_plan(startup_server):
+    """`graph_audit --costs` on the plan a batched server holds: every
+    program keeps its contract and has its cost entry, and the table holds
+    no entry of the solo half."""
+    from distributed_llama_tpu.analysis.graph_audit import audit_engine
+    from distributed_llama_tpu.runtime.profiling import cost_problems
+
+    _, _, state = startup_server
+    eng = state.engine
+    table = eng.cost_table(build=False)
+    assert table is not None and cost_problems(eng, table) == []
+    assert sorted(table.entries) == sorted(tuple(k) for k in eng.warm_plan())
+    reports = audit_engine(eng)
+    assert len(reports) == len(eng.warm_plan())
+    assert [p for r in reports for p in r.problems] == []
+
+
 def test_two_threads_receive_their_own_compile_events():
     install_listener()
     operands = [jnp.ones((n,)) for n in (31, 37, 41)]  # made before a span is open
@@ -243,9 +354,8 @@ def test_a_served_request_moves_the_counts_of_the_programs_it_used(startup_serve
         delta = by_kind[kind]["dispatches"] - stats_before[kind]["dispatches"]
         assert delta == sum(n for k, n in moved.items() if k[0] == kind)
         assert 0 < by_kind[kind]["dispatched"] <= by_kind[kind]["planned"]
-    for kind in ("prefill", "decode"):
-        assert by_kind[kind] == stats_before[kind]
-        assert by_kind[kind]["dispatched"] == 0 and by_kind[kind]["warmed"] == by_kind[kind]["planned"]
+    # the solo half is not planned on a Batcher's engine, and was not run
+    assert "prefill" not in by_kind and "decode" not in by_kind
 
 
 def test_stats_startup_is_small_at_a_plan_of_200_programs():
@@ -323,11 +433,11 @@ def test_a_planted_misbucketed_shape_is_named(startup_server, monkeypatch):
     eng = state.engine
     monkeypatch.setenv("DLT_FLIGHTREC_DIR", "")  # no disk copy
     monkeypatch.setattr(eng.sentinel, "fatal", True)
-    key = ("prefill_row", 3, 64)
+    key = ("prefill_row", 3, 128)
     try:
         with pytest.raises(RecompileError):
-            with eng._guard("prefill_row[3|kv64]", key):
-                eng._dispatch_prefill_row(0, [1, 2, 3], 0, 64)
+            with eng._guard("prefill_row[3|kv128]", key):
+                eng._dispatch_prefill_row(0, [1, 2, 3], 0, 128)
     finally:
         eng.page_pool.release_all_rows()
         eng._pt_cache = None
@@ -336,12 +446,12 @@ def test_a_planted_misbucketed_shape_is_named(startup_server, monkeypatch):
     assert named, "no recompile recorded"
     last = named[-1]
     assert (last["kind"], last["size"], last["kv_len"]) == key
-    assert last["label"] == "prefill_row[3|kv64]" and "forward" in last["fun"]
+    assert last["label"] == "prefill_row[3|kv128]" and "forward" in last["fun"]
     record = tracing.last_flight_record()
     assert record["reason"].startswith("sanitizer:recompile")
     events = [e for e in record["events"] if e["name"] == "sanitizer.recompile"]
     assert events and events[-1]["args"]["kind"] == "prefill_row"
-    assert (events[-1]["args"]["size"], events[-1]["args"]["kv_len"]) == (3, 64)
+    assert (events[-1]["args"]["size"], events[-1]["args"]["kv_len"]) == (3, 128)
     assert _get_json(port, "/stats")["steps"]["counters"]["sanitizer_recompiles"] >= 1
     # a thread with no open span: the compile is `unknown`
     monkeypatch.setattr(eng.sentinel, "fatal", False)
