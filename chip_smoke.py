@@ -32,6 +32,22 @@ Four chips (`--chips 4`, run by the builder): only `--tp 4` over
 KV, batch 2 — and what it is compared with, the one-device engine in the
 same process on the same prompts.
 
+`--arch kimi_k2` (one chip, run by the builder): Kimi-K2.6's widths as
+`perfbench/configs/kimi-k2.6.json` has them (hidden 7168, 64 latent-attention
+heads, a dense layer at 18432 and ONE expert layer that holds 16 of 384 experts
+of width 2048 beside a shared one, an eighth of the vocabulary), at 2 rows:
+
+1. numbers: the held-experts layer through the grouped kernel told its live
+   blocks against its float32 `ragged_dot` form on the same inputs, at 16, 256
+   and 2048 pairs (rows past the live blocks must never reach the result);
+   latent attention's absorbed form through the paged latent arm against the
+   same engine's float32 XLA path, teacher-forced logits, prefill then decode
+   (the float32 path is held to the plain reference on the CPU,
+   `tests/z_perfbench/test_kimi_k2_program.py`);
+2. the server with the configuration's own arguments at batch 2
+   (`--speculative off`): the same requests, 0 recompiles, the notices, the
+   `/stats` `moe` block and `kv_pool.bytes_per_token`.
+
 Every phase prints one JSON line. The LAST line of standard output is
 `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}` and
 the exit code 0 only if JAX found the TPU and no phase failed; anything else
@@ -64,6 +80,24 @@ TINY = dict(
     dim=256, hidden_dim=512, n_layers=2, n_heads=8, n_kv_heads=4,
     head_dim=32, vocab_size=512, seq_len=256, rope_theta=10000.0,
 )
+# perfbench/configs/kimi-k2.6.json (moonshotai/Kimi-K2.6 config.json), the
+# program's header names; depth is the caller's
+KIMI_K26 = dict(
+    dim=7168, hidden_dim=18432, n_heads=64, n_kv_heads=64, vocab_size=20480,
+    seq_len=262144, n_experts=384, n_active_experts=8, moe_hidden_dim=2048,
+    rope_theta=50000.0, rope_scaling_factor=64.0, rope_scaling_orig_max_seq_len=4096,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, n_dense_layers=1, experts_held=16, expert_first=0,
+    n_shared_experts=1, routed_scale=2.827,
+)  # 16 held where the configuration holds 48: the float32 comparison
+# dequantizes every held expert at once (2.8 GB at 16, 8.4 at 48)
+# the held experts' grouped bf16 kernel against float32 `ragged_dot`, in units
+# of the output's std. Measured on the v5e (PR 40, seed 7): 0.026, 0.037 and
+# 0.041 at 16, 256 and 2048 pairs (bfloat16's own rounding; a row block that
+# read another expert, or a row past the live blocks, reads about 1). The
+# whole latent model, bf16 kernels against float32 XLA (the dense models'
+# bounds above): top-1 0.969 / 0.969, max diff 0.068 / 0.124 std
+MAX_EXPERT_DIFF_STD = 0.08
 CHATML = (
     "{% for m in messages %}<|im_start|>{{ m['role'] }}\n{{ m['content'] }}"
     "<|im_end|>\n{% endfor %}{% if add_generation_prompt %}"
@@ -139,13 +173,16 @@ def finish(device: dict) -> "NoReturn":
 
 def build_model(shape: dict, n_layers: int, seed: int) -> str:
     from distributed_llama_tpu.formats.mfile import ArchType, RopeType, tensor_walk
-    from distributed_llama_tpu.testing import tiny_header, write_tiny_model
+    from distributed_llama_tpu.testing import tiny_header, tiny_latent_header, write_tiny_model
 
-    h = tiny_header(
-        arch=ArchType.QWEN3, rope_type=RopeType.FALCON,
-        **{**shape, "n_layers": n_layers},
-    )
-    path = os.path.join(WORK, f"qwen3_d{h.dim}_L{n_layers}_seed{seed}.m")
+    if "kv_lora_rank" in shape:
+        h = tiny_latent_header(**{**shape, "n_layers": n_layers})
+    else:
+        h = tiny_header(
+            arch=ArchType.QWEN3, rope_type=RopeType.FALCON,
+            **{**shape, "n_layers": n_layers},
+        )
+    path = os.path.join(WORK, f"{ArchType.name(h.arch_type)}_d{h.dim}_L{n_layers}_seed{seed}.m")
     t0 = time.time()
     specs = tensor_walk(h)  # header_bytes is set by the writer; payload only
     want = sum(s.n_bytes for s in specs)
@@ -551,6 +588,154 @@ def phase_server(model: str, tokenizer: str, rehearse: bool) -> None:
     httpd.server_close()
 
 
+# -- the kimi_k2 branch ---------------------------------------------------------
+
+
+def phase_latent_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llama_tpu.models.transformer import _activation
+    from distributed_llama_tpu.ops.moe import moe_ffn_held, moe_router_sigmoid
+    from distributed_llama_tpu.runtime.engine import InferenceEngine
+
+    rng = np.random.default_rng(13)
+
+    def engine(dtype):
+        return InferenceEngine(
+            model, compute_dtype=dtype, batch=1, max_chunk=64, max_seq_len=256,
+            kv_layout="paged", device_decode=False,
+        )
+
+    # (a) the expert layer alone: grouped kernel (live blocks) vs float32 ragged_dot
+    eng = engine("bfloat16")
+    cfg, ep = eng.cfg, eng.params.layers.experts
+    held = jax.jit(  # the stacks are operands: closed over, they would be constants of the program
+        lambda cfg, ep, y, idx, wts: moe_ffn_held(
+            y, idx, wts, ep.w1, ep.w3, ep.w2, cfg.expert_first, jnp.int32(0),
+            partial(_activation, cfg), cfg.dtype, pallas=cfg.pallas_arg),
+        static_argnums=0,
+    )
+    for tokens in (2, 32, 256):
+        y = jnp.asarray(rng.standard_normal((1, tokens, cfg.dim)), jnp.float32)
+        idx, wts = moe_router_sigmoid(
+            y, ep.gate[0], ep.bias[0], cfg.n_active_experts, cfg.routed_scale)
+        # a token's 8 picks land on the 48 held with probability 1/8 each:
+        # send every other token's first pick here, so that few tokens still hit
+        idx = idx.at[0, ::2, 0].set(jnp.arange(0, tokens, 2) % cfg.n_experts_held)
+        fast, stats = held(cfg, ep, y, idx, wts)
+        slow, stats32 = held(cfg.with_(compute_dtype="float32", use_pallas=False,
+                                       pallas_interpret=False), ep, y, idx, wts)
+        diff = float(jnp.max(jnp.abs(fast - slow)) / jnp.std(slow))
+        say("numbers", check="held experts: grouped kernel vs float32 ragged_dot",
+            pairs=tokens * cfg.n_active_experts, landed_hit=[int(v) for v in stats],
+            finite=bool(jnp.isfinite(fast).all()), max_diff_std=round(diff, 4),
+            bound=MAX_EXPERT_DIFF_STD)
+        if not (diff <= MAX_EXPERT_DIFF_STD and np.array_equal(stats, stats32) and int(stats[0])):
+            fail(f"numbers/held experts at {tokens} tokens: {diff} stds, {stats} vs {stats32}")
+    free(eng)
+
+    # (b) the whole step, latent arm and all: bf16 kernels vs float32 XLA
+    n_pre, n_dec = (16, 4) if rehearse else (64, 32)
+    ids = [int(x) for x in rng.integers(1, cfg.vocab_size, n_pre + n_dec)]
+    logits = {}
+    for dtype in ("float32", "bfloat16"):
+        eng = engine(dtype)
+        eng._ensure_pages_all_rows(0, n_pre + n_dec)
+        rows = [eng.forward_tokens(ids[:n_pre], 0, logits_mode="all")[0]]
+        for i in range(n_dec):
+            rows.append(eng.forward_tokens([ids[n_pre + i]], n_pre + i)[0][None])
+        logits[dtype] = np.concatenate(rows)
+        free(eng)
+    want, got = logits["float32"], logits["bfloat16"]
+    std = float(want.std())
+    for name, sl in (("prefill", slice(0, n_pre)), ("decode", slice(n_pre, None))):
+        agree = float((want[sl].argmax(-1) == got[sl].argmax(-1)).mean())
+        diff = float(np.abs(want[sl] - got[sl]).max() / std)
+        say("numbers", check=f"latent model bf16 kernels vs float32 XLA, {name}",
+            positions=int(want[sl].shape[0]), top1_agreement=round(agree, 3),
+            max_diff_std=round(diff, 4), bounds=[MIN_TOP1_AGREEMENT, MAX_LOGIT_DIFF_STD])
+        if agree < MIN_TOP1_AGREEMENT or not diff <= MAX_LOGIT_DIFF_STD:
+            fail(f"numbers/latent {name}: top-1 {agree}, max diff {diff} stds")
+
+
+def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
+    import socket
+
+    import jax
+
+    from distributed_llama_tpu.server import api
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    argv = [
+        "--model", model, "--tokenizer", tokenizer, "--port", str(port),
+        "--batch", "2", "--temperature", "0.0", "--speculative", "off",
+        "--max-seq-len", "256" if rehearse else "2048",
+        "--max-batch-size", "8" if rehearse else "256",
+    ]
+    say("serve", argv=[a for a in argv if a not in (model, tokenizer)])
+    t0 = time.time()
+    httpd = api.serve(api.parse_args(argv))
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    engine = httpd.api_state.engine
+    plan = engine.warm_plan()
+    table = engine.cost_table(build=False)
+    kvb = max(k for _, _, k in plan)
+    on_tpu = jax.devices()[0].platform == "tpu"
+    kernels = {}
+    # a step's kernels: q_a|kv_a, q_b, wo a layer kind apart, the dense w13 and
+    # w2, the shared expert's two, the three grouped expert calls, the head
+    for kind, size in (("batch_decode", 1), ("prefill_row", max(s for k, s, _ in plan if k == "prefill_row"))):
+        e = table.entries.get((kind, size, kvb)) if table else None
+        kernels[f"{kind}[{size}|kv{kvb}]"] = e and [e.pallas_calls, e.tpu_custom_calls]
+        if not e or (e.tpu_custom_calls if on_tpu else e.pallas_calls) < 3 + 3 + 2 + 2 + 3 + 1:
+            fail(f"kernels: {kind}[{size}] holds {kernels}: a weight fell off its kernel")
+    say("serve", start_seconds=round(time.time() - t0, 1), warm_plan_programs=len(plan),
+        kernels_traced_compiled=kernels, kv=dict(layout=engine.kv_layout, page=engine.page_size),
+        device_gib={k: round(v / 2**30, 2) for k, v in (jax.devices()[0].memory_stats() or {}).items()
+                    if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")})
+    if table is None or table.failures:
+        fail(f"cost table: {table and dict(list(table.failures.items())[:3])}")
+    chat(port, "plain", "Say something about rivers.", 24)
+    chat(port, "streamed", "Say something about hills.", 24, stream=True)
+    both = [
+        threading.Thread(target=chat, args=(port, f"concurrent-{i}", text, 40))
+        for i, text in enumerate(("One two three four.", "A b c d e f g h i j k."))
+    ]
+    for th in both:
+        th.start()
+    for th in both:
+        th.join(timeout=900)
+    status, raw = http(port, "/stats")
+    stats = json.loads(raw) if status == 200 else {}
+    counters = stats.get("steps", {}).get("counters", {})
+    sup = stats.get("supervisor", {})
+    watched = {k: counters.get(k, 0) for k in (
+        "sanitizer_recompiles", "supervisor_rebuilds", "stall_resets",
+        "recover_reset_failed", "sanitizer_d2h_violations")}
+    moe, pool = stats.get("moe") or {}, stats.get("kv_pool") or {}
+    say("stats", status=status, watched=watched, supervisor=sup.get("state"),
+        requests_completed=counters.get("requests_completed"), moe=moe, kv_pool=pool,
+        notices=stats.get("notices"))
+    cfg = engine.cfg
+    if status != 200 or any(watched.values()) or sup.get("state") != "serving":
+        fail(f"/stats: {watched}, supervisor {sup.get('state')}")
+    if counters.get("requests_completed", 0) < 4:
+        fail(f"/stats counts {counters.get('requests_completed')} completed requests of 4")
+    if (moe.get("held"), moe.get("experts")) != (cfg.n_experts_held, cfg.n_experts) or not moe.get("expert_pairs"):
+        fail(f"/stats moe: {moe}")
+    itemsize = 2 if cfg.cache_dtype == "bfloat16" else 4
+    if pool.get("bytes_per_token") != cfg.n_layers * cfg.latent_page_width * itemsize:
+        fail(f"/stats kv_pool.bytes_per_token: {pool.get('bytes_per_token')}")
+    httpd.shutdown()
+    httpd.server_close()
+
+
 # -- phase: four chips ----------------------------------------------------------
 
 
@@ -643,6 +828,7 @@ def main() -> None:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--arch", choices=("qwen3", "kimi_k2"), default="qwen3")
     args = ap.parse_args()
 
     os.environ["DLT_SANITIZERS"] = "1"  # recompile sentinel + host-sync guard
@@ -693,6 +879,12 @@ def main() -> None:
         else [("numbers", phase_numbers, 1 if args.rehearse else 4),
               ("server", phase_server, shape["n_layers"])]
     )
+    if args.arch == "kimi_k2":
+        # a dense layer and one expert layer: every kind of layer, 1.4 GB
+        # (rehearsed: `testing.tiny_latent_header`'s own tiny widths)
+        shape = {"kv_lora_rank": 256, "vocab_size": 256, "seq_len": 256} if args.rehearse else KIMI_K26
+        phases = [("latent_numbers", phase_latent_numbers, 2),
+                  ("latent_server", phase_latent_server, 2)]
     for name, phase, depth in phases:
         try:
             tokenizer = build_tokenizer(shape["vocab_size"])

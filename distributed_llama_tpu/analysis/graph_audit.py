@@ -475,7 +475,19 @@ def f32_dot_budget(engine, entry: LadderEntry) -> int:
     path + probs·V). Everything else — the quantized Q40/int8 projections,
     logits — must keep bf16 inputs, so any EXTRA f32-touching dot is an
     accidental upcast of a quantized matmul path."""
+    if engine.cfg.is_latent:
+        return latent_f32_dots(engine)
     return 2 * attention_sites(engine, entry) + recurrence_f32_dots(engine, entry)
+
+
+def latent_f32_dots(engine) -> int:
+    """The float32 dots of a latent model's programs: the absorbed query
+    meets the latent page in bfloat16, so an attention body keeps ONE float32
+    product (the probabilities' sum over the latents), and there is a body a
+    leading dense layer (each its own call) and one in the expert layers'
+    scan; that scan's body also holds the router's float32 logits."""
+    cfg = engine.cfg
+    return cfg.n_dense_layers + (2 if cfg.n_moe_layers else 0)
 
 
 def recurrence_f32_dots(engine, entry: LadderEntry) -> int:
@@ -588,6 +600,8 @@ def _fused_kernel_active(engine) -> bool:
     from ..models.kv_arms import _fused_paged_eligible
 
     cfg = engine.cfg
+    if cfg.is_latent:  # the latent arm reads through a gather (ROADMAP R5)
+        return False
     tp = engine.mesh.shape["tp"] if engine.mesh is not None else 1
     # the pool's own kv heads (it may store more than the model has:
     # paged_kv.pool_kv_heads), a tp shard's share of them
@@ -1043,7 +1057,7 @@ def add_engine_args(p) -> None:
     and the audited config can never drift apart syntactically."""
     p.add_argument("--model", default=None, help=".m file (default: tiny synthetic)")
     p.add_argument(
-        "--arch", choices=["llama", "olmo_hybrid"], default="llama",
+        "--arch", choices=["llama", "olmo_hybrid", "kimi_k2"], default="llama",
         help="the tiny synthetic model's architecture (ignored with --model): "
         "olmo_hybrid = two periods of three gated-delta layers and a full one "
         "(pass --speculative off --prefix-cache-mb 0: refused for it)",
@@ -1134,6 +1148,10 @@ def engine_from_args(args, workdir: str):
         model = workdir + "/tiny.m"
         if getattr(args, "arch", "llama") == "olmo_hybrid":
             hdr = tiny_hybrid_header()
+        elif getattr(args, "arch", "llama") == "kimi_k2":
+            from ..testing import tiny_latent_header
+
+            hdr = tiny_latent_header()
         elif mesh is not None:
             # layer/head counts must divide over the mesh axes
             hdr = tiny_header(

@@ -109,9 +109,10 @@ def config_key(engine) -> str:
     if getattr(engine, "grammar", None) is not None:
         gr = f"_gr{engine.grammar.n_states}"
     # a layer pattern changes every program's body (a period of layers, a
-    # second kind of cache): its own family, keyed by the architecture
+    # second kind of cache; latent attention and held experts): its own
+    # family, keyed by the architecture
     arch = ""
-    if cfg.is_hybrid:
+    if cfg.is_hybrid or cfg.is_latent:
         from ..formats.mfile import ArchType
 
         arch = "_" + ArchType.name(cfg.arch_type)

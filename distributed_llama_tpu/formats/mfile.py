@@ -11,7 +11,11 @@ Binary-compatible with the reference engine's model format:
   An ``olmo_hybrid`` file (this project's own extension: the reference has no
   such architecture) walks each layer by its KIND: a linear-attention layer
   holds the gated-delta mixer's tensors where a full-attention layer holds
-  q,k,v,wo (see `tensor_walk`).
+  q,k,v,wo (see `tensor_walk`). A ``kimi_k2`` file (also this project's own)
+  holds latent attention's two low-rank projection pairs where the others
+  hold q,k,v, a dense feed-forward in its leading layers, and in the others a
+  router over ALL the published experts beside the stacks of the experts this
+  file HOLDS (one chip's share of a deployment) and the shared experts.
 
 Float header values are stored as int32s and cast on read (so e.g. a rope
 theta of 500000 is the int 500000); norm epsilon is encoded as the exponent
@@ -63,6 +67,24 @@ K_LIN_KEY_HEAD_DIM = 25
 K_LIN_VALUE_HEAD_DIM = 26
 K_LIN_CONV_KERNEL = 27
 K_LIN_NEG_EIGVAL = 28
+# kimi_k2 (also past the reference's): latent attention's ranks and head
+# sizes, YaRN's parameters (its factor and original length ride keys 14 and
+# 17), the leading dense layers, the share of the routed experts this file
+# holds, the shared experts. Floats are stored in thousandths.
+K_Q_LORA_RANK = 29
+K_KV_LORA_RANK = 30
+K_QK_NOPE_HEAD_DIM = 31
+K_QK_ROPE_HEAD_DIM = 32
+K_V_HEAD_DIM = 33
+K_YARN_BETA_FAST = 34
+K_YARN_BETA_SLOW = 35
+K_YARN_MSCALE_MILLI = 36
+K_YARN_MSCALE_ALL_DIM_MILLI = 37
+K_N_DENSE_LAYERS = 38
+K_EXPERTS_HELD = 39
+K_EXPERT_FIRST = 40
+K_N_SHARED_EXPERTS = 41
+K_ROUTED_SCALE_MILLI = 42
 
 
 class ArchType:
@@ -70,10 +92,11 @@ class ArchType:
     QWEN3 = 0xABCD01
     QWEN3_MOE = 0xABCD02
     OLMO_HYBRID = 0xABCD03
+    KIMI_K2 = 0xABCD04
 
     _NAMES = {
         LLAMA: "llama", QWEN3: "qwen3", QWEN3_MOE: "qwen3_moe",
-        OLMO_HYBRID: "olmo_hybrid",
+        OLMO_HYBRID: "olmo_hybrid", KIMI_K2: "kimi_k2",
     }
 
     @classmethod
@@ -90,6 +113,7 @@ class RopeType:
     LLAMA = 0
     FALCON = 1
     LLAMA3_1 = 2
+    YARN = 3  # interleaved pairs, YaRN's blended frequencies (ops/rope.py)
 
 
 @dataclass
@@ -128,6 +152,26 @@ class ModelHeader:
     lin_value_head_dim: int = 0
     lin_conv_kernel: int = 0
     lin_neg_eigval: int = 0
+    # kimi_k2: latent attention (q through a rank `q_lora_rank`, k and v
+    # through one of `kv_lora_rank` beside a shared RoPE'd key), the first
+    # `n_dense_layers` layers dense at `hidden_dim`, the others routed over
+    # `n_experts` published experts of which this file holds `experts_held`
+    # from `expert_first` on, beside `n_shared_experts` shared ones, all of
+    # width `moe_hidden_dim`
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    n_dense_layers: int = 0
+    experts_held: int = 0
+    expert_first: int = 0
+    n_shared_experts: int = 0
+    routed_scale: float = 1.0
     header_bytes: int = 0  # magic + size field + kv pairs
     file_bytes: int = 0
 
@@ -151,6 +195,10 @@ class ModelHeader:
     def layer_is_linear(self, layer: int) -> bool:
         return self.is_hybrid and (layer + 1) % self.full_attn_interval != 0
 
+    @property
+    def is_latent(self) -> bool:
+        return self.arch_type == ArchType.KIMI_K2
+
     def finalize(self, max_seq_len: int = 0) -> "ModelHeader":
         """Apply derived-field defaults (reference: src/llm.cpp:105-117)."""
         self.orig_seq_len = self.seq_len
@@ -160,6 +208,19 @@ class ModelHeader:
             self.head_dim = self.dim // self.n_heads
         if self.arch_type in (ArchType.QWEN3, ArchType.QWEN3_MOE, ArchType.OLMO_HYBRID):
             self.rope_type = RopeType.FALCON
+        if self.is_latent:
+            self.rope_type = RopeType.YARN
+            self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+            if not (self.q_lora_rank and self.kv_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError("kimi_k2: the header lacks latent attention's sizes")
+            if not 0 < self.experts_held <= self.n_experts - self.expert_first:
+                raise ValueError(
+                    f"kimi_k2: experts {self.expert_first}..+{self.experts_held} "
+                    f"are not among the {self.n_experts} published"
+                )
+            if not 0 <= self.n_dense_layers < self.n_layers or not self.moe_hidden_dim:
+                raise ValueError("kimi_k2: the header lacks the expert layers' sizes")
         if self.is_hybrid:
             if self.full_attn_interval < 2 or self.n_layers % self.full_attn_interval:
                 raise ValueError(
@@ -184,6 +245,8 @@ class TensorSpec:
     role: str  # embedding|q|k|v|wo|moe_gate|w1|w2|w3|q_norm|k_norm|norm0|norm1|final_norm|wcls
     # olmo_hybrid linear layers: lin_q|lin_k|lin_v|lin_g|lin_a|lin_b|lin_conv|
     # lin_a_log|lin_dt_bias|lin_o_norm|lin_wo
+    # kimi_k2: q_a|q_a_norm|q_b|kv_a|kv_a_norm|kv_b|wo, moe_gate|moe_bias,
+    # sw1|sw2|sw3 (the shared experts, as one of their summed width)
     layer: int  # -1 for global tensors
     expert: int  # -1 for non-expert tensors
     shape: tuple  # logical (out_features, in_features) or (n,) — torch row-major
@@ -251,12 +314,45 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
             add("lin_dt_bias", l, -1, (h.lin_value_heads,), FloatType.F32)
             add("lin_o_norm", l, -1, (h.lin_value_head_dim,), FloatType.F32)
             add("lin_wo", l, -1, (h.dim, hv), wt)
+        elif h.is_latent:
+            # latent attention: q through its rank and a norm; one
+            # projection to the latent c (normed) and the shared key's RoPE
+            # half; the latent's expansion to every head's k_nope | v
+            add("q_a", l, -1, (h.q_lora_rank, h.dim), wt)
+            add("q_a_norm", l, -1, (h.q_lora_rank,), FloatType.F32)
+            add("q_b", l, -1, (h.n_heads * h.head_dim, h.q_lora_rank), wt)
+            add("kv_a", l, -1, (h.kv_lora_rank + h.qk_rope_head_dim, h.dim), wt)
+            add("kv_a_norm", l, -1, (h.kv_lora_rank,), FloatType.F32)
+            add(
+                "kv_b", l, -1,
+                (h.n_heads * (h.qk_nope_head_dim + h.v_head_dim), h.kv_lora_rank), wt,
+            )
+            add("wo", l, -1, (h.dim, h.n_heads * h.v_head_dim), wt)
         else:
             add("q", l, -1, (h.q_dim, h.dim), wt)
             add("k", l, -1, (h.kv_dim, h.dim), wt)
             add("v", l, -1, (h.kv_dim, h.dim), wt)
             add("wo", l, -1, (h.dim, h.q_dim), wt)
-        if h.n_experts > 0:
+        if h.is_latent and l >= h.n_dense_layers:
+            # the router scores ALL the published experts (its selection
+            # bias beside it); the stacks hold this file's share alone,
+            # expert e of the file being published expert expert_first + e
+            ff = h.moe_hidden_dim
+            add("moe_gate", l, -1, (h.n_experts, h.dim), FloatType.F32)
+            add("moe_bias", l, -1, (h.n_experts,), FloatType.F32)
+            for e in range(h.experts_held):
+                add("w1", l, e, (ff, h.dim), wt)
+                add("w2", l, e, (h.dim, ff), wt)
+                add("w3", l, e, (ff, h.dim), wt)
+            sff = h.n_shared_experts * ff
+            add("sw1", l, -1, (sff, h.dim), wt)
+            add("sw2", l, -1, (h.dim, sff), wt)
+            add("sw3", l, -1, (sff, h.dim), wt)
+        elif h.is_latent:
+            add("w1", l, -1, (h.hidden_dim, h.dim), wt)
+            add("w2", l, -1, (h.dim, h.hidden_dim), wt)
+            add("w3", l, -1, (h.hidden_dim, h.dim), wt)
+        elif h.n_experts > 0:
             add("moe_gate", l, -1, (h.n_experts, h.dim), FloatType.F32)
             for e in range(h.n_experts):
                 add("w1", l, e, (h.ff_dim, h.dim), wt)
@@ -311,6 +407,17 @@ class MFileReader:
 
     def __exit__(self, *exc):
         self.close()
+
+    def release_pages(self) -> None:
+        """Tell the kernel the mapped pages are not needed for now (they are
+        file-backed and clean: nothing is lost, a later read faults them in
+        again). A no-op where the platform has no such advice."""
+        advice = getattr(mmap, "MADV_DONTNEED", None)
+        if advice is not None and hasattr(self._mm, "madvise"):
+            try:
+                self._mm.madvise(advice)
+            except OSError:  # dlt: allow(swallowed-exception) — advice, not a contract
+                pass
 
     def raw(self, spec: TensorSpec) -> memoryview:
         return memoryview(self._mm)[spec.offset : spec.offset + spec.n_bytes]
@@ -382,6 +489,20 @@ def _parse_header(buf, file_size: int) -> ModelHeader:
         K_LIN_VALUE_HEAD_DIM: lambda v: setattr(h, "lin_value_head_dim", v),
         K_LIN_CONV_KERNEL: lambda v: setattr(h, "lin_conv_kernel", v),
         K_LIN_NEG_EIGVAL: lambda v: setattr(h, "lin_neg_eigval", v),
+        K_Q_LORA_RANK: lambda v: setattr(h, "q_lora_rank", v),
+        K_KV_LORA_RANK: lambda v: setattr(h, "kv_lora_rank", v),
+        K_QK_NOPE_HEAD_DIM: lambda v: setattr(h, "qk_nope_head_dim", v),
+        K_QK_ROPE_HEAD_DIM: lambda v: setattr(h, "qk_rope_head_dim", v),
+        K_V_HEAD_DIM: lambda v: setattr(h, "v_head_dim", v),
+        K_YARN_BETA_FAST: lambda v: setattr(h, "yarn_beta_fast", float(v)),
+        K_YARN_BETA_SLOW: lambda v: setattr(h, "yarn_beta_slow", float(v)),
+        K_YARN_MSCALE_MILLI: lambda v: setattr(h, "yarn_mscale", v / 1000.0),
+        K_YARN_MSCALE_ALL_DIM_MILLI: lambda v: setattr(h, "yarn_mscale_all_dim", v / 1000.0),
+        K_N_DENSE_LAYERS: lambda v: setattr(h, "n_dense_layers", v),
+        K_EXPERTS_HELD: lambda v: setattr(h, "experts_held", v),
+        K_EXPERT_FIRST: lambda v: setattr(h, "expert_first", v),
+        K_N_SHARED_EXPERTS: lambda v: setattr(h, "n_shared_experts", v),
+        K_ROUTED_SCALE_MILLI: lambda v: setattr(h, "routed_scale", v / 1000.0),
     }
     for i in range(0, n_kv, 2):
         key, value = vals[i], vals[i + 1]

@@ -62,10 +62,39 @@ class ModelConfig:
     lin_value_dim: int = 0  # per head
     lin_conv_kernel: int = 0
     lin_neg_eigval: bool = False
+    # latent attention (kimi_k2): q through a rank-`q_lora_rank` pair, k and v
+    # through a rank-`kv_lora_rank` latent beside one RoPE'd key of
+    # `qk_rope_dim` that every head shares; the cache holds [latent | key]
+    # and nothing else. 0 = the model has ordinary q, k, v and every program
+    # is what it was before these fields existed
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    attn_scale: float = 0.0  # the softmax scale (YaRN's mscale^2 inside)
+    # expert layers that hold a SHARE of the published experts: the first
+    # `n_dense_layers` layers are dense at `hidden_dim`; the others route
+    # over all `n_experts`, sigmoid scores and a selection bias, and compute
+    # the experts `expert_first .. expert_first + n_experts_held - 1` of width
+    # `moe_hidden_dim` beside `n_shared_experts` shared ones. 0 held = the
+    # model's experts, if any, are Qwen3-MoE's (all held, softmax)
+    n_dense_layers: int = 0
+    n_experts_held: int = 0
+    expert_first: int = 0
+    n_shared_experts: int = 0
+    moe_hidden_dim: int = 0
+    routed_scale: float = 1.0
 
     @property
     def layer_kinds(self) -> tuple:
-        """("linear" | "full") per layer."""
+        """A name per layer: "linear" | "full" by the token mixer, or, where
+        the feed-forward is what differs (latent models), "dense" | "moe"."""
+        if self.is_latent:
+            return tuple(
+                "dense" if l < self.n_dense_layers else "moe"
+                for l in range(self.n_layers)
+            )
         p = self.full_attn_interval
         return tuple(
             "full" if p == 1 or (l + 1) % p == 0 else "linear"
@@ -85,6 +114,25 @@ class ModelConfig:
     @property
     def is_hybrid(self) -> bool:
         return self.n_rec_layers > 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """What a token keeps a layer: the latent and the shared key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_page_width(self) -> int:
+        """The width a page STORES a token at: `latent_width` in whole
+        128-lane tiles (576 -> 640; the tail holds zeros)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.n_experts_held else 0
 
     @property
     def lin_kdim(self) -> int:
@@ -173,4 +221,25 @@ def config_from_header(
         lin_value_dim=h.lin_value_head_dim,
         lin_conv_kernel=h.lin_conv_kernel,
         lin_neg_eigval=bool(h.lin_neg_eigval),
+        **(_latent_fields(h) if h.is_latent else {}),
+    )
+
+
+def _latent_fields(h: ModelHeader) -> dict:
+    from ..ops.rope import yarn_mscale
+
+    m = yarn_mscale(h.rope_scaling_factor, h.yarn_mscale_all_dim)
+    return dict(
+        q_lora_rank=h.q_lora_rank,
+        kv_lora_rank=h.kv_lora_rank,
+        qk_nope_dim=h.qk_nope_head_dim,
+        qk_rope_dim=h.qk_rope_head_dim,
+        v_head_dim=h.v_head_dim,
+        attn_scale=float(h.head_dim**-0.5 * m * m),
+        n_dense_layers=h.n_dense_layers,
+        n_experts_held=h.experts_held,
+        expert_first=h.expert_first,
+        n_shared_experts=h.n_shared_experts,
+        moe_hidden_dim=h.moe_hidden_dim,
+        routed_scale=float(h.routed_scale),
     )

@@ -62,10 +62,14 @@ class CacheAddr(NamedTuple):
     rec_row: Any = None  # scalar int32: the call's ONE batch row is slot
     # `rec_row` of the recurrent leaves (a paged admission prefill: b = 1
     # against the whole batch's state). None: batch row r is slot r.
+    latent: bool = False  # static: the pool's page is one [latent | key]
+    # vector a token (latent attention), not k and v heads
 
 
 def select_arm(addr: CacheAddr):
     """The arm for what `addr` carries — the one list of cache layouts."""
+    if addr.latent:
+        return latent_arm
     if addr.page_table is not None:
         return paged_arm
     if addr.sp_ctx is not None:
@@ -202,6 +206,25 @@ def _layer_view(buf, layer, b: int, n: int):
 # -- the arms ---------------------------------------------------------------
 
 
+def _page_write_index(cfg, page_table, positions, ps: int, n_pool: int):
+    """Where a paged write lands: (physical page [b, t], offset in it [b, t])
+    of each new row, (table[pos // ps], pos % ps). Invalid writes — parked
+    rows at/past seq_len, or an unmapped (-1) table entry — remap to
+    pairwise-distinct page indices past the pool and DROP (colliding dropped
+    indices would be undefined scatter behavior, the same discipline as
+    scatter_cache_update_sp)."""
+    b, t = positions.shape
+    max_slots = page_table.shape[1]
+    slot = positions // ps
+    offset = positions % ps
+    safe_slot = jnp.clip(slot, 0, max_slots - 1)
+    phys = jnp.take_along_axis(page_table, safe_slot, axis=1)  # [b, t]
+    invalid = (positions >= cfg.seq_len) | (slot >= max_slots) | (phys < 0)
+    b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+    col = jnp.arange(t, dtype=jnp.int32)[None, :]
+    return jnp.where(invalid, n_pool + b_idx * t + col, phys), offset
+
+
 def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     """Paged layout (runtime/paged_kv.py): the cache stacks are page POOLS
     [L, P, ps, h, d]; logical positions map through the per-row page table.
@@ -221,19 +244,7 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
         k, v = _pad_heads(k, pool_kv), _pad_heads(v, pool_kv)
         q_pool = _pad_heads(q, n_q // n_kv * pool_kv)
     max_slots = page_table.shape[1]
-    # write: scatter each new row to (table[pos // ps], pos % ps).
-    # Invalid writes — parked rows at/past seq_len, or an unmapped
-    # (-1) table entry — remap to pairwise-distinct page indices past
-    # the pool and DROP (colliding dropped indices would be undefined
-    # scatter behavior, the same discipline as scatter_cache_update_sp)
-    slot = positions // ps
-    offset = positions % ps
-    safe_slot = jnp.clip(slot, 0, max_slots - 1)
-    phys = jnp.take_along_axis(page_table, safe_slot, axis=1)  # [b, t]
-    invalid = (positions >= cfg.seq_len) | (slot >= max_slots) | (phys < 0)
-    b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-    col = jnp.arange(t, dtype=jnp.int32)[None, :]
-    phys = jnp.where(invalid, n_pool + b_idx * t + col, phys)
+    phys, offset = _page_write_index(cfg, page_table, positions, ps, n_pool)
     cache = _write(
         cache, k, v,
         lambda buf, rows: buf.at[li, phys, offset].set(
@@ -275,6 +286,38 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     if pool_kv > n_kv:
         k_view, v_view = k_view[:, :, :n_kv], v_view[:, :, :n_kv]
     return _attention_auto(cfg, q, k_view, v_view, positions, pos_start), cache
+
+
+def latent_arm(cfg, cache, addr, q, k, v, positions, pos_start):
+    """Latent attention's pool (runtime/paged_kv.py): `cache.k` is
+    [L, P, ps, W], ONE vector a token a layer, the normed latent and the
+    shared RoPE'd key side by side (W: `cfg.latent_page_width`, the tail
+    zeros), and there is no `cache.v`: the values are the latent itself. The
+    caller hands the ABSORBED query (q [b, t, H, W]: q_nope through W_uk, then
+    q_rope, then zeros) and this token's vector as k [b, t, 1, W]; what comes
+    back is [b, t, H, W], whose first `kv_lora_rank` are the probabilities'
+    sum over the latents (the caller expands it through W_uv). Writes and
+    reads go through the page table exactly as `paged_arm`'s; the read is the
+    gather arm in `jax.numpy`, prefill and decode alike. Float pools only."""
+    if addr.page_table is None:
+        raise NotImplementedError(
+            "latent attention keeps its cache in the paged pool only"
+        )
+    _float_only(cache, "latent")
+    li, ps, page_table = addr.layer, addr.page_size, addr.page_table
+    b = q.shape[0]
+    max_slots = page_table.shape[1]
+    phys, offset = _page_write_index(cfg, page_table, positions, ps, cache.k.shape[1])
+    cache = replace(
+        cache,
+        k=cache.k.at[li, phys, offset].set(
+            k[:, :, 0].astype(cache.k.dtype), mode="drop", unique_indices=True
+        ),
+    )
+    n_read = max_slots if addr.kv_len is None else min(-(-addr.kv_len // ps), max_slots)
+    pages = jnp.maximum(jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0)
+    view = cache.k[li, pages].reshape(b, n_read * ps, 1, cache.k.shape[-1])
+    return gqa_attention(q, view, view, positions, scale=cfg.attn_scale), cache
 
 
 def stacked_arm(cfg, cache, addr, q, k, v, positions, pos_start):

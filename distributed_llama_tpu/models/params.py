@@ -62,6 +62,47 @@ _register(GdnParams, ["wqkvg", "wab", "conv", "a_log", "dt_bias", "o_norm", "wo"
 
 
 @dataclass
+class MlaParams:
+    """Latent attention's weights (kimi_k2; models/kv_arms.latent_arm),
+    stacked over all layers. q: x -> rank -> norm -> heads of [nope | rope];
+    k, v: x -> [latent | shared key's rope half], the latent normed and
+    expanded per head by `w_uk` / `w_uv`, which the absorbed form multiplies
+    into the query and the output instead of into every cached token."""
+
+    wqkva: Weight  # [L, q_rank + page_width, dim]: q_a | kv_a, fused; kv_a's
+    # out padded with zero rows to the page's width (config.latent_page_width)
+    q_norm: jnp.ndarray  # [L, q_rank]
+    wqb: Weight  # [L, H * (nope + rope), q_rank]
+    kv_norm: jnp.ndarray  # [L, kv_rank]
+    w_uk: jnp.ndarray  # [L, H, nope, kv_rank] compute dtype: kv_b's k_nope rows
+    w_uv: jnp.ndarray  # [L, H, v_dim, kv_rank] compute dtype: kv_b's v rows.
+    # Both DEQUANTIZED at load: absorbed, kv_b contracts over its OUT axis,
+    # across Q40's blocks of 32 along `in`, so no Q40 kernel serves it
+    wo: Weight  # [L, dim, H * v_dim]
+
+
+_register(MlaParams, ["wqkva", "q_norm", "wqb", "kv_norm", "w_uk", "w_uv", "wo"])
+
+
+@dataclass
+class ExpertParams:
+    """The expert layers' feed-forward (kimi_k2), stacked over those layers
+    alone ([Lm, ...]): the router over ALL published experts, the stacks of
+    the experts HELD, the shared experts as one dense SwiGLU."""
+
+    gate: jnp.ndarray  # [Lm, E, dim] f32
+    bias: jnp.ndarray  # [Lm, E] f32: added to the scores to PICK, never to weigh
+    w1: Weight  # [Lm, Eh, ff, dim]
+    w3: Weight  # [Lm, Eh, ff, dim]
+    w2: Weight  # [Lm, Eh, dim, ff]
+    s13: Weight  # [Lm, 2 * shared ff, dim]: the shared experts' w1 | w3
+    s2: Weight  # [Lm, dim, shared ff]
+
+
+_register(ExpertParams, ["gate", "bias", "w1", "w3", "w2", "s13", "s2"])
+
+
+@dataclass
 class LayerParams:
     """Per-layer weights, each stacked with a leading [n_layers] axis.
 
@@ -94,12 +135,17 @@ class LayerParams:
     wqkv: Optional[Weight] = None  # [L, q_dim+2*kv_dim, dim] fused projection
     w13: Optional[Weight] = None  # [L, 2*ff, dim] fused dense ffn in-proj
     gdn: Optional[GdnParams] = None  # the linear-attention layers' mixers
+    # a latent model (cfg.is_latent): `mla` holds attention (wo too; q, k, v,
+    # wqkv and this class's wo are None), w13 / w2 the leading DENSE layers
+    # alone ([n_dense_layers, ...]), `experts` the other layers' feed-forward
+    mla: Optional[MlaParams] = None
+    experts: Optional[ExpertParams] = None
 
 
 _register(
     LayerParams,
     ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1", "q_norm", "k_norm",
-     "moe_gate", "wqkv", "w13", "gdn"],
+     "moe_gate", "wqkv", "w13", "gdn", "mla", "experts"],
 )
 
 
@@ -125,7 +171,8 @@ class KVCache:
     """
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: Optional[jnp.ndarray]  # None: a latent pool (k holds [latent | key]
+    # [L, pages, ps, page_width], and the values are read out of it)
     # int8 KV arm (cache_dtype="int8"): per-(token, head) f32 dequant scales,
     # shaped like k/v minus the trailing head_dim axis. None on bf16/f32
     # engines — None children flatten away, so the float arms' leaf set (and
@@ -139,6 +186,14 @@ class KVCache:
     # inputs in the compute dtype. None on every other model (flattens away).
     rec: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
+    # a model that holds a share of its experts counts what its expert layers
+    # did, on the device, in the value every program already carries and
+    # returns: [2, 2] int32 running sums (they wrap; readers take differences),
+    # row 0 the decode steps' (one position a row), row 1 the prompt chunks';
+    # column 0 `expert_pairs`, the (token, expert) pairs that landed on held
+    # experts, column 1 `experts_hit`, the held experts with at least one
+    # pair, each summed over expert layers and calls. None on every other model
+    moe: Optional[jnp.ndarray] = None
 
     @property
     def batch(self) -> int:
@@ -153,7 +208,7 @@ class KVCache:
         return self.k_scale is not None
 
 
-_register(KVCache, ["k", "v", "k_scale", "v_scale", "rec", "conv"])
+_register(KVCache, ["k", "v", "k_scale", "v_scale", "rec", "conv", "moe"])
 
 
 def init_rec_state(cfg: ModelConfig, rows: int) -> dict:
@@ -291,6 +346,8 @@ def load_params(
 
     if cfg.is_hybrid:
         return _load_hybrid(reader, cfg, dense)
+    if cfg.is_latent:
+        return _load_latent(reader, cfg, dense)
 
     roles = ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1"]
     if cfg.is_qwen3:
@@ -398,6 +455,110 @@ def _load_hybrid(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
         norm0=put([one("norm0", l) for l in every]),
         norm1=put([one("norm1", l) for l in every]),
         gdn=gdn,
+    )
+    return ModelParams(
+        embedding=_put(_load_one(reader, reader.by_name["embedding"], np.float32)),
+        layers=layers,
+        final_norm=_put(_load_one(reader, reader.by_name["final_norm"], dense)),
+        wcls=_put(_load_one(reader, reader.by_name["wcls"], dense)),
+    )
+
+
+def _pad_out(part, out: int):
+    """A host weight with zero OUTPUT rows appended up to `out`: a T-layout
+    Q40 pair (qp [nb*4, out], dt [nb, out]; zero scales under the code for
+    0) or a dense [out, in]."""
+    if not isinstance(part, tuple):
+        return np.pad(part, ((0, out - part.shape[0]), (0, 0)))
+    qp, dt = part
+    pad = out - dt.shape[1]
+    zero_words = np.full((qp.shape[0], pad), 0x88888888, np.uint32).view(np.int32)
+    return (
+        np.concatenate([qp, zero_words], axis=1),
+        np.concatenate([dt, np.zeros((dt.shape[0], pad), dt.dtype)], axis=1),
+    )
+
+
+def _expert_stack(reader: MFileReader, role: str, layers, n_held: int, dense):
+    """One role's held experts of every expert layer as ONE host value
+    [layers, held, ...], each expert repacked straight into its place: the
+    stacks are four fifths of such a file, and lists of per-expert arrays
+    stacked twice would hold them three times over. The repack (a transpose of
+    4-byte words) runs on a few threads: numpy copies without the GIL."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    spec = reader.by_name[f"{role}.l{layers[0]}.e0"]
+    if spec.float_type != FloatType.Q40:
+        return np.stack([
+            np.stack([_load_one(reader, reader.by_name[f"{role}.l{l}.e{e}"], dense)
+                      for e in range(n_held)]) for l in layers
+        ])
+    out_f, in_f = spec.shape
+    q = np.empty((len(layers), n_held, in_f // 8, out_f), np.int32)
+    d = np.empty((len(layers), n_held, in_f // 32, out_f), np.float16)
+
+    def fill(at):
+        i, e = at
+        q[i, e], d[i, e] = q40_raw_to_t_layout(
+            reader.raw(reader.by_name[f"{role}.l{layers[i]}.e{e}"]), out_f, in_f
+        )
+
+    with ThreadPoolExecutor(8) as pool:
+        list(pool.map(fill, [(i, e) for i in range(len(layers)) for e in range(n_held)]))
+    return q, d
+
+
+def _load_latent(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
+    """kimi_k2: latent attention over all layers, a dense feed-forward over
+    the leading layers, the experts' over the others. Single chip only (the
+    engine refuses a mesh for this architecture)."""
+
+    def one(role, l, dtype=dense):
+        return _load_one(reader, reader.by_name[f"{role}.l{l}"], dtype)
+
+    def experts_of(role):
+        return _put(_expert_stack(reader, role, moe_l, cfg.n_experts_held, dense))
+
+    every = range(cfg.n_layers)
+    dense_l = range(cfg.n_dense_layers)
+    moe_l = range(cfg.n_dense_layers, cfg.n_layers)
+    put = lambda parts: _put(_stack(parts))  # noqa: E731
+    f32 = np.float32
+    H, nope, vd, r = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
+
+    def kv_b(l):
+        # [H * (nope + v), rank] -> per head, k_nope's rows then v's
+        spec = reader.by_name[f"kv_b.l{l}"]
+        return reader.tensor_f32(spec).reshape(H, nope + vd, r).astype(dense)
+
+    kvb = [kv_b(l) for l in every]
+    mla = MlaParams(
+        wqkva=put([
+            _fuse_rows([one("q_a", l), _pad_out(one("kv_a", l), cfg.latent_page_width)], 1)
+            for l in every
+        ]),
+        q_norm=put([one("q_a_norm", l) for l in every]),
+        wqb=put([one("q_b", l) for l in every]),
+        kv_norm=put([one("kv_a_norm", l) for l in every]),
+        w_uk=put([w[:, :nope] for w in kvb]),
+        w_uv=put([w[:, nope:] for w in kvb]),
+        wo=put([one("wo", l) for l in every]),
+    )
+    experts = ExpertParams(
+        gate=put([one("moe_gate", l, f32) for l in moe_l]),
+        bias=put([one("moe_bias", l, f32) for l in moe_l]),
+        w1=experts_of("w1"), w3=experts_of("w3"), w2=experts_of("w2"),
+        s13=put([_fuse_rows([one("sw1", l), one("sw3", l)], 1) for l in moe_l]),
+        s2=put([one("sw2", l) for l in moe_l]),
+    )
+    layers = LayerParams(
+        q=None, k=None, v=None, wo=None, w1=None, w3=None,
+        w13=put([_fuse_rows([one("w1", l), one("w3", l)], 1) for l in dense_l])
+        if cfg.n_dense_layers else None,
+        w2=put([one("w2", l) for l in dense_l]) if cfg.n_dense_layers else None,
+        norm0=put([one("norm0", l) for l in every]),
+        norm1=put([one("norm1", l) for l in every]),
+        mla=mla, experts=experts,
     )
     return ModelParams(
         embedding=_put(_load_one(reader, reader.by_name["embedding"], np.float32)),

@@ -1,4 +1,4 @@
-"""The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE / Olmo-Hybrid).
+"""The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE / Olmo-Hybrid / Kimi-K2).
 
 Functional re-design of the reference's per-node op graph (reference:
 buildLlmNet, src/llm.cpp:152-649). One layer body is `lax.scan`ned over
@@ -27,11 +27,19 @@ sits on each sub-layer's OUTPUT and there is none before it:
 
 with mixer = attention over a q/k normed across the whole projection, or the
 gated delta rule (`_gdn_mixer`).
+
+Kimi-K2 (the DeepSeek-V3 block; no reference analogue; `_latent_layers`):
+pre-norm residual blocks, latent attention in every layer (`_latent_attention`),
+a dense feed-forward in the leading layers and, in the others, sigmoid-routed
+experts of which this model file HOLDS a share, beside the shared experts
+(`_held_expert_ffn`).
 """
 
 from __future__ import annotations
 
+from dataclasses import replace
 from functools import partial
+from types import SimpleNamespace
 from typing import Any
 
 import jax
@@ -40,6 +48,7 @@ import jax.numpy as jnp
 from ..formats.mfile import HiddenAct
 from ..ops import moe_router, rms_norm
 from ..ops.activations import gelu, silu
+from ..ops.moe import moe_ffn_held, moe_router_sigmoid
 from ..ops.quant import QuantTensor, dequantize_t, quant_matmul, quantize_q80_activations
 from ..ops.rope import RopeTables, apply_rope
 from .config import ModelConfig
@@ -421,6 +430,111 @@ def _hybrid_layers(cfg, params, rope, x, cache, positions, pos_start, valid, add
     return x, cache
 
 
+def _latent_attention(cfg, rope, y, mp, cache, addr, li, positions, pos_start):
+    """Latent attention of the normed activation y [b, t, dim] for layer `li`
+    of the `mp` stack, in the ABSORBED form: the per-head expansion of the
+    latent (W_uk, W_uv) multiplies into the query and into the weighted sum,
+    so every head attends over the one vector a token that the cache holds
+    (kv_arms.latent_arm). Equal to expanding k_nope and v for every cached
+    token (the published form; the benchmark's reference does that). Returns
+    (att [b, t, dim] before the residual, cache)."""
+    b, t, _ = y.shape
+    q80, eps = cfg.q80_activations, cfg.norm_epsilon
+    H, nope, rd, rank = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
+    width = cfg.latent_page_width
+    qkv = linear(y, mp.wqkva, cfg.dtype, cfg.pallas_arg, q80, li)
+    c_q = rms_norm(qkv[..., : cfg.q_lora_rank], _sel_layer(mp.q_norm, li), eps)
+    q = linear(c_q, mp.wqb, cfg.dtype, cfg.pallas_arg, q80, li).reshape(b, t, H, nope + rd)
+    row = qkv[..., cfg.q_lora_rank :]  # [latent | key's rope half | zeros]
+    c_kv = rms_norm(row[..., :rank], _sel_layer(mp.kv_norm, li), eps)
+    k_rope = apply_rope(row[..., None, rank : rank + rd], rope, positions, cfg.rope_type)
+    q_rope = apply_rope(q[..., nope:], rope, positions, cfg.rope_type)
+    precision = jax.lax.Precision.HIGHEST if cfg.dtype == jnp.float32 else None
+    # both absorbed products give the compute dtype (the MXU sums in float32
+    # and rounds once): their results enter another product at that dtype
+    q_abs = jnp.einsum(
+        "bthn,hnr->bthr", q[..., :nope].astype(cfg.dtype), _sel_layer(mp.w_uk, li),
+        precision=precision,
+    )
+    tail = width - rank - rd
+    q_lat = jnp.concatenate(
+        [q_abs, q_rope.astype(cfg.dtype), jnp.zeros((b, t, H, tail), cfg.dtype)], axis=-1
+    )
+    k_lat = jnp.concatenate(
+        [c_kv[..., None, :], k_rope, jnp.zeros((b, t, 1, tail), jnp.float32)], axis=-1
+    )
+    a_addr = addr._replace(layer=li, latent=True)
+    o_lat, cache = select_arm(a_addr)(
+        cfg, cache, a_addr, q_lat, k_lat, None, positions, pos_start
+    )
+    o = jnp.einsum(
+        "bthr,hvr->bthv", o_lat[..., :rank], _sel_layer(mp.w_uv, li),
+        precision=precision,
+    )
+    o = o.reshape(b, t, H * cfg.v_head_dim).astype(y.dtype)
+    return linear(o, mp.wo, cfg.dtype, cfg.pallas_arg, q80, li), cache
+
+
+def _held_expert_ffn(cfg, y, ep, mi):
+    """The feed-forward of expert layer `mi` of the `ep` stack on the normed
+    activation y: the published gate over ALL experts, the held experts' part
+    of the routed sum (ops/moe.moe_ffn_held), and the shared experts, which
+    every chip of the deployment computes alike. Returns (out, stats [2])."""
+    idx, wts = moe_router_sigmoid(
+        y, _sel_layer(ep.gate, mi), _sel_layer(ep.bias, mi),
+        cfg.n_active_experts, cfg.routed_scale,
+    )
+    routed, stats = moe_ffn_held(
+        y, idx, wts, ep.w1, ep.w3, ep.w2, cfg.expert_first, mi,
+        partial(_activation, cfg), cfg.dtype, q80=cfg.q80_activations,
+        pallas=cfg.pallas_arg,
+    )
+    shared = _dense_ffn(
+        cfg, y, SimpleNamespace(w13=ep.s13, w2=ep.s2, w1=None, w3=None), mi
+    )
+    return routed + shared, stats
+
+
+def _latent_layers(cfg, params, rope, x, cache, positions, pos_start, addr):
+    """A latent model's layer stack (kimi_k2): the leading dense layers one
+    call each, then ONE scan over the expert layers. Pre-norm residual blocks,
+    latent attention in every layer; weights stay stacked by kind and are
+    selected inside the kernels (attention and norms by layer, the dense
+    feed-forward by its index among the dense layers, the experts by theirs).
+    The cache rides the carry, and with it the expert layers' two counters
+    (`KVCache.moe`): row 0 when the call is a decode step, row 1 otherwise."""
+    lp = params.layers
+    eps = cfg.norm_epsilon
+    counts_row = 0 if x.shape[1] == 1 else 1
+
+    def attn_block(x, cache, li):
+        y = rms_norm(x, _sel_layer(lp.norm0, li), eps)
+        a, cache = _latent_attention(
+            cfg, rope, y, lp.mla, cache, addr, li, positions, pos_start
+        )
+        return x + a.astype(x.dtype), cache
+
+    for li in range(cfg.n_dense_layers):
+        li = jnp.int32(li)
+        x, cache = attn_block(x, cache, li)
+        y = rms_norm(x, _sel_layer(lp.norm1, li), eps)
+        x = x + _dense_ffn(cfg, y, lp, li).astype(x.dtype)
+
+    def body(carry, mi):
+        x, cache = carry
+        li = mi + cfg.n_dense_layers
+        x, cache = attn_block(x, cache, li)
+        y = rms_norm(x, _sel_layer(lp.norm1, li), eps)
+        h, stats = _held_expert_ffn(cfg, y, lp.experts, mi)
+        cache = replace(cache, moe=cache.moe.at[counts_row].add(stats))
+        return (x + h.astype(x.dtype), cache), None
+
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache), jnp.arange(cfg.n_moe_layers, dtype=jnp.int32)
+    )
+    return x, cache
+
+
 def forward_uncompiled(
     cfg: ModelConfig,
     params: ModelParams,
@@ -481,6 +595,10 @@ def forward_uncompiled(
     if cfg.is_hybrid:
         x, new_cache = _hybrid_layers(
             cfg, params, rope, x, cache, positions, pos_start, valid, addr
+        )
+    elif cfg.is_latent:
+        x, new_cache = _latent_layers(
+            cfg, params, rope, x, cache, positions, pos_start, addr
         )
     else:
         layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)

@@ -68,6 +68,30 @@ def moe_router(
     return top_i.astype(jnp.int32), top_p
 
 
+def moe_router_sigmoid(
+    x: jnp.ndarray, gate: jnp.ndarray, bias: jnp.ndarray, n_active: int,
+    scale: float = 1.0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """DeepSeek-V3's gate with one group (`noaux_tc`, n_group = topk_group
+    = 1), which Kimi-K2 publishes: sigmoid scores, the top `n_active` of
+    score + bias PICKED, the picked weighed by their scores WITHOUT the bias,
+    normalised over the picked (1e-20 under the sum) and scaled.
+
+    x: [..., dim]; gate: [n_experts, dim] f32; bias: [n_experts] f32.
+    Returns (indices [..., n_active] int32, weights [..., n_active] f32)."""
+    logits = jnp.einsum(
+        "...d,ed->...e",
+        x.astype(jnp.float32),
+        gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), n_active)
+    w = jnp.take_along_axis(scores, top_i, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return top_i.astype(jnp.int32), w
+
+
 def expert_stack_matrix(w, dtype) -> jnp.ndarray:
     """[E, in, out] dense matrix from a stacked expert weight — QuantTensor
     in the T layout (via quant.dequantize_t) or dense [E, out, in]. The
@@ -153,6 +177,17 @@ def _grouped_layout_direct(g_flat: jnp.ndarray, n_groups: int, block_r: int):
         n_groups - 1,
     )
     return dest, block_expert, R_pad
+
+
+def _block_rows(rows: int, n_groups: int) -> int:
+    """Rows of a row block of the grouped layouts: about rows / n_groups in
+    powers of two from 8 to 64 (small blocks waste less tail padding, large
+    ones re-read an expert less often across a big group's blocks)."""
+    avg = max(1, rows // max(n_groups, 1))
+    block_r = 8
+    while block_r * 2 <= min(avg, 64):
+        block_r *= 2
+    return block_r
 
 
 def _grouped_quant_eligible(w1, w3, w2, dtype, q80: bool, pallas) -> bool:
@@ -283,10 +318,7 @@ def moe_ffn_ragged(
         # block_r trades tail-padding waste (small blocks) against expert
         # weight re-reads across row blocks (large groups split into many
         # blocks re-stream the same expert): target ~rows/n_groups, clamped
-        avg = max(1, rows // max(n_groups, 1))
-        block_r = 8
-        while block_r * 2 <= min(avg, 64):
-            block_r *= 2
+        block_r = _block_rows(rows, n_groups)
         dest, block_expert, R_pad = _grouped_layout_direct(g_flat, n_groups, block_r)
         xrep = jnp.repeat(y.reshape(n_tok, dim), k, axis=0)  # row r = token r//k
         xp = jnp.zeros((R_pad, dim), y.dtype).at[dest].set(xrep.astype(y.dtype))
@@ -353,3 +385,120 @@ def moe_ffn_ragged(
     if ep_axis is not None:
         out = jax.lax.psum(out, ep_axis)
     return out.reshape(b, t, dim).astype(y.dtype)
+
+
+
+
+def held_layout(local: jnp.ndarray, n_held: int, block_r: int):
+    """`_grouped_layout_direct` for a layer that holds a share of the
+    experts. `local` [rows] int32: a pair's expert as this layer numbers its
+    own (0 .. n_held-1), anything else for a pair routed elsewhere. Those
+    pairs get NO row: their `dest` lies past the buffer (a scatter with
+    mode="drop" leaves them out) and no group stands in for them.
+
+    Returns (dest [rows], block_expert [n_blocks], n_live, counts [n_held],
+    R_pad). The first `n_live` = sum(ceil(counts / block_r)) row blocks hold
+    every held pair, and a kernel told `n_live` runs those blocks alone.
+    R_pad is the static bound: every pair could land here."""
+    rows = local.shape[0]
+    R_pad = _padded_rows_bound(rows, n_held, block_r)
+    oh = (local[:, None] == jnp.arange(n_held, dtype=local.dtype)).astype(jnp.int32)
+    within = jnp.sum(jnp.cumsum(oh, axis=0) * oh, axis=1) - 1  # stable rank
+    counts = jnp.sum(oh, axis=0)
+    padded_sizes = ((counts + block_r - 1) // block_r) * block_r
+    padded_ends = jnp.cumsum(padded_sizes.astype(jnp.int32))
+    padded_starts = padded_ends - padded_sizes
+    held = (local >= 0) & (local < n_held)
+    dest = jnp.where(
+        held, padded_starts[jnp.clip(local, 0, n_held - 1)] + within, R_pad
+    ).astype(jnp.int32)
+    n_live = padded_ends[-1] // block_r
+    blocks = jnp.arange(R_pad // block_r, dtype=jnp.int32) * block_r
+    # the group whose padded span holds the block's first row; empty groups
+    # share their start with the next one, and side="right" steps over them
+    block_expert = jnp.clip(
+        jnp.searchsorted(padded_starts, blocks, side="right").astype(jnp.int32) - 1,
+        0, n_held - 1,
+    )
+    return dest, block_expert, n_live.astype(jnp.int32), counts, R_pad
+
+
+def moe_ffn_held(
+    y: jnp.ndarray,  # [b, t, dim] normed activations
+    idx: jnp.ndarray,  # [b, t, k] int32 expert ids over ALL published experts
+    wts: jnp.ndarray,  # [b, t, k] f32 combine weights (normalised over all k)
+    w1, w3, w2,  # the HELD experts' all-layers stacks [Lm, Eh, ...]
+    expert_first: int,  # published id of the stacks' expert 0
+    layer,  # scalar int32 index into the stacks' leading axis
+    act_fn, dtype, q80: bool = False, pallas=None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of a routed feed-forward that the held experts give: sum over
+    the picked experts held here of w_i W2_i(act(W1_i y) * W3_i y). A pair
+    routed to an expert that lives elsewhere is DROPPED before the grouped
+    layout: no row, no matmul, nothing in its stead (what the absent experts
+    would add is left out, as on the chip of a deployment that holds these).
+
+    Grouped kernel or gather? By the pairs and experts held: the grouped
+    call reads every expert HIT once (at most min(pairs, held)), the gather
+    one expert a PAIR, so the grouped call never reads more; it is taken
+    wherever its kernel is eligible, and the dequantizing `ragged_dot`
+    formulation (the float32 parity path) elsewhere.
+
+    Returns (out [b, t, dim] in y's dtype, stats [2] int32: the pairs that
+    landed on held experts, the held experts with at least one)."""
+    b, t, dim = y.shape
+    k = idx.shape[-1]
+    n_tok, rows = b * t, b * t * k
+    n_held = w1.q.shape[1] if isinstance(w1, QuantTensor) else w1.shape[1]
+    local = idx.reshape(rows) - expert_first
+    held = (local >= 0) & (local < n_held)
+    w_flat = jnp.where(held, wts.reshape(rows), 0.0).astype(jnp.float32)
+
+    if _grouped_quant_eligible(w1, w3, w2, dtype, q80, pallas):
+        from .pallas_q40 import q40_matmul_pallas_grouped
+
+        block_r = _block_rows(rows, n_held)
+        dest, block_expert, n_live, counts, R_pad = held_layout(local, n_held, block_r)
+        xrep = jnp.repeat(y.reshape(n_tok, dim), k, axis=0)  # row r = token r//k
+        xp = jnp.zeros((R_pad, dim), y.dtype).at[dest].set(xrep, mode="drop")
+        # the layer folds into the FLAT group index, as in moe_ffn_ragged
+        block_expert = block_expert + layer * n_held
+
+        def gdot(x_, w_):
+            return q40_matmul_pallas_grouped(
+                x_, w_.q, w_.d, block_expert, block_r, dtype=dtype,
+                interpret=pallas == "interpret", n_live=n_live,
+            )
+
+        h = (act_fn(gdot(xp, w1)) * gdot(xp, w3)).astype(y.dtype)
+        # rows past the live blocks were never written: select, do not weigh
+        per_row = jnp.where(
+            held[:, None], gdot(h, w2)[jnp.minimum(dest, R_pad - 1)], 0.0
+        )
+        out = jnp.sum((per_row * w_flat[:, None]).reshape(n_tok, k, dim), axis=1)
+    else:
+        # parity paths: the held pairs sorted by expert ahead of the dropped
+        # ones, `lax.ragged_dot` over the held groups
+        w1, w3, w2 = (slice_layer(w, layer) for w in (w1, w3, w2))
+        g = jnp.where(held, local, n_held)
+        order = jnp.argsort(g, stable=True)
+        tok = order // k
+        xs = y.reshape(n_tok, dim)[tok]
+        counts = jnp.bincount(g, length=n_held + 1)[:n_held].astype(jnp.int32)
+        precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+        def rdot(x_, w_):
+            return jax.lax.ragged_dot(
+                x_.astype(dtype), expert_stack_matrix(w_, dtype), counts,
+                precision=precision, preferred_element_type=jnp.float32,
+            )
+
+        xq = quantize_q80_activations(xs) if q80 else xs
+        h = (act_fn(rdot(xq, w1)) * rdot(xq, w3)).astype(y.dtype)
+        hq = quantize_q80_activations(h) if q80 else h
+        out_rows = jnp.where(held[order][:, None], rdot(hq, w2), 0.0)
+        out = jnp.zeros((n_tok, dim), jnp.float32).at[tok].add(
+            out_rows * w_flat[order][:, None]
+        )
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0)]).astype(jnp.int32)
+    return out.reshape(b, t, dim).astype(y.dtype), stats

@@ -701,6 +701,13 @@ def q40_matmul_pallas_grouped(
     block_r: int,
     dtype=jnp.bfloat16,
     interpret: bool = False,
+    n_live: jnp.ndarray | None = None,  # scalar int32: only the first n_live
+    # row blocks hold rows (ops/moe.py `held_layout`: a layer that holds a
+    # share of the experts bounds its rows by ALL the pairs and fills what
+    # landed on it). The grid then has n_live row blocks and no more: the
+    # blocks past them cost nothing, and their rows of the result are never
+    # written (the caller must not read them). None: every block is live
+    # (the program this was before the argument)
 ) -> jnp.ndarray:
     """Grouped (ragged) quantized matmul: row block i is multiplied by
     group block_expert[i]'s weight, streamed from HBM as int8 — the MoE
@@ -759,6 +766,12 @@ def q40_matmul_pallas_grouped(
     qt2 = qt.reshape(E * rows4, out)
     dt3 = dt.reshape(E * nb, out)
     grid = (R_pad // block_r, out // tile_n, k_steps)
+    if n_live is not None:
+        # a grid as long as the live blocks: the row axis is the call's own
+        # count, not the static bound (a no-op grid step still costs its
+        # 0.3-0.4 us, and at 32 decoding rows 51 of the bound's 74 row blocks
+        # are dead: 3 ms a step over 7 layers' three calls)
+        grid = (jnp.asarray(n_live, jnp.int32), out // tile_n, k_steps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,

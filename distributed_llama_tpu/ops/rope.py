@@ -67,8 +67,52 @@ def _scale_frequency_llama3(
     return (1 - smooth) * freq / scaling_factor + smooth * freq
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 mscale ln(factor) + 1 (1 where the
+    context is not extended)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(
+    dim: int, theta: float, factor: float, beta_fast: float, beta_slow: float,
+    orig_max_seq_len: int,
+) -> np.ndarray:
+    """YaRN's per-pair frequencies (Peng et al., arXiv:2309.00071, as the
+    DeepSeek-V3 modelling code has them): pair i keeps theta^(-2i/dim) where
+    it turns more than `beta_fast` times over the original context, takes
+    that over `factor` where it turns fewer than `beta_slow` times, and a
+    linear blend between the two correction dims."""
+    i = np.arange(dim // 2, dtype=np.float64)  # dlt: allow(float64) — host-side precompute; cast to f32 before device
+    extra = theta ** (-2.0 * i / dim)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(orig_max_seq_len / (rotations * 2.0 * math.pi)) / (
+            2.0 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((i - low) / ((high + 0.001 if high == low else high) - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
 def build_rope_tables(h: ModelHeader) -> RopeTables:
     """Precompute per-position cos/sin for all pair indices of one head."""
+    if h.rope_type == RopeType.YARN:
+        # latent attention rotates its `qk_rope_head_dim` dims alone
+        freqs = yarn_frequencies(
+            h.qk_rope_head_dim, h.rope_theta, h.rope_scaling_factor,
+            h.yarn_beta_fast, h.yarn_beta_slow, h.rope_scaling_orig_max_seq_len,
+        )
+        scale = yarn_mscale(h.rope_scaling_factor, h.yarn_mscale) / yarn_mscale(
+            h.rope_scaling_factor, h.yarn_mscale_all_dim
+        )
+        pos = np.arange(h.seq_len, dtype=np.float64)[:, None]  # dlt: allow(float64) — host-side; angles cast to f32 below
+        angles = (pos * freqs[None, :]).astype(np.float32)
+        return RopeTables(
+            cos=jnp.asarray(np.cos(angles) * np.float32(scale)),
+            sin=jnp.asarray(np.sin(angles) * np.float32(scale)),
+        )
     half = h.head_dim // 2
     freqs = np.empty(half, dtype=np.float64)  # dlt: allow(float64) — host-side precompute; cast to f32 before device
     # scaling is gated on the factor alone, matching the reference
@@ -126,7 +170,7 @@ def apply_rope_falcon(
 def apply_rope(
     x: jnp.ndarray, tables: RopeTables, positions: jnp.ndarray, rope_type: int
 ) -> jnp.ndarray:
-    if rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1):
+    if rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1, RopeType.YARN):
         return apply_rope_llama(x, tables, positions)
     if rope_type == RopeType.FALCON:
         return apply_rope_falcon(x, tables, positions)

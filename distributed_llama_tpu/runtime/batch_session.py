@@ -177,6 +177,13 @@ class BatchSession:
         # Batcher sets it) — step()/spec_step() enter step.dispatch and
         # step.fetch on it; None = nobody partitions this thread's time
         self.phases = None
+        # a model that holds a share of its experts counts what its expert
+        # layers did on the device (`KVCache.moe`): `step` fetches the running
+        # sums with the chunk's tokens and leaves the difference since the
+        # last fetch here, [[decode pairs, decode experts hit], [the prompt
+        # chunks' since then, likewise]]; None on every other model
+        self.moe_counts = None
+        self._moe_seen = None
         engine.reset()
 
     def free_rows(self) -> list[int]:
@@ -594,6 +601,13 @@ class BatchSession:
             # .copy(): the fetched view of a device array is READ-ONLY, and
             # admit writes rows into these between chunks
             self.keys = eng._host_fetch(keys).copy()
+            if eng.cache.moe is not None:
+                seen = eng._host_fetch(eng.cache.moe).astype(np.int64)
+                if self._moe_seen is not None:
+                    # int32 sums that wrap: a chunk's difference is far
+                    # under 2**31, so it survives the wrap
+                    self.moe_counts = (seen - self._moe_seen) % (1 << 32)
+                self._moe_seen = seen
         # whole-chunk wall (dispatch + fetch): the batched serving path's
         # per-program series — /stats latency numbers and the roofline join
         # (profiling.roofline_view) read it exactly like solo decode[n]

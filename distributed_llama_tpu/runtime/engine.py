@@ -298,11 +298,18 @@ class InferenceEngine:
         )
         if q80_activations:
             self.cfg = self.cfg.with_(q80_activations=True)
-        if self.cfg.is_hybrid:
-            # a recurrent state cannot be cut at a token position: what
-            # assumes KV can (below, and the prefix cache further down) is
-            # refused for this architecture, not served without it
+        if self.cfg.is_hybrid or self.cfg.is_latent:
+            # what assumes a cache can be cut in ways these architectures'
+            # caches have not been taught (below, and the prefix cache further
+            # down) is refused, not served without it: a recurrent state
+            # cannot be cut at a token position; the latent page ([latent |
+            # key], one vector a token) and the held-experts layer are taught
+            # to one chip's paged float pool and the Batcher's programs
+            from .paged_kv import resolve_kv_layout as _layout
             from .speculative import resolve_spec_mode as _spec
+
+            if self.cfg.is_latent and kv_layout is None and not os.environ.get("DLT_KV_LAYOUT"):
+                kv_layout = "paged"  # where nobody chose: the one layout it has
 
             refused = [
                 what for what, asked in (
@@ -310,13 +317,22 @@ class InferenceEngine:
                     ("int8 KV (--kv-dtype int8)", cache_dtype == "int8"),
                     ("speculative decoding (--speculative other than off)",
                      _spec(speculative, default="off") is not None),
+                    ("the contiguous KV layout (kv_layout other than paged)",
+                     self.cfg.is_latent and _layout(kv_layout) != "paged"),
                 ) if asked
             ]
             if refused:
+                why = (
+                    "linear-attention layers keep a recurrent state a row, which "
+                    "has no snapshots or rollback yet (ROADMAP R7)"
+                    if self.cfg.is_hybrid else
+                    "latent attention keeps one [latent | key] vector a token in "
+                    "the paged float pool of one chip, and its expert layers hold "
+                    "a share of the experts without an exchange (ROADMAP R5)"
+                )
                 raise ValueError(
-                    f"{ArchType.name(self.header.arch_type)}: linear-attention layers "
-                    "keep a recurrent state a row, which has no snapshots or "
-                    "rollback yet (ROADMAP R7): " + "; ".join(refused) + " "
+                    f"{ArchType.name(self.header.arch_type)}: {why}: "
+                    + "; ".join(refused) + " "
                     + ("is" if len(refused) == 1 else "are") + " not supported"
                 )
         self.mesh = mesh
@@ -375,6 +391,10 @@ class InferenceEngine:
             self.reader, self.cfg, shardings=shardings,
             tp=mesh.shape["tp"] if self.use_pipeline else 1,
         )
+        # the weights are on the device: give the file's pages back (they
+        # were read through `mmap` and would stay resident, 10 GB of a 40 GiB
+        # host at the benchmark's largest files; a later read faults them in)
+        self.reader.release_pages()
         self.rope = build_rope_tables(self.header)
         self.batch = batch
         # what a prompt chunk's tail past its real tokens is filled with. A
@@ -483,7 +503,7 @@ class InferenceEngine:
         # nor match each other.
         from .prefix_cache import PrefixCache
 
-        if self.cfg.is_hybrid:
+        if self.cfg.is_hybrid or self.cfg.is_latent:
             from .prefix_cache import resolve_budget_mb
 
             if resolve_budget_mb(prefix_cache_mb, default_mb=0) > 0:
@@ -491,6 +511,10 @@ class InferenceEngine:
                     "prefix cache off: a linear-attention layer's recurrent "
                     "state has no snapshots at page boundaries yet (ROADMAP "
                     "R7), so a cached prefix cannot be resumed"
+                    if self.cfg.is_hybrid else
+                    "prefix cache off: its publish, share and ship programs "
+                    "read a page as k and v heads, and a latent page is one "
+                    "[latent | key] vector a token (ROADMAP R5)"
                 )
             prefix_cache_mb = 0
         self.prefix_cache = PrefixCache.build(self, prefix_cache_mb)
@@ -587,8 +611,8 @@ class InferenceEngine:
 
         Where nobody said (the library, the CLI, tests) the architecture's
         default stands: the plans that were here first are pinned as they are
-        (the goldens, the warm-plan tests), and a hybrid model's batched plan,
-        which is newer, starts without the solo half. Either way `generate`
+        (the goldens, the warm-plan tests), and a hybrid or a latent model's
+        batched plan, which is newer, starts without the solo half. Either way `generate`
         and `prefill` still run on an engine whose plan leaves them out: they
         compile what they use when they use it, and say so once
         (`_solo_entry`). `verify` and the contiguous layout's `prefix_extract`
@@ -596,7 +620,7 @@ class InferenceEngine:
         D12)."""
         batched = self.batch > 1 and self.device_decode
         if self.server_role is None:
-            return not (batched and self.cfg.is_hybrid)
+            return not (batched and (self.cfg.is_hybrid or self.cfg.is_latent))
         return not batched or self.server_role == "prefill"
 
     def _solo_entry(self, what: str) -> None:
@@ -622,6 +646,24 @@ class InferenceEngine:
             "bytes": self.rec_slot_bytes * self.batch,
             "slot_bytes": self.rec_slot_bytes,
             "layers": self.cfg.n_rec_layers,
+        }
+
+    def moe_snapshot(self):
+        """The held-experts layers as /stats reports them (`moe`), without the
+        running sums (the Batcher owns those): None for a model whose expert
+        layers, if any, hold every expert."""
+        cfg = self.cfg
+        if not cfg.n_experts_held:
+            return None
+        return {
+            "experts": cfg.n_experts,
+            "held": cfg.n_experts_held,
+            "first": cfg.expert_first,
+            "active": cfg.n_active_experts,
+            # one held expert's three matrices as the file and the device
+            # hold them (Q40: 18 bytes for 32 weights)
+            "expert_bytes": 3 * cfg.dim * cfg.moe_hidden_dim * 18 // 32,
+            "layers": cfg.n_moe_layers,
         }
 
     def _notice(self, msg: str) -> None:

@@ -168,6 +168,12 @@ def page_pool_bytes(cfg, n_pages: int, page_size: int, tp: int = 1) -> int:
     """Device bytes of a pool's k+v tensors (+ the f32 scale sidecars on the
     int8 arm — capacity math, /stats, and the cost model must all price the
     STORED width, including the 4 scale bytes per head_dim payload bytes)."""
+    if cfg.is_latent:
+        # one [latent | key] vector a token a layer, at its stored width
+        return (
+            cfg.n_kv_layers * n_pages * page_size
+            * cfg.latent_page_width * jnp.dtype(cfg.kv_dtype).itemsize
+        )
     per_vector = cfg.head_dim * jnp.dtype(cfg.kv_dtype).itemsize
     if cfg.kv_quantized:
         per_vector += 4  # one f32 scale per (token, kv-head) vector
@@ -186,6 +192,18 @@ def init_kv_pool(cfg, n_pages: int, page_size: int, rows: int = 0, tp: int = 1) 
     # the leading axis is the layers that KEEP KV (a hybrid model's full-
     # attention layers); its linear layers' state slots, `rows` of them,
     # ride the same value (models/params.init_rec_state)
+    if cfg.is_latent:
+        # latent attention: ONE vector a token a layer and no `v` (the values
+        # are the latent; models/kv_arms.latent_arm), with the expert layers'
+        # counters beside it
+        return KVCache(
+            k=jnp.zeros(
+                (cfg.n_kv_layers, n_pages, page_size, cfg.latent_page_width),
+                cfg.kv_dtype,
+            ),
+            v=None,
+            moe=jnp.zeros((2, 2), jnp.int32),
+        )
     shape = (
         cfg.n_kv_layers, n_pages, page_size, pool_kv_heads(cfg.n_kv_heads, tp),
         cfg.head_dim,
@@ -213,6 +231,9 @@ def copy_page(cache: KVCache, src, dst, out_sharding=None) -> KVCache:
     mesh-paged engines pin the pool's pp/tp layout in-program (the page
     moves within every shard locally — the slice keeps the layer and head
     axes whole, so no collective is traced; graph_audit asserts it)."""
+    if cache.v is None:  # a latent pool [L, P, ps, W]: the page is `k`'s alone
+        seg = jax.lax.dynamic_slice_in_dim(cache.k, src, 1, axis=1)
+        return replace(cache, k=jax.lax.dynamic_update_slice_in_dim(cache.k, seg, dst, axis=1))
     L, _, ps, h, d = cache.k.shape
     k_seg = jax.lax.dynamic_slice(cache.k, (0, src, 0, 0, 0), (L, 1, ps, h, d))
     v_seg = jax.lax.dynamic_slice(cache.v, (0, src, 0, 0, 0), (L, 1, ps, h, d))
@@ -394,6 +415,9 @@ class PagePool:
                 # their real token capacity — ~2x pages under int8
                 "kv_dtype": self.kv_dtype,
                 "page_bytes": self.page_bytes,
+                # what a token costs the pool over all its layers, as stored
+                # (padded heads, scale sidecars, a latent page's one vector)
+                "bytes_per_token": self.page_bytes // self.page_size,
                 "pool_bytes": self.page_bytes * self.n_pages,
                 "used_bytes": self.page_bytes * self.used_pages,
                 "tokens_capacity": self.n_pages * self.page_size,

@@ -352,10 +352,20 @@ class Batcher:
         # /debug/batch_timeline. Emission is a pre-bound tuple append: zero
         # device work, and no switch: the benchmark's per-layer readers
         # read every step.
+        # a model that holds a share of its experts (`engine.moe_snapshot`)
+        # adds what its expert layers did in the chunk: (token, expert) pairs
+        # that landed on held experts and held experts with at least one,
+        # summed over layers and steps; and the same of the prompt chunks
+        # whose completion this chunk's fetch observed
+        self._moe_totals = None if engine.moe_snapshot() is None else [0, 0, 0, 0]
         self._em_timeline = TRACER.bind_global(
             "batch_step",
             ("decoding", "prefilling", "free", "spec",
-             "pool_pages_used", "queue_depth", "turn"),
+             "pool_pages_used", "queue_depth", "turn")
+            + (() if self._moe_totals is None else (
+                "expert_pairs", "experts_hit",
+                "prefill_expert_pairs", "prefill_experts_hit",
+            )),
         )
         # the phases that partition a turn of the loop, in the trace ring
         # and on the profiler's host plane (runtime/phases.py)
@@ -554,20 +564,42 @@ class Batcher:
 
     def _timeline_step(
         self, engine, slots, n_decoding: int, t_us: int, dur_us: int,
-        spec: bool,
+        spec: bool, moe_counts=None,
     ):
         """One batch-composition snapshot: slot roles + pool/backlog
-        occupancy at this step boundary. A pre-bound tuple append."""
+        occupancy at this step boundary. A pre-bound tuple append.
+        `moe_counts`: `BatchSession.moe_counts` of the chunk that just ran
+        (None where no chunk ran, and before a session's second fetch)."""
         n_prefilling = sum(
             1 for s in slots if s is not None and s.prefilling
         )
         n_free = sum(1 for s in slots if s is None)
+        moe = ()
+        if self._moe_totals is not None:
+            moe = (0, 0, 0, 0) if moe_counts is None else tuple(
+                int(v) for v in moe_counts.reshape(4)
+            )
+            for i, v in enumerate(moe):
+                self._moe_totals[i] += v
         self._em_timeline(
             t_us, dur_us, n_decoding, n_prefilling, n_free,
             1 if spec else 0,
             engine.page_pool.used_pages if engine.paged else 0,
             self.queue_depth(),
             self.phases.turn,
+            *moe,
+        )
+
+    def moe_snapshot(self, engine):
+        """/stats `moe`: the engine's shape of the layer and this loop's
+        running sums (racy-but-consistent-enough, like the slots)."""
+        snap = engine.moe_snapshot()
+        if snap is None or self._moe_totals is None:
+            return snap
+        pairs, hit, p_pairs, p_hit = self._moe_totals
+        return dict(
+            snap, expert_pairs=pairs, experts_hit=hit,
+            prefill_expert_pairs=p_pairs, prefill_experts_hit=p_hit,
         )
 
     def _first_tokens(self, req: _BatchReq, row: int):
@@ -1085,7 +1117,7 @@ class Batcher:
             t_chunk_us = to_us(t_chunk)
             self._timeline_step(
                 engine, slots, len(decode_rows), t_chunk_us, chunk_dur_us,
-                spec=spec_drafts is not None,
+                spec=spec_drafts is not None, moe_counts=session.moe_counts,
             )
             n_put = n_over = n_finished = 0  # batcher.deliver's arguments
             for row, req in enumerate(slots):
@@ -1169,7 +1201,7 @@ def refuse_state_handoff(engine, args) -> None:
     asked to ship or tier KV for such a model is refused at start-up (the
     engine itself refuses meshes, int8 KV and speculation, and turns the
     prefix cache off with a notice)."""
-    if not engine.cfg.is_hybrid:
+    if not (engine.cfg.is_hybrid or engine.cfg.is_latent):
         return
     from ..runtime.kv_tiering import tiers_configured
     from .disagg import resolve_peers, resolve_role
@@ -1183,8 +1215,13 @@ def refuse_state_handoff(engine, args) -> None:
         asked.append("KV tiering (DLT_KV_*_TIER_*) demotes and promotes prefix-cache pages")
     if asked:
         raise ValueError(
-            "; ".join(asked) + ": this architecture's recurrent state has no "
-            "snapshots or hand-off yet (ROADMAP R7): not supported"
+            "; ".join(asked) + (
+                ": this architecture's recurrent state has no snapshots or "
+                "hand-off yet (ROADMAP R7): not supported"
+                if engine.cfg.is_hybrid else
+                ": the page programs read a page as k and v heads, and a "
+                "latent page is one vector a token (ROADMAP R5): not supported"
+            )
         )
 
 
@@ -2537,6 +2574,14 @@ class Handler(BaseHTTPRequestHandler):
                 # a slot is live while a request holds its row: `batcher`'s
                 # `slots_active`
                 "rec_state": st.engine.rec_state_snapshot(),
+                # expert layers that hold a share of the published experts
+                # (None on every other model): the share, and what landed on
+                # it since the server started
+                "moe": (
+                    st.batcher.moe_snapshot(st.engine)
+                    if st.batcher is not None
+                    else st.engine.moe_snapshot()
+                ),
                 # where start-up went (runtime/tracing.py `StartupRecord`):
                 # aggregates built once at the seal, the since-seal dispatch
                 # counts by kind filled in here; the rows: /debug/startup

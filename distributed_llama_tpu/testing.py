@@ -47,6 +47,24 @@ def tiny_header(
     lin_value_head_dim: int = 0,
     lin_conv_kernel: int = 4,
     lin_neg_eigval: bool = True,
+    # kimi_k2: latent attention's sizes, YaRN (`rope_scaling_factor` and
+    # `rope_scaling_orig_max_seq_len` above are its factor and original
+    # length), `n_dense_layers` leading dense layers, then layers that route
+    # over `n_experts` and hold `experts_held` of them from `expert_first` on
+    q_lora_rank: int = 0,
+    kv_lora_rank: int = 0,
+    qk_nope_head_dim: int = 0,
+    qk_rope_head_dim: int = 0,
+    v_head_dim: int = 0,
+    yarn_beta_fast: int = 32,
+    yarn_beta_slow: int = 1,
+    yarn_mscale: float = 1.0,
+    yarn_mscale_all_dim: float = 1.0,
+    n_dense_layers: int = 1,
+    experts_held: int = 0,
+    expert_first: int = 0,
+    n_shared_experts: int = 1,
+    routed_scale: float = 1.0,
 ) -> ModelHeader:
     h = ModelHeader(
         version=1,
@@ -79,6 +97,17 @@ def tiny_header(
         h.lin_value_head_dim = lin_value_head_dim
         h.lin_conv_kernel = lin_conv_kernel
         h.lin_neg_eigval = int(lin_neg_eigval)
+    if arch == ArchType.KIMI_K2:
+        h.q_lora_rank, h.kv_lora_rank = q_lora_rank, kv_lora_rank
+        h.qk_nope_head_dim, h.qk_rope_head_dim = qk_nope_head_dim, qk_rope_head_dim
+        h.v_head_dim = v_head_dim
+        h.yarn_beta_fast, h.yarn_beta_slow = float(yarn_beta_fast), float(yarn_beta_slow)
+        h.yarn_mscale, h.yarn_mscale_all_dim = yarn_mscale, yarn_mscale_all_dim
+        h.n_dense_layers = n_dense_layers
+        h.experts_held = experts_held or n_experts
+        h.expert_first = expert_first
+        h.n_shared_experts = n_shared_experts
+        h.routed_scale = routed_scale
     return h.finalize()
 
 
@@ -103,7 +132,7 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
         mfile.K_HEAD_DIM: h.head_dim,
         mfile.K_NORM_EPSILON: 5 if abs(h.norm_epsilon - 1e-5) < 1e-9 else 6,
     }
-    if h.rope_scaling_factor != 1.0:
+    if h.rope_scaling_factor != 1.0 and not h.is_latent:
         kv[mfile.K_ROPE_SCALING_FACTOR] = int(h.rope_scaling_factor)
         kv[mfile.K_ROPE_SCALING_LOW_FREQ_FACTOR] = int(h.rope_scaling_low_freq_factor)
         kv[mfile.K_ROPE_SCALING_HIGH_FREQ_FACTORY] = int(h.rope_scaling_high_freq_factor)
@@ -118,10 +147,32 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
         kv[mfile.K_LIN_VALUE_HEAD_DIM] = h.lin_value_head_dim
         kv[mfile.K_LIN_CONV_KERNEL] = h.lin_conv_kernel
         kv[mfile.K_LIN_NEG_EIGVAL] = h.lin_neg_eigval
+    if h.is_latent:
+        # YaRN's factor and original length ride the scaling keys whatever
+        # the factor is
+        kv[mfile.K_ROPE_SCALING_FACTOR] = int(h.rope_scaling_factor)
+        kv[mfile.K_ROPE_SCALING_ORIG_MAX_SEQ_LEN] = h.rope_scaling_orig_max_seq_len
+        kv[mfile.K_Q_LORA_RANK] = h.q_lora_rank
+        kv[mfile.K_KV_LORA_RANK] = h.kv_lora_rank
+        kv[mfile.K_QK_NOPE_HEAD_DIM] = h.qk_nope_head_dim
+        kv[mfile.K_QK_ROPE_HEAD_DIM] = h.qk_rope_head_dim
+        kv[mfile.K_V_HEAD_DIM] = h.v_head_dim
+        kv[mfile.K_YARN_BETA_FAST] = int(h.yarn_beta_fast)
+        kv[mfile.K_YARN_BETA_SLOW] = int(h.yarn_beta_slow)
+        kv[mfile.K_YARN_MSCALE_MILLI] = round(h.yarn_mscale * 1000)
+        kv[mfile.K_YARN_MSCALE_ALL_DIM_MILLI] = round(h.yarn_mscale_all_dim * 1000)
+        kv[mfile.K_N_DENSE_LAYERS] = h.n_dense_layers
+        kv[mfile.K_EXPERTS_HELD] = h.experts_held
+        kv[mfile.K_EXPERT_FIRST] = h.expert_first
+        kv[mfile.K_N_SHARED_EXPERTS] = h.n_shared_experts
+        kv[mfile.K_ROUTED_SCALE_MILLI] = round(h.routed_scale * 1000)
     return kv
 
 
-_NORM_ROLES = ("norm0", "norm1", "final_norm", "q_norm", "k_norm", "lin_o_norm")
+_NORM_ROLES = (
+    "norm0", "norm1", "final_norm", "q_norm", "k_norm", "lin_o_norm",
+    "q_a_norm", "kv_a_norm",
+)
 
 
 def _gdn_init(role: str, shape: tuple, rng) -> np.ndarray | None:
@@ -141,6 +192,25 @@ def _gdn_init(role: str, shape: tuple, rng) -> np.ndarray | None:
         x[-1] += 1.0
         return x
     return None
+
+
+def tiny_latent_header(**kw) -> ModelHeader:
+    """A tiny kimi_k2 header with every mechanism of the architecture: two
+    low-rank paths, YaRN, 1 dense + 2 expert layers, 16 experts of which 4
+    are held (from expert 4 on), 1 shared expert. Widths are the smallest the
+    stacked Q40 kernels take (a contraction of 256s, lanes of 128)."""
+    base = dict(
+        arch=ArchType.KIMI_K2, dim=256, hidden_dim=512, n_layers=3, n_heads=4,
+        n_kv_heads=4, vocab_size=256, seq_len=128, n_experts=16,
+        n_active_experts=4, moe_hidden_dim=256, rope_theta=50000.0,
+        rope_scaling_factor=64.0, rope_scaling_orig_max_seq_len=16,
+        q_lora_rank=256, kv_lora_rank=256, qk_nope_head_dim=64,
+        qk_rope_head_dim=32, v_head_dim=64, n_dense_layers=1, experts_held=4,
+        expert_first=4, n_shared_experts=1, routed_scale=2.827,
+    )
+    base.update(kw)
+    return tiny_header(**base)
+
 
 def gdn_recurrence(S, q, k, v, log_alpha, beta):
     """The gated delta rule written out position by position: the definition

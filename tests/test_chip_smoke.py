@@ -45,6 +45,7 @@ def rehearsals(tmp_path_factory):
         # the cache directory placed from outside / left to the program
         "one": _run(["--rehearse"], JAX_COMPILATION_CACHE_DIR=str(cache)),
         "four": _run(["--rehearse", "--chips", "4"]),
+        "kimi": _run(["--rehearse", "--arch", "kimi_k2"]),
     }
     runs = {}
     for name, p in procs.items():
@@ -128,6 +129,30 @@ def test_four_chip_rehearsal_runs_tensor_parallelism_only(rehearsals):
     assert tp4["all_reduces"] >= 2
     check = next(l for l in _by_phase(lines, "tp4") if "check" in l)
     assert min(check["leading_tokens_equal"]) >= min(check["bounds"][1], check["new_tokens"])
+
+
+def test_the_kimi_k2_branch_rehearses_its_numbers_and_its_server(rehearsals):
+    """`--arch kimi_k2`: the held-experts layer and latent attention against
+    their float32 forms, then the server at 2 rows; on the CPU it too fails
+    for the device check alone."""
+    code, lines, last = rehearsals["kimi"]
+    result = json.loads(last)
+    assert code != 0 and result["reasons"] == ["need 1 tpu device(s), jax found 1 x cpu"], result
+    numbers = _by_phase(lines, "numbers")
+    experts = [l for l in numbers if l["check"].startswith("held experts")]
+    assert [l["pairs"] for l in experts] == [8, 128, 1024]
+    assert all(l["finite"] and l["max_diff_std"] <= l["bound"] and l["landed_hit"][0] > 0 for l in experts)
+    latent = [l for l in numbers if l["check"].startswith("latent model")]
+    assert len(latent) == 2 and all(l["max_diff_std"] <= l["bounds"][1] for l in latent)
+    serve = _by_phase(lines, "serve")[-1]
+    assert all(traced >= 14 for traced, _ in serve["kernels_traced_compiled"].values())
+    assert {l["request"] for l in _by_phase(lines, "request")} == {
+        "plain", "streamed", "concurrent-0", "concurrent-1"}
+    (stats,) = _by_phase(lines, "stats")
+    assert not any(stats["watched"].values()) and stats["supervisor"] == "serving"
+    assert (stats["moe"]["held"], stats["moe"]["experts"], stats["moe"]["first"]) == (4, 16, 4)
+    assert stats["moe"]["expert_pairs"] > 0 and stats["moe"]["experts_hit"] > 0
+    assert stats["kv_pool"]["bytes_per_token"] == 2 * 384 * 2  # two layers' bfloat16 pages
 
 
 def test_compile_cache_defaults_to_the_checkout(rehearsals):

@@ -457,6 +457,62 @@ def test_hybrid_batch_decode_gathers_no_pool(hybrid_engine):
     assert "gdn_decode_step" in text and "paged_decode_attention" in text
 
 
+# -- the latent model's programs (a latent page, held experts) -----------------
+
+LATENT_ARGS = ["--arch", "kimi_k2", "--kv-layout", "paged", "--speculative", "off",
+               "--prefix-cache-mb", "0", "--compute-dtype", "bfloat16"]
+
+
+def test_repo_golden_covers_the_tiny_latent_model(monkeypatch):
+    """One golden for the tiny Kimi-K2's warm plan (prefill_row, batch_decode,
+    page_copy) in bfloat16 with Pallas interpreted, so that it holds the
+    grouped expert kernel told its live blocks beside the stacked Q40 kernels."""
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    assert gd.main(["--check", "--coverage", *LATENT_ARGS]) == 0
+
+
+@pytest.fixture(scope="module", params=["xla", "interpret"])
+def latent_engine(request, tmp_path_factory):
+    import argparse
+
+    mp = pytest.MonkeyPatch()
+    if request.param == "interpret":
+        mp.setenv("DLT_PALLAS_INTERPRET", "1")
+    else:
+        mp.delenv("DLT_PALLAS_INTERPRET", raising=False)
+    p = argparse.ArgumentParser()
+    ga.add_engine_args(p)
+    eng = ga.engine_from_args(p.parse_args(LATENT_ARGS), str(tmp_path_factory.mktemp("latent")))
+    yield eng
+    eng.close()
+    mp.undo()
+
+
+def test_latent_programs_meet_their_contracts(latent_engine):
+    """No float64, no collective, the float32 dots within a dense layer's
+    attention, the scan body's attention and its router, and both leaves of
+    the cache (the latent pool, the experts' counters) donated on every jit
+    entry; the batched plan holds the Batcher's programs alone."""
+    eng = latent_engine
+    ga.assert_clean(ga.audit_engine(eng))
+    assert ga.donation_problems(eng) == []
+    assert len(jax.tree_util.tree_leaves(eng.cache)) == 2  # k (no v), moe
+    assert {kind for kind, _, _ in eng.warm_plan()} == {"prefill_row", "batch_decode", "page_copy"}
+    assert ga.f32_dot_budget(eng, ga.LadderEntry("batch_decode", 8, 128)) == 3
+
+
+def test_latent_batch_decode_reads_the_page_through_the_gather_arm(latent_engine):
+    """The latent arm is the gather arm in `jax.numpy` whatever Pallas says
+    (no pool-gather ban in its contract), and with Pallas on the step holds
+    the grouped kernel's live-block form."""
+    eng = latent_engine
+    entry = ga.LadderEntry("batch_decode", 8, 128)
+    assert ga.contract_for(eng, entry).forbid_pool_gather is None
+    text = str(ga.trace_entry(eng, entry))
+    assert "paged_decode_attention" not in text
+    assert ("q40_matmul_pallas_grouped" in text) == eng.cfg.pallas_interpret
+
+
 def test_a_lost_state_donation_is_reported():
     txt = 'func @main(%a {tf.aliasing_output = 0 : i32}, %b {tf.aliasing_output = 1 : i32})'
     assert ga.donated_leaf_check("x", txt, 2) == []

@@ -7,6 +7,7 @@ A compile that passes is a compile, never a run. Each case asserts a Mosaic
 kernel (`tpu_custom_call`) in the compiled HLO; skipped where the topology
 cannot be described (no libtpu)."""
 
+import math
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -164,7 +165,122 @@ def _paged_arm_30_heads(rows, n_read):
     return build
 
 
+def _kimi_cfg(layers=8):
+    """Kimi-K2.6 as `perfbench/configs/kimi-k2.6.json` cuts it: published
+    widths, a dense layer and 7 expert layers, 48 of 384 experts held, an
+    eighth of the vocabulary."""
+    from distributed_llama_tpu.models.config import ModelConfig
+
+    m = 0.1 * math.log(64) + 1
+    return ModelConfig(
+        arch_type=0xABCD04, dim=7168, hidden_dim=18432, n_layers=layers, n_heads=64,
+        n_kv_heads=64, head_dim=192, vocab_size=20480, seq_len=2048, n_experts=384,
+        n_active_experts=8, hidden_act=1, rope_type=3, norm_epsilon=1e-5,
+        use_pallas=True, q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, attn_scale=192**-0.5 * m * m, n_dense_layers=1,
+        n_experts_held=48, expert_first=0, n_shared_experts=1, moe_hidden_dim=2048,
+        routed_scale=2.827,
+    )
+
+
+def _kimi_params(cfg, S):
+    """The model's parameter tree as `models/params._load_latent` builds it,
+    described and not held (10 GB)."""
+    from distributed_llama_tpu.models.params import (
+        ExpertParams, LayerParams, MlaParams, ModelParams,
+    )
+    from distributed_llama_tpu.ops.quant import QuantTensor
+
+    def q40(*lead, out, inn):
+        return QuantTensor(q=S((*lead, inn // 8, out), jnp.int32),
+                           d=S((*lead, inn // 32, out), jnp.float16))
+
+    L, Lm, Ld, dim, bf = cfg.n_layers, cfg.n_moe_layers, cfg.n_dense_layers, cfg.dim, jnp.bfloat16
+    H, Eh, ff = cfg.n_heads, cfg.n_experts_held, cfg.moe_hidden_dim
+    mla = MlaParams(
+        wqkva=q40(L, out=cfg.q_lora_rank + cfg.latent_page_width, inn=dim),
+        q_norm=S((L, cfg.q_lora_rank), jnp.float32),
+        wqb=q40(L, out=H * cfg.head_dim, inn=cfg.q_lora_rank),
+        kv_norm=S((L, cfg.kv_lora_rank), jnp.float32),
+        w_uk=S((L, H, cfg.qk_nope_dim, cfg.kv_lora_rank), bf),
+        w_uv=S((L, H, cfg.v_head_dim, cfg.kv_lora_rank), bf),
+        wo=q40(L, out=dim, inn=H * cfg.v_head_dim),
+    )
+    experts = ExpertParams(
+        gate=S((Lm, cfg.n_experts, dim), jnp.float32), bias=S((Lm, cfg.n_experts), jnp.float32),
+        w1=q40(Lm, Eh, out=ff, inn=dim), w3=q40(Lm, Eh, out=ff, inn=dim),
+        w2=q40(Lm, Eh, out=dim, inn=ff),
+        s13=q40(Lm, out=2 * ff, inn=dim), s2=q40(Lm, out=dim, inn=ff),
+    )
+    layers = LayerParams(
+        q=None, k=None, v=None, wo=None, w1=None, w3=None,
+        w13=q40(Ld, out=2 * cfg.hidden_dim, inn=dim), w2=q40(Ld, out=dim, inn=cfg.hidden_dim),
+        norm0=S((L, dim), jnp.float32), norm1=S((L, dim), jnp.float32), mla=mla, experts=experts,
+    )
+    return ModelParams(
+        embedding=S((cfg.vocab_size, dim), jnp.float32), layers=layers,
+        final_norm=S((dim,), jnp.float32), wcls=q40(out=cfg.vocab_size, inn=dim),
+    )
+
+
+def _kimi_step(rows, t, pages=2560, kv_len=2048):
+    """The served program's model step (`forward_uncompiled`) at Kimi-K2.6's
+    widths: `rows` decoding rows of one position (the cell's batch-decode
+    step), or one prompt chunk of `t` tokens through a row's page-table
+    slice; the latent pool donated."""
+    from distributed_llama_tpu.models.params import KVCache
+    from distributed_llama_tpu.models.transformer import forward_uncompiled
+    from distributed_llama_tpu.ops.rope import RopeTables
+
+    cfg = _kimi_cfg()
+
+    def build(S):
+        def fn(params, rope, pool, counts, tokens, pos, table):
+            logits, cache = forward_uncompiled(
+                cfg, params, rope, KVCache(k=pool, v=None, moe=counts), tokens, pos,
+                kv_len=kv_len, page_table=table, page_size=PAGE,
+            )
+            return logits, cache.k, cache.moe
+
+        rope = RopeTables(cos=S((2048, 32), jnp.float32), sin=S((2048, 32), jnp.float32))
+        pool = S((cfg.n_layers, pages, PAGE, cfg.latent_page_width), jnp.bfloat16)
+        pos = S((rows,), jnp.int32) if t == 1 else S((), jnp.int32)
+        return fn, [_kimi_params(cfg, S), rope, pool, S((2, 2), jnp.int32),
+                    S((rows, t), jnp.int32), pos, S((rows, 128), jnp.int32)], (2, 3)
+
+    return build
+
+
+def _kimi_grouped(pairs, role):
+    """The routed experts' grouped matmul told its live blocks, at the shapes
+    `ops/moe.moe_ffn_held` gives it: `pairs` (token, expert) pairs bound the
+    rows, 48 experts of 7 layers in one flat stack."""
+    from distributed_llama_tpu.ops.moe import _padded_rows_bound, _block_rows
+
+    inn, out = {"w1": (7168, 2048), "w2": (2048, 7168)}[role]
+
+    def build(S):
+        block_r = _block_rows(pairs, 48)
+        R_pad = _padded_rows_bound(pairs, 48, block_r)
+
+        def fn(x, q, d, be, nl):
+            return pq.q40_matmul_pallas_grouped(x, q, d, be, block_r, n_live=nl)
+
+        return fn, [S((R_pad, inn), jnp.bfloat16), S((7, 48, inn // 8, out), jnp.int32),
+                    S((7, 48, inn // 32, out), jnp.float16),
+                    S((R_pad // block_r,), jnp.int32), S((), jnp.int32)]
+
+    return build
+
+
 CASES = {
+    # Kimi-K2.6 (perfbench/configs/kimi-k2.6.json): the cell's decode step at
+    # 32 rows and a prompt's chunk of 256, whole, and the grouped expert kernel
+    # alone at both (256 and 2048 pairs)
+    "kimi-step-32rows": _kimi_step(32, 1),
+    "kimi-step-prompt256": _kimi_step(1, 256),
+    **{f"kimi-grouped-{pairs}pairs-{role}": _kimi_grouped(pairs, role)
+       for pairs in (256, 2048) for role in ("w1", "w2")},
     # Olmo-Hybrid-7B: the decode step at the issue's 32 rows, the cell's 24
     # and the next fallback, 16
     "gdn-decode-32rows": _gdn(32),
@@ -254,6 +370,16 @@ def test_kernel_compiles_for_v5e(v5e, case):
         donate = [(0,)]  # the state, updated in place as the step programs donate it
     compiled = jax.jit(fn, donate_argnums=donate[0] if donate else ()).lower(*args).compile()
     assert count_tpu_kernels(compiled) >= 1
+    if case.startswith("kimi-step"):
+        # 7 expert layers' three grouped calls are one scan's body; with the
+        # stacked projections, the dense layer's and the head: the step's
+        # kernels. No copy of the pool (0.42 GB) or of an expert stack (2.5
+        # GB) beside them: the temps are the three expert stacks' scale
+        # planes, bitcast float16 -> int16 once a program (0.92 GB:
+        # `pallas_q40._dt_operand`, no no-op at these shapes), and a prompt's
+        # scores
+        assert count_tpu_kernels(compiled) >= 3 + 5 + 4 + 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 5 << 28
     if case.startswith("paged") or case.startswith("gdn"):
         # the pool (the state) is read where it lies: a reshaped or
         # re-laid-out operand shows up as a copy of the whole of it (GBs) in
