@@ -269,14 +269,15 @@ def trace_entry(engine, entry: LadderEntry):
             if gt_sds is not None:
                 extra += [gt_sds, _sds((b,), jnp.int32)]
 
-            def fn(tok, pos, keys, temp, topp, *ops):
+            def fn(tok, pos, keys, temp, topp, c_tok, c_keys, from_host, *ops):
                 it = iter(ops)
                 pt = next(it) if engine.paged else None
                 gtab = next(it) if gt_sds is not None else None
                 gst = next(it) if gt_sds is not None else None
                 return batch_decode_chunk(
                     cfg, engine.params, engine.rope, engine.cache, tok, pos,
-                    keys, temp, topp, n_steps=entry.size, kv_len=entry.kv_len,
+                    keys, temp, topp, c_tok, c_keys, from_host,
+                    n_steps=entry.size, kv_len=entry.kv_len,
                     page_table=pt, page_size=ps,
                     grammar_table=gtab, grammar_state=gst,
                 )
@@ -284,7 +285,8 @@ def trace_entry(engine, entry: LadderEntry):
             return jax.make_jaxpr(fn)(
                 _sds((b,), jnp.int32), _sds((b,), jnp.int32),
                 _sds((b, 2), jnp.uint32), _sds((b,), jnp.float32),
-                _sds((b,), jnp.float32), *extra,
+                _sds((b,), jnp.float32), _sds((b,), jnp.int32),
+                _sds((b, 2), jnp.uint32), _sds((b,), jnp.bool_), *extra,
             )
         return jax.make_jaxpr(fn)(
             _sds((b,), jnp.int32), _sds((b,), jnp.int32),
@@ -832,6 +834,7 @@ def donation_problems(engine) -> list:
                     cfg, engine.params, engine.rope, engine.cache, tokb,
                     jnp.zeros((b,), jnp.int32), jnp.zeros((b, 2), jnp.uint32),
                     jnp.zeros((b,), jnp.float32), jnp.full((b,), 0.9, jnp.float32),
+                    tokb, jnp.zeros((b, 2), jnp.uint32), jnp.ones((b,), bool),
                     n_steps=1, kv_len=kvb, page_table=pt, page_size=ps,
                     grammar_table=gt, grammar_state=gsb,
                 ),

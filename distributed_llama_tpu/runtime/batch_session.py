@@ -51,6 +51,7 @@ import numpy as np
 from ..models.params import KVCache
 from ..models.transformer import forward_uncompiled
 from ..ops.sampling import sample_logits_per_row, split_row_keys
+from .telemetry import watchdog
 from .tracing import to_us
 
 
@@ -69,6 +70,12 @@ def batch_decode_chunk(
     keys: jnp.ndarray,  # [b, 2] uint32 per-row threefry key states
     temperature: jnp.ndarray,  # [b] f32 (<= 0 = greedy row)
     topp: jnp.ndarray,  # [b] f32
+    carry_token: jnp.ndarray,  # [b] int32: the chunk before's `last`
+    carry_keys: jnp.ndarray,  # [b, 2] uint32: the chunk before's `keys`
+    from_host: jnp.ndarray,  # [b] bool: rows whose `token` / `keys` the host
+    # set since the chunk before was dispatched (armed, released, advanced by
+    # a verify round); every other row goes on from the carry, so the next
+    # chunk can be dispatched before this one's tokens were fetched
     n_steps: int = 16,
     kv_len: int | None = None,
     page_table: jnp.ndarray | None = None,  # paged KV layout (paged_kv.py)
@@ -82,7 +89,12 @@ def batch_decode_chunk(
     compiled program per (batch, n_steps, kv_len) serves any mix of
     greedy/sampled/seeded rows (and, with grammar operands, any mix of
     constrained/unconstrained rows). Returns (tokens [b, n_steps], cache,
-    keys) — plus the final grammar states when the operands are threaded."""
+    keys, last token [b], a copy of the cache's `moe` counters or None, the
+    final grammar states or None). `keys` and `last` are the next chunk's
+    carry; the counters are returned apart from the cache because the next
+    chunk's dispatch donates the cache."""
+    token = jnp.where(from_host, token, carry_token)
+    keys = jnp.where(from_host[:, None], keys, carry_keys)
 
     def step(carry, _):
         token, pos, cache, keys, gstate = carry
@@ -101,13 +113,11 @@ def batch_decode_chunk(
             gstate = jnp.where(adv < 0, gstate, adv)
         return (nxt, pos + 1, cache, keys, gstate), nxt
 
-    (_, _, cache, keys, gout), toks = jax.lax.scan(
+    (last, _, cache, keys, gout), toks = jax.lax.scan(
         step, (token, pos, cache, keys, grammar_state), None, length=n_steps
     )
     toks = jnp.transpose(toks, (1, 0))
-    if grammar_state is not None:
-        return toks, cache, keys, gout
-    return toks, cache, keys
+    return toks, cache, keys, last, cache.moe, gout
 
 
 @partial(jax.jit, static_argnames=("cfg", "kv_len"), donate_argnames=("cache",))
@@ -143,12 +153,28 @@ def prefill_row(
     )
 
 
+class DecodeChunk:
+    """A decode chunk between `BatchSession.dispatch` and `fetch`: the
+    program's outputs still on the device, and when it ran."""
+
+    def __init__(self, n_steps, toks, keys, moe, t_dispatch, ahead):
+        self.n_steps = n_steps
+        self.toks = toks  # [b, n_steps] device tokens, until fetched
+        self.keys = keys  # [b, 2] the key states after the chunk
+        self.moe = moe  # the `KVCache.moe` sums after the chunk, or None
+        self.t_dispatch = t_dispatch  # perf_counter at the dispatch's start
+        self.ahead = ahead  # dispatched before its predecessor was fetched
+        self.fetched = False
+        # set by `fetch`: the chunk's own interval (see there)
+        self.t_start = self.t_end = t_dispatch
+
+
 class BatchSession:
     """Host-side slot state for one continuously-batched engine.
 
     Not thread-safe — the server's Batcher worker owns it. All device work
-    happens in `admit` (prefill) and `step` (decode chunk); between calls
-    the device is idle and admission decisions are free.
+    happens in `admit` (prefill) and `dispatch` (decode chunk); `fetch` is
+    the one call that waits for the device.
     """
 
     def __init__(self, engine):
@@ -184,6 +210,19 @@ class BatchSession:
         # chunks' since then, likewise]]; None on every other model
         self.moe_counts = None
         self._moe_seen = None
+        # rows whose `token` / `keys` the host set since the last dispatch
+        # (armed, advanced by a verify round, or read back by the fetch of
+        # the newest chunk): the next chunk takes those rows' operands from
+        # the host and every other row's from the chunk before, on the device
+        self.from_host = np.ones((b,), bool)
+        self._carry = None  # (last token, keys) of the newest chunk, on the device
+        self._newest = None  # the DecodeChunk dispatched last
+        self._t_fetched = 0.0  # perf_counter when the last fetch returned
+        # a mesh's outputs are committed operands, a second lowering of
+        # every program once fed back (the solo loop warms that twin,
+        # engine._warmup_fill): there every chunk is fetched before the next
+        # is dispatched and the host's vectors are the carry
+        self.can_run_ahead = engine.mesh is None
         engine.reset()
 
     def free_rows(self) -> list[int]:
@@ -414,6 +453,7 @@ class BatchSession:
             self.temp[row] = st["temperature"]
             self.topp[row] = st["topp"]
             self.keys[row] = np.asarray(st["key_data"], np.uint32)  # dlt: allow(host-sync) — host tuple, no device source
+            self.from_host[row] = True
             self.grammars[row] = st["grammar"]
             self.active[row] = True
             del self._pending[row]
@@ -435,9 +475,7 @@ class BatchSession:
         engines release the row's page mappings here: pages shared with
         prefix-cache entries survive via the entry's own refs, everything
         else returns to the pool (the refcount-release-on-finish contract)."""
-        self.active[row] = False
-        self.pos[row] = self.seq_len
-        self.temp[row] = 0.0  # greedy is the cheap sampling path for junk
+        self.park(row)
         self.grammars[row] = None  # the session's OWNER closes it
         st = self._pending.pop(row, None)
         if st is not None and st.get("entry") is not None:
@@ -445,6 +483,15 @@ class BatchSession:
         if self.engine.paged:
             self.engine.page_pool.release_row(row)
             self.engine._pt_cache = None
+
+    def park(self, row: int) -> None:
+        """Take the row out of the chunks to come and keep what it holds
+        (its pages, its grammar session): the Batcher parks a row whose
+        budget the chunks dispatched already cover, and releases it once
+        the last of them is delivered."""
+        self.active[row] = False
+        self.pos[row] = self.seq_len
+        self.temp[row] = 0.0  # greedy is the cheap sampling path for junk
 
     def publish_row(self, row: int, tokens: list) -> None:
         """Publish the first `len(tokens) - 1` tokens' KV of `row` into the
@@ -485,6 +532,8 @@ class BatchSession:
         rows = sorted(drafts)
         if not rows:
             return {}
+        if self._newest is not None and not self._newest.fetched:
+            raise ValueError("a decode chunk is in flight: fetch it first")
         for r in rows:
             if not self.active[r]:
                 raise ValueError(f"row {r} is not active")
@@ -509,11 +558,30 @@ class BatchSession:
         for r, emitted in out.items():
             self.pos[r] += len(emitted)
             self.token[r] = emitted[-1]
+            self.from_host[r] = True
         return out
 
-    def step(self, n_steps: int) -> np.ndarray:
-        """One decode chunk for every slot; returns host tokens [b, n_steps]
-        (junk in parked rows). Advances every row's position by n_steps."""
+    def step(self, n_steps) -> np.ndarray:
+        """One decode chunk for every slot, lock-step: dispatch it and wait
+        for its tokens. Returns host tokens [b, n_steps] (junk in parked
+        rows). Advances every row's position by n_steps.
+
+        THE door through which a chunk's tokens reach the host: given a
+        chunk that `dispatch` returned it only waits for that one (the
+        Batcher's loop, which has dispatched the next chunk by then), so
+        whoever wraps this method sees every token of every driver (the
+        benchmark's rehearsal alters them here)."""
+        chunk = n_steps if isinstance(n_steps, DecodeChunk) else self.dispatch(n_steps)
+        return self.fetch(chunk)
+
+    def dispatch(self, n_steps: int) -> "DecodeChunk":
+        """Dispatch one decode chunk for every slot and return at once: pages
+        ensured, operands built, the chunk program called, every active row's
+        position advanced by n_steps, nothing fetched. The handle goes to
+        `fetch`. A caller may dispatch the next chunk before it fetches this
+        one (the Batcher's loop runs one chunk ahead of the device): a row's
+        input token and key state then come from this chunk's outputs on the
+        device, except for the rows the host set in between (`from_host`)."""
         eng = self.engine
         ends = [int(self.pos[r]) + 1 + n_steps for r in self.active_rows()]
         if ends and max(ends) > self.seq_len:
@@ -540,20 +608,23 @@ class BatchSession:
                 for r in self.active_rows()
             )
         # the sanitizer scope covers the Batcher's production decode path
-        # exactly like the solo loops: the ONLY device->host syncs allowed
-        # in here are the two _host_fetch calls below (DLT_SANITIZERS=1)
-        # the guard holds the program call as well as the fetch: a first
-        # dispatch blocks on XLA's compile there (the compile threshold, and
-        # the start-up record's `startup.warm` span with its compile stages),
-        # and a compile after the seal is named by the slot the guard sets
+        # exactly like the solo loops: nothing in a dispatch may sync
+        # device->host (DLT_SANITIZERS=1). The guard holds the program call:
+        # a first dispatch blocks on XLA's compile there (the compile
+        # threshold, and the start-up record's `startup.warm` span with its
+        # compile stages), and a compile after the seal is named by the slot
+        # the guard sets
         with eng._sanitizer_scope(), eng._guard(
             f"batch_decode[{n_steps}]", ("batch_decode", n_steps, kv_len)
         ):
-            token = jnp.asarray(self.token)
-            pos = jnp.asarray(self.pos)
-            keys = jnp.asarray(self.keys)
-            temp = jnp.asarray(self.temp)
-            topp = jnp.asarray(self.topp)
+            # COPIES of the host's vectors: the positions advance below
+            # while the transfer (a view of the buffer, on the CPU) may
+            # still be read
+            token, pos, keys, temp, topp, from_host = jax.device_put((
+                self.token.copy(), self.pos.copy(), self.keys.copy(),
+                self.temp.copy(), self.topp.copy(), self.from_host.copy(),
+            ))
+            last = moe = None
             if eng.use_pipeline:
                 from ..parallel.pipeline import pipeline_batch_decode_chunk
 
@@ -563,60 +634,96 @@ class BatchSession:
                     page_table=eng._pt_operand() if eng.paged else None,
                     page_size=eng.page_size,
                 )
-            elif eng.grammar is not None:
-                # grammar-capable engine: the SAME warm program serves
-                # constrained and free rows — the state vector (FREE 0 for
-                # unconstrained rows) is just another small operand. The
-                # in-graph final states are discarded: the host sessions
-                # are authoritative and re-advance from the fetched tokens
-                # before the next step is dispatched.
-                gr_state = jnp.asarray(
-                    np.fromiter(
-                        (g.row_state if g is not None else 0 for g in self.grammars),
-                        np.int32,
-                        count=len(self.grammars),
-                    )
-                )
-                toks, eng.cache, keys, _ = batch_decode_chunk(
-                    eng.cfg, eng.params, eng.rope, eng.cache,
-                    token, pos, keys, temp, topp, n_steps=n_steps, kv_len=kv_len,
-                    page_table=eng._pt_operand() if eng.paged else None,
-                    page_size=eng.page_size,
-                    grammar_table=eng._gr_operand(), grammar_state=gr_state,
-                )
             else:
-                toks, eng.cache, keys = batch_decode_chunk(
+                # on a mesh the session never runs ahead (`can_run_ahead`),
+                # every row is the host's, and the carry is the host's
+                # vectors again: an output fed back would be a committed
+                # operand, a second lowering of every program
+                carry = (token, keys) if self._carry is None else self._carry
+                gr = {}
+                if eng.grammar is not None:
+                    # grammar-capable engine: the SAME warm program serves
+                    # constrained and free rows — the state vector (FREE 0
+                    # for unconstrained rows) is just another small operand.
+                    # The in-graph final states are discarded: the host
+                    # sessions are authoritative and re-advance from the
+                    # fetched tokens before the next step is dispatched.
+                    gr = dict(
+                        grammar_table=eng._gr_operand(),
+                        grammar_state=jnp.asarray(
+                            np.fromiter(
+                                (g.row_state if g is not None else 0 for g in self.grammars),
+                                np.int32,
+                                count=len(self.grammars),
+                            )
+                        ),
+                    )
+                toks, eng.cache, keys, last, moe, _ = batch_decode_chunk(
                     eng.cfg, eng.params, eng.rope, eng.cache,
-                    token, pos, keys, temp, topp, n_steps=n_steps, kv_len=kv_len,
+                    token, pos, keys, temp, topp, *carry, from_host,
+                    n_steps=n_steps, kv_len=kv_len,
                     page_table=eng._pt_operand() if eng.paged else None,
-                    page_size=eng.page_size,
+                    page_size=eng.page_size, **gr,
                 )
-            # the fetch is the batch path's one blocking device call —
-            # watchdogged like the solo decode path, so a wedged device
-            # raises StallError into the Batcher loop (reset + bounded
-            # client retry) instead of hanging every co-batched request
-            if phases is not None:
-                phases.enter("step.fetch", n_steps)
-            host = eng._host_fetch(toks)
-            # .copy(): the fetched view of a device array is READ-ONLY, and
-            # admit writes rows into these between chunks
-            self.keys = eng._host_fetch(keys).copy()
-            if eng.cache.moe is not None:
-                seen = eng._host_fetch(eng.cache.moe).astype(np.int64)
+                if self.can_run_ahead:
+                    self._carry = (last, keys)
+        chunk = DecodeChunk(
+            n_steps, toks, keys, moe, t_chunk,
+            ahead=self._newest is not None and not self._newest.fetched,
+        )
+        self._newest = chunk
+        self.from_host[:] = False
+        self.pos += n_steps
+        # parked rows stay pinned at seq_len (a long-lived session must not
+        # creep their positions toward int32 range)
+        self.pos[~self.active] = self.seq_len
+        return chunk
+
+    def fetch(self, chunk: "DecodeChunk") -> np.ndarray:
+        """Wait for a dispatched chunk and return its host tokens
+        [b, n_steps] (junk in the rows that were parked when it was
+        dispatched). Chunks are fetched in the order they were dispatched."""
+        eng = self.engine
+        phases = self.phases
+        if phases is not None:
+            phases.enter("step.fetch", chunk.n_steps)
+        # the fetch is the batch path's one blocking device call —
+        # watchdogged like the solo decode path, so a wedged device raises
+        # StallError into the Batcher loop (reset + bounded client retry)
+        # instead of hanging every co-batched request. The only
+        # device->host syncs of the decode path are the _host_fetch calls
+        # below (DLT_SANITIZERS=1)
+        with eng._sanitizer_scope(), watchdog(
+            f"batch_decode[{chunk.n_steps}] fetch", stats=eng.stats
+        ):
+            host = eng._host_fetch(chunk.toks)
+            if chunk is self._newest:
+                # nothing was dispatched since: the host's copy is the truth
+                # again for every row it has not set since (a lock-step
+                # caller, a mesh, the turn before a verify round)
+                rows = ~self.from_host
+                self.token[rows] = host[rows, -1]
+                self.keys[rows] = eng._host_fetch(chunk.keys)[rows]
+                self.from_host[:] = True
+            if chunk.moe is not None:
+                seen = eng._host_fetch(chunk.moe).astype(np.int64)
                 if self._moe_seen is not None:
                     # int32 sums that wrap: a chunk's difference is far
                     # under 2**31, so it survives the wrap
                     self.moe_counts = (seen - self._moe_seen) % (1 << 32)
                 self._moe_seen = seen
-        # whole-chunk wall (dispatch + fetch): the batched serving path's
-        # per-program series — /stats latency numbers and the roofline join
+        now = time.perf_counter()
+        # the chunk's own interval: from its dispatch, or from its
+        # predecessor's fetch where it was dispatched ahead of that and
+        # waited its turn on the device, to its fetch. The series sums to the
+        # wall a decoding session spent, one chunk at a time:
+        # /stats latency numbers and the roofline join
         # (profiling.roofline_view) read it exactly like solo decode[n]
+        chunk.t_start = max(chunk.t_dispatch, self._t_fetched)
+        chunk.t_end = self._t_fetched = now
+        chunk.fetched = True
+        chunk.toks = chunk.keys = chunk.moe = None
         eng.stats.record(
-            f"batch_decode[{n_steps}]", (time.perf_counter() - t_chunk) * 1e6
+            f"batch_decode[{chunk.n_steps}]", (now - chunk.t_start) * 1e6
         )
-        self.pos += n_steps
-        # parked rows stay pinned at seq_len (a long-lived session must not
-        # creep their positions toward int32 range)
-        self.pos[~self.active] = self.seq_len
-        self.token = host[:, -1].copy()
         return host

@@ -179,6 +179,18 @@ def chunk_plan(n_tokens: int, pos_start: int, max_chunk: int, seq_len: int):
         i += n_real
 
 
+# decode steps a chunk where nobody chose (`decode_chunk_size`). A loop that
+# fetches every chunk before it dispatches the next pays its host turn with
+# the device idle, so its chunk is long, behind a first-chunk ramp of 8: the
+# solo loops (`generate`, the library's callers) and a Batcher on a mesh. A
+# server's Batcher on one chip dispatches one chunk ahead of the device
+# (`BatchSession.dispatch` / `fetch`): its host turn hides behind the chunk
+# that runs, and the chunk is what a freed row and a first token wait for
+# (PERF.md section 6, PR 41)
+SOLO_CHUNK = 64
+BATCHER_CHUNK = 16
+
+
 class _ProgramGuard(watchdog):
     """`_guard`'s watchdog, which also holds the thread's program slot
     (tracing.ProgramSpan) for as long as the guarded call runs."""
@@ -213,9 +225,10 @@ class InferenceEngine:
         mesh=None,
         cache_dtype: str | None = None,
         device_decode: bool = True,
-        decode_chunk_size: int = 64,  # decode steps per host dispatch: one
-        # dispatch + one token fetch per chunk; a stop token wastes at most
-        # the chunk's tail (not re-derived on the current stack — ROADMAP S4)
+        decode_chunk_size: int | None = None,  # decode steps per host
+        # dispatch: one dispatch + one token fetch per chunk; a stop token
+        # wastes at most the chunk's tail. None = by who drives the engine
+        # (`SOLO_CHUNK` / `BATCHER_CHUNK` below)
         verbose: bool = False,
         q80_activations: bool = False,
         execution: str = "auto",
@@ -411,7 +424,11 @@ class InferenceEngine:
         # False = per-token host loop with the reference's exact RNG stream.
         self.device_decode = device_decode
         self.server_role = server_role
-        self.decode_chunk_size = decode_chunk_size
+        self.decode_chunk_size = decode_chunk_size or (
+            BATCHER_CHUNK
+            if server_role is not None and not self.warms_solo_programs and mesh is None
+            else SOLO_CHUNK
+        )
         self.stats = StepStats()
         # KV layout (runtime/paged_kv.py): paged replaces the per-row
         # contiguous slabs with a page pool + per-row page tables. The
@@ -762,12 +779,9 @@ class InferenceEngine:
         plan = []
         kvbs = self._kv_buckets()
         prefill_sizes = _chunk_buckets(self.max_chunk)
-        decode_sizes = sorted(
-            set(
-                self._halving_sizes(self.decode_chunk_size)
-                + self._halving_sizes(min(8, self.decode_chunk_size))
-            )
-        )
+        # the chunk and what a shrink loop makes of it; the first-chunk ramp
+        # of 8 is among a longer chunk's halves
+        decode_sizes = self._halving_sizes(self.decode_chunk_size)
         batched = self.batch > 1 and self.device_decode
         for kvb in kvbs if self.warms_solo_programs else []:
             for s in prefill_sizes:
@@ -1063,7 +1077,7 @@ class InferenceEngine:
                 # so the max_chunk bucket itself gets warmed whenever it fits
                 room = self.cfg.seq_len - self.decode_chunk_size - 10
                 s.admit(0, [1] * max(2, min(self.max_chunk, room)))
-                for chunk in (8, self.decode_chunk_size):
+                for chunk in (min(8, self.decode_chunk_size), self.decode_chunk_size):
                     if s.pos[0] + 1 + chunk <= self.cfg.seq_len:
                         s.step(chunk)
                 s.release(0)
@@ -1329,46 +1343,40 @@ class InferenceEngine:
         keys = jnp.zeros((b, 2), jnp.uint32)
         temp = jnp.zeros((b,), jnp.float32)
         topp = jnp.full((b,), 0.9, jnp.float32)
+        # the paged operands are part of the compiled shape: warming
+        # without them compiled a contiguous-signature program the
+        # serving path never dispatches (a post-seal recompile at
+        # every deep kv bucket — caught by the deep-bucket test)
+        paged = dict(
+            page_table=self._pt_operand() if self.paged else None,
+            page_size=self.page_size,
+        )
         if self.use_pipeline:
             from ..parallel.pipeline import pipeline_batch_decode_chunk
 
             _, self.cache, _ = pipeline_batch_decode_chunk(
                 self.cfg, self.mesh, self.params, self.rope, self.cache,
                 token, pos_vec, keys, temp, topp, n_steps=n_steps,
-                kv_len=kv_len,
-                page_table=self._pt_operand() if self.paged else None,
-                page_size=self.page_size,
+                kv_len=kv_len, **paged,
             )
-        elif self.grammar is not None:
-            from .batch_session import batch_decode_chunk
+            return
+        from .batch_session import batch_decode_chunk
 
-            # the grammar operands are part of the compiled shape too
-            # (same rule as the paged operands below): BatchSession.step
-            # always threads them on a grammar-capable engine, so the warm
-            # program must carry them
-            _, self.cache, _, _ = batch_decode_chunk(
-                self.cfg, self.params, self.rope, self.cache,
-                token, pos_vec, keys, temp, topp, n_steps=n_steps,
-                kv_len=kv_len,
-                page_table=self._pt_operand() if self.paged else None,
-                page_size=self.page_size,
+        gr = {}
+        if self.grammar is not None:
+            # the grammar operands are part of the compiled shape too:
+            # BatchSession.dispatch always threads them on a grammar-capable
+            # engine, so the warm program must carry them
+            gr = dict(
                 grammar_table=self._gr_operand(),
                 grammar_state=jnp.zeros((b,), jnp.int32),
             )
-        else:
-            from .batch_session import batch_decode_chunk
-
-            _, self.cache, _ = batch_decode_chunk(
-                self.cfg, self.params, self.rope, self.cache,
-                token, pos_vec, keys, temp, topp, n_steps=n_steps,
-                kv_len=kv_len,
-                # the paged operands are part of the compiled shape: warming
-                # without them compiled a contiguous-signature program the
-                # serving path never dispatches (a post-seal recompile at
-                # every deep kv bucket — caught by the deep-bucket test)
-                page_table=self._pt_operand() if self.paged else None,
-                page_size=self.page_size,
-            )
+        self.cache = batch_decode_chunk(
+            self.cfg, self.params, self.rope, self.cache,
+            token, pos_vec, keys, temp, topp, token, keys,
+            jnp.ones((b,), bool), n_steps=n_steps, kv_len=kv_len,
+            **paged, **gr,
+        )[1]
 
     def _guard(self, label: str, key) -> watchdog:
         """Watchdog for a blocking device call; `key` identifies the

@@ -409,8 +409,10 @@ def lower_entry(engine, key):
             return jax.jit(fn).lower(a_params, a_rope, a_cache, *args)
         from .batch_session import batch_decode_chunk
 
+        # the carry (last token, keys) and the rows the host set
+        carry = (args[0], args[2], _sds((b,), jnp.bool_))
         return batch_decode_chunk.lower(
-            cfg, a_params, a_rope, a_cache, *args, n_steps=size, kv_len=kvb,
+            cfg, a_params, a_rope, a_cache, *args, *carry, n_steps=size, kv_len=kvb,
             page_table=pt_sds, page_size=ps,
             grammar_table=gr_sds, grammar_state=gr_state(b),
         )

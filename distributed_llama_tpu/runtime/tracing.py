@@ -304,14 +304,19 @@ def trace_payload(trace_id: str, events: list) -> dict:
 # -- batch-composition timeline ----------------------------------------------
 
 #: the phases that partition one turn of the Batcher's loop (server/api.py
-#: `Batcher._loop`, runtime/batch_session.py `BatchSession.step`), in the
-#: order a turn passes through them, with each span's argument keys. They
-#: do not overlap and leave nothing out: a phase ends where the next one
-#: starts (runtime/phases.py `PhaseClock`), so over any window the spans
-#: add up to the Batcher thread's wall. `turn` is the loop iteration's
-#: ordinal: the phases of one turn join on it without interval arithmetic.
-#: `batch_step` (the decode chunk's wall) contains `batcher.draft`,
-#: `step.dispatch` and `step.fetch` of its turn.
+#: `Batcher._turn`, runtime/batch_session.py `BatchSession.dispatch` /
+#: `fetch`), in the order a turn passes through them, with each span's
+#: argument keys. They do not overlap and leave nothing out: a phase ends
+#: where the next one starts (runtime/phases.py `PhaseClock`), so over any
+#: window the spans add up to the Batcher thread's wall. `turn` is the loop
+#: iteration's ordinal: the phases of one turn join on it without interval
+#: arithmetic. A turn dispatches chunk k+1 and then fetches and delivers
+#: chunk k (`step.fetch` carries the fetched chunk's `n_steps`); a lock-step
+#: turn (`Batcher._turn`) dispatches and fetches the same chunk.
+#: `batch_step` is a decode chunk's own interval on the device's side of
+#: the loop (`BatchSession.fetch`), emitted at its delivery, and names the
+#: turn whose `step.dispatch` dispatched it; `ahead` says that happened
+#: before the chunk before it was fetched.
 BATCHER_PHASES = {
     # blocked on the request queue with every row free and nothing waiting
     "batcher.idle": ("turn",),
@@ -349,8 +354,10 @@ def batch_timeline_chrome(events: list) -> list:
     ``batch_slots`` stacks decoding/prefilling/free rows, ``kv_pool`` plots
     pages used — so chrome://tracing / Perfetto render slot composition and
     pool pressure as stacked area charts over time; the turn's phases are
-    ``X`` slices on the same track (the chunk holds its draft, dispatch and
-    fetch); park/shed/first-token marks land as global instant events."""
+    ``X`` slices on the track beside it (a chunk dispatched ahead runs while
+    the thread admits, dispatches the next and fetches the one before, so
+    the two do not nest); park/shed/first-token marks land as global
+    instant events."""
     out: list = []
     pid = os.getpid()
     for ev in events:
@@ -370,7 +377,7 @@ def batch_timeline_chrome(events: list) -> list:
                 {
                     "name": "chunk", "cat": "dlt_batch", "ph": "X",
                     "ts": int(t_us), "dur": max(int(dur_us), 1),
-                    "pid": pid, "tid": 0, "args": args,
+                    "pid": pid, "tid": 1, "args": args,
                 }
             )
             slots = {

@@ -265,6 +265,12 @@ class _BatchReq:
         # queue, in order — the retirement-time prefix-cache publish needs
         # the row's actual token chain (ids + generated)
         self.n = 0  # tokens decoded into this row (budget accounting)
+        self.n_dispatched = 0  # decode steps dispatched for this row: the
+        # loop runs one chunk ahead of the device, so `n` trails it by the
+        # chunk in flight; what reads counts is decided from this one
+        self.drained = False  # the chunks dispatched cover `max_new` (or
+        # seq_len): the row is parked on the device and keeps its slot until
+        # the chunk in flight delivers its last tokens
         self.n_out = 0  # tokens actually delivered to on_token (usage
         # accounting: excludes post-stop overrun the writer drains away)
         self.n_overrun = 0  # chunk-tail tokens the engine decoded PAST
@@ -279,6 +285,24 @@ class _BatchReq:
 
 #: queue sentinel waking the Batcher loop for shutdown (never a request)
 _BATCHER_STOP = object()
+
+#: decode chunks between two tries at a speculative round once a try's
+#: drafts all came up empty (`Batcher._may_draft`)
+DRAFT_RETRY_TURNS = 3
+
+
+class _Dispatched:
+    """A decode chunk the Batcher dispatched and has not delivered yet: the
+    session's handle, the requests that decode in it by row, the turn whose
+    `step.dispatch` dispatched it and the pool's pages at that moment."""
+
+    __slots__ = ("chunk", "rows", "turn", "pool_pages_used")
+
+    def __init__(self, chunk, rows, turn, pool_pages_used):
+        self.chunk = chunk
+        self.rows = rows
+        self.turn = turn
+        self.pool_pages_used = pool_pages_used
 
 
 class Batcher:
@@ -316,9 +340,11 @@ class Batcher:
 
         self.state = state
         engine = state.engine
-        # chunk = admission latency quantum. Smaller admits faster but pays
-        # more dispatch round trips per token; the engine default balances
-        # the two for throughput.
+        # chunk = admission latency quantum: what a freed row and a first
+        # token wait for. The loop dispatches one chunk ahead of the device
+        # (`_turn`), so the host's turn is hidden behind the chunk that runs
+        # and a short chunk costs the device nothing: the engine's default
+        # for a server's Batcher is `runtime.engine.BATCHER_CHUNK`.
         self.chunk = chunk_size or engine.decode_chunk_size
         # interleaved admission: a newcomer's prompt prefills at most this
         # many tokens per decode-chunk boundary (one max_chunk prefill chunk
@@ -361,7 +387,7 @@ class Batcher:
         self._em_timeline = TRACER.bind_global(
             "batch_step",
             ("decoding", "prefilling", "free", "spec",
-             "pool_pages_used", "queue_depth", "turn")
+             "pool_pages_used", "queue_depth", "turn", "ahead")
             + (() if self._moe_totals is None else (
                 "expert_pairs", "experts_hit",
                 "prefill_expert_pairs", "prefill_experts_hit",
@@ -374,6 +400,20 @@ class Batcher:
         # readers take racy-but-consistent-enough snapshots
         self.slots: list[_BatchReq | None] = [None] * engine.batch
         self.backlog: "object" = None  # set by the loop (deque)
+        # the loop's own state (`_turn`): its session, the chunk dispatched
+        # and not delivered yet, and what its policies remember
+        self.session = None
+        self._in_flight: _Dispatched | None = None
+        self._preempted_last = False
+        self._ramped_last = False
+        self._draft_wait = 0
+        self._overrun_carry = 0  # overrun `_finish` counted since the last
+        # delivery: the next `batcher.deliver` span carries it
+        # chunks dispatched before their predecessor was fetched, and the
+        # others, verify rounds among them (/stats `batcher`; a `batch_step`
+        # span carries `ahead`)
+        self.chunks_ahead = 0
+        self.chunks_lockstep = 0
         self._stopping = False  # set by stop(); the loop exits at the next
         # boundary, failing whatever is still in flight — teardown must
         # release the engine (and its sealed sentinel), not strand it on a
@@ -414,6 +454,8 @@ class Batcher:
             "max_backlog": self.max_backlog,
             "chunk_size": self.chunk,
             "prefill_budget": self.prefill_budget,
+            "chunks_ahead": self.chunks_ahead,
+            "chunks_lockstep": self.chunks_lockstep,
         }
 
     def queue_depth(self) -> int:
@@ -529,9 +571,21 @@ class Batcher:
         s = Sampler(1, 1.0, 0.9, seed)
         return np.asarray(jax.random.key_data(_sampler_prng_key(s)))
 
-    def _finish(self, req: _BatchReq, session, slots, row):
+    def _finish(self, req: _BatchReq, row: int):
         import queue
 
+        session = self.session
+        ahead = self._in_flight
+        if ahead is not None and ahead.rows.get(row) is req:
+            # the loop runs one chunk ahead of the device: a row that ends by
+            # what its TOKENS say (EOS, a grammar terminal, a stopped client,
+            # a deadline, a shed) is found with that chunk dispatched already.
+            # Its tokens there are decoded, discarded at the delivery and
+            # counted here, once, as overrun. A row that ends by COUNT never
+            # gets here with a chunk in flight (`_rows_to_decode`)
+            del ahead.rows[row]
+            req.n_overrun += ahead.chunk.n_steps
+            self._overrun_carry += ahead.chunk.n_steps
         if req.trace is not None:
             # terminal event: errors land even for unsampled traces, so a
             # failed request is always reconstructable from /debug/trace
@@ -554,8 +608,14 @@ class Batcher:
             # the compiled grammar itself stays in the ApiState LRU
             req.grammar_session.close()
             req.grammar_session = None
+        # the row's pages go back to the pool HERE, with a chunk that still
+        # writes them possibly in flight (the junk chunk above). The device
+        # runs programs in dispatch order: whoever is handed one of these
+        # pages writes it with a program dispatched after this instant, so
+        # after the junk, and reads nothing of it before its own write (the
+        # parked-row write-before-read invariant)
         session.release(row)
-        slots[row] = None
+        self.slots[row] = None
         req.done.set()
         try:
             req.emit.put_nowait(None)  # wake the writer (FIFO: after tokens)
@@ -563,13 +623,19 @@ class Batcher:
             pass  # writer will notice done via its get timeout
 
     def _timeline_step(
-        self, engine, slots, n_decoding: int, t_us: int, dur_us: int,
-        spec: bool, moe_counts=None,
+        self, n_decoding: int, t_us: int, dur_us: int, spec: bool = False,
+        moe_counts=None, turn: int | None = None, ahead: bool = False,
+        pool_pages_used: int | None = None,
     ):
         """One batch-composition snapshot: slot roles + pool/backlog
-        occupancy at this step boundary. A pre-bound tuple append.
+        occupancy. A pre-bound tuple append. For a decode chunk it is
+        emitted at the chunk's delivery, spans the chunk's own interval
+        (`BatchSession.fetch`) and names the turn whose `step.dispatch`
+        dispatched it, with the rows and the pool's pages of that dispatch.
         `moe_counts`: `BatchSession.moe_counts` of the chunk that just ran
         (None where no chunk ran, and before a session's second fetch)."""
+        engine = self.state.engine
+        slots = self.slots
         n_prefilling = sum(
             1 for s in slots if s is not None and s.prefilling
         )
@@ -581,12 +647,15 @@ class Batcher:
             )
             for i, v in enumerate(moe):
                 self._moe_totals[i] += v
+        if pool_pages_used is None:
+            pool_pages_used = engine.page_pool.used_pages if engine.paged else 0
         self._em_timeline(
             t_us, dur_us, n_decoding, n_prefilling, n_free,
             1 if spec else 0,
-            engine.page_pool.used_pages if engine.paged else 0,
+            pool_pages_used,
             self.queue_depth(),
-            self.phases.turn,
+            self.phases.turn if turn is None else turn,
+            1 if ahead else 0,
             *moe,
         )
 
@@ -630,7 +699,7 @@ class Batcher:
                 "first_chunk", req.t_armed_us, first_chunk_us, ("row",), (row,)
             )
 
-    def _shed_expired(self, session, slots):
+    def _shed_expired(self):
         """Per-chunk-boundary deadline sweep: a row whose end-to-end
         deadline passed retires NOW — decode and PREFILL alike are
         compute for an answer the client stopped waiting for. Tokens it
@@ -638,7 +707,7 @@ class Batcher:
         (complete_batched's ledger path)."""
         now_mono = time.monotonic()
         engine = self.state.engine
-        for row, req in enumerate(slots):
+        for row, req in enumerate(self.slots):
             if (
                 req is None or req.deadline is None
                 or now_mono <= req.deadline
@@ -654,7 +723,7 @@ class Batcher:
             req.error = req.error or DeadlineExceeded(
                 "deadline passed mid-serve"
             )
-            self._finish(req, session, slots, row)
+            self._finish(req, row)
 
     def _drained(self, req: _BatchReq):
         """One request moved from self.q into the class backlog: its
@@ -664,535 +733,687 @@ class Batcher:
             n = self._pending_by_class.get(req.slo_class, 0)
             self._pending_by_class[req.slo_class] = max(n - 1, 0)
 
+    # -- the loop: one turn is admission, the staged prompt's prefill, the
+    # -- next chunk's dispatch, the chunk before's fetch and its delivery
+
     def _loop(self):
-        import queue
-
-        from ..runtime.batch_session import BatchSession
-        from ..runtime.paged_kv import PagePoolExhausted
-
         from .scheduler import ClassQueues
 
-        engine = self.state.engine
-        session = BatchSession(engine)
-        # the session's step enters step.dispatch / step.fetch on the
-        # loop's own clock (runtime/phases.py): one partition of the thread
-        phases = session.phases = self.phases
-        slots = self.slots
+        self._new_session()
         # class-priority backlog (server/scheduler.py): interactive drains
         # before standard drains before batch; within a class, FIFO — the
         # pre-SLO-class all-standard behavior is byte-identical
-        backlog = ClassQueues()
-        self.backlog = backlog
-        ramped_last = False
-        preempted_last = False  # one preemption per chunk boundary: reset
-        # only after a decode chunk actually ran, so a backlog of waiters
-        # cannot cascade-evict every lower-class row with zero decode
-        # steps between (the twin's one-outstanding-preemption rule)
+        self.backlog = ClassQueues()
+        while not self._stopping:
+            self._turn()
+        self._fail_everything()
+        self.phases.close()
 
+    def _new_session(self):
+        from ..runtime.batch_session import BatchSession
+
+        self.session = BatchSession(self.state.engine)
+        # the session's dispatch and fetch enter step.dispatch / step.fetch
+        # on the loop's own clock (runtime/phases.py): one partition of the
+        # thread
+        self.session.phases = self.phases
+        self._in_flight = None
+
+    def _fail_everything(self):
+        """Teardown: fail everything still queued or in flight so writers
+        unblock — the engine is now releasable (ApiState.close owns the
+        actual close)."""
+        import queue
+
+        for row, req in enumerate(self.slots):
+            if req is not None:
+                req.error = req.error or Overloaded(retry_after_s=2)
+                self._finish(req, row)
+        for req in list(self.backlog):
+            req.error = Overloaded(retry_after_s=2)
+            req.done.set()
         while True:
-            if self._stopping:
-                # teardown: fail everything still queued or in flight so
-                # writers unblock, then exit — the engine is now
-                # releasable (ApiState.close owns the actual close)
-                for row, req in enumerate(slots):
-                    if req is not None:
-                        req.error = req.error or Overloaded(retry_after_s=2)
-                        self._finish(req, session, slots, row)
-                for req in list(backlog):
-                    req.error = Overloaded(retry_after_s=2)
-                    req.done.set()
-                while True:
-                    try:
-                        req = self.q.get_nowait()
-                    except queue.Empty:
-                        break
-                    if req is _BATCHER_STOP:
-                        continue
-                    req.error = Overloaded(retry_after_s=2)
-                    req.done.set()
-                phases.close()
-                return
-            # drain the queue into the class backlog; block only when fully
-            # idle (no active slots and nothing waiting). A turn's phases
-            # start here: whatever the last turn left open (it may have
-            # left through any `continue` below) ends at this instant.
-            idle = all(s is None for s in slots)
-            if idle and not backlog:
-                phases.begin_turn("batcher.idle")
-                req = self.q.get()
-                phases.enter("batcher.admit", 0, 0)
-                if req is _BATCHER_STOP:
-                    continue
-                self._drained(req)
-                backlog.append(req, req.slo_class)
-            else:
-                phases.begin_turn("batcher.admit", 0, 0)
-            n_admitted = 0
-            while True:
-                try:
-                    req = self.q.get_nowait()
-                except queue.Empty:
-                    break
-                if req is _BATCHER_STOP:
-                    continue
-                self._drained(req)
-                backlog.append(req, req.slo_class)
-            # admit in class-priority order into free slots at this chunk
-            # boundary (within a class: arrival order).
-            # Admission only STAGES the prompt (begin_admit): the prefill
-            # itself advances in bounded chunks interleaved between decode
-            # steps below, so a long newcomer prompt no longer stalls every
-            # co-batched decode stream for its whole prefill (the old
-            # admit-then-full-prefill behavior; Sarathi-style piggyback).
-            for row in range(engine.batch):
-                if slots[row] is not None or not backlog:
-                    continue
-                req = backlog.popleft()
-                if req.deadline is not None and time.monotonic() > req.deadline:
-                    # the deadline passed while the request sat in the
-                    # backlog: shed it BEFORE spending a prefill on an
-                    # answer nobody is waiting for — the cheapest token is
-                    # the one never decoded
-                    engine.stats.incr("deadline_shed")
-                    self.scheduler.record(req.slo_class, "shed_backlog")
-                    # timeline mark: once per shed decision, cold path
-                    TRACER.event(  # dlt: allow(trace-hot-emit)
-                        "batch_shed", now_us(), 0,
-                        ("row", "reason", "slo_class"),
-                        (row, "deadline", req.slo_class),
-                    )
-                    req.error = DeadlineExceeded(
-                        "deadline passed before admission"
-                    )
-                    req.done.set()
-                    continue
-                try:
-                    nowu = req.t_slot_us = now_us()
-                    t0 = req.t_enqueue_us or nowu
-                    req.ledger.queue_us = max(nowu - t0, 0)
-                    if req.trace is not None:
-                        # once per REQUEST (not per token): sanctioned cold
-                        # emit inside the admission sweep
-                        req.trace.event(  # dlt: allow(trace-hot-emit)
-                            "queue_wait", t0, max(nowu - t0, 0), ("row",), (row,)
-                        )
-                    if req.kv_external is not None:
-                        # deferred disaggregated-KV insert: THIS thread owns
-                        # the engine's dispatches, so the paged scatter (or
-                        # contiguous device_put) is race-free here, and the
-                        # begin_admit below then matches the fresh entry
-                        req.kv_external.apply(self.state)
-                        req.kv_external = None
-                    key = self._key_for_seed(req.seed) if req.seed is not None else None
-                    if req.grammar is not None:
-                        # arena install on THIS thread (it mutates the
-                        # shared table the next dispatch uploads); mixed
-                        # constrained/unconstrained rows co-batch through
-                        # the one warm program — free rows ride state 0
-                        from ..runtime.grammar import GrammarSession
-
-                        req.grammar_session = GrammarSession(
-                            engine.grammar, req.grammar
-                        )
-                    session.begin_admit(
-                        row, req.ids, temperature=req.temperature,
-                        topp=req.topp, key_data=key, trace=req.trace,
-                        grammar=req.grammar_session,
-                    )
-                    req.ledger.prefix_hit_tokens = session.pending_resume(row)
-                    req.prefilling = True
-                    slots[row] = req
-                    n_admitted += 1
-                    self.scheduler.record(req.slo_class, "admit")
-                except Exception as e:
-                    if req.grammar_session is not None:
-                        req.grammar_session.close()
-                        req.grammar_session = None
-                    req.error = e
-                    req.done.set()
-            phases.set(n_admitted, self.queue_depth())
-
-            # per-boundary deadline sweep over ALL active rows —
-            # PREFILLING included: a request whose deadline passed must
-            # stop burning prefill chunks exactly as it stops burning
-            # decode chunks (the pre-admission shed above catches only
-            # deadlines that died in the backlog; without this a long
-            # prompt with a short deadline would keep prefilling for
-            # dozens of boundaries after its answer went worthless)
-            self._shed_expired(session, slots)
-
-            # class preemption (server/scheduler.py): with every slot held
-            # and a higher-class request waiting, evict the lowest-class
-            # least-progress decoding row (strictly below the waiter's
-            # class — standard never preempts standard) so the waiter is
-            # admitted at the NEXT boundary instead of after a batch
-            # co-tenant's whole budget. At most one preemption per chunk
-            # boundary (`preempted_last` holds until a decode chunk runs);
-            # the victim gets the standard 503 + Retry-After.
-            if backlog and not preempted_last and all(
-                s is not None for s in slots
-            ):
-                victim = self.scheduler.preempt_victim(
-                    backlog.peek_class(),
-                    [
-                        (r, s.slo_class, s.n)
-                        for r, s in enumerate(slots)
-                        if s is not None and not s.prefilling
-                    ],
-                )
-                if victim is not None:
-                    preempted_last = True
-                    vreq = slots[victim]
-                    vreq.preempted = True
-                    vreq.error = vreq.error or Overloaded(retry_after_s=1)
-                    self.scheduler.record(vreq.slo_class, "preempt")
-                    # timeline mark: once per preemption decision, cold path
-                    TRACER.event(  # dlt: allow(trace-hot-emit)
-                        "batch_shed", now_us(), 0,
-                        ("row", "reason", "slo_class"),
-                        (victim, "preempt", vreq.slo_class),
-                    )
-                    self._finish(vreq, session, slots, victim)
-                    continue  # re-run admission: the freed slot goes to
-                    # the waiting higher-class request immediately
-
-            if all(s is None for s in slots):
-                continue
-            decode_rows = [
-                r for r, s in enumerate(slots) if s is not None and not s.prefilling
-            ]
-            # interleaved prefill: advance ONE staged admission per chunk
-            # boundary, in STAGING order (session.pending_rows) — finish the
-            # earliest prompt before starting a later one, so an in-flight
-            # admission's TTFT doesn't grow with later arrivals landing on
-            # lower-numbered rows. With live decode streams the advance is
-            # bounded by prefill_budget tokens; with none it runs to
-            # completion (nothing to starve).
-            prefill_rows = [
-                r
-                for r in session.pending_rows()
-                if slots[r] is not None and slots[r].prefilling
-            ]
-            armed = False
-            prefill_wall_us = 0  # this boundary's prefill advance (timeline)
-            if prefill_rows:
-                row = prefill_rows[0]
-                req = slots[row]
-                if req.stopped:
-                    # the client died mid-admission (writer thread flagged
-                    # it): abandon the rest of its prompt instead of burning
-                    # one prefill chunk per boundary on a dead request and
-                    # head-of-line blocking every admission staged behind it
-                    self._finish(req, session, slots, row)
-                    continue
-                try:
-                    budget = self.prefill_budget if decode_rows else None
-                    phases.enter("batcher.prefill", row, 0, -1)
-                    n_before = session.prefilled_tokens
-                    t_pf = time.perf_counter()
-                    remaining = session.prefill_pending(row, budget)
-                    prefill_wall_us = int((time.perf_counter() - t_pf) * 1e6)
-                    phases.set(
-                        row, session.prefilled_tokens - n_before, remaining
-                    )
-                    req.ledger.prefill_us += prefill_wall_us
-                    if decode_rows:
-                        engine.stats.incr("interleaved_prefill_chunks")
-                except PagePoolExhausted:
-                    # paged KV pool out of pages mid-admission. If no
-                    # OTHER row actually HOLDS pages (slot occupancy is
-                    # not enough — a staged co-tenant that never got a
-                    # page can free nothing), this prompt can never fit:
-                    # shed it with the standard 503 instead of spinning
-                    # forever. Reclaimable prefix entries don't count
-                    # either — the failed allocation already ran the
-                    # reclaim hook to exhaustion.
-                    if not decode_rows and not any(
-                        engine.page_pool.row_holds_pages(r)
-                        for r in range(engine.batch)
-                        if r != row
-                    ):
-                        engine.stats.incr("kv_pool_shed_503")
-                        self.scheduler.record(req.slo_class, "shed_pool")
-                        # timeline mark: once per shed decision, cold path
-                        TRACER.event(  # dlt: allow(trace-hot-emit)
-                            "batch_shed", now_us(), 0,
-                            ("row", "reason", "slo_class"),
-                            (row, "pool_admission", req.slo_class),
-                        )
-                        req.error = Overloaded(retry_after_s=2)
-                        self._finish(req, session, slots, row)
-                        continue
-                    # otherwise PARK: keep the prompt's progress and retry
-                    # at the next chunk boundary. Live decode rows MUST
-                    # keep stepping below — they are what finishes and
-                    # frees the pages the parked admission waits for (a
-                    # bare `continue` here livelocked: nobody decoded,
-                    # nobody freed). With co-tenants but none decoding,
-                    # yield briefly so the retry loop doesn't spin hot.
-                    engine.stats.incr("kv_pool_admission_parked")
-                    self.scheduler.record(req.slo_class, "park")
-                    # timeline mark: once per parked boundary, cold path
-                    TRACER.event(  # dlt: allow(trace-hot-emit)
-                        "batch_park", now_us(), 0,
-                        ("row", "pool_pages_used", "slo_class"),
-                        (row, engine.page_pool.used_pages, req.slo_class),
-                    )
-                    remaining = None
-                    if not decode_rows:
-                        time.sleep(0.005)
-                        continue
-                except Exception as e:
-                    req.error = e
-                    self._finish(req, session, slots, row)
-                    continue
-                if remaining == 0:
-                    req.prefilling = False
-                    req.t_armed_us = now_us()
-                    decode_rows.append(row)
-                    armed = True
-            if not decode_rows:
-                # only prefilling rows: no decode chunk to run yet — still a
-                # timeline step (admission stalls are exactly the pathology
-                # the post-hoc view exists to show)
-                self._timeline_step(
-                    engine, slots, 0, now_us() - prefill_wall_us,
-                    prefill_wall_us, spec=False,
-                )
-                continue
-            # a row at pos == seq_len-1 has zero decode headroom: finish it
-            # (the request keeps what it generated) instead of flooring the
-            # chunk clamp at 1 and letting session.step's overrun guard fail
-            # every co-batched request — reachable for library users driving
-            # the Batcher directly; the HTTP path's budget clamp never gets
-            # here. Prefilling rows are parked at seq_len by construction and
-            # must NOT be swept up by this check.
-            # ... and a row whose writer thread set `stopped` between
-            # chunks (client gone, stream cancelled) retires HERE, at the
-            # chunk boundary, instead of decoding up to a full extra chunk
-            # before the consume loop sees the flag — post-stop tokens are
-            # pure overrun waste
-            for row in list(decode_rows):
-                req = slots[row]
-                if req.stopped or session.seq_len - 1 - int(session.pos[row]) <= 0:
-                    self._finish(req, session, slots, row)
-                    decode_rows.remove(row)
-            if not decode_rows:
-                continue
-            # chunk size: ramp to 8 right after an admission finishes its
-            # prefill (a fresh request's first tokens — and a tiny request's
-            # only tokens — reach the client after ~8 steps, not a full
-            # chunk). The ramp alternates: never two ramped chunks in a row,
-            # so sustained admission traffic costs at most half the chunks
-            # (the round-4 loop re-ramped on EVERY admission and could run
-            # at chunk=8 permanently). The clamp is only the HARD seq_len
-            # headroom — a row hitting its own max_new mid-chunk just has
-            # its surplus tokens discarded and its slot released (no more
-            # shrinking every co-tenant's chunks to the smallest remaining
-            # budget, which fragmented steady-state traffic into 1-2-token
-            # dispatches).
-            headroom = min(
-                session.seq_len - 1 - int(session.pos[row]) for row in decode_rows
-            )
-            # speculative round (runtime/speculative.py): when every decode
-            # row is greedy with a full verify bucket of headroom, draft per
-            # row from its delivered context (prompt ids + streamed tokens)
-            # and verify all rows in ONE dispatch — rows whose draft came up
-            # empty still advance by their one greedy bonus token. A sampled
-            # co-tenant, tight headroom, or an all-empty draft round falls
-            # back to the plain chunk, so draft-hostile traffic keeps the
-            # chunked loop's throughput.
-            t_chunk = time.perf_counter()  # spans: draft + dispatch + fetch
             try:
-                # drafting runs INSIDE the failure scope: a model-backed
-                # draft source dispatches device work, and a wedged draft
-                # engine must take the same fail-requests-and-recover path
-                # as a main-engine failure — not kill the batcher thread
-                spec_drafts = None
-                if engine.spec_mode is not None and engine.device_decode:
-                    phases.enter("batcher.draft", 0)
-                    K = engine.spec_buckets[-1]
-                    if all(
-                        slots[r].temperature == 0.0
-                        and session.seq_len - int(session.pos[r]) >= K + 1
-                        for r in decode_rows
-                    ):
-                        try:
-                            drafts = {}
-                            for r in decode_rows:
-                                req = slots[r]
-                                cap = min(K, req.max_new - req.n - 1)
-                                drafts[r] = (
-                                    engine.draft_source.draft(
-                                        list(req.ids) + req.out_ids, cap
-                                    )
-                                    if cap > 0
-                                    else []
-                                )
-                            phases.set(sum(len(d) for d in drafts.values()))
-                            if any(drafts.values()):
-                                spec_drafts = drafts
-                        except PagePoolExhausted:
-                            # a paged DRAFT engine ran out of ITS OWN pool
-                            # (a separate allocator from the main engine's)
-                            # — shedding a main-batch row would free
-                            # nothing there. Degrade this round to the
-                            # plain chunk, the same fallback draft-hostile
-                            # traffic takes.
-                            engine.stats.incr("kv_pool_draft_skipped")
-                            spec_drafts = None
-                if spec_drafts is not None:
-                    per_row = session.spec_step(spec_drafts)
-                    phases.enter("batcher.deliver", 0, 0, 0)
-                else:
-                    n = min(8, self.chunk) if armed and not ramped_last else self.chunk
-                    ramped_last = armed and not ramped_last
-                    while n > max(headroom, 1):
-                        n //= 2
-                    n = max(n, 1)
-                    toks = session.step(n)
-                    phases.enter("batcher.deliver", 0, 0, 0)
-                    per_row = {
-                        r: [int(t) for t in toks[r]]
-                        for r, s in enumerate(slots)
-                        if s is not None and not s.prefilling
-                    }
-            except PagePoolExhausted:
-                # paged KV pool out of pages mid-decode (co-tenants grew
-                # into the budget together): SHED the lowest-SLO-class
-                # least-progress decode row (server/scheduler.py — the
-                # "whom" the ROADMAP item asked for; all-standard traffic
-                # reduces to the old least-progress pick) — its pages free
-                # immediately, everyone else keeps decoding. The shed
-                # client gets the standard 503 + Retry-After.
-                phases.enter("batcher.deliver", 0, 0, 1)
-                victim = self.scheduler.shed_victim(
-                    [(r, slots[r].slo_class, slots[r].n) for r in decode_rows]
-                )
-                vreq = slots[victim]
-                vreq.error = vreq.error or Overloaded(retry_after_s=1)
-                self.scheduler.record(vreq.slo_class, "shed_pool")
+                req = self.q.get_nowait()
+            except queue.Empty:
+                break
+            if req is _BATCHER_STOP:
+                continue
+            req.error = Overloaded(retry_after_s=2)
+            req.done.set()
+
+    def _turn(self):
+        """One turn of the loop, one chunk ahead of the device where it can
+        be: admit and stage from the queue, dispatch the staged prompt's
+        prefill chunk, dispatch chunk k+1, and only then wait for chunk k
+        and deliver it. The device always has its next program queued, so
+        the host's part of a turn costs the device nothing; what reads
+        COUNTS (a row's budget, seq_len headroom, pages, deadlines) is
+        decided at the dispatch, exactly; what reads TOKENS (EOS, a grammar
+        terminal, a stopped client) sees them a chunk later than the device
+        (`_finish`).
+
+        A turn that must see chunk k's tokens before it can build chunk
+        k+1's operands is lock-step, by what the loop observes: a decoding
+        row under a grammar (the host session advances from the fetched
+        tokens), a speculative round (drafts continue the delivered text), a
+        mesh (`BatchSession.can_run_ahead`). It delivers the chunk in flight
+        and ends; the turns after it dispatch, fetch and deliver in one."""
+        from ..runtime.paged_kv import PagePoolExhausted
+
+        if not self._drain_queue():
+            return
+        self._admit()
+        # per-boundary deadline sweep over ALL active rows —
+        # PREFILLING included: a request whose deadline passed must
+        # stop burning prefill chunks exactly as it stops burning
+        # decode chunks (the pre-admission shed in `_admit` catches only
+        # deadlines that died in the backlog; without this a long
+        # prompt with a short deadline would keep prefilling for
+        # dozens of boundaries after its answer went worthless)
+        self._shed_expired()
+        if self._preempt():
+            return  # re-run admission: the freed slot goes to the waiting
+            # higher-class request immediately
+        if all(s is None for s in self.slots) and self._in_flight is None:
+            return
+        armed = self._prefill_staged()
+        if armed is None:
+            return
+        rows = self._rows_to_decode()
+        prev = self._in_flight
+        if not rows and prev is None:
+            return
+        try_draft = bool(rows) and self._may_draft(rows)
+        lockstep = try_draft or (bool(rows) and self._must_see_tokens(rows))
+        t_turn = time.perf_counter()  # a verify round's span: draft + dispatch + fetch
+        try:
+            if prev is not None and (lockstep or not rows):
+                # nothing may be dispatched ahead of the chunk in flight
+                # (or nothing is left to dispatch): deliver it
+                self._in_flight = None
+                self._deliver(prev)
+                return
+            # drafting runs INSIDE the failure scope: a model-backed
+            # draft source dispatches device work, and a wedged draft
+            # engine must take the same fail-requests-and-recover path
+            # as a main-engine failure — not kill the batcher thread
+            drafts = self._draft(rows) if try_draft else None
+            if drafts is not None:
+                per_row = self.session.spec_step(drafts)
+                self.phases.enter("batcher.deliver", 0, 0, 0)
+                self._spec_delivered(rows, per_row, drafts, t_turn)
+                return
+            self._in_flight = self._dispatch(rows, armed)
+            if lockstep:
+                prev, self._in_flight = self._in_flight, None
+            if prev is not None:
+                self._deliver(prev)
+        except PagePoolExhausted:
+            self._shed_for_pages(rows)
+        except Exception as e:
+            self._fail_and_recover(e)
+
+    def _shed_for_pages(self, rows):
+        """Paged KV pool out of pages mid-decode (co-tenants grew
+        into the budget together): SHED the lowest-SLO-class
+        least-progress decode row (server/scheduler.py — the
+        "whom" the ROADMAP item asked for; all-standard traffic
+        reduces to the old least-progress pick) — its pages free
+        immediately, everyone else keeps decoding. The shed
+        client gets the standard 503 + Retry-After."""
+        self.phases.enter("batcher.deliver", 0, 0, 1)
+        slots = self.slots
+        victim = self.scheduler.shed_victim(
+            [(r, slots[r].slo_class, slots[r].n) for r in rows]
+        )
+        vreq = slots[victim]
+        vreq.error = vreq.error or Overloaded(retry_after_s=1)
+        self.scheduler.record(vreq.slo_class, "shed_pool")
+        # timeline mark: once per shed decision, cold path
+        TRACER.event(  # dlt: allow(trace-hot-emit)
+            "batch_shed", now_us(), 0,
+            ("row", "reason", "slo_class"),
+            (victim, "pool_decode", vreq.slo_class),
+        )
+        self._finish(vreq, victim)
+        self.state.engine.stats.incr("kv_pool_shed_503")
+
+    def _fail_and_recover(self, e: Exception):
+        """Engine failure, at a dispatch or at a fetch: fail every
+        in-flight request (the chunk in flight is theirs too: its
+        handle is dropped with the session), then hand
+        the failure to the supervised recovery path — a cheap
+        in-place reset for a first transient stall, a full
+        teardown-and-rebuild (fresh pool/prefix cache/sentinel,
+        re-warmed ladder) for sticky stalls, fatal sanitizer
+        breaches, and unknown engine exceptions
+        (runtime/supervisor.py). THIS thread owns the engine's
+        dispatches, so the rebuild is race-free here; while it
+        runs, /health reports `recovering` (503) and new
+        admissions shed."""
+        # classify + pre-transition FIRST: by the time any failed
+        # request's 500 reaches its client, /health must already
+        # say `recovering` — a client that polls (or instantly
+        # retries) after its 500 must never read a stale `serving`
+        # and then get shed by the rebuild it didn't know about
+        self.phases.enter(
+            "batcher.deliver", 0, 0,
+            sum(1 for s in self.slots if s is not None),
+        )
+        entered = self.state.recover_enter(e)
+        self._in_flight = None
+        for row, req in enumerate(self.slots):
+            if req is not None:
+                req.error = e
+                self._finish(req, row)
+        self.state.recover(exc=e, entered=entered)
+        self._new_session()  # a rebuild swaps the engine object
+
+    def _drain_queue(self) -> bool:
+        """Drain the queue into the class backlog; block only when fully
+        idle (no active slots, nothing waiting, no chunk in flight). A
+        turn's phases start here: whatever the last turn left open (it may
+        have left through any `return` of `_turn`) ends at this instant.
+        False: the loop was woken to stop."""
+        import queue
+
+        phases, backlog = self.phases, self.backlog
+        idle = all(s is None for s in self.slots) and self._in_flight is None
+        if idle and not backlog:
+            phases.begin_turn("batcher.idle")
+            req = self.q.get()
+            phases.enter("batcher.admit", 0, 0)
+            if req is _BATCHER_STOP:
+                return False
+            self._drained(req)
+            backlog.append(req, req.slo_class)
+        else:
+            phases.begin_turn("batcher.admit", 0, 0)
+        while True:
+            try:
+                req = self.q.get_nowait()
+            except queue.Empty:
+                break
+            if req is _BATCHER_STOP:
+                continue
+            self._drained(req)
+            backlog.append(req, req.slo_class)
+        return not self._stopping
+
+    def _admit(self):
+        """Admit in class-priority order into free slots at this chunk
+        boundary (within a class: arrival order). Admission only STAGES
+        the prompt (begin_admit): the prefill itself advances in bounded
+        chunks interleaved between decode chunks (`_prefill_staged`), so a
+        long newcomer prompt does not stall every co-batched decode stream
+        for its whole prefill (Sarathi-style piggyback)."""
+        engine, session = self.state.engine, self.session
+        slots, backlog = self.slots, self.backlog
+        n_admitted = 0
+        for row in range(engine.batch):
+            if slots[row] is not None or not backlog:
+                continue
+            req = backlog.popleft()
+            if req.deadline is not None and time.monotonic() > req.deadline:
+                # the deadline passed while the request sat in the
+                # backlog: shed it BEFORE spending a prefill on an
+                # answer nobody is waiting for — the cheapest token is
+                # the one never decoded
+                engine.stats.incr("deadline_shed")
+                self.scheduler.record(req.slo_class, "shed_backlog")
                 # timeline mark: once per shed decision, cold path
                 TRACER.event(  # dlt: allow(trace-hot-emit)
                     "batch_shed", now_us(), 0,
                     ("row", "reason", "slo_class"),
-                    (victim, "pool_decode", vreq.slo_class),
+                    (row, "deadline", req.slo_class),
                 )
-                self._finish(vreq, session, slots, victim)
-                engine.stats.incr("kv_pool_shed_503")
+                req.error = DeadlineExceeded(
+                    "deadline passed before admission"
+                )
+                req.done.set()
                 continue
+            try:
+                nowu = req.t_slot_us = now_us()
+                t0 = req.t_enqueue_us or nowu
+                req.ledger.queue_us = max(nowu - t0, 0)
+                if req.trace is not None:
+                    # once per REQUEST (not per token): sanctioned cold
+                    # emit inside the admission sweep
+                    req.trace.event(  # dlt: allow(trace-hot-emit)
+                        "queue_wait", t0, max(nowu - t0, 0), ("row",), (row,)
+                    )
+                if req.kv_external is not None:
+                    # deferred disaggregated-KV insert: THIS thread owns
+                    # the engine's dispatches, so the paged scatter (or
+                    # contiguous device_put) is race-free here, and the
+                    # begin_admit below then matches the fresh entry
+                    req.kv_external.apply(self.state)
+                    req.kv_external = None
+                key = self._key_for_seed(req.seed) if req.seed is not None else None
+                if req.grammar is not None:
+                    # arena install on THIS thread (it mutates the
+                    # shared table the next dispatch uploads); mixed
+                    # constrained/unconstrained rows co-batch through
+                    # the one warm program — free rows ride state 0
+                    from ..runtime.grammar import GrammarSession
+
+                    req.grammar_session = GrammarSession(
+                        engine.grammar, req.grammar
+                    )
+                session.begin_admit(
+                    row, req.ids, temperature=req.temperature,
+                    topp=req.topp, key_data=key, trace=req.trace,
+                    grammar=req.grammar_session,
+                )
+                req.ledger.prefix_hit_tokens = session.pending_resume(row)
+                req.prefilling = True
+                slots[row] = req
+                n_admitted += 1
+                self.scheduler.record(req.slo_class, "admit")
             except Exception as e:
-                # engine failure: fail every in-flight request, then hand
-                # the failure to the supervised recovery path — a cheap
-                # in-place reset for a first transient stall, a full
-                # teardown-and-rebuild (fresh pool/prefix cache/sentinel,
-                # re-warmed ladder) for sticky stalls, fatal sanitizer
-                # breaches, and unknown engine exceptions
-                # (runtime/supervisor.py). THIS thread owns the engine's
-                # dispatches, so the rebuild is race-free here; while it
-                # runs, /health reports `recovering` (503) and new
-                # admissions shed.
-                # classify + pre-transition FIRST: by the time any failed
-                # request's 500 reaches its client, /health must already
-                # say `recovering` — a client that polls (or instantly
-                # retries) after its 500 must never read a stale `serving`
-                # and then get shed by the rebuild it didn't know about
-                phases.enter(
-                    "batcher.deliver", 0, 0,
-                    sum(1 for s in slots if s is not None),
-                )
-                entered = self.state.recover_enter(e)
-                for row, req in enumerate(slots):
-                    if req is not None:
-                        req.error = e
-                        self._finish(req, session, slots, row)
-                self.state.recover(exc=e, entered=entered)
-                engine = self.state.engine  # a rebuild swaps the object
-                session = BatchSession(engine)
-                session.phases = phases
-                continue
-            chunk_dur_us = int((time.perf_counter() - t_chunk) * 1e6)
-            preempted_last = False  # a decode chunk ran: the next boundary
-            # may preempt again if a higher-class waiter is still parked
-            t_chunk_us = to_us(t_chunk)
-            self._timeline_step(
-                engine, slots, len(decode_rows), t_chunk_us, chunk_dur_us,
-                spec=spec_drafts is not None, moe_counts=session.moe_counts,
+                if req.grammar_session is not None:
+                    req.grammar_session.close()
+                    req.grammar_session = None
+                req.error = e
+                req.done.set()
+        self.phases.set(n_admitted, self.queue_depth())
+
+    def _preempt(self) -> bool:
+        """Class preemption (server/scheduler.py): with every slot held
+        and a higher-class request waiting, evict the lowest-class
+        least-progress decoding row (strictly below the waiter's
+        class — standard never preempts standard) so the waiter is
+        admitted at the NEXT boundary instead of after a batch
+        co-tenant's whole budget. At most one preemption per chunk
+        boundary (`_preempted_last` holds until a decode chunk is
+        delivered, so a backlog of waiters cannot cascade-evict every
+        lower-class row with zero decode steps between: the twin's
+        one-outstanding-preemption rule); the victim gets the standard 503
+        + Retry-After. True: a row was evicted."""
+        slots, backlog = self.slots, self.backlog
+        if not backlog or self._preempted_last or any(s is None for s in slots):
+            return False
+        victim = self.scheduler.preempt_victim(
+            backlog.peek_class(),
+            [
+                (r, s.slo_class, s.n)
+                for r, s in enumerate(slots)
+                if not s.prefilling and not s.drained
+            ],
+        )
+        if victim is None:
+            return False
+        self._preempted_last = True
+        vreq = slots[victim]
+        vreq.preempted = True
+        vreq.error = vreq.error or Overloaded(retry_after_s=1)
+        self.scheduler.record(vreq.slo_class, "preempt")
+        # timeline mark: once per preemption decision, cold path
+        TRACER.event(  # dlt: allow(trace-hot-emit)
+            "batch_shed", now_us(), 0,
+            ("row", "reason", "slo_class"),
+            (victim, "preempt", vreq.slo_class),
+        )
+        self._finish(vreq, victim)
+        return True
+
+    def _prefill_staged(self):
+        """Interleaved prefill: advance ONE staged admission per chunk
+        boundary, in STAGING order (session.pending_rows) — finish the
+        earliest prompt before starting a later one, so an in-flight
+        admission's TTFT doesn't grow with later arrivals landing on
+        lower-numbered rows. With live decode streams the advance is
+        bounded by prefill_budget tokens; with none it runs to
+        completion (nothing to starve). The chunks are dispatch-only.
+        Returns whether a row armed, or None where the turn ends here."""
+        from ..runtime.paged_kv import PagePoolExhausted
+
+        engine, session, slots = self.state.engine, self.session, self.slots
+        prefill_rows = [
+            r
+            for r in session.pending_rows()
+            if slots[r] is not None and slots[r].prefilling
+        ]
+        if not prefill_rows:
+            return False
+        decoding = any(s is not None and not s.prefilling for s in slots)
+        row = prefill_rows[0]
+        req = slots[row]
+        if req.stopped:
+            # the client died mid-admission (writer thread flagged
+            # it): abandon the rest of its prompt instead of burning
+            # one prefill chunk per boundary on a dead request and
+            # head-of-line blocking every admission staged behind it
+            self._finish(req, row)
+            return None
+        try:
+            budget = self.prefill_budget if decoding else None
+            self.phases.enter("batcher.prefill", row, 0, -1)
+            n_before = session.prefilled_tokens
+            t_pf = time.perf_counter()
+            remaining = session.prefill_pending(row, budget)
+            prefill_wall_us = int((time.perf_counter() - t_pf) * 1e6)
+            self.phases.set(
+                row, session.prefilled_tokens - n_before, remaining
             )
-            n_put = n_over = n_finished = 0  # batcher.deliver's arguments
-            for row, req in enumerate(slots):
-                if req is None or req.prefilling or row not in per_row:
-                    continue
-                # one span per row per chunk through the pre-bound emitters
-                # (a tuple append each; the chunk wall is shared — per-row
-                # attribution is the row's token count / acceptance)
-                if spec_drafts is not None:
-                    req.ledger.spec_us += chunk_dur_us
-                    req.ledger.spec_accepted_tokens += max(len(per_row[row]) - 1, 0)
-                    if req._em_spec is not None:
-                        req._em_spec(
-                            t_chunk_us, chunk_dur_us,
-                            len(spec_drafts.get(row) or ()),
-                            max(len(per_row[row]) - 1, 0),
-                        )
+            req.ledger.prefill_us += prefill_wall_us
+            if decoding:
+                engine.stats.incr("interleaved_prefill_chunks")
+        except PagePoolExhausted:
+            # paged KV pool out of pages mid-admission. If no
+            # OTHER row actually HOLDS pages (slot occupancy is
+            # not enough — a staged co-tenant that never got a
+            # page can free nothing), this prompt can never fit:
+            # shed it with the standard 503 instead of spinning
+            # forever. Reclaimable prefix entries don't count
+            # either — the failed allocation already ran the
+            # reclaim hook to exhaustion.
+            if not decoding and not any(
+                engine.page_pool.row_holds_pages(r)
+                for r in range(engine.batch)
+                if r != row
+            ):
+                engine.stats.incr("kv_pool_shed_503")
+                self.scheduler.record(req.slo_class, "shed_pool")
+                # timeline mark: once per shed decision, cold path
+                TRACER.event(  # dlt: allow(trace-hot-emit)
+                    "batch_shed", now_us(), 0,
+                    ("row", "reason", "slo_class"),
+                    (row, "pool_admission", req.slo_class),
+                )
+                req.error = Overloaded(retry_after_s=2)
+                self._finish(req, row)
+                return None
+            # otherwise PARK: keep the prompt's progress and retry
+            # at the next chunk boundary. Live decode rows MUST
+            # keep stepping — they are what finishes and
+            # frees the pages the parked admission waits for (ending
+            # the turn here livelocked: nobody decoded,
+            # nobody freed). With co-tenants but none decoding,
+            # yield briefly so the retry loop doesn't spin hot.
+            engine.stats.incr("kv_pool_admission_parked")
+            self.scheduler.record(req.slo_class, "park")
+            # timeline mark: once per parked boundary, cold path
+            TRACER.event(  # dlt: allow(trace-hot-emit)
+                "batch_park", now_us(), 0,
+                ("row", "pool_pages_used", "slo_class"),
+                (row, engine.page_pool.used_pages, req.slo_class),
+            )
+            if not decoding:
+                time.sleep(0.005)
+                return None
+            return False
+        except Exception as e:
+            req.error = e
+            self._finish(req, row)
+            return None
+        if remaining == 0:
+            req.prefilling = False
+            req.t_armed_us = now_us()
+            return True
+        if not decoding:
+            # only prefilling rows: no decode chunk to run yet — still a
+            # timeline step (admission stalls are exactly the pathology
+            # the post-hoc view exists to show)
+            self._timeline_step(
+                0, now_us() - prefill_wall_us, prefill_wall_us
+            )
+        return False
+
+    def _rows_to_decode(self) -> list:
+        """The rows the next chunk decodes, decided from counts the host
+        has: a row whose budget (`max_new`) the chunks dispatched so far
+        cover, or that has no seq_len headroom left (reachable for library
+        users driving the Batcher directly; the HTTP path's budget clamp
+        never gets there), takes no further chunk. It is parked on the
+        device and keeps its slot until the chunk in flight brings its last
+        tokens (`_deliver` retires it); with nothing in flight it retires
+        now and keeps what it generated. So a row that ends by its budget
+        decodes no junk chunk: its surplus is the tail of its last chunk.
+        A row whose writer thread set `stopped` (client gone, stream
+        cancelled) retires HERE instead of decoding on; what it still has
+        in flight is overrun (`_finish`). Prefilling rows are parked at
+        seq_len by construction and are not swept up by the headroom
+        check."""
+        session, ahead = self.session, self._in_flight
+        rows = []
+        for row, req in enumerate(self.slots):
+            if req is None or req.prefilling or req.drained:
+                continue
+            if req.stopped:
+                self._finish(req, row)
+            elif (
+                req.n_dispatched >= req.max_new
+                or session.seq_len - 1 - int(session.pos[row]) <= 0
+            ):
+                if ahead is not None and ahead.rows.get(row) is req:
+                    req.drained = True
+                    session.park(row)
                 else:
-                    req.ledger.decode_us += chunk_dur_us
-                    if req._em_decode is not None:
-                        req._em_decode(t_chunk_us, chunk_dur_us, len(per_row[row]))
-                row_toks = per_row[row]
-                gr = req.grammar_session
-                if req.n == 0 and row_toks:
-                    self._first_tokens(req, row)
-                n_put += len(row_toks)
-                for i, t in enumerate(row_toks):
-                    req.n += 1
-                    req.out_ids.append(t)
-                    if gr is not None:
-                        # the host session is authoritative: re-advance it
-                        # from the fetched token before the next chunk's
-                        # state vector is assembled (the in-graph carry is
-                        # only its traced mirror)
-                        gr.advance(t)
-                    try:
-                        req.emit.put_nowait(t)
-                    except queue.Full:
-                        # this client is EMIT_DEPTH tokens behind its writer
-                        # — drop that row only; co-batched requests and the
-                        # engine are unaffected (the writer thread owns the
-                        # socket, so a merely-slow client costs nothing here)
-                        req.error = req.error or RuntimeError(
-                            "client fell too far behind the token stream"
-                        )
-                        req.stopped = True
-                    if (
-                        req.stopped or req.n >= req.max_new
-                        or t in req.eos_ids
-                        or (gr is not None and (gr.done or gr.at_terminal))
-                    ):
-                        # a grammar TERMINAL stop (the DFA reached a state
-                        # where only EOS remains legal) retires the row
-                        # exactly like EOS: the token that got it there was
-                        # DELIVERED — it lands in the goodput ledger as
-                        # generated, and the chunk tail past it is ordinary
-                        # overrun, not a new waste class.
-                        # surplus tokens past max_new in this chunk are
-                        # discarded; the row parks (session.release) so
-                        # co-tenants keep full-size chunks. The eos_ids
-                        # check is the row-local EOS signal: without it the
-                        # loop decodes up to a full extra chunk before the
-                        # writer thread's `stopped` flag is visible,
-                        # inflating req.n and burning decode compute. The
-                        # chunk tail past the stop WAS decoded by the
-                        # engine — without this count it would appear in
-                        # neither generated nor discarded tokens
-                        tail = len(row_toks) - i - 1
-                        req.n_overrun += tail
-                        n_put -= tail
-                        n_over += tail
-                        n_finished += 1
-                        self._finish(req, session, slots, row)
-                        break
-            phases.set(n_put, n_over, n_finished)
+                    self._finish(req, row)
+            else:
+                rows.append(row)
+        return rows
+
+    def _must_see_tokens(self, rows) -> bool:
+        """Whether the next chunk's operands need the tokens of the chunk
+        before (a verify round aside, `_may_draft`): a decoding row under a
+        grammar, a session that cannot run ahead (a mesh)."""
+        return not self.session.can_run_ahead or any(
+            self.slots[r].grammar_session is not None for r in rows
+        )
+
+    def _may_draft(self, rows) -> bool:
+        """A speculative round (runtime/speculative.py) is worth a try: every
+        decode row is greedy with a full verify bucket of headroom, and the
+        last try was not just now for nothing. Drafts continue the text
+        DELIVERED so far, so a try costs the loop its chunk ahead: after a
+        round of empty drafts the loop decodes `DRAFT_RETRY_TURNS` chunks
+        before it tries again, as many steps as the chunk that stood between
+        two tries when a chunk was 64 steps."""
+        engine, session, slots = self.state.engine, self.session, self.slots
+        if engine.spec_mode is None or not engine.device_decode:
+            return False
+        if self._draft_wait > 0:
+            self._draft_wait -= 1
+            return False
+        K = engine.spec_buckets[-1]
+        return all(
+            slots[r].temperature == 0.0
+            and session.seq_len - int(session.pos[r]) >= K + 1
+            for r in rows
+        )
+
+    def _draft(self, rows):
+        """Draft per row from its delivered context (prompt ids + streamed
+        tokens) for ONE verify dispatch over all rows — rows whose draft
+        came up empty still advance by their one greedy bonus token. An
+        all-empty round (None) falls back to the plain chunk, so
+        draft-hostile traffic keeps the chunked loop's throughput."""
+        from ..runtime.paged_kv import PagePoolExhausted
+
+        engine, slots = self.state.engine, self.slots
+        self.phases.enter("batcher.draft", 0)
+        K = engine.spec_buckets[-1]
+        try:
+            drafts = {}
+            for r in rows:
+                req = slots[r]
+                cap = min(K, req.max_new - req.n - 1)
+                drafts[r] = (
+                    engine.draft_source.draft(
+                        list(req.ids) + req.out_ids, cap
+                    )
+                    if cap > 0
+                    else []
+                )
+            self.phases.set(sum(len(d) for d in drafts.values()))
+            if any(drafts.values()):
+                return drafts
+        except PagePoolExhausted:
+            # a paged DRAFT engine ran out of ITS OWN pool
+            # (a separate allocator from the main engine's)
+            # — shedding a main-batch row would free
+            # nothing there. Degrade this round to the
+            # plain chunk, the same fallback draft-hostile
+            # traffic takes.
+            engine.stats.incr("kv_pool_draft_skipped")
+        if self.session.can_run_ahead:
+            self._draft_wait = DRAFT_RETRY_TURNS
+        return None
+
+    def _dispatch(self, rows, armed: bool) -> "_Dispatched":
+        """Dispatch the next decode chunk for `rows`. Its length is the
+        Batcher's chunk, clamped only by the HARD seq_len headroom — a row
+        hitting its own max_new mid-chunk just has its surplus tokens
+        discarded and its slot released (no shrinking every co-tenant's
+        chunks to the smallest remaining budget, which fragmented
+        steady-state traffic into 1-2-token dispatches). A loop that cannot
+        run ahead (a mesh) keeps the long chunk its host turn is hidden in
+        and ramps to 8 right after an admission armed, so a fresh request's
+        first tokens come after ~8 steps; never two ramped chunks in a row."""
+        session, slots = self.session, self.slots
+        n = self.chunk
+        if not session.can_run_ahead:
+            ramp = armed and not self._ramped_last
+            self._ramped_last = ramp
+            if ramp:
+                n = min(8, n)
+        headroom = min(session.seq_len - 1 - int(session.pos[r]) for r in rows)
+        while n > max(headroom, 1):
+            n //= 2
+        n = max(n, 1)
+        chunk = session.dispatch(n)
+        engine = self.state.engine
+        for r in rows:
+            slots[r].n_dispatched += n
+        if chunk.ahead:
+            self.chunks_ahead += 1
+        else:
+            self.chunks_lockstep += 1
+        return _Dispatched(
+            chunk, {r: slots[r] for r in rows}, self.phases.turn,
+            engine.page_pool.used_pages if engine.paged else 0,
+        )
+
+    def _deliver(self, sent: "_Dispatched"):
+        """Wait for a dispatched chunk (the thread sleeps here while the
+        device works) and hand its tokens to the rows that decoded in it."""
+        chunk = sent.chunk
+        toks = self.session.step(chunk)  # the fetch alone: it is dispatched
+        self.phases.enter("batcher.deliver", 0, 0, 0)
+        self._preempted_last = False  # a decode chunk ran: the next boundary
+        # may preempt again if a higher-class waiter is still parked
+        t_us = to_us(chunk.t_start)
+        dur_us = int((chunk.t_end - chunk.t_start) * 1e6)
+        n_decoding = len(sent.rows)
+        # a row that retired since the dispatch (`_finish` took it off the
+        # record) decoded junk here: discarded, counted there
+        per_row = {
+            r: [int(t) for t in toks[r]]
+            for r, req in sent.rows.items() if self.slots[r] is req
+        }
+        self._timeline_step(
+            n_decoding, t_us, dur_us, moe_counts=self.session.moe_counts,
+            turn=sent.turn, ahead=chunk.ahead,
+            pool_pages_used=sent.pool_pages_used,
+        )
+        self._deliver_rows(per_row, t_us, dur_us)
+
+    def _spec_delivered(self, rows, per_row, drafts, t_turn: float):
+        """A verify round's tokens: what `_deliver` does for a chunk."""
+        dur_us = int((time.perf_counter() - t_turn) * 1e6)
+        self._preempted_last = False
+        self.chunks_lockstep += 1
+        for r, emitted in per_row.items():
+            self.slots[r].n_dispatched += len(emitted)
+        self._timeline_step(
+            len(rows), to_us(t_turn), dur_us, spec=True,
+            moe_counts=self.session.moe_counts,
+        )
+        self._deliver_rows(per_row, to_us(t_turn), dur_us, drafts)
+
+    def _deliver_rows(self, per_row, t_chunk_us, chunk_dur_us, spec_drafts=None):
+        import queue
+
+        n_put = n_over = n_finished = 0  # batcher.deliver's arguments
+        for row, row_toks in per_row.items():
+            req = self.slots[row]
+            # one span per row per chunk through the pre-bound emitters
+            # (a tuple append each; the chunk wall is shared — per-row
+            # attribution is the row's token count / acceptance)
+            if spec_drafts is not None:
+                req.ledger.spec_us += chunk_dur_us
+                req.ledger.spec_accepted_tokens += max(len(row_toks) - 1, 0)
+                if req._em_spec is not None:
+                    req._em_spec(
+                        t_chunk_us, chunk_dur_us,
+                        len(spec_drafts.get(row) or ()),
+                        max(len(row_toks) - 1, 0),
+                    )
+            else:
+                req.ledger.decode_us += chunk_dur_us
+                if req._em_decode is not None:
+                    req._em_decode(t_chunk_us, chunk_dur_us, len(row_toks))
+            gr = req.grammar_session
+            if req.n == 0 and row_toks:
+                self._first_tokens(req, row)
+            n_put += len(row_toks)
+            for i, t in enumerate(row_toks):
+                req.n += 1
+                req.out_ids.append(t)
+                if gr is not None:
+                    # the host session is authoritative: re-advance it
+                    # from the fetched token before the next chunk's
+                    # state vector is assembled (the in-graph carry is
+                    # only its traced mirror)
+                    gr.advance(t)
+                try:
+                    req.emit.put_nowait(t)
+                except queue.Full:
+                    # this client is EMIT_DEPTH tokens behind its writer
+                    # — drop that row only; co-batched requests and the
+                    # engine are unaffected (the writer thread owns the
+                    # socket, so a merely-slow client costs nothing here)
+                    req.error = req.error or RuntimeError(
+                        "client fell too far behind the token stream"
+                    )
+                    req.stopped = True
+                if (
+                    req.stopped or req.n >= req.max_new
+                    or t in req.eos_ids
+                    or (gr is not None and (gr.done or gr.at_terminal))
+                    or (req.drained and i == len(row_toks) - 1)
+                ):
+                    # a grammar TERMINAL stop (the DFA reached a state
+                    # where only EOS remains legal) retires the row
+                    # exactly like EOS: the token that got it there was
+                    # DELIVERED — it lands in the goodput ledger as
+                    # generated, and the chunk tail past it is ordinary
+                    # overrun, not a new waste class.
+                    # surplus tokens past max_new in this chunk are
+                    # discarded; the row parks (session.release) so
+                    # co-tenants keep full-size chunks. The eos_ids
+                    # check is the row-local EOS signal: without it the
+                    # loop decodes on until the
+                    # writer thread's `stopped` flag is visible,
+                    # inflating req.n and burning decode compute. The
+                    # chunk tail past the stop WAS decoded by the
+                    # engine — without this count it would appear in
+                    # neither generated nor discarded tokens; so was the
+                    # chunk dispatched ahead, where the row ends by its
+                    # tokens (`_finish` counts that one)
+                    tail = len(row_toks) - i - 1
+                    req.n_overrun += tail
+                    n_put -= tail
+                    n_over += tail
+                    n_finished += 1
+                    self._finish(req, row)
+                    break
+        n_over += self._overrun_carry
+        self._overrun_carry = 0
+        self.phases.set(n_put, n_over, n_finished)
 
 
 def refuse_state_handoff(engine, args) -> None:

@@ -1,4 +1,4 @@
-"""sha256 of graph_diff.fingerprint_ladder for the four older architectures (run in both trees)."""
+"""sha256 of graph_diff.fingerprint_ladder for the four older architectures (run in both trees): a ladder's, then one a program kind."""
 import hashlib, json, os, sys, tempfile
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["DLT_PALLAS_INTERPRET"] = "1"
@@ -24,4 +24,7 @@ for name, h in heads.items():
         prints = gd.fingerprint_ladder(eng)
         doc = json.dumps({k: fp.to_dict() for k, fp in sorted(prints.items())}, sort_keys=True)
         print(name, dtype, len(prints), hashlib.sha256(doc.encode()).hexdigest()[:16], flush=True)
+        for kind in sorted({k.split("[")[0] for k in prints}):
+            part = json.dumps({k: fp.to_dict() for k, fp in sorted(prints.items()) if k.split("[")[0] == kind}, sort_keys=True)
+            print(" ", name, dtype, kind, sum(k.split("[")[0] == kind for k in prints), hashlib.sha256(part.encode()).hexdigest()[:16], flush=True)
         eng.close()

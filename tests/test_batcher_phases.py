@@ -117,23 +117,70 @@ def test_phases_partition_every_turn(phase_server):
     assert abs(wall - sum(e["dur_us"] for e in phases)) <= 0.01 * wall
 
 
-def test_batch_step_contains_its_turns_dispatch_and_fetch(phase_server):
+LATE_US = 2000  # a chunk's interval is taken beside its phases, not from them
+
+
+def test_batch_step_names_the_turn_that_dispatched_it(phase_server):
+    """A chunk's span is its own interval (`BatchSession.fetch`): it ends
+    where its fetch returned and starts at its dispatch or, dispatched ahead
+    (`ahead`), where the chunk before it ended; `turn` is the turn whose
+    `step.dispatch` dispatched it, a turn before the one that fetched it. A
+    verify round still holds its turn's draft, dispatch and fetch."""
     _, port, state = phase_server
     _traffic(port, n_callers=2, each=1)
     _wait_idle(state)
     events, phases = _phase_events(port)
     turns = _by_turn(phases)
-    chunks = [e for e in events if e["name"] == "batch_step" and e["args"]["decoding"] > 0]
-    assert chunks
+    chunks = sorted((e for e in events if e["name"] == "batch_step" and e["args"]["decoding"] > 0),
+                    key=lambda e: e["t_us"])
+    assert chunks and any(c["args"]["ahead"] for c in chunks)
+    fetch_ends = [e["t_us"] + e["dur_us"] for e in phases if e["name"] == "step.fetch"]
+    ends = [e["t_us"] + e["dur_us"] for e in events if e["name"] == "batch_step"]
     for chunk in chunks:
-        assert list(chunk["args"])[-1] == "turn"  # the keys before it are as they were
+        args = chunk["args"]
+        assert list(args)[-2:] == ["turn", "ahead"]  # the keys before them are as they were
         lo, hi = chunk["t_us"], chunk["t_us"] + chunk["dur_us"]
-        inside = {e["name"]: e for e in turns[chunk["args"]["turn"]]
+        inside = {e["name"]: e for e in turns[args["turn"]]
                   if e["name"] in ("batcher.draft", "step.dispatch", "step.fetch")}
-        assert {"step.dispatch", "step.fetch"} <= set(inside), (chunk, list(inside))
-        for e in inside.values():
-            assert lo - SLACK_US <= e["t_us"] and e["t_us"] + e["dur_us"] <= hi + SLACK_US, (chunk, e)
-        assert inside["step.fetch"]["args"]["n_steps"] == inside["step.dispatch"]["args"]["n_steps"]
+        assert "step.dispatch" in inside, (chunk, list(inside))
+        dispatch = inside["step.dispatch"]
+        if args["spec"]:
+            assert {"batcher.draft", "step.fetch"} <= set(inside) and not args["ahead"]
+            for e in inside.values():
+                assert lo - SLACK_US <= e["t_us"] and e["t_us"] + e["dur_us"] <= hi + SLACK_US, (chunk, e)
+            continue
+        # it ends where a fetch returned, and that fetch is of its length
+        assert min(abs(hi - t) for t in fetch_ends) <= LATE_US, chunk
+        if args["ahead"]:
+            # dispatched while the chunk before it ran: it starts where that
+            # one ended, after its own dispatch began
+            assert min(abs(lo - t) for t in ends) <= SLACK_US, chunk
+            assert dispatch["t_us"] <= lo + SLACK_US
+        else:
+            assert abs(lo - dispatch["t_us"]) <= LATE_US, (chunk, dispatch)
+
+
+def test_the_batch_decode_series_sums_to_the_chunks_walls(phase_server):
+    """`/stats` `batch_decode[n]` records each chunk's own interval, what its
+    `batch_step` span spans: dispatched ahead, the intervals lie end to end,
+    so the series reads milliseconds a step and not twice that."""
+    _, port, state = phase_server
+    _traffic(port, n_callers=2, each=1)
+    _wait_idle(state)
+    events, _phases = _phase_events(port)
+    plain = [e for e in events if e["name"] == "batch_step" and not e["args"]["spec"]]
+    decoded_us = sum(e["dur_us"] for e in plain if e["args"]["decoding"] > 0)
+    other_us = sum(e["dur_us"] for e in plain if e["args"]["decoding"] == 0)
+    series = {k: v for k, v in _get_json(port, "/stats")["steps"].items()
+              if k.startswith("batch_decode[")}
+    total_us = sum(v["avg_ms"] * v["count"] for v in series.values()) * 1e3
+    n = sum(v["count"] for v in series.values())
+    assert n >= sum(1 for e in plain if e["args"]["decoding"] > 0) > 0
+    assert decoded_us - n <= total_us + 0.01 * decoded_us
+    assert total_us <= 1.01 * (decoded_us + other_us) + n
+    batcher = _get_json(port, "/stats")["batcher"]
+    assert batcher["chunks_ahead"] >= sum(1 for e in plain if e["args"]["ahead"]) > 0
+    assert batcher["chunks_lockstep"] > 0  # the greedy rows' verify rounds
 
 
 def test_phases_are_slices_in_the_chrome_view(phase_server):

@@ -289,13 +289,13 @@ def test_replica_decode_boundary_expiry_counts_decoded_waste(
     # the tiny CPU model decodes too fast to outlive any honest budget:
     # slow each decode chunk to ~60 ms so a 150 ms deadline survives
     # admission + prefill but dies after a couple of chunk boundaries
-    orig = BatchSession.step
+    orig = BatchSession.dispatch
 
     def slow_step(self, n):
         time.sleep(0.06)
         return orig(self, n)
 
-    monkeypatch.setattr(BatchSession, "step", slow_step)
+    monkeypatch.setattr(BatchSession, "dispatch", slow_step)
     with pytest.raises(urllib.error.HTTPError) as ei:
         with _chat(port,
                    {"messages": [{"role": "user", "content": "write a saga"}],

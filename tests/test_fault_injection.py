@@ -577,7 +577,7 @@ def test_stall_error_gets_one_inplace_retry_batched(batched_server, monkeypatch)
     httpd, port = batched_server
     st = httpd.RequestHandlerClass.state
     boom = {"armed": True}
-    orig_step = BatchSession.step
+    orig_step = BatchSession.dispatch
 
     def stalling_step(self, n):
         if boom["armed"]:
@@ -585,7 +585,7 @@ def test_stall_error_gets_one_inplace_retry_batched(batched_server, monkeypatch)
             raise StallError("injected chunk stall")
         return orig_step(self, n)
 
-    monkeypatch.setattr(BatchSession, "step", stalling_step)
+    monkeypatch.setattr(BatchSession, "dispatch", stalling_step)
     with _post(port) as r:
         data = json.loads(r.read())
     assert data["usage"]["completion_tokens"] > 0
@@ -654,8 +654,9 @@ def test_row_local_eos_stops_decode_and_usage_accounting(tmp_path_factory):
     assert req.n_out == first
     # the chunk tail the engine decoded past the EOS is real compute: it
     # must be counted as overrun waste (folded into the ledger's discarded
-    # tokens at completion), never silently vanish — and never inflate n
-    assert req.n + req.n_overrun == 8, (
+    # tokens at completion), never silently vanish — and never inflate n.
+    # So is the chunk the loop had dispatched ahead when it read the EOS
+    assert req.n + req.n_overrun == 16, (
         f"chunk-tail accounting drifted: n={req.n} overrun={req.n_overrun}"
     )
 
@@ -810,7 +811,7 @@ def test_stall_produces_flight_record_with_request_spans(
     # 60 ms before the planted stall did.
     monkeypatch.setenv("DLT_FLIGHTREC_DIR", "")  # memory-only for the test
     boom = {"armed": True}
-    orig_step = BatchSession.step
+    orig_step = BatchSession.dispatch
     logs = []
 
     def stalling_step(self, n):
@@ -822,7 +823,7 @@ def test_stall_produces_flight_record_with_request_spans(
                 time.sleep(0.2)
         return orig_step(self, n)
 
-    monkeypatch.setattr(BatchSession, "step", stalling_step)
+    monkeypatch.setattr(BatchSession, "dispatch", stalling_step)
     tid = "feedbeefcafe0002"
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}/v1/chat/completions",

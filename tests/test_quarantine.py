@@ -324,14 +324,14 @@ def test_replica_strikes_and_refuses_with_422(tmp_path, monkeypatch):
     state = httpd.api_state
     try:
         armed = {"on": True}
-        orig = BatchSession.step
+        orig = BatchSession.dispatch
 
         def bad_step(self, n):
             if armed["on"]:
                 raise RuntimeError("chaos: wedged on this prompt")
             return orig(self, n)
 
-        monkeypatch.setattr(BatchSession, "step", bad_step)
+        monkeypatch.setattr(BatchSession, "dispatch", bad_step)
         # two engine failures on the same body: strike 1, strike 2
         fps_seen = []
         for i in range(2):

@@ -11,7 +11,7 @@ import warnings
 import pytest
 
 from distributed_llama_tpu.formats.mfile import ArchType
-from distributed_llama_tpu.runtime.engine import InferenceEngine
+from distributed_llama_tpu.runtime.engine import BATCHER_CHUNK, SOLO_CHUNK, InferenceEngine
 from distributed_llama_tpu.runtime.tracing import STARTUP_SPANS
 from distributed_llama_tpu.server import api
 from distributed_llama_tpu.testing import tiny_header, write_tiny_model, write_tiny_tokenizer
@@ -74,7 +74,14 @@ def test_a_batched_server_plans_its_batchers_programs_in_the_bare_plans_order(fi
     served, bare, eng = _plans(_args(files, "--batch", "4", *extra))
     assert eng.server_role in ("unified", "decode") and not eng.warms_solo_programs
     assert {k[0] for k in bare} >= set(SOLO)
-    assert served == [k for k in bare if k[0] not in SOLO]
+    # ... at the chunk of a Batcher that runs ahead of the device, where the
+    # bare engine's lock-step loops keep theirs
+    assert (eng.decode_chunk_size, BATCHER_CHUNK, SOLO_CHUNK) == (16, 16, 64)
+    assert served == [
+        k for k in bare
+        if k[0] not in SOLO and not (k[0] == "batch_decode" and k[1] > BATCHER_CHUNK)
+    ]
+    assert sorted(k[1] for k in served if k[0] == "batch_decode") == [1, 2, 4, 8, 16]
     assert {"prefill_row", "batch_decode"} <= {k[0] for k in served}
     # the record's bound on its spans follows the narrower plan
     assert eng.startup.limit == 2 * len(served) + len(STARTUP_SPANS)
@@ -87,6 +94,24 @@ def test_a_server_without_a_batcher_or_at_role_prefill_plans_the_bare_plan(files
     served, bare, eng = _plans(_args(files, *extra))
     assert eng.server_role is not None and eng.warms_solo_programs
     assert served == bare and set(SOLO) <= {k[0] for k in served}
+
+
+@pytest.mark.parametrize("model,extra,chunk", [
+    ("dense", ("--batch", "4"), 16), ("hybrid", ("--batch", "2", "--speculative", "off"), 16),
+    ("dense", ("--batch", "4", "--role", "prefill"), 64), ("dense", ("--batch", "1"), 64),
+    ("dense", ("--batch", "4", "--tp", "2"), 64),
+], ids=["dense", "hybrid", "role-prefill", "batch-1", "mesh"])
+def test_the_chunk_is_16_where_a_batcher_alone_drives_one_chip(files, model, extra, chunk):
+    """A server's Batcher on one chip runs a chunk ahead of the device and
+    plans `batch_decode` at 16 and its halves; an engine whose plan keeps
+    the solo loops, and a mesh (its Batcher is lock-step), keep 64."""
+    eng = api.make_served_engine(_args(files, *extra, model=model))
+    try:
+        assert eng.decode_chunk_size == chunk
+        sizes = sorted({k[1] for k in eng.warm_plan() if k[0] in ("batch_decode", "decode")})
+        assert sizes == [n for n in (1, 2, 4, 8, 16, 32, 64) if n <= chunk]
+    finally:
+        eng.close()
 
 
 def test_the_servers_decision_and_the_engines_are_one(files):

@@ -772,7 +772,7 @@ def test_batcher_recovers_from_engine_failure(batched_api_server, monkeypatch):
 
     port = batched_api_server
     boom = {"armed": True}
-    orig_step = BatchSession.step
+    orig_step = BatchSession.dispatch
 
     def exploding_step(self, n):
         if boom["armed"]:
@@ -780,7 +780,7 @@ def test_batcher_recovers_from_engine_failure(batched_api_server, monkeypatch):
             raise RuntimeError("injected device failure")
         return orig_step(self, n)
 
-    monkeypatch.setattr(BatchSession, "step", exploding_step)
+    monkeypatch.setattr(BatchSession, "dispatch", exploding_step)
 
     payload = {"messages": [{"role": "user", "content": "hello"}], "max_tokens": 4}
     with pytest.raises(urllib.error.HTTPError) as ei:
@@ -873,13 +873,13 @@ def test_heterogeneous_budgets_keep_full_chunks(tmp_path_factory, monkeypatch):
     eng = _batcher_engine(tmp_path_factory)
     state = types.SimpleNamespace(engine=eng, recover=lambda: None)
     sizes = []
-    orig_step = BatchSession.step
+    orig_step = BatchSession.dispatch
 
     def spy(self, n):
         sizes.append(n)
         return orig_step(self, n)
 
-    monkeypatch.setattr(BatchSession, "step", spy)
+    monkeypatch.setattr(BatchSession, "dispatch", spy)
     b = api_mod.Batcher(state, chunk_size=8)
 
     long_req = api_mod._BatchReq([5, 9], 40, 0.0, 0.9, None, lambda t: None)
@@ -1138,13 +1138,23 @@ def test_stats_endpoint(batched_api_server):
     assert data["batch"] >= 2
 
 
-def test_interleaved_admission_long_prompt_mid_stream(batched_api_server):
+def test_interleaved_admission_long_prompt_mid_stream(batched_api_server, monkeypatch):
     """A LONG-prompt request admitted while another stream decodes: its
     prompt prefills in bounded chunks between the live stream's decode
     chunks (the Batcher's interleaved path — interleaved_prefill_chunks
     counters tick), and BOTH completions still match their solo runs
     token for token."""
+    from distributed_llama_tpu.runtime.batch_session import BatchSession
+
     port = batched_api_server
+    # the tiny CPU model decodes 200 tokens before a second request can
+    # land (this test failed on that since the seed): hold every chunk's
+    # dispatch 30 ms, so the live stream's 13 chunks outlast the 0.1 s below
+    orig = BatchSession.dispatch
+
+    def slow_dispatch(self, n):
+        time.sleep(0.03)
+        return orig(self, n)
 
     def ask(body, out, i):
         with _post(port, body) as r:
@@ -1172,10 +1182,11 @@ def test_interleaved_admission_long_prompt_mid_stream(batched_api_server):
             "interleaved_prefill_chunks", 0
         )
 
+    monkeypatch.setattr(BatchSession, "dispatch", slow_dispatch)
     out = [None, None]
     t_live = threading.Thread(target=ask, args=(live_body, out, 0))
     t_live.start()
-    time.sleep(0.35)  # the live stream is mid-generation
+    time.sleep(0.1)  # the live stream is mid-generation
     t_long = threading.Thread(target=ask, args=(long_body, out, 1))
     t_long.start()
     t_live.join(timeout=120)

@@ -177,7 +177,10 @@ def test_the_batchers_plan_alone_seals_a_server_that_serves_chat(startup_server,
     plan = eng.warm_plan()
     startup = _get_json(port, "/stats")["startup"]
     assert set(startup["by_kind"]) == {"prefill_row", "batch_decode", "page_copy"}
-    assert startup["programs_warmed"] == startup["programs_planned"] == len(plan) == 11
+    # 3 `prefill_row`, `page_copy`, and `batch_decode` at the Batcher's chunk
+    # of 16 and its halves (seven sizes, 11 programs, when the chunk was 64)
+    assert sorted(n for kind, n, _ in plan if kind == "batch_decode") == [1, 2, 4, 8, 16]
+    assert startup["programs_warmed"] == startup["programs_planned"] == len(plan) == 9
     assert startup["never_warmed"] == [] and startup["never_warmed_n"] == 0
     # the solo programs' stats series were never opened: nothing ran them
     series = _get_json(port, "/stats")["steps"]

@@ -193,7 +193,7 @@ def test_engine_failure_rebuilds_in_place_token_identical(
         engine_before = state.engine
         # force an unhandled engine exception inside the step loop
         boom = {"armed": True}
-        orig = BatchSession.step
+        orig = BatchSession.dispatch
 
         def bad_step(self, n):
             if boom["armed"]:
@@ -201,7 +201,7 @@ def test_engine_failure_rebuilds_in_place_token_identical(
                 raise RuntimeError("chaos: engine wedged")
             return orig(self, n)
 
-        monkeypatch.setattr(BatchSession, "step", bad_step)
+        monkeypatch.setattr(BatchSession, "dispatch", bad_step)
         with pytest.raises(urllib.error.HTTPError) as ei:
             with _post(port) as r:
                 r.read()
@@ -288,12 +288,12 @@ def test_restart_budget_exhaustion_fails_replica_visibly(
         max_restarts=1, window_s=600.0, backoff_s=0.0
     )
     try:
-        orig = BatchSession.step
+        orig = BatchSession.dispatch
 
         def always_bad(self, n):
             raise RuntimeError("chaos: permanently wedged")
 
-        monkeypatch.setattr(BatchSession, "step", always_bad)
+        monkeypatch.setattr(BatchSession, "dispatch", always_bad)
         # failure 1: consumes the budget (rebuild succeeds but the engine
         # is monkeypatched to keep failing); failure 2: budget exhausted.
         # DISTINCT bodies per attempt: repeating one body would trip the
@@ -319,7 +319,7 @@ def test_restart_budget_exhaustion_fails_replica_visibly(
             i += 1
             time.sleep(0.05)
         assert state.supervisor.state == FAILED
-        monkeypatch.setattr(BatchSession, "step", orig)
+        monkeypatch.setattr(BatchSession, "dispatch", orig)
         with pytest.raises(urllib.error.HTTPError) as ei:
             _get(port, "/health")
         assert ei.value.code == 503
@@ -352,7 +352,7 @@ def test_rebuild_reseals_fresh_sentinel_zero_recompiles(
         old_sentinel = state.engine.sentinel
         assert old_sentinel is not None and old_sentinel.sealed
         boom = {"armed": True}
-        orig = BatchSession.step
+        orig = BatchSession.dispatch
 
         def bad_step(self, n):
             if boom["armed"]:
@@ -360,7 +360,7 @@ def test_rebuild_reseals_fresh_sentinel_zero_recompiles(
                 raise RuntimeError("chaos: engine wedged")
             return orig(self, n)
 
-        monkeypatch.setattr(BatchSession, "step", bad_step)
+        monkeypatch.setattr(BatchSession, "dispatch", bad_step)
         try:
             with _post(port, timeout=600) as r:
                 r.read()
