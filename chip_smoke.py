@@ -48,6 +48,26 @@ of width 2048 beside a shared one, an eighth of the vocabulary), at 2 rows:
    (`--speculative off`): the same requests, 0 recompiles, the notices, the
    `/stats` `moe` block and `kv_pool.bytes_per_token`.
 
+`--arch granite_hybrid` (one chip, run by the builder): Granite-4.0-H-Micro's
+widths as `perfbench/configs/granite-4.0-h-micro.json` has them (hidden 2048,
+64 state-space heads of 64 with a state of 128, 32Q/8KV attention heads of 64
+without a position embedding, ffn 8192, vocabulary 100352, the four
+multipliers), with the layers' PUBLISHED initialisation (decays of 0.2-0.999,
+which the benchmark's seeded files cannot draw), at 2 rows:
+
+1. numbers: `ssd_decode_step` on the chip against `ssd_chunked` over 64
+   positions, decays of 0.9-0.999, on the state itself (float32 on both
+   sides; the same walk with the state rounded to bfloat16 is printed beside
+   it and has to fail the bound); one period of ten layers, bf16 kernels
+   (the state-space kernel, the page-table kernel over a pool that stores
+   head 64 as 128, flash prefill at head 64) against the same engine's
+   float32 XLA path, teacher-forced logits, prefill then decode (the float32
+   path is held to the plain reference on the CPU,
+   `tests/z_perfbench/test_granite_hybrid_program.py`);
+2. the server at all 40 layers with the configuration's own arguments at
+   batch 2 (`--speculative off`): the same requests, 0 recompiles, the
+   notices, `/stats` `rec_state.kind` and `kv_pool.bytes_per_token`.
+
 Every phase prints one JSON line. The LAST line of standard output is
 `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}` and
 the exit code 0 only if JAX found the TPU and no phase failed; anything else
@@ -98,6 +118,30 @@ KIMI_K26 = dict(
 # whole latent model, bf16 kernels against float32 XLA (the dense models'
 # bounds above): top-1 0.969 / 0.969, max diff 0.068 / 0.124 std
 MAX_EXPERT_DIFF_STD = 0.08
+# perfbench/configs/granite-4.0-h-micro.json (ibm-granite/granite-4.0-h-micro
+# config.json), the program's header names; depth is the caller's
+GRANITE_4HM = dict(
+    dim=2048, hidden_dim=8192, n_heads=32, n_kv_heads=8, head_dim=64, vocab_size=100352,
+    seq_len=131072, full_attn_interval=10, full_attn_offset=5, lin_heads=64,
+    lin_key_head_dim=128, lin_value_head_dim=64, lin_conv_kernel=4, embedding_mult=12.0,
+    attention_mult=0.015625, residual_mult=0.22, logits_scaling=8.0,
+)
+# --rehearse: `graph_audit.tiny_ssm_hybrid_header`'s widths (8 kv heads of 64
+# stored as 128, 16 state-space heads of 16 with a state of 64: every matmul
+# meets the stacked Q40 kernels' rule and both kernels run interpreted)
+TINY_GRANITE = dict(
+    dim=256, hidden_dim=512, n_heads=8, n_kv_heads=8, head_dim=64, vocab_size=512,
+    seq_len=256, full_attn_interval=4, full_attn_offset=2, lin_heads=16,
+    lin_key_head_dim=64, lin_value_head_dim=16,
+)
+# the state-space decode kernel against the chunked form after 64 positions,
+# on the state, over the state's largest value: float32 on both sides.
+# Measured on the v5e (PR 42, seed 7): 7.3e-5 (the chunked form's products
+# are float32 in bfloat16 passes; interpreted on the CPU the two differ by
+# 5e-7); a state rounded to bfloat16 a step reads 0.129. The whole model, bf16
+# kernels against float32 XLA over one period (the dense models' bounds
+# above): top-1 0.953 / 0.906, max diff 0.088 / 0.217 std
+MAX_SSD_DIFF = 3e-4
 CHATML = (
     "{% for m in messages %}<|im_start|>{{ m['role'] }}\n{{ m['content'] }}"
     "<|im_end|>\n{% endfor %}{% if add_generation_prompt %}"
@@ -173,10 +217,14 @@ def finish(device: dict) -> "NoReturn":
 
 def build_model(shape: dict, n_layers: int, seed: int) -> str:
     from distributed_llama_tpu.formats.mfile import ArchType, RopeType, tensor_walk
-    from distributed_llama_tpu.testing import tiny_header, tiny_latent_header, write_tiny_model
+    from distributed_llama_tpu.testing import (
+        tiny_header, tiny_latent_header, tiny_ssm_header, write_tiny_model,
+    )
 
     if "kv_lora_rank" in shape:
         h = tiny_latent_header(**{**shape, "n_layers": n_layers})
+    elif "full_attn_offset" in shape:
+        h = tiny_ssm_header(**{**shape, "n_layers": n_layers})
     else:
         h = tiny_header(
             arch=ArchType.QWEN3, rope_type=RopeType.FALCON,
@@ -662,7 +710,14 @@ def phase_latent_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
             fail(f"numbers/latent {name}: top-1 {agree}, max diff {diff} stds")
 
 
-def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
+def serve_two_rows(model: str, tokenizer: str, rehearse: bool, need: dict, block: str):
+    """The server at batch 2 with `--speculative off` (what the architectures
+    that refuse speculation start with): the cost table's kernel counts
+    against `need` {program kind: kernels}, four requests (one streamed, two
+    at once), then `/stats`: no recompile, rebuild or reset, four requests
+    completed. Says the `stats` line with the `/stats` block `block` in it and
+    returns (httpd, engine, stats) for the architecture's own checks; the
+    caller shuts the server down."""
     import socket
 
     import jax
@@ -688,13 +743,12 @@ def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
     kvb = max(k for _, _, k in plan)
     on_tpu = jax.devices()[0].platform == "tpu"
     kernels = {}
-    # a step's kernels: q_a|kv_a, q_b, wo a layer kind apart, the dense w13 and
-    # w2, the shared expert's two, the three grouped expert calls, the head
     for kind, size in (("batch_decode", 1), ("prefill_row", max(s for k, s, _ in plan if k == "prefill_row"))):
         e = table.entries.get((kind, size, kvb)) if table else None
         kernels[f"{kind}[{size}|kv{kvb}]"] = e and [e.pallas_calls, e.tpu_custom_calls]
-        if not e or (e.tpu_custom_calls if on_tpu else e.pallas_calls) < 3 + 3 + 2 + 2 + 3 + 1:
-            fail(f"kernels: {kind}[{size}] holds {kernels}: a weight fell off its kernel")
+        if not e or (e.tpu_custom_calls if on_tpu else e.pallas_calls) < need[kind]:
+            fail(f"kernels: {kind}[{size}] holds {kernels}, fewer than {need[kind]}: "
+                 "a weight or a state fell off its kernel")
     say("serve", start_seconds=round(time.time() - t0, 1), warm_plan_programs=len(plan),
         kernels_traced_compiled=kernels, kv=dict(layout=engine.kv_layout, page=engine.page_size),
         device_gib={k: round(v / 2**30, 2) for k, v in (jax.devices()[0].memory_stats() or {}).items()
@@ -718,20 +772,127 @@ def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
     watched = {k: counters.get(k, 0) for k in (
         "sanitizer_recompiles", "supervisor_rebuilds", "stall_resets",
         "recover_reset_failed", "sanitizer_d2h_violations")}
-    moe, pool = stats.get("moe") or {}, stats.get("kv_pool") or {}
     say("stats", status=status, watched=watched, supervisor=sup.get("state"),
-        requests_completed=counters.get("requests_completed"), moe=moe, kv_pool=pool,
+        requests_completed=counters.get("requests_completed"),
+        **{block: stats.get(block) or {}}, kv_pool=stats.get("kv_pool") or {},
         notices=stats.get("notices"))
-    cfg = engine.cfg
     if status != 200 or any(watched.values()) or sup.get("state") != "serving":
         fail(f"/stats: {watched}, supervisor {sup.get('state')}")
     if counters.get("requests_completed", 0) < 4:
         fail(f"/stats counts {counters.get('requests_completed')} completed requests of 4")
+    return httpd, engine, stats
+
+
+def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
+    # a step's kernels: q_a|kv_a, q_b, wo a layer kind apart, the dense w13 and
+    # w2, the shared expert's two, the three grouped expert calls, the head
+    need = 3 + 3 + 2 + 2 + 3 + 1
+    httpd, engine, stats = serve_two_rows(
+        model, tokenizer, rehearse, {"batch_decode": need, "prefill_row": need}, "moe")
+    cfg, moe, pool = engine.cfg, stats.get("moe") or {}, stats.get("kv_pool") or {}
     if (moe.get("held"), moe.get("experts")) != (cfg.n_experts_held, cfg.n_experts) or not moe.get("expert_pairs"):
         fail(f"/stats moe: {moe}")
     itemsize = 2 if cfg.cache_dtype == "bfloat16" else 4
     if pool.get("bytes_per_token") != cfg.n_layers * cfg.latent_page_width * itemsize:
         fail(f"/stats kv_pool.bytes_per_token: {pool.get('bytes_per_token')}")
+    httpd.shutdown()
+    httpd.server_close()
+
+
+# -- the granite_hybrid branch ----------------------------------------------------
+
+
+def phase_ssm_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llama_tpu.ops.ssd import ssd_chunked, ssd_decode_step
+    from distributed_llama_tpu.runtime.engine import InferenceEngine
+
+    rng = np.random.default_rng(17)
+    interp = bool(os.environ.get("DLT_PALLAS_INTERPRET"))
+
+    # (a) the decode kernel against the chunked form, 64 positions, decays
+    # drawn as published: a step a head log-uniform in [0.001, 0.1] over
+    # rates that keep every decay in 0.9-0.999
+    H, P, N = (16, 16, 64) if rehearse else (64, 64, 128)
+    rows, steps = 2, 64
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s, dtype=np.float32))  # noqa: E731
+    x, B, C = f32(rows, steps, H, P), f32(rows, steps, N), f32(rows, steps, N)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (1, 1, H))) * np.exp(
+        0.1 * rng.standard_normal((rows, steps, H)))
+    A = -np.minimum(rng.uniform(1.0, 16.0, H), 0.1 / dt.max(axis=(0, 1)))  # decay >= 0.9
+    dt, A, D = jnp.asarray(dt, jnp.float32), jnp.asarray(A, jnp.float32), jnp.ones(H, jnp.float32)
+    decay = np.exp(np.asarray(dt * A))
+    s0 = f32(rows, N, H * P)
+    _, s_ref = jax.jit(ssd_chunked)(s0, x, B, C, dt, A, D)
+    keep = jnp.ones((rows,), bool)
+    to_bf16 = lambda v: jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)  # noqa: E731
+    got, other = {}, {}
+    for name, rounding in (("float32", lambda v: v), ("bfloat16_state", to_bf16)):
+        rec = jnp.stack([s0 * 2.0, s0])
+        for i in range(steps):
+            _, rec = ssd_decode_step(rec, 1, x[:, i], B[:, i], C[:, i], dt[:, i], A, D, keep,
+                                     interpret=interp)
+            rec = rounding(rec)
+        got[name] = float(jnp.max(jnp.abs(rec[1] - s_ref)) / jnp.max(jnp.abs(s_ref)))
+        other[name] = float(jnp.max(jnp.abs(rec[0] - rounding(s0 * 2.0))))
+    other = other["float32"]  # the step writes its own layer's state alone
+    say("numbers", check="state-space kernel vs chunked form, 64 positions, on the state",
+        heads=[H, P, N], decays=[round(float(decay.min()), 4), round(float(decay.max()), 4)],
+        kernel_state=float(f"{got['float32']:.3g}"), kernel_other_layer=other,
+        control_bfloat16_state=float(f"{got['bfloat16_state']:.3g}"), bound=MAX_SSD_DIFF)
+    if not got["float32"] <= MAX_SSD_DIFF or other != 0.0:
+        fail(f"numbers/state space: {got}, other layer {other}")
+    if not got["bfloat16_state"] > MAX_SSD_DIFF:
+        fail(f"numbers/state space: a bfloat16 state passes the bound ({got})")
+
+    # (b) one period, state-space and attention layers alike: bf16 kernels vs
+    # float32 XLA
+    n_pre, n_dec = (16, 4) if rehearse else (64, 32)
+    logits, kinds = {}, None
+    for dtype in ("float32", "bfloat16"):
+        eng = InferenceEngine(
+            model, compute_dtype=dtype, batch=1, max_chunk=64, max_seq_len=256,
+            kv_layout="paged", device_decode=False,
+        )
+        vocab, kinds = eng.cfg.vocab_size, eng.cfg.layer_kinds
+        pool_head = int(eng.cache.k.shape[-1])
+        ids = [int(v) for v in np.random.default_rng(18).integers(1, vocab, n_pre + n_dec)]
+        eng._ensure_pages_all_rows(0, n_pre + n_dec)
+        out = [eng.forward_tokens(ids[:n_pre], 0, logits_mode="all")[0]]
+        for i in range(n_dec):
+            out.append(eng.forward_tokens([ids[n_pre + i]], n_pre + i)[0][None])
+        logits[dtype] = np.concatenate(out)
+        free(eng)
+    want, have = logits["float32"], logits["bfloat16"]
+    std = float(want.std())
+    for name, sl in (("prefill", slice(0, n_pre)), ("decode", slice(n_pre, None))):
+        agree = float((want[sl].argmax(-1) == have[sl].argmax(-1)).mean())
+        diff = float(np.abs(want[sl] - have[sl]).max() / std)
+        say("numbers", check=f"state-space hybrid bf16 kernels vs float32 XLA, {name}",
+            layers="".join(k[0] for k in kinds), pool_head_dim=pool_head,
+            positions=int(want[sl].shape[0]), top1_agreement=round(agree, 3),
+            max_diff_std=round(diff, 4), bounds=[MIN_TOP1_AGREEMENT, MAX_LOGIT_DIFF_STD])
+        if agree < MIN_TOP1_AGREEMENT or not diff <= MAX_LOGIT_DIFF_STD:
+            fail(f"numbers/state-space hybrid {name}: top-1 {agree}, max diff {diff} stds")
+
+
+def phase_ssm_server(model: str, tokenizer: str, rehearse: bool) -> None:
+    # a program's kernels: a state-space layer body a run (in-projection,
+    # out-projection, w13, w2; a decode step's holds the state-space kernel
+    # too), the full layer's (wqkv, attention's, wo, w13, w2), the head
+    httpd, engine, stats = serve_two_rows(
+        model, tokenizer, rehearse,
+        {"batch_decode": 2 * 5 + 5 + 1, "prefill_row": 2 * 4 + 5 + 1}, "rec_state")
+    cfg, rec, pool = engine.cfg, stats.get("rec_state") or {}, stats.get("kv_pool") or {}
+    if (rec.get("kind"), rec.get("slots"), rec.get("layers")) != ("ssd", 2, cfg.n_rec_layers):
+        fail(f"/stats rec_state: {rec}")
+    # a token's KV as STORED: head 64 as 128
+    stored = 2 * cfg.n_kv_layers * cfg.n_kv_heads * int(engine.cache.k.shape[-1]) * 2
+    if pool.get("bytes_per_token") != stored:
+        fail(f"/stats kv_pool.bytes_per_token: {pool.get('bytes_per_token')}, stored {stored}")
     httpd.shutdown()
     httpd.server_close()
 
@@ -828,7 +989,7 @@ def main() -> None:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--rehearse", action="store_true")
-    ap.add_argument("--arch", choices=("qwen3", "kimi_k2"), default="qwen3")
+    ap.add_argument("--arch", choices=("qwen3", "kimi_k2", "granite_hybrid"), default="qwen3")
     args = ap.parse_args()
 
     os.environ["DLT_SANITIZERS"] = "1"  # recompile sentinel + host-sync guard
@@ -885,6 +1046,13 @@ def main() -> None:
         shape = {"kv_lora_rank": 256, "vocab_size": 256, "seq_len": 256} if args.rehearse else KIMI_K26
         phases = [("latent_numbers", phase_latent_numbers, 2),
                   ("latent_server", phase_latent_server, 2)]
+    if args.arch == "granite_hybrid":
+        # numbers on one period (every kind of layer, 0.7 GB), the server on
+        # all 40 layers (2.6 GB: nothing of this model is cut)
+        shape = TINY_GRANITE if args.rehearse else GRANITE_4HM
+        period = shape["full_attn_interval"]
+        phases = [("ssm_numbers", phase_ssm_numbers, period),
+                  ("ssm_server", phase_ssm_server, period if args.rehearse else 40)]
     for name, phase, depth in phases:
         try:
             tokenizer = build_tokenizer(shape["vocab_size"])

@@ -510,6 +510,15 @@ def recurrence_f32_dots(engine, entry: LadderEntry) -> int:
     # (CacheAddr.rec_row), which the kernel does not take
     row = 0 if entry.kind == "prefill_row" and engine.paged else None
     kernel = one_position and _rec_kernel_eligible(cfg, 1, row)
+    if cfg.lin_kind == "ssd":
+        # a state-space layer projects its step in float32 (1 dot); its
+        # decode kernel has no dot at all, and the chunked form has four
+        # (C B^T, the sub-chunk's product, the carried state's read-out, the
+        # state's update). The stack holds ONE layer body a run of such
+        # layers (before the period's full layer, and after it)
+        before = cfg.full_attn_offset % cfg.full_attn_interval
+        runs = (before > 0) + (cfg.full_attn_interval - 1 - before > 0)
+        return (1 if kernel else 1 + 4) * runs
     return (1 if kernel else 1 + 7) * (cfg.full_attn_interval - 1)
 
 
@@ -605,11 +614,11 @@ def _fused_kernel_active(engine) -> bool:
     if cfg.is_latent:  # the latent arm reads through a gather (ROADMAP R5)
         return False
     tp = engine.mesh.shape["tp"] if engine.mesh is not None else 1
-    # the pool's own kv heads (it may store more than the model has:
-    # paged_kv.pool_kv_heads), a tp shard's share of them
+    # the pool's own kv heads and head width (it may store more than the
+    # model has: paged_kv.pool_kv_heads, pool_head_dim), a tp shard's share
     n_kv = engine.cache.k.shape[3] // tp
     return _fused_paged_eligible(
-        cfg, (cfg.n_heads // cfg.n_kv_heads * n_kv, cfg.head_dim), n_kv, 1,
+        cfg, (cfg.n_heads // cfg.n_kv_heads * n_kv, engine.cache.k.shape[4]), n_kv, 1,
         engine.cache.k.shape[2],
     )
 
@@ -1060,10 +1069,12 @@ def add_engine_args(p) -> None:
     and the audited config can never drift apart syntactically."""
     p.add_argument("--model", default=None, help=".m file (default: tiny synthetic)")
     p.add_argument(
-        "--arch", choices=["llama", "olmo_hybrid", "kimi_k2"], default="llama",
+        "--arch", choices=["llama", "olmo_hybrid", "kimi_k2", "granite_hybrid"],
+        default="llama",
         help="the tiny synthetic model's architecture (ignored with --model): "
-        "olmo_hybrid = two periods of three gated-delta layers and a full one "
-        "(pass --speculative off --prefix-cache-mb 0: refused for it)",
+        "olmo_hybrid = two periods of three gated-delta layers and a full one, "
+        "granite_hybrid = two periods of state-space layers around a full one "
+        "(pass --speculative off --prefix-cache-mb 0: refused for them)",
     )
     p.add_argument("--compute-dtype", default="float32")
     p.add_argument("--batch", type=int, default=2)
@@ -1134,6 +1145,21 @@ def tiny_hybrid_header():
     )
 
 
+def tiny_ssm_hybrid_header():
+    """The tiny Granite-Hybrid the state-space golden and its contracts are
+    traced on: two periods of `mamba, mamba, attention, mamba`, 8 kv heads of
+    64 (a paged pool stores 128), 16 state-space heads of 16 (whole lanes: the
+    Pallas step traces where Pallas is on) with a state of 64 (an
+    in-projection of 640 and an out-projection of 256 inputs: the stacked Q40
+    kernels take every matmul)."""
+    from ..testing import tiny_ssm_header
+
+    return tiny_ssm_header(
+        dim=256, hidden_dim=512, n_heads=8, n_kv_heads=8, head_dim=64, lin_heads=16,
+        lin_key_head_dim=64,
+    )
+
+
 def engine_from_args(args, workdir: str):
     """Build the engine the parsed `add_engine_args` flags describe
     (writing a tiny synthetic model into `workdir` when no --model)."""
@@ -1151,6 +1177,8 @@ def engine_from_args(args, workdir: str):
         model = workdir + "/tiny.m"
         if getattr(args, "arch", "llama") == "olmo_hybrid":
             hdr = tiny_hybrid_header()
+        elif getattr(args, "arch", "llama") == "granite_hybrid":
+            hdr = tiny_ssm_hybrid_header()
         elif getattr(args, "arch", "llama") == "kimi_k2":
             from ..testing import tiny_latent_header
 

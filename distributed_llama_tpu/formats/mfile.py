@@ -85,6 +85,19 @@ K_EXPERTS_HELD = 39
 K_EXPERT_FIRST = 40
 K_N_SHARED_EXPERTS = 41
 K_ROUTED_SCALE_MILLI = 42
+# granite_hybrid (also past the reference's): where in a period the full
+# layer sits (olmo_hybrid's is the period's last), the state-space layers'
+# B/C groups and conv bias (their heads, state size and head size ride keys
+# 24, 25 and 26), and Granite's four multipliers. Floats in thousandths but
+# the attention multiplier, which is 1/64 at the published size and rides in
+# MILLIONTHS (15625), exact for every multiplier that is a whole number of them
+K_FULL_ATTN_OFFSET = 43
+K_LIN_GROUPS = 44
+K_LIN_CONV_BIAS = 45
+K_EMBEDDING_MULT_MILLI = 46
+K_ATTENTION_MULT_MICRO = 47
+K_RESIDUAL_MULT_MILLI = 48
+K_LOGITS_SCALING_MILLI = 49
 
 
 class ArchType:
@@ -93,10 +106,12 @@ class ArchType:
     QWEN3_MOE = 0xABCD02
     OLMO_HYBRID = 0xABCD03
     KIMI_K2 = 0xABCD04
+    GRANITE_HYBRID = 0xABCD05
 
     _NAMES = {
         LLAMA: "llama", QWEN3: "qwen3", QWEN3_MOE: "qwen3_moe",
         OLMO_HYBRID: "olmo_hybrid", KIMI_K2: "kimi_k2",
+        GRANITE_HYBRID: "granite_hybrid",
     }
 
     @classmethod
@@ -114,6 +129,7 @@ class RopeType:
     FALCON = 1
     LLAMA3_1 = 2
     YARN = 3  # interleaved pairs, YaRN's blended frequencies (ops/rope.py)
+    NONE = 4  # no position embedding: q and k are left as projected
 
 
 @dataclass
@@ -172,6 +188,20 @@ class ModelHeader:
     expert_first: int = 0
     n_shared_experts: int = 0
     routed_scale: float = 1.0
+    # granite_hybrid: layer l is full attention where l % interval == offset
+    # (-1: the period's last, olmo_hybrid's) and a Mamba-2 state-space layer
+    # otherwise: `lin_value_heads` heads of `lin_value_head_dim`, a state of
+    # `lin_key_head_dim` a head channel, `lin_groups` B/C groups; the
+    # embedding times `embedding_mult`, every sub-layer's output times
+    # `residual_mult`, the scores times `attention_mult` (0: head_dim^-1/2),
+    # the logits over `logits_scaling`
+    full_attn_offset: int = -1
+    lin_groups: int = 0
+    lin_conv_bias: int = 0
+    embedding_mult: float = 1.0
+    attention_mult: float = 0.0
+    residual_mult: float = 1.0
+    logits_scaling: float = 1.0
     header_bytes: int = 0  # magic + size field + kv pairs
     file_bytes: int = 0
 
@@ -190,10 +220,16 @@ class ModelHeader:
 
     @property
     def is_hybrid(self) -> bool:
-        return self.arch_type == ArchType.OLMO_HYBRID
+        return self.arch_type in (ArchType.OLMO_HYBRID, ArchType.GRANITE_HYBRID)
+
+    @property
+    def is_ssm(self) -> bool:
+        """The linear layers are Mamba-2 state-space layers, not gated-delta."""
+        return self.arch_type == ArchType.GRANITE_HYBRID
 
     def layer_is_linear(self, layer: int) -> bool:
-        return self.is_hybrid and (layer + 1) % self.full_attn_interval != 0
+        p = self.full_attn_interval
+        return self.is_hybrid and layer % p != self.full_attn_offset % p
 
     @property
     def is_latent(self) -> bool:
@@ -221,20 +257,34 @@ class ModelHeader:
                 )
             if not 0 <= self.n_dense_layers < self.n_layers or not self.moe_hidden_dim:
                 raise ValueError("kimi_k2: the header lacks the expert layers' sizes")
+        if self.is_ssm:
+            self.rope_type = RopeType.NONE
+            self.lin_key_heads = self.lin_value_heads
+            if self.lin_groups != 1:
+                raise ValueError(
+                    f"granite_hybrid: {self.lin_groups} B/C groups: state-space "
+                    "layers with one B and one C for all heads are the ones supported"
+                )
         if self.is_hybrid:
+            name = ArchType.name(self.arch_type)
             if self.full_attn_interval < 2 or self.n_layers % self.full_attn_interval:
                 raise ValueError(
-                    f"olmo_hybrid: {self.n_layers} layers are not whole periods of "
+                    f"{name}: {self.n_layers} layers are not whole periods of "
+                    f"{self.full_attn_interval}"
+                )
+            if not -1 <= self.full_attn_offset < self.full_attn_interval:
+                raise ValueError(
+                    f"{name}: no layer {self.full_attn_offset} in a period of "
                     f"{self.full_attn_interval}"
                 )
             if self.lin_key_heads != self.lin_value_heads:
                 raise ValueError(
-                    "olmo_hybrid: linear layers with more value heads than key "
+                    f"{name}: linear layers with more value heads than key "
                     f"heads are not supported ({self.lin_key_heads} key, "
                     f"{self.lin_value_heads} value)"
                 )
             if self.lin_conv_kernel < 2 or not self.lin_key_head_dim or not self.lin_value_head_dim:
-                raise ValueError("olmo_hybrid: the header lacks the linear layers' sizes")
+                raise ValueError(f"{name}: the header lacks the linear layers' sizes")
         return self
 
 
@@ -247,6 +297,8 @@ class TensorSpec:
     # lin_a_log|lin_dt_bias|lin_o_norm|lin_wo
     # kimi_k2: q_a|q_a_norm|q_b|kv_a|kv_a_norm|kv_b|wo, moe_gate|moe_bias,
     # sw1|sw2|sw3 (the shared experts, as one of their summed width)
+    # granite_hybrid state-space layers: ssm_in|ssm_dt|ssm_conv|ssm_conv_bias|
+    # ssm_a_log|ssm_dt_bias|ssm_d|ssm_norm|ssm_out
     layer: int  # -1 for global tensors
     expert: int  # -1 for non-expert tensors
     shape: tuple  # logical (out_features, in_features) or (n,) — torch row-major
@@ -295,7 +347,27 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
 
     add("embedding", -1, -1, (h.vocab_size, h.dim), FloatType.F32)
     for l in range(h.n_layers):
-        if h.layer_is_linear(l):
+        if h.layer_is_linear(l) and h.is_ssm:
+            # the Mamba-2 mixer (ops/ssd.py): the in-projection's z | xBC
+            # rows as one Q40 tensor and its `dt` rows apart in float32 (the
+            # step decides what the state keeps for the rest of the sequence,
+            # as the delta rule's gates do), the depthwise causal conv's taps
+            # (tap-major, over x | B | C) and bias, the three per-head
+            # vectors, the gated norm's weight and the output projection
+            H = h.lin_value_heads
+            d_inner = H * h.lin_value_head_dim
+            n_conv = d_inner + 2 * h.lin_groups * h.lin_key_head_dim
+            add("ssm_in", l, -1, (d_inner + n_conv, h.dim), wt)
+            add("ssm_dt", l, -1, (H, h.dim), FloatType.F32)
+            add("ssm_conv", l, -1, (h.lin_conv_kernel, n_conv), FloatType.F32)
+            if h.lin_conv_bias:
+                add("ssm_conv_bias", l, -1, (n_conv,), FloatType.F32)
+            add("ssm_a_log", l, -1, (H,), FloatType.F32)
+            add("ssm_dt_bias", l, -1, (H,), FloatType.F32)
+            add("ssm_d", l, -1, (H,), FloatType.F32)
+            add("ssm_norm", l, -1, (d_inner,), FloatType.F32)
+            add("ssm_out", l, -1, (h.dim, d_inner), wt)
+        elif h.layer_is_linear(l):
             # the gated-delta mixer (ops/gated_delta.py): four projections
             # of the residual stream, the two gates' float projections, the
             # depthwise causal conv's taps (tap-major: row i multiplies the
@@ -365,7 +437,7 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
         if is_qwen:
             add("q_norm", l, -1, (h.head_dim,), FloatType.F32)
             add("k_norm", l, -1, (h.head_dim,), FloatType.F32)
-        elif h.is_hybrid and not h.layer_is_linear(l):
+        elif h.arch_type == ArchType.OLMO_HYBRID and not h.layer_is_linear(l):
             # Olmo's q/k norm spans the whole projection, not a head
             add("q_norm", l, -1, (h.q_dim,), FloatType.F32)
             add("k_norm", l, -1, (h.kv_dim,), FloatType.F32)
@@ -503,6 +575,13 @@ def _parse_header(buf, file_size: int) -> ModelHeader:
         K_EXPERT_FIRST: lambda v: setattr(h, "expert_first", v),
         K_N_SHARED_EXPERTS: lambda v: setattr(h, "n_shared_experts", v),
         K_ROUTED_SCALE_MILLI: lambda v: setattr(h, "routed_scale", v / 1000.0),
+        K_FULL_ATTN_OFFSET: lambda v: setattr(h, "full_attn_offset", v),
+        K_LIN_GROUPS: lambda v: setattr(h, "lin_groups", v),
+        K_LIN_CONV_BIAS: lambda v: setattr(h, "lin_conv_bias", v),
+        K_EMBEDDING_MULT_MILLI: lambda v: setattr(h, "embedding_mult", v / 1000.0),
+        K_ATTENTION_MULT_MICRO: lambda v: setattr(h, "attention_mult", v / 1e6),
+        K_RESIDUAL_MULT_MILLI: lambda v: setattr(h, "residual_mult", v / 1000.0),
+        K_LOGITS_SCALING_MILLI: lambda v: setattr(h, "logits_scaling", v / 1000.0),
     }
     for i in range(0, n_kv, 2):
         key, value = vals[i], vals[i + 1]

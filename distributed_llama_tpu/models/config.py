@@ -52,16 +52,31 @@ class ModelConfig:
     # argument — at construction (from DLT_PALLAS_INTERPRET) so a program
     # traced in one mode can never be replayed in the other.
     pallas_interpret: bool = False
-    # layer pattern (olmo_hybrid): layer l is full attention where
-    # (l + 1) % full_attn_interval == 0 and gated-delta linear attention
-    # (ops/gated_delta.py) otherwise. 1 = every layer is full attention, and
-    # every program of such a model is what it was before the pattern existed
+    # layer pattern (olmo_hybrid, granite_hybrid): layer l is full attention
+    # where l % full_attn_interval == full_attn_offset (-1: the period's
+    # last) and a linear layer otherwise, of the kind `lin_kind` names:
+    # "gated_delta" (ops/gated_delta.py) or "ssd" (Mamba-2's state-space
+    # layer, ops/ssd.py). 1 = every layer is full attention, and every
+    # program of such a model is what it was before the pattern existed
     full_attn_interval: int = 1
+    full_attn_offset: int = -1
+    lin_kind: str = "gated_delta"
     lin_heads: int = 0
     lin_key_dim: int = 0  # per head
     lin_value_dim: int = 0  # per head
     lin_conv_kernel: int = 0
     lin_neg_eigval: bool = False
+    # an "ssd" layer: `lin_heads` heads of `lin_value_dim` channels, each
+    # channel a state of `lin_key_dim`; B and C are `lin_groups` vectors of
+    # `lin_key_dim` a position, shared by the heads of a group
+    lin_groups: int = 0
+    lin_conv_bias: bool = False
+    # Granite's multipliers: the embedding times the first, every sub-layer's
+    # output times the second before it joins the residual stream, the
+    # logits over the third. The fourth, the softmax scale, is `attn_scale`
+    embedding_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_scaling: float = 1.0
     # latent attention (kimi_k2): q through a rank-`q_lora_rank` pair, k and v
     # through a rank-`kv_lora_rank` latent beside one RoPE'd key of
     # `qk_rope_dim` that every head shares; the cache holds [latent | key]
@@ -97,7 +112,7 @@ class ModelConfig:
             )
         p = self.full_attn_interval
         return tuple(
-            "full" if p == 1 or (l + 1) % p == 0 else "linear"
+            "full" if l % p == self.full_attn_offset % p else "linear"
             for l in range(self.n_layers)
         )
 
@@ -144,6 +159,9 @@ class ModelConfig:
 
     @property
     def lin_conv_channels(self) -> int:
+        """What a linear layer's conv runs over: q | k | v, or x | B | C."""
+        if self.lin_kind == "ssd":
+            return self.lin_vdim + 2 * self.lin_groups * self.lin_key_dim
         return 2 * self.lin_kdim + self.lin_vdim
 
     @property
@@ -216,12 +234,26 @@ def config_from_header(
         compute_dtype=compute_dtype,
         cache_dtype=cache_dtype,
         full_attn_interval=h.full_attn_interval if h.is_hybrid else 1,
+        full_attn_offset=h.full_attn_offset if h.is_hybrid else -1,
         lin_heads=h.lin_value_heads,
         lin_key_dim=h.lin_key_head_dim,
         lin_value_dim=h.lin_value_head_dim,
         lin_conv_kernel=h.lin_conv_kernel,
         lin_neg_eigval=bool(h.lin_neg_eigval),
         **(_latent_fields(h) if h.is_latent else {}),
+        **(_ssm_fields(h) if h.is_ssm else {}),
+    )
+
+
+def _ssm_fields(h: ModelHeader) -> dict:
+    return dict(
+        lin_kind="ssd",
+        lin_groups=h.lin_groups,
+        lin_conv_bias=bool(h.lin_conv_bias),
+        embedding_mult=float(h.embedding_mult),
+        residual_mult=float(h.residual_mult),
+        logits_scaling=float(h.logits_scaling),
+        attn_scale=float(h.attention_mult or h.head_dim**-0.5),
     )
 
 

@@ -34,6 +34,7 @@ from ..ops.pallas_attention import (
 )
 from ..ops.pallas_gdn import gdn_decode_step, gdn_head_chunk
 from ..ops.quant import _use_pallas
+from ..ops.ssd import ssd_chunked, ssd_decode_step
 from .params import KVCache
 
 
@@ -86,8 +87,15 @@ def _pallas_enabled(cfg) -> bool:
     return cfg.use_pallas if cfg.use_pallas is not None else _use_pallas()
 
 
-def _attention_auto(cfg, q, k_view, v_view, positions, pos_start):
-    """Pick the attention implementation for this (static) shape:
+def _softmax_scale(cfg):
+    """The model's own softmax scale (Granite's attention multiplier), or
+    None: head_dim^-1/2, every attention's default."""
+    return cfg.attn_scale or None
+
+
+def _attention_auto(cfg, q, k_view, v_view, positions, pos_start, scale=None):
+    """Pick the attention implementation for this (static) shape (`scale`:
+    the softmax scale where it is not the model's `_softmax_scale`):
 
     * prefill-sized q on a bf16 cache with the Pallas path enabled -> blocked
       flash kernel (ops/pallas_attention.py) — no O(t*S) score tensor;
@@ -96,6 +104,7 @@ def _attention_auto(cfg, q, k_view, v_view, positions, pos_start):
       bounds with the kv_len position bucket.
     """
     t = q.shape[1]
+    scale = scale or _softmax_scale(cfg)
     # interpret mode rides in the (static, hashable) config, so the jit
     # cache can never replay a program traced in the other mode. Per-row
     # pos_start (vector) only occurs at decode t=1, which takes the einsum
@@ -108,9 +117,9 @@ def _attention_auto(cfg, q, k_view, v_view, positions, pos_start):
         and flash_attention_aligned(q, k_view, t)
     ):
         return flash_attention(
-            q, k_view, v_view, pos_start, interpret=cfg.pallas_interpret
+            q, k_view, v_view, pos_start, scale=scale, interpret=cfg.pallas_interpret
         )
-    return gqa_attention(q, k_view, v_view, positions)
+    return gqa_attention(q, k_view, v_view, positions, scale=scale)
 
 
 def _fused_paged_eligible(cfg, heads_dim, n_kv: int, t: int, ps: int) -> bool:
@@ -194,6 +203,11 @@ def _pad_heads(x, n: int):
     return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
 
 
+def _pad_head_dim(x, d: int):
+    """[b, t, heads, hd] with zeros appended to every head up to `d`."""
+    return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, d - x.shape[3])))
+
+
 def _layer_view(buf, layer, b: int, n: int):
     """[b, n, ...]: the first n positions of `layer`'s rows in a stacked
     buffer — a bucketed dynamic-slice, the only cache traffic of a stacked
@@ -240,9 +254,17 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     # their outputs cut off again
     n_kv, pool_kv = k.shape[2], cache.k.shape[3]
     n_q, q_pool = q.shape[2], q
+    # a pool whose heads are wider than the model's (paged_kv.pool_head_dim:
+    # 64 stored as 128): q, k, v get a tail of zeros, which adds nothing to a
+    # score or to an output's first `hd` values; the scale stays the model's
+    hd, pool_hd = q.shape[3], cache.k.shape[4]
+    scale = _softmax_scale(cfg)
+    if pool_hd > hd:
+        q_pool, k, v = (_pad_head_dim(x, pool_hd) for x in (q, k, v))
+        scale = scale or hd**-0.5
     if pool_kv > n_kv:
         k, v = _pad_heads(k, pool_kv), _pad_heads(v, pool_kv)
-        q_pool = _pad_heads(q, n_q // n_kv * pool_kv)
+        q_pool = _pad_heads(q_pool, n_q // n_kv * pool_kv)
     max_slots = page_table.shape[1]
     phys, offset = _page_write_index(cfg, page_table, positions, ps, n_pool)
     cache = _write(
@@ -262,10 +284,10 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
         a = paged_decode_attention(
             q_pool, cache.k, cache.v, cache.k_scale, cache.v_scale,
             jnp.asarray(li, jnp.int32), positions[:, 0], page_table,
-            n_read=n_read, page_size=ps,
+            n_read=n_read, page_size=ps, scale=scale,
             interpret=cfg.pallas_interpret,
         )
-        return (a[:, :, :n_q] if pool_kv > n_kv else a), cache
+        return (a[:, :, :n_q, :hd] if (pool_kv > n_kv or pool_hd > hd) else a), cache
     # prefill chunks, tp shards with few local kv heads, no Pallas: gather
     # them into the contiguous [b, n*ps, h, d] view the attention math
     # consumes — this gather is the arm's whole read cost (the cost model
@@ -281,11 +303,14 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
         # view to the compute dtype (prefill stays flash-eligible)
         k_view = dequantize_kv(k_view, cache.k_scale[li, pages], cfg.dtype)
         v_view = dequantize_kv(v_view, cache.v_scale[li, pages], cfg.dtype)
-    k_view = k_view.reshape(b, n_read * ps, -1, cfg.head_dim)
-    v_view = v_view.reshape(b, n_read * ps, -1, cfg.head_dim)
+    k_view = k_view.reshape(b, n_read * ps, -1, pool_hd)
+    v_view = v_view.reshape(b, n_read * ps, -1, pool_hd)
     if pool_kv > n_kv:
         k_view, v_view = k_view[:, :, :n_kv], v_view[:, :, :n_kv]
-    return _attention_auto(cfg, q, k_view, v_view, positions, pos_start), cache
+    if pool_hd > hd:
+        q = _pad_head_dim(q, pool_hd)
+    a = _attention_auto(cfg, q, k_view, v_view, positions, pos_start, scale)
+    return (a[..., :hd] if pool_hd > hd else a), cache
 
 
 def latent_arm(cfg, cache, addr, q, k, v, positions, pos_start):
@@ -441,12 +466,72 @@ def sp_arm(cfg, cache, addr, q, k, v, positions, pos_start):
 
 
 # -- the recurrent arm ------------------------------------------------------
+# A linear layer's KIND (`cfg.lin_kind`) owns its arithmetic: what the conv's
+# output and the gates' projections become (`operands`), the Pallas decode
+# step over the state where it lies (`step`), the chunked form (`chunked`).
+# The slots, the conv and its tail, the fresh-row rule and the kernel's gate
+# are `recurrent_arm`'s, written once.
+
+
+def _gdn_operands(cfg, y, gates, gp, valid):
+    """The delta rule's (q, k, v, log_alpha, beta) of the conv's activated
+    output y [b, t, 2*hk + hv] and the gates' projections (a, b) [b, t, H];
+    gp = (a_log [H], dt_bias [H])."""
+    bsz, t = valid.shape
+    H, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    hk = H * dk
+    (a, b), (a_log, dt_bias) = gates, gp
+    q = gated_delta.l2_normalize(y[..., :hk].reshape(bsz, t, H, dk)) * dk**-0.5
+    k = gated_delta.l2_normalize(y[..., hk : 2 * hk].reshape(bsz, t, H, dk))
+    v = y[..., 2 * hk :].reshape(bsz, t, H, dv)
+    log_alpha, beta = gated_delta.gdn_gates(a, b, a_log, dt_bias, cfg.lin_neg_eigval)
+    log_alpha = jnp.where(valid[..., None], log_alpha, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    return q, k, v, log_alpha, beta
+
+
+def _gdn_step(cfg, rec, layer, ops, fresh):
+    q, k, v, log_alpha, beta = ops
+    return gdn_decode_step(
+        rec, layer, q[:, 0], k[:, 0], v[:, 0],
+        jnp.exp(log_alpha[:, 0]), beta[:, 0], ~fresh,
+        interpret=cfg.pallas_interpret,
+    )
+
+
+def _ssd_operands(cfg, y, gates, gp, valid):
+    """The state space's (x, B, C, dt, A, D) of the conv's activated output
+    y [b, t, H*P + 2*N] (x | B | C) and the step's projection (dt,) [b, t, H];
+    gp = (a_log [H], dt_bias [H], d [H]). `dt` is 0 where not valid."""
+    bsz, t = valid.shape
+    H, N, P = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    (dt,), (a_log, dt_bias, d) = gates, gp
+    x = y[..., : H * P].reshape(bsz, t, H, P)
+    B = y[..., H * P : H * P + N]
+    C = y[..., H * P + N :]
+    dt = jnp.where(valid[..., None], jax.nn.softplus(dt + dt_bias), 0.0)
+    return x, B, C, dt, -jnp.exp(a_log), d
+
+
+def _ssd_step(cfg, rec, layer, ops, fresh):
+    x, B, C, dt, A, D = ops
+    return ssd_decode_step(
+        rec, layer, x[:, 0], B[:, 0], C[:, 0], dt[:, 0], A, D, ~fresh,
+        interpret=cfg.pallas_interpret,
+    )
+
+
+# kind -> (operands, step, chunked)
+_REC_KINDS = {
+    "gated_delta": (_gdn_operands, _gdn_step, gated_delta.gdn_chunked),
+    "ssd": (_ssd_operands, _ssd_step, ssd_chunked),
+}
 
 
 def _rec_kernel_eligible(cfg, t: int, rec_row) -> bool:
-    """Gate for the Pallas decode step (ops/pallas_gdn.py): Pallas enabled,
-    one position a row, batch rows that are the state's slots, and heads that
-    fill whole lanes in some chunk."""
+    """Gate for a kind's Pallas decode step (ops/pallas_gdn.py, ops/ssd.py):
+    Pallas enabled, one position a row, batch rows that are the state's
+    slots, and heads that fill whole lanes in some chunk."""
     return (
         _pallas_enabled(cfg)
         and t == 1
@@ -455,21 +540,21 @@ def _rec_kernel_eligible(cfg, t: int, rec_row) -> bool:
     )
 
 
-def recurrent_arm(cfg, cache, addr, rec_layer, z, a, b, gp, positions, valid):
-    """A linear-attention layer's "cache": no page list, a slot a row. Not
-    one of `select_arm`'s — the layer's KIND picks it, not the address.
+def recurrent_arm(cfg, cache, addr, rec_layer, z, conv, gates, gp, positions, valid):
+    """A linear layer's "cache": no page list, a slot a row. Not one of
+    `select_arm`'s — the layer's KIND picks it, not the address.
 
-    z [b, t, 2*hk + hv] f32, the q | k | v projections before the conv; a, b
-    [b, t, H] f32, the gates' projections; gp (conv taps [K, C], a_log [H],
-    dt_bias [H]) this layer's; positions [b, t]; valid [b, t] bool: false on
-    a chunk's padding and on parked rows (positions at seq_len), whose state
-    and conv tail stay what they were. A row at position 0 starts from a zero
-    state and a zero tail, whatever its slot held: a slot needs no clearing
-    when a request takes it. Returns (o [b, t, H, dv] f32, cache)."""
-    taps, a_log, dt_bias = gp
-    bsz, t = positions.shape
-    H, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
-    hk = H * dk
+    z [b, t, channels] f32, the conv's inputs (q | k | v, or x | B | C);
+    conv = (taps [K, C], bias [C] or None); gates, the kind's gate
+    projections [b, t, H] f32 each, and gp, its per-head vectors, as its
+    `operands` function takes them; positions [b, t]; valid [b, t] bool:
+    false on a chunk's padding and on parked rows (positions at seq_len),
+    whose state and conv tail stay what they were. A row at position 0 starts
+    from a zero state and a zero tail, whatever its slot held: a slot needs
+    no clearing when a request takes it. Returns (o [b, t, H, dv] f32, cache)."""
+    operands, step, chunked = _REC_KINDS[cfg.lin_kind]
+    taps, bias = conv
+    t = positions.shape[1]
     fresh = positions[:, 0] == 0  # [b]
     row = addr.rec_row
 
@@ -485,22 +570,13 @@ def recurrent_arm(cfg, cache, addr, rec_layer, z, a, b, gp, positions, valid):
 
     tail = jnp.where(fresh[:, None, None], 0.0, slot(cache.conv).astype(jnp.float32))
     y, new_tail = gated_delta.causal_conv(z, tail, taps, valid)
-    y = jax.nn.silu(y)
-    q = gated_delta.l2_normalize(y[..., :hk].reshape(bsz, t, H, dk)) * dk**-0.5
-    k = gated_delta.l2_normalize(y[..., hk : 2 * hk].reshape(bsz, t, H, dk))
-    v = y[..., 2 * hk :].reshape(bsz, t, H, dv)
-    log_alpha, beta = gated_delta.gdn_gates(a, b, a_log, dt_bias, cfg.lin_neg_eigval)
-    log_alpha = jnp.where(valid[..., None], log_alpha, 0.0)
-    beta = jnp.where(valid[..., None], beta, 0.0)
+    y = jax.nn.silu(y if bias is None else y + bias)
+    ops = operands(cfg, y, gates, gp, valid)
     conv = put(cache.conv, new_tail)
 
     if _rec_kernel_eligible(cfg, t, row):
-        o, rec = gdn_decode_step(
-            cache.rec, jnp.asarray(rec_layer, jnp.int32), q[:, 0], k[:, 0], v[:, 0],
-            jnp.exp(log_alpha[:, 0]), beta[:, 0], ~fresh,
-            interpret=cfg.pallas_interpret,
-        )
+        o, rec = step(cfg, cache.rec, jnp.asarray(rec_layer, jnp.int32), ops, fresh)
         return o[:, None], replace(cache, rec=rec, conv=conv)
     S = jnp.where(fresh[:, None, None], 0.0, slot(cache.rec).astype(jnp.float32))
-    o, S = gated_delta.gdn_chunked(S, q, k, v, log_alpha, beta)
+    o, S = chunked(S, *ops)
     return o, replace(cache, rec=put(cache.rec, S), conv=conv)

@@ -62,6 +62,29 @@ _register(GdnParams, ["wqkvg", "wab", "conv", "a_log", "dt_bias", "o_norm", "wo"
 
 
 @dataclass
+class MambaParams:
+    """The state-space layers' mixer weights (granite_hybrid; ops/ssd.py),
+    stacked over those layers alone, in layer order. `H` heads of `P`
+    channels (d_inner = H P), a state of `N`, conv channels x | B | C."""
+
+    w_in: Weight  # [Lr, 2*d_inner + 2*N, dim]: z | xBC of the in-projection
+    w_dt: jnp.ndarray  # [Lr, H, dim] f32: the in-projection's step rows
+    conv: jnp.ndarray  # [Lr, K, d_inner + 2*N] f32 depthwise taps
+    conv_bias: jnp.ndarray  # [Lr, d_inner + 2*N] f32 (zeros: the model has none)
+    a_log: jnp.ndarray  # [Lr, H] f32
+    dt_bias: jnp.ndarray  # [Lr, H] f32
+    d: jnp.ndarray  # [Lr, H] f32: the skip
+    norm: jnp.ndarray  # [Lr, d_inner]: the gated norm's weight
+    w_out: Weight  # [Lr, dim, d_inner]
+
+
+_register(
+    MambaParams,
+    ["w_in", "w_dt", "conv", "conv_bias", "a_log", "dt_bias", "d", "norm", "w_out"],
+)
+
+
+@dataclass
 class MlaParams:
     """Latent attention's weights (kimi_k2; models/kv_arms.latent_arm),
     stacked over all layers. q: x -> rank -> norm -> heads of [nope | rope];
@@ -107,8 +130,9 @@ class LayerParams:
     """Per-layer weights, each stacked with a leading [n_layers] axis.
 
     A hybrid model (cfg.is_hybrid) stacks by KIND: the attention fields hold
-    the full-attention layers alone ([n_kv_layers, ...]), `gdn` the linear
-    ones, and the feed-forward fields and both norms all n_layers.
+    the full-attention layers alone ([n_kv_layers, ...]), `gdn` or `ssm` (by
+    `cfg.lin_kind`) the linear ones, and the feed-forward fields and both
+    norms all n_layers.
 
     Decode makes one kernel dispatch per matmul, so the loader FUSES the
     row-split projections that share an input: q/k/v -> `wqkv` (always) and
@@ -140,12 +164,13 @@ class LayerParams:
     # alone ([n_dense_layers, ...]), `experts` the other layers' feed-forward
     mla: Optional[MlaParams] = None
     experts: Optional[ExpertParams] = None
+    ssm: Optional[MambaParams] = None  # the state-space layers' mixers (granite_hybrid)
 
 
 _register(
     LayerParams,
     ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1", "q_norm", "k_norm",
-     "moe_gate", "wqkv", "w13", "gdn", "mla", "experts"],
+     "moe_gate", "wqkv", "w13", "gdn", "mla", "experts", "ssm"],
 )
 
 
@@ -179,8 +204,8 @@ class KVCache:
     # every donation/sharding contract over it) is unchanged.
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
-    # a hybrid model's linear-attention layers keep no KV: a fixed state a
-    # row instead (ops/gated_delta.py), slots by batch row whatever layout
+    # a hybrid model's linear layers keep no KV: a fixed state a row instead
+    # (ops/gated_delta.py, ops/ssd.py), slots by batch row whatever layout
     # k/v have. `rec` [n_rec_layers, rows, dk, H*dv] f32; `conv`
     # [n_rec_layers, rows, K-1, channels], the conv's last pre-activation
     # inputs in the compute dtype. None on every other model (flattens away).
@@ -416,9 +441,10 @@ def _pad_in_blocks(part: tuple, multiple: int = 8) -> tuple:
 
 
 def _load_hybrid(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
-    """olmo_hybrid: the attention stack over the full layers, the gated-delta
-    stack over the linear ones, the feed-forward and both norms over all.
-    Single chip only (the engine refuses a mesh for this architecture)."""
+    """olmo_hybrid, granite_hybrid: the attention stack over the full layers,
+    the mixers' stack over the linear ones (gated-delta or state-space, by
+    `cfg.lin_kind`), the feed-forward and both norms over all. Single chip
+    only (the engine refuses a mesh for these architectures)."""
 
     def one(role, l, dtype=dense):
         return _load_one(reader, reader.by_name[f"{role}.l{l}"], dtype)
@@ -429,32 +455,50 @@ def _load_hybrid(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
     every = range(cfg.n_layers)
     put = lambda parts: _put(_stack(parts))  # noqa: E731
     f32 = np.float32
-    gdn = GdnParams(
-        wqkvg=put([
-            _fuse_rows([one(r, l) for r in ("lin_q", "lin_k", "lin_v", "lin_g")], 1)
-            for l in lin
-        ]),
-        wab=put([np.concatenate([one("lin_a", l, f32), one("lin_b", l, f32)]) for l in lin]),
-        conv=put([one("lin_conv", l, f32) for l in lin]),
-        a_log=put([one("lin_a_log", l) for l in lin]),
-        dt_bias=put([one("lin_dt_bias", l) for l in lin]),
-        o_norm=put([one("lin_o_norm", l) for l in lin]),
-        wo=put([
-            _pad_in_blocks(w) if isinstance(w, tuple) else w
-            for w in (one("lin_wo", l) for l in lin)
-        ]),
-    )
+    qk_norm = f"q_norm.l{full[0]}" in reader.by_name
+    if cfg.lin_kind == "ssd":
+        mixers = dict(ssm=MambaParams(
+            w_in=put([one("ssm_in", l) for l in lin]),
+            w_dt=put([one("ssm_dt", l, f32) for l in lin]),
+            conv=put([one("ssm_conv", l, f32) for l in lin]),
+            conv_bias=put([
+                one("ssm_conv_bias", l) if cfg.lin_conv_bias
+                else np.zeros(cfg.lin_conv_channels, f32) for l in lin
+            ]),
+            a_log=put([one("ssm_a_log", l) for l in lin]),
+            dt_bias=put([one("ssm_dt_bias", l) for l in lin]),
+            d=put([one("ssm_d", l) for l in lin]),
+            norm=put([one("ssm_norm", l) for l in lin]),
+            w_out=put([one("ssm_out", l) for l in lin]),
+        ))
+    else:
+        mixers = dict(gdn=GdnParams(
+            wqkvg=put([
+                _fuse_rows([one(r, l) for r in ("lin_q", "lin_k", "lin_v", "lin_g")], 1)
+                for l in lin
+            ]),
+            wab=put([np.concatenate([one("lin_a", l, f32), one("lin_b", l, f32)]) for l in lin]),
+            conv=put([one("lin_conv", l, f32) for l in lin]),
+            a_log=put([one("lin_a_log", l) for l in lin]),
+            dt_bias=put([one("lin_dt_bias", l) for l in lin]),
+            o_norm=put([one("lin_o_norm", l) for l in lin]),
+            wo=put([
+                _pad_in_blocks(w) if isinstance(w, tuple) else w
+                for w in (one("lin_wo", l) for l in lin)
+            ]),
+        ))
     layers = LayerParams(
         q=None, k=None, v=None, w1=None, w3=None,
         wqkv=put([_fuse_rows([one(r, l) for r in ("q", "k", "v")], 1) for l in full]),
         wo=put([one("wo", l) for l in full]),
-        q_norm=put([one("q_norm", l) for l in full]),
-        k_norm=put([one("k_norm", l) for l in full]),
+        # a q/k norm over the whole projection where the file has one (Olmo's)
+        q_norm=put([one("q_norm", l) for l in full]) if qk_norm else None,
+        k_norm=put([one("k_norm", l) for l in full]) if qk_norm else None,
         w13=put([_fuse_rows([one("w1", l), one("w3", l)], 1) for l in every]),
         w2=put([one("w2", l) for l in every]),
         norm0=put([one("norm0", l) for l in every]),
         norm1=put([one("norm1", l) for l in every]),
-        gdn=gdn,
+        **mixers,
     )
     return ModelParams(
         embedding=_put(_load_one(reader, reader.by_name["embedding"], np.float32)),

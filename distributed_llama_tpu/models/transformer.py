@@ -1,4 +1,5 @@
-"""The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE / Olmo-Hybrid / Kimi-K2).
+"""The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE / Olmo-Hybrid / Kimi-K2 /
+Granite-Hybrid).
 
 Functional re-design of the reference's per-node op graph (reference:
 buildLlmNet, src/llm.cpp:152-649). One layer body is `lax.scan`ned over
@@ -27,6 +28,15 @@ sits on each sub-layer's OUTPUT and there is none before it:
 
 with mixer = attention over a q/k normed across the whole projection, or the
 gated delta rule (`_gdn_mixer`).
+
+Granite-Hybrid (no reference analogue; `_ssm_layers`): periods whose full
+layer sits anywhere in them, the others Mamba-2 state-space layers
+(ops/ssd.py, `_ssm_mixer`); pre-norm blocks and Granite's four multipliers:
+
+    x = e E[token];  x += r mixer(rms_norm(x, norm0));  x += r ffn(rms_norm(x, norm1))
+
+attention with no position embedding and scores times `attn_scale`, logits
+over `logits_scaling`.
 
 Kimi-K2 (the DeepSeek-V3 block; no reference analogue; `_latent_layers`):
 pre-norm residual blocks, latent attention in every layer (`_latent_attention`),
@@ -376,10 +386,11 @@ def _gdn_mixer(cfg, x, gp, cache, addr, ri, positions, valid):
         "btd,hd->bth", x.astype(jnp.float32), _sel_layer(gp.wab, ri),
         precision=jax.lax.Precision.HIGHEST,
     )
+    z = zg[..., :n_conv].astype(jnp.float32)
+    gates = ab[..., : cfg.lin_heads], ab[..., cfg.lin_heads :]
     o, cache = recurrent_arm(
-        cfg, cache, addr, ri, zg[..., :n_conv].astype(jnp.float32),
-        ab[..., : cfg.lin_heads], ab[..., cfg.lin_heads :],
-        (_sel_layer(gp.conv, ri), _sel_layer(gp.a_log, ri), _sel_layer(gp.dt_bias, ri)),
+        cfg, cache, addr, ri, z, (_sel_layer(gp.conv, ri), None), gates,
+        (_sel_layer(gp.a_log, ri), _sel_layer(gp.dt_bias, ri)),
         positions, valid,
     )
     gate = zg[..., n_conv:].astype(jnp.float32).reshape(b, t, cfg.lin_heads, cfg.lin_value_dim)
@@ -424,6 +435,88 @@ def _hybrid_layers(cfg, params, rope, x, cache, positions, pos_start, valid, add
         y = linear(a.reshape(b, t, cfg.q_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, p)
         x = x + rms_norm(y, _sel_layer(lp.norm0, fi), eps).astype(x.dtype)
         return (ffn_block(x, fi), cache), None
+
+    periods = jnp.arange(cfg.n_layers // period, dtype=jnp.int32)
+    (x, cache), _ = jax.lax.scan(body, (x, cache), periods)
+    return x, cache
+
+
+def _ssm_mixer(cfg, y, mp, cache, addr, ri, positions, valid):
+    """The Mamba-2 mixer of the NORMED activation y [b, t, dim] for
+    state-space layer `ri` of the `mp` stack: the in-projection (z | xBC in
+    Q40, the step in float32: it decides what the state keeps), the recurrent
+    arm (conv, the state space over this layer's slots, the skip), the gate
+    BEFORE the norm over all of d_inner, the output projection. Returns
+    (out [b, t, dim] before the residual, cache)."""
+    b, t, _ = y.shape
+    q80, d_inner = cfg.q80_activations, cfg.lin_vdim
+    zx = linear(y, mp.w_in, cfg.dtype, cfg.pallas_arg, q80, ri)
+    dt = jnp.einsum(
+        "btd,hd->bth", y.astype(jnp.float32), _sel_layer(mp.w_dt, ri),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    o, cache = recurrent_arm(
+        cfg, cache, addr, ri, zx[..., d_inner:].astype(jnp.float32),
+        (_sel_layer(mp.conv, ri), _sel_layer(mp.conv_bias, ri)), (dt,),
+        (_sel_layer(mp.a_log, ri), _sel_layer(mp.dt_bias, ri), _sel_layer(mp.d, ri)),
+        positions, valid,
+    )
+    g = o.reshape(b, t, d_inner) * silu(zx[..., :d_inner].astype(jnp.float32))
+    g = rms_norm(g, _sel_layer(mp.norm, ri), cfg.norm_epsilon)
+    return linear(g, mp.w_out, cfg.dtype, cfg.pallas_arg, q80, ri), cache
+
+
+def _ssm_layers(cfg, params, rope, x, cache, positions, pos_start, valid, addr):
+    """Granite-Hybrid's layer stack: pre-norm blocks whose outputs join the
+    residual stream times `residual_mult`; per period, `full_attn_offset`
+    state-space layers, the full-attention layer (no position embedding; the
+    cache arms take `attn_scale` for the scores), then the period's other
+    state-space layers. ONE scan over the periods whose body runs each run
+    of state-space layers as an inner scan, so a program holds three layer
+    bodies whatever the period's length (ten unrolled layers lowered a
+    prompt's chunk in 1.2-1.7 s and compiled it in 7 where this takes 0.7-0.9
+    and 4: PERF.md section 6, PR 42). Weights stay
+    stacked by kind and are selected inside the kernels: state-space stack
+    index `p (interval-1) + j`, full stack and KV index `p`, feed-forward and
+    norm index the layer's own. Returns (x, cache)."""
+    b, t, _ = x.shape
+    lp = params.layers
+    period, before = cfg.full_attn_interval, cfg.full_attn_offset % cfg.full_attn_interval
+    eps, rm = cfg.norm_epsilon, cfg.residual_mult
+
+    def ffn_block(x, fi):
+        y = rms_norm(x, _sel_layer(lp.norm1, fi), eps)
+        return x + rm * _dense_ffn(cfg, y, lp, fi).astype(x.dtype)
+
+    def ssm_run(x, cache, p, first, n):
+        """The period's state-space layers `first .. first + n - 1`."""
+        skipped = 1 if first > before else 0  # the full layer is no mixer
+
+        def body(carry, j):
+            x, cache = carry
+            fi = p * period + j
+            y = rms_norm(x, _sel_layer(lp.norm0, fi), eps)
+            ri = p * (period - 1) + j - skipped
+            y, cache = _ssm_mixer(cfg, y, lp.ssm, cache, addr, ri, positions, valid)
+            return (ffn_block(x + rm * y.astype(x.dtype), fi), cache), None
+
+        if n == 0:
+            return x, cache
+        js = jnp.arange(first, first + n, dtype=jnp.int32)
+        return jax.lax.scan(body, (x, cache), js)[0]
+
+    def body(carry, p):
+        x, cache = carry
+        x, cache = ssm_run(x, cache, p, 0, before)
+        fi = p * period + before
+        y = rms_norm(x, _sel_layer(lp.norm0, fi), eps)
+        q, k, v = _qkv(cfg, rope, y, lp, positions, p)
+        a_addr = addr._replace(layer=p)
+        a, cache = select_arm(a_addr)(cfg, cache, a_addr, q, k, v, positions, pos_start)
+        y = linear(a.reshape(b, t, cfg.q_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, p)
+        x = ffn_block(x + rm * y.astype(x.dtype), fi)
+        x, cache = ssm_run(x, cache, p, before + 1, period - 1 - before)
+        return (x, cache), None
 
     periods = jnp.arange(cfg.n_layers // period, dtype=jnp.int32)
     (x, cache), _ = jax.lax.scan(body, (x, cache), periods)
@@ -572,6 +665,8 @@ def forward_uncompiled(
         valid = (tokens >= 0) & (positions < cfg.seq_len)
         tokens = jnp.maximum(tokens, 0)
     x = params.embedding[tokens].astype(jnp.float32)
+    if cfg.embedding_mult != 1.0:
+        x = x * cfg.embedding_mult
 
     # the scan's xs carry only the layer index; the stacked weights ride in
     # via closure and each matmul selects its layer inside the kernel
@@ -593,7 +688,8 @@ def forward_uncompiled(
         return (x, cache), None
 
     if cfg.is_hybrid:
-        x, new_cache = _hybrid_layers(
+        stack = _ssm_layers if cfg.lin_kind == "ssd" else _hybrid_layers
+        x, new_cache = stack(
             cfg, params, rope, x, cache, positions, pos_start, valid, addr
         )
     elif cfg.is_latent:
@@ -608,7 +704,10 @@ def forward_uncompiled(
     if logits_mode == "last":
         x = x[:, -1, :]
     logits = linear(x, params.wcls, cfg.dtype, cfg.pallas_arg, cfg.q80_activations)
-    return logits.astype(jnp.float32), new_cache
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits, new_cache
 
 
 # The jit entry point: cache is donated (updated in place in HBM); one

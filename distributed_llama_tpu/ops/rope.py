@@ -174,4 +174,6 @@ def apply_rope(
         return apply_rope_llama(x, tables, positions)
     if rope_type == RopeType.FALCON:
         return apply_rope_falcon(x, tables, positions)
+    if rope_type == RopeType.NONE:
+        return x
     raise ValueError(f"unsupported rope type {rope_type}")

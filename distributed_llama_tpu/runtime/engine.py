@@ -336,8 +336,8 @@ class InferenceEngine:
             ]
             if refused:
                 why = (
-                    "linear-attention layers keep a recurrent state a row, which "
-                    "has no snapshots or rollback yet (ROADMAP R7)"
+                    "linear layers (gated-delta, state-space) keep a recurrent "
+                    "state a row, which has no snapshots or rollback yet (ROADMAP R7)"
                     if self.cfg.is_hybrid else
                     "latent attention keeps one [latent | key] vector a token in "
                     "the paged float pool of one chip, and its expert layers hold "
@@ -525,9 +525,9 @@ class InferenceEngine:
 
             if resolve_budget_mb(prefix_cache_mb, default_mb=0) > 0:
                 self._notice(
-                    "prefix cache off: a linear-attention layer's recurrent "
-                    "state has no snapshots at page boundaries yet (ROADMAP "
-                    "R7), so a cached prefix cannot be resumed"
+                    "prefix cache off: a linear layer's recurrent state has no "
+                    "snapshots at page boundaries yet (ROADMAP R7), so a cached "
+                    "prefix cannot be resumed"
                     if self.cfg.is_hybrid else
                     "prefix cache off: its publish, share and ship programs "
                     "read a page as k and v heads, and a latent page is one "
@@ -654,11 +654,12 @@ class InferenceEngine:
 
     def rec_state_snapshot(self):
         """The recurrent-state cache as /stats reports it beside `kv_pool`:
-        one slot a batch row, allocated once. None for a model that keeps no
-        such state."""
+        one slot a batch row, allocated once; `kind` is the linear layers'
+        (`gated_delta` | `ssd`). None for a model that keeps no such state."""
         if not self.rec_slot_bytes:
             return None
         return {
+            "kind": self.cfg.lin_kind,
             "slots": self.batch,
             "bytes": self.rec_slot_bytes * self.batch,
             "slot_bytes": self.rec_slot_bytes,

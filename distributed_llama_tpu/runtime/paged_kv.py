@@ -164,6 +164,22 @@ def pool_kv_heads(n_kv_heads: int, tp: int = 1) -> int:
     return n_kv_heads if local < 8 else -(-local // 8) * 8 * tp
 
 
+def pool_head_dim(cfg) -> int:
+    """The width a pool stores a head at: a hybrid model's head of 64 is
+    stored as 128, its tail zeros. The TPU keeps a bf16 pool's trailing
+    (heads, head_dim) axes in (16, 128) tiles: (8, 64) takes four times its
+    bytes there, every layer call re-lays the whole pool out (seen compiling
+    Granite's step for a v5e: 2.1 GB of temps), and the page-table decode
+    kernel is not eligible (models/kv_arms._fused_paged_eligible). At 128 the
+    pool is twice its bytes, the kernel reads it where it lies, and the arm
+    pads q, k, v and cuts the output (models/kv_arms.paged_arm). Only where
+    nothing else reads a page: the prefix cache's, the transport's and the
+    tiers' page programs take a page at the model's width, and a hybrid model
+    starts without them (ROADMAP R7 queues the dense models' head of 64)."""
+    hd = cfg.head_dim
+    return 128 if cfg.is_hybrid and 64 <= hd < 128 and cfg.n_kv_heads % 8 == 0 else hd
+
+
 def page_pool_bytes(cfg, n_pages: int, page_size: int, tp: int = 1) -> int:
     """Device bytes of a pool's k+v tensors (+ the f32 scale sidecars on the
     int8 arm — capacity math, /stats, and the cost model must all price the
@@ -174,7 +190,7 @@ def page_pool_bytes(cfg, n_pages: int, page_size: int, tp: int = 1) -> int:
             cfg.n_kv_layers * n_pages * page_size
             * cfg.latent_page_width * jnp.dtype(cfg.kv_dtype).itemsize
         )
-    per_vector = cfg.head_dim * jnp.dtype(cfg.kv_dtype).itemsize
+    per_vector = pool_head_dim(cfg) * jnp.dtype(cfg.kv_dtype).itemsize
     if cfg.kv_quantized:
         per_vector += 4  # one f32 scale per (token, kv-head) vector
     return (
@@ -206,7 +222,7 @@ def init_kv_pool(cfg, n_pages: int, page_size: int, rows: int = 0, tp: int = 1) 
         )
     shape = (
         cfg.n_kv_layers, n_pages, page_size, pool_kv_heads(cfg.n_kv_heads, tp),
-        cfg.head_dim,
+        pool_head_dim(cfg),
     )
     k = jnp.zeros(shape, dtype=cfg.kv_dtype)
     v = jnp.zeros(shape, dtype=cfg.kv_dtype)

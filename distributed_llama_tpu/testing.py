@@ -65,6 +65,18 @@ def tiny_header(
     expert_first: int = 0,
     n_shared_experts: int = 1,
     routed_scale: float = 1.0,
+    # granite_hybrid: the full layer is layer `full_attn_offset` of a period
+    # of `full_attn_interval`, the others state-space layers of `lin_heads`
+    # heads of `lin_value_head_dim` channels with a state of
+    # `lin_key_head_dim`; Granite's four multipliers (attention's 0 =
+    # head_dim^-1/2)
+    full_attn_offset: int = -1,
+    lin_groups: int = 1,
+    lin_conv_bias: bool = True,
+    embedding_mult: float = 1.0,
+    attention_mult: float = 0.0,
+    residual_mult: float = 1.0,
+    logits_scaling: float = 1.0,
 ) -> ModelHeader:
     h = ModelHeader(
         version=1,
@@ -97,6 +109,15 @@ def tiny_header(
         h.lin_value_head_dim = lin_value_head_dim
         h.lin_conv_kernel = lin_conv_kernel
         h.lin_neg_eigval = int(lin_neg_eigval)
+    if arch == ArchType.GRANITE_HYBRID:
+        h.full_attn_interval, h.full_attn_offset = full_attn_interval, full_attn_offset
+        h.lin_key_heads = h.lin_value_heads = lin_heads
+        h.lin_key_head_dim = lin_key_head_dim
+        h.lin_value_head_dim = lin_value_head_dim
+        h.lin_conv_kernel = lin_conv_kernel
+        h.lin_groups, h.lin_conv_bias = lin_groups, int(lin_conv_bias)
+        h.embedding_mult, h.attention_mult = embedding_mult, attention_mult
+        h.residual_mult, h.logits_scaling = residual_mult, logits_scaling
     if arch == ArchType.KIMI_K2:
         h.q_lora_rank, h.kv_lora_rank = q_lora_rank, kv_lora_rank
         h.qk_nope_head_dim, h.qk_rope_head_dim = qk_nope_head_dim, qk_rope_head_dim
@@ -146,7 +167,16 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
         kv[mfile.K_LIN_KEY_HEAD_DIM] = h.lin_key_head_dim
         kv[mfile.K_LIN_VALUE_HEAD_DIM] = h.lin_value_head_dim
         kv[mfile.K_LIN_CONV_KERNEL] = h.lin_conv_kernel
+    if h.is_hybrid and not h.is_ssm:
         kv[mfile.K_LIN_NEG_EIGVAL] = h.lin_neg_eigval
+    if h.is_ssm:
+        kv[mfile.K_FULL_ATTN_OFFSET] = h.full_attn_offset
+        kv[mfile.K_LIN_GROUPS] = h.lin_groups
+        kv[mfile.K_LIN_CONV_BIAS] = h.lin_conv_bias
+        kv[mfile.K_EMBEDDING_MULT_MILLI] = round(h.embedding_mult * 1000)
+        kv[mfile.K_ATTENTION_MULT_MICRO] = round(h.attention_mult * 1e6)
+        kv[mfile.K_RESIDUAL_MULT_MILLI] = round(h.residual_mult * 1000)
+        kv[mfile.K_LOGITS_SCALING_MILLI] = round(h.logits_scaling * 1000)
     if h.is_latent:
         # YaRN's factor and original length ride the scaling keys whatever
         # the factor is
@@ -171,27 +201,49 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
 
 _NORM_ROLES = (
     "norm0", "norm1", "final_norm", "q_norm", "k_norm", "lin_o_norm",
-    "q_a_norm", "kv_a_norm",
+    "q_a_norm", "kv_a_norm", "ssm_norm",
 )
 
 
 def _gdn_init(role: str, shape: tuple, rng) -> np.ndarray | None:
-    """The gated-delta layer's published initialisation for the three
-    tensors a plain normal draw would make degenerate (Yang et al., as the
-    flash-linear-attention `GatedDeltaNet` draws them): `exp(a_log)` uniform
-    in [1, 16]; `dt_bias` the inverse softplus of a step log-uniform in
-    [0.001, 0.1]; conv taps std 0.02 around a last tap of 1. None for every
-    other role."""
-    if role == "lin_a_log":
+    """The linear layers' published initialisation for the tensors a plain
+    normal draw would make degenerate. The gated-delta layer's (Yang et al.,
+    as the flash-linear-attention `GatedDeltaNet` draws them) and Mamba-2's
+    (Dao, Gu, as `Mamba2` draws them) agree: `exp(a_log)` uniform in [1, 16];
+    `dt_bias` the inverse softplus of a step log-uniform in [0.001, 0.1];
+    conv taps std 0.02 around a last tap of 1 (here for both: Mamba-2's
+    uniform taps are as plain as a normal draw); the skip `D` ones. None for
+    every other role."""
+    if role in ("lin_a_log", "ssm_a_log"):
         return np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
-    if role == "lin_dt_bias":
+    if role in ("lin_dt_bias", "ssm_dt_bias"):
         dt = np.exp(rng.uniform(np.log(0.001), np.log(0.1), shape))
         return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
-    if role == "lin_conv":
+    if role in ("lin_conv", "ssm_conv"):
         x = rng.standard_normal(shape).astype(np.float32) * 0.02
         x[-1] += 1.0
         return x
+    if role == "ssm_d":
+        return np.ones(shape, np.float32)
     return None
+
+
+def tiny_ssm_header(**kw) -> ModelHeader:
+    """A tiny granite_hybrid header with every mechanism of the
+    architecture: two periods of `mamba, mamba, attention, mamba` (the full
+    layer mid-period), 4 state-space heads of 16 channels with a state of 32,
+    one B/C group, a conv of 4 with its bias, attention heads of 16 without a
+    position embedding, and the four multipliers off 1."""
+    base = dict(
+        arch=ArchType.GRANITE_HYBRID, dim=64, hidden_dim=128, n_layers=8,
+        n_heads=4, n_kv_heads=2, head_dim=16, vocab_size=256, seq_len=128,
+        full_attn_interval=4, full_attn_offset=2, lin_heads=4,
+        lin_key_head_dim=32, lin_value_head_dim=16, lin_conv_kernel=4,
+        embedding_mult=3.0, attention_mult=2.0, residual_mult=0.5,
+        logits_scaling=2.0,
+    )
+    base.update(kw)
+    return tiny_header(**base)
 
 
 def tiny_latent_header(**kw) -> ModelHeader:
@@ -234,6 +286,29 @@ def gdn_recurrence(S, q, k, v, log_alpha, beta):
     xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, log_alpha, beta))
     S4, o = jax.lax.scan(step, _heads(S, q.shape[2]), xs)
     return jnp.moveaxis(o, 0, 1), _lanes(S4)
+
+
+def ssd_recurrence(S, x, B, C, dt, A, D):
+    """Mamba-2's state space written out position by position: the
+    definition that `ops/ssd.ssd_chunked` and the Pallas step
+    `ops/ssd.ssd_decode_step` are held to (tests, `chip_smoke.py`).
+    S [b, N, H*P]; x [b, t, H, P]; B, C [b, t, N]; dt [b, t, H]; A, D [H];
+    float32, products at `highest`. Returns (y [b, t, H, P], S)."""
+    import jax
+    import jax.numpy as jnp
+
+    from .ops.gated_delta import HP, _heads, _lanes
+
+    def step(S4, xs):
+        x_t, B_t, C_t, dt_t = xs
+        S4 = S4 * jnp.exp(dt_t * A)[:, :, None, None]
+        S4 = S4 + B_t[:, None, :, None] * (dt_t[..., None] * x_t)[:, :, None, :]
+        y = jnp.einsum("bhnp,bn->bhp", S4, C_t, precision=HP)
+        return S4, y + D[:, None] * x_t
+
+    xs = tuple(jnp.moveaxis(v, 1, 0) for v in (x, B, C, dt))
+    S4, y = jax.lax.scan(step, _heads(S, x.shape[2]), xs)
+    return jnp.moveaxis(y, 0, 1), _lanes(S4)
 
 
 # bulk writer piece size: 4M elements. Each worker thread draws and quantizes

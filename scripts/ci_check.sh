@@ -132,6 +132,11 @@ XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 DLT_PALLAS_INTERPRET=1 \
   python scripts/dlt_graph_diff.py --check --coverage --arch olmo_hybrid \
   --kv-layout paged --speculative off --prefix-cache-mb 0
+# the tiny Granite-Hybrid's ladder (runs of state-space layers in inner scans,
+# the state-space decode kernel's body, head 64 stored as 128), in bfloat16
+DLT_PALLAS_INTERPRET=1 \
+  python scripts/dlt_graph_diff.py --check --coverage --arch granite_hybrid \
+  --kv-layout paged --speculative off --prefix-cache-mb 0 --compute-dtype bfloat16
 
 echo "== graph contracts (MASKED ladder goldens, grammar arena) =="
 # the grammar-capable engine's decode/verify programs carry the mask-table

@@ -46,6 +46,7 @@ def rehearsals(tmp_path_factory):
         "one": _run(["--rehearse"], JAX_COMPILATION_CACHE_DIR=str(cache)),
         "four": _run(["--rehearse", "--chips", "4"]),
         "kimi": _run(["--rehearse", "--arch", "kimi_k2"]),
+        "granite": _run(["--rehearse", "--arch", "granite_hybrid"]),
     }
     runs = {}
     for name, p in procs.items():
@@ -179,3 +180,31 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert p.returncode != 0 and result["ok"] is False
     assert any("the program is not here" in r for r in result["reasons"])
     assert os.listdir(tmp_path) == ["chip_smoke.py"]
+
+
+def test_the_granite_hybrid_branch_rehearses_its_numbers_and_its_server(rehearsals):
+    """`--arch granite_hybrid`: the state-space kernel against the chunked
+    form on the state (and a bfloat16 state failing the same bound), a period
+    of layers against its float32 form, then the server at 2 rows; on the CPU
+    it too fails for the device check alone."""
+    code, lines, last = rehearsals["granite"]
+    result = json.loads(last)
+    assert code != 0 and result["reasons"] == ["need 1 tpu device(s), jax found 1 x cpu"], result
+    numbers = _by_phase(lines, "numbers")
+    assert len(numbers) == 3
+    state = numbers[0]
+    assert state["kernel_state"] <= state["bound"] < state["control_bfloat16_state"]
+    assert state["kernel_other_layer"] == 0.0 and state["decays"][0] >= 0.9
+    for l in numbers[1:]:
+        assert l["layers"] == "llfl" and l["pool_head_dim"] == 128  # head 64 stored as 128
+        assert l["top1_agreement"] >= l["bounds"][0] and l["max_diff_std"] <= l["bounds"][1]
+    serve = _by_phase(lines, "serve")[-1]
+    assert serve["kernels_traced_compiled"] == {
+        "batch_decode[1|kv256]": [16, 0], "prefill_row[8|kv256]": [14, 0]}
+    requests = {l["request"]: l for l in _by_phase(lines, "request")}
+    assert set(requests) == {"plain", "streamed", "concurrent-0", "concurrent-1"}
+    assert all(r["status"] == 200 and r["text_chars"] > 0 for r in requests.values())
+    (stats,) = _by_phase(lines, "stats")
+    assert not any(stats["watched"].values()) and stats["supervisor"] == "serving"
+    assert stats["rec_state"]["kind"] == "ssd" and stats["rec_state"]["slots"] == 2
+    assert any("prefix cache off" in n for n in stats["notices"])

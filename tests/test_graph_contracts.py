@@ -513,6 +513,89 @@ def test_latent_batch_decode_reads_the_page_through_the_gather_arm(latent_engine
     assert ("q40_matmul_pallas_grouped" in text) == eng.cfg.pallas_interpret
 
 
+# -- the state-space hybrid's programs (runs of layers in inner scans) ----------
+
+SSM_ARGS = ["--arch", "granite_hybrid", "--kv-layout", "paged", "--speculative", "off",
+            "--prefix-cache-mb", "0", "--compute-dtype", "bfloat16"]
+
+
+def test_repo_golden_covers_the_tiny_state_space_hybrid(monkeypatch):
+    """One golden for the tiny Granite-Hybrid's warm plan (prefill_row,
+    batch_decode, page_copy) in bfloat16 with Pallas interpreted, so that it
+    holds the state-space decode kernel's body beside the stacked Q40 and
+    page-table kernels'."""
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    assert gd.main(["--check", "--coverage", *SSM_ARGS]) == 0
+
+
+@pytest.fixture(scope="module", params=["xla", "interpret"])
+def ssm_engine(request, tmp_path_factory):
+    import argparse
+
+    mp = pytest.MonkeyPatch()
+    if request.param == "interpret":
+        mp.setenv("DLT_PALLAS_INTERPRET", "1")
+    else:
+        mp.delenv("DLT_PALLAS_INTERPRET", raising=False)
+    p = argparse.ArgumentParser()
+    ga.add_engine_args(p)
+    eng = ga.engine_from_args(p.parse_args(SSM_ARGS), str(tmp_path_factory.mktemp("ssm")))
+    yield eng
+    eng.close()
+    mp.undo()
+
+
+def test_state_space_programs_meet_their_contracts(ssm_engine):
+    """No float64, the float32 dots within what attention and the state
+    space need, no collective, and every leaf of the cache donated on every
+    jit entry; the batched plan holds the Batcher's programs alone."""
+    eng = ssm_engine
+    ga.assert_clean(ga.audit_engine(eng))
+    assert ga.donation_problems(eng) == []
+    assert len(jax.tree_util.tree_leaves(eng.cache)) == 4  # k, v, rec, conv
+    assert {kind for kind, _, _ in eng.warm_plan()} == {"prefill_row", "batch_decode", "page_copy"}
+
+
+def test_state_space_f32_dot_budget_counts_a_body_a_run(ssm_engine):
+    """A program holds attention's 2 and ONE state-space layer body a run of
+    such layers (two runs: before the period's full layer and after it): the
+    step's projection (1) and the recurrence: the Pallas step has no dot (0),
+    the chunked form has 4."""
+    eng = ssm_engine
+    kernel = eng.cfg.pallas_interpret
+    want = {
+        ("batch_decode", 8): 2 + 2 * (1 if kernel else 5),
+        ("prefill_row", 1): 2 + 2 * 5,  # one row against the batch's slots: never the kernel
+        ("prefill_row", 16): 2 + 2 * 5,
+    }
+    for (kind, size), budget in want.items():
+        entry = ga.LadderEntry(kind, size, 128)
+        assert ga.f32_dot_budget(eng, entry) == budget
+        dots = jt.dot_input_census(ga.trace_entry(eng, entry))
+        got = sum(n for (l, r), n in dots.items() if "float32" in (l, r))
+        assert got == budget, (kind, size, dots)
+
+
+def test_state_space_batch_decode_reads_head_64_through_the_kernel(ssm_engine):
+    """8 kv heads of 64 stored as 128: with Pallas on, the full-attention
+    layer of the batch-decode program takes the page-table kernel through the
+    padded pool, the contract pins the pool's gathers to zero, and the
+    state-space layers take their decode kernel."""
+    eng = ssm_engine
+    entry = ga.LadderEntry("batch_decode", 8, 128)
+    contract = ga.contract_for(eng, entry)
+    assert eng.cfg.head_dim == 64 and eng.cache.k.shape[3:] == (8, 128)
+    if not eng.cfg.pallas_interpret:
+        assert contract.forbid_pool_gather is None  # the gather arm, off the TPU
+        return
+    assert contract.forbid_pool_gather == tuple(eng.cache.k.shape)
+    jaxpr = ga.trace_entry(eng, entry)
+    assert ga.contract_problems(eng, contract, jaxpr) == []
+    text = str(jaxpr)
+    assert "ssd_decode_step" in text and "paged_decode_attention" in text
+    assert "gdn_decode_step" not in text
+
+
 def test_a_lost_state_donation_is_reported():
     txt = 'func @main(%a {tf.aliasing_output = 0 : i32}, %b {tf.aliasing_output = 1 : i32})'
     assert ga.donated_leaf_check("x", txt, 2) == []

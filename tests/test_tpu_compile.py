@@ -251,6 +251,103 @@ def _kimi_step(rows, t, pages=2560, kv_len=2048):
     return build
 
 
+def _granite_cfg():
+    """Granite-4.0-H-Micro as `perfbench/configs/granite-4.0-h-micro.json`
+    has it: nothing cut."""
+    from distributed_llama_tpu.models.config import ModelConfig
+
+    return ModelConfig(
+        arch_type=0xABCD05, dim=2048, hidden_dim=8192, n_layers=40, n_heads=32,
+        n_kv_heads=8, head_dim=64, vocab_size=100352, seq_len=2048, n_experts=0,
+        n_active_experts=0, hidden_act=1, rope_type=4, norm_epsilon=1e-5,
+        use_pallas=True, full_attn_interval=10, full_attn_offset=5, lin_kind="ssd",
+        lin_heads=64, lin_key_dim=128, lin_value_dim=64, lin_conv_kernel=4, lin_groups=1,
+        lin_conv_bias=True, embedding_mult=12.0, residual_mult=0.22, logits_scaling=8.0,
+        attn_scale=0.015625,
+    )
+
+
+def _granite_params(cfg, S):
+    """The parameter tree as `models/params._load_hybrid` builds it for the
+    state-space kind, described and not held (2.6 GB)."""
+    from distributed_llama_tpu.models.params import LayerParams, MambaParams, ModelParams
+    from distributed_llama_tpu.ops.quant import QuantTensor
+
+    def q40(*lead, out, inn):
+        return QuantTensor(q=S((*lead, inn // 8, out), jnp.int32),
+                           d=S((*lead, inn // 32, out), jnp.float16))
+
+    L, Lr, Lf, dim, f32 = cfg.n_layers, cfg.n_rec_layers, cfg.n_kv_layers, cfg.dim, jnp.float32
+    H, di, ch = cfg.lin_heads, cfg.lin_vdim, cfg.lin_conv_channels
+    ssm = MambaParams(
+        w_in=q40(Lr, out=di + ch, inn=dim), w_dt=S((Lr, H, dim), f32),
+        conv=S((Lr, cfg.lin_conv_kernel, ch), f32), conv_bias=S((Lr, ch), f32),
+        a_log=S((Lr, H), f32), dt_bias=S((Lr, H), f32), d=S((Lr, H), f32),
+        norm=S((Lr, di), f32), w_out=q40(Lr, out=dim, inn=di),
+    )
+    layers = LayerParams(
+        q=None, k=None, v=None, w1=None, w3=None,
+        wqkv=q40(Lf, out=cfg.q_dim + 2 * cfg.kv_dim, inn=dim), wo=q40(Lf, out=dim, inn=cfg.q_dim),
+        w13=q40(L, out=2 * cfg.hidden_dim, inn=dim), w2=q40(L, out=dim, inn=cfg.hidden_dim),
+        norm0=S((L, dim), f32), norm1=S((L, dim), f32), ssm=ssm,
+    )
+    return ModelParams(
+        embedding=S((cfg.vocab_size, dim), f32), layers=layers,
+        final_norm=S((dim,), f32), wcls=q40(out=cfg.vocab_size, inn=dim),
+    )
+
+
+def _granite_step(rows, t, slots=32, pages=4096, kv_len=2048):
+    """The served program's model step (`forward_uncompiled`) at
+    Granite-4.0-H-Micro's widths, all 40 layers: `rows` decoding rows of one
+    position (the cell's batch-decode step: 36 `ssd_decode_step` calls over
+    the 2.4 GB state where it lies, the page-table kernel over a pool that
+    stores head 64 as 128), or one prompt
+    chunk of `t` tokens against slot 3 of the batch's state; the pool, the
+    state and the conv tails donated."""
+    from distributed_llama_tpu.models.params import KVCache
+    from distributed_llama_tpu.models.transformer import forward_uncompiled
+    from distributed_llama_tpu.ops.rope import RopeTables
+
+    cfg = _granite_cfg()
+
+    def build(S):
+        def fn(params, rope, pool_k, pool_v, rec, conv, tokens, pos, table):
+            logits, cache = forward_uncompiled(
+                cfg, params, rope, KVCache(k=pool_k, v=pool_v, rec=rec, conv=conv), tokens, pos,
+                kv_len=kv_len, page_table=table, page_size=PAGE,
+                rec_row=None if t == 1 else jnp.int32(3),
+            )
+            return logits, cache.k, cache.v, cache.rec, cache.conv
+
+        rope = RopeTables(cos=S((2048, 32), jnp.float32), sin=S((2048, 32), jnp.float32))
+        pool = S((cfg.n_kv_layers, pages, PAGE, cfg.n_kv_heads, 128), jnp.bfloat16)  # 64 stored as 128
+        rec = S((cfg.n_rec_layers, slots, cfg.lin_key_dim, cfg.lin_vdim), jnp.float32)
+        conv = S((cfg.n_rec_layers, slots, 3, cfg.lin_conv_channels), jnp.bfloat16)
+        pos = S((rows,), jnp.int32) if t == 1 else S((), jnp.int32)
+        return fn, [_granite_params(cfg, S), rope, pool, pool, rec, conv,
+                    S((rows, t), jnp.int32), pos, S((rows, 128), jnp.int32)], (2, 3, 4, 5)
+
+    return build
+
+
+def _ssd(rows):
+    """The state-space decode step over every such layer's state
+    (ops/ssd.py): 2 MB a row a layer, updated in place."""
+    from distributed_llama_tpu.ops.ssd import ssd_decode_step
+
+    def build(S):
+        H, P, N = 64, 64, 128
+        f32 = jnp.float32
+        return ssd_decode_step, [
+            S((36, rows, N, H * P), f32), S((), jnp.int32), S((rows, H, P), f32),
+            S((rows, N), f32), S((rows, N), f32), S((rows, H), f32), S((H,), f32), S((H,), f32),
+            S((rows,), jnp.bool_),
+        ], (0,)
+
+    return build
+
+
 def _kimi_grouped(pairs, role):
     """The routed experts' grouped matmul told its live blocks, at the shapes
     `ops/moe.moe_ffn_held` gives it: `pairs` (token, expert) pairs bound the
@@ -274,6 +371,12 @@ def _kimi_grouped(pairs, role):
 
 
 CASES = {
+    # Granite-4.0-H-Micro (perfbench/configs/granite-4.0-h-micro.json): the
+    # cell's decode step at 32 rows and a prompt's chunk of 256, whole, and
+    # the state-space decode kernel alone at the rows the cell may keep
+    "granite-step-32rows": _granite_step(32, 1),
+    "granite-step-prompt256": _granite_step(1, 256),
+    **{f"ssd-decode-{rows}rows": _ssd(rows) for rows in (48, 32, 24)},
     # Kimi-K2.6 (perfbench/configs/kimi-k2.6.json): the cell's decode step at
     # 32 rows and a prompt's chunk of 256, whole, and the grouped expert kernel
     # alone at both (256 and 2048 pairs)
@@ -380,7 +483,15 @@ def test_kernel_compiles_for_v5e(v5e, case):
         # scores
         assert count_tpu_kernels(compiled) >= 3 + 5 + 4 + 1
         assert compiled.memory_analysis().temp_size_in_bytes < 5 << 28
-    if case.startswith("paged") or case.startswith("gdn"):
+    if case.startswith("granite-step"):
+        # 36 state-space layers in two inner scans' bodies and 4 full layers in
+        # the outer one: the kernels of three layer bodies (four matmuls a
+        # layer, attention's kernel in the full one) and the head; a decode
+        # step's state-space bodies hold `ssd_decode_step` too. No copy of the
+        # state (2.4 GB at 32 rows) or of the pool (1 GB) beside them
+        assert count_tpu_kernels(compiled) >= 2 * 4 + 5 + 1 + (2 if "rows" in case else 0)
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    if case.startswith("paged") or case.startswith("gdn") or case.startswith("ssd"):
         # the pool (the state) is read where it lies: a reshaped or
         # re-laid-out operand shows up as a copy of the whole of it (GBs) in
         # the program's temps
