@@ -28,9 +28,11 @@ from ..ops import gated_delta, gqa_attention
 from ..ops.attention import flash_attention_sp, gqa_attention_sp, scatter_cache_update_sp
 from ..ops.kv_quant import dequantize_kv, quantize_kv
 from ..ops.pallas_attention import (
+    PAGED_PREFETCH_WORDS,
     flash_attention,
     flash_attention_aligned,
     paged_decode_attention,
+    paged_prefetch_words,
 )
 from ..ops.pallas_gdn import gdn_decode_step, gdn_head_chunk
 from ..ops.quant import _use_pallas
@@ -51,7 +53,9 @@ class CacheAddr(NamedTuple):
     kv_len: int | None = None  # static: attention reads only the first
     # kv_len positions (a slice that fuses into the attention ops). The
     # engine picks the power-of-two bucket covering pos_start + t, so decode
-    # reads scale with the position, not the allocated cache. None = all.
+    # reads scale with the position, not the allocated cache (and seq_len
+    # itself where the read follows the live pages whatever the bound:
+    # `decode_reads_live_pages`). None = all.
     page_table: Any = None  # [b, max_slots] int32 traced array (paged
     # layout, runtime/paged_kv.py): writes scatter through the table and
     # reads gather the first kv_len/page_size pages per row. -1 entries are
@@ -142,6 +146,50 @@ def _fused_paged_eligible(cfg, heads_dim, n_kv: int, t: int, ps: int) -> bool:
         and n_heads % n_kv == 0
         and head_dim % 8 == 0
         and (cfg.pallas_interpret or (n_kv % 8 == 0 and head_dim % 128 == 0))
+    )
+
+
+def _pool_q_heads(n_q: int, n_kv: int, pool_kv: int) -> int:
+    """The query heads a pool of `pool_kv` kv heads is asked about by a model
+    of `n_q` over `n_kv`: a group of queries a stored head (`paged_arm` pads
+    with zero queries), or the model's own where the pool stores no more."""
+    return n_q // n_kv * pool_kv if pool_kv > n_kv else n_q
+
+
+def _paged_kernel_serves(cfg, pool_shape, n_q: int, n_kv: int, t: int) -> bool:
+    """`paged_arm`'s gate as the POOL's shape [L, P, ps, kv heads, head dim]
+    decides it: `_fused_paged_eligible` for the query the pool is asked."""
+    ps, pool_kv, pool_hd = pool_shape[2:]
+    return _fused_paged_eligible(
+        cfg, (_pool_q_heads(n_q, n_kv, pool_kv), pool_hd), pool_kv, t, ps
+    )
+
+
+def decode_reads_live_pages(cfg, cache, rows: int, max_slots: int | None, mesh) -> bool:
+    """Whether a decode step (t = 1) of `rows` rows reads NOTHING that grows
+    with its KV read bound, so that one program at the bound `seq_len` serves
+    every position (the engine then plans and dispatches a Batcher's
+    `batch_decode` at that bound alone: `InferenceEngine.decode_kv_bound`).
+
+    True where every attention layer of the step takes `paged_arm` and that
+    arm's own gate takes the page-table kernel for the pool's shape: the
+    kernel copies a row's live pages through the scalar-prefetched table, and
+    the bound sets the width of the table's slice and nothing else
+    (`_paged_block_pages` is the same from 256 positions up). False for an
+    int8 pool (its scale sidecars are gathered in HLO over the whole bound),
+    for the latent arm (a gather: its cost follows the bound), on a mesh, for
+    the contiguous layout (`max_slots` None: no page table), and where the
+    table of the deepest bound, [rows, max_slots], would not fit the kernel's
+    scalar memory. It reads shapes, the pool's dtype and the arm."""
+    if mesh is not None or max_slots is None or cache.quantized:
+        return False
+    # the arm of the step's attention layers, addressed as the model graph
+    # addresses it (`transformer._latent_layers` sets `latent`)
+    arm = select_arm(CacheAddr(page_table=max_slots, latent=cfg.is_latent))
+    return (
+        arm is paged_arm
+        and _paged_kernel_serves(cfg, cache.k.shape, cfg.n_heads, cfg.n_kv_heads, 1)
+        and paged_prefetch_words(rows, max_slots) <= PAGED_PREFETCH_WORDS
     )
 
 
@@ -264,7 +312,7 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
         scale = scale or hd**-0.5
     if pool_kv > n_kv:
         k, v = _pad_heads(k, pool_kv), _pad_heads(v, pool_kv)
-        q_pool = _pad_heads(q_pool, n_q // n_kv * pool_kv)
+        q_pool = _pad_heads(q_pool, _pool_q_heads(n_q, n_kv, pool_kv))
     max_slots = page_table.shape[1]
     phys, offset = _page_write_index(cfg, page_table, positions, ps, n_pool)
     cache = _write(
@@ -275,7 +323,7 @@ def paged_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     )
     # read: the first kv_len/ps page entries per row
     n_read = max_slots if addr.kv_len is None else min(-(-addr.kv_len // ps), max_slots)
-    if _fused_paged_eligible(cfg, q_pool.shape[2:], pool_kv, t, ps):
+    if _paged_kernel_serves(cfg, cache.k.shape, n_q, n_kv, t):
         # decode-sized: the page-table KERNEL reads the row's live pages of
         # the pool where they lie (scalar-prefetched table, one copy a page,
         # many pages a grid step) — no materialized page gather, no KV view

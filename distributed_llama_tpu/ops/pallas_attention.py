@@ -270,6 +270,14 @@ def flash_attention(
 
 PAGED_BLOCK_TOKENS = 256  # positions a block; probe_paged_attention.py's sweep
 PAGED_VMEM_BUDGET = 10 * 2**20  # of the 16 MiB a kernel may scope on a v5e
+PAGED_PREFETCH_WORDS = 192 * 2**10  # of the 256 Ki int32 words of a v5e's SMEM,
+# where the scalar-prefetch operand lies whole (tests/test_tpu_compile.py
+# compiles the widest table this admits)
+
+
+def paged_prefetch_words(b: int, n_read: int) -> int:
+    """int32 words of the kernel's scalar-prefetch operand (`meta` below)."""
+    return 2 + 3 * b + b * n_read
 
 
 def _paged_block_pages(

@@ -593,7 +593,7 @@ class BatchSession:
                 f"decode chunk would overrun seq_len={self.seq_len}: "
                 f"max row end {max(ends)} (step n_steps={n_steps})"
             )
-        kv_len = eng._kv_bucket(min(max(ends, default=1), self.seq_len))
+        kv_len = eng._batch_decode_bound(min(max(ends, default=1), self.seq_len))
         t_chunk = time.perf_counter()
         phases = self.phases
         if phases is not None:

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..formats.mfile import ArchType, MFileReader
-from ..models import KVCache, config_from_header, forward, init_kv_cache, load_params
+from ..models import KVCache, config_from_header, forward, init_kv_cache, kv_arms, load_params
 from ..ops import build_rope_tables
 from ..tokenizer import Sampler
 from .telemetry import StepStats, _tree_bytes, memory_report, watchdog
@@ -732,9 +732,12 @@ class InferenceEngine:
     def _kv_bucket(self, end_pos: int) -> int | None:
         """Static KV read bound: smallest power-of-two bucket covering
         `end_pos` (floored so tiny contexts don't multiply compiled
-        programs). Attention then reads cache[:, :bucket] instead of the
-        whole allocation — decode cost scales with position, not seq_len —
-        at the price of O(log seq_len) compiled step variants."""
+        programs). An arm that gathers or slices its read (a prompt's chunk,
+        the contiguous and latent arms, an int8 pool's scales) then reads
+        the bucket's positions instead of the whole allocation — its cost
+        scales with position, not seq_len — at the price of O(log seq_len)
+        compiled step variants. The page-table decode kernel reads a row's
+        live pages whatever the bound: `decode_kv_bound`."""
         floor = min(256, self.cfg.seq_len)
         b = floor
         while b < end_pos:
@@ -748,6 +751,28 @@ class InferenceEngine:
         while out[-1] < self.cfg.seq_len:
             out.append(min(out[-1] * 2, self.cfg.seq_len))
         return out
+
+    @property
+    def decode_kv_bound(self) -> str:
+        """How a Batcher's decode chunk takes its KV read bound:
+        "live_pages", one bound (`seq_len`) at every position, where the
+        step's attention reads a row's live pages and nothing that grows with
+        the bound (`kv_arms.decode_reads_live_pages`: the page-table kernel
+        over a float pool on one chip); else "ladder", `_kv_bucket`'s.
+        `/stats` `startup` says which."""
+        live = kv_arms.decode_reads_live_pages(
+            self.cfg, self.cache, self.batch,
+            self.page_pool.max_slots if self.paged else None, self.mesh,
+        )
+        return "live_pages" if live else "ladder"
+
+    def _batch_decode_bound(self, end_pos: int) -> int:
+        """THE KV read bound of a `batch_decode` program whose rows end by
+        `end_pos`: `warm_plan` plans it and `BatchSession.dispatch`
+        dispatches it from here, so a chunk's key is always a planned one."""
+        if self.decode_kv_bound == "live_pages":
+            return self.cfg.seq_len
+        return self._kv_bucket(end_pos)
 
     @staticmethod
     def _halving_sizes(top: int) -> list:
@@ -776,7 +801,9 @@ class InferenceEngine:
         crosses bucket boundaries, a prefix-cache resume that starts
         mid-ladder. A (size, kvb) pair is reachable iff size <= kvb (the
         bucket must cover the chunk's own end). Prefix-cache copy/extract
-        programs ride the same ladder at (bucket, bucket)."""
+        programs ride the same ladder at (bucket, bucket). `batch_decode`
+        alone leaves the cross product where its step reads live pages
+        only: one bound, `seq_len`, a size (`_batch_decode_bound`)."""
         plan = []
         kvbs = self._kv_buckets()
         prefill_sizes = _chunk_buckets(self.max_chunk)
@@ -784,6 +811,7 @@ class InferenceEngine:
         # of 8 is among a longer chunk's halves
         decode_sizes = self._halving_sizes(self.decode_chunk_size)
         batched = self.batch > 1 and self.device_decode
+        decode_bounds = {self._batch_decode_bound(kvb) for kvb in kvbs}
         for kvb in kvbs if self.warms_solo_programs else []:
             for s in prefill_sizes:
                 if s <= kvb:
@@ -797,7 +825,7 @@ class InferenceEngine:
                     if s <= kvb:
                         plan.append(("prefill_row", s, kvb))
                 for n in decode_sizes:
-                    if n <= kvb:
+                    if n <= kvb and kvb in decode_bounds:
                         plan.append(("batch_decode", n, kvb))
         if self.spec_mode is not None and self.device_decode:
             # speculative verify programs: one prefill-shaped logits-at-
@@ -1104,7 +1132,7 @@ class InferenceEngine:
             self._in_warmup = False
             phase.close()
         # the seal's moment: "warmed" and "dispatched since" part here
-        rec.seal(self.warm_plan())
+        rec.seal(self.warm_plan(), self.decode_kv_bound)
         if self.sentinel is not None:
             # the ladder is compiled: from here on, any XLA compile is a
             # ladder hole — counted (sanitizer_recompiles) and optionally

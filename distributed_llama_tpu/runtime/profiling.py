@@ -556,7 +556,11 @@ def _paged_kernel_census(eqn, in_hbm):
     kernel copies a row's live pages and no other, so the census prices what
     it BOUNDS: every block of every row whole, K and V (an int8 pool's f32
     scale pages are gathered in HLO beside the call and priced there like
-    any gather). Returns ``(bytes, blocks)`` — the blocks a call may walk,
+    any gather). Where a Batcher's chunk is planned at the one bound
+    `seq_len` (`InferenceEngine.decode_kv_bound` "live_pages") that bound is
+    the WHOLE context: the entry is an upper bound a row, what a row at
+    `seq_len` - 1 would read, not what the traffic's rows read (`/debug/costs`
+    shows it; `roofline_view` joins it, see there). Returns ``(bytes, blocks)`` — the blocks a call may walk,
     each running the body's dots once — or None (any other pallas_call keeps
     the generic sub-jaxpr handling). Without this the program's KV reads
     would census as ZERO bytes — the roofline would flatter itself by
@@ -936,7 +940,12 @@ def roofline_view(engine, table: CostTable):
     """(gauges, labeled_series) joining the cost table with the recorded
     per-program walls. Per-series numbers use the recent-window p50 wall
     (warmup's compile walls age out) and the shallowest-kv cost variant
-    (a conservative floor)."""
+    (a conservative floor). Where `batch_decode` is planned at the one bound
+    `seq_len` (`InferenceEngine.decode_kv_bound` "live_pages") its only
+    variant prices the page-table kernel at the whole context a row
+    (`_paged_kernel_census`): `program_gb_s{batch_decode[n]}`,
+    `bw_utilization` and `mfu` are then a CEILING, high by the pages the rows
+    do not hold (PERF.md section 7)."""
     gauges: dict = {}
     series: dict = {}
     prog_gbs: list = []

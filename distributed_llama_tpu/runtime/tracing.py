@@ -687,11 +687,13 @@ class StartupRecord:
 
     # -- the seal's summary ---------------------------------------------------
 
-    def seal(self, plan: list) -> None:
+    def seal(self, plan: list, decode_kv_bound: str = "ladder") -> None:
         """The moment warm-up ends: snapshot the counts and build `/stats`
-        `startup` once (it is polled inside a benchmark's window)."""
+        `startup` once (it is polled inside a benchmark's window).
+        `decode_kv_bound`: how the engine planned its Batcher's decode
+        chunks (`InferenceEngine.decode_kv_bound`)."""
         self.sealed_at = dict(self.dispatches)
-        self.summary = startup_summary(self, plan)
+        self.summary = startup_summary(self, plan, decode_kv_bound)
 
     def stats(self) -> dict | None:
         """`/stats` `startup`: the seal's summary with the since-seal counts
@@ -761,14 +763,15 @@ def _stage_table(spans: list, name: str, stages: tuple) -> dict:
     return out
 
 
-def startup_summary(record: StartupRecord, plan: list) -> dict:
+def startup_summary(record: StartupRecord, plan: list, decode_kv_bound: str) -> dict:
     """`/stats` `startup`: aggregates only. The phases, the stage sums of
     `startup.build` and `startup.warm` (a warm span's `rest_s` is its wall
     less JAX's three stages: dispatch, and what the call waited for of the
     device), what compiled inside a phase and outside every program span,
     cache hits and misses by program span, the five longest program spans,
     and by `kind` the programs planned, first dispatched in warm-up,
-    dispatched since the seal, and those dispatches."""
+    dispatched since the seal, and those dispatches; beside them which way
+    the engine planned `batch_decode`'s KV read bound."""
     spans = list(record.spans)
     build = _stage_table(spans, "startup.build", ("census_us", "lower_us", "compile_us"))
     warm = _stage_table(spans, "startup.warm", ("trace_us", "lower_us", "compile_us"))
@@ -800,6 +803,7 @@ def startup_summary(record: StartupRecord, plan: list) -> dict:
         "warm": warm,
         "outside": outside,
         "by_kind": by_kind,
+        "decode_kv_bound": decode_kv_bound,
         "programs_planned": len(planned),
         "programs_warmed": len(record.first_in_warmup),
         "never_warmed": never[:8],

@@ -5,16 +5,19 @@ attached (the on-chip-measurement guide, section 2, third rehearsal).
     JAX_PLATFORMS=cpu python scripts/compile_rehearsal.py --model m.m \\
         --tokenizer t.t --max-seq-len 4096 --batch 4 [--kv-dtype int8] [--tp 4]
 
-The engine is built here on the CPU from the server's own arguments
-(`server.api.parse_args` -> `cli.make_engine`), so the programs are the ones
-`serve()` would warm; its arrays are then re-described as living on the
+The engine is built here on the CPU from the server's own arguments, as the
+server builds it (`server.api.parse_args` -> `make_served_engine`: a batched
+server's plan holds its Batcher's programs only), so the programs are the
+ones `serve()` would warm; its arrays are then re-described as living on the
 devices of `--topology` (default v5e:2x2) and the warm-plan entries chosen
 with `--kinds`/`--sizes` go through `profiling.lower_entry(...).compile()` —
 the TPU's compiler raises here what it would raise on the chip. The engine
 asks `jax.default_backend()` whether to use its kernels and sees the CPU, so
 the script turns them on itself (`use_pallas=True`); nothing else is steered.
 
-What it prints per program: the lowering's and the compile's microseconds
+It first prints the plan's programs by kind and how `batch_decode` takes its
+KV read bound (`--kinds none` stops there: the plan of a configuration's
+server arguments, nothing compiled). What it prints per program: the lowering's and the compile's microseconds
 apart (`lower_us`, `compile_us`: the names of the start-up record's
 `startup.build` span), Mosaic kernels (`tpu_custom_call`), collectives by name, and `memory_analysis()` bytes on one
 device. A compile that passes is a compile, never a run: nothing executes.
@@ -22,6 +25,7 @@ A depth-cut model file is enough — the layer scan compiles one layer body.
 """
 
 import argparse
+import collections
 import os
 import re
 import sys
@@ -57,11 +61,10 @@ def main() -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
 
-    from distributed_llama_tpu.cli import make_engine
     from distributed_llama_tpu.runtime import profiling
-    from distributed_llama_tpu.server.api import parse_args
+    from distributed_llama_tpu.server.api import make_served_engine, parse_args
 
-    engine = make_engine(parse_args(server_argv))
+    engine = make_served_engine(parse_args(server_argv))
     engine.cfg = engine.cfg.with_(use_pallas=True)
 
     devices = topologies.get_topology_desc(
@@ -103,6 +106,8 @@ def main() -> int:
         f"layers={engine.cfg.n_layers} kv={engine.cfg.cache_dtype} "
         f"batch={engine.batch} mesh={dict(engine.mesh.shape) if engine.mesh else None}"
     )
+    by_kind = dict(collections.Counter(kind for kind, _, _ in plan))
+    print(f"plan by kind: {by_kind} decode_kv_bound={engine.decode_kv_bound}")
     failed = 0
     for key in chosen:
         # the two stages apart, under the names the start-up record's

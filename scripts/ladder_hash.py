@@ -1,30 +1,74 @@
-"""sha256 of graph_diff.fingerprint_ladder for the four older architectures (run in both trees): a ladder's, then one a program kind."""
-import hashlib, json, os, sys, tempfile
+"""sha256 of graph_diff.fingerprint_ladder for every architecture the tree
+has (run it in two trees to prove a change moved no program, or only those it
+meant to): a hash a ladder, one a program kind and one a KEY.
+
+    python scripts/ladder_hash.py <checkout> [--seq-len 1024] [--out keys.txt]
+    python scripts/ladder_hash.py <checkout> --seq-len 1024 --against keys.txt
+
+`--seq-len` stretches the tiny models' context so that a ladder has several
+KV buckets (128, the default, has one). `--against` compares key by key with
+another tree's `--out` file: every key THIS tree plans must hash as it does
+there; keys only the other tree plans are counted by kind (exit code 1 on a
+key that differs or is new)."""
+import argparse, collections, dataclasses, hashlib, json, os, sys, tempfile
+
+ap = argparse.ArgumentParser()
+ap.add_argument("tree")
+ap.add_argument("--seq-len", type=int, default=128)
+ap.add_argument("--out")
+ap.add_argument("--against")
+args = ap.parse_args()
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["DLT_PALLAS_INTERPRET"] = "1"
-sys.path.insert(0, sys.argv[1])
-from distributed_llama_tpu.analysis import graph_diff as gd
-from distributed_llama_tpu.analysis.graph_audit import tiny_hybrid_header
+sys.path.insert(0, args.tree)
+from distributed_llama_tpu import testing
+from distributed_llama_tpu.analysis import graph_audit, graph_diff as gd
 from distributed_llama_tpu.formats.mfile import ArchType, RopeType
 from distributed_llama_tpu.runtime.engine import InferenceEngine
 from distributed_llama_tpu.testing import tiny_header, write_tiny_model
+
+
+def sha(doc) -> str:
+    return hashlib.sha256(json.dumps(doc, sort_keys=True).encode()).hexdigest()[:16]
+
+
 d = tempfile.mkdtemp()
 heads = {
     "llama": tiny_header(seq_len=128),
     "qwen3": tiny_header(arch=ArchType.QWEN3, rope_type=RopeType.FALCON, seq_len=128, dim=256, hidden_dim=512, n_heads=8, n_kv_heads=4, head_dim=32),
     "qwen3_moe": tiny_header(arch=ArchType.QWEN3_MOE, rope_type=RopeType.FALCON, seq_len=128, dim=256, hidden_dim=512, moe_hidden_dim=256, n_experts=8, n_active_experts=2, n_heads=8, n_kv_heads=4, head_dim=32),
-    "olmo_hybrid": tiny_hybrid_header(),
+    "olmo_hybrid": graph_audit.tiny_hybrid_header(),
 }
+# the newer families, where the tree has them (a parent may not)
+if hasattr(graph_audit, "tiny_ssm_hybrid_header"):
+    heads["granite_hybrid"] = graph_audit.tiny_ssm_hybrid_header()
+if hasattr(testing, "tiny_latent_header"):
+    heads["kimi_k2"] = testing.tiny_latent_header()
+SOLO_OFF = ("olmo_hybrid", "granite_hybrid", "kimi_k2")
+keys = {}
 for name, h in heads.items():
     path = f"{d}/{name}.m"
-    write_tiny_model(path, h, seed=0)
+    write_tiny_model(path, dataclasses.replace(h, seq_len=args.seq_len, orig_seq_len=args.seq_len), seed=0)
     for dtype in ("float32", "bfloat16"):
-        kw = dict(speculative="off") if name == "olmo_hybrid" else {}
+        kw = dict(speculative="off") if name in SOLO_OFF else {}
+        if name == "kimi_k2":
+            kw["prefix_cache_mb"] = 0
         eng = InferenceEngine(path, compute_dtype=dtype, batch=2, max_chunk=16, decode_chunk_size=8, kv_layout="paged", **kw)
-        prints = gd.fingerprint_ladder(eng)
-        doc = json.dumps({k: fp.to_dict() for k, fp in sorted(prints.items())}, sort_keys=True)
-        print(name, dtype, len(prints), hashlib.sha256(doc.encode()).hexdigest()[:16], flush=True)
+        prints = {k: fp.to_dict() for k, fp in sorted(gd.fingerprint_ladder(eng).items())}
+        print(name, dtype, len(prints), sha(prints), flush=True)
         for kind in sorted({k.split("[")[0] for k in prints}):
-            part = json.dumps({k: fp.to_dict() for k, fp in sorted(prints.items()) if k.split("[")[0] == kind}, sort_keys=True)
-            print(" ", name, dtype, kind, sum(k.split("[")[0] == kind for k in prints), hashlib.sha256(part.encode()).hexdigest()[:16], flush=True)
+            part = {k: fp for k, fp in prints.items() if k.split("[")[0] == kind}
+            print(" ", name, dtype, kind, len(part), sha(part), flush=True)
+        keys.update({f"{name} {dtype} {k}": sha(fp) for k, fp in prints.items()})
         eng.close()
+if args.out:
+    with open(args.out, "w") as f:
+        f.writelines(f"{k}\t{v}\n" for k, v in keys.items())
+if args.against:
+    with open(args.against) as f:
+        other = dict(line.rstrip("\n").split("\t") for line in f)
+    bad = sorted(k for k, v in keys.items() if other.get(k) != v)
+    gone = collections.Counter(k.split(" ")[2].split("[")[0] for k in other if k not in keys)
+    print(f"{len(keys)} keys here, {len(keys) - len(bad)} hash as in {args.against}; "
+          f"{len(bad)} differ or are new: {bad[:8]}; only there, by kind: {dict(gone)}")
+    sys.exit(1 if bad else 0)

@@ -262,3 +262,88 @@ def test_int8_cache_is_refused_off_the_stacked_and_paged_arms(addr):
     x = jnp.zeros((B, 1, N_KV, HD), jnp.float32)
     with pytest.raises(NotImplementedError, match="int8 KV"):
         select_arm(addr)(cfg, cache, addr, x, x, x, jnp.zeros((B, 1), jnp.int32), jnp.int32(0))
+
+
+def _pool(n_kv=N_KV, hd=HD, dtype=jnp.float32, ps=PS):
+    """A pool of the given trailing shape, described and not held."""
+    import jax
+
+    k = jax.ShapeDtypeStruct((L, N_PAGES, ps, n_kv, hd), dtype)
+    if dtype != jnp.int8:
+        return KVCache(k=k, v=k)
+    s = jax.ShapeDtypeStruct((L, N_PAGES, ps, n_kv), jnp.float32)
+    return KVCache(k=k, v=k, k_scale=s, v_scale=s)
+
+
+@pytest.mark.parametrize(
+    "cfg_kw,pool_kw,rows,max_slots,mesh,live",
+    [
+        # the page-table kernel over a float pool on one chip: interpreted on
+        # any shape, compiled where the POOL's trailing axes fill whole tiles
+        (dict(pallas_interpret=True), {}, B, S // PS, None, True),
+        (dict(pallas_interpret=True), dict(dtype=jnp.bfloat16), B, S // PS, None, True),
+        (dict(use_pallas=True), dict(n_kv=8, hd=128, dtype=jnp.bfloat16), 16, 256, None, True),
+        # ... the two hybrids' padded pools included: 30 heads stored as 32,
+        # head 64 stored as 128 (the model's own counts are the config's)
+        (dict(use_pallas=True, n_heads=30, n_kv_heads=30, head_dim=128),
+         dict(n_kv=32, hd=128, dtype=jnp.bfloat16), 24, 128, None, True),
+        (dict(use_pallas=True, n_heads=32, n_kv_heads=8, head_dim=64),
+         dict(n_kv=8, hd=128, dtype=jnp.bfloat16), 32, 128, None, True),
+        # every other read grows with the bound, or is not this arm's
+        (dict(use_pallas=True), {}, B, S // PS, None, False),  # ragged tiles: the gather arm
+        (dict(use_pallas=False), dict(n_kv=8, hd=128), 16, 256, None, False),  # no Pallas
+        (dict(pallas_interpret=True), dict(dtype=jnp.int8), B, S // PS, None, False),
+        (dict(pallas_interpret=True), {}, B, S // PS, "mesh", False),
+        (dict(pallas_interpret=True), {}, B, None, None, False),  # contiguous: no table
+        (dict(pallas_interpret=True), {}, 96, 2048, None, False),  # the table overruns SMEM
+        (dict(pallas_interpret=True), {}, 95, 2048, None, True),
+    ],
+    ids=["interpret-f32", "interpret-bf16", "compiled-8x128", "compiled-30-as-32",
+         "compiled-64-as-128", "compiled-ragged", "no-pallas", "int8", "mesh", "contiguous",
+         "table-over-budget", "table-at-budget"],
+)
+def test_decode_reads_live_pages_where_the_page_table_kernel_serves(
+    cfg_kw, pool_kw, rows, max_slots, mesh, live
+):
+    from distributed_llama_tpu.models.kv_arms import decode_reads_live_pages
+
+    cfg = _cfg(pool_kw.get("dtype") == jnp.int8).with_(**cfg_kw)
+    assert decode_reads_live_pages(cfg, _pool(**pool_kw), rows, max_slots, mesh) is live
+
+
+def test_decode_never_reads_live_pages_through_the_latent_arm():
+    from distributed_llama_tpu.models.kv_arms import decode_reads_live_pages
+    from distributed_llama_tpu.testing import tiny_latent_header
+
+    cfg = config_from_header(tiny_latent_header(), "float32").with_(pallas_interpret=True)
+    assert cfg.is_latent
+    assert not decode_reads_live_pages(cfg, _pool(), B, S // PS, None)
+
+
+@pytest.mark.parametrize("kv_len", [S, None], ids=["seq_len", "unbounded"])
+def test_the_page_table_kernel_reads_the_same_pages_at_any_bound(kv_len):
+    """What `decode_reads_live_pages` rests on: the kernel's answer at the
+    bound `seq_len` is its answer at the bucket that covers the rows (a wider
+    slice of the table, the same live pages), and the arm's gate does not
+    read the bound."""
+    import jax
+
+    rng = np.random.default_rng(43)
+    cfg = _cfg(False).with_(pallas_interpret=True)
+    pos = np.array([5, 0, S], np.int32)
+    q, k, v = (
+        jnp.asarray(rng.standard_normal((B, 1, n, HD), dtype=np.float32))
+        for n in (N_HEADS, N_KV, N_KV)
+    )
+    pool = jnp.asarray(rng.standard_normal((L, N_PAGES, PS, N_KV, HD), dtype=np.float32))
+    cache = KVCache(k=pool, v=pool + 1.0)
+
+    def run(bound):
+        addr = CacheAddr(layer=LAYER, kv_len=bound, page_table=jnp.asarray(_page_table()),
+                         page_size=PS)
+        call = lambda: paged_arm(cfg, cache, addr, q, k, v, jnp.asarray(pos[:, None]),
+                                 jnp.asarray(pos))
+        assert "pallas_call" in str(jax.make_jaxpr(call)())
+        return np.asarray(call()[0])
+
+    np.testing.assert_allclose(run(kv_len)[:2], run(KV_LEN)[:2], rtol=1e-6, atol=1e-6)

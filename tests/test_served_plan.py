@@ -23,10 +23,14 @@ SOLO = ("prefill", "decode")
 
 def _headers():
     from distributed_llama_tpu.analysis.graph_audit import tiny_hybrid_header
+    from distributed_llama_tpu.testing import tiny_latent_header
 
     small = dict(dim=64, hidden_dim=128, n_layers=1, seq_len=64, vocab_size=288)
     return {
         "dense": tiny_header(arch=ArchType.LLAMA, **small),
+        # contexts of several KV buckets: 256, 512, 1024, 2048
+        "long": tiny_header(arch=ArchType.LLAMA, **{**small, "seq_len": 2048}),
+        "latent": tiny_latent_header(seq_len=1024, vocab_size=288),
         "moe": tiny_header(
             arch=ArchType.QWEN3_MOE, n_experts=4, n_active_experts=2, moe_hidden_dim=64, **small
         ),
@@ -209,3 +213,122 @@ def test_the_engines_own_solo_callers_stay_silent(files):
             assert eng.notices == []
         finally:
             eng.close()
+
+
+# -- one `batch_decode` bound where the decode step reads live pages only -----
+
+_LONG = ("--batch", "4", "--speculative", "off", "--prefix-cache-mb", "0")
+
+
+def _ladder(plan, kind="batch_decode"):
+    return [(n, kvb) for k, n, kvb in plan if k == kind]
+
+
+def test_a_paged_float_engine_under_the_kernel_plans_batch_decode_at_seq_len_alone(
+    files, monkeypatch
+):
+    """Where the page-table kernel serves the decode step (interpret mode
+    here), a Batcher's chunk is planned at ONE bound, `seq_len`, a size; the
+    plan is the ladder's own minus `batch_decode`'s lower buckets, in its
+    order, and no key is new."""
+    from distributed_llama_tpu.models import kv_arms
+
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    eng = api.make_served_engine(_args(files, *_LONG, model="long"))
+    try:
+        plan = eng.warm_plan()
+        assert eng.decode_kv_bound == "live_pages"
+        assert _ladder(plan) == [(n, 2048) for n in (1, 2, 4, 8, 16)]
+        assert sorted({kvb for _, kvb in _ladder(plan, "prefill_row")}) == [256, 512, 1024, 2048]
+        assert eng._batch_decode_bound(17) == eng._batch_decode_bound(2048) == 2048
+        monkeypatch.setattr(kv_arms, "decode_reads_live_pages", lambda *a: False)
+        parent = eng.warm_plan()
+        assert eng.decode_kv_bound == "ladder" and eng._batch_decode_bound(17) == 256
+        assert len(_ladder(parent)) == 20
+        assert plan == [k for k in parent if k[0] != "batch_decode" or k[2] == 2048]
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("model,extra,buckets,interpret", [
+    ("long", (*_LONG, "--kv-dtype", "int8"), (256, 512, 1024, 2048), True),
+    ("latent", ("--batch", "4", "--speculative", "off"), (256, 512, 1024), True),
+    ("long", (*_LONG, "--tp", "2"), (256, 512, 1024, 2048), True),
+    ("long", (*_LONG, "--kv-layout", "contiguous"), (256, 512, 1024, 2048), True),
+    ("long", _LONG, (256, 512, 1024, 2048), False),
+], ids=["int8-pool", "kimi_k2", "mesh", "contiguous", "no-pallas"])
+def test_every_other_engine_keeps_the_ladder_key_for_key(
+    files, monkeypatch, model, extra, buckets, interpret
+):
+    """An int8 pool (its scales are gathered over the bound), the latent arm
+    (a gather), a mesh, the contiguous layout and the no-Pallas path plan the
+    whole cross product: what the predicate's absence plans, key for key."""
+    from distributed_llama_tpu.models import kv_arms
+
+    if interpret:
+        monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # the latent model's prefix-cache notice
+        eng = api.make_served_engine(_args(files, *extra, model=model))
+    try:
+        plan = eng.warm_plan()
+        sizes = sorted({n for n, _ in _ladder(plan)})
+        assert eng.decode_kv_bound == "ladder"
+        assert _ladder(plan) == [(n, kvb) for kvb in buckets for n in sizes]
+        assert eng._batch_decode_bound(300) == eng._kv_bucket(300) == 512
+        monkeypatch.setattr(kv_arms, "decode_reads_live_pages", lambda *a: False)
+        assert eng.warm_plan() == plan
+    finally:
+        eng.close()
+
+
+def test_rows_that_cross_three_buckets_dispatch_planned_keys_and_the_ladders_tokens(
+    files, monkeypatch
+):
+    """A `BatchSession` whose rows cross 256, 512 and 1,024 positions: every
+    chunk is dispatched under a planned key (nothing compiles after the seal)
+    and the tokens are the ladder's."""
+    from distributed_llama_tpu.models import kv_arms
+    from distributed_llama_tpu.runtime.batch_session import BatchSession
+
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("DLT_SANITIZERS", "1")
+    prompts = {0: [5 + i % 200 for i in range(230)], 1: [7 + i % 190 for i in range(490)],
+               2: [9 + i % 180 for i in range(1000)]}
+
+    def drive(eng, seen):
+        """Admit the rows one after another, two chunks after each: the
+        longest row ends at 246, 262, 506, 522, 1016, 1032."""
+        guard = eng._guard
+        monkeypatch.setattr(
+            eng, "_guard", lambda label, key: (seen.append(key), guard(label, key))[1]
+        )
+        session = BatchSession(eng)
+        out = []
+        for row, prompt in prompts.items():
+            session.admit(row, prompt)
+            out += [session.step(16)[: row + 1].tolist() for _ in range(2)]
+        return out
+
+    args = _args(files, *_LONG, "--max-batch-size", "16", model="long")
+    eng = api.make_served_engine(args)
+    try:
+        eng.warmup()
+        seen = []
+        live = drive(eng, seen)
+        assert eng.startup.stats()["decode_kv_bound"] == "live_pages"
+        assert eng.startup.stats()["by_kind"]["batch_decode"]["planned"] == 5
+        assert [k for k in seen if k[0] == "batch_decode"] == [("batch_decode", 16, 2048)] * 6
+        assert set(seen) <= set(eng.warm_plan())
+        assert "sanitizer_recompiles" not in eng.stats.counters_snapshot()
+    finally:
+        eng.close()
+    monkeypatch.setattr(kv_arms, "decode_reads_live_pages", lambda *a: False)
+    monkeypatch.delenv("DLT_SANITIZERS")
+    ladder_eng = api.make_served_engine(args)
+    try:
+        seen = []
+        assert drive(ladder_eng, seen) == live
+        assert [k[2] for k in seen if k[0] == "batch_decode"] == [256, 512, 512, 1024, 1024, 2048]
+    finally:
+        ladder_eng.close()

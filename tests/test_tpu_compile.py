@@ -90,9 +90,10 @@ def _flash(t, cache_len):
     return build
 
 
-def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS):
+def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS, slots=256):
     """The page-table decode kernel over a pool of the served shape: int8
-    with its f32 scale sidecars, or bf16 (the cells' cache)."""
+    with its f32 scale sidecars, or bf16 (the cells' cache); `slots`: a row's
+    page-table entries (`seq_len` / 16)."""
 
     def build(S):
         pool = S((layers, 2048, PAGE, KV_HEADS, HEAD_DIM), dtype)
@@ -107,10 +108,16 @@ def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS):
 
         return fn, [
             S((b, t, heads, HEAD_DIM), jnp.bfloat16), pool, pool, *scales,
-            S((), jnp.int32), S((b,), jnp.int32), S((b, 256), jnp.int32),
+            S((), jnp.int32), S((b,), jnp.int32), S((b, slots), jnp.int32),
         ]
 
     return build
+
+
+# the widest page table `kv_arms.decode_reads_live_pages` admits at a context
+# of 32k (2,048 entries a row): 95 rows, 768 KiB of the v5e's 1 MiB of SMEM
+WIDEST_SLOTS = 2048
+WIDEST_ROWS = (pa.PAGED_PREFETCH_WORDS - 2) // (WIDEST_SLOTS + 3)
 
 
 def _gdn(rows):
@@ -462,6 +469,16 @@ CASES = {
             ("8b", 16, 9, 64, HEADS, LAYERS),
         )
     },
+    # a Batcher's decode chunk at its ONE bound, `seq_len` (PR 43: n_read =
+    # seq_len / 16 whatever the rows' positions): Qwen3-8B's 16 rows of 256
+    # entries here; Qwen3-14B's 8 rows of 128 above, Olmo-Hybrid's 24 rows
+    # over 32 stored heads (`paged-arm-30heads-b24-read128`) and Granite's 32
+    # rows with head 64 stored as 128 (`granite-step-32rows`, kv_len 2048)
+    # already at theirs; and the widest table the predicate admits
+    "paged-bf16-8b-b16-t1-read256": _paged(16, 1, 256, dtype=jnp.bfloat16),
+    f"paged-bf16-8b-b{WIDEST_ROWS}-t1-read{WIDEST_SLOTS}": _paged(
+        WIDEST_ROWS, 1, WIDEST_SLOTS, dtype=jnp.bfloat16, slots=WIDEST_SLOTS
+    ),
 }
 
 
@@ -497,6 +514,14 @@ def test_kernel_compiles_for_v5e(v5e, case):
         # the program's temps
         big = max(args, key=lambda a: a.size * a.dtype.itemsize)
         assert compiled.memory_analysis().temp_size_in_bytes < big.size * big.dtype.itemsize // 64
+
+
+def test_the_widest_table_is_the_predicates_edge():
+    """The case above IS the budget's edge: one row more and the engine
+    keeps the ladder (`kv_arms.decode_reads_live_pages`)."""
+    assert WIDEST_ROWS == 95
+    assert pa.paged_prefetch_words(WIDEST_ROWS, WIDEST_SLOTS) <= pa.PAGED_PREFETCH_WORDS
+    assert pa.paged_prefetch_words(WIDEST_ROWS + 1, WIDEST_SLOTS) > pa.PAGED_PREFETCH_WORDS
 
 
 @pytest.mark.parametrize("rows", (8, 16))
