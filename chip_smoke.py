@@ -40,13 +40,16 @@ of width 2048 beside a shared one, an eighth of the vocabulary), at 2 rows:
 1. numbers: the held-experts layer through the grouped kernel told its live
    blocks against its float32 `ragged_dot` form on the same inputs, at 16, 256
    and 2048 pairs (rows past the live blocks must never reach the result);
+   the latent arm's page-table kernel against its gathered view on the same
+   pool (16 rows at positions of their own over a scattered table; PR 44);
    latent attention's absorbed form through the paged latent arm against the
    same engine's float32 XLA path, teacher-forced logits, prefill then decode
    (the float32 path is held to the plain reference on the CPU,
    `tests/z_perfbench/test_kimi_k2_program.py`);
 2. the server with the configuration's own arguments at batch 2
    (`--speculative off`): the same requests, 0 recompiles, the notices, the
-   `/stats` `moe` block and `kv_pool.bytes_per_token`.
+   `/stats` `moe` block and `kv_pool.bytes_per_token`; the decode step holds
+   the page-table kernel and `decode_kv_bound` reads `live_pages`.
 
 `--arch granite_hybrid` (one chip, run by the builder): Granite-4.0-H-Micro's
 widths as `perfbench/configs/granite-4.0-h-micro.json` has them (hidden 2048,
@@ -118,6 +121,12 @@ KIMI_K26 = dict(
 # whole latent model, bf16 kernels against float32 XLA (the dense models'
 # bounds above): top-1 0.969 / 0.969, max diff 0.068 / 0.124 std
 MAX_EXPERT_DIFF_STD = 0.08
+# the latent arm's kernel against its gathered view, as a share of the largest
+# output: the same bfloat16 products summed in float32 in another order and
+# rounded to bfloat16 on both sides, so one bfloat16 step apart at most (2^-7
+# of the value; the chip's probe read 0.004-0.016 on outputs of 1-4); two
+# steps of room, where a wrong page or mask reads O(1)
+MAX_LATENT_READ_DIFF = 2**-6
 # perfbench/configs/granite-4.0-h-micro.json (ibm-granite/granite-4.0-h-micro
 # config.json), the program's header names; depth is the caller's
 GRANITE_4HM = dict(
@@ -686,6 +695,37 @@ def phase_latent_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
             fail(f"numbers/held experts at {tokens} tokens: {diff} stds, {stats} vs {stats32}")
     free(eng)
 
+    # (a2) the latent arm's two reads of one pool: the page-table kernel
+    # (a decode step: rows at positions of their own) against the gathered
+    # view, bfloat16 pages at the model's page width, a scattered table
+    from distributed_llama_tpu.models import kv_arms
+    from distributed_llama_tpu.models.params import KVCache
+
+    ps, width, rows = 16, cfg.latent_page_width, 2 if rehearse else 16
+    slots, pages = (8, 64) if rehearse else (128, 2560)
+    own = np.random.default_rng(44)  # (b) below keeps the draws it had before this check
+    bf = lambda *sh: jnp.asarray(own.standard_normal(sh, dtype=np.float32)).astype(jnp.bfloat16)  # noqa: E731
+    pool, q, k = bf(2, pages, ps, width), bf(rows, 1, cfg.n_heads, width), bf(rows, 1, 1, width)
+    table = jnp.asarray(own.permutation(pages)[: rows * slots].reshape(rows, slots).astype(np.int32))
+    pos = jnp.asarray(np.linspace(5, slots * ps - 2, rows).astype(np.int32))
+    arm = jax.jit(
+        lambda cfg, pool, q, k, pos, table: kv_arms.latent_arm(
+            cfg, KVCache(k=pool, v=None),
+            kv_arms.CacheAddr(layer=jnp.int32(1), kv_len=slots * ps, page_table=table,
+                              page_size=ps, latent=True),
+            q, k, None, pos[:, None], pos)[0][..., : cfg.kv_lora_rank].astype(jnp.float32),
+        static_argnums=0,
+    )
+    serves = kv_arms._latent_kernel_serves(cfg, pool, rows, slots, 1)
+    fast = arm(cfg, pool, q, k, pos, table)
+    slow = arm(cfg.with_(use_pallas=False, pallas_interpret=False), pool, q, k, pos, table)
+    diff = float(jnp.max(jnp.abs(fast - slow)) / jnp.max(jnp.abs(slow)))
+    say("numbers", check="latent arm: page-table kernel vs gathered view, one pool",
+        rows=rows, table=[rows, slots], kernel_serves=bool(serves),
+        finite=bool(jnp.isfinite(fast).all()), max_diff_of_largest=round(diff, 5), bound=MAX_LATENT_READ_DIFF)
+    if not (serves and diff <= MAX_LATENT_READ_DIFF):
+        fail(f"numbers/latent arm: kernel serves {serves}, max diff {diff}")
+
     # (b) the whole step, latent arm and all: bf16 kernels vs float32 XLA
     n_pre, n_dec = (16, 4) if rehearse else (64, 32)
     ids = [int(x) for x in rng.integers(1, cfg.vocab_size, n_pre + n_dec)]
@@ -787,8 +827,12 @@ def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
     # a step's kernels: q_a|kv_a, q_b, wo a layer kind apart, the dense w13 and
     # w2, the shared expert's two, the three grouped expert calls, the head
     need = 3 + 3 + 2 + 2 + 3 + 1
+    # a decode step reads the latent pool through the page-table kernel: a
+    # call in the dense layer and one in the expert layers' scan body (PR 44)
     httpd, engine, stats = serve_two_rows(
-        model, tokenizer, rehearse, {"batch_decode": need, "prefill_row": need}, "moe")
+        model, tokenizer, rehearse, {"batch_decode": need + 2, "prefill_row": need}, "moe")
+    if engine.decode_kv_bound != "live_pages":
+        fail(f"decode_kv_bound: {engine.decode_kv_bound}")
     cfg, moe, pool = engine.cfg, stats.get("moe") or {}, stats.get("kv_pool") or {}
     if (moe.get("held"), moe.get("experts")) != (cfg.n_experts_held, cfg.n_experts) or not moe.get("expert_pairs"):
         fail(f"/stats moe: {moe}")

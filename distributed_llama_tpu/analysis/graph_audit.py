@@ -593,7 +593,7 @@ def contract_for(engine, entry: LadderEntry) -> ProgramContract:
     if (
         row["fused_decode"]
         and getattr(engine, "paged", False)
-        and _fused_kernel_active(engine)
+        and _fused_kernel_active(engine, entry.kind)
     ):
         pool = tuple(engine.cache.k.shape)
     return ProgramContract(
@@ -604,15 +604,20 @@ def contract_for(engine, entry: LadderEntry) -> ProgramContract:
     )
 
 
-def _fused_kernel_active(engine) -> bool:
+def _fused_kernel_active(engine, kind: str) -> bool:
     """True when the paged decode programs trace the page-table Pallas
-    kernel (models/kv_arms.py _fused_paged_eligible at decode's t=1), read
-    off what the gate reads: the config and the pool's own shape."""
-    from ..models.kv_arms import _fused_paged_eligible
+    kernel (models/kv_arms.py _fused_paged_eligible at decode's t=1, or the
+    latent arm's `_latent_kernel_serves`, which wants the per-row positions
+    of a `batch_decode` step), read off what the gate reads: the config and
+    the pool's own shape."""
+    from ..models.kv_arms import _fused_paged_eligible, _latent_kernel_serves
 
     cfg = engine.cfg
-    if cfg.is_latent:  # the latent arm reads through a gather (ROADMAP R5)
-        return False
+    if cfg.is_latent:
+        return _latent_kernel_serves(
+            cfg, engine.cache.k, engine.batch, engine.page_pool.max_slots, 1,
+            per_row=kind == "batch_decode",
+        )
     tp = engine.mesh.shape["tp"] if engine.mesh is not None else 1
     # the pool's own kv heads and head width (it may store more than the
     # model has: paged_kv.pool_kv_heads, pool_head_dim), a tp shard's share

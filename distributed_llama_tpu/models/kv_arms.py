@@ -28,6 +28,7 @@ from ..ops import gated_delta, gqa_attention
 from ..ops.attention import flash_attention_sp, gqa_attention_sp, scatter_cache_update_sp
 from ..ops.kv_quant import dequantize_kv, quantize_kv
 from ..ops.pallas_attention import (
+    LATENT_BLOCK_TOKENS,
     PAGED_PREFETCH_WORDS,
     flash_attention,
     flash_attention_aligned,
@@ -165,27 +166,55 @@ def _paged_kernel_serves(cfg, pool_shape, n_q: int, n_kv: int, t: int) -> bool:
     )
 
 
+def _latent_kernel_serves(
+    cfg, pool, rows: int, n_read: int, t: int, per_row: bool = True
+) -> bool:
+    """`latent_arm`'s gate, as the POOL [L, P, ps, W] decides it: Pallas
+    enabled, a decode step (`per_row`: every row at a position of its own,
+    and a page of queries at most; a prompt's chunk, one row from one scalar
+    start however short, keeps the gathered view: a row's bucket is 2.6 MB
+    here, and its 36 programs would each lower the kernel), a float pool, and
+    — where the kernel is compiled, not interpreted — a page [ps, W] of whole
+    tiles of the pool's dtype (W in whole lanes, ps in whole sublane tiles: 8
+    rows of 4 bytes, 16 of 2), the order a page copy needs, and a table
+    [rows, n_read] within the kernel's scalar memory."""
+    ps, width = pool.shape[2:]
+    return (
+        _pallas_enabled(cfg)
+        and per_row
+        and t <= ps
+        and jnp.issubdtype(pool.dtype, jnp.floating)
+        and paged_prefetch_words(rows, n_read) <= PAGED_PREFETCH_WORDS
+        and (
+            cfg.pallas_interpret
+            or (width % 128 == 0 and ps % (32 // pool.dtype.itemsize) == 0)
+        )
+    )
+
+
 def decode_reads_live_pages(cfg, cache, rows: int, max_slots: int | None, mesh) -> bool:
     """Whether a decode step (t = 1) of `rows` rows reads NOTHING that grows
     with its KV read bound, so that one program at the bound `seq_len` serves
     every position (the engine then plans and dispatches a Batcher's
     `batch_decode` at that bound alone: `InferenceEngine.decode_kv_bound`).
 
-    True where every attention layer of the step takes `paged_arm` and that
-    arm's own gate takes the page-table kernel for the pool's shape: the
-    kernel copies a row's live pages through the scalar-prefetched table, and
-    the bound sets the width of the table's slice and nothing else
-    (`_paged_block_pages` is the same from 256 positions up). False for an
-    int8 pool (its scale sidecars are gathered in HLO over the whole bound),
-    for the latent arm (a gather: its cost follows the bound), on a mesh, for
-    the contiguous layout (`max_slots` None: no page table), and where the
-    table of the deepest bound, [rows, max_slots], would not fit the kernel's
-    scalar memory. It reads shapes, the pool's dtype and the arm."""
+    True where every attention layer of the step takes `paged_arm` or
+    `latent_arm` and that arm's own gate takes the page-table kernel for the
+    pool's shape: the kernel copies a row's live pages through the
+    scalar-prefetched table, and the bound sets the width of the table's
+    slice and nothing else (`_paged_block_pages` is the same from a block's
+    positions up). False for an int8 pool (its scale sidecars are gathered in
+    HLO over the whole bound), on a mesh, for the contiguous layout
+    (`max_slots` None: no page table), and where the table of the deepest
+    bound, [rows, max_slots], would not fit the kernel's scalar memory. It
+    reads shapes, the pool's dtype and the arm."""
     if mesh is not None or max_slots is None or cache.quantized:
         return False
     # the arm of the step's attention layers, addressed as the model graph
     # addresses it (`transformer._latent_layers` sets `latent`)
     arm = select_arm(CacheAddr(page_table=max_slots, latent=cfg.is_latent))
+    if arm is latent_arm:
+        return _latent_kernel_serves(cfg, cache.k, rows, max_slots, 1)
     return (
         arm is paged_arm
         and _paged_kernel_serves(cfg, cache.k.shape, cfg.n_heads, cfg.n_kv_heads, 1)
@@ -369,16 +398,20 @@ def latent_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     caller hands the ABSORBED query (q [b, t, H, W]: q_nope through W_uk, then
     q_rope, then zeros) and this token's vector as k [b, t, 1, W]; what comes
     back is [b, t, H, W], whose first `kv_lora_rank` are the probabilities'
-    sum over the latents (the caller expands it through W_uv). Writes and
-    reads go through the page table exactly as `paged_arm`'s; the read is the
-    gather arm in `jax.numpy`, prefill and decode alike. Float pools only."""
+    sum over the latents (the caller expands it through W_uv; where the
+    kernel serves, the columns past `kv_lora_rank` are zeros). Writes and
+    reads go through the page table exactly as `paged_arm`'s: a decode-sized
+    read is the page-table kernel over the row's live pages where
+    `_latent_kernel_serves` (ops/pallas_attention.paged_decode_attention told
+    a 4-D pool and no V), a prompt's chunk and every other case the gathered
+    view in `jax.numpy`. Float pools only."""
     if addr.page_table is None:
         raise NotImplementedError(
             "latent attention keeps its cache in the paged pool only"
         )
     _float_only(cache, "latent")
     li, ps, page_table = addr.layer, addr.page_size, addr.page_table
-    b = q.shape[0]
+    b, t = q.shape[:2]
     max_slots = page_table.shape[1]
     phys, offset = _page_write_index(cfg, page_table, positions, ps, cache.k.shape[1])
     cache = replace(
@@ -388,6 +421,14 @@ def latent_arm(cfg, cache, addr, q, k, v, positions, pos_start):
         ),
     )
     n_read = max_slots if addr.kv_len is None else min(-(-addr.kv_len // ps), max_slots)
+    if _latent_kernel_serves(cfg, cache.k, b, n_read, t, jnp.ndim(pos_start) == 1):
+        a = paged_decode_attention(
+            q, cache.k, None, None, None, jnp.asarray(li, jnp.int32),
+            positions[:, 0], page_table, n_read=n_read, page_size=ps,
+            scale=cfg.attn_scale, block_tokens=LATENT_BLOCK_TOKENS,
+            interpret=cfg.pallas_interpret, v_width=cfg.kv_lora_rank,
+        )
+        return a, cache
     pages = jnp.maximum(jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0)
     view = cache.k[li, pages].reshape(b, n_read * ps, 1, cache.k.shape[-1])
     return gqa_attention(q, view, view, positions, scale=cfg.attn_scale), cache

@@ -267,8 +267,16 @@ def flash_attention(
 # * the scale sidecars [L, P, ps, n_kv] f32 are stored with the page axis
 #   minor-most, so a kernel operand would also be a whole-array copy per
 #   call. Their pages are gathered in HLO instead (1/32 of the payload).
+#
+# A LATENT pool (latent attention, `kv_arms.latent_arm`) is the same walk with
+# other parameters, read off the operands: the pool is 4-D [L, P, ps, W], one
+# [latent | key] vector a token, and there is no V pool. A page is one [ps, W]
+# block, so one stream of copies into one pair of buffers; the values are the
+# buffer's own first `v_width` columns; there is one "kv head", so every query
+# row of a batch row meets every column and no column is masked for its head.
 
 PAGED_BLOCK_TOKENS = 256  # positions a block; probe_paged_attention.py's sweep
+LATENT_BLOCK_TOKENS = 512  # the same for a latent page (its `--latent` sweep)
 PAGED_VMEM_BUDGET = 10 * 2**20  # of the 16 MiB a kernel may scope on a v5e
 PAGED_PREFETCH_WORDS = 192 * 2**10  # of the 256 Ki int32 words of a v5e's SMEM,
 # where the scalar-prefetch operand lies whole (tests/test_tpu_compile.py
@@ -281,17 +289,19 @@ def paged_prefetch_words(b: int, n_read: int) -> int:
 
 
 def _paged_block_pages(
-    block_tokens: int, n_read: int, ps: int, n_kv: int, hd: int, rows: int, itemsize: int
+    block_tokens: int, n_read: int, ps: int, n_kv: int, hd: int, rows: int,
+    itemsize: int, bufs: int = 4,
 ) -> int:
     """Pages a block: `block_tokens` positions, halved while the two K and
-    two V buffers and the [rows, block*n_kv] f32 score-sized values (six live
-    at once: scores, probabilities and their three bf16 terms) overrun the
-    budget — a verify block's rows are t times a decode step's."""
+    two V buffers (`bufs`: a latent pool has the two K buffers only) and the
+    [rows, block*n_kv] f32 score-sized values (six live at once: scores,
+    probabilities and their three bf16 terms) overrun the budget — a verify
+    block's rows are t times a decode step's."""
     ppb = max(1, min(block_tokens // ps, n_read))
 
     def need(ppb):
         block = ppb * ps
-        return 4 * block * n_kv * hd * itemsize + 6 * rows * block * n_kv * 4
+        return bufs * block * n_kv * hd * itemsize + 6 * rows * block * n_kv * 4
 
     while ppb > 1 and need(ppb) > PAGED_VMEM_BUDGET:
         ppb //= 2
@@ -299,19 +309,25 @@ def _paged_block_pages(
 
 
 def _paged_decode_kernel(
-    m_ref, q_ref, k_hbm, v_hbm, *rest,
-    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt,
+    m_ref, q_ref, *rest,
+    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt, v_width=None,
 ):
     """One batch row's attention over its live pages (see the notes above).
     m_ref (scalar prefetch) carries [layer, first live row, pos_base[b],
     live pages[b], next live row[b], page_table[b*n_read]]; pos_base is each
     row's FIRST query position (batch decode's unequal rows share the
     program). Stale buffer tails and clamped-page garbage are masked for
-    live rows; a row with no live page writes zeros (discarded host-side)."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, cnt_ref = rest
+    live rows; a row with no live page writes zeros (discarded host-side).
+    `v_width` (static): None for K and V pools; for a latent pool, whose refs
+    have no V, the leading columns of a page that are its values."""
+    latent = v_width is not None
+    if latent:
+        k_hbm, o_ref, kbuf, sem, cnt_ref = rest
+        v_hbm = vbuf = None
+    elif quantized:
+        k_hbm, v_hbm, ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, cnt_ref = rest
     else:
-        o_ref, kbuf, vbuf, sem, cnt_ref = rest
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cnt_ref = rest
     bi = pl.program_id(0)
     layer = m_ref[0]
     block = ppb * ps
@@ -329,8 +345,9 @@ def _paged_decode_kernel(
             dst = pl.ds(p * ps, ps)
             do(pltpu.make_async_copy(
                 k_hbm.at[layer, page], kbuf.at[slot, dst], sem.at[0, slot]))
-            do(pltpu.make_async_copy(
-                v_hbm.at[layer, page], vbuf.at[slot, dst], sem.at[1, slot]))
+            if not latent:
+                do(pltpu.make_async_copy(
+                    v_hbm.at[layer, page], vbuf.at[slot, dst], sem.at[1, slot]))
             return 0
 
         jax.lax.fori_loop(0, n, one, 0)
@@ -342,7 +359,8 @@ def _paged_decode_kernel(
     def _():
         # a masked column's probability is 0, and 0 x a NaN left in VMEM is
         # not: V's buffers start finite (a stale tail then holds pool values)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        values = kbuf if latent else vbuf
+        values[...] = jnp.zeros_like(values)
         cnt_ref[0] = 0
 
         @pl.when(m_ref[1] < b)
@@ -362,14 +380,18 @@ def _paged_decode_kernel(
     q = q_ref[0].astype(cdt)  # [rows_p, hd], rows ordered (kv head, token, g)
     r_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_p, 1), 0)
     row_pos = pos_base + jax.lax.div(jax.lax.rem(r_iota, i32(t * g)), i32(g))  # [rows_p, 1]
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_p, cols), 1)
-    r_head = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows_p, cols), 0), i32(t * g))
-    # columns are (token, kv head): the other heads' columns are masked for good
-    masked = jnp.full((rows_p, cols), NEG_INF, jnp.float32)
-    head_bias = jax.lax.select(
-        jax.lax.rem(c_iota, i32(n_kv)) == r_head, jnp.zeros_like(masked), masked
-    )
-    col_tok = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), i32(n_kv))
+    if latent:  # one vector a token: a column is a token, every query row's
+        masked = jnp.full((rows_p, cols), NEG_INF, jnp.float32)
+        col_tok = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    else:
+        c_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_p, cols), 1)
+        r_head = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows_p, cols), 0), i32(t * g))
+        # columns are (token, kv head): the other heads' columns are masked for good
+        masked = jnp.full((rows_p, cols), NEG_INF, jnp.float32)
+        head_bias = jax.lax.select(
+            jax.lax.rem(c_iota, i32(n_kv)) == r_head, jnp.zeros_like(masked), masked
+        )
+        col_tok = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), i32(n_kv))
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
@@ -385,7 +407,7 @@ def _paged_decode_kernel(
             start(ahead, jax.lax.select(more, i + 1, i32(0)), 1 - slot)
 
         wait(bi, i, slot)
-        k = kbuf[slot].reshape(cols, hd).astype(cdt)
+        k = (kbuf[slot] if latent else kbuf[slot].reshape(cols, hd)).astype(cdt)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
             precision=None if cdt == jnp.bfloat16 else jax.lax.Precision.HIGHEST,
@@ -395,7 +417,7 @@ def _paged_decode_kernel(
         else:
             s = s * scale
         visible = col_tok + i * block <= row_pos
-        s = jax.lax.select(visible, s + head_bias, masked)
+        s = jax.lax.select(visible, s if latent else s + head_bias, masked)
 
         m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
         # clamp so a fully-masked ROW (padding, a dead tail) stays finite;
@@ -406,7 +428,7 @@ def _paged_decode_kernel(
         l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         if quantized:
             p = p * vs_ref[0, pl.ds(i, 1), :]
-        v = vbuf[slot].reshape(cols, hd)
+        v = kbuf[slot, :, :v_width] if latent else vbuf[slot].reshape(cols, hd)
         if cdt == jnp.bfloat16:
             # f32 p x bf16 v, exactly: p = hi + mid + lo in bf16, one dot
             hi = p.astype(jnp.bfloat16)
@@ -428,19 +450,26 @@ def _paged_decode_kernel(
 
     m0 = jnp.full((rows_p, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((rows_p, 1), jnp.float32)
-    acc0 = jnp.zeros((rows_p, hd), jnp.float32)
+    acc0 = jnp.zeros((rows_p, v_width if latent else hd), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    if latent and v_width < hd:
+        # the key's columns hold no value: zeros, as a sum over them is not asked
+        o_ref[0, :, :v_width] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[0, :, v_width:] = jnp.zeros((rows_p, hd - v_width), o_ref.dtype)
+    else:
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @partial(
     jax.jit,
-    static_argnames=("n_read", "page_size", "scale", "block_tokens", "interpret"),
+    static_argnames=(
+        "n_read", "page_size", "scale", "block_tokens", "interpret", "v_width"
+    ),
 )
 def paged_decode_attention(
     q: jnp.ndarray,  # [b, t, n_heads, head_dim]
     k_pool: jnp.ndarray,  # [L, n_pages, ps, n_kv, head_dim] float or int8
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray | None,  # None: a latent pool [L, n_pages, ps, W]
     k_scale: jnp.ndarray | None,  # [L, n_pages, ps, n_kv] f32 (int8 pools)
     v_scale: jnp.ndarray | None,
     layer_idx: jnp.ndarray,  # traced scalar int32 — one program for all layers
@@ -451,6 +480,7 @@ def paged_decode_attention(
     scale: float | None = None,
     block_tokens: int = PAGED_BLOCK_TOKENS,
     interpret: bool = False,
+    v_width: int | None = None,  # a latent page's value columns (None: all)
 ) -> jnp.ndarray:
     """Page-table GQA decode attention over the pool, float or int8.
 
@@ -459,9 +489,15 @@ def paged_decode_attention(
     no materialized page gather, no KV view in HBM; per-row positions make
     solo decode, batch decode and the speculative verify block one kernel
     shape family. A row at or past position n_read*ps (parked) reads nothing.
-    Returns [b, t, h, hd] in q.dtype."""
+    Returns [b, t, h, hd] in q.dtype.
+
+    A 4-D float `k_pool` with no `v_pool` is a LATENT pool: one vector of W a
+    token, which every head of q [b, t, h, W] attends over and whose first
+    `v_width` columns are the values. The result's columns from `v_width` on
+    are zeros."""
     b, t, n_heads, hd = q.shape
-    n_kv = k_pool.shape[3]
+    latent = k_pool.ndim == 4
+    n_kv = 1 if latent else k_pool.shape[3]
     ps = page_size
     g = n_heads // n_kv
     rows = n_heads * t  # decode-sized q: every query row of a batch row at once
@@ -477,7 +513,8 @@ def paged_decode_attention(
         else jnp.float32
     )
     ppb = _paged_block_pages(
-        block_tokens, n_read, ps, n_kv, hd, rows_p, k_pool.dtype.itemsize
+        block_tokens, n_read, ps, n_kv, hd, rows_p, k_pool.dtype.itemsize,
+        bufs=2 if latent else 4,
     )
     n_blocks = -(-n_read // ppb)
     cols = ppb * ps * n_kv
@@ -515,8 +552,21 @@ def paged_decode_attention(
 
     q_spec = pl.BlockSpec((1, rows_p, hd), lambda bi, m: (bi, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [q_spec, pool_spec, pool_spec]
-    operands = [meta, q3, k_pool, v_pool]
+    if latent:
+        in_specs = [q_spec, pool_spec]
+        operands = [meta, q3, k_pool]
+        buffers = [pltpu.VMEM((2, ppb * ps, hd), k_pool.dtype)]
+        sems = pltpu.SemaphoreType.DMA((1, 2))
+        # values narrower than the page only in whole lane tiles
+        v_width = v_width if v_width and v_width % 128 == 0 else hd
+    else:
+        in_specs = [q_spec, pool_spec, pool_spec]
+        operands = [meta, q3, k_pool, v_pool]
+        buffers = [
+            pltpu.VMEM((2, ppb * ps, n_kv, hd), k_pool.dtype),
+            pltpu.VMEM((2, ppb * ps, n_kv, hd), v_pool.dtype),
+        ]
+        sems = pltpu.SemaphoreType.DMA((2, 2))
     if quantized:
         # head-minor like the buffer's rows: [b, block, (token, kv head)]
         def cols_of(sc):
@@ -534,9 +584,8 @@ def paged_decode_attention(
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, ppb * ps, n_kv, hd), k_pool.dtype),
-            pltpu.VMEM((2, ppb * ps, n_kv, hd), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            *buffers,
+            sems,
             pltpu.SMEM((1,), jnp.int32),  # blocks walked so far
         ],
     )
@@ -544,6 +593,7 @@ def paged_decode_attention(
         partial(
             _paged_decode_kernel, scale=scale, g=g, t=t, ps=ps, ppb=ppb,
             n_read=n_read, n_kv=n_kv, b=b, quantized=quantized, cdt=cdt,
+            v_width=v_width if latent else None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows_p, hd), q.dtype),

@@ -758,7 +758,8 @@ class InferenceEngine:
         "live_pages", one bound (`seq_len`) at every position, where the
         step's attention reads a row's live pages and nothing that grows with
         the bound (`kv_arms.decode_reads_live_pages`: the page-table kernel
-        over a float pool on one chip); else "ladder", `_kv_bucket`'s.
+        over a float pool, k/v heads or latent, on one chip); else "ladder",
+        `_kv_bucket`'s.
         `/stats` `startup` says which."""
         live = kv_arms.decode_reads_live_pages(
             self.cfg, self.cache, self.batch,

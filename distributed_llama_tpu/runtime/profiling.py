@@ -64,6 +64,7 @@ from __future__ import annotations
 
 import dataclasses
 import glob
+import math
 import os
 import re
 import tempfile
@@ -554,9 +555,10 @@ def _paged_kernel_census(eqn, in_hbm):
     pallas_call whose HBM reads happen *inside* the kernel (the HLO page
     gather it removed) — and price them at STORED width, float or int8. The
     kernel copies a row's live pages and no other, so the census prices what
-    it BOUNDS: every block of every row whole, K and V (an int8 pool's f32
-    scale pages are gathered in HLO beside the call and priced there like
-    any gather). Where a Batcher's chunk is planned at the one bound
+    it BOUNDS: every block of every row whole, K and V (a latent pool's one
+    vector a token once: its values are the page's own columns; an int8
+    pool's f32 scale pages are gathered in HLO beside the call and priced
+    there like any gather). Where a Batcher's chunk is planned at the one bound
     `seq_len` (`InferenceEngine.decode_kv_bound` "live_pages") that bound is
     the WHOLE context: the entry is an upper bound a row, what a row at
     `seq_len` - 1 would read, not what the traffic's rows read (`/debug/costs`
@@ -565,21 +567,26 @@ def _paged_kernel_census(eqn, in_hbm):
     the generic sub-jaxpr handling). Without this the program's KV reads
     would census as ZERO bytes — the roofline would flatter itself by
     exactly the traffic the kernel moves."""
-    # operands: meta, q [b, rows, hd], K pool, V pool[, scales]; the kernel's
-    # K buffer [2, block, n_kv, hd] is its first scratch operand
-    if eqn.params.get("name") != "paged_decode_attention" or not all(in_hbm[2:4]):
+    # operands: meta, q [b, rows, hd], K pool, V pool[, scales] — or, for a
+    # latent pool [L, P, ps, W], the one pool (K only: its values are the
+    # page's own columns); the kernel's K buffer [2, block, n_kv, hd]
+    # ([2, block, W]) is its first scratch operand
+    if eqn.params.get("name") != "paged_decode_attention":
         return None
     meta, q, pool = (v.aval for v in eqn.invars[:3])
-    b, (_, _, ps, n_kv, hd) = q.shape[0], pool.shape
+    pools = 1 if pool.ndim == 4 else 2
+    if not all(in_hbm[2 : 2 + pools]):
+        return None
+    b, ps, token = q.shape[0], pool.shape[2], tuple(pool.shape[3:])
     block = next(
         v.aval.shape[1]
         for v in eqn.params["jaxpr"].invars
-        if tuple(v.aval.shape[2:]) == (n_kv, hd) and v.aval.shape[0] == 2
+        if tuple(v.aval.shape[2:]) == token and v.aval.shape[0] == 2
     )
     # meta = [layer, first live row, pos_base[b], live[b], next[b], table[b*n_read]]
     n_read = (int(meta.size) - 2 - 3 * b) // b
     blocks = b * -(-n_read * ps // block)
-    return 2 * blocks * block * n_kv * hd * pool.dtype.itemsize, blocks
+    return pools * blocks * block * math.prod(token) * pool.dtype.itemsize, blocks
 
 
 def _census_walk(jaxpr, mult: float, hbm: dict, acc: dict) -> None:

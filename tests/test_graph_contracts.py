@@ -501,16 +501,46 @@ def test_latent_programs_meet_their_contracts(latent_engine):
     assert ga.f32_dot_budget(eng, ga.LadderEntry("batch_decode", 8, 128)) == 3
 
 
-def test_latent_batch_decode_reads_the_page_through_the_gather_arm(latent_engine):
-    """The latent arm is the gather arm in `jax.numpy` whatever Pallas says
-    (no pool-gather ban in its contract), and with Pallas on the step holds
-    the grouped kernel's live-block form."""
+def test_latent_batch_decode_reads_the_page_through_the_kernel_where_pallas_serves(latent_engine):
+    """With Pallas the latent `batch_decode` entry holds the page-table call
+    over the 4-D pool and no pool gather (its contract pins that), beside the
+    grouped kernel's live-block form; without Pallas the gather, and no pin."""
     eng = latent_engine
     entry = ga.LadderEntry("batch_decode", 8, 128)
-    assert ga.contract_for(eng, entry).forbid_pool_gather is None
-    text = str(ga.trace_entry(eng, entry))
-    assert "paged_decode_attention" not in text
+    contract = ga.contract_for(eng, entry)
+    jaxpr = ga.trace_entry(eng, entry)
+    text = str(jaxpr)
     assert ("q40_matmul_pallas_grouped" in text) == eng.cfg.pallas_interpret
+    if not eng.cfg.pallas_interpret:
+        assert contract.forbid_pool_gather is None  # the gather arm, off the TPU
+        assert "paged_decode_attention" not in text
+        assert jt.pool_gather_count(jaxpr, tuple(eng.cache.k.shape)) >= 1
+        return
+    assert eng.cache.k.ndim == 4 and contract.forbid_pool_gather == tuple(eng.cache.k.shape)
+    assert ga.contract_problems(eng, contract, jaxpr) == []
+    assert "paged_decode_attention" in text
+    # a prompt's chunk keeps the gathered view, and no pin
+    chunk = ga.LadderEntry("prefill_row", 8, 128)
+    assert ga.contract_for(eng, chunk).forbid_pool_gather is None
+    assert "paged_decode_attention" not in str(ga.trace_entry(eng, chunk))
+
+
+def test_latent_warm_plan_holds_one_batch_decode_bound_where_the_kernel_serves(latent_engine):
+    """`decode_kv_bound` is `live_pages` for a latent engine whose decode
+    step takes the kernel, and its plan holds `batch_decode` at `seq_len`
+    alone, a chunk size; without Pallas the ladder, a bucket a size."""
+    eng = latent_engine
+    decode = [(n, kvb) for kind, n, kvb in eng.warm_plan() if kind == "batch_decode"]
+    sizes = sorted({n for n, _ in decode})
+    if eng.cfg.pallas_interpret:
+        assert eng.decode_kv_bound == "live_pages"
+        assert sorted(decode) == [(n, eng.cfg.seq_len) for n in sizes]
+    else:
+        assert eng.decode_kv_bound == "ladder"
+        assert sorted(decode) == sorted((n, kvb) for kvb in eng._kv_buckets() for n in sizes if n <= kvb)
+    # a prompt's programs keep their ladder either way
+    rows = {kvb for kind, _, kvb in eng.warm_plan() if kind == "prefill_row"}
+    assert rows == set(eng._kv_buckets())
 
 
 # -- the state-space hybrid's programs (runs of layers in inner scans) ----------

@@ -311,13 +311,33 @@ def test_decode_reads_live_pages_where_the_page_table_kernel_serves(
     assert decode_reads_live_pages(cfg, _pool(**pool_kw), rows, max_slots, mesh) is live
 
 
-def test_decode_never_reads_live_pages_through_the_latent_arm():
+@pytest.mark.parametrize(
+    "cfg_kw,pool,rows,max_slots,mesh,live",
+    [
+        # the latent arm's own gate (PR 44): the page-table kernel over the
+        # 4-D pool, interpreted on any shape, compiled on whole tiles
+        (dict(pallas_interpret=True), (PS, 384, jnp.float32), B, S // PS, None, True),
+        (dict(use_pallas=True), (16, 640, jnp.bfloat16), 16, 128, None, True),
+        (dict(use_pallas=True), (16, 576, jnp.bfloat16), 16, 128, None, False),  # off the lanes
+        (dict(use_pallas=False), (16, 640, jnp.bfloat16), 16, 128, None, False),  # the gather
+        (dict(pallas_interpret=True), (PS, 384, jnp.float32), B, S // PS, "mesh", False),
+        (dict(pallas_interpret=True), (PS, 384, jnp.float32), 96, 2048, None, False),
+    ],
+    ids=["interpret", "compiled-16x640", "compiled-576", "no-pallas", "mesh", "table-over-budget"],
+)
+def test_decode_reads_live_pages_through_the_latent_arm_where_its_kernel_serves(
+    cfg_kw, pool, rows, max_slots, mesh, live
+):
+    import jax
+
     from distributed_llama_tpu.models.kv_arms import decode_reads_live_pages
     from distributed_llama_tpu.testing import tiny_latent_header
 
-    cfg = config_from_header(tiny_latent_header(), "float32").with_(pallas_interpret=True)
+    cfg = config_from_header(tiny_latent_header(), "float32").with_(**cfg_kw)
     assert cfg.is_latent
-    assert not decode_reads_live_pages(cfg, _pool(), B, S // PS, None)
+    ps, width, dtype = pool
+    cache = KVCache(k=jax.ShapeDtypeStruct((L, N_PAGES, ps, width), dtype), v=None)
+    assert decode_reads_live_pages(cfg, cache, rows, max_slots, mesh) is live
 
 
 @pytest.mark.parametrize("kv_len", [S, None], ids=["seq_len", "unbounded"])

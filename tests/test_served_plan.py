@@ -252,17 +252,18 @@ def test_a_paged_float_engine_under_the_kernel_plans_batch_decode_at_seq_len_alo
 
 @pytest.mark.parametrize("model,extra,buckets,interpret", [
     ("long", (*_LONG, "--kv-dtype", "int8"), (256, 512, 1024, 2048), True),
-    ("latent", ("--batch", "4", "--speculative", "off"), (256, 512, 1024), True),
+    ("latent", ("--batch", "4", "--speculative", "off"), (256, 512, 1024), False),
     ("long", (*_LONG, "--tp", "2"), (256, 512, 1024, 2048), True),
     ("long", (*_LONG, "--kv-layout", "contiguous"), (256, 512, 1024, 2048), True),
     ("long", _LONG, (256, 512, 1024, 2048), False),
-], ids=["int8-pool", "kimi_k2", "mesh", "contiguous", "no-pallas"])
+], ids=["int8-pool", "kimi_k2-no-pallas", "mesh", "contiguous", "no-pallas"])
 def test_every_other_engine_keeps_the_ladder_key_for_key(
     files, monkeypatch, model, extra, buckets, interpret
 ):
-    """An int8 pool (its scales are gathered over the bound), the latent arm
-    (a gather), a mesh, the contiguous layout and the no-Pallas path plan the
-    whole cross product: what the predicate's absence plans, key for key."""
+    """An int8 pool (its scales are gathered over the bound), a mesh, the
+    contiguous layout and the no-Pallas path (the gathered view: k/v heads
+    and the latent page alike) plan the whole cross product: what the
+    predicate's absence plans, key for key."""
     from distributed_llama_tpu.models import kv_arms
 
     if interpret:
@@ -278,6 +279,31 @@ def test_every_other_engine_keeps_the_ladder_key_for_key(
         assert eng._batch_decode_bound(300) == eng._kv_bucket(300) == 512
         monkeypatch.setattr(kv_arms, "decode_reads_live_pages", lambda *a: False)
         assert eng.warm_plan() == plan
+    finally:
+        eng.close()
+
+
+def test_a_latent_engine_under_the_kernel_plans_batch_decode_at_seq_len_alone(files, monkeypatch):
+    """The latent arm's decode step reads live pages through the page-table
+    kernel (PR 44; interpret mode here): one `batch_decode` bound a size, the
+    prompts' ladder as it was, and no key the ladder did not hold."""
+    from distributed_llama_tpu.models import kv_arms
+
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # the latent model's prefix-cache notice
+        eng = api.make_served_engine(_args(files, "--batch", "4", "--speculative", "off", model="latent"))
+    try:
+        plan = eng.warm_plan()
+        sizes = sorted({n for n, _ in _ladder(plan)})
+        assert eng.decode_kv_bound == "live_pages"
+        assert _ladder(plan) == [(n, 1024) for n in sizes]
+        assert sorted({kvb for _, kvb in _ladder(plan, "prefill_row")}) == [256, 512, 1024]
+        assert eng._batch_decode_bound(17) == 1024
+        monkeypatch.setattr(kv_arms, "decode_reads_live_pages", lambda *a: False)
+        parent = eng.warm_plan()
+        assert eng.decode_kv_bound == "ladder" and len(_ladder(parent)) == 3 * len(sizes)
+        assert plan == [k for k in parent if k[0] != "batch_decode" or k[2] == 1024]
     finally:
         eng.close()
 

@@ -114,6 +114,26 @@ def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS, slots=256):
     return build
 
 
+def _latent_paged(b, t, n_read, slots=128):
+    """The page-table kernel over Kimi-K2.6's latent pool as the cell holds it
+    (8 layers, 2,560 pages of 16 x 640 bfloat16, 64 heads over one vector a
+    token whose first 512 columns are the values): K only."""
+
+    def build(S):
+        def fn(q, pool, *rest):
+            return pa.paged_decode_attention(
+                q, pool, None, None, None, *rest, n_read=n_read, page_size=PAGE,
+                scale=0.1352, v_width=512, block_tokens=pa.LATENT_BLOCK_TOKENS,
+            )
+
+        return fn, [
+            S((b, t, 64, 640), jnp.bfloat16), S((8, 2560, PAGE, 640), jnp.bfloat16),
+            S((), jnp.int32), S((b,), jnp.int32), S((b, slots), jnp.int32),
+        ]
+
+    return build
+
+
 # the widest page table `kv_arms.decode_reads_live_pages` admits at a context
 # of 32k (2,048 entries a row): 95 rows, 768 KiB of the v5e's 1 MiB of SMEM
 WIDEST_SLOTS = 2048
@@ -389,6 +409,15 @@ CASES = {
     # alone at both (256 and 2048 pairs)
     "kimi-step-32rows": _kimi_step(32, 1),
     "kimi-step-prompt256": _kimi_step(1, 256),
+    # the page-table kernel over the latent pool (PR 44) alone: the cell's 16
+    # rows at its one bound (128 entries a row), a block of 4 queries a row,
+    # and the widest table the gate admits (95 rows x 2,048 entries: the SMEM
+    # bound's edge, the same words as the k/v kernel's)
+    "latent-paged-b16-t1-read128": _latent_paged(16, 1, 128),
+    "latent-paged-b16-t4-read128": _latent_paged(16, 4, 128),
+    f"latent-paged-b{WIDEST_ROWS}-t1-read{WIDEST_SLOTS}": _latent_paged(
+        WIDEST_ROWS, 1, WIDEST_SLOTS, slots=WIDEST_SLOTS
+    ),
     **{f"kimi-grouped-{pairs}pairs-{role}": _kimi_grouped(pairs, role)
        for pairs in (256, 2048) for role in ("w1", "w2")},
     # Olmo-Hybrid-7B: the decode step at the issue's 32 rows, the cell's 24
@@ -499,6 +528,11 @@ def test_kernel_compiles_for_v5e(v5e, case):
         # `pallas_q40._dt_operand`, no no-op at these shapes), and a prompt's
         # scores
         assert count_tpu_kernels(compiled) >= 3 + 5 + 4 + 1
+        # a decode step reads the latent pool through the page-table kernel
+        # (PR 44): one call in the dense layer, one in the expert layers'
+        # scan body, 14 -> 16 sites (8 calls a step); a prompt's chunk keeps
+        # the gathered view
+        assert count_tpu_kernels(compiled) == (16 if "rows" in case else 14)
         assert compiled.memory_analysis().temp_size_in_bytes < 5 << 28
     if case.startswith("granite-step"):
         # 36 state-space layers in two inner scans' bodies and 4 full layers in
@@ -508,7 +542,7 @@ def test_kernel_compiles_for_v5e(v5e, case):
         # state (2.4 GB at 32 rows) or of the pool (1 GB) beside them
         assert count_tpu_kernels(compiled) >= 2 * 4 + 5 + 1 + (2 if "rows" in case else 0)
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    if case.startswith("paged") or case.startswith("gdn") or case.startswith("ssd"):
+    if case.startswith(("paged", "latent-paged", "gdn", "ssd")):
         # the pool (the state) is read where it lies: a reshaped or
         # re-laid-out operand shows up as a copy of the whole of it (GBs) in
         # the program's temps
