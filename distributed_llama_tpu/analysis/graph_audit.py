@@ -494,8 +494,9 @@ def latent_f32_dots(engine) -> int:
 
 def recurrence_f32_dots(engine, entry: LadderEntry) -> int:
     """The float32 dots a hybrid model's linear layers need, counted in one
-    period's scan body (0 for every other model). Each of a period's linear
-    layers projects its two gates in float32 (1 dot); its recurrence is then
+    period's scan body (0 for every other model), which holds ONE layer body
+    a run of linear layers (`LayerPlan.runs`). A gated-delta layer projects
+    its two gates in float32 (1 dot); its recurrence is then
     either the Pallas decode step, whose body's two dots take bfloat16 (0;
     traced only where Pallas is on), or the chunked form: K K^T and Q K^T
     (2), the forward substitution's row update (1) and the sub-chunk scan's
@@ -510,16 +511,15 @@ def recurrence_f32_dots(engine, entry: LadderEntry) -> int:
     # (CacheAddr.rec_row), which the kernel does not take
     row = 0 if entry.kind == "prefill_row" and engine.paged else None
     kernel = one_position and _rec_kernel_eligible(cfg, 1, row)
+    plan = cfg.layer_plan
+    runs = sum(plan.mixers[plan.lead + run.first] != "attention" for run in plan.runs)
     if cfg.lin_kind == "ssd":
         # a state-space layer projects its step in float32 (1 dot); its
         # decode kernel has no dot at all, and the chunked form has four
         # (C B^T, the sub-chunk's product, the carried state's read-out, the
-        # state's update). The stack holds ONE layer body a run of such
-        # layers (before the period's full layer, and after it)
-        before = cfg.full_attn_offset % cfg.full_attn_interval
-        runs = (before > 0) + (cfg.full_attn_interval - 1 - before > 0)
+        # state's update)
         return (1 if kernel else 1 + 4) * runs
-    return (1 if kernel else 1 + 7) * (cfg.full_attn_interval - 1)
+    return (1 if kernel else 1 + 7) * runs
 
 
 # -- the declarative contract registry --------------------------------------
@@ -605,26 +605,14 @@ def contract_for(engine, entry: LadderEntry) -> ProgramContract:
 
 
 def _fused_kernel_active(engine, kind: str) -> bool:
-    """True when the paged decode programs trace the page-table Pallas
-    kernel (models/kv_arms.py _fused_paged_eligible at decode's t=1, or the
-    latent arm's `_latent_kernel_serves`, which wants the per-row positions
-    of a `batch_decode` step), read off what the gate reads: the config and
-    the pool's own shape."""
-    from ..models.kv_arms import _fused_paged_eligible, _latent_kernel_serves
+    """True when the paged decode programs of `kind` trace the page-table
+    Pallas kernel: the arms' own gate (`kv_arms.decode_kernel_serves`), asked
+    about this engine's pool."""
+    from ..models.kv_arms import decode_kernel_serves
 
-    cfg = engine.cfg
-    if cfg.is_latent:
-        return _latent_kernel_serves(
-            cfg, engine.cache.k, engine.batch, engine.page_pool.max_slots, 1,
-            per_row=kind == "batch_decode",
-        )
     tp = engine.mesh.shape["tp"] if engine.mesh is not None else 1
-    # the pool's own kv heads and head width (it may store more than the
-    # model has: paged_kv.pool_kv_heads, pool_head_dim), a tp shard's share
-    n_kv = engine.cache.k.shape[3] // tp
-    return _fused_paged_eligible(
-        cfg, (cfg.n_heads // cfg.n_kv_heads * n_kv, engine.cache.k.shape[4]), n_kv, 1,
-        engine.cache.k.shape[2],
+    return decode_kernel_serves(
+        engine.cfg, engine.cache.k, kind, engine.batch, engine.page_pool.max_slots, tp
     )
 
 

@@ -7,10 +7,67 @@ but hashable/frozen so it can be a static argument to jit-compiled functions.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from typing import NamedTuple
 
 import jax.numpy as jnp
 
 from ..formats.mfile import ArchType, HiddenAct, ModelHeader, RopeType
+
+
+class LayerRun(NamedTuple):
+    """A run of like layers inside a period."""
+
+    first: int  # the run's first layer, counted from the period's
+    n: int
+    scan: bool  # the period holds other layers of this kind, so the run is
+    # an inner scan whatever its length (every layer of a kind is traced in
+    # one form); False: the kind's one layer of the period, a call
+
+
+@dataclass(frozen=True)
+class LayerPlan:
+    """A model's layer stack: `lead` leading layers, then whole periods of
+    `period` layers that repeat the kinds of the first. A layer has a token
+    mixer and a feed-forward, each with a stack of weights (and a cache)
+    that holds the layers of its kind alone, in order."""
+
+    mixers: tuple  # a layer: "attention" | "gated_delta" | "ssd" | "latent"
+    ffns: tuple  # a layer: "dense" | "moe" | "held"
+    lead: int
+    period: int
+    # the residual block: x += residual_mult * post(sub_layer(pre(x))), the
+    # norm as `pre` (pre_norm) or as `post`
+    pre_norm: bool
+    residual_mult: float
+
+    @property
+    def n_periods(self) -> int:
+        return (len(self.mixers) - self.lead) // self.period
+
+    def place(self, l: int, stack: str = "layer") -> tuple:
+        """(index, stride) of layer `l` in a stack: "mixer" | "ffn", the
+        stack of its kind of mixer (weights and cache alike) or feed-forward,
+        or "layer", the stack of all layers (the norms'). The index is the
+        layers of that kind before it, and the stride how many a period
+        holds: the same layer p periods on sits at `index + p * stride`."""
+        if stack == "layer":
+            return l, self.period
+        kinds = self.mixers if stack == "mixer" else self.ffns
+        in_period = kinds[self.lead : self.lead + self.period]
+        return kinds[:l].count(kinds[l]), in_period.count(kinds[l])
+
+    @property
+    def runs(self) -> tuple:
+        """The first period's runs of like layers, in order."""
+        kinds = list(zip(self.mixers, self.ffns))[self.lead : self.lead + self.period]
+        runs, first = [], 0
+        while first < len(kinds):
+            n = 1
+            while first + n < len(kinds) and kinds[first + n] == kinds[first]:
+                n += 1
+            runs.append(LayerRun(first, n, kinds.count(kinds[first]) > 1))
+            first += n
+        return tuple(runs)
 
 
 @dataclass(frozen=True)
@@ -102,19 +159,34 @@ class ModelConfig:
     routed_scale: float = 1.0
 
     @property
+    def layer_plan(self) -> "LayerPlan":
+        """The stack as `models/transformer._walk` takes it: the ONE source of
+        the layer pattern (`layer_kinds` is read off it)."""
+        p, full = self.full_attn_interval, self.full_attn_offset % self.full_attn_interval
+        mixers = tuple(
+            "latent" if self.is_latent else "attention" if l % p == full else self.lin_kind
+            for l in range(self.n_layers)
+        )
+        if self.n_experts_held:
+            ffns = tuple(
+                "dense" if l < self.n_dense_layers else "held" for l in range(self.n_layers)
+            )
+        else:
+            ffns = ("moe" if self.is_moe else "dense",) * self.n_layers
+        return LayerPlan(
+            mixers=mixers, ffns=ffns, lead=self.n_dense_layers, period=p,
+            pre_norm=self.arch_type != ArchType.OLMO_HYBRID,
+            residual_mult=self.residual_mult,
+        )
+
+    @property
     def layer_kinds(self) -> tuple:
         """A name per layer: "linear" | "full" by the token mixer, or, where
         the feed-forward is what differs (latent models), "dense" | "moe"."""
+        plan = self.layer_plan
         if self.is_latent:
-            return tuple(
-                "dense" if l < self.n_dense_layers else "moe"
-                for l in range(self.n_layers)
-            )
-        p = self.full_attn_interval
-        return tuple(
-            "full" if l % p == self.full_attn_offset % p else "linear"
-            for l in range(self.n_layers)
-        )
+            return tuple("dense" if f == "dense" else "moe" for f in plan.ffns)
+        return tuple("full" if m == "attention" else "linear" for m in plan.mixers)
 
     @property
     def n_kv_layers(self) -> int:
@@ -133,6 +205,58 @@ class ModelConfig:
     @property
     def is_latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def pad_token(self) -> int:
+        """What a prompt chunk's tail past its real tokens is filled with. A
+        model that keeps a recurrent state pads with -1: its forward reads
+        "token below 0" as "do not advance the state"
+        (transformer.forward_uncompiled)."""
+        return -1 if self.is_hybrid else 0
+
+    def rec_row(self, row):
+        """The `rec_row` operand of a one-row call for batch row `row`: a
+        recurrent state's slots are by batch row, so the call is told whose it
+        advances; None (no operand at all) where the model keeps no state."""
+        return row if self.is_hybrid else None
+
+    @property
+    def cache_refusals(self) -> dict:
+        """What this model's cache cannot do yet, capability -> why: whatever
+        assumes a cache can be cut in ways an architecture's cache has not been
+        taught is refused, not served without it. "mesh", "int8_kv",
+        "speculation", "contiguous" (the KV layout) and "solo" (the solo
+        programs in a batched engine's default warm plan) give the reason of
+        an engine's refusal, "prefix_cache" the notice that it is off,
+        "handoff" (disaggregated serving, KV tiering) the end of a server's
+        refusal. Empty for a cache of k and v heads a token."""
+        def refusals(refused, why, prefix_cache, handoff):
+            return {**dict.fromkeys(refused, why), "prefix_cache": prefix_cache, "handoff": handoff}
+
+        if self.is_hybrid:
+            return refusals(
+                ("mesh", "int8_kv", "speculation", "solo"),
+                "linear layers (gated-delta, state-space) keep a recurrent "
+                "state a row, which has no snapshots or rollback yet (ROADMAP R7)",
+                "prefix cache off: a linear layer's recurrent state has no "
+                "snapshots at page boundaries yet (ROADMAP R7), so a cached "
+                "prefix cannot be resumed",
+                "this architecture's recurrent state has no snapshots or "
+                "hand-off yet (ROADMAP R7)",
+            )
+        if self.is_latent:
+            return refusals(
+                ("mesh", "int8_kv", "speculation", "contiguous", "solo"),
+                "latent attention keeps one [latent | key] vector a token in "
+                "the paged float pool of one chip, and its expert layers hold "
+                "a share of the experts without an exchange (ROADMAP R5)",
+                "prefix cache off: its publish, share and ship programs "
+                "read a page as k and v heads, and a latent page is one "
+                "[latent | key] vector a token (ROADMAP R5)",
+                "the page programs read a page as k and v heads, and a "
+                "latent page is one vector a token (ROADMAP R5)",
+            )
+        return {}
 
     @property
     def latent_width(self) -> int:

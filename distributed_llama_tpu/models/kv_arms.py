@@ -1,6 +1,6 @@
 """The KV-cache arms of the layer: write this step's k, v, then attend.
 
-`models/transformer._layer` projects q, k, v and hands them here. An arm
+`models/transformer._attention` projects q, k, v and hands them here. An arm
 owns one cache layout — where a row lands, which rows attention reads back —
 and nothing else of the layer. All arms share one signature,
 
@@ -192,6 +192,22 @@ def _latent_kernel_serves(
     )
 
 
+def decode_kernel_serves(
+    cfg, pool, kind: str, rows: int, max_slots: int, tp: int = 1
+) -> bool:
+    """Whether a decode program of `kind` ("decode" | "batch_decode": one
+    position a row) of `rows` rows reads `pool` through the page-table
+    kernel: the gate of the arm that the model's attention layers take
+    (`latent_arm` | `paged_arm`), asked as the arm asks it. `tp`: the shards
+    of the pool's head axis, each of which asks about its own heads."""
+    if cfg.is_latent:
+        return _latent_kernel_serves(
+            cfg, pool, rows, max_slots, 1, per_row=kind == "batch_decode"
+        )
+    shard = (*pool.shape[:3], pool.shape[3] // tp, pool.shape[4])
+    return _paged_kernel_serves(cfg, shard, cfg.n_heads // tp, cfg.n_kv_heads // tp, 1)
+
+
 def decode_reads_live_pages(cfg, cache, rows: int, max_slots: int | None, mesh) -> bool:
     """Whether a decode step (t = 1) of `rows` rows reads NOTHING that grows
     with its KV read bound, so that one program at the bound `seq_len` serves
@@ -207,17 +223,11 @@ def decode_reads_live_pages(cfg, cache, rows: int, max_slots: int | None, mesh) 
     HLO over the whole bound), on a mesh, for the contiguous layout
     (`max_slots` None: no page table), and where the table of the deepest
     bound, [rows, max_slots], would not fit the kernel's scalar memory. It
-    reads shapes, the pool's dtype and the arm."""
+    reads shapes, the pool's dtype and the arm's gate."""
     if mesh is not None or max_slots is None or cache.quantized:
         return False
-    # the arm of the step's attention layers, addressed as the model graph
-    # addresses it (`transformer._latent_layers` sets `latent`)
-    arm = select_arm(CacheAddr(page_table=max_slots, latent=cfg.is_latent))
-    if arm is latent_arm:
-        return _latent_kernel_serves(cfg, cache.k, rows, max_slots, 1)
     return (
-        arm is paged_arm
-        and _paged_kernel_serves(cfg, cache.k.shape, cfg.n_heads, cfg.n_kv_heads, 1)
+        decode_kernel_serves(cfg, cache.k, "batch_decode", rows, max_slots)
         and paged_prefetch_words(rows, max_slots) <= PAGED_PREFETCH_WORDS
     )
 

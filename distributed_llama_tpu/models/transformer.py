@@ -2,47 +2,49 @@
 Granite-Hybrid).
 
 Functional re-design of the reference's per-node op graph (reference:
-buildLlmNet, src/llm.cpp:152-649). One layer body is `lax.scan`ned over
-stacked weights; XLA fuses norm->matmul->rope->attention chains and inserts
-collectives when the arrays carry shardings (parallel/sharding.py).
+buildLlmNet, src/llm.cpp:152-649). ONE walker (`_walk`) takes every family's
+layer stack by the config's plan (`ModelConfig.layer_plan`): the leading
+layers as calls, then a `lax.scan` over the periods, a run of like layers in
+a period an inner scan, all over stacked weights; XLA fuses
+norm->matmul->rope->attention chains and inserts collectives when the arrays
+carry shardings (parallel/sharding.py). A model whose layers are all alike
+is one scan over its layers.
 
-Math per layer (reference att segment src/llm.cpp:278-418, ff segment
-src/llm.cpp:421-569):
+ONE residual block (`_block`) is every layer's: a token mixer and then a
+feed-forward, each joined to the residual stream as
 
-    y  = rms_norm(x, norm0);  q,k,v = y @ Wq,Wk,Wv
-    [qwen3: per-head rms_norm of q,k]          (src/llm.cpp:337-361)
-    q,k = rope(q,k); cache[pos] = k,v          (shiftForward)
-    a  = gqa_attention(q, cache);  x += a @ Wo (+ TP psum in reference)
-    y  = rms_norm(x, norm1)
-    dense: x += (silu(y@W1) * (y@W3)) @ W2
-    moe:   route -> top-k experts' swiglu, weighted sum (src/llm.cpp:440-514)
+    x += r * post(sub_layer(pre(x)))
+
+with the norm as `pre` (and `post` nothing) or as `post`, and `r` the
+residual multiplier. The plan names each layer's mixer and feed-forward, and
+`_mix` / `_feed` look them up:
+
+    mixers         attention (`_attention`: q,k,v = y @ Wq,Wk,Wv; the head
+                   norm of Qwen3 or the whole-projection norm of Olmo; RoPE;
+                   a cache arm of models/kv_arms.py writes k, v and attends;
+                   @ Wo. Reference att segment src/llm.cpp:278-418),
+                   gated_delta (`_gdn_mixer`, ops/gated_delta.py),
+                   ssd (`_ssm_mixer`, Mamba-2, ops/ssd.py),
+                   latent (`_latent_attention`, the absorbed form)
+    feed-forwards  dense (`_dense_ffn`: (silu(y@W1) * (y@W3)) @ W2),
+                   moe (`_moe_ffn`: route -> top-k experts' swiglu, weighted
+                   sum, src/llm.cpp:440-514),
+                   held (`_held_expert_ffn`: sigmoid-routed experts of which
+                   this model file HOLDS a share, beside the shared experts)
+
+    Llama, Qwen3   attention + dense, pre-norm; Qwen3-MoE: attention + moe
+    Olmo-Hybrid    periods of `full_attn_interval`, all but the last of a
+                   period gated_delta and the last attention, + dense; the
+                   norm on each sub-layer's OUTPUT and none before it
+    Granite-Hybrid periods whose attention layer sits anywhere in them, the
+                   others ssd, + dense; pre-norm; the embedding times
+                   `embedding_mult`, r = `residual_mult`, no position
+                   embedding, scores times `attn_scale`, logits over
+                   `logits_scaling`
+    Kimi-K2        latent in every layer (the DeepSeek-V3 block); dense in the
+                   leading layers and held in the others; pre-norm
 
 Final: rms_norm(x, final_norm) @ Wcls -> logits   (src/llm.cpp:593-636)
-
-Olmo-Hybrid (no reference analogue; `_hybrid_layers`): the layers come in
-periods of `full_attn_interval`, all but the last of a period gated-delta
-linear attention (ops/gated_delta.py) and the last full attention; the norm
-sits on each sub-layer's OUTPUT and there is none before it:
-
-    x += rms_norm(mixer(x), norm0);  x += rms_norm(ffn(x), norm1)
-
-with mixer = attention over a q/k normed across the whole projection, or the
-gated delta rule (`_gdn_mixer`).
-
-Granite-Hybrid (no reference analogue; `_ssm_layers`): periods whose full
-layer sits anywhere in them, the others Mamba-2 state-space layers
-(ops/ssd.py, `_ssm_mixer`); pre-norm blocks and Granite's four multipliers:
-
-    x = e E[token];  x += r mixer(rms_norm(x, norm0));  x += r ffn(rms_norm(x, norm1))
-
-attention with no position embedding and scores times `attn_scale`, logits
-over `logits_scaling`.
-
-Kimi-K2 (the DeepSeek-V3 block; no reference analogue; `_latent_layers`):
-pre-norm residual blocks, latent attention in every layer (`_latent_attention`),
-a dense feed-forward in the leading layers and, in the others, sigmoid-routed
-experts of which this model file HOLDS a share, beside the shared experts
-(`_held_expert_ffn`).
 """
 
 from __future__ import annotations
@@ -60,6 +62,7 @@ from ..ops import moe_router, rms_norm
 from ..ops.activations import gelu, silu
 from ..ops.moe import moe_ffn_held, moe_router_sigmoid
 from ..ops.quant import QuantTensor, dequantize_t, quant_matmul, quantize_q80_activations
+from ..ops.quant import slice_layer as _sel_layer  # w[i] of a stacked weight; w where i is None
 from ..ops.rope import RopeTables, apply_rope
 from .config import ModelConfig
 from .kv_arms import CacheAddr, _pallas_enabled, recurrent_arm, select_arm
@@ -95,15 +98,6 @@ def linear(
 
 def _activation(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return silu(x) if cfg.hidden_act == HiddenAct.SILU else gelu(x)
-
-
-def _sel_layer(w: Any, i) -> Any:
-    """w[i] for a stacked per-layer weight (QuantTensor-aware); identity when
-    i is None (w already belongs to one layer). Delegates to the single
-    stack-slicing owner in ops/quant.py."""
-    from ..ops.quant import slice_layer
-
-    return slice_layer(w, i)
 
 
 def _dense_ffn(cfg: ModelConfig, y: jnp.ndarray, lp: LayerParams, layer=None) -> jnp.ndarray:
@@ -325,50 +319,16 @@ def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp: LayerParams, positions, laye
     return q, k, v
 
 
-def _ffn(cfg: ModelConfig, y, lp: LayerParams, layer_idx, ep_axis):
-    """The feed-forward of the normed activation y: experts or dense."""
-    if cfg.is_moe:
-        return _moe_ffn(cfg, y, lp, layer_idx, ep_axis=ep_axis)
-    return _dense_ffn(cfg, y, lp, layer_idx)
-
-
-def _layer(
-    cfg: ModelConfig,
-    rope: RopeTables,
-    x: jnp.ndarray,  # [b, t, dim] residual stream (f32)
-    positions: jnp.ndarray,  # [b, t] int32
-    pos_start: jnp.ndarray,  # int32 cache write offset: scalar, or [b] per row
-    lp: LayerParams,
-    cache: KVCache,  # the layout `addr` describes, float or int8
-    addr: CacheAddr,
-    layer_idx=None,  # scalar int32 when `lp` holds ALL layers stacked: the
-    # big matmuls select the layer inside the Pallas kernel (no weight-slice
-    # copy — see quant_matmul) and the small per-layer tensors are sliced
-    # here. None = `lp` is already a single layer's weights.
-    reduce_fn=None,  # TP partial-sum reduction (shard_map path): applied to
-    # the attention and ffn output projections. None under GSPMD — XLA
-    # inserts the psum itself from the shardings (the reference's explicit
-    # SYNC_NODE_SLICES after att/ff, src/llm.cpp:418,569).
-    ep_axis=None,  # mesh axis name when the MoE expert stacks are sharded
-    # under shard_map (expert parallelism — see _moe_ffn); attention weights
-    # are replicated over this axis and the MoE output psums over it
-) -> tuple[jnp.ndarray, KVCache]:
-    if reduce_fn is None:
-        reduce_fn = lambda z: z
-    b, t, _ = x.shape
-
-    # --- attention block ---
-    y = rms_norm(x, _sel_layer(lp.norm0, layer_idx), cfg.norm_epsilon)
-    q, k, v = _qkv(cfg, rope, y, lp, positions, layer_idx)
+def _attention(cfg, rope, y, lp: LayerParams, cache, addr, wi, positions, pos_start):
+    """The attention mixer of the activation y [b, t, dim] for layer `wi` of
+    the attention stack (None: `lp` is one layer's weights): q, k, v, the cache
+    arm `addr` selects (`addr.layer`: the layer's rows of the cache), the
+    output projection. Returns (out [b, t, dim] before the residual, cache)."""
+    b, t, _ = y.shape
+    q, k, v = _qkv(cfg, rope, y, lp, positions, wi)
     a, cache = select_arm(addr)(cfg, cache, addr, q, k, v, positions, pos_start)
     n_local_heads = q.shape[2]  # == cfg.n_heads unless sharded under shard_map
-    att_out = linear(a.reshape(b, t, n_local_heads * cfg.head_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, layer_idx)
-    x = x + reduce_fn(att_out).astype(x.dtype)
-
-    # --- ffn block ---
-    y = rms_norm(x, _sel_layer(lp.norm1, layer_idx), cfg.norm_epsilon)
-    x = x + reduce_fn(_ffn(cfg, y, lp, layer_idx, ep_axis)).astype(x.dtype)
-    return x, cache
+    return linear(a.reshape(b, t, n_local_heads * cfg.head_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, wi), cache
 
 
 def _gdn_mixer(cfg, x, gp, cache, addr, ri, positions, valid):
@@ -402,45 +362,6 @@ def _gdn_mixer(cfg, x, gp, cache, addr, ri, positions, valid):
     return linear(o, gp.wo, cfg.dtype, cfg.pallas_arg, q80, ri), cache
 
 
-def _hybrid_layers(cfg, params, rope, x, cache, positions, pos_start, valid, addr):
-    """Olmo-Hybrid's layer stack: one scan over the PERIODS, its body the
-    period's linear layers and then its full layer. Weights stay stacked by
-    kind and are selected inside the kernels: linear stack index
-    `p (interval-1) + j`, full stack and KV index `p`, feed-forward and norm
-    index `p interval + j`. The KV cache, the recurrent state and the conv
-    tail ride the carry as one `KVCache` value. `valid` [b, t]: false where a
-    position must not advance a recurrent state. Returns (x, cache)."""
-    b, t, _ = x.shape
-    lp = params.layers
-    period = cfg.full_attn_interval
-    eps = cfg.norm_epsilon
-
-    def ffn_block(x, fi):
-        h = _dense_ffn(cfg, x, lp, fi)
-        return x + rms_norm(h, _sel_layer(lp.norm1, fi), eps).astype(x.dtype)
-
-    def body(carry, p):
-        x, cache = carry
-        for j in range(period - 1):
-            fi = p * period + j
-            y, cache = _gdn_mixer(
-                cfg, x, lp.gdn, cache, addr, p * (period - 1) + j, positions, valid
-            )
-            x = x + rms_norm(y, _sel_layer(lp.norm0, fi), eps).astype(x.dtype)
-            x = ffn_block(x, fi)
-        fi = p * period + period - 1
-        q, k, v = _qkv(cfg, rope, x, lp, positions, p)
-        a_addr = addr._replace(layer=p)
-        a, cache = select_arm(a_addr)(cfg, cache, a_addr, q, k, v, positions, pos_start)
-        y = linear(a.reshape(b, t, cfg.q_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, p)
-        x = x + rms_norm(y, _sel_layer(lp.norm0, fi), eps).astype(x.dtype)
-        return (ffn_block(x, fi), cache), None
-
-    periods = jnp.arange(cfg.n_layers // period, dtype=jnp.int32)
-    (x, cache), _ = jax.lax.scan(body, (x, cache), periods)
-    return x, cache
-
-
 def _ssm_mixer(cfg, y, mp, cache, addr, ri, positions, valid):
     """The Mamba-2 mixer of the NORMED activation y [b, t, dim] for
     state-space layer `ri` of the `mp` stack: the in-projection (z | xBC in
@@ -464,63 +385,6 @@ def _ssm_mixer(cfg, y, mp, cache, addr, ri, positions, valid):
     g = o.reshape(b, t, d_inner) * silu(zx[..., :d_inner].astype(jnp.float32))
     g = rms_norm(g, _sel_layer(mp.norm, ri), cfg.norm_epsilon)
     return linear(g, mp.w_out, cfg.dtype, cfg.pallas_arg, q80, ri), cache
-
-
-def _ssm_layers(cfg, params, rope, x, cache, positions, pos_start, valid, addr):
-    """Granite-Hybrid's layer stack: pre-norm blocks whose outputs join the
-    residual stream times `residual_mult`; per period, `full_attn_offset`
-    state-space layers, the full-attention layer (no position embedding; the
-    cache arms take `attn_scale` for the scores), then the period's other
-    state-space layers. ONE scan over the periods whose body runs each run
-    of state-space layers as an inner scan, so a program holds three layer
-    bodies whatever the period's length (ten unrolled layers lowered a
-    prompt's chunk in 1.2-1.7 s and compiled it in 7 where this takes 0.7-0.9
-    and 4: PERF.md section 6, PR 42). Weights stay
-    stacked by kind and are selected inside the kernels: state-space stack
-    index `p (interval-1) + j`, full stack and KV index `p`, feed-forward and
-    norm index the layer's own. Returns (x, cache)."""
-    b, t, _ = x.shape
-    lp = params.layers
-    period, before = cfg.full_attn_interval, cfg.full_attn_offset % cfg.full_attn_interval
-    eps, rm = cfg.norm_epsilon, cfg.residual_mult
-
-    def ffn_block(x, fi):
-        y = rms_norm(x, _sel_layer(lp.norm1, fi), eps)
-        return x + rm * _dense_ffn(cfg, y, lp, fi).astype(x.dtype)
-
-    def ssm_run(x, cache, p, first, n):
-        """The period's state-space layers `first .. first + n - 1`."""
-        skipped = 1 if first > before else 0  # the full layer is no mixer
-
-        def body(carry, j):
-            x, cache = carry
-            fi = p * period + j
-            y = rms_norm(x, _sel_layer(lp.norm0, fi), eps)
-            ri = p * (period - 1) + j - skipped
-            y, cache = _ssm_mixer(cfg, y, lp.ssm, cache, addr, ri, positions, valid)
-            return (ffn_block(x + rm * y.astype(x.dtype), fi), cache), None
-
-        if n == 0:
-            return x, cache
-        js = jnp.arange(first, first + n, dtype=jnp.int32)
-        return jax.lax.scan(body, (x, cache), js)[0]
-
-    def body(carry, p):
-        x, cache = carry
-        x, cache = ssm_run(x, cache, p, 0, before)
-        fi = p * period + before
-        y = rms_norm(x, _sel_layer(lp.norm0, fi), eps)
-        q, k, v = _qkv(cfg, rope, y, lp, positions, p)
-        a_addr = addr._replace(layer=p)
-        a, cache = select_arm(a_addr)(cfg, cache, a_addr, q, k, v, positions, pos_start)
-        y = linear(a.reshape(b, t, cfg.q_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, p)
-        x = ffn_block(x + rm * y.astype(x.dtype), fi)
-        x, cache = ssm_run(x, cache, p, before + 1, period - 1 - before)
-        return (x, cache), None
-
-    periods = jnp.arange(cfg.n_layers // period, dtype=jnp.int32)
-    (x, cache), _ = jax.lax.scan(body, (x, cache), periods)
-    return x, cache
 
 
 def _latent_attention(cfg, rope, y, mp, cache, addr, li, positions, pos_start):
@@ -588,44 +452,183 @@ def _held_expert_ffn(cfg, y, ep, mi):
     return routed + shared, stats
 
 
-def _latent_layers(cfg, params, rope, x, cache, positions, pos_start, addr):
-    """A latent model's layer stack (kimi_k2): the leading dense layers one
-    call each, then ONE scan over the expert layers. Pre-norm residual blocks,
-    latent attention in every layer; weights stay stacked by kind and are
-    selected inside the kernels (attention and norms by layer, the dense
-    feed-forward by its index among the dense layers, the experts by theirs).
-    The cache rides the carry, and with it the expert layers' two counters
-    (`KVCache.moe`): row 0 when the call is a decode step, row 1 otherwise."""
-    lp = params.layers
-    eps = cfg.norm_epsilon
-    counts_row = 0 if x.shape[1] == 1 else 1
+def _mix(kind, cfg, rope, y, lp, cache, addr, i, positions, pos_start, valid):
+    """Layer `i` of the stack of token mixers of `kind` on the activation y.
+    Every mixer is found as this module's attribute when the call is traced.
+    Returns (out [b, t, dim] before the residual, cache)."""
+    if kind == "attention":
+        return _attention(cfg, rope, y, lp, cache, addr, i, positions, pos_start)
+    if kind == "latent":
+        return _latent_attention(cfg, rope, y, lp.mla, cache, addr, i, positions, pos_start)
+    if kind == "gated_delta":
+        return _gdn_mixer(cfg, y, lp.gdn, cache, addr, i, positions, valid)
+    return _ssm_mixer(cfg, y, lp.ssm, cache, addr, i, positions, valid)
 
-    def attn_block(x, cache, li):
-        y = rms_norm(x, _sel_layer(lp.norm0, li), eps)
-        a, cache = _latent_attention(
-            cfg, rope, y, lp.mla, cache, addr, li, positions, pos_start
-        )
-        return x + a.astype(x.dtype), cache
 
-    for li in range(cfg.n_dense_layers):
-        li = jnp.int32(li)
-        x, cache = attn_block(x, cache, li)
-        y = rms_norm(x, _sel_layer(lp.norm1, li), eps)
-        x = x + _dense_ffn(cfg, y, lp, li).astype(x.dtype)
+def _feed(kind, cfg, y, lp, cache, i, ep_axis):
+    """Layer `i` of the stack of feed-forwards of `kind` on the activation y.
+    Returns (out, cache): held experts count their tokens into the cache's
+    two counters (`KVCache.moe`), row 0 in a decode step and row 1 otherwise."""
+    if kind == "held":
+        h, stats = _held_expert_ffn(cfg, y, lp.experts, i)
+        counts_row = 0 if y.shape[1] == 1 else 1
+        return h, replace(cache, moe=cache.moe.at[counts_row].add(stats))
+    if kind == "moe":
+        return _moe_ffn(cfg, y, lp, i, ep_axis=ep_axis), cache
+    return _dense_ffn(cfg, y, lp, i), cache
 
-    def body(carry, mi):
-        x, cache = carry
-        li = mi + cfg.n_dense_layers
-        x, cache = attn_block(x, cache, li)
-        y = rms_norm(x, _sel_layer(lp.norm1, li), eps)
-        h, stats = _held_expert_ffn(cfg, y, lp.experts, mi)
-        cache = replace(cache, moe=cache.moe.at[counts_row].add(stats))
-        return (x + h.astype(x.dtype), cache), None
 
-    (x, cache), _ = jax.lax.scan(
-        body, (x, cache), jnp.arange(cfg.n_moe_layers, dtype=jnp.int32)
+def _block(
+    cfg: ModelConfig,
+    rope: RopeTables,
+    x: jnp.ndarray,  # [b, t, dim] residual stream (f32)
+    positions: jnp.ndarray,  # [b, t] int32
+    pos_start: jnp.ndarray,  # int32 cache write offset: scalar, or [b] per row
+    lp: LayerParams,
+    cache: KVCache,  # the layout `addr` describes, float or int8
+    addr: CacheAddr,
+    kinds: tuple,  # the layer's (mixer, feed-forward)
+    index,  # index("layer" | "mixer" | "ffn") -> the layer's place in that
+    # stack (`LayerPlan.place`), traced where it is first asked for
+    valid=None,  # [b, t] bool: false where a recurrent state must not advance
+    reduce_fn=None,
+    ep_axis=None,
+) -> tuple[jnp.ndarray, KVCache]:
+    """THE residual block, every family's: the mixer and then the
+    feed-forward, each as x += r * post(sub_layer(pre(x))), the norm as `pre`
+    or as `post` and `r` the residual multiplier (`cfg.layer_plan`)."""
+    plan = cfg.layer_plan
+    mixer, ffn = kinds
+    r = plan.residual_mult
+    li = index("layer")
+
+    def normed(v, w):
+        return rms_norm(v, _sel_layer(w, li), cfg.norm_epsilon)
+
+    def join(x, y, w):
+        if reduce_fn is not None:
+            y = reduce_fn(y)
+        if not plan.pre_norm:
+            y = normed(y, w)
+        y = y.astype(x.dtype)
+        return x + (y if r == 1.0 else r * y)
+
+    y = normed(x, lp.norm0) if plan.pre_norm else x
+    mi = index("mixer")
+    if mi is not None:
+        addr = addr._replace(layer=mi)
+    y, cache = _mix(mixer, cfg, rope, y, lp, cache, addr, mi, positions, pos_start, valid)
+    x = join(x, y, lp.norm0)
+    y = normed(x, lp.norm1) if plan.pre_norm else x
+    y, cache = _feed(ffn, cfg, y, lp, cache, index("ffn"), ep_axis)
+    return join(x, y, lp.norm1), cache
+
+
+def _layer(
+    cfg: ModelConfig,
+    rope: RopeTables,
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    pos_start: jnp.ndarray,
+    lp: LayerParams,
+    cache: KVCache,
+    addr: CacheAddr,  # `addr.layer`: the layer's rows of the cache
+    layer_idx=None,  # scalar int32 when `lp` holds ALL layers stacked: the
+    # big matmuls select the layer inside the Pallas kernel (no weight-slice
+    # copy — see quant_matmul) and the small per-layer tensors are sliced
+    # here. None = `lp` is already a single layer's weights.
+    reduce_fn=None,  # TP partial-sum reduction (shard_map path): applied to
+    # the attention and ffn output projections. None under GSPMD — XLA
+    # inserts the psum itself from the shardings (the reference's explicit
+    # SYNC_NODE_SLICES after att/ff, src/llm.cpp:418,569).
+    ep_axis=None,  # mesh axis name when the MoE expert stacks are sharded
+    # under shard_map (expert parallelism — see _moe_ffn); attention weights
+    # are replicated over this axis and the MoE output psums over it
+) -> tuple[jnp.ndarray, KVCache]:
+    """One layer of a model whose layers are all alike, the cache's rows and
+    the weights addressed apart (parallel/pipeline.py scans it over a stage's
+    own slice of both)."""
+    plan = cfg.layer_plan
+    return _block(
+        cfg, rope, x, positions, pos_start, lp, cache, addr,
+        (plan.mixers[0], plan.ffns[0]), lambda stack: layer_idx,
+        reduce_fn=reduce_fn, ep_axis=ep_axis,
     )
-    return x, cache
+
+
+def _stack_index(plan, l: int, p=None, j=None):
+    """`index(stack)` for `_block`: where layer `l` of the first period (or a
+    leading layer: `p` None) sits in a stack (`LayerPlan.place`) `p` periods
+    on (a scalar int32), and, in a run's inner scan, with the scanned `j` in
+    place of `l`. An index is traced once where two stacks share it, and `* 1`
+    and `+ 0` never: a model whose layers are all alike indexes every stack
+    with `p` itself."""
+    seen = {}
+
+    def index(stack):
+        i, stride = plan.place(l, stack)
+        key = i if p is None else (i, stride)
+        if key not in seen:
+            if p is None:
+                seen[key] = jnp.int32(i)
+            else:
+                at = p if stride == 1 else p * stride
+                if j is None:
+                    seen[key] = at + i if i else at
+                elif stack == "layer":
+                    seen[key] = at + j
+                else:
+                    # less the layers of other kinds before it (a `- 0` stays
+                    # an equation: Granite's programs hold it since PR 42)
+                    seen[key] = at + j - (l - i)
+        return seen[key]
+
+    return index
+
+
+def _walk(cfg, params, rope, x, cache, positions, pos_start, valid, addr):
+    """The layer stack of every family, by `cfg.layer_plan`: the leading
+    layers one call each, then ONE scan over the periods whose body takes the
+    period's runs of like layers in order, a run an inner scan (a program
+    holds one layer body a run, whatever the period's length: ten unrolled
+    layers lowered a prompt's chunk in 1.2-1.7 s and compiled it in 7 where
+    the inner scans take 0.7-0.9 and 4: PERF.md section 6, PR 42) and a kind's
+    one layer of the period a call. A model whose layers are all alike is the
+    case "no leading layer, a period of one": the scan over its layers.
+
+    The scans' xs carry only indices; the stacked weights ride in via closure
+    and each matmul selects its layer inside the kernel (scanning over sliced
+    weights instead would copy every layer's weights out of the stack on
+    every step — a dynamic-slice cannot fuse into a pallas_call). The FULL
+    cache rides the CARRY as one value (an int8 cache's scale sidecars, a
+    recurrent state, the conv tail and the experts' counters are leaves of
+    it) and each layer updates its rows in place (CacheAddr.layer)."""
+    plan = cfg.layer_plan
+
+    def block(x, cache, l, index):
+        return _block(
+            cfg, rope, x, positions, pos_start, params.layers, cache, addr,
+            (plan.mixers[l], plan.ffns[l]), index, valid,
+        )
+
+    for l in range(plan.lead):
+        x, cache = block(x, cache, l, _stack_index(plan, l))
+
+    def period(carry, p):
+        for run in plan.runs:
+            l = plan.lead + run.first
+            if run.scan:
+
+                def body(c, j, l=l):
+                    return block(*c, l, _stack_index(plan, l, p, j)), None
+
+                carry, _ = jax.lax.scan(body, carry, jnp.arange(l, l + run.n, dtype=jnp.int32))
+            else:
+                carry = block(*carry, l, _stack_index(plan, l, p))
+        return carry, None
+
+    periods = jnp.arange(plan.n_periods, dtype=jnp.int32)
+    return jax.lax.scan(period, (x, cache), periods)[0]
 
 
 def forward_uncompiled(
@@ -664,41 +667,16 @@ def forward_uncompiled(
         # somewhere; so must no parked row (position at seq_len)
         valid = (tokens >= 0) & (positions < cfg.seq_len)
         tokens = jnp.maximum(tokens, 0)
+    else:
+        valid = None
     x = params.embedding[tokens].astype(jnp.float32)
     if cfg.embedding_mult != 1.0:
         x = x * cfg.embedding_mult
 
-    # the scan's xs carry only the layer index; the stacked weights ride in
-    # via closure and each matmul selects its layer inside the kernel
-    # (scanning over sliced weights instead would copy every layer's weights
-    # out of the stack on every step — a dynamic-slice cannot fuse into a
-    # pallas_call). The FULL cache rides the CARRY as one value (an int8
-    # cache's scale sidecars are leaves of it) and each layer updates its
-    # rows in place (CacheAddr.layer).
     addr = CacheAddr(
         kv_len=kv_len, page_table=page_table, page_size=page_size, rec_row=rec_row
     )
-
-    def body(carry, li):
-        x, cache = carry
-        x, cache = _layer(
-            cfg, rope, x, positions, pos_start, params.layers, cache,
-            addr._replace(layer=li), layer_idx=li,
-        )
-        return (x, cache), None
-
-    if cfg.is_hybrid:
-        stack = _ssm_layers if cfg.lin_kind == "ssd" else _hybrid_layers
-        x, new_cache = stack(
-            cfg, params, rope, x, cache, positions, pos_start, valid, addr
-        )
-    elif cfg.is_latent:
-        x, new_cache = _latent_layers(
-            cfg, params, rope, x, cache, positions, pos_start, addr
-        )
-    else:
-        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-        (x, new_cache), _ = jax.lax.scan(body, (x, cache), layer_ids)
+    x, new_cache = _walk(cfg, params, rope, x, cache, positions, pos_start, valid, addr)
 
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     if logits_mode == "last":

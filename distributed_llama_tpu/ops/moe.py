@@ -37,18 +37,9 @@ import jax.numpy as jnp
 
 from .quant import QuantTensor, dequantize_t, quantize_q80_activations, slice_layer
 
-# A/B knob for the layer-fold formulation (measured NEUTRAL at bench scale,
-# kept for stacks where the dynamic-slice transient grows with E*ff). Read
-# ONCE at import: the value is baked into traced functions by the jit cache
-# anyway, so a module-level constant makes the process-start-only contract
-# structural instead of conventional (ADVICE r4).
-import os as _os
-
-MOE_LAYER_FOLD = _os.environ.get("DLT_MOE_LAYER_FOLD", "1") != "0"
-
 
 def moe_router(
-    x: jnp.ndarray, gate: jnp.ndarray, n_active: int, norm_topk: bool = True
+    x: jnp.ndarray, gate: jnp.ndarray, n_active: int
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Select experts for each token.
 
@@ -63,8 +54,7 @@ def moe_router(
     )
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, n_active)
-    if norm_topk:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_i.astype(jnp.int32), top_p
 
 
@@ -234,7 +224,7 @@ def moe_ffn_ragged(
     # index = layer * n_groups + e). The dynamic-slice alternative
     # materializes every expert's weights per layer per chunk (~50 MB a
     # layer at the bench MoE shape) — measured NEUTRAL there (3 interleaved
-    # A/B reps, DLT_MOE_LAYER_FOLD knob; XLA overlaps the copy), but the
+    # A/B reps; XLA overlaps the copy), but the
     # copy grows with E*ff (GB-scale at 30B-A3B) while the fold stays free
 ) -> jnp.ndarray:
     """Exact top-k expert SwiGLU via sort + grouped (ragged) matmuls.
@@ -254,16 +244,13 @@ def moe_ffn_ragged(
 
     use_grouped = _grouped_quant_eligible(w1, w3, w2, dtype, q80, pallas)
     stacked = layer is not None
-    if stacked and use_grouped:
-        fold_off = not MOE_LAYER_FOLD
+    if stacked and use_grouped and ep_axis is not None:
         # EP pads zero experts around the stack; padding the FULL all-layers
         # stack would copy every layer's experts (the very transient the
         # fold avoids) — slice this layer first until the pad moves to load
-        # time. DLT_MOE_LAYER_FOLD=0 is the A/B knob (process-start-only,
-        # read at trace time): forces the dynamic-slice formulation.
-        if fold_off or ep_axis is not None:
-            w1, w3, w2 = (slice_layer(w, layer) for w in (w1, w3, w2))
-            stacked = False
+        # time
+        w1, w3, w2 = (slice_layer(w, layer) for w in (w1, w3, w2))
+        stacked = False
     if not use_grouped:
         # the materialized/ragged_dot path works per layer — slice here
         # (these parity paths are not the production bandwidth path)

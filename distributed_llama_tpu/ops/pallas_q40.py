@@ -79,24 +79,6 @@ from ..formats.quants import Q_BLOCK
 LANE = 128
 
 
-def _i8_compiler_params():
-    """Experiment knob (DLT_I8_DIMSEM=1): declare the i8 kernels' grid as
-    (parallel out, arbitrary k). PROCESS-START-ONLY: the env var is read at
-    trace time, so flipping it mid-process is ignored by the jit cache —
-    A/B it with one subprocess per arm (as scripts did). Measured NEUTRAL
-    on the 1B full decode step (3 interleaved subprocess reps: 1.819-1.831
-    plain vs 1.823-1.830 dimsem ms); kept off by default."""
-    import os
-
-    if os.environ.get("DLT_I8_DIMSEM"):
-        return {
-            "compiler_params": pltpu.CompilerParams(
-                dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)
-            )
-        }
-    return {}
-
-
 DEFAULT_TILE_N = 256
 
 
@@ -627,7 +609,6 @@ def q40_matmul_pallas_i8(x, qt, dt, interpret: bool = False) -> jnp.ndarray:
         out_specs=pl.BlockSpec((R, tile_n), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((R, out), jnp.float32),
         interpret=interpret,
-        **_i8_compiler_params(),
     )(x8a, x8b, xs, bs, mask, qt, dt)
     return out2.reshape(*lead, out)
 
@@ -676,7 +657,6 @@ def q40_matmul_pallas_stacked_i8(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, out), jnp.float32),
         interpret=interpret,
-        **_i8_compiler_params(),
     )(jnp.asarray(layer, jnp.int32).reshape(1), x8a, x8b, xs, bs, mask, qt2, dt3)
     return out2.reshape(*lead, out)
 

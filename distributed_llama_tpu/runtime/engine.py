@@ -311,41 +311,28 @@ class InferenceEngine:
         )
         if q80_activations:
             self.cfg = self.cfg.with_(q80_activations=True)
-        if self.cfg.is_hybrid or self.cfg.is_latent:
-            # what assumes a cache can be cut in ways these architectures'
-            # caches have not been taught (below, and the prefix cache further
-            # down) is refused, not served without it: a recurrent state
-            # cannot be cut at a token position; the latent page ([latent |
-            # key], one vector a token) and the held-experts layer are taught
-            # to one chip's paged float pool and the Batcher's programs
+        refusals = self.cfg.cache_refusals
+        if refusals:
             from .paged_kv import resolve_kv_layout as _layout
             from .speculative import resolve_spec_mode as _spec
 
-            if self.cfg.is_latent and kv_layout is None and not os.environ.get("DLT_KV_LAYOUT"):
+            if "contiguous" in refusals and kv_layout is None and not os.environ.get("DLT_KV_LAYOUT"):
                 kv_layout = "paged"  # where nobody chose: the one layout it has
 
             refused = [
-                what for what, asked in (
-                    ("a tp/pp/sp/ep/dp mesh", mesh is not None),
-                    ("int8 KV (--kv-dtype int8)", cache_dtype == "int8"),
-                    ("speculative decoding (--speculative other than off)",
+                (capability, what) for capability, what, asked in (
+                    ("mesh", "a tp/pp/sp/ep/dp mesh", mesh is not None),
+                    ("int8_kv", "int8 KV (--kv-dtype int8)", cache_dtype == "int8"),
+                    ("speculation", "speculative decoding (--speculative other than off)",
                      _spec(speculative, default="off") is not None),
-                    ("the contiguous KV layout (kv_layout other than paged)",
-                     self.cfg.is_latent and _layout(kv_layout) != "paged"),
-                ) if asked
+                    ("contiguous", "the contiguous KV layout (kv_layout other than paged)",
+                     _layout(kv_layout) != "paged"),
+                ) if asked and capability in refusals
             ]
             if refused:
-                why = (
-                    "linear layers (gated-delta, state-space) keep a recurrent "
-                    "state a row, which has no snapshots or rollback yet (ROADMAP R7)"
-                    if self.cfg.is_hybrid else
-                    "latent attention keeps one [latent | key] vector a token in "
-                    "the paged float pool of one chip, and its expert layers hold "
-                    "a share of the experts without an exchange (ROADMAP R5)"
-                )
                 raise ValueError(
-                    f"{ArchType.name(self.header.arch_type)}: {why}: "
-                    + "; ".join(refused) + " "
+                    f"{ArchType.name(self.header.arch_type)}: {refusals[refused[0][0]]}: "
+                    + "; ".join(what for _, what in refused) + " "
                     + ("is" if len(refused) == 1 else "are") + " not supported"
                 )
         self.mesh = mesh
@@ -410,10 +397,7 @@ class InferenceEngine:
         self.reader.release_pages()
         self.rope = build_rope_tables(self.header)
         self.batch = batch
-        # what a prompt chunk's tail past its real tokens is filled with. A
-        # hybrid model's padding is -1: its forward reads "token below 0" as
-        # "do not advance the recurrent state" (transformer.forward_uncompiled)
-        self.pad_token = -1 if self.cfg.is_hybrid else 0
+        self.pad_token = self.cfg.pad_token
         # bytes of one batch row's recurrent state over all linear layers
         # (0: the model keeps none)
         from ..models.params import rec_state_bytes
@@ -520,19 +504,11 @@ class InferenceEngine:
         # nor match each other.
         from .prefix_cache import PrefixCache
 
-        if self.cfg.is_hybrid or self.cfg.is_latent:
+        if "prefix_cache" in self.cfg.cache_refusals:
             from .prefix_cache import resolve_budget_mb
 
             if resolve_budget_mb(prefix_cache_mb, default_mb=0) > 0:
-                self._notice(
-                    "prefix cache off: a linear layer's recurrent state has no "
-                    "snapshots at page boundaries yet (ROADMAP R7), so a cached "
-                    "prefix cannot be resumed"
-                    if self.cfg.is_hybrid else
-                    "prefix cache off: its publish, share and ship programs "
-                    "read a page as k and v heads, and a latent page is one "
-                    "[latent | key] vector a token (ROADMAP R5)"
-                )
+                self._notice(self.cfg.cache_refusals["prefix_cache"])
             prefix_cache_mb = 0
         self.prefix_cache = PrefixCache.build(self, prefix_cache_mb)
         self.last_prefix_hit_tokens = 0  # tokens the most recent prefill
@@ -637,7 +613,7 @@ class InferenceEngine:
         D12)."""
         batched = self.batch > 1 and self.device_decode
         if self.server_role is None:
-            return not (batched and (self.cfg.is_hybrid or self.cfg.is_latent))
+            return not (batched and "solo" in self.cfg.cache_refusals)
         return not batched or self.server_role == "prefill"
 
     def _solo_entry(self, what: str) -> None:
@@ -1342,9 +1318,7 @@ class InferenceEngine:
                 self.cfg, self.params, self.rope, self.cache, toks_dev,
                 pos_dev, logits_mode="last", kv_len=kv_len,
                 page_table=pt_row, page_size=self.page_size,
-                # a hybrid model's state slots are by batch row: this b=1
-                # call is told whose it advances (None = no operand at all)
-                rec_row=jnp.int32(row) if self.cfg.is_hybrid else None,
+                rec_row=self.cfg.rec_row(jnp.int32(row)),
             )
         else:
             from .batch_session import prefill_row

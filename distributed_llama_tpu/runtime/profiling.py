@@ -449,7 +449,7 @@ def lower_entry(engine, key):
                 _sds((), jnp.int32), logits_mode="last", kv_len=kvb,
                 page_table=_sds((1, engine.page_pool.max_slots), jnp.int32),
                 page_size=ps,
-                rec_row=_sds((), jnp.int32) if cfg.is_hybrid else None,
+                rec_row=cfg.rec_row(_sds((), jnp.int32)),
             )
         from .batch_session import prefill_row
 

@@ -29,7 +29,6 @@ import contextlib
 import json
 import threading
 import time
-from dataclasses import dataclass
 from http.server import BaseHTTPRequestHandler, HTTPServer
 
 import numpy as np
@@ -115,57 +114,6 @@ class DeadlineExceeded(Exception):
     before delivery. Mapped to ``504``; the goodput ledger labels every
     token it burned ``deadline`` — an answer nobody was still waiting
     for is pure waste, however correct."""
-
-
-@dataclass
-class CacheItem:
-    end_pos: int
-    role: str
-    content: str
-
-
-class NaiveCache:
-    """DEPRECATED: KV-prefix reuse across chat turns (reference:
-    dllama-api.cpp:296-341). Retired in favor of the engine's radix prefix
-    cache (runtime/prefix_cache.py), which is multi-conversation correct —
-    NaiveCache remembered exactly ONE conversation, so two interleaved
-    users evicted each other's prefix on every turn (the "interleaved-user
-    thrash"). The class is kept for API compatibility and as the reference
-    baseline; the server no longer constructs it. The old per-request miss
-    signal survives as the ``cache_miss`` StepStats counter (a chat request
-    that reused zero prefix tokens)."""
-
-    def __init__(self):
-        self.items: list[CacheItem] = []
-
-    def clear(self):
-        self.items = []
-
-    def push(self, end_pos: int, role: str, content: str):
-        self.items.append(CacheItem(end_pos, role, content))
-
-    def resolve_delta_prompt(self, messages: list[dict]) -> tuple[list[dict], int]:
-        """Returns (delta messages to prefill, start position)."""
-        n = len(self.items)
-        if n == 0:
-            return messages, 0
-        if len(messages) > n:
-            i = 0
-            while i < n:
-                if (
-                    self.items[i].role != messages[i]["role"]
-                    or self.items[i].content != messages[i]["content"]
-                ):
-                    break
-                i += 1
-            if i == n:
-                start = self.items[i - 1].end_pos
-                return messages[i:], start
-        self.cache_miss()
-        return messages, 0
-
-    def cache_miss(self):
-        self.items = []
 
 
 def finish_reason(params: dict, n_prompt: int, n_completion: int, seq_len: int) -> str:
@@ -1422,7 +1370,8 @@ def refuse_state_handoff(engine, args) -> None:
     asked to ship or tier KV for such a model is refused at start-up (the
     engine itself refuses meshes, int8 KV and speculation, and turns the
     prefix cache off with a notice)."""
-    if not (engine.cfg.is_hybrid or engine.cfg.is_latent):
+    why = engine.cfg.cache_refusals.get("handoff")
+    if why is None:
         return
     from ..runtime.kv_tiering import tiers_configured
     from .disagg import resolve_peers, resolve_role
@@ -1435,15 +1384,7 @@ def refuse_state_handoff(engine, args) -> None:
     if tiers_configured():
         asked.append("KV tiering (DLT_KV_*_TIER_*) demotes and promotes prefix-cache pages")
     if asked:
-        raise ValueError(
-            "; ".join(asked) + (
-                ": this architecture's recurrent state has no snapshots or "
-                "hand-off yet (ROADMAP R7): not supported"
-                if engine.cfg.is_hybrid else
-                ": the page programs read a page as k and v heads, and a "
-                "latent page is one vector a token (ROADMAP R5): not supported"
-            )
-        )
+        raise ValueError("; ".join(asked) + f": {why}: not supported")
 
 
 class ApiState:
@@ -2378,7 +2319,6 @@ DLT_ENV_SURFACE = (
     "DLT_GW_RECOVER",
     "DLT_GW_RECOVER_TIMEOUT_S",
     "DLT_HBM_DRIFT_MB",
-    "DLT_I8_DIMSEM",
     "DLT_KV_DISK_TIER_DIR",
     "DLT_KV_DISK_TIER_MB",
     "DLT_KV_DTYPE",
@@ -2390,7 +2330,6 @@ DLT_ENV_SURFACE = (
     "DLT_KV_POOL_MB",
     "DLT_KV_TIER_PEERS",
     "DLT_KV_TRANSPORT",
-    "DLT_MOE_LAYER_FOLD",
     "DLT_NO_NATIVE",
     "DLT_NO_PALLAS",
     "DLT_NO_WARMUP",

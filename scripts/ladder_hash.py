@@ -24,6 +24,7 @@ sys.path.insert(0, args.tree)
 from distributed_llama_tpu import testing
 from distributed_llama_tpu.analysis import graph_audit, graph_diff as gd
 from distributed_llama_tpu.formats.mfile import ArchType, RopeType
+from distributed_llama_tpu.models.config import config_from_header
 from distributed_llama_tpu.runtime.engine import InferenceEngine
 from distributed_llama_tpu.testing import tiny_header, write_tiny_model
 
@@ -44,15 +45,16 @@ if hasattr(graph_audit, "tiny_ssm_hybrid_header"):
     heads["granite_hybrid"] = graph_audit.tiny_ssm_hybrid_header()
 if hasattr(testing, "tiny_latent_header"):
     heads["kimi_k2"] = testing.tiny_latent_header()
-SOLO_OFF = ("olmo_hybrid", "granite_hybrid", "kimi_k2")
 keys = {}
 for name, h in heads.items():
     path = f"{d}/{name}.m"
     write_tiny_model(path, dataclasses.replace(h, seq_len=args.seq_len, orig_seq_len=args.seq_len), seed=0)
+    # what the architecture's cache refuses is not asked for
+    refusals = config_from_header(h).cache_refusals
+    kw = dict(speculative="off") if "speculation" in refusals else {}
+    if "prefix_cache" in refusals:
+        kw["prefix_cache_mb"] = 0
     for dtype in ("float32", "bfloat16"):
-        kw = dict(speculative="off") if name in SOLO_OFF else {}
-        if name == "kimi_k2":
-            kw["prefix_cache_mb"] = 0
         eng = InferenceEngine(path, compute_dtype=dtype, batch=2, max_chunk=16, decode_chunk_size=8, kv_layout="paged", **kw)
         prints = {k: fp.to_dict() for k, fp in sorted(gd.fingerprint_ladder(eng).items())}
         print(name, dtype, len(prints), sha(prints), flush=True)

@@ -421,15 +421,16 @@ def test_hybrid_programs_meet_their_contracts(hybrid_engine):
 
 
 def test_hybrid_f32_dot_budget_counts_the_recurrence(hybrid_engine):
-    """Per period: attention's 2, and for each of the 3 linear layers the
-    gates' projection (1) and the recurrence: the Pallas step's dots are
-    bfloat16 (0), the chunked form has 7."""
+    """Per period: attention's 2, and in the ONE body of the run of 3 linear
+    layers (an inner scan since PR 45) the gates' projection (1) and the
+    recurrence: the Pallas step's dots are bfloat16 (0), the chunked form has
+    7."""
     eng = hybrid_engine
     kernel = eng.cfg.pallas_interpret
     want = {
-        ("batch_decode", 8): 2 + 3 * (1 if kernel else 8),
-        ("prefill_row", 1): 2 + 3 * 8,  # one row against the batch's slots: never the kernel
-        ("prefill_row", 16): 2 + 3 * 8,
+        ("batch_decode", 8): 2 + (1 if kernel else 8),
+        ("prefill_row", 1): 2 + 8,  # one row against the batch's slots: never the kernel
+        ("prefill_row", 16): 2 + 8,
     }
     for (kind, size), budget in want.items():
         entry = ga.LadderEntry(kind, size, 128)

@@ -143,10 +143,13 @@ def test_engine_greedy_identity_ngram(model_path):
     )
 
 
-def test_verify_logits_bit_identical_to_stepwise(model_path):
-    """The verify forward's FETCHED LOGITS at every drafted position equal
-    the per-step decode logits bit for bit — the property greedy acceptance
-    rests on (argmax of equal arrays is equal)."""
+def test_verify_logits_equal_stepwise_at_every_drafted_position(model_path):
+    """What greedy acceptance rests on: at every drafted position the verify
+    forward's FETCHED LOGITS have the argmax of the per-step decode logits,
+    and equal them within float32 rounding. Not to the bit on the CPU: the
+    verify program is the step's at t = 5, and XLA:CPU's dot sums 5 rows in
+    another order than 1 (ONE matmul of the model against its own first row
+    alone differs by 2.4e-7; the logits here by 5e-7 at most, PR 45)."""
     prompt = [3, 17, 99, 4]
     pos = len(prompt) - 1
 
@@ -168,7 +171,10 @@ def test_verify_logits_bit_identical_to_stepwise(model_path):
     ids = np.asarray(ids_dev)[0]
     logits = np.asarray(logits_dev)[0]
     for i in range(5):
-        assert np.array_equal(logits[i], chain_logits[i]), f"position {i} drifted"
+        assert int(np.argmax(logits[i])) == int(np.argmax(chain_logits[i])), f"position {i}"
+        np.testing.assert_allclose(
+            logits[i], chain_logits[i], rtol=0, atol=1e-5, err_msg=f"position {i} drifted"
+        )
     assert accept_greedy(drafts, ids) == 4  # the chain is its own draft
 
 
