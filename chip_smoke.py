@@ -51,6 +51,29 @@ of width 2048 beside a shared one, an eighth of the vocabulary), at 2 rows:
    `/stats` `moe` block and `kv_pool.bytes_per_token`; the decode step holds
    the page-table kernel and `decode_kv_bound` reads `live_pages`.
 
+`--arch laguna` (one chip, run by the builder): Laguna-S-2.1's widths as
+`perfbench/configs/laguna-s-2.1.json` has them (hidden 3072, 48 query heads on
+a full-attention layer and 72 on a sliding-window layer over 8 stored heads of
+128, a window of 512, half of a full layer's head rotated at YaRN's
+frequencies, the gate a head, a dense layer at 12288 and one period of expert
+layers that hold 16 of 256 experts of width 1024 beside a shared one, half the
+vocabulary), at 2 rows:
+
+1. numbers: the window arm's page-table kernel told the window against its
+   gathered view on the same ring (16 rows at positions from under the window
+   to 5,000, 9 queries a stored head); the leading layer and one period, bf16
+   kernels (the flash kernel with the band over the gathered ring, the
+   page-table kernel over the ring and over the pool, the grouped expert
+   kernel) against the same engine's float32 XLA path, teacher-forced logits,
+   640 prompt positions in chunks of 64 and 32 decode steps, so both cross the
+   window's edge (the float32 path is held to the plain reference on the CPU,
+   `tests/z_perfbench/test_laguna_program.py`);
+2. the server with the configuration's own arguments at batch 2
+   (`--speculative off`): the same requests, 0 recompiles, the notices, the
+   `/stats` `window_pool` and `moe` blocks and `kv_pool.bytes_per_token` (the
+   full layers alone); the decode step holds the page-table kernel for both
+   kinds of layer and `decode_kv_bound` reads `live_pages`.
+
 `--arch granite_hybrid` (one chip, run by the builder): Granite-4.0-H-Micro's
 widths as `perfbench/configs/granite-4.0-h-micro.json` has them (hidden 2048,
 64 state-space heads of 64 with a state of 128, 32Q/8KV attention heads of 64
@@ -127,6 +150,21 @@ MAX_EXPERT_DIFF_STD = 0.08
 # of the value; the chip's probe read 0.004-0.016 on outputs of 1-4); two
 # steps of room, where a wrong page or mask reads O(1)
 MAX_LATENT_READ_DIFF = 2**-6
+# perfbench/configs/laguna-s-2.1.json (poolside/Laguna-S-2.1 config.json), the
+# program's header names; depth is the caller's, 16 held where the
+# configuration holds 128 (the float32 comparison dequantizes every held expert)
+LAGUNA_S21 = dict(
+    dim=3072, hidden_dim=12288, n_heads=48, n_kv_heads=8, head_dim=128, vocab_size=50176,
+    seq_len=1048576, n_experts=256, n_active_experts=10, moe_hidden_dim=1024,
+    rope_theta=500000.0, rope_scaling_factor=128.0, rope_scaling_orig_max_seq_len=8192,
+    full_attn_interval=4, full_attn_offset=0, window=512, window_heads=72, rotary_share=0.5,
+    n_dense_layers=1, experts_held=16, expert_first=0, n_shared_experts=1, routed_scale=2.5,
+)
+# --rehearse: `testing.tiny_window_header`'s own widths, a window of 24
+TINY_LAGUNA = dict(window=24, vocab_size=256, seq_len=256)
+# the window arm's kernel against its gathered view, as the latent arm's: the
+# same bfloat16 products in another order, rounded to bfloat16 on both sides
+MAX_WINDOW_READ_DIFF = 2**-6
 # perfbench/configs/granite-4.0-h-micro.json (ibm-granite/granite-4.0-h-micro
 # config.json), the program's header names; depth is the caller's
 GRANITE_4HM = dict(
@@ -227,10 +265,12 @@ def finish(device: dict) -> "NoReturn":
 def build_model(shape: dict, n_layers: int, seed: int) -> str:
     from distributed_llama_tpu.formats.mfile import ArchType, RopeType, tensor_walk
     from distributed_llama_tpu.testing import (
-        tiny_header, tiny_latent_header, tiny_ssm_header, write_tiny_model,
+        tiny_header, tiny_latent_header, tiny_ssm_header, tiny_window_header, write_tiny_model,
     )
 
-    if "kv_lora_rank" in shape:
+    if "window" in shape:
+        h = tiny_window_header(**{**shape, "n_layers": n_layers})
+    elif "kv_lora_rank" in shape:
         h = tiny_latent_header(**{**shape, "n_layers": n_layers})
     elif "full_attn_offset" in shape:
         h = tiny_ssm_header(**{**shape, "n_layers": n_layers})
@@ -843,6 +883,130 @@ def phase_latent_server(model: str, tokenizer: str, rehearse: bool) -> None:
     httpd.server_close()
 
 
+# -- the laguna branch ------------------------------------------------------------
+
+
+def phase_window_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llama_tpu.models import kv_arms
+    from distributed_llama_tpu.models.params import KVCache
+    from distributed_llama_tpu.runtime.engine import InferenceEngine
+
+    chunk = 16 if rehearse else 64
+
+    def engine(dtype):
+        return InferenceEngine(
+            model, compute_dtype=dtype, batch=1, max_chunk=chunk, max_seq_len=256 if rehearse else 1024,
+            kv_layout="paged", device_decode=False,
+        )
+
+    # (a) the window arm's two reads of one ring: the page-table kernel told
+    # the window (a decode step: rows at positions of their own) against the
+    # gathered view with the band, bfloat16 pages
+    eng = engine("bfloat16")
+    cfg = eng.cfg.with_(seq_len=8192)
+    free(eng)
+    ps, rows, slots = 16, 2 if rehearse else 16, cfg.window_ring // 16
+    own = np.random.default_rng(46)
+    bf = lambda *sh: jnp.asarray(own.standard_normal(sh, dtype=np.float32)).astype(jnp.bfloat16)  # noqa: E731
+    ring_shape = (2, rows * slots, ps, cfg.n_kv_heads, cfg.head_dim)
+    wk, wv = bf(*ring_shape), bf(*ring_shape)
+    q = bf(rows, 1, cfg.window_heads, cfg.head_dim)
+    k, v = bf(rows, 1, cfg.n_kv_heads, cfg.head_dim), bf(rows, 1, cfg.n_kv_heads, cfg.head_dim)
+    pos = jnp.asarray(np.linspace(5, 200 if rehearse else 5000, rows).astype(np.int32))
+    arm = jax.jit(
+        lambda cfg, wk, wv, q, k, v, pos: kv_arms.window_arm(
+            cfg, KVCache(k=wk[:, :1], v=wv[:, :1], wk=wk, wv=wv),
+            kv_arms.CacheAddr(layer=jnp.int32(1), page_size=ps, window=True,
+                              page_table=jnp.zeros((rows, 1), jnp.int32)),
+            q, k, v, pos[:, None], pos)[0].astype(jnp.float32),
+        static_argnums=0,
+    )
+    serves = kv_arms._paged_kernel_serves(cfg, ring_shape, cfg.window_heads, cfg.n_kv_heads, 1)
+    fast = arm(cfg, wk, wv, q, k, v, pos)
+    slow = arm(cfg.with_(use_pallas=False, pallas_interpret=False), wk, wv, q, k, v, pos)
+    diff = float(jnp.max(jnp.abs(fast - slow)) / jnp.max(jnp.abs(slow)))
+    say("numbers", check="window arm: page-table kernel vs gathered view, one ring",
+        rows=rows, ring=[slots, ps], window=cfg.window, kernel_serves=bool(serves),
+        finite=bool(jnp.isfinite(fast).all()), max_diff_of_largest=round(diff, 5),
+        bound=MAX_WINDOW_READ_DIFF)
+    if not (serves and diff <= MAX_WINDOW_READ_DIFF):
+        fail(f"numbers/window arm: kernel serves {serves}, max diff {diff}")
+
+    # (b) the whole step, both kinds of layer: bf16 kernels vs float32 XLA,
+    # prompt chunks and decode steps that cross the window's edge
+    n_pre, n_dec = (48, 8) if rehearse else (640, 32)
+    rng = np.random.default_rng(19)
+    ids = [int(x) for x in rng.integers(1, cfg.vocab_size, n_pre + n_dec)]
+    logits = {}
+    for dtype in ("float32", "bfloat16", "bfloat16-xla"):
+        eng = engine(dtype.split("-")[0])
+        if dtype.endswith("-xla"):
+            # the same bfloat16 arithmetic with no Pallas kernel: what the
+            # kernels add to bfloat16's own rounding is the two lines' distance
+            eng.cfg = eng.cfg.with_(use_pallas=False, pallas_interpret=False)
+        eng._ensure_pages_all_rows(0, n_pre + n_dec)
+        out = [eng.forward_tokens(ids[c : c + chunk], c, logits_mode="all")[0]
+               for c in range(0, n_pre, chunk)]
+        for i in range(n_dec):
+            out.append(eng.forward_tokens([ids[n_pre + i]], n_pre + i)[0][None])
+        logits[dtype] = np.concatenate(out)
+        free(eng)
+    want, got, plain = logits["float32"], logits["bfloat16"], logits["bfloat16-xla"]
+    std = float(want.std())
+    for name, sl in (("prefill", slice(0, n_pre)), ("decode", slice(n_pre, None))):
+        agree = float((want[sl].argmax(-1) == got[sl].argmax(-1)).mean())
+        diff = np.abs(want[sl] - got[sl]).max(axis=1) / std
+        xla = np.abs(want[sl] - plain[sl]).max(axis=1) / std
+        say("numbers", check=f"windowed model bf16 kernels vs float32 XLA, {name}",
+            positions=int(want[sl].shape[0]), window=cfg.window, top1_agreement=round(agree, 3),
+            max_diff_std=round(float(diff.max()), 4), median_diff_std=round(float(np.median(diff)), 4),
+            bf16_without_kernels=[round(float(xla.max()), 4), round(float(np.median(xla)), 4)],
+            last_positions_diff_std=[round(float(d), 3) for d in diff[-8:]],
+            bounds=[MIN_TOP1_AGREEMENT, MAX_LOGIT_DIFF_STD])
+        # a flipped expert pick moves one position's logits by more than the
+        # dense models' bound (tests/z_perfbench/test_laguna_program.py), so
+        # the bound is held by the MEDIAN position; and where bfloat16 alone,
+        # without a kernel, already reads over it, the kernels are held to
+        # that reading with a quarter of room. Measured on the v5e (PR 46,
+        # seed 7): prompts top-1 0.969, median 0.074 (without kernels 0.063),
+        # widest 0.50 (0.48); one row's decode steps top-1 0.844, median 0.382
+        # (0.347), widest 0.73 (0.71): bfloat16's own, not the kernels'
+        median, without = float(np.median(diff)), float(np.median(xla))
+        if agree < MIN_TOP1_AGREEMENT or median > max(MAX_LOGIT_DIFF_STD, 1.25 * without):
+            fail(f"numbers/windowed {name}: top-1 {agree}, median diff {median} stds "
+                 f"(bfloat16 without kernels {without})")
+
+
+def phase_window_server(model: str, tokenizer: str, rehearse: bool) -> None:
+    # a step's kernels: wqkv, wo a layer kind apart, attention's (the
+    # page-table kernel in a decode step, flash in a prompt's chunk, for both
+    # kinds), the dense w13 and w2, the shared expert's two and the three
+    # grouped calls in a window and in a full expert layer's body, the head
+    # (rehearsed, the tiny widths' three wo, contractions of 128 and 192, and
+    # the window layers' wqkv, 320 outputs, miss the stacked kernels' rule)
+    need = 3 * 3 + 2 + 2 * 5 + 1 - (4 if rehearse else 0)
+    httpd, engine, stats = serve_two_rows(
+        model, tokenizer, rehearse, {"batch_decode": need, "prefill_row": need}, "window_pool")
+    if engine.decode_kv_bound != "live_pages":
+        fail(f"decode_kv_bound: {engine.decode_kv_bound}")
+    cfg, ring, moe, pool = (engine.cfg, stats.get("window_pool") or {}, stats.get("moe") or {},
+                            stats.get("kv_pool") or {})
+    if (ring.get("window"), ring.get("layers"), ring.get("rows")) != (cfg.window, cfg.n_win_layers, 2) \
+            or not ring.get("kv_positions_live") or ring.get("ring_positions") != cfg.window_ring:
+        fail(f"/stats window_pool: {ring}")
+    if (moe.get("held"), moe.get("experts")) != (cfg.n_experts_held, cfg.n_experts) or not moe.get("expert_pairs"):
+        fail(f"/stats moe: {moe}")
+    itemsize = 2 if cfg.cache_dtype == "bfloat16" else 4
+    if pool.get("bytes_per_token") != 2 * cfg.n_kv_layers * cfg.n_kv_heads * cfg.head_dim * itemsize:
+        fail(f"/stats kv_pool.bytes_per_token: {pool.get('bytes_per_token')}")
+    httpd.shutdown()
+    httpd.server_close()
+
+
 # -- the granite_hybrid branch ----------------------------------------------------
 
 
@@ -1033,7 +1197,8 @@ def main() -> None:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--rehearse", action="store_true")
-    ap.add_argument("--arch", choices=("qwen3", "kimi_k2", "granite_hybrid"), default="qwen3")
+    ap.add_argument("--arch", choices=("qwen3", "kimi_k2", "granite_hybrid", "laguna"),
+                    default="qwen3")
     args = ap.parse_args()
 
     os.environ["DLT_SANITIZERS"] = "1"  # recompile sentinel + host-sync guard
@@ -1097,6 +1262,12 @@ def main() -> None:
         period = shape["full_attn_interval"]
         phases = [("ssm_numbers", phase_ssm_numbers, period),
                   ("ssm_server", phase_ssm_server, period if args.rehearse else 40)]
+    if args.arch == "laguna":
+        # the leading layer and one period: every kind of layer, 1.2 GB
+        # (rehearsed: `testing.tiny_window_header`'s own tiny widths)
+        shape = TINY_LAGUNA if args.rehearse else LAGUNA_S21
+        phases = [("window_numbers", phase_window_numbers, 5),
+                  ("window_server", phase_window_server, 5)]
     for name, phase, depth in phases:
         try:
             tokenizer = build_tokenizer(shape["vocab_size"])

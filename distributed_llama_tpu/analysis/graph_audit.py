@@ -479,6 +479,8 @@ def f32_dot_budget(engine, entry: LadderEntry) -> int:
     accidental upcast of a quantized matmul path."""
     if engine.cfg.is_latent:
         return latent_f32_dots(engine)
+    if engine.cfg.window:
+        return windowed_f32_dots(engine)
     return 2 * attention_sites(engine, entry) + recurrence_f32_dots(engine, entry)
 
 
@@ -490,6 +492,19 @@ def latent_f32_dots(engine) -> int:
     scan; that scan's body also holds the router's float32 logits."""
     cfg = engine.cfg
     return cfg.n_dense_layers + (2 if cfg.n_moe_layers else 0)
+
+
+def windowed_f32_dots(engine) -> int:
+    """The float32 dots of a windowed model's programs: an attention body a
+    leading layer and a run of the period (`LayerPlan.runs`: the window
+    layers' scan, the full layer's call), each with the softmax-side two and,
+    where the model gates attention's output, the gate's float32 projection;
+    an expert layer's body also holds the router's float32 logits."""
+    cfg = engine.cfg
+    plan = cfg.layer_plan
+    bodies = list(range(plan.lead)) + [plan.lead + run.first for run in plan.runs]
+    routers = sum(plan.ffns[l] == "held" for l in bodies)
+    return (2 + int(cfg.attn_gate)) * len(bodies) + routers
 
 
 def recurrence_f32_dots(engine, entry: LadderEntry) -> int:
@@ -739,7 +754,9 @@ def donation_problems(engine) -> list:
 
     def check(name, lowered):
         problems.extend(donation_check(name, lowered))
-        if engine.cfg.is_hybrid:
+        if engine.cfg.is_hybrid or engine.cfg.window:
+            # a second kind of cache beside k and v (a recurrent state, a
+            # window layer's rings): every leaf of it has to alias too
             problems.extend(donated_leaf_check(name, lowered, n_leaves))
 
     if engine.use_pipeline:
@@ -1062,7 +1079,7 @@ def add_engine_args(p) -> None:
     and the audited config can never drift apart syntactically."""
     p.add_argument("--model", default=None, help=".m file (default: tiny synthetic)")
     p.add_argument(
-        "--arch", choices=["llama", "olmo_hybrid", "kimi_k2", "granite_hybrid"],
+        "--arch", choices=["llama", "olmo_hybrid", "kimi_k2", "granite_hybrid", "laguna"],
         default="llama",
         help="the tiny synthetic model's architecture (ignored with --model): "
         "olmo_hybrid = two periods of three gated-delta layers and a full one, "
@@ -1176,6 +1193,10 @@ def engine_from_args(args, workdir: str):
             from ..testing import tiny_latent_header
 
             hdr = tiny_latent_header()
+        elif getattr(args, "arch", "llama") == "laguna":
+            from ..testing import tiny_window_header
+
+            hdr = tiny_window_header(seq_len=128)
         elif mesh is not None:
             # layer/head counts must divide over the mesh axes
             hdr = tiny_header(

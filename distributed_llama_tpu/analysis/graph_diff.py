@@ -112,7 +112,7 @@ def config_key(engine) -> str:
     # second kind of cache; latent attention and held experts): its own
     # family, keyed by the architecture
     arch = ""
-    if cfg.is_hybrid or cfg.is_latent:
+    if cfg.is_hybrid or cfg.is_latent or cfg.window:
         from ..formats.mfile import ArchType
 
         arch = "_" + ArchType.name(cfg.arch_type)

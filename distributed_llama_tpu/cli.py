@@ -90,6 +90,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--net-turbo", default=None, help=argparse.SUPPRESS)
     p.add_argument("--max-batch-size", "--nbatches", dest="max_chunk", type=int, default=32)
     p.add_argument("--prefill-chunk-size", type=int, default=0)
+    p.add_argument(
+        "--max-prompt-tokens", type=int, default=0,
+        help="longest prompt the server admits (a longer one is a client "
+        "error, like one past the context window); the warm plan then holds "
+        "prompt-chunk programs up to the KV bucket that covers it instead of "
+        "up to --max-seq-len. 0 = the context window",
+    )
     p.add_argument("--prefill-chunk-threshold", type=int, default=128)
     p.add_argument(
         "--prefix-cache-mb", type=int, default=-1,
@@ -271,6 +278,7 @@ def make_engine(args, server_role: str | None = None) -> InferenceEngine:
             cache_dtype=args.cache_dtype,
             max_seq_len=args.max_seq_len,
             max_chunk=max_chunk,
+            max_prompt_len=getattr(args, "max_prompt_tokens", 0) or None,
             mesh=mesh,
             batch=batch,
             device_decode=not getattr(args, "host_decode", False),

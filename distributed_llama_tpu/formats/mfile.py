@@ -98,6 +98,19 @@ K_EMBEDDING_MULT_MILLI = 46
 K_ATTENTION_MULT_MICRO = 47
 K_RESIDUAL_MULT_MILLI = 48
 K_LOGITS_SCALING_MILLI = 49
+# laguna (also past the reference's): sliding-window attention layers beside
+# the full ones (the period and the full layer's place ride keys 22 and 43):
+# the window in positions, the window layers' query heads (the full layers'
+# ride key 5), their RoPE base (the full layers': key 12, with YaRN's factor,
+# original length and betas on keys 14, 17, 34 and 35), the share of a full
+# layer's head that is rotated, in thousandths, and whether a sigmoid gate a
+# head multiplies the attention's output. The expert layers ride kimi_k2's
+# keys (21, 38-42); there is no selection bias
+K_WINDOW = 50
+K_WINDOW_HEADS = 51
+K_WINDOW_ROPE_THETA = 52
+K_ROTARY_MILLI = 53
+K_ATTN_GATE = 54
 
 
 class ArchType:
@@ -107,11 +120,12 @@ class ArchType:
     OLMO_HYBRID = 0xABCD03
     KIMI_K2 = 0xABCD04
     GRANITE_HYBRID = 0xABCD05
+    LAGUNA = 0xABCD06
 
     _NAMES = {
         LLAMA: "llama", QWEN3: "qwen3", QWEN3_MOE: "qwen3_moe",
         OLMO_HYBRID: "olmo_hybrid", KIMI_K2: "kimi_k2",
-        GRANITE_HYBRID: "granite_hybrid",
+        GRANITE_HYBRID: "granite_hybrid", LAGUNA: "laguna",
     }
 
     @classmethod
@@ -202,6 +216,20 @@ class ModelHeader:
     attention_mult: float = 0.0
     residual_mult: float = 1.0
     logits_scaling: float = 1.0
+    # laguna: layer l is full attention where l % interval == offset and a
+    # sliding-window layer otherwise: `window_heads` query heads over the same
+    # `n_kv_heads`, each query attending over the last `window` positions
+    # (its own included), RoPE at `window_rope_theta` over the whole head; a
+    # full layer rotates the first `rotary_share` of a head at YaRN's
+    # frequencies. `attn_gate`: a sigmoid gate a head, projected from the
+    # layer's normed input, multiplies the attention's output before `wo`.
+    # The feed-forward is kimi_k2's (`n_dense_layers` dense, then a held
+    # share of sigmoid-routed experts beside shared ones) without the bias
+    window: int = 0
+    window_heads: int = 0
+    window_rope_theta: float = 10000.0
+    rotary_share: float = 1.0
+    attn_gate: int = 0
     header_bytes: int = 0  # magic + size field + kv pairs
     file_bytes: int = 0
 
@@ -235,6 +263,23 @@ class ModelHeader:
     def is_latent(self) -> bool:
         return self.arch_type == ArchType.KIMI_K2
 
+    @property
+    def is_windowed(self) -> bool:
+        return self.arch_type == ArchType.LAGUNA
+
+    @property
+    def holds_experts(self) -> bool:
+        """The expert layers hold a share of the published experts."""
+        return self.is_latent or self.is_windowed
+
+    def layer_is_window(self, layer: int) -> bool:
+        p = self.full_attn_interval
+        return self.is_windowed and layer % p != self.full_attn_offset % p
+
+    def layer_heads(self, layer: int) -> int:
+        """Query heads of attention layer `layer`."""
+        return self.window_heads if self.layer_is_window(layer) else self.n_heads
+
     def finalize(self, max_seq_len: int = 0) -> "ModelHeader":
         """Apply derived-field defaults (reference: src/llm.cpp:105-117)."""
         self.orig_seq_len = self.seq_len
@@ -250,13 +295,33 @@ class ModelHeader:
             if not (self.q_lora_rank and self.kv_lora_rank and self.qk_nope_head_dim
                     and self.qk_rope_head_dim and self.v_head_dim):
                 raise ValueError("kimi_k2: the header lacks latent attention's sizes")
+        if self.is_windowed:
+            self.rope_type = RopeType.FALCON  # halves, inside the rotated dims
+            p = self.full_attn_interval
+            if p < 2 or not 0 <= self.full_attn_offset < p:
+                raise ValueError(
+                    f"laguna: no full layer {self.full_attn_offset} in a period of {p}"
+                )
+            if (self.n_layers - self.n_dense_layers) % p:
+                raise ValueError(
+                    f"laguna: the {self.n_layers - self.n_dense_layers} layers after the "
+                    f"{self.n_dense_layers} leading ones are not whole periods of {p}"
+                )
+            if any(self.layer_is_window(l) for l in range(self.n_dense_layers)):
+                raise ValueError("laguna: a leading (dense) layer has to be a full-attention one")
+            if self.window < 1 or not self.window_heads or self.window_heads % self.n_kv_heads:
+                raise ValueError("laguna: the header lacks the window layers' sizes")
+            if int(self.head_dim * self.rotary_share) % 2 or not 0 < self.rotary_share <= 1:
+                raise ValueError(f"laguna: a rotary share of {self.rotary_share} of a head")
+        if self.holds_experts:
+            name = ArchType.name(self.arch_type)
             if not 0 < self.experts_held <= self.n_experts - self.expert_first:
                 raise ValueError(
-                    f"kimi_k2: experts {self.expert_first}..+{self.experts_held} "
+                    f"{name}: experts {self.expert_first}..+{self.experts_held} "
                     f"are not among the {self.n_experts} published"
                 )
             if not 0 <= self.n_dense_layers < self.n_layers or not self.moe_hidden_dim:
-                raise ValueError("kimi_k2: the header lacks the expert layers' sizes")
+                raise ValueError(f"{name}: the header lacks the expert layers' sizes")
         if self.is_ssm:
             self.rope_type = RopeType.NONE
             self.lin_key_heads = self.lin_value_heads
@@ -299,6 +364,8 @@ class TensorSpec:
     # sw1|sw2|sw3 (the shared experts, as one of their summed width)
     # granite_hybrid state-space layers: ssm_in|ssm_dt|ssm_conv|ssm_conv_bias|
     # ssm_a_log|ssm_dt_bias|ssm_d|ssm_norm|ssm_out
+    # laguna: q|k|v|wo at the layer's own head count, attn_gate, and kimi_k2's
+    # feed-forward roles without moe_bias
     layer: int  # -1 for global tensors
     expert: int  # -1 for non-expert tensors
     shape: tuple  # logical (out_features, in_features) or (n,) — torch row-major
@@ -401,17 +468,23 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
             )
             add("wo", l, -1, (h.dim, h.n_heads * h.v_head_dim), wt)
         else:
-            add("q", l, -1, (h.q_dim, h.dim), wt)
+            # a window layer has its own count of query heads (laguna)
+            q_dim = h.layer_heads(l) * h.head_dim
+            add("q", l, -1, (q_dim, h.dim), wt)
             add("k", l, -1, (h.kv_dim, h.dim), wt)
             add("v", l, -1, (h.kv_dim, h.dim), wt)
-            add("wo", l, -1, (h.dim, h.q_dim), wt)
-        if h.is_latent and l >= h.n_dense_layers:
+            add("wo", l, -1, (h.dim, q_dim), wt)
+            if h.attn_gate:
+                # the output gate a head, small and decisive: float32
+                add("attn_gate", l, -1, (h.layer_heads(l), h.dim), FloatType.F32)
+        if h.holds_experts and l >= h.n_dense_layers:
             # the router scores ALL the published experts (its selection
             # bias beside it); the stacks hold this file's share alone,
             # expert e of the file being published expert expert_first + e
             ff = h.moe_hidden_dim
             add("moe_gate", l, -1, (h.n_experts, h.dim), FloatType.F32)
-            add("moe_bias", l, -1, (h.n_experts,), FloatType.F32)
+            if h.is_latent:  # laguna's router has no selection bias
+                add("moe_bias", l, -1, (h.n_experts,), FloatType.F32)
             for e in range(h.experts_held):
                 add("w1", l, e, (ff, h.dim), wt)
                 add("w2", l, e, (h.dim, ff), wt)
@@ -420,7 +493,7 @@ def tensor_walk(h: ModelHeader) -> list[TensorSpec]:
             add("sw1", l, -1, (sff, h.dim), wt)
             add("sw2", l, -1, (h.dim, sff), wt)
             add("sw3", l, -1, (sff, h.dim), wt)
-        elif h.is_latent:
+        elif h.holds_experts:
             add("w1", l, -1, (h.hidden_dim, h.dim), wt)
             add("w2", l, -1, (h.dim, h.hidden_dim), wt)
             add("w3", l, -1, (h.hidden_dim, h.dim), wt)
@@ -582,6 +655,11 @@ def _parse_header(buf, file_size: int) -> ModelHeader:
         K_ATTENTION_MULT_MICRO: lambda v: setattr(h, "attention_mult", v / 1e6),
         K_RESIDUAL_MULT_MILLI: lambda v: setattr(h, "residual_mult", v / 1000.0),
         K_LOGITS_SCALING_MILLI: lambda v: setattr(h, "logits_scaling", v / 1000.0),
+        K_WINDOW: lambda v: setattr(h, "window", v),
+        K_WINDOW_HEADS: lambda v: setattr(h, "window_heads", v),
+        K_WINDOW_ROPE_THETA: lambda v: setattr(h, "window_rope_theta", float(v)),
+        K_ROTARY_MILLI: lambda v: setattr(h, "rotary_share", v / 1000.0),
+        K_ATTN_GATE: lambda v: setattr(h, "attn_gate", v),
     }
     for i in range(0, n_kv, 2):
         key, value = vals[i], vals[i + 1]

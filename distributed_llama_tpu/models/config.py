@@ -31,7 +31,7 @@ class LayerPlan:
     mixer and a feed-forward, each with a stack of weights (and a cache)
     that holds the layers of its kind alone, in order."""
 
-    mixers: tuple  # a layer: "attention" | "gated_delta" | "ssd" | "latent"
+    mixers: tuple  # a layer: "attention" | "window" | "gated_delta" | "ssd" | "latent"
     ffns: tuple  # a layer: "dense" | "moe" | "held"
     lead: int
     period: int
@@ -157,14 +157,28 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_hidden_dim: int = 0
     routed_scale: float = 1.0
+    # sliding-window attention layers (laguna) in the places of a period that
+    # `full_attn_interval` / `full_attn_offset` leave: `window_heads` query
+    # heads over the model's kv heads, a query at p attending over positions
+    # (p - window, p], with a RoPE table of their own (`RopeTables.window`).
+    # Their k and v live in a RING of `window_ring` positions a batch row
+    # (`KVCache.wk`; the engine sizes it: window + a prompt chunk + a page).
+    # 0 = no such layer, and every program is what it was before these fields
+    window: int = 0
+    window_heads: int = 0
+    window_ring: int = 0
+    # a sigmoid gate a head, projected from the layer's normed input,
+    # multiplies attention's output before `wo` (both attention kinds)
+    attn_gate: bool = False
 
     @property
     def layer_plan(self) -> "LayerPlan":
         """The stack as `models/transformer._walk` takes it: the ONE source of
         the layer pattern (`layer_kinds` is read off it)."""
         p, full = self.full_attn_interval, self.full_attn_offset % self.full_attn_interval
+        other = "window" if self.window else self.lin_kind
         mixers = tuple(
-            "latent" if self.is_latent else "attention" if l % p == full else self.lin_kind
+            "latent" if self.is_latent else "attention" if l % p == full else other
             for l in range(self.n_layers)
         )
         if self.n_experts_held:
@@ -181,22 +195,32 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> tuple:
-        """A name per layer: "linear" | "full" by the token mixer, or, where
-        the feed-forward is what differs (latent models), "dense" | "moe"."""
+        """A name per layer, read off the plan: "full" | "window" | "linear"
+        by the token mixer, or, where every mixer is latent attention and the
+        feed-forward is what differs, "dense" | "moe"."""
         plan = self.layer_plan
-        if self.is_latent:
+        if set(plan.mixers) == {"latent"}:
             return tuple("dense" if f == "dense" else "moe" for f in plan.ffns)
-        return tuple("full" if m == "attention" else "linear" for m in plan.mixers)
+        names = {"attention": "full", "window": "window"}
+        return tuple(names.get(m, "linear") for m in plan.mixers)
+
+    def _n_mixers(self, *kinds) -> int:
+        return sum(m in kinds for m in self.layer_plan.mixers)
 
     @property
     def n_kv_layers(self) -> int:
-        """Layers that keep a KV cache (the pool's leading axis)."""
-        return self.n_layers // self.full_attn_interval
+        """Layers that keep their KV in the paged pool (its leading axis)."""
+        return self._n_mixers("attention", "latent")
+
+    @property
+    def n_win_layers(self) -> int:
+        """Sliding-window layers: their KV is a ring a row (`KVCache.wk`)."""
+        return self._n_mixers("window")
 
     @property
     def n_rec_layers(self) -> int:
         """Layers that keep a recurrent state a row instead."""
-        return self.n_layers - self.n_kv_layers
+        return self._n_mixers("gated_delta", "ssd")
 
     @property
     def is_hybrid(self) -> bool:
@@ -216,9 +240,10 @@ class ModelConfig:
 
     def rec_row(self, row):
         """The `rec_row` operand of a one-row call for batch row `row`: a
-        recurrent state's slots are by batch row, so the call is told whose it
-        advances; None (no operand at all) where the model keeps no state."""
-        return row if self.is_hybrid else None
+        recurrent state's slots, and a window layer's ring, are by batch row,
+        so the call is told whose it advances; None (no operand at all) where
+        the model keeps nothing by row."""
+        return row if self.is_hybrid or self.window else None
 
     @property
     def cache_refusals(self) -> dict:
@@ -255,6 +280,19 @@ class ModelConfig:
                 "[latent | key] vector a token (ROADMAP R5)",
                 "the page programs read a page as k and v heads, and a "
                 "latent page is one vector a token (ROADMAP R5)",
+            )
+        if self.window:
+            return refusals(
+                ("mesh", "int8_kv", "speculation", "contiguous", "solo"),
+                "sliding-window layers keep their k and v in a ring of "
+                "window + chunk positions a batch row beside the full "
+                "layers' paged float pool of one chip, which has no "
+                "rollback and no second page table yet (ROADMAP R4)",
+                "prefix cache off: its publish, share and ship programs "
+                "move a row's pages of the one pool, and a window layer's "
+                "ring holds the last positions alone (ROADMAP R4)",
+                "the page programs move a row's pages of the one pool, and a "
+                "window layer's ring holds the last positions alone (ROADMAP R4)",
             )
         return {}
 
@@ -357,8 +395,8 @@ def config_from_header(
         norm_epsilon=h.norm_epsilon,
         compute_dtype=compute_dtype,
         cache_dtype=cache_dtype,
-        full_attn_interval=h.full_attn_interval if h.is_hybrid else 1,
-        full_attn_offset=h.full_attn_offset if h.is_hybrid else -1,
+        full_attn_interval=h.full_attn_interval if h.is_hybrid or h.is_windowed else 1,
+        full_attn_offset=h.full_attn_offset if h.is_hybrid or h.is_windowed else -1,
         lin_heads=h.lin_value_heads,
         lin_key_dim=h.lin_key_head_dim,
         lin_value_dim=h.lin_value_head_dim,
@@ -366,6 +404,27 @@ def config_from_header(
         lin_neg_eigval=bool(h.lin_neg_eigval),
         **(_latent_fields(h) if h.is_latent else {}),
         **(_ssm_fields(h) if h.is_ssm else {}),
+        **(_window_fields(h) if h.is_windowed else {}),
+    )
+
+
+def _held_fields(h: ModelHeader) -> dict:
+    return dict(
+        n_dense_layers=h.n_dense_layers,
+        n_experts_held=h.experts_held,
+        expert_first=h.expert_first,
+        n_shared_experts=h.n_shared_experts,
+        moe_hidden_dim=h.moe_hidden_dim,
+        routed_scale=float(h.routed_scale),
+    )
+
+
+def _window_fields(h: ModelHeader) -> dict:
+    return dict(
+        window=h.window,
+        window_heads=h.window_heads,
+        attn_gate=bool(h.attn_gate),
+        **_held_fields(h),
     )
 
 
@@ -392,10 +451,5 @@ def _latent_fields(h: ModelHeader) -> dict:
         qk_rope_dim=h.qk_rope_head_dim,
         v_head_dim=h.v_head_dim,
         attn_scale=float(h.head_dim**-0.5 * m * m),
-        n_dense_layers=h.n_dense_layers,
-        n_experts_held=h.experts_held,
-        expert_first=h.expert_first,
-        n_shared_experts=h.n_shared_experts,
-        moe_hidden_dim=h.moe_hidden_dim,
-        routed_scale=float(h.routed_scale),
+        **_held_fields(h),
     )

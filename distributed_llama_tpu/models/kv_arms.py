@@ -70,10 +70,18 @@ class CacheAddr(NamedTuple):
     # against the whole batch's state). None: batch row r is slot r.
     latent: bool = False  # static: the pool's page is one [latent | key]
     # vector a token (latent attention), not k and v heads
+    window: bool = False  # static: a sliding-window layer: its k and v live in
+    # the ring a batch row owns (`KVCache.wk`), not in the paged pool
 
 
 def select_arm(addr: CacheAddr):
-    """The arm for what `addr` carries — the one list of cache layouts."""
+    """The arm for what `addr` carries — the one list of cache layouts. A
+    window changes WHERE a layer's k and v live (a ring of the last positions
+    a batch row, written and read modulo its length) and what a query sees
+    (the last `cfg.window` positions); the page-table kernel and the flash
+    kernel are the paged arm's, told the window."""
+    if addr.window:
+        return window_arm
     if addr.latent:
         return latent_arm
     if addr.page_table is not None:
@@ -98,9 +106,12 @@ def _softmax_scale(cfg):
     return cfg.attn_scale or None
 
 
-def _attention_auto(cfg, q, k_view, v_view, positions, pos_start, scale=None):
+def _attention_auto(cfg, q, k_view, v_view, positions, pos_start, scale=None, band=None):
     """Pick the attention implementation for this (static) shape (`scale`:
-    the softmax scale where it is not the model's `_softmax_scale`):
+    the softmax scale where it is not the model's `_softmax_scale`; `band`:
+    a window layer's (window, col_offset [b]): a query at p sees the last
+    `window` positions alone, and the view's column 0 holds position
+    `col_offset`):
 
     * prefill-sized q on a bf16 cache with the Pallas path enabled -> blocked
       flash kernel (ops/pallas_attention.py) — no O(t*S) score tensor;
@@ -121,10 +132,12 @@ def _attention_auto(cfg, q, k_view, v_view, positions, pos_start, scale=None):
         and k_view.dtype == jnp.bfloat16
         and flash_attention_aligned(q, k_view, t)
     ):
+        told = {} if band is None else dict(window=band[0], col_offset=band[1][0])
         return flash_attention(
-            q, k_view, v_view, pos_start, scale=scale, interpret=cfg.pallas_interpret
+            q, k_view, v_view, pos_start, scale=scale, interpret=cfg.pallas_interpret, **told
         )
-    return gqa_attention(q, k_view, v_view, positions, scale=scale)
+    told = {} if band is None else dict(window=band[0], col_offset=band[1])
+    return gqa_attention(q, k_view, v_view, positions, scale=scale, **told)
 
 
 def _fused_paged_eligible(cfg, heads_dim, n_kv: int, t: int, ps: int) -> bool:
@@ -226,6 +239,11 @@ def decode_reads_live_pages(cfg, cache, rows: int, max_slots: int | None, mesh) 
     reads shapes, the pool's dtype and the arm's gate."""
     if mesh is not None or max_slots is None or cache.quantized:
         return False
+    if cfg.window and not _paged_kernel_serves(
+        cfg, cache.wk.shape, cfg.window_heads, cfg.n_kv_heads, 1
+    ):
+        return False  # the window layers' gathered view is the ring's, not
+        # the bound's, but a step with two kinds of read is not claimed here
     return (
         decode_kernel_serves(cfg, cache.k, "batch_decode", rows, max_slots)
         and paged_prefetch_words(rows, max_slots) <= PAGED_PREFETCH_WORDS
@@ -442,6 +460,73 @@ def latent_arm(cfg, cache, addr, q, k, v, positions, pos_start):
     pages = jnp.maximum(jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0)
     view = cache.k[li, pages].reshape(b, n_read * ps, 1, cache.k.shape[-1])
     return gqa_attention(q, view, view, positions, scale=cfg.attn_scale), cache
+
+
+def window_arm(cfg, cache, addr, q, k, v, positions, pos_start):
+    """A sliding-window layer's cache (runtime/paged_kv.py): `cache.wk`,
+    `cache.wv` [Lw, rows * slots, ps, h, d], a RING of `slots` pages a batch
+    row (`cfg.window_ring` positions: the window, a prompt chunk and a page).
+    Position p of batch row r lives in page `r * slots + (p // ps) % slots`,
+    whatever the row's context: a write lands on the page that held position
+    p - ring, which no query still sees. A query at p sees (p - window, p].
+
+    Reads list the ring's pages in position order from the page that holds
+    the first query's `p - window + 1`: a decode-sized read is the
+    page-table kernel over that list (the pages that intersect the window
+    and no other: `paged_decode_attention` told the window), a prompt's chunk
+    the gathered view with the band mask (`_attention_auto`: the flash
+    kernel where it takes it). A listed page past the row's last
+    position holds older positions or none; the causal mask hides it, as it
+    hides an unmapped page's garbage in the paged arm. `addr.rec_row`: the
+    call's one batch row (a prompt chunk), else batch row r is ring r.
+    Float pools, one chip."""
+    if addr.page_size is None:
+        raise NotImplementedError(
+            "a sliding-window layer keeps its cache beside the paged pool only"
+        )
+    W, ps, li = cfg.window, addr.page_size, addr.layer
+    b, t = q.shape[:2]
+    slots = cfg.window_ring // ps
+    n_pool = cache.wk.shape[1]
+    i32 = jnp.int32
+    row = jnp.arange(b, dtype=i32)[:, None] if addr.rec_row is None else addr.rec_row
+    # invalid writes (parked rows at or past seq_len) drop, as the paged arm's
+    page = row * slots + (positions // ps) % slots
+    dropped = n_pool + jnp.arange(b, dtype=i32)[:, None] * t + jnp.arange(t, dtype=i32)[None, :]
+    phys = jnp.where(positions >= cfg.seq_len, dropped, page)
+    put = lambda buf, rows: buf.at[li, phys, positions % ps].set(  # noqa: E731
+        rows.astype(buf.dtype), mode="drop", unique_indices=True
+    )
+    cache = replace(cache, wk=put(cache.wk, k), wv=put(cache.wv, v))
+
+    first_page = jnp.maximum(positions[:, 0] - (W - 1), 0) // ps  # [b]
+    scale = _softmax_scale(cfg)
+
+    def listed(n):  # [b, n]: the ring's pages of logical pages first_page + 0..n-1
+        return row * slots + (first_page[:, None] + jnp.arange(n, dtype=i32)[None, :]) % slots
+
+    if _paged_kernel_serves(cfg, cache.wk.shape, q.shape[2], k.shape[2], t):
+        n_read = min((W + t - 2) // ps + 2, slots)
+        pos = positions[:, 0]
+        a = paged_decode_attention(
+            q, cache.wk, cache.wv, None, None, jnp.asarray(li, i32), pos,
+            listed(n_read), n_read=n_read, page_size=ps, scale=scale,
+            interpret=cfg.pallas_interpret, window=W,
+            pos_first=jnp.where(pos >= cfg.seq_len, pos + 1, first_page * ps),
+        )
+        return a, cache
+    # what the chunk's queries see, in whole 128s of positions (the flash
+    # kernel's blocks): pages past the ring's length repeat its first ones,
+    # at positions past the last query's
+    n_view = -(-(W + t + ps - 2) // ps)
+    n_view = -(-n_view * ps // 128) * 128 // ps if ps <= 128 else n_view
+    pages = listed(n_view)
+    k_view = cache.wk[li, pages].reshape(b, n_view * ps, *cache.wk.shape[3:])
+    v_view = cache.wv[li, pages].reshape(b, n_view * ps, *cache.wv.shape[3:])
+    a = _attention_auto(
+        cfg, q, k_view, v_view, positions, pos_start, scale, band=(W, first_page * ps)
+    )
+    return a, cache
 
 
 def stacked_arm(cfg, cache, addr, q, k, v, positions, pos_start):

@@ -108,13 +108,29 @@ _register(MlaParams, ["wqkva", "q_norm", "wqb", "kv_norm", "w_uk", "w_uv", "wo"]
 
 
 @dataclass
+class WindowParams:
+    """The sliding-window layers' attention weights (laguna), stacked over
+    those layers alone: what `transformer._attention` reads of `LayerParams`
+    for a full layer, at the window layers' own count of query heads."""
+
+    wqkv: Weight  # [Lw, window q_dim + 2*kv_dim, dim]
+    wo: Weight  # [Lw, dim, window q_dim]
+    gate: Optional[jnp.ndarray]  # [Lw, window heads, dim] f32
+    q = k = v = q_norm = k_norm = None  # neither separate projections nor norms
+
+
+_register(WindowParams, ["wqkv", "wo", "gate"])
+
+
+@dataclass
 class ExpertParams:
-    """The expert layers' feed-forward (kimi_k2), stacked over those layers
+    """The expert layers' feed-forward (kimi_k2, laguna), stacked over those layers
     alone ([Lm, ...]): the router over ALL published experts, the stacks of
     the experts HELD, the shared experts as one dense SwiGLU."""
 
     gate: jnp.ndarray  # [Lm, E, dim] f32
-    bias: jnp.ndarray  # [Lm, E] f32: added to the scores to PICK, never to weigh
+    bias: Optional[jnp.ndarray]  # [Lm, E] f32: added to the scores to PICK,
+    # never to weigh; None (no leaf) where the router has none (laguna)
     w1: Weight  # [Lm, Eh, ff, dim]
     w3: Weight  # [Lm, Eh, ff, dim]
     w2: Weight  # [Lm, Eh, dim, ff]
@@ -165,12 +181,18 @@ class LayerParams:
     mla: Optional[MlaParams] = None
     experts: Optional[ExpertParams] = None
     ssm: Optional[MambaParams] = None  # the state-space layers' mixers (granite_hybrid)
+    # a windowed model (cfg.window, laguna): the attention fields hold the
+    # FULL layers alone, `win` the sliding-window layers' at their own head
+    # count, `gate` the full layers' output gate [n_kv_layers, H, dim] f32;
+    # w13 / w2 and `experts` as a latent model's
+    gate: Optional[jnp.ndarray] = None
+    win: Optional[WindowParams] = None
 
 
 _register(
     LayerParams,
     ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1", "q_norm", "k_norm",
-     "moe_gate", "wqkv", "w13", "gdn", "mla", "experts", "ssm"],
+     "moe_gate", "wqkv", "w13", "gdn", "mla", "experts", "ssm", "gate", "win"],
 )
 
 
@@ -219,6 +241,14 @@ class KVCache:
     # experts, column 1 `experts_hit`, the held experts with at least one
     # pair, each summed over expert layers and calls. None on every other model
     moe: Optional[jnp.ndarray] = None
+    # a windowed model's sliding-window layers keep the last positions alone,
+    # in a RING a batch row: `wk`, `wv` [n_win_layers, rows * slots, ps, n_kv,
+    # head_dim], pages `row * slots .. + slots - 1` batch row `row`'s, the
+    # page of position p at slot `(p // ps) % slots` (models/kv_arms.window_arm).
+    # No allocator: a row owns its slots as it owns a recurrent state's.
+    # None on every other model
+    wk: Optional[jnp.ndarray] = None
+    wv: Optional[jnp.ndarray] = None
 
     @property
     def batch(self) -> int:
@@ -233,7 +263,7 @@ class KVCache:
         return self.k_scale is not None
 
 
-_register(KVCache, ["k", "v", "k_scale", "v_scale", "rec", "conv", "moe"])
+_register(KVCache, ["k", "v", "k_scale", "v_scale", "rec", "conv", "moe", "wk", "wv"])
 
 
 def init_rec_state(cfg: ModelConfig, rows: int) -> dict:
@@ -373,6 +403,8 @@ def load_params(
         return _load_hybrid(reader, cfg, dense)
     if cfg.is_latent:
         return _load_latent(reader, cfg, dense)
+    if cfg.window:
+        return _load_windowed(reader, cfg, dense)
 
     roles = ["q", "k", "v", "wo", "w1", "w2", "w3", "norm0", "norm1"]
     if cfg.is_qwen3:
@@ -500,12 +532,7 @@ def _load_hybrid(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
         norm1=put([one("norm1", l) for l in every]),
         **mixers,
     )
-    return ModelParams(
-        embedding=_put(_load_one(reader, reader.by_name["embedding"], np.float32)),
-        layers=layers,
-        final_norm=_put(_load_one(reader, reader.by_name["final_norm"], dense)),
-        wcls=_put(_load_one(reader, reader.by_name["wcls"], dense)),
-    )
+    return _model_params(reader, layers, dense)
 
 
 def _pad_out(part, out: int):
@@ -560,14 +587,8 @@ def _load_latent(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
     def one(role, l, dtype=dense):
         return _load_one(reader, reader.by_name[f"{role}.l{l}"], dtype)
 
-    def experts_of(role):
-        return _put(_expert_stack(reader, role, moe_l, cfg.n_experts_held, dense))
-
     every = range(cfg.n_layers)
-    dense_l = range(cfg.n_dense_layers)
-    moe_l = range(cfg.n_dense_layers, cfg.n_layers)
     put = lambda parts: _put(_stack(parts))  # noqa: E731
-    f32 = np.float32
     H, nope, vd, r = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
 
     def kv_b(l):
@@ -588,25 +609,80 @@ def _load_latent(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
         w_uv=put([w[:, nope:] for w in kvb]),
         wo=put([one("wo", l) for l in every]),
     )
-    experts = ExpertParams(
-        gate=put([one("moe_gate", l, f32) for l in moe_l]),
-        bias=put([one("moe_bias", l, f32) for l in moe_l]),
-        w1=experts_of("w1"), w3=experts_of("w3"), w2=experts_of("w2"),
-        s13=put([_fuse_rows([one("sw1", l), one("sw3", l)], 1) for l in moe_l]),
-        s2=put([one("sw2", l) for l in moe_l]),
-    )
     layers = LayerParams(
-        q=None, k=None, v=None, wo=None, w1=None, w3=None,
-        w13=put([_fuse_rows([one("w1", l), one("w3", l)], 1) for l in dense_l])
-        if cfg.n_dense_layers else None,
-        w2=put([one("w2", l) for l in dense_l]) if cfg.n_dense_layers else None,
-        norm0=put([one("norm0", l) for l in every]),
-        norm1=put([one("norm1", l) for l in every]),
-        mla=mla, experts=experts,
+        q=None, k=None, v=None, wo=None, w1=None, w3=None, mla=mla,
+        **_held_ffn_fields(reader, cfg, dense),
     )
+    return _model_params(reader, layers, dense)
+
+
+def _model_params(reader: MFileReader, layers: LayerParams, dense) -> ModelParams:
     return ModelParams(
         embedding=_put(_load_one(reader, reader.by_name["embedding"], np.float32)),
         layers=layers,
         final_norm=_put(_load_one(reader, reader.by_name["final_norm"], dense)),
         wcls=_put(_load_one(reader, reader.by_name["wcls"], dense)),
     )
+
+
+def _held_ffn_fields(reader: MFileReader, cfg: ModelConfig, dense) -> dict:
+    """`LayerParams`' feed-forward fields and norms of a model whose expert
+    layers hold a share: w13 / w2 over the leading dense layers, `experts`
+    over the others (the router's selection bias where the file has one),
+    both norms over all."""
+
+    def one(role, l, dtype=dense):
+        return _load_one(reader, reader.by_name[f"{role}.l{l}"], dtype)
+
+    def experts_of(role):
+        return _put(_expert_stack(reader, role, moe_l, cfg.n_experts_held, dense))
+
+    every = range(cfg.n_layers)
+    dense_l = range(cfg.n_dense_layers)
+    moe_l = range(cfg.n_dense_layers, cfg.n_layers)
+    put = lambda parts: _put(_stack(parts))  # noqa: E731
+    f32 = np.float32
+    biased = f"moe_bias.l{moe_l[0]}" in reader.by_name
+    experts = ExpertParams(
+        gate=put([one("moe_gate", l, f32) for l in moe_l]),
+        bias=put([one("moe_bias", l, f32) for l in moe_l]) if biased else None,
+        w1=experts_of("w1"), w3=experts_of("w3"), w2=experts_of("w2"),
+        s13=put([_fuse_rows([one("sw1", l), one("sw3", l)], 1) for l in moe_l]),
+        s2=put([one("sw2", l) for l in moe_l]),
+    )
+    return dict(
+        w13=put([_fuse_rows([one("w1", l), one("w3", l)], 1) for l in dense_l])
+        if cfg.n_dense_layers else None,
+        w2=put([one("w2", l) for l in dense_l]) if cfg.n_dense_layers else None,
+        norm0=put([one("norm0", l) for l in every]),
+        norm1=put([one("norm1", l) for l in every]),
+        experts=experts,
+    )
+
+
+def _load_windowed(reader: MFileReader, cfg: ModelConfig, dense) -> ModelParams:
+    """laguna: the attention stack over the full layers, `win` over the
+    sliding-window ones at their own head count, a gate a head for both, and
+    the feed-forward of a model that holds a share of its experts. Single
+    chip only (the engine refuses a mesh for this architecture)."""
+
+    def one(role, l, dtype=dense):
+        return _load_one(reader, reader.by_name[f"{role}.l{l}"], dtype)
+
+    def attention(layers):
+        return dict(
+            wqkv=put([_fuse_rows([one(r, l) for r in ("q", "k", "v")], 1) for l in layers]),
+            wo=put([one("wo", l) for l in layers]),
+            gate=put([one("attn_gate", l, np.float32) for l in layers])
+            if cfg.attn_gate else None,
+        )
+
+    put = lambda parts: _put(_stack(parts))  # noqa: E731
+    kinds = cfg.layer_kinds
+    layers = LayerParams(
+        q=None, k=None, v=None, w1=None, w3=None,
+        **attention([l for l, kind in enumerate(kinds) if kind == "full"]),
+        win=WindowParams(**attention([l for l, kind in enumerate(kinds) if kind == "window"])),
+        **_held_ffn_fields(reader, cfg, dense),
+    )
+    return _model_params(reader, layers, dense)

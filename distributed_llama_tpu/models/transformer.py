@@ -1,5 +1,5 @@
 """The unified transformer forward pass (Llama / Qwen3 / Qwen3-MoE / Olmo-Hybrid / Kimi-K2 /
-Granite-Hybrid).
+Granite-Hybrid / Laguna).
 
 Functional re-design of the reference's per-node op graph (reference:
 buildLlmNet, src/llm.cpp:152-649). ONE walker (`_walk`) takes every family's
@@ -22,7 +22,11 @@ residual multiplier. The plan names each layer's mixer and feed-forward, and
     mixers         attention (`_attention`: q,k,v = y @ Wq,Wk,Wv; the head
                    norm of Qwen3 or the whole-projection norm of Olmo; RoPE;
                    a cache arm of models/kv_arms.py writes k, v and attends;
-                   @ Wo. Reference att segment src/llm.cpp:278-418),
+                   a sigmoid gate a head where the model has one; @ Wo.
+                   Reference att segment src/llm.cpp:278-418),
+                   window (`_attention` again, with the window layers' own
+                   weights stack, head count and RoPE table, over the ring
+                   of the last positions: kv_arms.window_arm),
                    gated_delta (`_gdn_mixer`, ops/gated_delta.py),
                    ssd (`_ssm_mixer`, Mamba-2, ops/ssd.py),
                    latent (`_latent_attention`, the absorbed form)
@@ -43,6 +47,10 @@ residual multiplier. The plan names each layer's mixer and feed-forward, and
                    `logits_scaling`
     Kimi-K2        latent in every layer (the DeepSeek-V3 block); dense in the
                    leading layers and held in the others; pre-norm
+    Laguna         periods of one attention layer (half of a head rotated, at
+                   YaRN's frequencies) and window layers (more query heads,
+                   plain RoPE) behind a leading attention + dense layer; a
+                   gate a head; held (no selection bias) in the others
 
 Final: rms_norm(x, final_norm) @ Wcls -> logits   (src/llm.cpp:593-636)
 """
@@ -276,9 +284,10 @@ def _moe_decode_i8(cfg, y, lp, layer, idx, wts):
     return out.reshape(*y.shape[:2], cfg.dim)
 
 
-def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp: LayerParams, positions, layer_idx):
+def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp, positions, layer_idx, n_heads=None):
     """q, k, v [b, t, heads, head_dim] of the normed activation y: the fused
-    or the three projections, the Qwen3 head norm, RoPE."""
+    or the three projections, the Qwen3 head norm, RoPE. `n_heads`: the
+    query heads of the stack `lp` where they are not `cfg.n_heads`."""
     b, t, _ = y.shape
     q80 = cfg.q80_activations
     # head counts come from the weight shapes, not cfg: under shard_map the
@@ -290,7 +299,7 @@ def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp: LayerParams, positions, laye
         # factor under the interleaved row sharding (models/params.py)
         qkv = linear(y, lp.wqkv, cfg.dtype, cfg.pallas_arg, q80, layer_idx)
         fused_out = qkv.shape[-1]
-        g_q = cfg.n_heads * cfg.head_dim
+        g_q = (n_heads or cfg.n_heads) * cfg.head_dim
         g_kv = cfg.n_kv_heads * cfg.head_dim
         local_q = fused_out * g_q // (g_q + 2 * g_kv)
         local_kv = fused_out * g_kv // (g_q + 2 * g_kv)
@@ -319,15 +328,25 @@ def _qkv(cfg: ModelConfig, rope: RopeTables, y, lp: LayerParams, positions, laye
     return q, k, v
 
 
-def _attention(cfg, rope, y, lp: LayerParams, cache, addr, wi, positions, pos_start):
+def _attention(cfg, rope, y, lp, cache, addr, wi, positions, pos_start, n_heads=None):
     """The attention mixer of the activation y [b, t, dim] for layer `wi` of
-    the attention stack (None: `lp` is one layer's weights): q, k, v, the cache
-    arm `addr` selects (`addr.layer`: the layer's rows of the cache), the
-    output projection. Returns (out [b, t, dim] before the residual, cache)."""
+    the attention stack `lp` (`LayerParams`, or a kind's own stack with its
+    fields: `WindowParams`, with `n_heads` query heads and `rope` the kind's
+    table; None: `lp` is one layer's weights): q, k, v, the cache arm `addr`
+    selects (`addr.layer`: the layer's rows of the cache), the sigmoid gate a
+    head where the stack has one, the output projection. Returns (out
+    [b, t, dim] before the residual, cache)."""
     b, t, _ = y.shape
-    q, k, v = _qkv(cfg, rope, y, lp, positions, wi)
+    q, k, v = _qkv(cfg, rope, y, lp, positions, wi, n_heads)
     a, cache = select_arm(addr)(cfg, cache, addr, q, k, v, positions, pos_start)
     n_local_heads = q.shape[2]  # == cfg.n_heads unless sharded under shard_map
+    if lp.gate is not None:
+        # small and decisive, like the linear layers' gates: float32
+        gate = jnp.einsum(
+            "btd,hd->bth", y.astype(jnp.float32), _sel_layer(lp.gate, wi),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        a = (a * jax.nn.sigmoid(gate)[..., None]).astype(a.dtype)
     return linear(a.reshape(b, t, n_local_heads * cfg.head_dim), lp.wo, cfg.dtype, cfg.pallas_arg, cfg.q80_activations, wi), cache
 
 
@@ -438,7 +457,7 @@ def _held_expert_ffn(cfg, y, ep, mi):
     of the routed sum (ops/moe.moe_ffn_held), and the shared experts, which
     every chip of the deployment computes alike. Returns (out, stats [2])."""
     idx, wts = moe_router_sigmoid(
-        y, _sel_layer(ep.gate, mi), _sel_layer(ep.bias, mi),
+        y, _sel_layer(ep.gate, mi), _sel_layer(ep.bias, mi),  # None: no bias
         cfg.n_active_experts, cfg.routed_scale,
     )
     routed, stats = moe_ffn_held(
@@ -458,6 +477,11 @@ def _mix(kind, cfg, rope, y, lp, cache, addr, i, positions, pos_start, valid):
     Returns (out [b, t, dim] before the residual, cache)."""
     if kind == "attention":
         return _attention(cfg, rope, y, lp, cache, addr, i, positions, pos_start)
+    if kind == "window":
+        return _attention(
+            cfg, rope.window, y, lp.win, cache, addr._replace(window=True), i,
+            positions, pos_start, cfg.window_heads,
+        )
     if kind == "latent":
         return _latent_attention(cfg, rope, y, lp.mla, cache, addr, i, positions, pos_start)
     if kind == "gated_delta":

@@ -172,6 +172,8 @@ def gqa_attention(
     v_cache: jnp.ndarray,
     positions: jnp.ndarray,
     scale: float | None = None,
+    window: int | None = None,
+    col_offset: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Causal GQA attention over the (padded) cache.
 
@@ -179,6 +181,8 @@ def gqa_attention(
     k_cache, v_cache: [batch, cache_len, n_kv_heads, head_dim]
     positions: [batch, q_len] int32 absolute position of each query token;
         cache slot t is visible to a query at position p iff t <= p.
+    window: a query at p sees positions (p - window, p] alone.
+    col_offset: [batch] int32, the position cache slot 0 holds (None: 0).
     Returns [batch, q_len, n_heads, head_dim] in q.dtype.
     """
     b, q_len, n_heads, head_dim = q.shape
@@ -200,7 +204,13 @@ def gqa_attention(
     scores = scores.astype(jnp.float32) * scale
 
     t_idx = jnp.arange(cache_len, dtype=jnp.int32)
-    mask = t_idx[None, None, :] <= positions[:, :, None]  # [b, q_len, cache_len]
+    if col_offset is None:
+        t_idx = t_idx[None, None, :]
+    else:
+        t_idx = col_offset[:, None, None] + t_idx[None, None, :]
+    mask = t_idx <= positions[:, :, None]  # [b, q_len, cache_len]
+    if window is not None:
+        mask &= t_idx > positions[:, :, None] - window
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
 
     probs = jax.nn.softmax(scores, axis=-1)

@@ -59,7 +59,7 @@ def moe_router(
 
 
 def moe_router_sigmoid(
-    x: jnp.ndarray, gate: jnp.ndarray, bias: jnp.ndarray, n_active: int,
+    x: jnp.ndarray, gate: jnp.ndarray, bias: jnp.ndarray | None, n_active: int,
     scale: float = 1.0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """DeepSeek-V3's gate with one group (`noaux_tc`, n_group = topk_group
@@ -67,7 +67,8 @@ def moe_router_sigmoid(
     score + bias PICKED, the picked weighed by their scores WITHOUT the bias,
     normalised over the picked (1e-20 under the sum) and scaled.
 
-    x: [..., dim]; gate: [n_experts, dim] f32; bias: [n_experts] f32.
+    x: [..., dim]; gate: [n_experts, dim] f32; bias: [n_experts] f32, or None
+    where the router has none (the picks are then the scores' own top).
     Returns (indices [..., n_active] int32, weights [..., n_active] f32)."""
     logits = jnp.einsum(
         "...d,ed->...e",
@@ -76,7 +77,9 @@ def moe_router_sigmoid(
         precision=jax.lax.Precision.HIGHEST,
     )
     scores = jax.nn.sigmoid(logits)
-    _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), n_active)
+    _, top_i = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32), n_active
+    )
     w = jnp.take_along_axis(scores, top_i, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return top_i.astype(jnp.int32), w

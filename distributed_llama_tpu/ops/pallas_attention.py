@@ -43,11 +43,14 @@ DEFAULT_BLOCK_T = 512
 DEFAULT_BLOCK_S = 1024
 
 
-def _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale, g):
+def _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale, g, window=None):
     """One KV block's online-softmax update (shared by the normalizing and
     the partial-stats kernels). ps_ref carries [pos_start, col_offset]:
     col_offset is the GLOBAL position of the cache's local row 0 — nonzero
-    when the cache operand is one shard of a sequence-parallel cache."""
+    when the cache operand is one shard of a sequence-parallel cache, or a
+    window layer's ring read in order from its first live page. `window`
+    (static): a query at p sees positions (p - window, p] alone, and a block
+    wholly under the first query's window is skipped like one past the last."""
     si = pl.program_id(2)
     ti = pl.program_id(1)
     pos_start = ps_ref[0]
@@ -67,6 +70,8 @@ def _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale, 
     # position is <= the last query's position
     last_pos = pos_start + ti * bt + (bt - 1)
     block_visible = col_offset + si * bs <= last_pos
+    if window is not None:
+        block_visible &= col_offset + si * bs + (bs - 1) > pos_start + ti * bt - window
 
     @pl.when(block_visible)
     def _():
@@ -82,7 +87,10 @@ def _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale, 
         col_pos = col_offset + si * bs + jax.lax.broadcasted_iota(
             jnp.int32, (rows, bs), 1
         )
-        s = jnp.where(col_pos <= row_pos, s, NEG_INF)
+        seen = col_pos <= row_pos
+        if window is not None:
+            seen &= col_pos > row_pos - window
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[...][:, :1]  # [rows, 1]
         m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
@@ -90,7 +98,7 @@ def _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale, 
         m_safe = jnp.maximum(m_cur, NEG_INF / 2)
         corr = jnp.exp(m_prev - m_safe)
         p = jnp.exp(s - m_safe)
-        p = jnp.where(col_pos <= row_pos, p, 0.0)
+        p = jnp.where(seen, p, 0.0)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
@@ -100,8 +108,12 @@ def _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale, 
         m_ref[...] = jnp.broadcast_to(m_safe, m_ref.shape)
 
 
-def _kernel(ps_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, g, n_s):
-    _attend_block(ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale=scale, g=g)
+def _kernel(
+    ps_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, g, n_s, window=None
+):
+    _attend_block(
+        ps_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale=scale, g=g, window=window
+    )
     si = pl.program_id(2)
     _, bt, _, hd = q_ref.shape
 
@@ -154,6 +166,11 @@ def _flash_operands(q, k_cache, v_cache, block_t, block_s):
     bt = min(block_t, t)
     while t % bt:
         bt //= 2
+    # a block's score-sized values are [bt * g, bs] f32, several alive at
+    # once: 9 queries a stored head at bt 256 overran a v5e's 16 MiB of
+    # scoped VMEM by 2 (tests/test_tpu_compile.py), 5 at 256 (1280 rows) fit
+    while bt * g > 2048 and bt > 8:
+        bt //= 2
     bs = min(block_s, S)
     while S % bs:
         bs //= 2
@@ -195,7 +212,7 @@ def _flash_grid_spec(dims, n_extra_outs=0):
     )
 
 
-@partial(jax.jit, static_argnames=("scale", "block_t", "block_s", "interpret"))
+@partial(jax.jit, static_argnames=("scale", "block_t", "block_s", "interpret", "window"))
 def flash_attention(
     q: jnp.ndarray,  # [b, t, n_heads, head_dim]
     k_cache: jnp.ndarray,  # [b, S, n_kv, head_dim]
@@ -205,6 +222,9 @@ def flash_attention(
     block_t: int = DEFAULT_BLOCK_T,
     block_s: int = DEFAULT_BLOCK_S,
     interpret: bool = False,
+    window: int | None = None,  # a query at p sees (p - window, p] alone
+    col_offset: jnp.ndarray | None = None,  # scalar int32: the position of
+    # the cache's row 0 (None: 0)
 ) -> jnp.ndarray:
     """Blocked causal GQA attention; same contract as gqa_attention with
     positions = pos_start + arange(t). Returns [b, t, n_heads, head_dim]."""
@@ -213,9 +233,12 @@ def flash_attention(
         scale = 1.0 / (hd ** 0.5)
     q4, k3, v3, dims = _flash_operands(q, k_cache, v_cache, block_t, block_s)
     _, _, _, _, n_kv, g, bt, bs, n_s = dims
-    ps = jnp.stack([jnp.asarray(pos_start, jnp.int32), jnp.int32(0)])
+    ps = jnp.stack([
+        jnp.asarray(pos_start, jnp.int32),
+        jnp.int32(0) if col_offset is None else jnp.asarray(col_offset, jnp.int32),
+    ])
     out = pl.pallas_call(
-        partial(_kernel, scale=scale, g=g, n_s=n_s),
+        partial(_kernel, scale=scale, g=g, n_s=n_s, **({"window": window} if window else {})),
         grid_spec=_flash_grid_spec(dims),
         out_shape=jax.ShapeDtypeStruct((b * n_kv, t, g, hd), q.dtype),
         interpret=interpret,
@@ -284,7 +307,8 @@ PAGED_PREFETCH_WORDS = 192 * 2**10  # of the 256 Ki int32 words of a v5e's SMEM,
 
 
 def paged_prefetch_words(b: int, n_read: int) -> int:
-    """int32 words of the kernel's scalar-prefetch operand (`meta` below)."""
+    """int32 words of the kernel's scalar-prefetch operand (`meta` below; a
+    windowed call's holds `b` more)."""
     return 2 + 3 * b + b * n_read
 
 
@@ -310,7 +334,7 @@ def _paged_block_pages(
 
 def _paged_decode_kernel(
     m_ref, q_ref, *rest,
-    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt, v_width=None,
+    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt, v_width=None, window=None,
 ):
     """One batch row's attention over its live pages (see the notes above).
     m_ref (scalar prefetch) carries [layer, first live row, pos_base[b],
@@ -319,7 +343,11 @@ def _paged_decode_kernel(
     program). Stale buffer tails and clamped-page garbage are masked for
     live rows; a row with no live page writes zeros (discarded host-side).
     `v_width` (static): None for K and V pools; for a latent pool, whose refs
-    have no V, the leading columns of a page that are its values."""
+    have no V, the leading columns of a page that are its values.
+    `window` (static): the table's first entry holds position `first[b]`
+    (one more section of m_ref, before the table) instead of 0, and a query
+    at p sees positions (p - window, p] alone: a window layer's ring, whose
+    table lists the pages that intersect the row's window, in order."""
     latent = v_width is not None
     if latent:
         k_hbm, o_ref, kbuf, sem, cnt_ref = rest
@@ -334,6 +362,8 @@ def _paged_decode_kernel(
     cols = block * n_kv
     rows_p, hd = q_ref.shape[1], q_ref.shape[2]
     POS, LIVE, NEXT, TABLE = 2, 2 + b, 2 + 2 * b, 2 + 3 * b
+    if window is not None:
+        FIRST, TABLE = TABLE, TABLE + b
 
     def copies(row, i, slot, do):
         """`do` each page copy of block i of `row` into buffer `slot`."""
@@ -376,6 +406,7 @@ def _paged_decode_kernel(
     cnt_ref[0] = base + n_blk
     nxt = m_ref[NEXT + bi]
     pos_base = m_ref[POS + bi]
+    first = m_ref[FIRST + bi] if window is not None else None
 
     q = q_ref[0].astype(cdt)  # [rows_p, hd], rows ordered (kv head, token, g)
     r_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_p, 1), 0)
@@ -416,7 +447,11 @@ def _paged_decode_kernel(
             s = s * (ks_ref[0, pl.ds(i, 1), :] * scale)
         else:
             s = s * scale
-        visible = col_tok + i * block <= row_pos
+        if window is None:
+            visible = col_tok + i * block <= row_pos
+        else:
+            col_pos = col_tok + (i * block + first)
+            visible = jax.lax.bitwise_and(col_pos <= row_pos, col_pos > row_pos - window)
         s = jax.lax.select(visible, s if latent else s + head_bias, masked)
 
         m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
@@ -463,7 +498,7 @@ def _paged_decode_kernel(
 @partial(
     jax.jit,
     static_argnames=(
-        "n_read", "page_size", "scale", "block_tokens", "interpret", "v_width"
+        "n_read", "page_size", "scale", "block_tokens", "interpret", "v_width", "window"
     ),
 )
 def paged_decode_attention(
@@ -481,6 +516,11 @@ def paged_decode_attention(
     block_tokens: int = PAGED_BLOCK_TOKENS,
     interpret: bool = False,
     v_width: int | None = None,  # a latent page's value columns (None: all)
+    window: int | None = None,  # a query at p sees (p - window, p] alone, and
+    pos_first: jnp.ndarray | None = None,  # [b] int32: the position that the
+    # table's FIRST entry starts at (a window layer's ring: its table lists
+    # the pages that intersect the row's window, not the row's from slot 0);
+    # a row whose `pos_first` lies past its `pos_base` reads nothing
 ) -> jnp.ndarray:
     """Page-table GQA decode attention over the pool, float or int8.
 
@@ -494,7 +534,13 @@ def paged_decode_attention(
     A 4-D float `k_pool` with no `v_pool` is a LATENT pool: one vector of W a
     token, which every head of q [b, t, h, W] attends over and whose first
     `v_width` columns are the values. The result's columns from `v_width` on
-    are zeros."""
+    are zeros.
+
+    With `window`, the same walk over another list: the caller's table holds
+    the pages that intersect `(pos - window, pos]` in order, the first of
+    them starting at position `pos_first`, and columns under the window are
+    masked like those past the position. The read is the window's, whatever
+    the row's context."""
     b, t, n_heads, hd = q.shape
     latent = k_pool.ndim == 4
     n_kv = 1 if latent else k_pool.shape[3]
@@ -535,18 +581,29 @@ def paged_decode_attention(
         jax.lax.slice_in_dim(page_table, 0, n_read, axis=1), 0
     ).astype(jnp.int32)  # [b, n_read]
     # a row's live pages: those holding a position <= its last query's
-    live = jax.lax.select(
-        pos_base < n_read * ps,
-        jnp.minimum(jax.lax.div(pos_base + (t - 1), jnp.int32(ps)) + 1, n_read),
-        jnp.zeros_like(pos_base),
-    )
+    if window is None:
+        live = jax.lax.select(
+            pos_base < n_read * ps,
+            jnp.minimum(jax.lax.div(pos_base + (t - 1), jnp.int32(ps)) + 1, n_read),
+            jnp.zeros_like(pos_base),
+        )
+        first = ()
+    else:
+        pos_first = jnp.asarray(pos_first, jnp.int32).reshape(b)
+        rel = pos_base - pos_first
+        live = jax.lax.select(
+            rel >= 0,
+            jnp.minimum(jax.lax.div(rel + (t - 1), jnp.int32(ps)) + 1, n_read),
+            jnp.zeros_like(pos_base),
+        )
+        first = (pos_first,)
     idx = jax.lax.select(
         live > 0, jnp.arange(b, dtype=jnp.int32), jnp.full((b,), b, jnp.int32)
     )
     # next live row after each row (b: none), and the first of all
     nxt = jax.lax.cummin(jnp.concatenate([idx[1:], jnp.full((1,), b, jnp.int32)]), reverse=True)
     meta = jnp.concatenate(
-        [li.reshape(1), jnp.min(idx).reshape(1), pos_base, live, nxt,
+        [li.reshape(1), jnp.min(idx).reshape(1), pos_base, live, nxt, *first,
          pages.reshape(b * n_read)]
     )
 
@@ -594,11 +651,14 @@ def paged_decode_attention(
             _paged_decode_kernel, scale=scale, g=g, t=t, ps=ps, ppb=ppb,
             n_read=n_read, n_kv=n_kv, b=b, quantized=quantized, cdt=cdt,
             v_width=v_width if latent else None,
+            **({"window": window} if window else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows_p, hd), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",
+        # a windowed call has a name of its own: a trace tells the two kinds
+        # of layer apart, and the cost table's census reads its `meta` right
+        name="paged_decode_attention" + ("_window" if window else ""),
     )(*operands)
     return (
         out[:, :rows]

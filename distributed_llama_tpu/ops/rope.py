@@ -36,13 +36,19 @@ from ..formats.mfile import ModelHeader, RopeType
 @jax.tree_util.register_pytree_node_class
 @dataclass(frozen=True)
 class RopeTables:
-    """cos/sin lookup tables, shape [seq_len, head_dim // 2] (f32)."""
+    """cos/sin lookup tables, shape [seq_len, rotated dims // 2] (f32): all of
+    a head's dims, or its first ones where the tables are narrower than half
+    a head (`apply_rope`). `window`: the sliding-window layers' own tables
+    where a model has such layers (None, and no leaf, otherwise)."""
 
     cos: jnp.ndarray
     sin: jnp.ndarray
+    window: "RopeTables | None" = None
 
     def tree_flatten(self):
-        return (self.cos, self.sin), None
+        if self.window is None:
+            return (self.cos, self.sin), None
+        return (self.cos, self.sin, self.window), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -96,8 +102,33 @@ def yarn_frequencies(
     return extra / factor * ramp + extra * (1.0 - ramp)
 
 
+def _tables(h: ModelHeader, freqs: np.ndarray, scale: float = 1.0) -> RopeTables:
+    pos = np.arange(h.seq_len, dtype=np.float64)[:, None]  # dlt: allow(float64) — host-side; angles cast to f32 below
+    angles = (pos * freqs[None, :]).astype(np.float32)
+    return RopeTables(
+        cos=jnp.asarray(np.cos(angles) * np.float32(scale)),
+        sin=jnp.asarray(np.sin(angles) * np.float32(scale)),
+    )
+
+
 def build_rope_tables(h: ModelHeader) -> RopeTables:
     """Precompute per-position cos/sin for all pair indices of one head."""
+    if h.is_windowed:
+        # a full layer rotates the first `rotary_share` of a head at YaRN's
+        # frequencies, cos and sin times its attention factor; a window layer
+        # the whole head at plain ones
+        rot = int(h.head_dim * h.rotary_share)
+        full = _tables(
+            h,
+            yarn_frequencies(
+                rot, h.rope_theta, h.rope_scaling_factor, h.yarn_beta_fast,
+                h.yarn_beta_slow, h.rope_scaling_orig_max_seq_len,
+            ),
+            yarn_mscale(h.rope_scaling_factor, h.yarn_mscale),
+        )
+        j = np.arange(h.head_dim // 2, dtype=np.float64)  # dlt: allow(float64) — host-side precompute; cast to f32 before device
+        window = _tables(h, h.window_rope_theta ** (-2.0 * j / h.head_dim))
+        return RopeTables(cos=full.cos, sin=full.sin, window=window)
     if h.rope_type == RopeType.YARN:
         # latent attention rotates its `qk_rope_head_dim` dims alone
         freqs = yarn_frequencies(
@@ -170,6 +201,11 @@ def apply_rope_falcon(
 def apply_rope(
     x: jnp.ndarray, tables: RopeTables, positions: jnp.ndarray, rope_type: int
 ) -> jnp.ndarray:
+    rot = 2 * tables.cos.shape[-1]
+    if rope_type != RopeType.NONE and rot < x.shape[-1]:
+        # tables narrower than a head: its first `rot` dims turn, the others pass
+        turned = apply_rope(x[..., :rot], tables, positions, rope_type)
+        return jnp.concatenate([turned, x[..., rot:]], axis=-1)
     if rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1, RopeType.YARN):
         return apply_rope_llama(x, tables, positions)
     if rope_type == RopeType.FALCON:

@@ -272,6 +272,10 @@ class InferenceEngine:
         kv_pool_mb: int | None = None,  # paged-pool HBM budget. None =
         # DLT_KV_POOL_MB env; 0/unset = contiguous parity (batch x seq_len
         # worth of pages), so default paged never fits fewer tokens
+        max_prompt_len: int | None = None,  # the longest prompt this
+        # engine's driver admits (--max-prompt-tokens): the warm plan holds
+        # prompt-chunk programs up to the KV bucket that covers it, not up to
+        # seq_len (answers may still run to seq_len). None = seq_len
         server_role: str | None = None,  # set by server/api.py alone (`serve`
         # and the supervisor's rebuild): the --role of the server process
         # that drives this engine, so the warm plan holds that driver's
@@ -404,6 +408,7 @@ class InferenceEngine:
 
         self.rec_slot_bytes = rec_state_bytes(self.cfg, 1)
         self.max_chunk = max(1, min(max_chunk, self.cfg.seq_len))
+        self.max_prompt_len = min(max_prompt_len or self.cfg.seq_len, self.cfg.seq_len)
         # device_decode: run the decode loop on device in chunks (fast path);
         # False = per-token host loop with the reference's exact RNG stream.
         self.device_decode = device_decode
@@ -431,6 +436,14 @@ class InferenceEngine:
         # shards of a pool's head axis (paged_kv.pool_kv_heads pads a shard's)
         self.kv_tp = mesh.shape["tp"] if mesh is not None else 1
         self.page_size = resolve_page_size(kv_page_size) if self.paged else None
+        if self.cfg.window:
+            # the sliding-window layers' ring a batch row: the window, one
+            # prompt chunk and a page, whatever --max-seq-len is
+            from .paged_kv import window_ring_positions
+
+            self.cfg = self.cfg.with_(
+                window_ring=window_ring_positions(self.cfg, self.max_chunk, self.page_size)
+            )
         self.page_pool = None
         self._pt_cache = None  # (pool.version, device tables) — the cached
         # page-table operand; invalidated by any pool mutation
@@ -642,6 +655,26 @@ class InferenceEngine:
             "layers": self.cfg.n_rec_layers,
         }
 
+    def window_snapshot(self):
+        """The sliding-window layers' rings as /stats reports them beside
+        `kv_pool` (`window_pool`): a ring a batch row a window layer,
+        allocated once and never exhausted. None for a model without such
+        layers."""
+        cfg = self.cfg
+        if not cfg.window:
+            return None
+        from .paged_kv import window_ring_bytes
+
+        return {
+            "window": cfg.window,
+            "layers": cfg.n_win_layers,
+            "rows": self.batch,
+            "ring_positions": cfg.window_ring,
+            "bytes": window_ring_bytes(cfg, self.batch),
+            # a token's k and v over the window layers, as stored
+            "bytes_per_position": window_ring_bytes(cfg, 1) // cfg.window_ring,
+        }
+
     def moe_snapshot(self):
         """The held-experts layers as /stats reports them (`moe`), without the
         running sums (the Batcher owns those): None for a model whose expert
@@ -777,12 +810,20 @@ class InferenceEngine:
         warmup prompt never produced), a long conversation whose decode
         crosses bucket boundaries, a prefix-cache resume that starts
         mid-ladder. A (size, kvb) pair is reachable iff size <= kvb (the
-        bucket must cover the chunk's own end). Prefix-cache copy/extract
+        bucket must cover the chunk's own end) and, for a prompt's chunk, kvb
+        is no deeper than the bucket that covers the longest prompt admitted
+        (`max_prompt_len`: a server told --max-prompt-tokens refuses longer
+        ones, so 6144 positions of context for long answers do not buy
+        prompt-chunk programs at 4096 and 6144 that no prompt reaches). Prefix-cache copy/extract
         programs ride the same ladder at (bucket, bucket). `batch_decode`
         alone leaves the cross product where its step reads live pages
         only: one bound, `seq_len`, a size (`_batch_decode_bound`)."""
         plan = []
         kvbs = self._kv_buckets()
+        # a prompt's chunks end by the bucket that covers the longest prompt
+        # admitted, its last chunk's padding included
+        prompt_end = -(-self.max_prompt_len // self.max_chunk) * self.max_chunk
+        prompt_kvbs = [k for k in kvbs if k <= self._kv_bucket(prompt_end)]
         prefill_sizes = _chunk_buckets(self.max_chunk)
         # the chunk and what a shrink loop makes of it; the first-chunk ramp
         # of 8 is among a longer chunk's halves
@@ -791,7 +832,7 @@ class InferenceEngine:
         decode_bounds = {self._batch_decode_bound(kvb) for kvb in kvbs}
         for kvb in kvbs if self.warms_solo_programs else []:
             for s in prefill_sizes:
-                if s <= kvb:
+                if s <= kvb and kvb in prompt_kvbs:
                     plan.append(("prefill", s, kvb))
             for n in decode_sizes:
                 if n <= kvb:
@@ -799,7 +840,7 @@ class InferenceEngine:
         if batched:
             for kvb in kvbs:
                 for s in prefill_sizes:
-                    if s <= kvb:
+                    if s <= kvb and kvb in prompt_kvbs:
                         plan.append(("prefill_row", s, kvb))
                 for n in decode_sizes:
                     if n <= kvb and kvb in decode_bounds:

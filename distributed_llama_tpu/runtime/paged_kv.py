@@ -199,6 +199,27 @@ def page_pool_bytes(cfg, n_pages: int, page_size: int, tp: int = 1) -> int:
     )
 
 
+def window_ring_positions(cfg, max_chunk: int, page_size: int) -> int:
+    """Positions a batch row's RING holds for each sliding-window layer
+    (`ModelConfig.window_ring`; 0 where the model has no such layer): the
+    window, one prompt chunk and a page of slack, in whole pages. A chunk of
+    `max_chunk` queries is written before it is read, its first query still
+    needs the `window - 1` positions before it, and a page is reused whole:
+    the page a chunk's last position lands in must not be one that the
+    chunk's first query still reads (models/kv_arms.window_arm)."""
+    if not cfg.window:
+        return 0
+    return -(-(cfg.window + max_chunk + page_size) // page_size) * page_size
+
+
+def window_ring_bytes(cfg, rows: int) -> int:
+    """Device bytes of the window layers' rings (`KVCache.wk` + `wv`)."""
+    return (
+        2 * cfg.n_win_layers * rows * cfg.window_ring
+        * pool_kv_heads(cfg.n_kv_heads) * cfg.head_dim * jnp.dtype(cfg.kv_dtype).itemsize
+    )
+
+
 def init_kv_pool(cfg, n_pages: int, page_size: int, rows: int = 0, tp: int = 1) -> KVCache:
     """The device page pool, riding the existing :class:`KVCache` pytree so
     every jit entry point's ``donate_argnames=("cache",)`` keeps working:
@@ -231,6 +252,15 @@ def init_kv_pool(cfg, n_pages: int, page_size: int, rows: int = 0, tp: int = 1) 
             k=k, v=v,
             k_scale=jnp.zeros(shape[:-1], jnp.float32),
             v_scale=jnp.zeros(shape[:-1], jnp.float32),
+        )
+    if cfg.window:
+        # the sliding-window layers' rings, `rows` of `window_ring` positions
+        # each (`KVCache.wk`), and the expert layers' counters; the pool
+        # above is the full layers' alone
+        ring = (cfg.n_win_layers, rows * (cfg.window_ring // page_size), *shape[2:])
+        return KVCache(
+            k=k, v=v, wk=jnp.zeros(ring, cfg.kv_dtype), wv=jnp.zeros(ring, cfg.kv_dtype),
+            moe=jnp.zeros((2, 2), jnp.int32) if cfg.n_experts_held else None,
         )
     return KVCache(k=k, v=v, **init_rec_state(cfg, rows))
 

@@ -555,7 +555,9 @@ def _paged_kernel_census(eqn, in_hbm):
     pallas_call whose HBM reads happen *inside* the kernel (the HLO page
     gather it removed) — and price them at STORED width, float or int8. The
     kernel copies a row's live pages and no other, so the census prices what
-    it BOUNDS: every block of every row whole, K and V (a latent pool's one
+    it BOUNDS: every block of every row whole (a window layer's call,
+    `paged_decode_attention_window`: the blocks of the pages that intersect a
+    row's window, which is what it reads at any context), K and V (a latent pool's one
     vector a token once: its values are the page's own columns; an int8
     pool's f32 scale pages are gathered in HLO beside the call and priced
     there like any gather). Where a Batcher's chunk is planned at the one bound
@@ -571,7 +573,8 @@ def _paged_kernel_census(eqn, in_hbm):
     # latent pool [L, P, ps, W], the one pool (K only: its values are the
     # page's own columns); the kernel's K buffer [2, block, n_kv, hd]
     # ([2, block, W]) is its first scratch operand
-    if eqn.params.get("name") != "paged_decode_attention":
+    name = eqn.params.get("name") or ""
+    if name not in ("paged_decode_attention", "paged_decode_attention_window"):
         return None
     meta, q, pool = (v.aval for v in eqn.invars[:3])
     pools = 1 if pool.ndim == 4 else 2
@@ -583,8 +586,11 @@ def _paged_kernel_census(eqn, in_hbm):
         for v in eqn.params["jaxpr"].invars
         if tuple(v.aval.shape[2:]) == token and v.aval.shape[0] == 2
     )
-    # meta = [layer, first live row, pos_base[b], live[b], next[b], table[b*n_read]]
-    n_read = (int(meta.size) - 2 - 3 * b) // b
+    # meta = [layer, first live row, pos_base[b], live[b], next[b], table[b*n_read]];
+    # a windowed call's holds first[b] before the table, and its table lists
+    # the pages that intersect a row's window: priced by what it reads,
+    # whatever the row's context
+    n_read = (int(meta.size) - 2 - (4 if name.endswith("_window") else 3) * b) // b
     blocks = b * -(-n_read * ps // block)
     return pools * blocks * block * math.prod(token) * pool.dtype.itemsize, blocks
 

@@ -244,13 +244,14 @@ class _Dispatched:
     session's handle, the requests that decode in it by row, the turn whose
     `step.dispatch` dispatched it and the pool's pages at that moment."""
 
-    __slots__ = ("chunk", "rows", "turn", "pool_pages_used")
+    __slots__ = ("chunk", "rows", "turn", "pool_pages_used", "kv_positions")
 
-    def __init__(self, chunk, rows, turn, pool_pages_used):
+    def __init__(self, chunk, rows, turn, pool_pages_used, kv_positions=None):
         self.chunk = chunk
         self.rows = rows
         self.turn = turn
         self.pool_pages_used = pool_pages_used
+        self.kv_positions = kv_positions  # `Batcher._kv_positions` of the chunk
 
 
 class Batcher:
@@ -332,6 +333,13 @@ class Batcher:
         # summed over layers and steps; and the same of the prompt chunks
         # whose completion this chunk's fetch observed
         self._moe_totals = None if engine.moe_snapshot() is None else [0, 0, 0, 0]
+        # a model with sliding-window layers (`engine.window_snapshot`) adds
+        # what its attention layers read in the chunk beside what they would
+        # read were every layer a full one, in cached positions summed over
+        # rows, attention layers and steps, from the rows' positions on the host
+        self._kv_totals = None if engine.window_snapshot() is None else [0, 0]
+        if self._kv_totals is not None:
+            engine.stats.gauge("window_pool_bytes", engine.window_snapshot()["bytes"])
         self._em_timeline = TRACER.bind_global(
             "batch_step",
             ("decoding", "prefilling", "free", "spec",
@@ -339,6 +347,9 @@ class Batcher:
             + (() if self._moe_totals is None else (
                 "expert_pairs", "experts_hit",
                 "prefill_expert_pairs", "prefill_experts_hit",
+            ))
+            + (() if self._kv_totals is None else (
+                "kv_positions_read", "kv_positions_live",
             )),
         )
         # the phases that partition a turn of the loop, in the trace ring
@@ -573,7 +584,7 @@ class Batcher:
     def _timeline_step(
         self, n_decoding: int, t_us: int, dur_us: int, spec: bool = False,
         moe_counts=None, turn: int | None = None, ahead: bool = False,
-        pool_pages_used: int | None = None,
+        pool_pages_used: int | None = None, kv_positions=None,
     ):
         """One batch-composition snapshot: slot roles + pool/backlog
         occupancy. A pre-bound tuple append. For a decode chunk it is
@@ -581,7 +592,8 @@ class Batcher:
         (`BatchSession.fetch`) and names the turn whose `step.dispatch`
         dispatched it, with the rows and the pool's pages of that dispatch.
         `moe_counts`: `BatchSession.moe_counts` of the chunk that just ran
-        (None where no chunk ran, and before a session's second fetch)."""
+        (None where no chunk ran, and before a session's second fetch);
+        `kv_positions`: `_kv_positions` of it."""
         engine = self.state.engine
         slots = self.slots
         n_prefilling = sum(
@@ -595,6 +607,13 @@ class Batcher:
             )
             for i, v in enumerate(moe):
                 self._moe_totals[i] += v
+        kv = ()
+        if self._kv_totals is not None:
+            kv = kv_positions or (0, 0)
+            self._kv_totals[0] += kv[0]
+            self._kv_totals[1] += kv[1]
+            # /metrics: the rings' size is fixed, the share in use moves
+            engine.stats.gauge("window_pool_used_share", round(self._window_used_share(), 4))
         if pool_pages_used is None:
             pool_pages_used = engine.page_pool.used_pages if engine.paged else 0
         self._em_timeline(
@@ -604,8 +623,52 @@ class Batcher:
             self.queue_depth(),
             self.phases.turn if turn is None else turn,
             1 if ahead else 0,
-            *moe,
+            *moe, *kv,
         )
+
+    def _kv_positions(self, rows, n: int):
+        """(read, live) of a decode chunk of `n` steps for `rows`, from their
+        positions before it: cached positions the attention layers read,
+        summed over rows, layers and steps (a full layer the row's context, a
+        window layer no more than the window), and what they would read were
+        every layer a full one. None for a model without window layers."""
+        if self._kv_totals is None:
+            return None
+        cfg, pos = self.state.engine.cfg, self.session.pos
+        n_full, n_win, W = cfg.n_kv_layers, cfg.n_win_layers, cfg.window
+        read = live = 0
+        for r in rows:
+            p = int(pos[r])
+            ctx = n * (p + 1) + n * (n - 1) // 2  # sum of p + i + 1 over the steps
+            under = max(0, min(n, W - 1 - p))  # steps whose context is under the window
+            windowed = under * (p + 1) + under * (under - 1) // 2 + (n - under) * W
+            read += n_full * ctx + n_win * windowed
+            live += (n_full + n_win) * ctx
+        return read, live
+
+    def window_snapshot(self, engine):
+        """/stats `window_pool`: the engine's rings, the share of their
+        positions that hold a live request's, and this loop's running sums of
+        what attention read and what it would have without a window."""
+        snap = engine.window_snapshot()
+        if snap is None or self._kv_totals is None:
+            return snap
+        return dict(
+            snap, used_share=self._window_used_share(),
+            kv_positions_read=self._kv_totals[0], kv_positions_live=self._kv_totals[1],
+        )
+
+    def _window_used_share(self) -> float:
+        """Share of the rings' positions that hold a live request's."""
+        engine, session = self.state.engine, self.session
+        ring = engine.cfg.window_ring
+        if session is None:
+            return 0.0
+        held = sum(
+            min(int(session.pos[r]), ring)
+            for r, req in enumerate(self.slots) if req is not None
+        )
+        return held / (ring * engine.batch)
 
     def moe_snapshot(self, engine):
         """/stats `moe`: the engine's shape of the layer and this loop's
@@ -1232,6 +1295,7 @@ class Batcher:
         while n > max(headroom, 1):
             n //= 2
         n = max(n, 1)
+        kv_positions = self._kv_positions(rows, n)
         chunk = session.dispatch(n)
         engine = self.state.engine
         for r in rows:
@@ -1242,7 +1306,7 @@ class Batcher:
             self.chunks_lockstep += 1
         return _Dispatched(
             chunk, {r: slots[r] for r in rows}, self.phases.turn,
-            engine.page_pool.used_pages if engine.paged else 0,
+            engine.page_pool.used_pages if engine.paged else 0, kv_positions,
         )
 
     def _deliver(self, sent: "_Dispatched"):
@@ -1265,7 +1329,7 @@ class Batcher:
         self._timeline_step(
             n_decoding, t_us, dur_us, moe_counts=self.session.moe_counts,
             turn=sent.turn, ahead=chunk.ahead,
-            pool_pages_used=sent.pool_pages_used,
+            pool_pages_used=sent.pool_pages_used, kv_positions=sent.kv_positions,
         )
         self._deliver_rows(per_row, t_us, dur_us)
 
@@ -1569,6 +1633,16 @@ class ApiState:
                 always=ledger.outcome != "ok",
             )
 
+    def _check_prompt_limit(self, n_prompt: int) -> None:
+        """--max-prompt-tokens: a longer prompt is the client's error, as one
+        past the context window is (the warm plan holds no program for it)."""
+        limit = self.engine.max_prompt_len
+        if n_prompt > limit:
+            raise PromptTooLong(
+                f"prompt ({n_prompt} tokens) exceeds this server's longest "
+                f"prompt ({limit}: --max-prompt-tokens)"
+            )
+
     def _compile_grammar(self, params: dict):
         """Resolve a request's ``response_format`` to a CompiledGrammar
         (None = unconstrained; the OpenAI-style ``{"type": "text"}`` is
@@ -1610,6 +1684,7 @@ class ApiState:
             raise PromptTooLong(
                 f"prompt ({len(ids)} tokens) exceeds the context window ({seq_len})"
             )
+        self._check_prompt_limit(len(ids))
         # structured output: compile response_format BEFORE any reservation
         # or engine work — a malformed body raises GrammarError here and
         # costs neither quota nor a ledger outcome (the handler's 400 owns
@@ -1983,6 +2058,7 @@ class ApiState:
             raise PromptTooLong(
                 f"prompt ({len(ids)} tokens) exceeds the context window ({seq_len})"
             )
+        self._check_prompt_limit(len(ids))
 
         # structured output: compile BEFORE any engine work (GrammarError
         # here is a client 400, like PromptTooLong above); the session —
@@ -2734,6 +2810,14 @@ class Handler(BaseHTTPRequestHandler):
                 # a slot is live while a request holds its row: `batcher`'s
                 # `slots_active`
                 "rec_state": st.engine.rec_state_snapshot(),
+                # the third kind, a windowed model's alone: a ring of the
+                # last positions a batch row a sliding-window layer (None on
+                # every other model), beside what attention read with it
+                "window_pool": (
+                    st.batcher.window_snapshot(st.engine)
+                    if st.batcher is not None
+                    else st.engine.window_snapshot()
+                ),
                 # expert layers that hold a share of the published experts
                 # (None on every other model): the share, and what landed on
                 # it since the server started

@@ -77,6 +77,17 @@ def tiny_header(
     attention_mult: float = 0.0,
     residual_mult: float = 1.0,
     logits_scaling: float = 1.0,
+    # laguna: the full layer is layer `full_attn_offset` of a period of
+    # `full_attn_interval`, the others sliding-window layers of `window_heads`
+    # query heads over `window` positions at `window_rope_theta`; a full layer
+    # rotates `rotary_share` of a head at YaRN's frequencies (`rope_theta`,
+    # `rope_scaling_factor`, `rope_scaling_orig_max_seq_len`, the betas);
+    # `attn_gate`: the sigmoid gate a head; the feed-forward is kimi_k2's
+    window: int = 0,
+    window_heads: int = 0,
+    window_rope_theta: float = 10000.0,
+    rotary_share: float = 1.0,
+    attn_gate: bool = True,
 ) -> ModelHeader:
     h = ModelHeader(
         version=1,
@@ -118,17 +129,24 @@ def tiny_header(
         h.lin_groups, h.lin_conv_bias = lin_groups, int(lin_conv_bias)
         h.embedding_mult, h.attention_mult = embedding_mult, attention_mult
         h.residual_mult, h.logits_scaling = residual_mult, logits_scaling
+    if arch == ArchType.LAGUNA:
+        h.full_attn_interval, h.full_attn_offset = full_attn_interval, full_attn_offset
+        h.window, h.window_heads = window, window_heads
+        h.window_rope_theta, h.rotary_share = window_rope_theta, rotary_share
+        h.attn_gate = int(attn_gate)
+        h.yarn_beta_fast, h.yarn_beta_slow = float(yarn_beta_fast), float(yarn_beta_slow)
+    if arch in (ArchType.KIMI_K2, ArchType.LAGUNA):
+        h.n_dense_layers = n_dense_layers
+        h.experts_held = experts_held or n_experts
+        h.expert_first = expert_first
+        h.n_shared_experts = n_shared_experts
+        h.routed_scale = routed_scale
     if arch == ArchType.KIMI_K2:
         h.q_lora_rank, h.kv_lora_rank = q_lora_rank, kv_lora_rank
         h.qk_nope_head_dim, h.qk_rope_head_dim = qk_nope_head_dim, qk_rope_head_dim
         h.v_head_dim = v_head_dim
         h.yarn_beta_fast, h.yarn_beta_slow = float(yarn_beta_fast), float(yarn_beta_slow)
         h.yarn_mscale, h.yarn_mscale_all_dim = yarn_mscale, yarn_mscale_all_dim
-        h.n_dense_layers = n_dense_layers
-        h.experts_held = experts_held or n_experts
-        h.expert_first = expert_first
-        h.n_shared_experts = n_shared_experts
-        h.routed_scale = routed_scale
     return h.finalize()
 
 
@@ -153,7 +171,7 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
         mfile.K_HEAD_DIM: h.head_dim,
         mfile.K_NORM_EPSILON: 5 if abs(h.norm_epsilon - 1e-5) < 1e-9 else 6,
     }
-    if h.rope_scaling_factor != 1.0 and not h.is_latent:
+    if h.rope_scaling_factor != 1.0 and not (h.is_latent or h.is_windowed):
         kv[mfile.K_ROPE_SCALING_FACTOR] = int(h.rope_scaling_factor)
         kv[mfile.K_ROPE_SCALING_LOW_FREQ_FACTOR] = int(h.rope_scaling_low_freq_factor)
         kv[mfile.K_ROPE_SCALING_HIGH_FREQ_FACTORY] = int(h.rope_scaling_high_freq_factor)
@@ -191,6 +209,19 @@ def header_kv(h: ModelHeader) -> dict[int, int]:
         kv[mfile.K_YARN_BETA_SLOW] = int(h.yarn_beta_slow)
         kv[mfile.K_YARN_MSCALE_MILLI] = round(h.yarn_mscale * 1000)
         kv[mfile.K_YARN_MSCALE_ALL_DIM_MILLI] = round(h.yarn_mscale_all_dim * 1000)
+    if h.is_windowed:
+        kv[mfile.K_ROPE_SCALING_FACTOR] = int(h.rope_scaling_factor)
+        kv[mfile.K_ROPE_SCALING_ORIG_MAX_SEQ_LEN] = h.rope_scaling_orig_max_seq_len
+        kv[mfile.K_YARN_BETA_FAST] = int(h.yarn_beta_fast)
+        kv[mfile.K_YARN_BETA_SLOW] = int(h.yarn_beta_slow)
+        kv[mfile.K_FULL_ATTN_INTERVAL] = h.full_attn_interval
+        kv[mfile.K_FULL_ATTN_OFFSET] = h.full_attn_offset
+        kv[mfile.K_WINDOW] = h.window
+        kv[mfile.K_WINDOW_HEADS] = h.window_heads
+        kv[mfile.K_WINDOW_ROPE_THETA] = int(h.window_rope_theta)
+        kv[mfile.K_ROTARY_MILLI] = round(h.rotary_share * 1000)
+        kv[mfile.K_ATTN_GATE] = h.attn_gate
+    if h.holds_experts:
         kv[mfile.K_N_DENSE_LAYERS] = h.n_dense_layers
         kv[mfile.K_EXPERTS_HELD] = h.experts_held
         kv[mfile.K_EXPERT_FIRST] = h.expert_first
@@ -259,6 +290,28 @@ def tiny_latent_header(**kw) -> ModelHeader:
         q_lora_rank=256, kv_lora_rank=256, qk_nope_head_dim=64,
         qk_rope_head_dim=32, v_head_dim=64, n_dense_layers=1, experts_held=4,
         expert_first=4, n_shared_experts=1, routed_scale=2.827,
+    )
+    base.update(kw)
+    return tiny_header(**base)
+
+
+def tiny_window_header(**kw) -> ModelHeader:
+    """A tiny laguna header with every mechanism of the architecture: a
+    leading full-attention + dense layer, then two periods of `window,
+    window, window, full`; 4 query heads on a full layer and 6 on a window
+    layer over 2 kv heads; a window of 24 positions (so that a prompt of a
+    few dozen tokens crosses it); half of a full layer's head rotated, at
+    YaRN's frequencies over an original length of 16; the gate a head; 16
+    experts of which 8 are held (from expert 4 on) beside a shared one, no
+    selection bias. Widths are the smallest the stacked Q40 kernels take."""
+    base = dict(
+        arch=ArchType.LAGUNA, dim=256, hidden_dim=512, n_layers=9, n_heads=4,
+        n_kv_heads=2, head_dim=32, vocab_size=256, seq_len=256, n_experts=16,
+        n_active_experts=4, moe_hidden_dim=256, rope_theta=500000.0,
+        rope_scaling_factor=8.0, rope_scaling_orig_max_seq_len=16,
+        full_attn_interval=4, full_attn_offset=0, window=24, window_heads=6,
+        rotary_share=0.5, n_dense_layers=1, experts_held=8, expert_first=4,
+        n_shared_experts=1, routed_scale=2.5, yarn_mscale=1.0,
     )
     base.update(kw)
     return tiny_header(**base)

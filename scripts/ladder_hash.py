@@ -45,6 +45,8 @@ if hasattr(graph_audit, "tiny_ssm_hybrid_header"):
     heads["granite_hybrid"] = graph_audit.tiny_ssm_hybrid_header()
 if hasattr(testing, "tiny_latent_header"):
     heads["kimi_k2"] = testing.tiny_latent_header()
+if hasattr(testing, "tiny_window_header"):
+    heads["laguna"] = testing.tiny_window_header()
 keys = {}
 for name, h in heads.items():
     path = f"{d}/{name}.m"

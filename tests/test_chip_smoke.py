@@ -47,6 +47,7 @@ def rehearsals(tmp_path_factory):
         "four": _run(["--rehearse", "--chips", "4"]),
         "kimi": _run(["--rehearse", "--arch", "kimi_k2"]),
         "granite": _run(["--rehearse", "--arch", "granite_hybrid"]),
+        "laguna": _run(["--rehearse", "--arch", "laguna"]),
     }
     runs = {}
     for name, p in procs.items():
@@ -207,4 +208,32 @@ def test_the_granite_hybrid_branch_rehearses_its_numbers_and_its_server(rehearsa
     (stats,) = _by_phase(lines, "stats")
     assert not any(stats["watched"].values()) and stats["supervisor"] == "serving"
     assert stats["rec_state"]["kind"] == "ssd" and stats["rec_state"]["slots"] == 2
+    assert any("prefix cache off" in n for n in stats["notices"])
+
+
+def test_the_laguna_branch_rehearses_its_numbers_and_its_server(rehearsals):
+    """`--arch laguna`: the window arm's kernel against its gathered view on
+    one ring, a leading layer and a period of window and full layers against
+    their float32 form past the window's edge, then the server at 2 rows; on
+    the CPU it too fails for the device check alone."""
+    code, lines, last = rehearsals["laguna"]
+    result = json.loads(last)
+    assert code != 0 and result["reasons"] == ["need 1 tpu device(s), jax found 1 x cpu"], result
+    numbers = _by_phase(lines, "numbers")
+    (arm,) = [l for l in numbers if l["check"].startswith("window arm")]
+    assert arm["kernel_serves"] and arm["finite"] and arm["max_diff_of_largest"] <= arm["bound"]
+    whole = [l for l in numbers if l["check"].startswith("windowed model")]
+    assert [l["positions"] for l in whole] == [48, 8] and all(l["positions"] + 40 > l["window"] for l in whole)
+    assert all(l["median_diff_std"] <= l["bounds"][1] and l["top1_agreement"] >= l["bounds"][0]
+               for l in whole)
+    serve = _by_phase(lines, "serve")[-1]
+    assert all(traced >= 18 for traced, _ in serve["kernels_traced_compiled"].values())
+    assert {l["request"] for l in _by_phase(lines, "request")} == {
+        "plain", "streamed", "concurrent-0", "concurrent-1"}
+    (stats,) = _by_phase(lines, "stats")
+    assert not any(stats["watched"].values()) and stats["supervisor"] == "serving"
+    ring = stats["window_pool"]
+    assert (ring["window"], ring["layers"], ring["rows"], ring["ring_positions"]) == (24, 3, 2, 48)
+    assert 0 < ring["kv_positions_read"] < ring["kv_positions_live"]
+    assert stats["kv_pool"]["bytes_per_token"] == 2 * 2 * 2 * 32 * 2  # the two full layers' k and v
     assert any("prefix cache off" in n for n in stats["notices"])

@@ -632,3 +632,68 @@ def test_a_lost_state_donation_is_reported():
     assert ga.donated_leaf_check("x", txt, 2) == []
     problems = ga.donated_leaf_check("x", txt, 4)
     assert problems and "4 leaves" in problems[0]
+
+
+# -- the windowed model's programs (two kinds of attention, a ring a row) -------
+
+WINDOW_ARGS = ["--arch", "laguna", "--kv-layout", "paged", "--speculative", "off",
+               "--prefix-cache-mb", "0", "--compute-dtype", "bfloat16"]
+
+
+def test_repo_golden_covers_the_tiny_windowed_model(monkeypatch):
+    """One golden for the tiny Laguna's warm plan (prefill_row, batch_decode,
+    page_copy) in bfloat16 with Pallas interpreted, so that it holds the
+    page-table kernel told the window and the flash kernel with the band
+    beside the full layers' and the grouped expert kernel's."""
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    assert gd.main(["--check", "--coverage", *WINDOW_ARGS]) == 0
+
+
+@pytest.fixture(scope="module", params=["xla", "interpret"])
+def window_engine(request, tmp_path_factory):
+    import argparse
+
+    mp = pytest.MonkeyPatch()
+    if request.param == "interpret":
+        mp.setenv("DLT_PALLAS_INTERPRET", "1")
+    else:
+        mp.delenv("DLT_PALLAS_INTERPRET", raising=False)
+    p = argparse.ArgumentParser()
+    ga.add_engine_args(p)
+    eng = ga.engine_from_args(p.parse_args(WINDOW_ARGS), str(tmp_path_factory.mktemp("window")))
+    yield eng
+    eng.close()
+    mp.undo()
+
+
+def test_windowed_programs_meet_their_contracts(window_engine):
+    """No float64, the float32 dots within what three attention bodies (the
+    softmax side and the gate) and two routers need, no collective, and every
+    leaf of the cache donated on every jit entry, the rings among them; the
+    batched plan holds the Batcher's programs alone."""
+    eng = window_engine
+    ga.assert_clean(ga.audit_engine(eng))
+    assert ga.donation_problems(eng) == []
+    assert len(jax.tree_util.tree_leaves(eng.cache)) == 5  # k, v, moe, wk, wv
+    assert {kind for kind, _, _ in eng.warm_plan()} == {"prefill_row", "batch_decode", "page_copy"}
+    entry = ga.LadderEntry("batch_decode", 8, 128)
+    assert ga.f32_dot_budget(eng, entry) == 3 * 3 + 2
+
+
+def test_windowed_batch_decode_reads_both_caches_through_the_kernel(window_engine):
+    """With Pallas on, a decode step reads the pool through
+    `paged_decode_attention` and the rings through
+    `paged_decode_attention_window`, one site a layer body; off the TPU both
+    take the gathered view."""
+    eng = window_engine
+    text = str(ga.trace_entry(eng, ga.LadderEntry("batch_decode", 8, 128)))
+    if not eng.cfg.pallas_interpret:
+        assert "paged_decode_attention" not in text and eng.decode_kv_bound == "ladder"
+        return
+    assert eng.decode_kv_bound == "live_pages"
+    assert text.count("name=paged_decode_attention_window") == 1  # the window run's scan body
+    assert text.count("name=paged_decode_attention ") + text.count("name=paged_decode_attention\n") >= 1
+    # a prompt's chunk of one page of queries is decode-sized too (the tiny
+    # plan's longest is 16); longer chunks take the gathered ring
+    chunk = str(ga.trace_entry(eng, ga.LadderEntry("prefill_row", 16, 128)))
+    assert chunk.count("name=paged_decode_attention_window") == 1

@@ -358,3 +358,30 @@ def test_rows_that_cross_three_buckets_dispatch_planned_keys_and_the_ladders_tok
         assert [k[2] for k in seen if k[0] == "batch_decode"] == [256, 512, 512, 1024, 1024, 2048]
     finally:
         ladder_eng.close()
+
+
+@pytest.mark.parametrize("limit,buckets", [
+    (None, [256, 512, 1024, 2048]),
+    (300, [256, 512]),      # the last chunk's padding included: 300 -> 304
+    (256, [256]),
+    (1500, [256, 512, 1024, 2048]),
+])
+def test_a_prompt_limit_cuts_the_prompt_chunks_ladder_and_nothing_else(files, limit, buckets):
+    """--max-prompt-tokens: prompt-chunk programs up to the KV bucket that
+    covers the longest prompt admitted; the decode programs keep every bucket
+    an answer may reach."""
+    extra = [] if limit is None else ["--max-prompt-tokens", str(limit)]
+    eng = api.make_served_engine(_args(files, "--batch", "2", *extra, model="long"))
+    plan = eng.warm_plan()
+    assert eng.max_prompt_len == (limit or 2048)
+    assert sorted({k for kind, _n, k in plan if kind == "prefill_row"}) == buckets
+    assert sorted({k for kind, _n, k in plan if kind == "batch_decode"}) == [256, 512, 1024, 2048]
+    eng.close()
+
+
+def test_a_prompt_over_the_limit_is_the_clients_error(files):
+    state = api.ApiState.__new__(api.ApiState)
+    state.engine = type("E", (), {"max_prompt_len": 100})()
+    state._check_prompt_limit(100)
+    with pytest.raises(api.PromptTooLong, match="--max-prompt-tokens"):
+        state._check_prompt_limit(101)

@@ -278,6 +278,100 @@ def _kimi_step(rows, t, pages=2560, kv_len=2048):
     return build
 
 
+def _laguna_cfg():
+    """Laguna-S-2.1 as `perfbench/configs/laguna-s-2.1.json` cuts it:
+    published widths, a leading full-attention + dense layer and two periods
+    of three sliding-window layers and a full one, 128 of 256 experts held,
+    half the vocabulary; the ring the engine sizes for a chunk of 256."""
+    from distributed_llama_tpu.models.config import ModelConfig
+
+    return ModelConfig(
+        arch_type=0xABCD06, dim=3072, hidden_dim=12288, n_layers=9, n_heads=48,
+        n_kv_heads=8, head_dim=128, vocab_size=50176, seq_len=6144, n_experts=256,
+        n_active_experts=10, hidden_act=1, rope_type=1, norm_epsilon=1e-6,
+        use_pallas=True, full_attn_interval=4, full_attn_offset=0, window=512,
+        window_heads=72, window_ring=784, attn_gate=True, n_dense_layers=1,
+        n_experts_held=128, expert_first=0, n_shared_experts=1, moe_hidden_dim=1024,
+        routed_scale=2.5,
+    )
+
+
+def _laguna_params(cfg, S):
+    """The parameter tree as `models/params._load_windowed` builds it,
+    described and not held (6.6 GB)."""
+    from distributed_llama_tpu.models.params import (
+        ExpertParams, LayerParams, ModelParams, WindowParams,
+    )
+    from distributed_llama_tpu.ops.quant import QuantTensor
+
+    def q40(*lead, out, inn):
+        return QuantTensor(q=S((*lead, inn // 8, out), jnp.int32),
+                           d=S((*lead, inn // 32, out), jnp.float16))
+
+    L, Lm, Ld, dim = cfg.n_layers, cfg.n_moe_layers, cfg.n_dense_layers, cfg.dim
+    Lf, Lw, Eh, ff, hd = cfg.n_kv_layers, cfg.n_win_layers, cfg.n_experts_held, cfg.moe_hidden_dim, cfg.head_dim
+    kv = cfg.n_kv_heads * hd
+    experts = ExpertParams(
+        gate=S((Lm, cfg.n_experts, dim), jnp.float32), bias=None,
+        w1=q40(Lm, Eh, out=ff, inn=dim), w3=q40(Lm, Eh, out=ff, inn=dim),
+        w2=q40(Lm, Eh, out=dim, inn=ff),
+        s13=q40(Lm, out=2 * ff, inn=dim), s2=q40(Lm, out=dim, inn=ff),
+    )
+    layers = LayerParams(
+        q=None, k=None, v=None, w1=None, w3=None,
+        wqkv=q40(Lf, out=cfg.n_heads * hd + 2 * kv, inn=dim),
+        wo=q40(Lf, out=dim, inn=cfg.n_heads * hd), gate=S((Lf, cfg.n_heads, dim), jnp.float32),
+        win=WindowParams(
+            wqkv=q40(Lw, out=cfg.window_heads * hd + 2 * kv, inn=dim),
+            wo=q40(Lw, out=dim, inn=cfg.window_heads * hd),
+            gate=S((Lw, cfg.window_heads, dim), jnp.float32),
+        ),
+        w13=q40(Ld, out=2 * cfg.hidden_dim, inn=dim), w2=q40(Ld, out=dim, inn=cfg.hidden_dim),
+        norm0=S((L, dim), jnp.float32), norm1=S((L, dim), jnp.float32), experts=experts,
+    )
+    return ModelParams(
+        embedding=S((cfg.vocab_size, dim), jnp.float32), layers=layers,
+        final_norm=S((dim,), jnp.float32), wcls=q40(out=cfg.vocab_size, inn=dim),
+    )
+
+
+def _laguna_step(rows, t, pages=11264, kv_len=6144, cell_rows=32):
+    """The served program's model step (`forward_uncompiled`) at
+    Laguna-S-2.1's widths: `rows` decoding rows of one position (the cell's
+    batch-decode step at its one bound), or one prompt chunk of `t` tokens
+    through a row's page-table slice and its ring; the pool (2.2 GB) and the
+    rings (0.6 GB) donated."""
+    from distributed_llama_tpu.models.params import KVCache
+    from distributed_llama_tpu.models.transformer import forward_uncompiled
+    from distributed_llama_tpu.ops.rope import RopeTables
+
+    cfg = _laguna_cfg()
+    slots = cfg.window_ring // PAGE
+
+    def build(S):
+        def fn(params, rope, k, v, wk, wv, counts, tokens, pos, table, row):
+            logits, cache = forward_uncompiled(
+                cfg, params, rope, KVCache(k=k, v=v, wk=wk, wv=wv, moe=counts), tokens, pos,
+                kv_len=kv_len, page_table=table, page_size=PAGE,
+                rec_row=None if t == 1 else row,
+            )
+            return logits, cache.k, cache.v, cache.wk, cache.wv, cache.moe
+
+        f32 = jnp.float32
+        rope = RopeTables(
+            cos=S((6144, 32), f32), sin=S((6144, 32), f32),
+            window=RopeTables(cos=S((6144, 64), f32), sin=S((6144, 64), f32)),
+        )
+        pool = S((cfg.n_kv_layers, pages, PAGE, 8, 128), jnp.bfloat16)
+        ring = S((cfg.n_win_layers, cell_rows * slots, PAGE, 8, 128), jnp.bfloat16)
+        pos = S((rows,), jnp.int32) if t == 1 else S((), jnp.int32)
+        return fn, [_laguna_params(cfg, S), rope, pool, pool, ring, ring, S((2, 2), jnp.int32),
+                    S((rows, t), jnp.int32), pos, S((rows, kv_len // PAGE), jnp.int32),
+                    S((), jnp.int32)], (2, 3, 4, 5, 6)
+
+    return build
+
+
 def _granite_cfg():
     """Granite-4.0-H-Micro as `perfbench/configs/granite-4.0-h-micro.json`
     has it: nothing cut."""
@@ -398,6 +492,14 @@ def _kimi_grouped(pairs, role):
 
 
 CASES = {
+    # Laguna-S-2.1 (perfbench/configs/laguna-s-2.1.json): the cell's decode
+    # step at 24 rows (and at 16) at its
+    # one bound, 6144, and a prompt's chunk of 256 at the deepest bucket its
+    # prompts reach (2048) and at the deepest the plan holds, whole
+    "laguna-step-24rows": _laguna_step(24, 1, cell_rows=24),
+    "laguna-step-16rows": _laguna_step(16, 1, cell_rows=16),
+    "laguna-step-prompt256-kv2048": _laguna_step(1, 256, kv_len=2048),
+    "laguna-step-prompt256-kv6144": _laguna_step(1, 256),
     # Granite-4.0-H-Micro (perfbench/configs/granite-4.0-h-micro.json): the
     # cell's decode step at 32 rows and a prompt's chunk of 256, whole, and
     # the state-space decode kernel alone at the rows the cell may keep
@@ -534,6 +636,17 @@ def test_kernel_compiles_for_v5e(v5e, case):
         # the gathered view
         assert count_tpu_kernels(compiled) == (16 if "rows" in case else 14)
         assert compiled.memory_analysis().temp_size_in_bytes < 5 << 28
+    if case.startswith("laguna-step"):
+        # the leading layer's kernels (wqkv, attention's, wo, w13, w2), a
+        # window layer's body (wqkv, attention's, wo, the three grouped calls,
+        # s13, s2), the period's full layer's (the same) and the head. A
+        # decode step reads both kinds of cache through the page-table kernel.
+        # No copy of the pool (2.2 GB), of the rings (0.6 GB) or of an expert
+        # stack (1.8 GB) beside them
+        assert count_tpu_kernels(compiled) >= 4 + 2 * 7 + 1 + (3 if "rows" in case else 0)
+        # (the temps are the expert stacks' scale planes, bitcast once a
+        # program as Kimi's are: 0.60 GB, and a prompt's scores)
+        assert compiled.memory_analysis().temp_size_in_bytes < 11 << 26
     if case.startswith("granite-step"):
         # 36 state-space layers in two inner scans' bodies and 4 full layers in
         # the outer one: the kernels of three layer bodies (four matmuls a
