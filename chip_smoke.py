@@ -11,8 +11,9 @@ ffn 12288, 32Q/8KV, head_dim 128, vocab 151936; no width is cut), then
 1. numbers, on a depth-cut copy at the same widths: teacher-forced logits of
    the bf16 kernel path against the same engine's float32 XLA path; the int8
    page-table kernel against the gather+dequant formulation on a random
-   pool; a short paged `--kv-dtype int8` generation against the bf16-paged
-   one, token for token;
+   pool, and the bf16 one with rows that end at every residue of a block (a
+   wait that does not balance its starts hangs there; PR 47); a short paged
+   `--kv-dtype int8` generation against the bf16-paged one, token for token;
 2. the server, at full depth: `server.api.serve(server.api.parse_args(...))`
    with the entry points' defaults (paged KV, prefix cache, n-gram
    speculation, grammar arena, warm-up on, sanitizers armed), batch 2,
@@ -41,7 +42,8 @@ of width 2048 beside a shared one, an eighth of the vocabulary), at 2 rows:
    blocks against its float32 `ragged_dot` form on the same inputs, at 16, 256
    and 2048 pairs (rows past the live blocks must never reach the result);
    the latent arm's page-table kernel against its gathered view on the same
-   pool (16 rows at positions of their own over a scattered table; PR 44);
+   pool (33 rows that hold 1..33 live pages over a scattered table, so they
+   end at every residue of the latent block's 32 pages; PRs 44, 47);
    latent attention's absorbed form through the paged latent arm against the
    same engine's float32 XLA path, teacher-forced logits, prefill then decode
    (the float32 path is held to the plain reference on the CPU,
@@ -60,8 +62,9 @@ layers that hold 16 of 256 experts of width 1024 beside a shared one, half the
 vocabulary), at 2 rows:
 
 1. numbers: the window arm's page-table kernel told the window against its
-   gathered view on the same ring (16 rows at positions from under the window
-   to 5,000, 9 queries a stored head); the leading layer and one period, bf16
+   gathered view on the same ring (36 rows: 1..32 live pages under the
+   window, every residue of a block of 16, then positions to 5,000 whose
+   table is 16 + 16 + 1 pages; 9 queries a stored head; PRs 46, 47); the leading layer and one period, bf16
    kernels (the flash kernel with the band over the gathered ring, the
    page-table kernel over the ring and over the pool, the grouped expert
    kernel) against the same engine's float32 XLA path, teacher-forced logits,
@@ -406,6 +409,45 @@ def phase_numbers(cut_model: str, tokenizer: str, rehearse: bool) -> None:
         if not kdiff <= MAX_PAGED_KERNEL_DIFF:
             fail(f"numbers/paged kernel ({store}): max diff {kdiff}")
 
+    # (b2) the same kernel's waits (PR 47: one a full block, a last block's by
+    # the binary digits of its pages): a decode step of rows that end at
+    # EVERY residue of a block of 16 pages, behind none, one and two full
+    # blocks, a parked row first, between and last. A wait that does not
+    # balance its starts hangs here or attends over pages not yet arrived.
+    ppb = 16
+    live = [0, *(r + ppb * (r % 3) for r in range(1, ppb + 1)), 0, ppb, 2 * ppb, 3 * ppb, 0]
+    b, n_read = len(live), 3 * ppb
+    P = sum(live) + 1
+    pos = jnp.asarray([n_read * ps if n == 0 else (n - 1) * ps + r % ps for r, n in enumerate(live)], jnp.int32)
+    order, rows_ = rng.permutation(P - 1) + 1, []  # page 0, where -1 clamps, is no row's
+    for n in live:
+        rows_.append(np.concatenate([order[:n], np.full(n_read - n, -1)]))
+        order = order[n:]
+    table_ = jnp.asarray(np.stack(rows_).astype(np.int32))
+    q = jnp.asarray(rng.standard_normal((b, 1, heads, hd), dtype=np.float32)).astype(jnp.bfloat16)
+    kp, vp = (
+        jnp.asarray(rng.standard_normal((L, P, ps, n_kv, hd), dtype=np.float32)).astype(jnp.bfloat16)
+        for _ in "kv"
+    )
+    got = paged_decode_attention(
+        q, kp, vp, None, None, jnp.int32(1), pos, table_, n_read=n_read, page_size=ps, interpret=interp,
+    ).astype(jnp.float32)
+    seen = jnp.maximum(table_, 0)
+    want = gqa_attention(
+        q.astype(jnp.float32), kp[1, seen].astype(jnp.float32).reshape(b, n_read * ps, n_kv, hd),
+        vp[1, seen].astype(jnp.float32).reshape(b, n_read * ps, n_kv, hd), pos[:, None],
+    )
+    alive = np.asarray(live) > 0
+    rdiff = float(jnp.max(jnp.abs(got - want)[alive]))
+    parked_zero = not bool(jnp.any(got[~alive]))
+    say(
+        "numbers", check="page-table kernel, rows at every residue of a block",
+        rows=b, live_pages=[min(live), max(live)], pages_a_block=ppb,
+        max_abs_diff=round(rdiff, 5), parked_rows_zero=parked_zero, bound=MAX_PAGED_KERNEL_DIFF,
+    )
+    if not (rdiff <= MAX_PAGED_KERNEL_DIFF and parked_zero):
+        fail(f"numbers/paged kernel residues: max diff {rdiff}, parked rows zero {parked_zero}")
+
     # (b') the gated-delta decode kernel (ops/pallas_gdn.py) against the
     # recurrence it implements, at Olmo-Hybrid-7B's heads (30 x 96 x 192; 4
     # rows, layer 1 of 2), and the chunked form a prompt's chunk takes
@@ -741,13 +783,15 @@ def phase_latent_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
     from distributed_llama_tpu.models import kv_arms
     from distributed_llama_tpu.models.params import KVCache
 
-    ps, width, rows = 16, cfg.latent_page_width, 2 if rehearse else 16
-    slots, pages = (8, 64) if rehearse else (128, 2560)
+    # (PR 47: row r holds r + 1 live pages, so the rows end at every residue
+    # of the latent block's 32 pages and one is a full block and a page)
+    ps, width, rows = 16, cfg.latent_page_width, 3 if rehearse else 33
+    slots, pages = (8, 64) if rehearse else (128, 4480)
     own = np.random.default_rng(44)  # (b) below keeps the draws it had before this check
     bf = lambda *sh: jnp.asarray(own.standard_normal(sh, dtype=np.float32)).astype(jnp.bfloat16)  # noqa: E731
     pool, q, k = bf(2, pages, ps, width), bf(rows, 1, cfg.n_heads, width), bf(rows, 1, 1, width)
     table = jnp.asarray(own.permutation(pages)[: rows * slots].reshape(rows, slots).astype(np.int32))
-    pos = jnp.asarray(np.linspace(5, slots * ps - 2, rows).astype(np.int32))
+    pos = jnp.asarray([r * ps + r % ps for r in range(rows)], jnp.int32)
     arm = jax.jit(
         lambda cfg, pool, q, k, pos, table: kv_arms.latent_arm(
             cfg, KVCache(k=pool, v=None),
@@ -909,14 +953,17 @@ def phase_window_numbers(model: str, tokenizer: str, rehearse: bool) -> None:
     eng = engine("bfloat16")
     cfg = eng.cfg.with_(seq_len=8192)
     free(eng)
-    ps, rows, slots = 16, 2 if rehearse else 16, cfg.window_ring // 16
+    # (PR 47: 32 rows under the window with 1..32 live pages, every residue
+    # of a block of 16, then rows past it whose table is 16 + 16 + 1 pages)
+    ps, rows, slots = 16, 3 if rehearse else 36, cfg.window_ring // 16
     own = np.random.default_rng(46)
     bf = lambda *sh: jnp.asarray(own.standard_normal(sh, dtype=np.float32)).astype(jnp.bfloat16)  # noqa: E731
     ring_shape = (2, rows * slots, ps, cfg.n_kv_heads, cfg.head_dim)
     wk, wv = bf(*ring_shape), bf(*ring_shape)
     q = bf(rows, 1, cfg.window_heads, cfg.head_dim)
     k, v = bf(rows, 1, cfg.n_kv_heads, cfg.head_dim), bf(rows, 1, cfg.n_kv_heads, cfg.head_dim)
-    pos = jnp.asarray(np.linspace(5, 200 if rehearse else 5000, rows).astype(np.int32))
+    under = [r * ps + r % ps for r in range(cfg.window // ps)]
+    pos = jnp.asarray(([5, 30, 200] if rehearse else under + [cfg.window, 1023, 3000, 5000])[:rows], jnp.int32)
     arm = jax.jit(
         lambda cfg, wk, wv, q, k, v, pos: kv_arms.window_arm(
             cfg, KVCache(k=wk[:, :1], v=wv[:, :1], wk=wk, wv=wv),

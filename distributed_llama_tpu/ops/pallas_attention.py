@@ -265,6 +265,17 @@ def flash_attention(
 #   scalar-prefetched table into a VMEM buffer [block, n_kv, hd]. Two buffers:
 #   the next block's copies (the next live ROW's first block after a row's
 #   last) fly under this block's arithmetic;
+# * a block's copies are started in groups of `PAGED_START_UNROLL` pages of
+#   straight-line code and waited for ONCE a pool (PR 47): every copy into a
+#   buffer signals that buffer's semaphore, a DMA semaphore counts what
+#   arrived, and a wait's descriptor only says how much to wait for, so a
+#   full block is one wait for the whole buffer and a row's last block one
+#   wait for each binary digit of its pages (8 + 4 + 1 for 13). The loops a
+#   page shared the core's one instruction stream with the block's dots and
+#   softmax and added 5-12% to a call (12-22% over a latent pool); a full
+#   block of 16 k/v pages now takes 1.50 us where its copies alone take 1.44
+#   (PERF.md section 6, PR 47). Groups of 2 have the gain but for a percent
+#   or two, and larger groups cost a program's lowering more than they buy;
 # * bytes follow the position, not the bucket: a row copies the pages up to
 #   its last query position and no other; a parked row (position at or past
 #   the bucket's end) copies nothing. `n_read` only bounds the table;
@@ -300,6 +311,7 @@ def flash_attention(
 
 PAGED_BLOCK_TOKENS = 256  # positions a block; probe_paged_attention.py's sweep
 LATENT_BLOCK_TOKENS = 512  # the same for a latent page (its `--latent` sweep)
+PAGED_START_UNROLL = 2  # pages a group of a block's copy starts (its `--unroll` sweep)
 PAGED_VMEM_BUDGET = 10 * 2**20  # of the 16 MiB a kernel may scope on a v5e
 PAGED_PREFETCH_WORDS = 192 * 2**10  # of the 256 Ki int32 words of a v5e's SMEM,
 # where the scalar-prefetch operand lies whole (tests/test_tpu_compile.py
@@ -334,7 +346,8 @@ def _paged_block_pages(
 
 def _paged_decode_kernel(
     m_ref, q_ref, *rest,
-    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt, v_width=None, window=None,
+    scale, g, t, ps, ppb, n_read, n_kv, b, quantized, cdt, unroll, v_width=None,
+    window=None,
 ):
     """One batch row's attention over its live pages (see the notes above).
     m_ref (scalar prefetch) carries [layer, first live row, pos_base[b],
@@ -364,26 +377,68 @@ def _paged_decode_kernel(
     POS, LIVE, NEXT, TABLE = 2, 2 + b, 2 + 2 * b, 2 + 3 * b
     if window is not None:
         FIRST, TABLE = TABLE, TABLE + b
+    # (index arithmetic in lax primitives: jnp's `//`, `%` and `where` are
+    # jitted helpers, each a nested lowering of every decode program's set-up)
+    i32 = jnp.int32
 
-    def copies(row, i, slot, do):
-        """`do` each page copy of block i of `row` into buffer `slot`."""
-        first = i * ppb
-        n = jnp.minimum(ppb, m_ref[LIVE + row] - first)
+    pools = ((k_hbm, kbuf),) if latent else ((k_hbm, kbuf), (v_hbm, vbuf))
 
-        def one(p, _):
+    def pages_of(row, i):
+        """Block i of `row`: its first table entry and its live pages (>= 1
+        wherever a block is started or waited for)."""
+        return i * ppb, jnp.minimum(ppb, m_ref[LIVE + row] - i * ppb)
+
+    def start(row, i, slot):
+        """Start each page copy of block i of `row` into buffer `slot`:
+        groups of `unroll` pages of straight-line code, a page past the
+        block's last skipped, so that one page's table read and address
+        arithmetic overlap the next's."""
+        first, n = pages_of(row, i)
+
+        def one(p):
             page = m_ref[TABLE + row * n_read + first + p]
-            dst = pl.ds(p * ps, ps)
-            do(pltpu.make_async_copy(
-                k_hbm.at[layer, page], kbuf.at[slot, dst], sem.at[0, slot]))
-            if not latent:
-                do(pltpu.make_async_copy(
-                    v_hbm.at[layer, page], vbuf.at[slot, dst], sem.at[1, slot]))
+            for j, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(
+                    hbm.at[layer, page], buf.at[slot, pl.ds(p * ps, ps)], sem.at[j, slot]
+                ).start()
+
+        def group(gi, _):
+            for j in range(unroll):
+                p = gi * unroll + j
+                if j == 0:
+                    one(p)  # a group's first page is live
+                else:
+                    pl.when(p < n)(partial(one, p))
             return 0
 
-        jax.lax.fori_loop(0, n, one, 0)
+        if unroll >= ppb:
+            group(0, 0)
+        else:
+            jax.lax.fori_loop(0, jax.lax.div(n + (unroll - 1), i32(unroll)), group, 0)
 
-    start = lambda row, i, slot: copies(row, i, slot, lambda c: c.start())
-    wait = lambda row, i, slot: copies(row, i, slot, lambda c: c.wait())
+    def wait(row, i, slot):
+        """Wait for block i of `row` in buffer `slot`. Every page copy of a
+        slot signals one semaphore a pool, and a DMA semaphore counts what
+        arrived: a wait's descriptor says how many bytes to wait for and
+        copies nothing, so its source is its destination. A full block is
+        one wait for the whole buffer; a row's last block is a wait for each
+        binary digit of its pages. What `start` started is waited for
+        exactly: the semaphore is zero again before the slot's next block."""
+        _, n = pages_of(row, i)
+
+        def wait_pages(count):
+            for j, (_, buf) in enumerate(pools):
+                whole = buf.at[slot, pl.ds(0, count * ps)]
+                pltpu.make_async_copy(whole, whole, sem.at[j, slot]).wait()
+
+        pl.when(n == ppb)(partial(wait_pages, ppb))
+
+        @pl.when(n < ppb)
+        def _():
+            size = 1
+            while size < ppb:
+                pl.when(jax.lax.bitwise_and(n, i32(size)) != 0)(partial(wait_pages, size))
+                size *= 2
 
     @pl.when(bi == 0)
     def _():
@@ -397,9 +452,6 @@ def _paged_decode_kernel(
         def _():
             start(m_ref[1], 0, 0)
 
-    # (index arithmetic in lax primitives: jnp's `//`, `%` and `where` are
-    # jitted helpers, each a nested lowering of every decode program's set-up)
-    i32 = jnp.int32
     n_pages = m_ref[LIVE + bi]
     n_blk = jax.lax.div(n_pages + (ppb - 1), i32(ppb))
     base = cnt_ref[0]  # blocks walked before this row: the buffers alternate
@@ -498,7 +550,8 @@ def _paged_decode_kernel(
 @partial(
     jax.jit,
     static_argnames=(
-        "n_read", "page_size", "scale", "block_tokens", "interpret", "v_width", "window"
+        "n_read", "page_size", "scale", "block_tokens", "interpret", "v_width", "window",
+        "start_unroll",
     ),
 )
 def paged_decode_attention(
@@ -521,6 +574,7 @@ def paged_decode_attention(
     # table's FIRST entry starts at (a window layer's ring: its table lists
     # the pages that intersect the row's window, not the row's from slot 0);
     # a row whose `pos_first` lies past its `pos_base` reads nothing
+    start_unroll: int = PAGED_START_UNROLL,
 ) -> jnp.ndarray:
     """Page-table GQA decode attention over the pool, float or int8.
 
@@ -650,7 +704,7 @@ def paged_decode_attention(
         partial(
             _paged_decode_kernel, scale=scale, g=g, t=t, ps=ps, ppb=ppb,
             n_read=n_read, n_kv=n_kv, b=b, quantized=quantized, cdt=cdt,
-            v_width=v_width if latent else None,
+            unroll=max(1, min(start_unroll, ppb)), v_width=v_width if latent else None,
             **({"window": window} if window else {}),
         ),
         grid_spec=grid_spec,

@@ -73,12 +73,16 @@ def test_one_chip_rehearsal_fails_for_the_device_check_alone(rehearsals):
 def test_one_chip_rehearsal_runs_every_phase(rehearsals):
     _, lines, _ = rehearsals["one"]
     checks = [l["check"] for l in _by_phase(lines, "numbers")]
-    assert len(checks) == 6
+    assert len(checks) == 7
     gated = next(l for l in _by_phase(lines, "numbers") if "gated-delta" in l["check"])
     assert max(gated[k] for k in ("kernel_o", "kernel_state", "chunked_o", "chunked_state")) <= gated["bound"]
     assert gated["kernel_other_layer"] == 0.0  # the step writes its own layer's state alone
     for store in ("int8", "bfloat16"):  # the page-table kernel alone, both pools
         assert f"{store} page-table kernel vs gather" in checks
+    # rows at every residue of a block, parked rows between (PR 47's waits)
+    residues = next(l for l in _by_phase(lines, "numbers") if "every residue" in l["check"])
+    assert residues["max_abs_diff"] <= residues["bound"] and residues["parked_rows_zero"]
+    assert residues["live_pages"] == [0, 3 * residues["pages_a_block"]]
     gen = next(l for l in _by_phase(lines, "numbers") if "generation" in l["check"])
     assert gen["decode_arm"] == "page-table kernel"
     assert gen["leading_tokens_equal"] >= gen["bound"]

@@ -36,6 +36,13 @@ from distributed_llama_tpu.runtime.paged_kv import (
 )
 from distributed_llama_tpu.testing import tiny_header, write_tiny_model
 from distributed_llama_tpu.tokenizer import Sampler
+from paged_kernel_cases import (
+    edge_pages,
+    edge_positions,
+    edge_tables,
+    frozen_expected,
+    frozen_result,
+)
 
 
 @pytest.fixture(scope="module")
@@ -278,6 +285,95 @@ def test_paged_flash_attention_masks_unmapped_pages():
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
+
+
+def _edge_call(store, t, ppb, **kw):
+    """The kernel over `paged_kernel_cases.edge_positions`' rows (blocks of
+    `ppb` pages, a table of three blocks) beside gqa_attention over the
+    contiguous view; -1 past every row's pages, a pool of garbage."""
+    rng = np.random.default_rng(47 + ppb)
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "int8": jnp.int8}[store]
+    L, ps, n_kv, hd, heads = 2, 8, 2, 32, 4
+    pages = np.asarray(edge_pages(ppb))
+    pos0 = edge_positions(ppb, ps, t)
+    b, n_read = len(pages), 3 * ppb
+    S = n_read * ps
+    tables, n_pages = edge_tables(rng, pages, n_read)  # a row's live pages and no other are mapped
+    k_lin = rng.standard_normal((b, S, n_kv, hd)).astype(np.float32)
+    v_lin = rng.standard_normal((b, S, n_kv, hd)).astype(np.float32)
+    qdt = jnp.bfloat16 if store == "bfloat16" else jnp.float32
+    q = jnp.asarray(rng.standard_normal((b, t, heads, hd)).astype(np.float32)).astype(qdt)
+    kp, vp, ksp, vsp, ref_k, ref_v = _build_pool(
+        rng, k_lin, v_lin, tables, L, n_pages, ps, layer=1, dtype=dtype)
+    out = paged_decode_attention(
+        q, *_as_jnp(kp, vp, ksp, vsp), jnp.int32(1), jnp.asarray(pos0),
+        jnp.asarray(tables), n_read=n_read, page_size=ps, block_tokens=ppb * ps,
+        interpret=True, **kw,
+    )
+    ref = gqa_attention(
+        q, jnp.asarray(ref_k).astype(qdt), jnp.asarray(ref_v).astype(qdt),
+        jnp.asarray(pos0[:, None] + np.arange(t)[None, :], jnp.int32),
+    )
+    return np.asarray(out, np.float32), np.asarray(ref, np.float32), pages > 0
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("store", sorted(PAGED_TOL))
+@pytest.mark.parametrize("ppb", [1, 3, 8, 16, 32])
+def test_a_last_block_of_every_page_count(ppb, store, t):
+    """PR 47: a full block is waited for once and a row's last block by the
+    binary digits of its pages. Every count 1..ppb of a last block, behind
+    none, one and two full blocks; a parked row first, between live rows and
+    last (the `ahead` start into the other buffer crosses it); blocks of 1,
+    3 (no power of two), 8, 16 and 32 pages; a decode step and a block of 4
+    queries; every stored dtype. (tests/paged_kernel_cases.py says what
+    interpret mode cannot see of this.)"""
+    got, want, live = _edge_call(store, t, ppb)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], rtol=PAGED_TOL[store], atol=PAGED_TOL[store])
+    assert not got[~live].any()  # a parked row copied nothing and wrote zeros
+
+
+def test_a_table_of_three_pages_is_one_block_of_three():
+    """`n_read` 3 under the default block length: `ppb` 3, and a last block
+    of 1 or 2 pages is waited for by the digits 1 and 2."""
+    rng = np.random.default_rng(3)
+    L, n_pages, ps, n_kv, hd, heads, b, n_read = 2, 16, 16, 2, 32, 4, 4, 3
+    S = n_read * ps
+    k_lin, v_lin = (rng.standard_normal((b, S, n_kv, hd)).astype(np.float32) for _ in "kv")
+    q = jnp.asarray(rng.standard_normal((b, 1, heads, hd)).astype(np.float32))
+    tables = rng.permutation(n_pages)[: b * n_read].reshape(b, n_read).astype(np.int32)
+    pos0 = np.array([S - 1, 3, 2 * ps - 1, S], np.int32)  # 3, 1, 2 pages and a parked row
+    kp, vp, _, _, ref_k, ref_v = _build_pool(
+        rng, k_lin, v_lin, tables, L, n_pages, ps, layer=1, dtype=jnp.float32)
+    out = paged_decode_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), None, None, jnp.int32(1), jnp.asarray(pos0),
+        jnp.asarray(tables), n_read=n_read, page_size=ps, interpret=True,
+    )
+    ref = gqa_attention(q, jnp.asarray(ref_k), jnp.asarray(ref_v), jnp.asarray(pos0[:, None]))
+    np.testing.assert_allclose(np.asarray(out)[:3], np.asarray(ref)[:3], rtol=1e-4, atol=1e-4)
+    assert not np.asarray(out)[3].any()
+
+
+@pytest.mark.parametrize("unroll", [1, 2, 3, 4, 8, 64])
+def test_the_copy_starts_in_groups_of_any_size_read_the_same_pages(unroll):
+    """`start_unroll` groups a block's copy starts (1: a page an iteration,
+    as before PR 47; past the block's pages: no loop at all). Which pages
+    are started does not depend on it, so neither does a bit of the result."""
+    got, want, live = _edge_call("float32", 1, 8, start_unroll=unroll)
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-4, atol=1e-4)
+    assert np.array_equal(got, _edge_call("float32", 1, 8)[0])
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8", "latent", "window"])
+def test_the_result_is_the_parents_bit_for_bit(kind):
+    """PR 47 changes how a block's copies are started and waited for and no
+    arithmetic: on the same operands the result is the array the parent's
+    kernel gave (frozen in tests/data/paged_decode_frozen.json, not
+    recomputed by a copy of the old code)."""
+    want, got = frozen_expected(kind), frozen_result(kind)
+    assert got.shape == want.shape and np.abs(want).max() > 1.0
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
 
 
 # -- engine-level identity and quality ----------------------------------------

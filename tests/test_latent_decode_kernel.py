@@ -23,6 +23,7 @@ from distributed_llama_tpu.ops.pallas_attention import (
 from distributed_llama_tpu.runtime.batch_session import BatchSession
 from distributed_llama_tpu.runtime.engine import InferenceEngine
 from distributed_llama_tpu.testing import tiny_latent_header, write_tiny_model
+from paged_kernel_cases import edge_pages, edge_positions, edge_tables
 
 # the tiny latent page: 256 latents + 32 of the key, stored as 384
 L, N_PAGES, PS, W, RANK, HEADS, N_READ = 2, 64, 16, 384, 256, 4, 8
@@ -91,6 +92,42 @@ def test_latent_kernel_matches_the_gathered_view(store, t, pages_a_block, v_widt
     np.testing.assert_allclose(got[live][..., :vw], ref[live][..., :vw], rtol=TOL[store], atol=TOL[store])
     assert not got[..., vw:].any()  # the key's columns hold no value
     assert not got[2].any()  # the parked row
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("store", sorted(TOL))
+@pytest.mark.parametrize("ppb", [1, 3, 8, 32])
+def test_a_last_block_of_every_page_count_of_the_one_stream(ppb, store, t):
+    """PR 47's one wait a block over the latent pool's ONE stream of copies
+    (one semaphore a buffer, no V): a last block of every count 1..ppb behind
+    none, one and two full blocks, parked rows first, between and last
+    (tests/paged_kernel_cases.py: the rows, and what interpret mode cannot
+    see of a wait)."""
+    rng = np.random.default_rng(47 + ppb)
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[store]
+    ps, width, rank = 8, 256, 128
+    pages = np.asarray(edge_pages(ppb))
+    pos0 = edge_positions(ppb, ps, t)
+    b, n_read = len(pages), 3 * ppb
+    tables, n_pages = edge_tables(rng, pages, n_read)
+    draw = lambda *sh: jnp.asarray(rng.standard_normal(sh, np.float32)).astype(dtype)  # noqa: E731
+    lin, pool = draw(b, n_read * ps, width), np.array(draw(L, n_pages, ps, width) * 8)
+    for r in range(b):
+        for si in range(pages[r]):
+            pool[1, tables[r, si]] = np.asarray(lin[r, si * ps : (si + 1) * ps])
+    q = draw(b, t, HEADS, width)
+    out = paged_decode_attention(
+        q, jnp.asarray(pool), None, None, None, jnp.int32(1), jnp.asarray(pos0),
+        jnp.asarray(tables), n_read=n_read, page_size=ps, scale=SCALE,
+        block_tokens=ppb * ps, interpret=True, v_width=rank,
+    )
+    view = lin[:, :, None, :]
+    positions = jnp.asarray(pos0[:, None] + np.arange(t)[None, :], jnp.int32)
+    ref = np.asarray(gqa_attention(q, view, view, positions, scale=SCALE), np.float32)
+    got, live = np.asarray(out, np.float32), pages > 0
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live][..., :rank], ref[live][..., :rank], rtol=TOL[store], atol=TOL[store])
+    assert not got[~live].any() and not got[..., rank:].any()
 
 
 def test_a_nan_left_in_the_buffers_does_not_reach_a_short_row():

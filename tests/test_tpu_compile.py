@@ -90,10 +90,11 @@ def _flash(t, cache_len):
     return build
 
 
-def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS, slots=256):
+def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS, slots=256, window=None):
     """The page-table decode kernel over a pool of the served shape: int8
     with its f32 scale sidecars, or bf16 (the cells' cache); `slots`: a row's
-    page-table entries (`seq_len` / 16)."""
+    page-table entries (`seq_len` / 16). `window`: a window layer's ring,
+    whose table lists the window's pages from `pos_first` (one more operand)."""
 
     def build(S):
         pool = S((layers, 2048, PAGE, KV_HEADS, HEAD_DIM), dtype)
@@ -102,13 +103,16 @@ def _paged(b, t, n_read, dtype=jnp.int8, heads=HEADS, layers=LAYERS, slots=256):
 
         def fn(q, k, v, *rest):
             ks, vs = rest[:2] if scales else (None, None)
+            li, pos, table, *first = rest[len(scales):]
+            told = {"window": window, "pos_first": first[0]} if window else {}
             return pa.paged_decode_attention(
-                q, k, v, ks, vs, *rest[len(scales):], n_read=n_read, page_size=PAGE
+                q, k, v, ks, vs, li, pos, table, n_read=n_read, page_size=PAGE, **told
             )
 
         return fn, [
             S((b, t, heads, HEAD_DIM), jnp.bfloat16), pool, pool, *scales,
             S((), jnp.int32), S((b,), jnp.int32), S((b, slots), jnp.int32),
+            *([S((b,), jnp.int32)] if window else []),
         ]
 
     return build
@@ -607,6 +611,18 @@ CASES = {
     # rows with head 64 stored as 128 (`granite-step-32rows`, kv_len 2048)
     # already at theirs; and the widest table the predicate admits
     "paged-bf16-8b-b16-t1-read256": _paged(16, 1, 256, dtype=jnp.bfloat16),
+    # PR 47's waits (one a full block, a last block's by the binary digits of
+    # its pages: descriptors of 1, 2, 4 and 8 pages of a buffer) where the
+    # blocks are no round number: a table of 3 pages is ONE block of 3, and
+    # Laguna's window layers (72 query heads over a window of 512: a ring's
+    # table of 33 pages is 16 + 16 + 1) alone at the cell's 24 rows and at 32
+    "paged-bf16-8b-b16-t1-read3": _paged(16, 1, 3, dtype=jnp.bfloat16),
+    **{
+        f"paged-bf16-window512-b{b}-t1-read33": _paged(
+            b, 1, 33, dtype=jnp.bfloat16, heads=72, layers=6, slots=33, window=512
+        )
+        for b in (24, 32)
+    },
     f"paged-bf16-8b-b{WIDEST_ROWS}-t1-read{WIDEST_SLOTS}": _paged(
         WIDEST_ROWS, 1, WIDEST_SLOTS, dtype=jnp.bfloat16, slots=WIDEST_SLOTS
     ),

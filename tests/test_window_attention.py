@@ -101,6 +101,50 @@ def test_the_page_table_kernel_reads_the_window_of_a_ring(dtype, group):
         assert np.abs(off - want)[pos >= W].max() > 50 * TOL[dtype]
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_table_of_33_pages_is_two_blocks_and_a_page(dtype):
+    """Laguna's shape of table: a window of 512 over pages of 16 lists 33
+    pages, which blocks of 16 walk as 16 + 16 + 1. PR 47 waits for a full
+    block once and for a last block by the binary digits of its pages: rows
+    under the window with every count 1..32 of live pages, rows past it with
+    32 and 33, a row that reads nothing among them (its `pos_first` past its
+    position), over a ring of 49 pages a row that the deep rows have wrapped.
+    (Interpret mode cannot see a wait that does not balance:
+    tests/paged_kernel_cases.py.)"""
+    window, slots, heads = 512, 49, 6
+    rng = np.random.default_rng(33)
+    pos = np.asarray(
+        [PS * (n - 1) + n % PS for n in range(1, 33)][::-1]
+        + [511, 512, 9999, 527, 528, 800, 1100], np.int32)
+    dead = pos == 9999
+    b, n_read = len(pos), (window - 1) // PS + 2
+    assert n_read == 33
+    upto = np.where(dead, 0, pos)
+    k, v = _history(rng, b, 1104, dtype)
+    shape = (2, b * slots, PS, N_KV, HD)
+    wk, wv = (np.array(jnp.asarray(rng.standard_normal(shape, np.float32) * 8).astype(dtype)) for _ in "kv")
+    kn, vn = np.asarray(k), np.asarray(v)
+    for r in range(b):
+        for p in range(int(upto[r]) + 1):
+            page = r * slots + (p // PS) % slots
+            wk[1, page, p % PS], wv[1, page, p % PS] = kn[r, p], vn[r, p]
+    q = jnp.asarray(rng.standard_normal((b, 1, heads, HD), np.float32)).astype(dtype)
+    first_page = np.maximum(pos - (window - 1), 0) // PS
+    table = (np.arange(b)[:, None] * slots
+             + (first_page[:, None] + np.arange(n_read)[None, :]) % slots).astype(np.int32)
+    live_pages = (pos - first_page * PS) // PS + 1
+    assert set(live_pages[~dead]) == set(range(1, 34))
+    got = np.asarray(paged_decode_attention(
+        q, jnp.asarray(wk), jnp.asarray(wv), None, None, jnp.int32(1), jnp.asarray(pos),
+        jnp.asarray(table), n_read=n_read, page_size=PS, interpret=True, window=window,
+        pos_first=jnp.asarray(np.where(dead, pos + 1, first_page * PS), jnp.int32),
+    ), np.float32)
+    want = _band_attention(q, k, v, upto[:, None], window)
+    # (a bfloat16 result past 2 rounds by more than TOL's half step of O(1))
+    np.testing.assert_allclose(got[~dead], want[~dead], atol=TOL[dtype], rtol=TOL[dtype])
+    assert not got[dead].any()
+
+
 def test_a_row_whose_table_starts_past_its_position_reads_nothing():
     """A parked row (`pos_first` > `pos_base`): zeros, and its neighbours'
     answers as without it."""
